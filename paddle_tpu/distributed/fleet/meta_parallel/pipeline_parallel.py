@@ -41,7 +41,6 @@ import numpy as np
 from ....core.tensor import Tensor
 from ..base.topology import get_hybrid_communicate_group
 from .pp_layers import PipelineLayer
-from ....framework import jax_compat as _jax_compat
 
 __all__ = ["PipelineParallel", "scan_pipeline", "pipeline_train_step",
            "build_schedule", "bubble_fraction", "analytic_bubble_fraction",
@@ -530,7 +529,7 @@ def scan_pipeline(stage_fn, stage_params, inputs, n_micro: int,
     # automatic — GSPMD shards the stage body over them from the data/param
     # shardings, composing pipeline with tensor/data parallelism in ONE
     # program (SURVEY.md §7.3 hard-part 2)
-    fn = _jax_compat.shard_map(per_stage, mesh=mesh,
+    fn = jax.shard_map(per_stage, mesh=mesh,
                        in_specs=(P(axis_name), P()),
                        out_specs=P(axis_name),
                        axis_names=frozenset({axis_name}), check_vma=False)

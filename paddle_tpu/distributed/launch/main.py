@@ -16,6 +16,7 @@ service (`init_parallel_env` reads the same env).
 from __future__ import annotations
 
 import argparse
+import glob
 import os
 import signal
 import subprocess
@@ -40,7 +41,8 @@ def build_parser():
                    choices=["collective", "ps"])
     p.add_argument("--job_id", default="default")
     p.add_argument("--devices", "--gpus", "--xpus", default=None,
-                   help="device ids to make visible")
+                   help="accepted for the reference CLI's sake and ignored: "
+                        "one process drives every local chip")
     p.add_argument("--log_dir", default="log")
     p.add_argument("--log_level", default="INFO")
     p.add_argument("--max_restart", type=int, default=3,
@@ -75,12 +77,28 @@ def _worker_env(args, local_rank: int, world_size: int, base_port: int):
         "PADDLE_TRAINER_ENDPOINTS": endpoints,
         "PADDLE_CURRENT_ENDPOINT": f"{args.host}:{base_port + rank}",
         "PADDLE_MASTER": args.master or f"{args.host}:{base_port - 1}",
-        "FLAGS_selected_devices": args.devices or "",
     })
     return env
 
 
+def _on_tpu_host() -> bool:
+    """Whether this host has TPU chips, told from the device files libtpu
+    opens (`/dev/accel*`, or `/dev/vfio/<n>` on the VFIO hosts) — without
+    JAX: a launcher that initialised the backend would itself hold every
+    chip its workers need."""
+    return bool(glob.glob("/dev/accel*") or glob.glob("/dev/vfio/[0-9]*"))
+
+
 def _spawn(args, world_size, base_port):
+    if args.nproc_per_node > 1 and _on_tpu_host() \
+            and os.environ.get("JAX_PLATFORMS", "").lower() != "cpu":
+        # every worker gets the same environment, so each would claim
+        # every chip: the second one fails or hangs inside libtpu
+        raise SystemExit(
+            f"[launch] --nproc_per_node={args.nproc_per_node} on a TPU "
+            "host: a chip belongs to one process, and one process drives "
+            "all local chips — launch one worker per host (or set "
+            "JAX_PLATFORMS=cpu for a CPU job)")
     procs = []
     os.makedirs(args.log_dir, exist_ok=True)
     for local_rank in range(args.nproc_per_node):

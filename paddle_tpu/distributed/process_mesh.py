@@ -124,9 +124,15 @@ def _jax_mesh_cached(ids_bytes, shape, dim_names):
 
     ids = np.frombuffer(ids_bytes, dtype=np.int64).reshape(shape)
     devices = jax.devices()
+    if ids.size and int(ids.max()) >= len(devices):
+        # never wrap round: ranks stacked on the first chips would run,
+        # slowly and out of memory, instead of saying what is missing
+        raise ValueError(
+            f"mesh names process id {int(ids.max())} but JAX has "
+            f"{len(devices)} device(s)")
     dev_arr = np.empty(shape, dtype=object)
     for idx in np.ndindex(*shape):
-        dev_arr[idx] = devices[int(ids[idx]) % len(devices)]
+        dev_arr[idx] = devices[int(ids[idx])]
     return Mesh(dev_arr, dim_names)
 
 

@@ -19,7 +19,6 @@ import functools
 from typing import Optional
 
 import numpy as np
-from ..framework import jax_compat as _jax_compat
 
 __all__ = ["ring_flash_attention", "ring_attention", "ulysses_attention"]
 
@@ -54,7 +53,7 @@ def ring_attention(q, k, v, axis_name: str = "sep", causal: bool = False,
 
     d = q.shape[-1]
     scale = sm_scale if sm_scale is not None else 1.0 / np.sqrt(d)
-    n = _jax_compat.axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     my = jax.lax.axis_index(axis_name)
     s_local = q.shape[1]
 
@@ -103,7 +102,7 @@ def _ring_shard_mapped(q, k, v, pmesh, axis_name, causal, sm_scale):
     spec = P(None, axis_name, None, None)
     body = functools.partial(ring_attention, axis_name=axis_name,
                              causal=causal, sm_scale=sm_scale)
-    fn = _jax_compat.shard_map(body, mesh=jmesh, in_specs=(spec, spec, spec),
+    fn = jax.shard_map(body, mesh=jmesh, in_specs=(spec, spec, spec),
                        out_specs=spec, check_vma=False)
     return fn(q, k, v)
 

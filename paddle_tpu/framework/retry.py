@@ -4,12 +4,8 @@ wall-clock deadline.
 Reference analog: the retry loops scattered through the reference's fleet
 stack (etcd re-registration in `fleet/elastic/manager.py`, RPC channel
 re-dials) — here centralised so every transient-failure path (checkpoint
-shard writes, the bench TPU probe, the elastic store's file lock) shares
-one policy and one monitor counter instead of a hand-rolled loop each.
-
-Stdlib-only on purpose: `bench.py` loads this file standalone (before any
-jax/paddle import, so the probe subprocess still owns the TPU); the
-monitor hook degrades to a no-op in that mode.
+shard writes, the elastic store's file lock) shares one policy and one
+monitor counter instead of a hand-rolled loop each.
 """
 from __future__ import annotations
 
@@ -29,10 +25,8 @@ class RetryDeadlineExceeded(TimeoutError):
 def _count(monitor_name: Optional[str], delta: int = 1) -> None:
     if not monitor_name:
         return
-    try:
-        from . import monitor
-    except ImportError:  # loaded standalone (bench.py pre-jax probe)
-        return
+    from . import monitor
+
     monitor.inc(monitor_name, delta)
 
 

@@ -23,7 +23,6 @@ from .....core import dispatch
 from .....core.tensor import Tensor
 from .....nn.layer.layers import Layer
 from .....ops._helpers import as_tensor
-from .....framework import jax_compat as _jax_compat
 
 __all__ = ["MoELayer", "NaiveGate", "SwitchGate", "GShardGate",
            "StackedExperts"]
@@ -365,7 +364,7 @@ def _ep_local_fn(x, gate_w, w1, b1, w2, b2, *, top_k, capacity, axis_name,
 
     t, hdim = x.shape
     e_total = gate_w.shape[1]
-    n = _jax_compat.axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     logits = jnp.einsum("th,he->te", x, gate_w,
                         preferred_element_type=jnp.float32)
     probs = jax.nn.softmax(logits, axis=-1)
@@ -434,7 +433,7 @@ def _ep_moe_fn(x, gate_w, w1, b1, w2, b2, *, top_k, capacity, activation,
     local = functools.partial(_ep_local_fn, top_k=top_k, capacity=capacity,
                               axis_name=axis_name, activation=activation)
     ep = P(axis_name)
-    fn = _jax_compat.shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(ep, P(), ep, ep, ep, ep),
         out_specs=(ep, P()), check_vma=False)

@@ -45,8 +45,8 @@ dispatch.register_op("pallas_swiglu_packed",
 
 
 def _pallas_on(x) -> bool:
-    return _psupport.kernels_enabled() and str(
-        np.dtype(x._data.dtype)) in ("float32", "bfloat16", "float16")
+    return _psupport.kernels_enabled(x._data) \
+        and _psupport.float_dtype_ok(x._data.dtype)
 
 
 def fused_rms_norm(x, norm_weight, norm_bias=None, epsilon=1e-6,

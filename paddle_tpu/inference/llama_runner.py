@@ -44,13 +44,16 @@ class GenerationConfig:
         self.seed = seed
 
 
-def _stack_llama_params(model: LlamaForCausalLM):
-    """Stack per-layer weights on a leading L axis (the fused-MT layout)."""
+def _stack_llama_params(model: LlamaForCausalLM, dtype=None):
+    """Stack per-layer weights on a leading L axis (the fused-MT layout),
+    each tensor cast to `dtype` BEFORE it is stacked: casting the stacked
+    copy instead holds a second full-precision model on the device, and
+    at real widths that build peak — not the engine — sets the depth a
+    chip can hold."""
     import jax.numpy as jnp
 
-    cfg = model.config
     layers = model.llama.layers
-    get = lambda t: t._data
+    get = lambda t: t._data if dtype is None else t._data.astype(dtype)
 
     def stack(fn):
         return jnp.stack([fn(l) for l in layers])
@@ -176,11 +179,7 @@ class LlamaInferenceEngine:
         self.max_batch_size = max_batch_size
         self.manager = BlockCacheManager(num_blocks, block_size,
                                          max_blocks_per_seq)
-        self.params = _stack_llama_params(model)
-        if dtype is not None:
-            self.params = {k: v.astype(dtype) if v.dtype in
-                           (jnp.float32, jnp.bfloat16, jnp.float16) else v
-                           for k, v in self.params.items()}
+        self.params = _stack_llama_params(model, dtype)
         self.weight_only = weight_only
         if weight_only is not None:
             self.params = _quantize_stacked(self.params, weight_only)

@@ -196,8 +196,8 @@ def rms_norm(x, weight, epsilon=1e-6, name=None):
         from ...ops.pallas import _support as _ps
         from ...ops.pallas import rms_norm as _prms
 
-        if _ps.kernels_enabled() and _prms.supported(tuple(x.shape),
-                                                     x._data.dtype):
+        if _ps.kernels_enabled(x._data) and _prms.supported(
+                tuple(x.shape), x._data.dtype):
             from ...incubate.nn import functional as _inc  # registers the op
 
             return dispatch.apply("pallas_rms_norm", [x, as_tensor(weight)],

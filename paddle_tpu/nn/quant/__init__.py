@@ -163,21 +163,16 @@ def dequant_matmul(x, wq, scale, weight_dtype: str = "int8"):
         # nibbles in VMEM (two contractions against the activation
         # halves), the XLA path unpacks ahead of the matmul (the convert
         # fuses into the gemm there)
-        if (_support.kernels_enabled()
-                and qm.int4_supported(x2d.shape, wq.shape, wq.dtype)
-                and x2d.shape[0] % 8 == 0 and n % 128 == 0
-                and k % 256 == 0):
+        if (_support.kernels_enabled(x)
+                and qm.int4_supported(x2d.shape, wq.shape, wq.dtype)):
             out = qm.quant_matmul_int4(x2d, wq, scale, out_dtype=x.dtype)
         else:
             wf = unpack_int4(wq).astype(x.dtype) \
                 * scale[:, None].astype(x.dtype)
             out = x2d @ wf.T
         return out.reshape(lead + (n,))
-    use_pallas = (_support.kernels_enabled()
-                  and qm.supported(x2d.shape, wq.shape, wq.dtype)
-                  and x2d.shape[0] % 8 == 0 and n % 128 == 0
-                  and k % 128 == 0)
-    if use_pallas:
+    if (_support.kernels_enabled(x)
+            and qm.supported(x2d.shape, wq.shape, wq.dtype)):
         out = qm.quant_matmul(x2d, wq, scale, out_dtype=x.dtype)
     else:
         wf = wq.astype(x.dtype) * scale[:, None].astype(x.dtype)

@@ -1,8 +1,7 @@
 """Per-scenario bench baseline store + regression comparison.
 
-STDLIB-ONLY by contract: `bench.py`'s parent process must stay jax-free
-(the TPU probe owns the chip), and `tools/bench_diff.py` must run
-anywhere. Do not import jax, numpy, or the rest of the package here.
+STDLIB-ONLY by contract: `tools/bench_diff.py` must run anywhere. Do
+not import jax, numpy, or the rest of the package here.
 
 Layout: one JSON file per scenario under ``profiler_log/baselines/``:
 ``{"scenario", "platform", "value", "unit", "extras", "saved_wall_time"}``
@@ -178,8 +177,6 @@ class BaselineStore:
             return False, "report has no scenario tag"
         if not platform:
             return False, "report has no platform tag"
-        if report.get("extras", {}).get("stale"):
-            return False, "stale carry-forward result, not a fresh run"
         prev = self.load(scenario)
         if prev is not None:
             prev_platform = prev.get("platform")

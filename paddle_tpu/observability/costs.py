@@ -49,20 +49,12 @@ class CostCard:
     @classmethod
     def from_compiled(cls, compiled) -> "CostCard":
         """Build from a `jax` compiled executable (`lower().compile()`).
-        jax returns `cost_analysis()` as a dict (new) or a 1-list of
-        dicts (old); both carry "flops" and "bytes accessed". Missing
-        keys stay None — CPU/backend coverage varies."""
+        `cost_analysis()` is a dict carrying "flops" and "bytes
+        accessed"; keys a backend does not report stay None."""
         from ..framework import monitor
 
         monitor.inc("observability.cost_analyses")
-        ca = {}
-        try:
-            raw = compiled.cost_analysis()
-            if isinstance(raw, (list, tuple)):
-                raw = raw[0] if raw else {}
-            ca = dict(raw or {})
-        except Exception:
-            pass
+        ca = compiled.cost_analysis() or {}
         flops = ca.get("flops")
         card = cls(flops=float(flops) if flops else None,
                    bytes_accessed=(float(ca["bytes accessed"])

@@ -31,10 +31,31 @@ def interpret_mode() -> bool:
     return not on_tpu()
 
 
-def kernels_enabled() -> bool:
+def kernels_enabled(*operands) -> bool:
+    """The gate every kernel selection starts from. `operands`, where the
+    caller has them, are arrays the kernel would take: one that lives on a
+    multi-device mesh with automatic (GSPMD) axes closes the gate, because
+    JAX refuses to lower a Mosaic kernel there — "Mosaic kernels cannot be
+    automatically partitioned. Please wrap the call in a shard_map." — and
+    the caller's XLA composite partitions fine. Inside `shard_map` the axes
+    are Manual and the gate stays open (the TP serving step, the EP MoE
+    body). A step jitted with `in_shardings` over UNCOMMITTED arguments
+    shows no mesh while it traces; there the refusal stands, at lowering."""
+    if any(_auto_partitioned(x) for x in operands):
+        return False
     if on_tpu():
         return bool(flags.flag_value("use_pallas"))
     return bool(flags.flag_value("pallas_interpret"))
+
+
+def _auto_partitioned(x) -> bool:
+    import jax
+    from jax.sharding import AxisType
+
+    if not isinstance(x, jax.Array):     # numpy, a pending lazy-mode value
+        return False
+    mesh = jax.typeof(x).sharding.mesh
+    return mesh.size > 1 and AxisType.Auto in mesh.axis_types
 
 
 def x64_off():
@@ -48,11 +69,7 @@ def x64_off():
     """
     import jax
 
-    if hasattr(jax, "enable_x64"):         # older jax: top-level
-        return jax.enable_x64(False)
-    from jax.experimental import enable_x64
-
-    return enable_x64(False)
+    return jax.enable_x64(False)
 
 
 def pallas_call(*args, **kwargs):
@@ -69,6 +86,26 @@ def pallas_call(*args, **kwargs):
             return inner(*operands)
 
     return wrapped
+
+
+def float_dtype_ok(dtype) -> bool:
+    """The float dtypes every gate here admits. float16 is out: Mosaic
+    for the v5e refuses f16 vectors in each of these kernels ("Invalid
+    vector type for load ... vector<8x128x2xf16>"), so f16 callers take
+    the XLA composites."""
+    import numpy as np
+
+    return np.dtype(dtype).name in ("float32", "bfloat16")
+
+
+def row_block(rows: int, row_bytes: int) -> int:
+    """Row block for a kernel that holds whole rows of `row_bytes` in
+    VMEM: the largest power of two dividing `rows` that keeps one block
+    near 1 MiB. Each operand is double-buffered and the f32 temporaries
+    sit beside them in the 16 MiB scoped limit — a fixed 256-row block
+    overflows it for f32 rows of 4096."""
+    cap = max(8, (1 << 20) // row_bytes)
+    return pick_block(rows, 1 << (cap.bit_length() - 1)) or rows
 
 
 def pick_block(n: int, preferred: int = 128) -> int:

@@ -21,7 +21,10 @@ def _erf_approx(x):
     # exp, which Mosaic lowers natively.
     a1, a2, a3 = 0.254829592, -0.284496736, 1.421413741
     a4, a5, p = -1.453152027, 1.061405429, 0.3275911
-    s = jnp.sign(x)
+    # not jnp.sign: without a newer libtpu Pallas lowers it through a helper
+    # whose constants come out f64 under the package's x64 mode (the value
+    # at 0 is immaterial: the bracket below vanishes there)
+    s = jnp.where(x < 0, -1.0, 1.0)
     ax = jnp.abs(x)
     t = 1.0 / (1.0 + p * ax)
     poly = t * (a1 + t * (a2 + t * (a3 + t * (a4 + t * a5))))
@@ -54,32 +57,52 @@ def _ref_bias_act(x, bias, act_method):
     return _ACTS[act_method](xf).astype(x.dtype)
 
 
-def _kernel(x_ref, b_ref, y_ref, *, act_method):
-    x = x_ref[:].astype(jnp.float32) + b_ref[:].astype(jnp.float32)
-    if act_method in ("swiglu", "geglu"):
-        d2 = x.shape[-1] // 2
-        a, b = x[..., :d2], x[..., d2:]
-        inner = _ACTS["silu" if act_method == "swiglu" else "gelu"](a)
-        y_ref[:] = (inner * b).astype(y_ref.dtype)
+def _blocks(r, hdim):
+    """(row, hidden) block of an elementwise pass over [r, hdim]. The
+    hidden axis is tiled too: a row block times the whole axis does not
+    fit scoped VMEM at real widths ([256, 11008] blocks of a SwiGLU need
+    32 MiB against the 16 MiB limit). The lane block must be a multiple
+    of 128 or the whole axis, so small/odd widths stay untiled."""
+    bh = _support.pick_block(hdim, 1024)
+    return (_support.pick_block(r, 256) or r,
+            bh if bh and bh % 128 == 0 else hdim)
+
+
+def _kernel(*refs, act_method):
+    """refs: x blocks, then their bias rows, then y — one x/bias pair,
+    or the two matching half-blocks of a packed GLU input."""
+    *ins, y_ref = refs
+    n = len(ins) // 2
+    xs = [x[:].astype(jnp.float32) + b[:].astype(jnp.float32)
+          for x, b in zip(ins[:n], ins[n:])]
+    if n == 2:
+        inner = _ACTS["silu" if act_method == "swiglu" else "gelu"](xs[0])
+        y_ref[:] = (inner * xs[1]).astype(y_ref.dtype)
     else:
-        y_ref[:] = _ACTS[act_method](x).astype(y_ref.dtype)
+        y_ref[:] = _ACTS[act_method](xs[0]).astype(y_ref.dtype)
 
 
 def _pallas_bias_act(x2d, bias, act_method):
     r, hdim = x2d.shape
-    br = _support.pick_block(r, 256) or r
-    out_h = hdim // 2 if act_method in ("swiglu", "geglu") else hdim
+    glu = act_method in ("swiglu", "geglu")
+    out_h = hdim // 2 if glu else hdim
+    br, bh = _blocks(r, out_h)
+    # a GLU reads block j of each half of the packed axis: the same
+    # arrays twice, the second index map offset by the half's block count
+    offs = (0, out_h // bh) if glu else (0,)
     return _support.pallas_call(
         functools.partial(_kernel, act_method=act_method),
-        grid=(pl.cdiv(r, br),),
-        in_specs=[
-            pl.BlockSpec((br, hdim), lambda i: (i, 0)),
-            pl.BlockSpec((hdim,), lambda i: (0,)),
-        ],
-        out_specs=pl.BlockSpec((br, out_h), lambda i: (i, 0)),
+        grid=(pl.cdiv(r, br), out_h // bh),
+        in_specs=[pl.BlockSpec((br, bh), lambda i, j, o=o: (i, j + o))
+                  for o in offs]
+        # bias as a [1, H] row: a 1-D (bh,) block is tiled T(bh) by Mosaic
+        # against XLA's own 1-D layout and refused
+        + [pl.BlockSpec((1, bh), lambda i, j, o=o: (0, j + o))
+           for o in offs],
+        out_specs=pl.BlockSpec((br, bh), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((r, out_h), x2d.dtype),
         interpret=_support.interpret_mode(),
-    )(x2d, bias)
+    )(*([x2d] * len(offs) + [bias.reshape(1, hdim)] * len(offs)))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
@@ -118,15 +141,13 @@ def _kernel2(x_ref, y_ref, o_ref):
 
 def _pallas_swiglu2(x2d, y2d):
     r, hdim = x2d.shape
-    br = _support.pick_block(r, 256) or r
+    br, bh = _blocks(r, hdim)
+    spec = pl.BlockSpec((br, bh), lambda i, j: (i, j))
     return _support.pallas_call(
         _kernel2,
-        grid=(pl.cdiv(r, br),),
-        in_specs=[
-            pl.BlockSpec((br, hdim), lambda i: (i, 0)),
-            pl.BlockSpec((br, hdim), lambda i: (i, 0)),
-        ],
-        out_specs=pl.BlockSpec((br, hdim), lambda i: (i, 0)),
+        grid=(pl.cdiv(r, br), hdim // bh),
+        in_specs=[spec, spec],
+        out_specs=spec,
         out_shape=jax.ShapeDtypeStruct((r, hdim), x2d.dtype),
         interpret=_support.interpret_mode(),
     )(x2d, y2d)

@@ -33,7 +33,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from . import _support
-from ...framework import jax_compat as _jax_compat
 
 NEG_INF = -1e30
 
@@ -385,7 +384,7 @@ def supported(q_shape, k_shape, dtype) -> bool:
         return False
     if d > 256:
         return False
-    if str(np.dtype(dtype)) not in ("float32", "bfloat16", "float16"):
+    if not _support.float_dtype_ok(dtype):
         return False
     bq, bk = _blocks(sq, sk)
     return bq >= 8 and bk >= 8
@@ -393,7 +392,7 @@ def supported(q_shape, k_shape, dtype) -> bool:
 
 def maybe_flash(q, k, v, causal):
     """Tensor-level entry used by nn.functional: returns a Tensor or None."""
-    if not _support.kernels_enabled():
+    if not _support.kernels_enabled(q._data):
         return None
     if not supported(tuple(q.shape), tuple(k.shape), q._data.dtype):
         return None
@@ -521,7 +520,7 @@ def _fa_forward_streamed(q, k, v, causal, sm_scale, kv_lens=None):
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32),
                         pltpu.VMEM((bq, 128), jnp.float32),
                         pltpu.VMEM((bq, 128), jnp.float32)],
-        compiler_params=_jax_compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         cost_estimate=pl.CostEstimate(
@@ -692,7 +691,7 @@ def _flash_bwd_streamed(q, k, v, g, lse, delta, lens, use_lens, causal,
                                lambda b_, h_, i, j: (b_, h_, i, 0)),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
-        compiler_params=_jax_compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interp,
@@ -730,7 +729,7 @@ def _flash_bwd_streamed(q, k, v, g, lse, delta, lens, use_lens, causal,
         ],
         scratch_shapes=[pltpu.VMEM((bk, d), jnp.float32),
                         pltpu.VMEM((bk, d), jnp.float32)],
-        compiler_params=_jax_compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interp,

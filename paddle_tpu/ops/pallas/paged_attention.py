@@ -276,8 +276,7 @@ def paged_attention_verify(q, k_cache, v_cache, block_tables, context_lens,
 
 
 def _ragged_kernel(kv_lens_ref, tables_ref, lane_ref, pos_ref,
-                   q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
-                   sm_scale, block_size):
+                   q_ref, k_ref, v_ref, *rest, sm_scale, block_size):
     """Ragged paged attention: ONE fixed-shape kernel for mixed
     prefill-chunk + decode + verify batches.
 
@@ -293,7 +292,22 @@ def _ragged_kernel(kv_lens_ref, tables_ref, lane_ref, pos_ref,
     kv_len == 0) compute nothing and emit zeros via the l_safe finish.
     Same online-softmax structure as `_decode_kernel` — the decode and
     verify kernels are special cases of this one (q_len==1 / q_len==S).
-    """
+
+    Quantized KV (`inference/kv_quant.py` layout): `rest` then leads with
+    the block's per-slot f32 scale rows `ks_ref`/`vs_ref`, each (1, BS),
+    and K/V arrive as int8 — the bf16/f32 KV never exists in HBM, which
+    is the point: a decode step is KV-bandwidth-bound, so halving the
+    bytes read halves the step's HBM traffic. The per-slot scale is
+    constant along D, so it factors out of both contractions and is
+    applied on the (Gp, BS) score tile: `(q.k_int) * ks` before the
+    softmax and `p * vs` before `p @ v_int` — the same maths as
+    dequantizing the (BS, D) blocks, with the scale as a lane-aligned
+    row instead of a sublane column."""
+    if len(rest) == 6:
+        ks_ref, vs_ref, o_ref, acc_ref, m_ref, l_ref = rest
+    else:
+        ks_ref = vs_ref = None
+        o_ref, acc_ref, m_ref, l_ref = rest
     t = pl.program_id(0)
     j = pl.program_id(2)
     nb = pl.num_programs(2)
@@ -315,6 +329,8 @@ def _ragged_kernel(kv_lens_ref, tables_ref, lane_ref, pos_ref,
         v = v_ref[0, 0].astype(jnp.float32)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
+        if ks_ref is not None:
+            s = s * ks_ref[0, 0]                            # (1, BS) row
         pos = j * block_size + jax.lax.broadcasted_iota(
             jnp.int32, s.shape, 1)
         # typed scalar: see the NEG_INF note in _decode_kernel
@@ -325,6 +341,8 @@ def _ragged_kernel(kv_lens_ref, tables_ref, lane_ref, pos_ref,
         p = jnp.exp(s - m_new[:, None])
         alpha = jnp.exp(m_prev - m_new)
         l_new = l_prev * alpha + p.sum(axis=-1)
+        if vs_ref is not None:
+            p = p * vs_ref[0, 0]
         acc_ref[...] = acc_ref[...] * alpha[:, None] + jax.lax.dot_general(
             p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
         m_ref[...] = m_new[:, None]
@@ -338,31 +356,34 @@ def _ragged_kernel(kv_lens_ref, tables_ref, lane_ref, pos_ref,
 
 
 def _ragged_call(q, k_cache, v_cache, block_tables, kv_lens, tok_lane,
-                 tok_pos, sm_scale):
-    """q: [T, KV_H, Gp, D] packed tokens; caches: [KV_H, NB, BS, D]."""
+                 tok_pos, sm_scale, k_scale=None, v_scale=None):
+    """q: [T, KV_H, Gp, D] packed tokens; caches: [KV_H, NB, BS, D]
+    (int8 when the f32 scale planes [KV_H, NB, 1, BS] ride along)."""
     tokens, kv_h, g_pad, d = q.shape
     block_size = k_cache.shape[2]
     max_blocks = block_tables.shape[1]
 
-    kern = functools.partial(_ragged_kernel, sm_scale=sm_scale,
-                             block_size=block_size)
+    def page(*block):
+        return pl.BlockSpec(
+            (1, 1) + block,
+            lambda t, h, j, lens, tables, lane, pos:
+            (h, tables[lane[t], j], 0, 0))
+
+    def band():
+        return pl.BlockSpec((1, 1, g_pad, d),
+                            lambda t, h, j, lens, tables, lane, pos:
+                            (t, h, 0, 0))
+
+    operands = [q, k_cache, v_cache]
+    in_specs = [band(), page(block_size, d), page(block_size, d)]
+    if k_scale is not None:
+        operands += [k_scale, v_scale]
+        in_specs += [page(1, block_size), page(1, block_size)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
         grid=(tokens, kv_h, max_blocks),
-        in_specs=[
-            pl.BlockSpec((1, 1, g_pad, d),
-                         lambda t, h, j, lens, tables, lane, pos:
-                         (t, h, 0, 0)),
-            pl.BlockSpec((1, 1, block_size, d),
-                         lambda t, h, j, lens, tables, lane, pos:
-                         (h, tables[lane[t], j], 0, 0)),
-            pl.BlockSpec((1, 1, block_size, d),
-                         lambda t, h, j, lens, tables, lane, pos:
-                         (h, tables[lane[t], j], 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, g_pad, d),
-                               lambda t, h, j, lens, tables, lane, pos:
-                               (t, h, 0, 0)),
+        in_specs=in_specs,
+        out_specs=band(),
         scratch_shapes=[
             pltpu.VMEM((g_pad, d), jnp.float32),
             pltpu.VMEM((g_pad, 1), jnp.float32),
@@ -370,114 +391,12 @@ def _ragged_call(q, k_cache, v_cache, block_tables, kv_lens, tok_lane,
         ],
     )
     return _support.pallas_call(
-        kern,
+        functools.partial(_ragged_kernel, sm_scale=sm_scale,
+                          block_size=block_size),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((tokens, kv_h, g_pad, d), q.dtype),
         interpret=_support.interpret_mode(),
-    )(kv_lens, block_tables, tok_lane, tok_pos, q, k_cache, v_cache)
-
-
-def _ragged_kernel_q(kv_lens_ref, tables_ref, lane_ref, pos_ref,
-                     q_ref, k_ref, v_ref, ks_ref, vs_ref, o_ref,
-                     acc_ref, m_ref, l_ref, *, sm_scale, block_size):
-    """Quantized-KV ragged kernel: identical online-softmax structure to
-    `_ragged_kernel`, but K/V arrive as int8 blocks with their per-slot
-    f32 scale rows (`inference/kv_quant.py` layout) and dequantize in
-    VMEM right before the MXU — the bf16/f32 KV never exists in HBM,
-    which is the whole point: a decode step is KV-bandwidth-bound, so
-    halving the bytes read halves the step's HBM traffic."""
-    t = pl.program_id(0)
-    j = pl.program_id(2)
-    nb = pl.num_programs(2)
-
-    @pl.when(j == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    lane = lane_ref[t]
-    ctx_len = kv_lens_ref[lane]
-    qpos = pos_ref[t]
-
-    @pl.when((j * block_size < ctx_len) & (qpos >= 0))
-    def _compute():
-        q = q_ref[0, 0].astype(jnp.float32) * sm_scale      # (Gp, D)
-        # dequant in VMEM: int8 block * per-slot scale column
-        k = k_ref[0, 0].astype(jnp.float32) \
-            * ks_ref[0, 0][:, None]                         # (BS, D)
-        v = v_ref[0, 0].astype(jnp.float32) \
-            * vs_ref[0, 0][:, None]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-        pos = j * block_size + jax.lax.broadcasted_iota(
-            jnp.int32, s.shape, 1)
-        # typed scalar: see the NEG_INF note in _decode_kernel
-        s = jnp.where(pos <= qpos, s, jnp.float32(NEG_INF))
-        m_prev = m_ref[...][:, 0]
-        l_prev = l_ref[...][:, 0]
-        m_new = jnp.maximum(m_prev, s.max(axis=-1))
-        p = jnp.exp(s - m_new[:, None])
-        alpha = jnp.exp(m_prev - m_new)
-        l_new = l_prev * alpha + p.sum(axis=-1)
-        acc_ref[...] = acc_ref[...] * alpha[:, None] + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-        m_ref[...] = m_new[:, None]
-        l_ref[...] = l_new[:, None]
-
-    @pl.when(j == nb - 1)
-    def _finish():
-        l = l_ref[...][:, 0]
-        l_safe = jnp.where(l == 0.0, jnp.float32(1.0), l)
-        o_ref[0, 0] = (acc_ref[...] / l_safe[:, None]).astype(o_ref.dtype)
-
-
-def _ragged_call_q(q, k_cache, v_cache, k_scale, v_scale, block_tables,
-                   kv_lens, tok_lane, tok_pos, sm_scale):
-    """q: [T, KV_H, Gp, D]; caches int8 [KV_H, NB, BS, D]; scales f32
-    [KV_H, NB, BS] (head-major, matching the cache swap)."""
-    tokens, kv_h, g_pad, d = q.shape
-    block_size = k_cache.shape[2]
-    max_blocks = block_tables.shape[1]
-
-    kern = functools.partial(_ragged_kernel_q, sm_scale=sm_scale,
-                             block_size=block_size)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,
-        grid=(tokens, kv_h, max_blocks),
-        in_specs=[
-            pl.BlockSpec((1, 1, g_pad, d),
-                         lambda t, h, j, lens, tables, lane, pos:
-                         (t, h, 0, 0)),
-            pl.BlockSpec((1, 1, block_size, d),
-                         lambda t, h, j, lens, tables, lane, pos:
-                         (h, tables[lane[t], j], 0, 0)),
-            pl.BlockSpec((1, 1, block_size, d),
-                         lambda t, h, j, lens, tables, lane, pos:
-                         (h, tables[lane[t], j], 0, 0)),
-            pl.BlockSpec((1, 1, block_size),
-                         lambda t, h, j, lens, tables, lane, pos:
-                         (h, tables[lane[t], j], 0)),
-            pl.BlockSpec((1, 1, block_size),
-                         lambda t, h, j, lens, tables, lane, pos:
-                         (h, tables[lane[t], j], 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, g_pad, d),
-                               lambda t, h, j, lens, tables, lane, pos:
-                               (t, h, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((g_pad, d), jnp.float32),
-            pltpu.VMEM((g_pad, 1), jnp.float32),
-            pltpu.VMEM((g_pad, 1), jnp.float32),
-        ],
-    )
-    return _support.pallas_call(
-        kern,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((tokens, kv_h, g_pad, d), q.dtype),
-        interpret=_support.interpret_mode(),
-    )(kv_lens, block_tables, tok_lane, tok_pos, q, k_cache, v_cache,
-      k_scale, v_scale)
+    )(kv_lens, block_tables, tok_lane, tok_pos, *operands)
 
 
 def ragged_metadata(q_lens, kv_lens, num_tokens):
@@ -539,18 +458,17 @@ def paged_attention_ragged(q, k_cache, v_cache, block_tables, kv_lens,
     kc = jnp.swapaxes(k_cache, 0, 1)  # [KV_H, NB, BS, D]
     vc = jnp.swapaxes(v_cache, 0, 1)
     if k_scale is not None:
-        out = _ragged_call_q(
-            qg, kc, vc,
-            jnp.swapaxes(k_scale, 0, 1),   # [KV_H, NB, BS]
-            jnp.swapaxes(v_scale, 0, 1),
-            block_tables.astype(jnp.int32), kv_lens.astype(jnp.int32),
-            tok_lane.astype(jnp.int32), tok_pos.astype(jnp.int32),
-            float(sm_scale))
-    else:
-        out = _ragged_call(qg, kc, vc, block_tables.astype(jnp.int32),
-                           kv_lens.astype(jnp.int32),
-                           tok_lane.astype(jnp.int32),
-                           tok_pos.astype(jnp.int32), float(sm_scale))
+        # [NB, KV_H, BS] -> [KV_H, NB, 1, BS]: each page's scales become a
+        # (1, BS) row, the one block shape Mosaic accepts for them (a
+        # (1, BS) block of a [.., NB, BS] plane is neither (8, 128)-
+        # divisible nor the array's own last two dims)
+        k_scale = jnp.swapaxes(k_scale, 0, 1)[:, :, None, :]
+        v_scale = jnp.swapaxes(v_scale, 0, 1)[:, :, None, :]
+    out = _ragged_call(qg, kc, vc, block_tables.astype(jnp.int32),
+                       kv_lens.astype(jnp.int32),
+                       tok_lane.astype(jnp.int32),
+                       tok_pos.astype(jnp.int32), float(sm_scale),
+                       k_scale, v_scale)
     return out[:, :, :g, :].reshape(tokens, h, d)
 
 
@@ -753,7 +671,7 @@ def supported(q_shape, dtype) -> bool:
         return False
     if q_shape[-1] > 256:
         return False
-    return str(np.dtype(dtype)) in ("float32", "bfloat16", "float16")
+    return _support.float_dtype_ok(dtype)
 
 
 def verify_supported(q_shape, dtype) -> bool:
@@ -766,7 +684,7 @@ def verify_supported(q_shape, dtype) -> bool:
         return False
     if q_shape[1] > 64:          # S*Gp rows must stay a small VMEM tile
         return False
-    return str(np.dtype(dtype)) in ("float32", "bfloat16", "float16")
+    return _support.float_dtype_ok(dtype)
 
 
 def ragged_supported(q_shape, dtype) -> bool:
@@ -779,4 +697,4 @@ def ragged_supported(q_shape, dtype) -> bool:
         return False
     if q_shape[-1] > 256:
         return False
-    return str(np.dtype(dtype)) in ("float32", "bfloat16", "float16")
+    return _support.float_dtype_ok(dtype)

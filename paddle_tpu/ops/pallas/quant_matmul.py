@@ -23,7 +23,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from . import _support
-from ...framework import jax_compat as _jax_compat
 
 
 def _qmm_kernel(x_ref, w_ref, s_ref, o_ref, acc_ref, *, n_k):
@@ -42,8 +41,8 @@ def _qmm_kernel(x_ref, w_ref, s_ref, o_ref, acc_ref, *, n_k):
 
     @pl.when(k == n_k - 1)
     def _done():
-        scale = s_ref[...].astype(jnp.float32)  # [bn]
-        o_ref[...] = (acc_ref[...] * scale[None, :]).astype(o_ref.dtype)
+        scale = s_ref[...].astype(jnp.float32)  # [1, bn]
+        o_ref[...] = (acc_ref[...] * scale).astype(o_ref.dtype)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
@@ -72,6 +71,13 @@ def _quant_matmul_bwd_rule(out_dtype, res, g):
 quant_matmul.defvjp(_quant_matmul_fwd_rule, _quant_matmul_bwd_rule)
 
 
+def _scale_spec(bn):
+    """Per-out-channel scales ride as a [1, N] row in (1, bn) blocks: a
+    1-D (bn,) block makes Mosaic tile the operand T(bn) against XLA's
+    T(1024) layout for a 1-D f32 array, which the compiler refuses."""
+    return pl.BlockSpec((1, bn), lambda i, j, kk: (0, j))
+
+
 def _build_qmm(m, n, k, out_dtype, cfg):
     bm, bn, bk = cfg
     n_k = pl.cdiv(k, bk)
@@ -81,13 +87,13 @@ def _build_qmm(m, n, k, out_dtype, cfg):
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),
             pl.BlockSpec((bn, bk), lambda i, j, kk: (j, kk)),
-            pl.BlockSpec((bn,), lambda i, j, kk: (j,)),
+            _scale_spec(bn),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         # f32 accumulator carried across the K grid axis
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=_jax_compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=_support.interpret_mode(),
     )
@@ -100,6 +106,7 @@ def _quant_matmul_fwd_only(x2d, wq, scale, out_dtype=None):
     n, k2 = wq.shape
     assert k == k2, (x2d.shape, wq.shape)
     out_dtype = out_dtype or x2d.dtype
+    scale = scale.reshape(1, n)
 
     default = (_support.pick_block(m, 256) or m,
                _support.pick_block(n, 512) or n,
@@ -130,11 +137,12 @@ def _qmm4_kernel(xlo_ref, xhi_ref, wp_ref, s_ref, o_ref, acc_ref, *, n_k):
 
     xlo = xlo_ref[...]                           # [bm, bkp] bf16/f32
     xhi = xhi_ref[...]
-    wp = wp_ref[...]                             # [bn, bkp] int8 packed
-    lo = wp & 0x0F                               # int32 ops: nibble +
-    lo = jnp.where(lo >= 8, lo - 16, lo)         # sign extension
-    hi = (wp >> 4) & 0x0F
-    hi = jnp.where(hi >= 8, hi - 16, hi)
+    # widened first: Mosaic has no i8 vector compare/select ("Target does
+    # not support this comparison"), and in i32 the sign extension needs
+    # neither — an arithmetic shift pair does it
+    wp = wp_ref[...].astype(jnp.int32)           # [bn, bkp] packed bytes
+    lo = (wp << 28) >> 28
+    hi = wp >> 4
     acc_ref[...] += jax.lax.dot_general(
         xlo, lo.astype(xlo.dtype), (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32)
@@ -144,8 +152,8 @@ def _qmm4_kernel(xlo_ref, xhi_ref, wp_ref, s_ref, o_ref, acc_ref, *, n_k):
 
     @pl.when(k == n_k - 1)
     def _done():
-        scale = s_ref[...].astype(jnp.float32)   # [bn]
-        o_ref[...] = (acc_ref[...] * scale[None, :]).astype(o_ref.dtype)
+        scale = s_ref[...].astype(jnp.float32)   # [1, bn]
+        o_ref[...] = (acc_ref[...] * scale).astype(o_ref.dtype)
 
 
 def _build_qmm4(m, n, kp, out_dtype, cfg):
@@ -163,12 +171,12 @@ def _build_qmm4(m, n, kp, out_dtype, cfg):
             pl.BlockSpec((bm, bkp),
                          lambda i, j, kk, _n=n_k: (i, kk + _n)),
             pl.BlockSpec((bn, bkp), lambda i, j, kk: (j, kk)),
-            pl.BlockSpec((bn,), lambda i, j, kk: (j,)),
+            _scale_spec(bn),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=_jax_compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=_support.interpret_mode(),
     )
@@ -186,22 +194,33 @@ def quant_matmul_int4(x2d, wq_packed, scale, out_dtype=None):
            _support.pick_block(n, 512) or n,
            _support.pick_block(kp, 256) or kp)
     return _build_qmm4(m, n, kp, out_dtype, cfg)(x2d, x2d, wq_packed,
-                                                 scale)
+                                                 scale.reshape(1, n))
+
+
+def _tileable(m, n, k_block_axis) -> bool:
+    """Block legality shared by both gemms: `pick_block` must find a
+    sublane-aligned M block, a lane-aligned N block and a lane-aligned
+    block on the K axis the grid walks (else it returns a whole odd axis
+    that Mosaic cannot tile)."""
+    return m % 8 == 0 and n % 128 == 0 and k_block_axis % 128 == 0
 
 
 def supported(x_shape, w_shape, w_dtype) -> bool:
-    """Pallas path: int8/fp8 2-D weights, dims divisible into legal tiles."""
+    """Gate for `quant_matmul`: int8/fp8 [N, K] weights against 2-D
+    activations whose dims divide into legal tiles."""
     import numpy as np
 
-    if len(x_shape) < 1 or len(w_shape) != 2:
+    if len(x_shape) != 2 or len(w_shape) != 2:
         return False
     name = np.dtype(w_dtype).name if not isinstance(w_dtype, str) else w_dtype
-    return name in ("int8", "float8_e4m3fn", "float8_e5m2")
+    return name in ("int8", "float8_e4m3fn", "float8_e5m2") \
+        and _tileable(x_shape[0], w_shape[0], w_shape[1])
 
 
 def int4_supported(x_shape, wp_shape, wp_dtype) -> bool:
     """Gate for `quant_matmul_int4`: split-half packed int8 storage,
-    2-D, K = 2 * packed width."""
+    2-D, K = 2 * packed width, dims dividing into legal tiles (the K
+    grid walks the packed byte axis)."""
     import numpy as np
 
     if len(x_shape) != 2 or len(wp_shape) != 2:
@@ -210,4 +229,5 @@ def int4_supported(x_shape, wp_shape, wp_dtype) -> bool:
         return False
     name = np.dtype(wp_dtype).name if not isinstance(wp_dtype, str) \
         else wp_dtype
-    return name == "int8"
+    return name == "int8" and _tileable(x_shape[0], wp_shape[0],
+                                        wp_shape[1])

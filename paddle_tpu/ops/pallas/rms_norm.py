@@ -18,12 +18,14 @@ from . import _support
 def _rms_fwd_kernel(x_ref, w_ref, y_ref, *, eps):
     x = x_ref[:].astype(jnp.float32)
     inv = jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps)
-    y_ref[:] = (x * inv).astype(y_ref.dtype) * w_ref[:]
+    # cast again after the weight: an f32 weight on bf16 rows (autocast)
+    # would otherwise promote the product past the output ref's dtype
+    y_ref[:] = ((x * inv).astype(y_ref.dtype) * w_ref[:]).astype(y_ref.dtype)
 
 
 def _pallas_fwd(x2d, w, eps):
     r, hdim = x2d.shape
-    br = _support.pick_block(r, 256) or r
+    br = _support.row_block(r, hdim * x2d.dtype.itemsize)
     return _support.pallas_call(
         functools.partial(_rms_fwd_kernel, eps=eps),
         grid=(pl.cdiv(r, br),),
@@ -69,8 +71,6 @@ def rms_norm(x, w, epsilon=1e-6):
 
 
 def supported(shape, dtype) -> bool:
-    import numpy as np
-
     if len(shape) < 2:
         return False
-    return str(np.dtype(dtype)) in ("float32", "bfloat16", "float16")
+    return _support.float_dtype_ok(dtype)

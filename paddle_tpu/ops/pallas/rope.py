@@ -32,7 +32,7 @@ def _rope_kernel(q_ref, k_ref, c_ref, s_ref, oq_ref, ok_ref, *, conj):
 
 def _pallas_rope(q, k, cos, sin, conj):
     b, s, h, d = q.shape
-    bs = _support.pick_block(s) or s
+    bs = _support.row_block(s, h * d * q.dtype.itemsize)
     return _support.pallas_call(
         functools.partial(_rope_kernel, conj=conj),
         grid=(b, s // bs),
@@ -81,8 +81,6 @@ def fused_rope(q, k, cos, sin, offset=0):
 
 
 def supported(q_shape, dtype) -> bool:
-    import numpy as np
-
     if len(q_shape) != 4 or q_shape[-1] % 2:
         return False
-    return str(np.dtype(dtype)) in ("float32", "bfloat16", "float16")
+    return _support.float_dtype_ok(dtype)

@@ -277,7 +277,6 @@ class ShardedEngine:
     def __init__(self, base, pmesh: ProcessMesh, *, tp: int, dp: int,
                  kind: str, overlap: bool, overlap_tiles: int):
         import jax
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import NamedSharding
         from jax.sharding import PartitionSpec as P
 
@@ -423,16 +422,16 @@ class ShardedEngine:
                                      donate_argnums=(0,))
 
         donate = tuple(range(1, 1 + len(self._pools)))
-        self._ragged = jax.jit(shard_map(
+        self._ragged = jax.jit(jax.shard_map(
             ragged, mesh=jmesh,
             in_specs=(pspec,) + poolspec + (R, R, R, R),
             out_specs=(lspec,) + poolspec,
-            check_rep=False), donate_argnums=donate)
-        self._verify = jax.jit(shard_map(
+            check_vma=False), donate_argnums=donate)
+        self._verify = jax.jit(jax.shard_map(
             verify, mesh=jmesh,
             in_specs=(pspec,) + poolspec + (R, R, R),
             out_specs=(vspec,) + poolspec,
-            check_rep=False), donate_argnums=donate)
+            check_vma=False), donate_argnums=donate)
         self._step_label = f"serving.ragged_step_tp{tp}"
         # KV migration (inference/kv_migrate.py, ISSUE 17): the gather/
         # scatter index the LOGICAL block axis, which is unsharded in

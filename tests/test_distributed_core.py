@@ -31,6 +31,27 @@ def test_process_mesh_basics():
     assert mesh == dist.ProcessMesh(np.arange(8).reshape(2, 4), ["dp", "mp"])
 
 
+def test_mesh_naming_more_ranks_than_devices_is_an_error():
+    """A mesh never wraps its extra ranks round onto the first devices."""
+    import jax
+
+    mesh = dist.ProcessMesh(np.arange(2 * jax.device_count()), ["x"])
+    with pytest.raises(ValueError, match=r"JAX has \d+ device"):
+        mesh.to_jax_mesh()
+
+
+def test_place_naming_a_missing_device_is_an_error():
+    """A Place resolves to the device it names or raises — never to a CPU
+    device in its stead."""
+    from paddle_tpu.framework.place import Place
+
+    assert Place("cpu", 1).jax_device.id == 1
+    with pytest.raises(ValueError, match="device"):
+        Place("cpu", 99).jax_device
+    with pytest.raises(RuntimeError):      # no TPU backend on the test mesh
+        paddle.TPUPlace(0).jax_device
+
+
 def test_placements():
     assert dist.Shard(0) == dist.Shard(0)
     assert dist.Shard(0) != dist.Shard(1)

@@ -203,6 +203,26 @@ def test_static_gradients():
     np.testing.assert_allclose(np.asarray(g._data), [4.0, 6.0])
 
 
+def test_compile_cache_is_placed_from_outside_or_at_a_fixed_path(monkeypatch):
+    """`JAX_COMPILATION_CACHE_DIR` set: JAX reads it itself and no code sets
+    a directory. Unset: `<checkout>/.jax_cache`, the same path every run."""
+    import jax
+
+    from paddle_tpu.framework import compile_cache
+
+    updates = []
+    monkeypatch.setattr(jax.config, "update",
+                        lambda key, value: updates.append((key, value)))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/some/dir")
+    assert compile_cache.configure() == "/some/dir"
+    assert updates == []
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    fixed = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), ".jax_cache")
+    assert compile_cache.configure() == fixed
+    assert updates == [("jax_compilation_cache_dir", fixed)]
+
+
 def test_launch_cli_env_contract(tmp_path):
     script = tmp_path / "worker.py"
     script.write_text(
@@ -222,6 +242,25 @@ def test_launch_cli_env_contract(tmp_path):
     assert len(logs) == 2
     content = open(os.path.join(log_dir, logs[0])).read()
     assert "ok" in content
+
+
+def test_launch_refuses_two_workers_on_a_tpu_host(tmp_path, monkeypatch):
+    """Workers share one environment, so two on a TPU host would both claim
+    every chip: the launcher says so instead of letting the second hang.
+    A CPU job (JAX_PLATFORMS=cpu) on the same host is still launched."""
+    from paddle_tpu.distributed.launch import main as launch_main
+
+    script = tmp_path / "worker.py"
+    script.write_text("print('ok')\n")
+    argv = ["--nproc_per_node", "2", "--log_dir", str(tmp_path / "logs"),
+            str(script)]
+    monkeypatch.setattr(launch_main, "_on_tpu_host", lambda: True)
+    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    with pytest.raises(SystemExit, match="one process"):
+        launch_main.launch(argv)
+    assert not (tmp_path / "logs").exists()      # nothing was spawned
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+    assert launch_main.launch(argv) == 0
 
 
 def test_launch_cli_failure_detection(tmp_path):

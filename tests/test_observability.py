@@ -366,11 +366,6 @@ def test_baseline_platform_rules(tmp_path):
     ok, reason = store.update(_report("cpu", 5000.0))
     assert not ok and "refusing" in reason
     assert store.load("serving_throughput")["value"] == 900.0
-    # stale carry-forward results don't move baselines either
-    stale = _report("tpu", 950.0)
-    stale["extras"]["stale"] = True
-    ok, reason = store.update(stale)
-    assert not ok and "stale" in reason
 
 
 def test_compare_reports_directions(tmp_path):

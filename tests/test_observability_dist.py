@@ -184,10 +184,8 @@ def test_hlo_comm_census_real_psum():
     import jax.numpy as jnp
     from jax.sharding import Mesh, PartitionSpec as P
 
-    from paddle_tpu.framework.jax_compat import shard_map
-
     mesh = Mesh(np.array(jax.devices()[:8]), ("g",))
-    fn = shard_map(lambda x: jax.lax.psum(x, "g"), mesh=mesh,
+    fn = jax.shard_map(lambda x: jax.lax.psum(x, "g"), mesh=mesh,
                    in_specs=P("g"), out_specs=P())
     compiled = jax.jit(fn).lower(jnp.ones((8, 32), jnp.float32)).compile()
     census = comms.hlo_comm_census(compiled.as_text())

@@ -591,3 +591,69 @@ class TestAutotune:
             flags.set_flags({"FLAGS_pallas_interpret": False})
         ref = x @ (w.astype(jnp.float32).T * s[None, :])
         assert float(jnp.abs(out - ref).max()) < 1e-3
+
+
+# ---------------------------------------------------------------------------
+# TPU lowering at real widths (no chip, no libtpu, nothing executed)
+# ---------------------------------------------------------------------------
+
+def _kernel_cases():
+    import chip_smoke
+
+    return chip_smoke.kernel_cases(chip_smoke.FULL)
+
+
+@pytest.mark.parametrize("case", _kernel_cases(), ids=lambda case: case[0])
+def test_kernel_lowers_for_tpu_at_7b_width(case, monkeypatch):
+    """Every Pallas entry point `chip_smoke.py` runs on the chip, lowered
+    for `lowering_platforms=("tpu",)` at the same Llama-2-7B-width shape
+    from abstract arguments. Lowering alone runs Pallas' TPU block-shape
+    checks — the interpreter, which is all the other tests here see,
+    accepts block specs the TPU lowering refuses (the int8-KV scale plane's
+    `(1, 1, block_size)` block was one)."""
+    import jax
+
+    from paddle_tpu.ops.pallas import _support
+
+    monkeypatch.setattr(_support, "backend", lambda: "tpu")
+    name, make, kernel, _composite = case
+    if " fp8 " in name:
+        # with no backend to ask, Pallas lowers for the OLDEST libtpu, which
+        # has no fp8 -> bf16 extension; the installed one does, and the chip
+        # run compiles and checks these two
+        pytest.skip("fp8 widening needs the installed libtpu's lowering")
+    args = jax.eval_shape(make, jax.random.key(0, impl="rbg"))
+    text = jax.jit(kernel).trace(*args).lower(
+        lowering_platforms=("tpu",)).as_text()
+    assert "tpu_custom_call" in text, name
+
+
+def test_gate_closes_for_gspmd_partitioned_operands():
+    """JAX refuses to lower a Mosaic kernel inside a GSPMD-partitioned
+    program ("Mosaic kernels cannot be automatically partitioned"), so the
+    gate closes for an operand on a multi-device mesh with automatic axes —
+    eagerly and while tracing — and stays open inside `shard_map`, on one
+    device, and for host arrays."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from paddle_tpu.ops.pallas import _support
+
+    mesh = Mesh(np.asarray(jax.devices()[:4]).reshape(2, 2), ("dp", "mp"))
+    x = jax.device_put(jnp.ones((8, 16)), NamedSharding(mesh, P("dp", None)))
+    seen = {}
+
+    def probe(name):
+        def f(a):
+            seen[name] = _support.kernels_enabled(a)
+            return a
+        return f
+
+    assert not _support.kernels_enabled(x)
+    jax.jit(probe("jit"))(x)
+    jax.jit(jax.shard_map(probe("shard_map"), mesh=mesh, in_specs=P("dp"),
+                          out_specs=P("dp")))(x)
+    jax.jit(probe("one device"))(jnp.ones((8, 16)))
+    assert seen == {"jit": False, "shard_map": True, "one device": True}
+    assert _support.kernels_enabled(np.ones(3)) and _support.kernels_enabled()

@@ -95,20 +95,28 @@ class TestInt4:
         assert (np.abs(back - w) <= step / 2 + 1e-7).all()
 
     def test_dequant_matmul_int4_matches_unpacked(self):
-        """The int4 execution path == the explicitly dequantized matmul
-        (bitwise: both run the same XLA ops on CPU)."""
+        """The int4 execution path == the explicitly dequantized matmul,
+        to f32 summation-order error."""
         import jax.numpy as jnp
 
         rng = np.random.default_rng(1)
-        x = jnp.asarray(rng.normal(0, 1, (5, 16)), jnp.float32)
+        x = rng.normal(0, 1, (5, 16)).astype(np.float32)
         wq, scale = Q.weight_quantize(
             Tensor(rng.normal(0, 1, (16, 8)).astype(np.float32)),
             algo="weight_only_int4")
         wq, scale = wq._data, scale._data
-        out = np.asarray(Q.dequant_matmul(x, wq, scale, "int4"))
+        out = np.asarray(Q.dequant_matmul(jnp.asarray(x), wq, scale, "int4"))
         wf = np.asarray(Q.unpack_int4(wq)).astype(np.float32) \
             * np.asarray(scale)[:, None]
-        np.testing.assert_allclose(out, np.asarray(x) @ wf.T, rtol=1e-6)
+        # Both sides are f32 K-term dot products of the SAME dequantized
+        # operands, summed in different orders (XLA's gemm vs numpy's).
+        # Each lies within gamma_K * sum_i |x_i w_i| of the exact value
+        # (gamma_K ~= K * u, u = 2**-24 the f32 unit roundoff), so they
+        # differ by at most twice that — a bound relative to the summed
+        # magnitudes, not to the result, which cancellation makes small.
+        k = x.shape[1]
+        bound = 2 * k * 2.0 ** -24 * (np.abs(x) @ np.abs(wf).T)
+        assert (np.abs(out - x @ wf.T) <= bound).all()
 
     def test_quant_matmul_int4_kernel(self):
         """The Pallas packed-int4 gemm (interpreter mode on CPU) against

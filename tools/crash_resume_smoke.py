@@ -41,17 +41,14 @@ def child(args):
     import jax
 
     jax.config.update("jax_platforms", "cpu")
-    try:
-        # share compiled executables across the driver's three child
-        # processes — the budget is dominated by recompiling the same
-        # tiny train step three times
-        jax.config.update("jax_compilation_cache_dir",
-                          os.path.join(os.path.dirname(args.ckpt),
-                                       "jax_cache"))
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
-    except Exception:
-        pass  # older jax: just slower
+    from paddle_tpu.framework import compile_cache
+
+    # share compiled executables across the driver's three child
+    # processes (and across runs) — the budget is dominated by
+    # recompiling the same tiny train step three times
+    compile_cache.configure()
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
     import numpy as np
 
     import paddle_tpu as paddle

@@ -73,9 +73,9 @@ jax.config.update("jax_platforms", "cpu")
 # every scenario builds fresh engines (and the watchdog rebuilds them
 # mid-run): share one persistent compilation cache so identical-shape
 # traces compile once, keeping the whole smoke under its CI budget
-jax.config.update("jax_compilation_cache_dir",
-                  os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                               "..", "profiler_log", "jax_cache"))
+from paddle_tpu.framework import compile_cache  # noqa: E402
+
+compile_cache.configure()
 jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
 jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
 
