@@ -2477,15 +2477,16 @@ def build_train_step(model):
         loss, grads = jax.value_and_grad(loss_fn)(params)
         b1, b2, lr, eps, wd = 0.9, 0.95, 3e-4, 1e-8, 0.1
         new_p, new_m, new_v = {}, {}, {}
-        for k in params:
-            g = grads[k].astype(jnp.float32)
-            new_m[k] = b1 * m_state[k] + (1 - b1) * g
-            new_v[k] = b2 * v_state[k] + (1 - b2) * g * g
-            mhat = new_m[k] / (1 - b1 ** step)
-            vhat = new_v[k] / (1 - b2 ** step)
-            pf = params[k].astype(jnp.float32)
-            pf = pf - lr * (mhat / (jnp.sqrt(vhat) + eps) + wd * pf)
-            new_p[k] = pf.astype(params[k].dtype)
+        with jax.named_scope("adamw"):
+            for k in params:
+                g = grads[k].astype(jnp.float32)
+                new_m[k] = b1 * m_state[k] + (1 - b1) * g
+                new_v[k] = b2 * v_state[k] + (1 - b2) * g * g
+                mhat = new_m[k] / (1 - b1 ** step)
+                vhat = new_v[k] / (1 - b2 ** step)
+                pf = params[k].astype(jnp.float32)
+                pf = pf - lr * (mhat / (jnp.sqrt(vhat) + eps) + wd * pf)
+                new_p[k] = pf.astype(params[k].dtype)
         return loss, new_p, new_m, new_v
 
     return train_step, params, m_state, v_state

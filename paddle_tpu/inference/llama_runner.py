@@ -216,17 +216,19 @@ class LlamaInferenceEngine:
         self.manager.set_kv_geometry(
             kv_quant.kv_bytes_per_block(**self._kv_geom), self.kv_bits)
 
-        self._prefill = jax.jit(functools.partial(
-            _prefill_fn, cfg=_StaticCfg(cfg)), donate_argnums=(1, 2))
-        self._decode = jax.jit(functools.partial(
-            _decode_fn, cfg=_StaticCfg(cfg)), donate_argnums=(1, 2))
+        def step(fn, donate):
+            # a bare partial has no name and the XLA module would be
+            # `jit__unknown`; with the function's it is `jit__ragged_fn`,
+            # which is how a profile's "XLA Modules" line tells the steps
+            bound = functools.partial(fn, cfg=_StaticCfg(cfg))
+            bound.__name__ = fn.__name__
+            return jax.jit(bound, donate_argnums=donate)
+
+        self._prefill = step(_prefill_fn, (1, 2))
+        self._decode = step(_decode_fn, (1, 2))
         if self.kv_bits == 8:
-            self._verify = jax.jit(functools.partial(
-                _verify_q_fn, cfg=_StaticCfg(cfg)),
-                donate_argnums=(1, 2, 3, 4))
-            self._ragged = jax.jit(functools.partial(
-                _ragged_q_fn, cfg=_StaticCfg(cfg)),
-                donate_argnums=(1, 2, 3, 4))
+            self._verify = step(_verify_q_fn, (1, 2, 3, 4))
+            self._ragged = step(_ragged_q_fn, (1, 2, 3, 4))
             # COW copy moves the int8 block AND its scale rows in ONE
             # donated executable — q + scale can never tear apart
             self._copy_block_q = jax.jit(
@@ -235,10 +237,8 @@ class LlamaInferenceEngine:
                     ks.at[:, d].set(ks[:, s]), vs.at[:, d].set(vs[:, s])),
                 donate_argnums=(0, 1, 2, 3))
         else:
-            self._verify = jax.jit(functools.partial(
-                _verify_fn, cfg=_StaticCfg(cfg)), donate_argnums=(1, 2))
-            self._ragged = jax.jit(functools.partial(
-                _ragged_fn, cfg=_StaticCfg(cfg)), donate_argnums=(1, 2))
+            self._verify = step(_verify_fn, (1, 2))
+            self._ragged = step(_ragged_fn, (1, 2))
         # COW device copy (prefix caching, `BlockCacheManager` hook):
         # copies one physical block's K and V across every layer in one
         # donated executable; src/dst trace as int32 scalars, so COWs
@@ -604,38 +604,46 @@ def _layer_body(x, layer_in, *, cfg, positions, tables, ctx_lens, mode,
     b, s, hdim = x.shape
     nh, kvh, d = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
 
-    h1 = _rms(x, ln1, cfg.eps)
-    qkv = _mm(h1, qkv_w)
-    q = qkv[..., :nh * d].reshape(b, s, nh, d)
-    k = qkv[..., nh * d:(nh + kvh) * d].reshape(b, s, kvh, d)
-    v = qkv[..., (nh + kvh) * d:].reshape(b, s, kvh, d)
-    # rope at absolute positions (positions: [B, S])
-    c = jnp.take(cos, positions, axis=0)[:, :, None, :]   # [B, S, 1, D/2]
-    si = jnp.take(sin, positions, axis=0)[:, :, None, :]
-    q = _rope_half(q, c, si)
-    k = _rope_half(k, c, si)
+    # one scope per region, the names `models/llama.py` gives the same
+    # regions of the training step (docs/OBSERVABILITY.md)
+    scope = jax.named_scope
+    with scope("llama.rms_norm"):
+        h1 = _rms(x, ln1, cfg.eps)
+    with scope("llama.qkv"):
+        qkv = _mm(h1, qkv_w)
+        q = qkv[..., :nh * d].reshape(b, s, nh, d)
+        k = qkv[..., nh * d:(nh + kvh) * d].reshape(b, s, kvh, d)
+        v = qkv[..., (nh + kvh) * d:].reshape(b, s, kvh, d)
+    with scope("llama.rope"):
+        # rope at absolute positions (positions: [B, S])
+        c = jnp.take(cos, positions, axis=0)[:, :, None, :]  # [B,S,1,D/2]
+        si = jnp.take(sin, positions, axis=0)[:, :, None, :]
+        q = _rope_half(q, c, si)
+        k = _rope_half(k, c, si)
 
     if mode == "ragged":
         tok_lane, tok_pos = ragged_meta
         ks = vs = None
-        if kv_scales is not None:
-            ks, vs = kv_scales
-            kc, vc, ks, vs = pk.write_kv_to_cache_ragged(
-                k[0], v[0], kc, vc, tables, tok_lane, tok_pos,
-                k_scale=ks, v_scale=vs)
-        else:
-            kc, vc = pk.write_kv_to_cache_ragged(
-                k[0], v[0], kc, vc, tables, tok_lane, tok_pos)
-        qr = q[0]                                     # [T, NH, D]
-        if pk.ragged_supported((s, nh, d), qr.dtype):
-            attn = pk.paged_attention_ragged(
-                qr, kc, vc, tables, ctx_lens, tok_lane, tok_pos,
-                k_scale=ks, v_scale=vs)
-        else:
-            attn = pk.paged_attention_ragged_ref(
-                qr, kc, vc, tables, ctx_lens, tok_lane, tok_pos,
-                k_scale=ks, v_scale=vs)
-        attn = attn.reshape(1, s, nh * d).astype(x.dtype)
+        with scope("llama.kv_write"):
+            if kv_scales is not None:
+                ks, vs = kv_scales
+                kc, vc, ks, vs = pk.write_kv_to_cache_ragged(
+                    k[0], v[0], kc, vc, tables, tok_lane, tok_pos,
+                    k_scale=ks, v_scale=vs)
+            else:
+                kc, vc = pk.write_kv_to_cache_ragged(
+                    k[0], v[0], kc, vc, tables, tok_lane, tok_pos)
+        with scope("llama.attn"):
+            qr = q[0]                                     # [T, NH, D]
+            if pk.ragged_supported((s, nh, d), qr.dtype):
+                attn = pk.paged_attention_ragged(
+                    qr, kc, vc, tables, ctx_lens, tok_lane, tok_pos,
+                    k_scale=ks, v_scale=vs)
+            else:
+                attn = pk.paged_attention_ragged_ref(
+                    qr, kc, vc, tables, ctx_lens, tok_lane, tok_pos,
+                    k_scale=ks, v_scale=vs)
+            attn = attn.reshape(1, s, nh * d).astype(x.dtype)
         tp = getattr(cfg, "tp", None)
         if tp is not None:
             # TP-sharded ragged step (serving/tp.py): o_w/down_w are
@@ -644,55 +652,66 @@ def _layer_body(x, layer_in, *, cfg, positions, tables, ctx_lens, mode,
             # overlaps tile k+1's compute (distributed/tp_overlap.py)
             from ..distributed.tp_overlap import row_parallel_matmul
 
-            x = x + row_parallel_matmul(attn, o_w, axis_name=tp.axis,
-                                        ntiles=tp.tiles, mm=_mm)
+            with scope("llama.o_proj"):
+                x = x + row_parallel_matmul(attn, o_w, axis_name=tp.axis,
+                                            ntiles=tp.tiles, mm=_mm)
         else:
-            x = x + _mm(attn, o_w)
-        h2 = _rms(x, ln2, cfg.eps)
-        gu = _mm(h2, gu_w)
-        g, u = jnp.split(gu, 2, axis=-1)
-        act = jax.nn.silu(g.astype(jnp.float32)).astype(g.dtype) * u
-        if tp is not None:
-            x = x + row_parallel_matmul(act, down_w, axis_name=tp.axis,
-                                        ntiles=tp.tiles, mm=_mm)
-        else:
-            x = x + _mm(act, down_w)
+            with scope("llama.o_proj"):
+                x = x + _mm(attn, o_w)
+        with scope("llama.rms_norm"):
+            h2 = _rms(x, ln2, cfg.eps)
+        with scope("llama.mlp"):
+            gu = _mm(h2, gu_w)
+            g, u = jnp.split(gu, 2, axis=-1)
+            act = jax.nn.silu(g.astype(jnp.float32)).astype(g.dtype) * u
+            if tp is not None:
+                x = x + row_parallel_matmul(act, down_w, axis_name=tp.axis,
+                                            ntiles=tp.tiles, mm=_mm)
+            else:
+                x = x + _mm(act, down_w)
         if kv_scales is not None:
             return x, (kc, vc, ks, vs)
         return x, (kc, vc)
 
-    start = positions[:, 0].astype(jnp.int32)
-    kc, vc = pk.write_kv_to_cache(k, v, kc, vc, tables, start)
+    with scope("llama.kv_write"):
+        start = positions[:, 0].astype(jnp.int32)
+        kc, vc = pk.write_kv_to_cache(k, v, kc, vc, tables, start)
 
-    if mode == "decode":
-        qd = q.reshape(b, nh, d)
-        if pk.supported((b, nh, d), qd.dtype):
-            attn = pk.paged_attention(qd, kc, vc, tables, ctx_lens)
+    with scope("llama.attn"):
+        if mode == "decode":
+            qd = q.reshape(b, nh, d)
+            if pk.supported((b, nh, d), qd.dtype):
+                attn = pk.paged_attention(qd, kc, vc, tables, ctx_lens)
+            else:
+                attn = pk.paged_attention_ref(qd, kc, vc, tables, ctx_lens)
+            attn = attn.reshape(b, s, nh * d)
+        elif mode == "verify":
+            if pk.verify_supported((b, s, nh, d), q.dtype):
+                attn = pk.paged_attention_verify(q, kc, vc, tables,
+                                                 ctx_lens)
+            else:
+                attn = pk.paged_attention_verify_ref(q, kc, vc, tables,
+                                                     ctx_lens)
+            attn = attn.reshape(b, s, nh * d)
         else:
-            attn = pk.paged_attention_ref(qd, kc, vc, tables, ctx_lens)
-        attn = attn.reshape(b, s, nh * d)
-    elif mode == "verify":
-        if pk.verify_supported((b, s, nh, d), q.dtype):
-            attn = pk.paged_attention_verify(q, kc, vc, tables, ctx_lens)
-        else:
-            attn = pk.paged_attention_verify_ref(q, kc, vc, tables, ctx_lens)
-        attn = attn.reshape(b, s, nh * d)
-    else:
-        kk, vv = k, v
-        if kvh != nh:
-            kk = jnp.repeat(kk, nh // kvh, axis=2)
-            vv = jnp.repeat(vv, nh // kvh, axis=2)
-        from ..nn.functional.attention import _sdpa_fn
+            kk, vv = k, v
+            if kvh != nh:
+                kk = jnp.repeat(kk, nh // kvh, axis=2)
+                vv = jnp.repeat(vv, nh // kvh, axis=2)
+            from ..nn.functional.attention import _sdpa_fn
 
-        attn = _sdpa_fn(q, kk, vv, None, True, None, False)
-        attn = attn.reshape(b, s, nh * d)
-    x = x + _mm(attn, o_w)
+            attn = _sdpa_fn(q, kk, vv, None, True, None, False)
+            attn = attn.reshape(b, s, nh * d)
+    with scope("llama.o_proj"):
+        x = x + _mm(attn, o_w)
 
-    h2 = _rms(x, ln2, cfg.eps)
-    gu = _mm(h2, gu_w)
-    g, u = jnp.split(gu, 2, axis=-1)
-    act = jax.nn.silu(g.astype(jnp.float32)).astype(g.dtype) * u
-    x = x + _mm(act, down_w)
+    with scope("llama.rms_norm"):
+        h2 = _rms(x, ln2, cfg.eps)
+    with scope("llama.mlp"):
+        gu = _mm(h2, gu_w)
+        g, u = jnp.split(gu, 2, axis=-1)
+        act = jax.nn.silu(g.astype(jnp.float32)).astype(g.dtype) * u
+        x = x + _mm(act, down_w)
     return x, (kc, vc)
 
 
@@ -704,6 +723,7 @@ def _run_stack(params, k_cache, v_cache, x, positions, tables, ctx_lens,
     cos, sin = params["rope_cos"], params["rope_sin"]
     quant_kv = k_scale is not None
 
+    @jax.named_scope("llama.layer")
     def body(x, layer_xs):
         if quant_kv:
             ln1, qkv_w, o_w, ln2, gu_w, down_w, kc, vc, ks, vs = layer_xs
@@ -728,31 +748,35 @@ def _run_stack(params, k_cache, v_cache, x, positions, tables, ctx_lens,
     else:
         x, (new_k, new_v) = jax.lax.scan(body, x, xs)
         new_ks = new_vs = None
-    x = _rms(x, params["final_norm"], cfg.eps)
+    with jax.named_scope("llama.rms_norm"):
+        x = _rms(x, params["final_norm"], cfg.eps)
     head = params.get("lm_head")
-    if head is None:
-        logits = jnp.einsum("bsh,vh->bsv", x,
-                            params["embed"].astype(x.dtype))
-    elif isinstance(head, dict):
-        # weight-only-quantized head (serving/quant.py): the vocab gemm
-        # is the largest single matmul of a decode step
-        logits = _mm(x, head)
-    else:
-        logits = jnp.einsum("bsh,hv->bsv", x, head.astype(x.dtype))
-    tp = getattr(cfg, "tp", None)
-    if tp is not None and tp.gather_logits and head is not None:
-        # column-parallel head (tied heads stay replicated): each shard
-        # holds a contiguous vocab slice; gathering in-program keeps the
-        # fused sampler device-side on replicated [..., V] logits
-        from ..distributed.tp_overlap import gather_columns
+    with jax.named_scope("llama.head"):
+        if head is None:
+            logits = jnp.einsum("bsh,vh->bsv", x,
+                                params["embed"].astype(x.dtype))
+        elif isinstance(head, dict):
+            # weight-only-quantized head (serving/quant.py): the vocab
+            # gemm is the largest single matmul of a decode step
+            logits = _mm(x, head)
+        else:
+            logits = jnp.einsum("bsh,hv->bsv", x, head.astype(x.dtype))
+        tp = getattr(cfg, "tp", None)
+        if tp is not None and tp.gather_logits and head is not None:
+            # column-parallel head (tied heads stay replicated): each
+            # shard holds a contiguous vocab slice; gathering in-program
+            # keeps the fused sampler device-side on replicated [..., V]
+            # logits
+            from ..distributed.tp_overlap import gather_columns
 
-        logits = gather_columns(logits, tp.axis)
+            logits = gather_columns(logits, tp.axis)
     if quant_kv:
         return logits, new_k, new_v, new_ks, new_vs
     return logits, new_k, new_v
 
 
 def _prefill_fn(params, k_cache, v_cache, input_ids, tables, lens, *, cfg):
+    import jax
     import jax.numpy as jnp
 
     from ..framework import monitor
@@ -761,7 +785,8 @@ def _prefill_fn(params, k_cache, v_cache, input_ids, tables, lens, *, cfg):
     # the serving tests assert this stays flat after warmup.
     monitor.inc("serving.prefill_retraces")
     b, s = input_ids.shape
-    x = jnp.take(params["embed"], input_ids, axis=0)
+    with jax.named_scope("llama.embed"):
+        x = jnp.take(params["embed"], input_ids, axis=0)
     positions = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32), (b, s))
     ctx = jnp.full((b,), s, jnp.int32)
     logits, nk, nv = _run_stack(params, k_cache, v_cache, x, positions,
@@ -773,13 +798,15 @@ def _prefill_fn(params, k_cache, v_cache, input_ids, tables, lens, *, cfg):
 
 
 def _decode_fn(params, k_cache, v_cache, tokens, ctx_lens, tables, *, cfg):
+    import jax
     import jax.numpy as jnp
 
     from ..framework import monitor
 
     monitor.inc("serving.decode_retraces")  # trace-time only (see prefill)
     b = tokens.shape[0]
-    x = jnp.take(params["embed"], tokens[:, None], axis=0)
+    with jax.named_scope("llama.embed"):
+        x = jnp.take(params["embed"], tokens[:, None], axis=0)
     positions = (ctx_lens - 1)[:, None].astype(jnp.int32)   # [B, 1]
     logits, nk, nv = _run_stack(params, k_cache, v_cache, x, positions,
                                 tables, ctx_lens.astype(jnp.int32), cfg,
@@ -793,13 +820,15 @@ def _ragged_stack(params, k_cache, v_cache, tokens, q_lens, kv_lens,
     [T] + per-lane (q_len, kv_len) metadata through the decoder stack in
     ragged mode. Returns (logits [T, V], new_k, new_v[, new_ks, new_vs
     when the KV pool is int8-quantized])."""
+    import jax
     import jax.numpy as jnp
 
     from ..ops.pallas import paged_attention as pk
 
     t = tokens.shape[0]
     tok_lane, tok_pos = pk.ragged_metadata(q_lens, kv_lens, t)
-    x = jnp.take(params["embed"], tokens[None, :], axis=0)   # [1, T, H]
+    with jax.named_scope("llama.embed"):
+        x = jnp.take(params["embed"], tokens[None, :], axis=0)  # [1, T, H]
     positions = jnp.maximum(tok_pos, 0)[None, :]             # [1, T]
     out = _run_stack(
         params, k_cache, v_cache, x, positions, tables,

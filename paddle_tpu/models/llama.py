@@ -79,6 +79,16 @@ def _apply_rope_fn(q, k, cos, sin, offset):
 dispatch.register_op("fused_rope", _apply_rope_fn, multi_out=True)
 
 
+def _scope(name: str):
+    """A program region's `jax.named_scope`. Trace-time only: the name
+    lands in the scope path of every op lowered under it, which is how a
+    device trace is read by region. The names are a contract, the same in
+    serving (`inference/llama_runner.py`): docs/OBSERVABILITY.md."""
+    import jax
+
+    return jax.named_scope(name)
+
+
 def fused_rotary_position_embedding(q, k, cos, sin, offset=0):
     """Analog of `incubate.nn.functional.fused_rotary_position_embedding`
     (reference kernel `phi/kernels/fusion/gpu/fused_rope_kernel.cu`)."""
@@ -110,12 +120,17 @@ class LlamaAttention(nn.Layer):
         from ..ops import manipulation as M
 
         b, s = x.shape[0], x.shape[1]
-        q = M.reshape(self.q_proj(x), [b, s, self.num_heads, self.head_dim])
-        k = M.reshape(self.k_proj(x), [b, s, self.num_kv_heads, self.head_dim])
-        v = M.reshape(self.v_proj(x), [b, s, self.num_kv_heads, self.head_dim])
-        q, k = fused_rotary_position_embedding(q, k, self.rope_cos,
-                                               self.rope_sin,
-                                               offset=position_offset)
+        with _scope("llama.qkv"):
+            q = M.reshape(self.q_proj(x),
+                          [b, s, self.num_heads, self.head_dim])
+            k = M.reshape(self.k_proj(x),
+                          [b, s, self.num_kv_heads, self.head_dim])
+            v = M.reshape(self.v_proj(x),
+                          [b, s, self.num_kv_heads, self.head_dim])
+        with _scope("llama.rope"):
+            q, k = fused_rotary_position_embedding(q, k, self.rope_cos,
+                                                   self.rope_sin,
+                                                   offset=position_offset)
         new_cache = None
         if kv_cache is not None:
             pk, pv = kv_cache
@@ -126,9 +141,11 @@ class LlamaAttention(nn.Layer):
         # GQA K/V stay un-repeated: the Pallas flash path groups natively;
         # the sdpa fallback expands inside _sdpa_fn.
         causal = kv_cache is None or q.shape[1] > 1
-        out = F.scaled_dot_product_attention(q, k, v, is_causal=causal)
-        out = M.reshape(out, [b, s, self.num_heads * self.head_dim])
-        out = self.o_proj(out)
+        with _scope("llama.attn"):
+            out = F.scaled_dot_product_attention(q, k, v, is_causal=causal)
+            out = M.reshape(out, [b, s, self.num_heads * self.head_dim])
+        with _scope("llama.o_proj"):
+            out = self.o_proj(out)
         if kv_cache is not None:
             return out, new_cache
         return out
@@ -162,13 +179,18 @@ class LlamaDecoderLayer(nn.Layer):
 
     def forward(self, x, position_offset=0, kv_cache=None):
         residual = x
-        h = self.input_layernorm(x)
+        with _scope("llama.rms_norm"):
+            h = self.input_layernorm(x)
         if kv_cache is not None:
             attn, new_cache = self.self_attn(h, position_offset, kv_cache)
         else:
             attn = self.self_attn(h, position_offset)
-        x = residual + attn
-        x = x + self.mlp(self.post_attention_layernorm(x))
+        with _scope("llama.o_proj"):
+            x = residual + attn
+        with _scope("llama.rms_norm"):
+            h = self.post_attention_layernorm(x)
+        with _scope("llama.mlp"):
+            x = x + self.mlp(h)
         if kv_cache is not None:
             return x, new_cache
         return x
@@ -189,25 +211,28 @@ class LlamaModel(nn.Layer):
         self.remat = False
 
     def forward(self, input_ids, position_offset=0, kv_caches=None):
-        x = self.embed_tokens(input_ids)
+        with _scope("llama.embed"):
+            x = self.embed_tokens(input_ids)
         new_caches = []
         use_remat = (self.remat and kv_caches is None
                      and dispatch._is_tracer(x._data))
         for i, layer in enumerate(self.layers):
-            if kv_caches is not None:
-                x, c = layer(x, position_offset, kv_caches[i])
-                new_caches.append(c)
-            elif use_remat:
-                import jax
+            with _scope("llama.layer"):
+                if kv_caches is not None:
+                    x, c = layer(x, position_offset, kv_caches[i])
+                    new_caches.append(c)
+                elif use_remat:
+                    import jax
 
-                def _call(xa, _layer=layer):
-                    return _layer(Tensor(xa), position_offset)._data
+                    def _call(xa, _layer=layer):
+                        return _layer(Tensor(xa), position_offset)._data
 
-                x = Tensor(jax.checkpoint(_call)(x._data),
-                           stop_gradient=x.stop_gradient)
-            else:
-                x = layer(x, position_offset)
-        x = self.norm(x)
+                    x = Tensor(jax.checkpoint(_call)(x._data),
+                               stop_gradient=x.stop_gradient)
+                else:
+                    x = layer(x, position_offset)
+        with _scope("llama.rms_norm"):
+            x = self.norm(x)
         if kv_caches is not None:
             return x, new_caches
         return x
@@ -230,19 +255,22 @@ class LlamaForCausalLM(nn.Layer):
             hidden, caches = self.llama(input_ids, position_offset, kv_caches)
         else:
             hidden = self.llama(input_ids, position_offset)
-        if self.lm_head is None:
-            from ..ops import linalg
+        with _scope("llama.head"):
+            if self.lm_head is None:
+                from ..ops import linalg
 
-            logits = linalg.matmul(hidden, self.llama.embed_tokens.weight,
-                                   transpose_y=True)
-        else:
-            logits = self.lm_head(hidden)
+                logits = linalg.matmul(hidden,
+                                       self.llama.embed_tokens.weight,
+                                       transpose_y=True)
+            else:
+                logits = self.lm_head(hidden)
         if labels is not None:
             from ..ops import manipulation as M
 
-            loss = F.cross_entropy(
-                M.reshape(logits, [-1, self.config.vocab_size]),
-                M.reshape(labels, [-1]))
+            with _scope("llama.loss"):
+                loss = F.cross_entropy(
+                    M.reshape(logits, [-1, self.config.vocab_size]),
+                    M.reshape(labels, [-1]))
             return loss, logits
         if kv_caches is not None:
             return logits, caches

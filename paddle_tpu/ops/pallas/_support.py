@@ -72,14 +72,17 @@ def x64_off():
     return jax.enable_x64(False)
 
 
-def pallas_call(*args, **kwargs):
+def pallas_call(*args, name: str, **kwargs):
     """`pl.pallas_call` whose returned callable traces with x64 disabled.
 
     All kernels in this package must go through this wrapper (see x64_off).
+    `name` is required: Pallas puts it on the custom call (`kernel_name`)
+    and, as a `jax.named_scope`, into the op's scope path, which is how a
+    device trace tells one kernel from another (docs/OBSERVABILITY.md).
     """
     from jax.experimental import pallas as pl
 
-    inner = pl.pallas_call(*args, **kwargs)
+    inner = pl.pallas_call(*args, name=name, **kwargs)
 
     def wrapped(*operands):
         with x64_off():

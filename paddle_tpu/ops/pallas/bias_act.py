@@ -101,6 +101,7 @@ def _pallas_bias_act(x2d, bias, act_method):
            for o in offs],
         out_specs=pl.BlockSpec((br, bh), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((r, out_h), x2d.dtype),
+        name="bias_act_fused",
         interpret=_support.interpret_mode(),
     )(*([x2d] * len(offs) + [bias.reshape(1, hdim)] * len(offs)))
 
@@ -149,6 +150,7 @@ def _pallas_swiglu2(x2d, y2d):
         in_specs=[spec, spec],
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct((r, hdim), x2d.dtype),
+        name="bias_act_swiglu2",
         interpret=_support.interpret_mode(),
     )(x2d, y2d)
 

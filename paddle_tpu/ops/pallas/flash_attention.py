@@ -253,6 +253,7 @@ def _fa_forward(q, k, v, causal, sm_scale, kv_lens=None):
             flops=4 * b * h * sq * sk * d,
             bytes_accessed=(q.size + k.size + v.size) * q.dtype.itemsize,
             transcendentals=b * h * sq * sk),
+        name="flash_fwd",
         interpret=interp,
     )(*args)
     return out, lse
@@ -305,6 +306,7 @@ def _flash_bwd_rule(causal, sm_scale, res, g):
         in_specs=dq_specs,
         out_specs=pl.BlockSpec((1, 1, bq, d), lambda b_, h_, i: (b_, h_, i, 0)),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        name="flash_dq",
         interpret=interp,
     )(*dq_args)
 
@@ -336,6 +338,7 @@ def _flash_bwd_rule(causal, sm_scale, res, g):
             jax.ShapeDtypeStruct((b, h, sk, d), k.dtype),
             jax.ShapeDtypeStruct((b, h, sk, d), v.dtype),
         ],
+        name="flash_dkv",
         interpret=interp,
     )(*dkv_args)
     if group > 1:
@@ -528,6 +531,7 @@ def _fa_forward_streamed(q, k, v, causal, sm_scale, kv_lens=None):
             bytes_accessed=(q.size * n_k + k.size + v.size)
             * q.dtype.itemsize,
             transcendentals=b * h * sq * sk),
+        name="flash_fwd_streamed",
         interpret=interp,
     )(*args)
     return out, lse
@@ -694,6 +698,7 @@ def _flash_bwd_streamed(q, k, v, g, lse, delta, lens, use_lens, causal,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
+        name="flash_dq_streamed",
         interpret=interp,
     )(*dq_args)
 
@@ -732,6 +737,7 @@ def _flash_bwd_streamed(q, k, v, g, lse, delta, lens, use_lens, causal,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
+        name="flash_dkv_streamed",
         interpret=interp,
     )(*dkv_args)
     if group > 1:

@@ -74,6 +74,7 @@ def _scatter_call(e_idx, p_idx, x, n_experts, capacity):
         # the zeros operand aliases the output: slots no row routes to
         # stay zero (operand index counts the scalar-prefetch args)
         input_output_aliases={2: 0},
+        name="fused_moe_scatter",
         interpret=_support.interpret_mode(),
     )(flat, x[:, None, :], zeros)
     return out.reshape(n_experts, cp1, hdim)[:, :capacity]
@@ -98,6 +99,7 @@ def _gather_call(e_idx, p_idx, buf):
         _read_row_kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((rows, 1, hdim), buf.dtype),
+        name="fused_moe_gather",
         interpret=_support.interpret_mode(),
     )(flat, buf.reshape(n_experts * capacity, 1, hdim))
     return out[:, 0, :] * keep[:, None].astype(buf.dtype)

@@ -110,6 +110,7 @@ def _decode_call(q, k_cache, v_cache, block_tables, context_lens, sm_scale):
         kern,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((batch, kv_h, g, d), q.dtype),
+        name="paged_attention_decode",
         interpret=_support.interpret_mode(),
     )(context_lens, block_tables, q, k_cache, v_cache)
 
@@ -232,6 +233,7 @@ def _verify_call(q, k_cache, v_cache, block_tables, context_lens, sm_scale,
         kern,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((batch, kv_h, rows, d), q.dtype),
+        name="paged_attention_verify",
         interpret=_support.interpret_mode(),
     )(context_lens, block_tables, q, k_cache, v_cache)
 
@@ -395,6 +397,7 @@ def _ragged_call(q, k_cache, v_cache, block_tables, kv_lens, tok_lane,
                           block_size=block_size),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((tokens, kv_h, g_pad, d), q.dtype),
+        name="paged_attention_ragged",
         interpret=_support.interpret_mode(),
     )(kv_lens, block_tables, tok_lane, tok_pos, *operands)
 

@@ -95,6 +95,7 @@ def _build_qmm(m, n, k, out_dtype, cfg):
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
+        name="quant_matmul_int8",
         interpret=_support.interpret_mode(),
     )
 
@@ -178,6 +179,7 @@ def _build_qmm4(m, n, kp, out_dtype, cfg):
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
+        name="quant_matmul_int4",
         interpret=_support.interpret_mode(),
     )
 

@@ -35,6 +35,7 @@ def _pallas_fwd(x2d, w, eps):
         ],
         out_specs=pl.BlockSpec((br, hdim), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((r, hdim), x2d.dtype),
+        name="rms_norm",
         interpret=_support.interpret_mode(),
     )(x2d, w)
 

@@ -50,6 +50,7 @@ def _pallas_rope(q, k, cos, sin, conj):
             jax.ShapeDtypeStruct(q.shape, q.dtype),
             jax.ShapeDtypeStruct(k.shape, k.dtype),
         ],
+        name="fused_rope",
         interpret=_support.interpret_mode(),
     )(q, k, cos, sin)
 

@@ -75,7 +75,9 @@ def _sample_fn(logits, temperature, top_k, seeds, draw_idx):
 def _jitted():
     import jax
 
-    return jax.jit(_sample_fn)
+    # the scope names the sampler's ops in a device trace
+    # (docs/OBSERVABILITY.md); the decorator keeps the module's name
+    return jax.jit(jax.named_scope("sampler")(_sample_fn))
 
 
 def sample_tokens(logits, temperature, top_k, seeds, draw_idx) -> np.ndarray:

@@ -4,6 +4,7 @@
 from __future__ import annotations
 
 import enum
+import functools
 import json
 import os
 import threading
@@ -112,15 +113,36 @@ class _Recorder:
 _active_recorder: Optional[_Recorder] = None
 
 
+@functools.lru_cache(maxsize=1)
+def _trace_annotation():
+    """`jax.profiler.TraceAnnotation`, imported on first use: importing
+    this module must not import JAX."""
+    from jax.profiler import TraceAnnotation
+
+    return TraceAnnotation
+
+
 class RecordEvent:
     """User-defined host range (reference `utils.py:30`); context manager or
-    explicit begin()/end()."""
+    explicit begin()/end().
 
-    def __init__(self, name: str, event_type=None):
+    The one span primitive of the program. It also enters a
+    `jax.profiler.TraceAnnotation(name, **ids)`: while a
+    `jax.profiler.start_trace` session runs, the span lands on the
+    `/host:CPU` plane of the profiler's trace, on the clock of the device
+    ops, with `ids` (ints or short constant strings) as its stats; with no
+    session the annotation is dropped in C++. The profiler session is the
+    only switch; nothing is formatted or buffered here."""
+
+    def __init__(self, name: str, event_type=None, **ids):
         self.name = name
+        self._ids = ids
         self._t0 = None
+        self._annotation = None
 
     def begin(self):
+        self._annotation = _trace_annotation()(self.name, **self._ids)
+        self._annotation.__enter__()
         self._t0 = time.perf_counter()
 
     def end(self):
@@ -130,6 +152,8 @@ class RecordEvent:
             _active_recorder.add(self.name, self._t0, time.perf_counter(),
                                  "range")
         self._t0 = None
+        self._annotation.__exit__(None, None, None)
+        self._annotation = None
 
     def __enter__(self):
         self.begin()

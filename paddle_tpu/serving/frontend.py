@@ -23,6 +23,7 @@ from __future__ import annotations
 import time
 from typing import Iterator, List, Optional, Sequence
 
+from ..profiler import RecordEvent
 from .fault_tolerance import (AdmissionConfig, EngineStalled,
                               WatchdogConfig)
 from .metrics import ServingMetrics
@@ -190,7 +191,8 @@ class ServingFrontend:
                 tenant = adapter
         req = Request(prompt_ids, sampling=sp, deadline=deadline,
                       stream_cb=cb, tenant=tenant, adapter=adapter)
-        self.scheduler.submit(req, now=now)
+        with RecordEvent("frontend.submit", req=req.req_id):
+            self.scheduler.submit(req, now=now)
         return RequestHandle(req)
 
     def cancel(self, handle: RequestHandle) -> bool:

@@ -301,6 +301,7 @@ class Scheduler:
         self._tpot_samples: Deque[float] = deque(maxlen=32)
         self._zero_progress = 0        # consecutive no-progress steps
         self._finish_events = 0        # terminal transitions, monotonic
+        self._step_index = 0           # `sched.step` span id, monotonic
         self.tokens_committed = 0      # tokens committed to request
         # streams over this scheduler's lifetime (decode + prefill first
         # tokens + speculative accepts) — the per-replica throughput
@@ -733,35 +734,39 @@ class Scheduler:
         """One scheduling round: expire deadlines, admit into free slots,
         run one fixed-shape decode over the occupied slots. Returns the
         number of tokens produced this step."""
-        now = self._clock() if now is None else now
-        finish_mark = self._finish_events
-        self._expire(now)
-        admitted = self._admit(now)
-        produced = self._decode(now)
-        # progress = tokens, prefill-chunk advancement, admissions, or
-        # terminal transitions; a non-idle scheduler sustaining zero
-        # progress is wedged — the watchdog's restart trigger and
-        # `EngineStalled`'s evidence
-        if produced > 0 or admitted > 0 or self._chunk_progress > 0 \
-                or self._finish_events > finish_mark:
-            self._zero_progress = 0
-        else:
-            self._zero_progress += 1
-        if self._pending_stall is not None:
-            reason, self._pending_stall = self._pending_stall, None
-            self._stall(reason)
-        elif (self._wd is not None and not self.idle
-                and self._zero_progress >= self._wd.stall_steps):
-            self._stall("zero_progress")
-        mgr = self.engine.manager
-        # occupancy = decoded lanes / total lanes for THIS step (finished
-        # sequences were already evicted, so num_running undercounts)
-        self.metrics.on_step(
-            occupancy=produced / len(self.slots),
-            kv_utilization=mgr.utilization(),
-            queue_depth=len(self.waiting),
-            decoded=produced > 0)
-        return produced
+        self._step_index += 1
+        with RecordEvent("sched.step", step=self._step_index):
+            now = self._clock() if now is None else now
+            finish_mark = self._finish_events
+            with RecordEvent("sched.expire"):
+                self._expire(now)
+            with RecordEvent("sched.admit"):
+                admitted = self._admit(now)
+            produced = self._decode(now)
+            # progress = tokens, prefill-chunk advancement, admissions, or
+            # terminal transitions; a non-idle scheduler sustaining zero
+            # progress is wedged — the watchdog's restart trigger and
+            # `EngineStalled`'s evidence
+            if produced > 0 or admitted > 0 or self._chunk_progress > 0 \
+                    or self._finish_events > finish_mark:
+                self._zero_progress = 0
+            else:
+                self._zero_progress += 1
+            if self._pending_stall is not None:
+                reason, self._pending_stall = self._pending_stall, None
+                self._stall(reason)
+            elif (self._wd is not None and not self.idle
+                    and self._zero_progress >= self._wd.stall_steps):
+                self._stall("zero_progress")
+            mgr = self.engine.manager
+            # occupancy = decoded lanes / total lanes for THIS step (finished
+            # sequences were already evicted, so num_running undercounts)
+            self.metrics.on_step(
+                occupancy=produced / len(self.slots),
+                kv_utilization=mgr.utilization(),
+                queue_depth=len(self.waiting),
+                decoded=produced > 0)
+            return produced
 
     @property
     def num_running(self) -> int:
@@ -942,9 +947,14 @@ class Scheduler:
 
         if self._finite_fn is None:
             import jax.numpy as jnp
-            self._finite_fn = jax.jit(
-                lambda x: jnp.isfinite(x).all(axis=-1))
-        return np.asarray(self._finite_fn(logits))
+
+            def nan_screen(x):
+                with jax.named_scope("llama.nan_screen"):
+                    return jnp.isfinite(x).all(axis=-1)
+
+            self._finite_fn = jax.jit(nan_screen)
+        with RecordEvent("sched.screen"):
+            return np.asarray(self._finite_fn(logits))
 
     def _isolated(self, req: Request, reason: str, phase: str,
                   slot: Optional[int] = None, in_slot: bool = True):
@@ -1290,34 +1300,36 @@ class Scheduler:
                                    in_slot=False)
                     continue
             self._queue_remove(req)
-            slot = self.slots.index(None)
-            # snapshot the prefill target HERE: for a preempted
-            # re-admission it includes the kept tokens, so the replay is
-            # token-deterministic; the pending `_last` (when present)
-            # stays pending and decodes after the chunks complete. A
-            # prefix hit starts the cursor AT the hit — chunking resumes
-            # from the first uncached token (a full hit leaves exactly
-            # one token: TTFT ≈ one decode step).
-            req._prefill_ctx = ctx
-            req._prefill_pos = hit
-            req._prefix_hit_tokens = 0 if resident else hit
-            req._chunks = 0
-            req._t_admit = self._clock()
-            req.status = RequestStatus.RUNNING
-            req._admit_seq = next(self._admit_counter)
-            self.slots[slot] = req
-            admitted += 1
-            self._charge_admission(req.tenant)
-            if self._prefix_tree is not None and not resident:
-                # a resident cursor is migrated KV, not a radix hit —
-                # keep the prefix-cache hit accounting honest
-                self.metrics.on_prefix_lease(hit)
-            if _obs.enabled():
-                self._obs_req(req, "admitted", t0=req._t_admit, slot=slot,
-                              prefix_hit_tokens=hit or None,
-                              queue_wait_ms=round(
-                                  (req._t_admit - req.t_submit) * 1e3, 3)
-                              if req.t_submit is not None else None)
+            with RecordEvent("sched.admit_one", req=req.req_id,
+                             prompt=len(ctx), prefix_hit=hit):
+                slot = self.slots.index(None)
+                # snapshot the prefill target HERE: for a preempted
+                # re-admission it includes the kept tokens, so the replay is
+                # token-deterministic; the pending `_last` (when present)
+                # stays pending and decodes after the chunks complete. A
+                # prefix hit starts the cursor AT the hit — chunking resumes
+                # from the first uncached token (a full hit leaves exactly
+                # one token: TTFT ≈ one decode step).
+                req._prefill_ctx = ctx
+                req._prefill_pos = hit
+                req._prefix_hit_tokens = 0 if resident else hit
+                req._chunks = 0
+                req._t_admit = self._clock()
+                req.status = RequestStatus.RUNNING
+                req._admit_seq = next(self._admit_counter)
+                self.slots[slot] = req
+                admitted += 1
+                self._charge_admission(req.tenant)
+                if self._prefix_tree is not None and not resident:
+                    # a resident cursor is migrated KV, not a radix hit —
+                    # keep the prefix-cache hit accounting honest
+                    self.metrics.on_prefix_lease(hit)
+                if _obs.enabled():
+                    self._obs_req(req, "admitted", t0=req._t_admit, slot=slot,
+                                  prefix_hit_tokens=hit or None,
+                                  queue_wait_ms=round(
+                                      (req._t_admit - req.t_submit) * 1e3, 3)
+                                  if req.t_submit is not None else None)
         return admitted
 
     def _grow_chunk(self, req: Request, slot: int, want: int) -> int:
@@ -1404,18 +1416,19 @@ class Scheduler:
             return False
         _, slot = max(victims)
         req = self.slots[slot]
-        self._publish_prefix(req)
-        self.engine.manager.free(req.seq_id)
-        self._release_spec(req)
-        self._adapter_release(req)
-        self.slots[slot] = None
-        req.status = RequestStatus.PREEMPTED
-        req.num_preemptions += 1
-        self._queue_push(req, front=True)
-        self.metrics.on_preempt()
-        if _obs.enabled():
-            self._obs_req(req, "preempted", reason="kv_pressure",
-                          tokens_kept=len(req.generated))
+        with RecordEvent("sched.preempt", req=req.req_id):
+            self._publish_prefix(req)
+            self.engine.manager.free(req.seq_id)
+            self._release_spec(req)
+            self._adapter_release(req)
+            self.slots[slot] = None
+            req.status = RequestStatus.PREEMPTED
+            req.num_preemptions += 1
+            self._queue_push(req, front=True)
+            self.metrics.on_preempt()
+            if _obs.enabled():
+                self._obs_req(req, "preempted", reason="kv_pressure",
+                              tokens_kept=len(req.generated))
         return True
 
     def _gather_rows(self, logits, rows: np.ndarray):
@@ -1440,79 +1453,81 @@ class Scheduler:
         if not active:
             return 0
         mgr = self.engine.manager
-        # grow (and possibly preempt) before building the batch arrays
-        decode_lanes = []              # (slot, req)
-        chunks = []                    # (slot, req, n_tokens, pre_len)
-        budget = self.prefill_chunk_tokens
-        for i, req in active:
-            if self.slots[i] is not req:
-                continue
-            if req.prefilling:
-                if budget <= 0:
-                    continue           # next step's budget serves it
-                rem = len(req._prefill_ctx) - req._prefill_pos
-                pre_len = mgr.seq_len(req.seq_id)
-                try:
-                    got = self._grow_chunk(req, i, min(rem, budget))
-                except Exception:      # injected/corrupt cache state
-                    self._isolated(req, "engine_fault:cache", "cache",
-                                   slot=i)
+        with RecordEvent("sched.grow"):
+            # grow (and possibly preempt) before building the batch arrays
+            decode_lanes = []              # (slot, req)
+            chunks = []                    # (slot, req, n_tokens, pre_len)
+            budget = self.prefill_chunk_tokens
+            for i, req in active:
+                if self.slots[i] is not req:
                     continue
-                if got:
-                    budget -= got
-                    chunks.append((i, req, got, pre_len))
-            else:
-                try:
-                    ok = self._grow(req, i)
-                except Exception:      # injected/corrupt cache state:
-                    self._isolated(req, "engine_fault:cache", "cache",
-                                   slot=i)
-                    continue           # attribution is trivial
-                if ok:
-                    decode_lanes.append((i, req))
-        # growth-path preemptions may have evicted earlier entries
-        decode_lanes = [(i, r) for i, r in decode_lanes
-                        if self.slots[i] is r]
-        chunks = [(i, r, n, p) for i, r, n, p in chunks
-                  if self.slots[i] is r]
+                if req.prefilling:
+                    if budget <= 0:
+                        continue           # next step's budget serves it
+                    rem = len(req._prefill_ctx) - req._prefill_pos
+                    pre_len = mgr.seq_len(req.seq_id)
+                    try:
+                        got = self._grow_chunk(req, i, min(rem, budget))
+                    except Exception:      # injected/corrupt cache state
+                        self._isolated(req, "engine_fault:cache", "cache",
+                                       slot=i)
+                        continue
+                    if got:
+                        budget -= got
+                        chunks.append((i, req, got, pre_len))
+                else:
+                    try:
+                        ok = self._grow(req, i)
+                    except Exception:      # injected/corrupt cache state:
+                        self._isolated(req, "engine_fault:cache", "cache",
+                                       slot=i)
+                        continue           # attribution is trivial
+                    if ok:
+                        decode_lanes.append((i, req))
+            # growth-path preemptions may have evicted earlier entries
+            decode_lanes = [(i, r) for i, r in decode_lanes
+                            if self.slots[i] is r]
+            chunks = [(i, r, n, p) for i, r, n, p in chunks
+                      if self.slots[i] is r]
         if not decode_lanes and not chunks:
             return 0
-        B = len(self.slots)
-        T = self.ragged_tokens
-        tokens = np.zeros((T,), np.int32)
-        q_lens = np.zeros((B,), np.int32)
-        kv_lens = np.zeros((B,), np.int32)
-        tables = np.full((B, mgr.max_blocks_per_seq), self._pad_block,
-                         np.int32)
-        rows = np.zeros((B,), np.int32)   # last packed row per lane
-        decode_set = {i for i, _r in decode_lanes}
-        chunk_of = {i: (n, p) for i, _r, n, p in chunks}
-        pre_lens = {}                     # seq_id -> pre-round cache len
-        cursor = 0
-        for i in range(B):                # slot order = packing order
-            req = self.slots[i]
-            if req is None:
-                continue
-            if i in decode_set:
-                tokens[cursor] = req._last
-                q_lens[i] = 1
-                kv_lens[i] = mgr.seq_len(req.seq_id)
-                pre_lens[req.seq_id] = int(kv_lens[i]) - 1
-                rows[i] = cursor
-                cursor += 1
-            elif i in chunk_of:
-                n, p = chunk_of[i]
-                tokens[cursor:cursor + n] = req._prefill_ctx[
-                    req._prefill_pos:req._prefill_pos + n]
-                q_lens[i] = n
-                kv_lens[i] = mgr.seq_len(req.seq_id)   # == p + n
-                pre_lens[req.seq_id] = p
-                rows[i] = cursor + n - 1
-                cursor += n
-            else:
-                continue
-            tables[i] = mgr.block_table_array([req.seq_id])[0]
-        all_lanes = decode_lanes + [(i, r) for i, r, _n, _p in chunks]
+        with RecordEvent("sched.pack"):
+            B = len(self.slots)
+            T = self.ragged_tokens
+            tokens = np.zeros((T,), np.int32)
+            q_lens = np.zeros((B,), np.int32)
+            kv_lens = np.zeros((B,), np.int32)
+            tables = np.full((B, mgr.max_blocks_per_seq), self._pad_block,
+                             np.int32)
+            rows = np.zeros((B,), np.int32)   # last packed row per lane
+            decode_set = {i for i, _r in decode_lanes}
+            chunk_of = {i: (n, p) for i, _r, n, p in chunks}
+            pre_lens = {}                     # seq_id -> pre-round cache len
+            cursor = 0
+            for i in range(B):                # slot order = packing order
+                req = self.slots[i]
+                if req is None:
+                    continue
+                if i in decode_set:
+                    tokens[cursor] = req._last
+                    q_lens[i] = 1
+                    kv_lens[i] = mgr.seq_len(req.seq_id)
+                    pre_lens[req.seq_id] = int(kv_lens[i]) - 1
+                    rows[i] = cursor
+                    cursor += 1
+                elif i in chunk_of:
+                    n, p = chunk_of[i]
+                    tokens[cursor:cursor + n] = req._prefill_ctx[
+                        req._prefill_pos:req._prefill_pos + n]
+                    q_lens[i] = n
+                    kv_lens[i] = mgr.seq_len(req.seq_id)   # == p + n
+                    pre_lens[req.seq_id] = p
+                    rows[i] = cursor + n - 1
+                    cursor += n
+                else:
+                    continue
+                tables[i] = mgr.block_table_array([req.seq_id])[0]
+            all_lanes = decode_lanes + [(i, r) for i, r, _n, _p in chunks]
         def probe(i, req):
             """Replay ONE lane of the failed step (same fixed shapes, so
             no recompile; KV writes are position-indexed and idempotent
@@ -1540,7 +1555,10 @@ class Scheduler:
 
         self._install_lane_adapters()
         try:
-            with RecordEvent("serving.decode_step"):
+            with RecordEvent("sched.dispatch", phase="decode",
+                             prefill_tokens=sum(n for _i, _r, n, _p
+                                                in chunks),
+                             decode_lanes=len(decode_lanes)):
                 logits, flagged = self._dispatch(
                     "decode", self.engine.ragged_step, tokens, q_lens,
                     kv_lens, tables)
@@ -1585,25 +1603,27 @@ class Scheduler:
                 lane_sample[i] = req
         try:
             _faults.check("serve.sample")
-            picked = sample_tokens(self._gather_rows(logits, rows),
-                                   *self._sampling_arrays(lane_sample))
+            with RecordEvent("sched.sample"):
+                picked = sample_tokens(self._gather_rows(logits, rows),
+                                       *self._sampling_arrays(lane_sample))
         except Exception as e:
             self._step_fault("sample", e, all_lanes, rollback=rollback)
             return 0
         self._step_faults = 0   # a full dispatch+sample round succeeded
-        produced = 0
-        for i, req in decode_lanes:
-            if self.slots[i] is not req:   # cancelled by a stream_cb
-                continue                   # earlier in this very loop
-            produced += 1
-            self._commit_token(req, int(picked[i]), i, t_tok,
-                               obs_decode=True)
-        chunk_tokens = 0
-        for i, req, n, _p in chunks:
-            if self.slots[i] is not req:   # cancelled mid-commit
-                continue
-            chunk_tokens += n
-            self._commit_chunk(req, n, i, t_tok, picked[i])
+        with RecordEvent("sched.commit"):
+            produced = 0
+            for i, req in decode_lanes:
+                if self.slots[i] is not req:   # cancelled by a stream_cb
+                    continue                   # earlier in this very loop
+                produced += 1
+                self._commit_token(req, int(picked[i]), i, t_tok,
+                                   obs_decode=True)
+            chunk_tokens = 0
+            for i, req, n, _p in chunks:
+                if self.slots[i] is not req:   # cancelled mid-commit
+                    continue
+                chunk_tokens += n
+                self._commit_chunk(req, n, i, t_tok, picked[i])
         self._chunk_progress = chunk_tokens
         self.metrics.on_ragged_step(chunk_tokens, len(decode_lanes))
         if decode_lanes:
@@ -1673,78 +1693,80 @@ class Scheduler:
         K = self.spec.num_draft_tokens
         S = K + 1
         proposer = self.spec.proposer
-        lanes = []             # (slot, req, toks, pre_len, prefilling)
-        for i, req in active:
-            if self.slots[i] is not req:
-                continue
-            pre_len = mgr.seq_len(req.seq_id)
-            if req.prefilling:
-                rem = len(req._prefill_ctx) - req._prefill_pos
-                want = min(S, rem)
-                if want == rem and rem > 1:
-                    want = rem - 1      # complete next round, at slot 0
+        with RecordEvent("sched.grow"):
+            lanes = []             # (slot, req, toks, pre_len, prefilling)
+            for i, req in active:
+                if self.slots[i] is not req:
+                    continue
+                pre_len = mgr.seq_len(req.seq_id)
+                if req.prefilling:
+                    rem = len(req._prefill_ctx) - req._prefill_pos
+                    want = min(S, rem)
+                    if want == rem and rem > 1:
+                        want = rem - 1      # complete next round, at slot 0
+                    try:
+                        got = self._grow_chunk(req, i, want)
+                    except Exception:       # injected/corrupt cache state
+                        self._isolated(req, "engine_fault:cache", "cache",
+                                       slot=i)
+                        continue
+                    if got == 0:
+                        continue
+                    toks = list(req._prefill_ctx[
+                        req._prefill_pos:req._prefill_pos + got])
+                    lanes.append((i, req, toks, pre_len, True))
+                    continue
                 try:
-                    got = self._grow_chunk(req, i, want)
-                except Exception:       # injected/corrupt cache state
-                    self._isolated(req, "engine_fault:cache", "cache",
-                                   slot=i)
+                    drafts = list(proposer.propose(
+                        req.seq_id, req.all_tokens(), K))[:K]
+                except Exception:
+                    drafts = []          # proposers must never kill the step
+                try:
+                    got = self._grow_n(req, i, 1 + len(drafts))
+                except Exception:        # injected/corrupt cache state
+                    self._isolated(req, "engine_fault:cache", "cache", slot=i)
                     continue
                 if got == 0:
                     continue
-                toks = list(req._prefill_ctx[
-                    req._prefill_pos:req._prefill_pos + got])
-                lanes.append((i, req, toks, pre_len, True))
-                continue
-            try:
-                drafts = list(proposer.propose(
-                    req.seq_id, req.all_tokens(), K))[:K]
-            except Exception:
-                drafts = []          # proposers must never kill the step
-            try:
-                got = self._grow_n(req, i, 1 + len(drafts))
-            except Exception:        # injected/corrupt cache state
-                self._isolated(req, "engine_fault:cache", "cache", slot=i)
-                continue
-            if got == 0:
-                continue
-            lanes.append((i, req, [req._last] + drafts[:got - 1], pre_len,
-                          False))
-        lanes = [ln for ln in lanes if self.slots[ln[0]] is ln[1]]
+                lanes.append((i, req, [req._last] + drafts[:got - 1], pre_len,
+                              False))
+            lanes = [ln for ln in lanes if self.slots[ln[0]] is ln[1]]
         if not lanes:
             return 0
-        B = len(self.slots)
-        tokens = np.zeros((B, S), np.int32)
-        ctx = np.full((B,), S, np.int32)      # pad lanes write guard block
-        # a lane within S tokens of its hard length cap has a table FULL
-        # of real blocks while ctx still counts the fixed S-token window,
-        # so the engines' block gather for positions past the cap indexes
-        # past the table width. Without the trailing guard columns the
-        # write survives only by accident (jnp OOB-gather fill int32-min,
-        # times a power-of-two block size, wraps to physical block 0 —
-        # which is the guard only because it's the first block ever
-        # leased); make the invariant explicit instead (width is a
-        # function of the fixed S: still one compiled program).
-        width = mgr.max_blocks_per_seq + (S + mgr.block_size - 2) \
-            // mgr.block_size
-        tables = np.full((B, width), self._pad_block, np.int32)
-        lane_reqs: List[Optional[Request]] = [None] * B
-        pre_lens = {}
-        for i, req, toks, pre_len, prefilling in lanes:
-            tokens[i, :len(toks)] = toks
-            # uniform layout: token j sits at position pre_len + j, so
-            # ctx counts the full fixed window even when the lane holds
-            # fewer than S real tokens (short drafts / a short chunk)
-            ctx[i] = pre_len + S
-            tables[i, :mgr.max_blocks_per_seq] = mgr.block_table_array(
-                [req.seq_id], pad=self._pad_block)[0]
-            # sampled rows matter for decode lanes always, and for a
-            # prefill lane only on its completing (one-token) chunk
-            if not prefilling:
-                lane_reqs[i] = req
-            elif req._prefill_pos + len(toks) >= len(req._prefill_ctx) \
-                    and req._last is None:
-                lane_reqs[i] = req
-            pre_lens[req.seq_id] = pre_len
+        with RecordEvent("sched.pack"):
+            B = len(self.slots)
+            tokens = np.zeros((B, S), np.int32)
+            ctx = np.full((B,), S, np.int32)      # pad lanes write guard block
+            # a lane within S tokens of its hard length cap has a table FULL
+            # of real blocks while ctx still counts the fixed S-token window,
+            # so the engines' block gather for positions past the cap indexes
+            # past the table width. Without the trailing guard columns the
+            # write survives only by accident (jnp OOB-gather fill int32-min,
+            # times a power-of-two block size, wraps to physical block 0 —
+            # which is the guard only because it's the first block ever
+            # leased); make the invariant explicit instead (width is a
+            # function of the fixed S: still one compiled program).
+            width = mgr.max_blocks_per_seq + (S + mgr.block_size - 2) \
+                // mgr.block_size
+            tables = np.full((B, width), self._pad_block, np.int32)
+            lane_reqs: List[Optional[Request]] = [None] * B
+            pre_lens = {}
+            for i, req, toks, pre_len, prefilling in lanes:
+                tokens[i, :len(toks)] = toks
+                # uniform layout: token j sits at position pre_len + j, so
+                # ctx counts the full fixed window even when the lane holds
+                # fewer than S real tokens (short drafts / a short chunk)
+                ctx[i] = pre_len + S
+                tables[i, :mgr.max_blocks_per_seq] = mgr.block_table_array(
+                    [req.seq_id], pad=self._pad_block)[0]
+                # sampled rows matter for decode lanes always, and for a
+                # prefill lane only on its completing (one-token) chunk
+                if not prefilling:
+                    lane_reqs[i] = req
+                elif req._prefill_pos + len(toks) >= len(req._prefill_ctx) \
+                        and req._last is None:
+                    lane_reqs[i] = req
+                pre_lens[req.seq_id] = pre_len
         def probe(i, req):
             t = np.zeros((B, S), np.int32)
             t[i] = tokens[i]
@@ -1761,7 +1783,10 @@ class Scheduler:
         lane_pairs = [(i, r) for i, r, _t, _p, _f in lanes]
         self._install_lane_adapters()
         try:
-            with RecordEvent("serving.verify_step"):
+            with RecordEvent("sched.dispatch", phase="verify",
+                             prefill_tokens=sum(len(ln[2]) for ln in lanes
+                                                if ln[4]),
+                             decode_lanes=sum(not ln[4] for ln in lanes)):
                 logits, flagged = self._dispatch(
                     "verify", self.engine.verify_step, tokens, ctx, tables)
         except Exception as e:
@@ -1786,49 +1811,52 @@ class Scheduler:
         t_tok = self._clock()
         try:
             _faults.check("serve.sample")
-            picked = sample_tokens(logits, *self._sampling_arrays(lane_reqs))
+            with RecordEvent("sched.sample"):
+                picked = sample_tokens(logits,
+                                       *self._sampling_arrays(lane_reqs))
         except Exception as e:
             self._step_fault("sample", e,
                              [(i, r) for i, r, _t, _p, _f in lanes],
                              rollback=rollback)
             return 0
         self._step_faults = 0   # a full verify+sample round succeeded
-        produced = proposed = accepted = 0
-        chunk_tokens = decode_lanes = 0
-        obs_on = _obs.enabled()
-        for i, req, toks, pre_len, prefilling in lanes:
-            if self.slots[i] is not req:   # cancelled by a stream_cb
-                continue                   # earlier in this very loop
-            if prefilling:
-                got = len(toks)
-                chunk_tokens += got
-                # a completing chunk has got == 1 -> window slot 0, the
-                # draw offset sequential decode would use
-                self._commit_chunk(req, got, i, t_tok, picked[i, got - 1])
-                continue
-            decode_lanes += 1
-            drafts = toks[1:]
-            a = 0
-            while a < len(drafts) and drafts[a] == int(picked[i, a]):
-                a += 1
-            proposed += len(drafts)
-            accepted += a
-            committed = 0
-            # emit the accepted drafts (== the sampled tokens) plus the
-            # bonus/correction token from the first unmatched position
-            for tok in (int(picked[i, j]) for j in range(a + 1)):
-                produced += 1
-                committed += 1
-                self._commit_token(req, tok, i, t_tok)
-                if req.status.terminal:
-                    break
-            if obs_on:
-                self._obs_req(req, "verify_round", t0=t_tok,
-                              tokens=committed, drafts=len(drafts),
-                              accepted=a)
-            if not req.status.terminal:
-                # roll back rejected speculation: keep pending + accepted
-                mgr.trim(req.seq_id, pre_len + 1 + a)
+        with RecordEvent("sched.commit"):
+            produced = proposed = accepted = 0
+            chunk_tokens = decode_lanes = 0
+            obs_on = _obs.enabled()
+            for i, req, toks, pre_len, prefilling in lanes:
+                if self.slots[i] is not req:   # cancelled by a stream_cb
+                    continue                   # earlier in this very loop
+                if prefilling:
+                    got = len(toks)
+                    chunk_tokens += got
+                    # a completing chunk has got == 1 -> window slot 0, the
+                    # draw offset sequential decode would use
+                    self._commit_chunk(req, got, i, t_tok, picked[i, got - 1])
+                    continue
+                decode_lanes += 1
+                drafts = toks[1:]
+                a = 0
+                while a < len(drafts) and drafts[a] == int(picked[i, a]):
+                    a += 1
+                proposed += len(drafts)
+                accepted += a
+                committed = 0
+                # emit the accepted drafts (== the sampled tokens) plus the
+                # bonus/correction token from the first unmatched position
+                for tok in (int(picked[i, j]) for j in range(a + 1)):
+                    produced += 1
+                    committed += 1
+                    self._commit_token(req, tok, i, t_tok)
+                    if req.status.terminal:
+                        break
+                if obs_on:
+                    self._obs_req(req, "verify_round", t0=t_tok,
+                                  tokens=committed, drafts=len(drafts),
+                                  accepted=a)
+                if not req.status.terminal:
+                    # roll back rejected speculation: keep pending + accepted
+                    mgr.trim(req.seq_id, pre_len + 1 + a)
         self._chunk_progress = chunk_tokens
         self.metrics.on_ragged_step(chunk_tokens, decode_lanes)
         if decode_lanes:
@@ -1872,7 +1900,8 @@ class Scheduler:
         self.tokens_committed += 1
         if req.t_first_token is None:
             req.t_first_token = t_tok
-            self.metrics.on_first_token(req)
+            with RecordEvent("sched.first_token", req=req.req_id):
+                self.metrics.on_first_token(req)
         if req.stream_cb is not None:
             req.stream_cb(req, tok)
         if obs_decode and _obs.enabled():
@@ -1894,32 +1923,34 @@ class Scheduler:
 
     def _finish(self, req: Request, status: RequestStatus, reason: str,
                 slot: Optional[int] = None, in_slot: bool = True):
-        if in_slot:
-            if slot is None:
-                slot = self.slots.index(req)
-            self.slots[slot] = None
-            if status is not RequestStatus.FAILED:
-                # a FAILED lane's KV may be poison (NaN isolation,
-                # engine fault) — never publish it into the shared tree
-                self._publish_prefix(req)
-            self.engine.manager.free(req.seq_id)
-        else:
-            # a WAITING request may hold imported KV (`import_session`)
-            # that no slot path will ever free
-            self._drop_resident_kv(req)
-        self._release_spec(req)
-        self._adapter_release(req)
-        req.status = status
-        req.finish_reason = reason
-        req.t_finish = self._clock()
-        self._finish_events += 1
-        self.metrics.on_finish(req)
-        if _obs.enabled():
-            self._obs_req(req, f"terminal:{status.value}",
-                          t0=req.t_finish, reason=reason,
-                          tokens=len(req.generated))
-            if status is RequestStatus.FAILED:
-                _obs.timeline.dump_flight(f"request_failed_{reason}")
+        with RecordEvent("sched.finish", req=req.req_id,
+                         status=status.value):
+            if in_slot:
+                if slot is None:
+                    slot = self.slots.index(req)
+                self.slots[slot] = None
+                if status is not RequestStatus.FAILED:
+                    # a FAILED lane's KV may be poison (NaN isolation,
+                    # engine fault) — never publish it into the shared tree
+                    self._publish_prefix(req)
+                self.engine.manager.free(req.seq_id)
+            else:
+                # a WAITING request may hold imported KV (`import_session`)
+                # that no slot path will ever free
+                self._drop_resident_kv(req)
+            self._release_spec(req)
+            self._adapter_release(req)
+            req.status = status
+            req.finish_reason = reason
+            req.t_finish = self._clock()
+            self._finish_events += 1
+            self.metrics.on_finish(req)
+            if _obs.enabled():
+                self._obs_req(req, f"terminal:{status.value}",
+                              t0=req.t_finish, reason=reason,
+                              tokens=len(req.generated))
+                if status is RequestStatus.FAILED:
+                    _obs.timeline.dump_flight(f"request_failed_{reason}")
 
     def _release_spec(self, req: Request):
         """Drop any speculative-proposer state for a request leaving the
