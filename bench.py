@@ -2147,6 +2147,10 @@ def kernel_micro_main():
     decode/verify dispatch wall time across batch compositions. On TPU
     this times the Pallas kernels; on CPU the XLA reference paths (the
     production fallback), platform-tagged like every other scenario.
+    The ragged kernel takes one grid step a lane and walks that lane's
+    live pages and live tokens; the legacy pair still visit batch x kv
+    heads x table width, so the `*_vs_legacy_x` ratios grow with the
+    table's dead width (here the tables are 8 pages, most of them live).
 
     Extras also carry `tp_ragged_cost` (ISSUE 16): the TP-sharded ragged
     executable's XLA cost card next to the single-chip one — lowering

@@ -51,13 +51,18 @@ FULL = SimpleNamespace(
     block_size=16, blocks_per_seq=128, lanes=8, chunk=64,
     prompts=(23, 200, 64, 331, 9), new_tokens=(24, 32, 16, 24, 32),
     small_layers=2, train_seq=2048, train_batch=2, train_steps=4,
-    moe_rows=1024, moe_experts=8, moe_capacity=256)
+    moe_rows=1024, moe_experts=8, moe_capacity=256,
+    # the serving cells' own ragged step (benchmark/configs/
+    # mistral-7b-v0.3-serve.json): T, lanes, table width, kv heads, group,
+    # head dim, block size, most live pages a lane
+    cell=(96, 32, 128, 8, 4, 128, 16, 14))
 TINY = SimpleNamespace(
     hidden=256, inter=512, heads=4, vocab=512, max_pos=128,
     block_size=16, blocks_per_seq=8, lanes=4, chunk=12,
     prompts=(5, 40, 12, 21), new_tokens=(6, 8, 4, 8),
     small_layers=2, train_seq=128, train_batch=2, train_steps=4,
-    moe_rows=32, moe_experts=4, moe_capacity=16)
+    moe_rows=32, moe_experts=4, moe_capacity=16,
+    cell=(20, 4, 16, 2, 4, 128, 16, 3))
 
 # --- tolerances -------------------------------------------------------------
 # All are relative to the largest magnitude of the reference they bound.
@@ -591,10 +596,37 @@ def kernel_cases(z):
         return (normal(key, 0, (T, H, D)), kc, vc, tables, kv_lens, lane,
                 pos)
 
-    def ragged_q_args(key):
-        q, kc, vc, *rest = ragged_args(key)
-        return (q,) + kv_quant.quantize_kv(kc) + kv_quant.quantize_kv(vc) \
-            + tuple(rest)
+    def cell_args(key):
+        # the benchmark's serving step: a table far wider than any lane's
+        # live pages, its dead entries all the scheduler's one pad block;
+        # lane 0 a prefill chunk behind prior context, decode lanes from
+        # one token to the most a lane holds, the last lane empty
+        t, lanes, width, kvh, g, d, bs, live = z.cell
+        chunk, most = t - lanes, live * bs
+        q_lens = jnp.asarray([chunk] + [1] * (lanes - 2) + [0], i32)
+        kv_lens = jnp.asarray(
+            [most] + [1 + (most - 1) * i // (lanes - 2)
+                      for i in range(1, lanes - 1)] + [0], i32)
+        nb = lanes * live + 1
+        # 5 shares no factor with 32 * 14 (or the rehearsal's 4 * 3): every
+        # live page a block of its own, scattered over the pool
+        own = 1 + (jnp.arange(lanes * live, dtype=i32) * 5 + 3) \
+            % (lanes * live)
+        tables = jnp.where(
+            jnp.arange(live, dtype=i32)[None] * bs < kv_lens[:, None],
+            own.reshape(lanes, live), 0)
+        tables = jnp.pad(tables, ((0, 0), (0, width - live)))
+        lane, pos = pk.ragged_metadata(q_lens, kv_lens, t)
+        return (normal(key, 0, (t, kvh * g, d)),
+                normal(key, 1, (nb, kvh, bs, d)),
+                normal(key, 2, (nb, kvh, bs, d)), tables, kv_lens, lane, pos)
+
+    def quantized(make):
+        def args(key):
+            q, kc, vc, *rest = make(key)
+            return (q,) + kv_quant.quantize_kv(kc) \
+                + kv_quant.quantize_kv(vc) + tuple(rest)
+        return args
 
     def ragged_q(fn):
         return lambda q, kq, ks, vq, vs, *rest: fn(
@@ -640,8 +672,13 @@ def kernel_cases(z):
     cases = [
         ("paged_attention_ragged bf16", ragged_args,
          pk.paged_attention_ragged, pk.paged_attention_ragged_ref),
-        ("paged_attention_ragged int8-KV", ragged_q_args,
+        ("paged_attention_ragged int8-KV", quantized(ragged_args),
          ragged_q(pk.paged_attention_ragged),
+         ragged_q(pk.paged_attention_ragged_ref)),
+        ("paged_attention_ragged bf16, the cells' shape", cell_args,
+         pk.paged_attention_ragged, pk.paged_attention_ragged_ref),
+        ("paged_attention_ragged int8-KV, the cells' shape",
+         quantized(cell_args), ragged_q(pk.paged_attention_ragged),
          ragged_q(pk.paged_attention_ragged_ref)),
         ("paged_attention (legacy decode)",
          lambda key: decode_args(key, H, D),

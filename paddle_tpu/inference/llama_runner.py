@@ -635,7 +635,8 @@ def _layer_body(x, layer_in, *, cfg, positions, tables, ctx_lens, mode,
                     k[0], v[0], kc, vc, tables, tok_lane, tok_pos)
         with scope("llama.attn"):
             qr = q[0]                                     # [T, NH, D]
-            if pk.ragged_supported((s, nh, d), qr.dtype):
+            if pk.ragged_supported((s, nh, d), qr.dtype, kc.shape,
+                                   kc.dtype, tables.shape[1]):
                 attn = pk.paged_attention_ragged(
                     qr, kc, vc, tables, ctx_lens, tok_lane, tok_pos,
                     k_scale=ks, v_scale=vs)

@@ -277,129 +277,277 @@ def paged_attention_verify(q, k_cache, v_cache, block_tables, context_lens,
     return jnp.swapaxes(out, 1, 2).reshape(batch, s, h, d)
 
 
-def _ragged_kernel(kv_lens_ref, tables_ref, lane_ref, pos_ref,
-                   q_ref, k_ref, v_ref, *rest, sm_scale, block_size):
+# VMEM the ragged kernel sizes its buffers against: the page double buffer
+# and one query tile's q/acc/m/l, leaving the rest of the 16 MiB scoped
+# limit to the per-head temporaries (K/V tiles, scores) Mosaic allocates.
+_RAGGED_VMEM_BUDGET = 10 << 20
+# query tokens per compute chunk: the granule the work per lane follows
+_RAGGED_Q_CHUNK = 8
+
+
+def _group_pad(g):
+    """A query-head group's rows, padded to whole 8-row sublane tiles."""
+    return -(-g // 8) * 8
+
+
+def _ragged_tiles(tokens, kv_h, g_pad, d, block_size, width, kv_itemsize):
+    """Static tile sizes of the ragged kernel, from shapes alone:
+    `(pages, q_tile)` or None when no tile fits `_RAGGED_VMEM_BUDGET`.
+
+    `pages` K/V pages are fetched per loop step so that one step's score
+    tile is a full 128-lane row of kv positions (fewer when the table is
+    narrower). `q_tile` query tokens share those pages: the largest
+    power-of-two multiple of `_RAGGED_Q_CHUNK` whose f32 q/acc rows and
+    lane-replicated m/l rows fit beside the page double buffer, and no
+    more than the packed buffer holds."""
+    pages = max(1, min(128 // block_size, width))
+    kv_bytes = 2 * 2 * pages * kv_h * block_size * max(d, 128) * kv_itemsize
+    per_token = kv_h * g_pad * (2 * max(d, 128) + 2 * 128) * 4
+    fit = (_RAGGED_VMEM_BUDGET - kv_bytes) // (per_token * _RAGGED_Q_CHUNK)
+    if fit < 1:
+        return None
+    chunks = min(1 << (fit.bit_length() - 1),
+                 -(-tokens // _RAGGED_Q_CHUNK))
+    return pages, chunks * _RAGGED_Q_CHUNK
+
+
+def _ragged_kernel(kv_lens_ref, q_lens_ref, q_starts_ref, tables_ref,
+                   q_hbm, k_hbm, v_hbm, *rest, sm_scale, block_size, pages,
+                   q_tile, g_pad, quantized, mxu_dtype):
     """Ragged paged attention: ONE fixed-shape kernel for mixed
-    prefill-chunk + decode + verify batches.
+    prefill-chunk + decode + verify batches, whose work follows the live
+    pages and live tokens of each lane.
 
-    The grid iterates fixed-shape token tiles over a PACKED query buffer:
-    tile t is one query token's head-group band [g_pad, D] (so a decode
-    lane costs exactly one tile and a 32-token prefill chunk costs 32 —
-    zero bucket padding). Per-token scalar-prefetch metadata maps every
-    tile to its owning sequence lane (`lane_ref`) and absolute position
-    (`pos_ref`, -1 for guard/empty token slots); the per-lane
-    `(kv_len, q_len, q_start)` prefix sums are folded into those two
-    arrays on the host/XLA side. Causal masking per tile is
-    `kv_pos <= pos_ref[t]`; guard tiles (pos -1, or a lane with
-    kv_len == 0) compute nothing and emit zeros via the l_safe finish.
-    Same online-softmax structure as `_decode_kernel` — the decode and
-    verify kernels are special cases of this one (q_len==1 / q_len==S).
+    grid = (lanes,). Everything ragged is scalar-prefetched: lane b owns
+    the packed query tokens [q_start, q_start + q_len) (lane-major, as
+    `ragged_metadata` packs them) whose first sits at absolute position
+    kv_len - q_len. The packed q buffer, the K/V pools (as stored,
+    [NB, KVH, BS, D]) and the output stay in HBM; the body moves what it
+    needs with its own DMAs:
 
-    Quantized KV (`inference/kv_quant.py` layout): `rest` then leads with
-    the block's per-slot f32 scale rows `ks_ref`/`vs_ref`, each (1, BS),
-    and K/V arrive as int8 — the bf16/f32 KV never exists in HBM, which
-    is the point: a decode step is KV-bandwidth-bound, so halving the
-    bytes read halves the step's HBM traffic. The per-slot scale is
-    constant along D, so it factors out of both contractions and is
-    applied on the (Gp, BS) score tile: `(q.k_int) * ks` before the
-    softmax and `p * vs` before `p @ v_int` — the same maths as
-    dequantizing the (BS, D) blocks, with the scale as a lane-aligned
-    row instead of a sublane column."""
-    if len(rest) == 6:
-        ks_ref, vs_ref, o_ref, acc_ref, m_ref, l_ref = rest
-    else:
-        ks_ref = vs_ref = None
-        o_ref, acc_ref, m_ref, l_ref = rest
-    t = pl.program_id(0)
-    j = pl.program_id(2)
-    nb = pl.num_programs(2)
+    - a lane's tokens go in tiles of `q_tile` (an empty lane issues none,
+      guard slots past sum(q_lens) belong to no lane and cost nothing);
+      a tile's q rows come in, and its output rows leave, in chunks of
+      `_RAGGED_Q_CHUNK` tokens, only the live ones;
+    - per tile a rolled loop walks the lane's live pages — up to the
+      tile's last query position, never the table's width — `pages` at a
+      time, double-buffered: one DMA brings a page for ALL kv heads (it
+      is contiguous in the pool), so a page is fetched once per tile.
+      The tail group's missing pages re-fetch the last live one and are
+      masked, so no dead page is ever read;
+    - per kv head the [rows, D] x [D, pages*BS] score tile and the
+      [rows, pages*BS] x [pages*BS, D] update run per live chunk with the
+      online softmax in f32 (m/l lane-replicated scratch). Scores take
+      the MXU in `mxu_dtype`: the operands' own bf16 when q and the pool
+      are bf16/int8 (products of bf16 values are exact in the f32
+      accumulator), f32 otherwise; `sm_scale` is applied to the f32
+      scores. Causal mask per row: `kv_pos <= position` and < kv_len.
 
-    @pl.when(j == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+    Output rows of a tile's last chunk past the lane's tokens are written
+    as zeros; the next lane, processed after it, overwrites the ones it
+    owns, and the output buffer starts zeroed (aliased input), so guard
+    rows come back exact zeros.
 
-    lane = lane_ref[t]
-    ctx_len = kv_lens_ref[lane]
-    qpos = pos_ref[t]
+    Quantized KV (`inference/kv_quant.py` layout): K/V arrive as int8 and
+    `rest` leads with the lane's per-slot f32 scale rows in logical order
+    `ks_ref`/`vs_ref` [1, KVH, groups, pages*BS] — the bf16/f32 KV never
+    exists in HBM. The per-slot scale is constant along D, so it factors
+    out of both contractions and is applied on the score tile:
+    `(q.k_int) * ks` before the softmax and `p * vs` before `p @ v_int`."""
+    if quantized:
+        ks_ref, vs_ref = rest[:2]
+        rest = rest[2:]
+    _, o_hbm, qbuf, kbuf, vbuf, acc_ref, m_ref, l_ref, sem = rest
+    kv_h, d = kbuf.shape[2], kbuf.shape[4]
+    qc = _RAGGED_Q_CHUNK
+    rows = qc * g_pad                     # MXU rows of one compute chunk
+    cols = pages * block_size             # kv positions of one page group
+    i32 = jnp.int32
+    b = pl.program_id(0)
+    kv_len = kv_lens_ref[b]
+    q_len = q_lens_ref[b]
+    q_start = q_starts_ref[b]
 
-    @pl.when((j * block_size < ctx_len) & (qpos >= 0))
-    def _compute():
-        q = q_ref[0, 0].astype(jnp.float32) * sm_scale      # (Gp, D)
-        k = k_ref[0, 0].astype(jnp.float32)                 # (BS, D)
-        v = v_ref[0, 0].astype(jnp.float32)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-        if ks_ref is not None:
-            s = s * ks_ref[0, 0]                            # (1, BS) row
-        pos = j * block_size + jax.lax.broadcasted_iota(
-            jnp.int32, s.shape, 1)
-        # typed scalar: see the NEG_INF note in _decode_kernel
-        s = jnp.where(pos <= qpos, s, jnp.float32(NEG_INF))
-        m_prev = m_ref[...][:, 0]
-        l_prev = l_ref[...][:, 0]
-        m_new = jnp.maximum(m_prev, s.max(axis=-1))
-        p = jnp.exp(s - m_new[:, None])
-        alpha = jnp.exp(m_prev - m_new)
-        l_new = l_prev * alpha + p.sum(axis=-1)
-        if vs_ref is not None:
-            p = p * vs_ref[0, 0]
-        acc_ref[...] = acc_ref[...] * alpha[:, None] + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-        m_ref[...] = m_new[:, None]
-        l_ref[...] = l_new[:, None]
+    def chunk_loop(n, body):
+        jax.lax.fori_loop(0, n, lambda c, _: body(c), None)
 
-    @pl.when(j == nb - 1)
-    def _finish():
-        l = l_ref[...][:, 0]
-        l_safe = jnp.where(l == 0.0, jnp.float32(1.0), l)
-        o_ref[0, 0] = (acc_ref[...] / l_safe[:, None]).astype(o_ref.dtype)
+    def toks(c):                          # chunk c's tokens of the tile
+        return pl.ds(c * i32(qc), qc)
+
+    def band(c):                          # ... and their MXU rows
+        return pl.ds(pl.multiple_of(c * i32(rows), rows), rows)
+
+    def tile(i):
+        t0 = i * i32(q_tile)              # the tile's first token, in-lane
+        n_tok = jnp.minimum(q_len - t0, i32(q_tile))
+        n_chunks = pl.cdiv(n_tok, i32(qc))
+        pos0 = kv_len - q_len + t0        # its absolute position
+        n_pages = pl.cdiv(pos0 + n_tok, i32(block_size))
+        n_groups = pl.cdiv(n_pages, i32(pages))
+
+        def q_copy(c):
+            return pltpu.make_async_copy(
+                q_hbm.at[pl.ds(q_start + t0 + c * i32(qc), qc)],
+                qbuf.at[toks(c)], sem.at[2, 0])
+
+        def o_copy(c):
+            return pltpu.make_async_copy(
+                acc_ref.at[toks(c)],
+                o_hbm.at[pl.ds(q_start + t0 + c * i32(qc), qc)],
+                sem.at[2, 1])
+
+        def page_copies(g, slot):
+            for p in range(pages):
+                j = jnp.minimum(g * i32(pages) + i32(p), n_pages - 1)
+                blk = tables_ref[b, j]
+                yield pltpu.make_async_copy(k_hbm.at[blk], kbuf.at[slot, p],
+                                            sem.at[0, slot])
+                yield pltpu.make_async_copy(v_hbm.at[blk], vbuf.at[slot, p],
+                                            sem.at[1, slot])
+
+        chunk_loop(n_chunks, lambda c: q_copy(c).start())
+        for cp in page_copies(i32(0), 0):
+            cp.start()
+
+        def init(c):
+            acc_ref[toks(c)] = jnp.zeros(
+                (qc,) + acc_ref.shape[1:], jnp.float32)
+            m_ref[:, band(c), :] = jnp.full(
+                (kv_h, rows, 128), NEG_INF, jnp.float32)
+            l_ref[:, band(c), :] = jnp.zeros(
+                (kv_h, rows, 128), jnp.float32)
+
+        chunk_loop(n_chunks, init)
+        chunk_loop(n_chunks, lambda c: q_copy(c).wait())
+
+        def group(g, _):
+            slot = g % 2
+
+            @pl.when(g + 1 < n_groups)
+            def _prefetch():
+                for cp in page_copies(g + 1, 1 - slot):
+                    cp.start()
+
+            for cp in page_copies(g, slot):
+                cp.wait()
+            kv_pos = g * i32(cols) + jax.lax.broadcasted_iota(
+                i32, (rows, cols), 1)
+            tok = jax.lax.broadcasted_iota(i32, (rows, cols), 0) // i32(g_pad)
+            for h in range(kv_h):
+                k = kbuf[slot, :, h].reshape(cols, d).astype(mxu_dtype)
+                v = vbuf[slot, :, h].reshape(cols, d).astype(jnp.float32)
+                if quantized:
+                    ks = ks_ref[0, h, pl.ds(g, 1), :]        # (1, cols)
+                    vs = vs_ref[0, h, pl.ds(g, 1), :]
+
+                def chunk(c, h=h, k=k, v=v):
+                    ts, rs = toks(c), band(c)
+                    q = qbuf[ts, h].reshape(rows, d).astype(mxu_dtype)
+                    s = jax.lax.dot_general(
+                        q, k, (((1,), (1,)), ((), ())),
+                        preferred_element_type=jnp.float32) * sm_scale
+                    if quantized:
+                        s = s * ks
+                    live = kv_pos <= jnp.minimum(
+                        pos0 + c * i32(qc) + tok, kv_len - 1)
+                    # typed scalars: python numbers weak-type to 64 bits
+                    # when the interpret-mode kernel is traced inside an
+                    # x64-on outer program (see _decode_kernel)
+                    s = jnp.where(live, s, jnp.float32(NEG_INF))
+                    m_prev = m_ref[h, rs, :]                 # (rows, 128)
+                    l_prev = l_ref[h, rs, :]
+                    m_new = jnp.maximum(
+                        m_prev, s.max(axis=-1, keepdims=True))
+                    p = jnp.exp(s - m_new[:, :1])
+                    alpha = jnp.exp(m_prev - m_new)
+                    l_ref[h, rs, :] = l_prev * alpha + p.sum(
+                        axis=-1, keepdims=True)
+                    m_ref[h, rs, :] = m_new
+                    if quantized:
+                        # a masked slot's scale may be anything
+                        p = jnp.where(live, p * vs, jnp.float32(0.0))
+                    pv = jax.lax.dot_general(
+                        p, v, (((1,), (0,)), ((), ())),
+                        preferred_element_type=jnp.float32)
+                    acc = acc_ref[ts, h].reshape(rows, d)
+                    acc_ref[ts, h] = (acc * alpha[:, :1] + pv).reshape(
+                        qc, g_pad, d)
+
+                chunk_loop(n_chunks, chunk)
+
+        jax.lax.fori_loop(0, n_groups, group, None)
+
+        def finish(c):
+            ts, rs = toks(c), band(c)
+            owned = (c * i32(qc) + jax.lax.broadcasted_iota(
+                i32, (rows, d), 0) // i32(g_pad)) < n_tok
+            for h in range(kv_h):
+                l = l_ref[h, rs, :][:, :1]
+                l_safe = jnp.where(l == 0.0, jnp.float32(1.0), l)
+                out = acc_ref[ts, h].reshape(rows, d) / l_safe
+                acc_ref[ts, h] = jnp.where(
+                    owned, out, jnp.float32(0.0)).reshape(qc, g_pad, d)
+            o_copy(c).start()
+
+        chunk_loop(n_chunks, finish)
+        chunk_loop(n_chunks, lambda c: o_copy(c).wait())
+
+    n_tiles = jnp.where(kv_len > 0, pl.cdiv(q_len, i32(q_tile)), i32(0))
+    jax.lax.fori_loop(0, n_tiles, lambda i, _: tile(i), None)
 
 
-def _ragged_call(q, k_cache, v_cache, block_tables, kv_lens, tok_lane,
-                 tok_pos, sm_scale, k_scale=None, v_scale=None):
-    """q: [T, KV_H, Gp, D] packed tokens; caches: [KV_H, NB, BS, D]
-    (int8 when the f32 scale planes [KV_H, NB, 1, BS] ride along)."""
+def _ragged_call(q, k_cache, v_cache, block_tables, kv_lens, q_lens,
+                 q_starts, sm_scale, tiles, mxu_dtype, k_scale=None,
+                 v_scale=None):
+    """q: f32 [T + chunk, KV_H, Gp, D] packed tokens; caches as stored,
+    [NB, KV_H, BS, D] (int8 when the per-lane f32 scale windows
+    [B, KV_H, groups, pages*BS] ride along). Returns f32, q's shape."""
     tokens, kv_h, g_pad, d = q.shape
     block_size = k_cache.shape[2]
-    max_blocks = block_tables.shape[1]
+    lanes = block_tables.shape[0]
+    pages, q_tile = tiles
+    rows = q_tile * g_pad
 
-    def page(*block):
-        return pl.BlockSpec(
-            (1, 1) + block,
-            lambda t, h, j, lens, tables, lane, pos:
-            (h, tables[lane[t], j], 0, 0))
-
-    def band():
-        return pl.BlockSpec((1, 1, g_pad, d),
-                            lambda t, h, j, lens, tables, lane, pos:
-                            (t, h, 0, 0))
-
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
     operands = [q, k_cache, v_cache]
-    in_specs = [band(), page(block_size, d), page(block_size, d)]
+    in_specs = [hbm, hbm, hbm]
     if k_scale is not None:
+        window = pl.BlockSpec(
+            (1,) + k_scale.shape[1:],
+            lambda b, lens, qlens, starts, tables: (b, 0, 0, 0))
         operands += [k_scale, v_scale]
-        in_specs += [page(1, block_size), page(1, block_size)]
+        in_specs += [window, window]
+    # the output buffer starts as zeros: guard rows are never written
+    operands.append(jnp.zeros(q.shape, jnp.float32))
+    in_specs.append(hbm)
+    page_buf = pltpu.VMEM((2, pages, kv_h, block_size, d), k_cache.dtype)
+    lm = pltpu.VMEM((kv_h, rows, 128), jnp.float32)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
-        grid=(tokens, kv_h, max_blocks),
+        grid=(lanes,),
         in_specs=in_specs,
-        out_specs=band(),
+        out_specs=hbm,
         scratch_shapes=[
-            pltpu.VMEM((g_pad, d), jnp.float32),
-            pltpu.VMEM((g_pad, 1), jnp.float32),
-            pltpu.VMEM((g_pad, 1), jnp.float32),
+            pltpu.VMEM((q_tile, kv_h, g_pad, d), jnp.float32),   # q tile
+            page_buf, page_buf,
+            pltpu.VMEM((q_tile, kv_h, g_pad, d), jnp.float32),   # acc
+            lm, lm,
+            pltpu.SemaphoreType.DMA((3, 2)),   # K, V pages by slot; q, out
         ],
     )
     return _support.pallas_call(
         functools.partial(_ragged_kernel, sm_scale=sm_scale,
-                          block_size=block_size),
+                          block_size=block_size, pages=pages, q_tile=q_tile,
+                          g_pad=g_pad, quantized=k_scale is not None,
+                          mxu_dtype=mxu_dtype),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((tokens, kv_h, g_pad, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct(q.shape, jnp.float32),
+        input_output_aliases={4 + len(operands) - 1: 0},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
         name="paged_attention_ragged",
         interpret=_support.interpret_mode(),
-    )(kv_lens, block_tables, tok_lane, tok_pos, *operands)
+    )(kv_lens, q_lens, q_starts, block_tables, *operands)
 
 
 def ragged_metadata(q_lens, kv_lens, num_tokens):
@@ -430,49 +578,80 @@ def paged_attention_ragged(q, k_cache, v_cache, block_tables, kv_lens,
 
     ONE kernel for every serving batch composition: decode lanes
     (q_len 1), prefill chunks (q_len n), and speculative verify windows
-    (q_len K+1) share this fixed-shape dispatch — the grid depends only
-    on the packed token budget T, never on the batch composition, so the
-    serving steady state holds exactly one compiled executable.
+    (q_len K+1) share this fixed-shape dispatch — the grid is the lane
+    count and every buffer is sized by static shapes (`_ragged_tiles`),
+    never by the batch composition, so the serving steady state holds
+    exactly one compiled executable. Its work follows, per lane, live
+    pages x query tokens: the table's width and the guard slots of the
+    packed buffer cost nothing (see `_ragged_kernel`).
 
     Args:
-      q: [T, H, D] — packed query tokens (lane-major, see
-         `ragged_metadata`).
-      k_cache/v_cache: [num_blocks, kv_heads, block_size, head_dim].
-      block_tables: [B, W] int32 physical block ids per lane.
+      q: [T, H, D] — packed query tokens, lane-major as
+         `ragged_metadata` packs them: lane i owns the contiguous slots
+         after lane i-1's, its tokens at consecutive positions ending at
+         kv_lens[i] - 1.
+      k_cache/v_cache: [num_blocks, kv_heads, block_size, head_dim], read
+         as stored.
+      block_tables: [B, W] int32 physical block ids per lane; entries
+         past a lane's live pages are never read.
       kv_lens: [B] int32 — tokens in cache per lane INCLUDING this
          dispatch's own tokens (0 for empty lanes).
       tok_lane/tok_pos: [T] int32 per-token owner lane / absolute
-         position (-1 = guard slot, output forced to 0).
+         position (-1 = guard slot, output forced to 0); the kernel
+         takes each lane's (q_start, q_len) from them.
       k_scale/v_scale: optional f32 [num_blocks, kv_heads, block_size]
          per-slot scale planes for int8 quantized caches
          (`inference/kv_quant.py`): dequantization then happens inside
-         the kernel body, right before the MXU.
+         the kernel body, on the score tile.
     Returns [T, H, D]; guard rows are exact zeros.
     """
     tokens, h, d = q.shape
-    kv_h = k_cache.shape[1]
+    _, kv_h, block_size, _ = k_cache.shape
+    lanes, width = block_tables.shape
     g = h // kv_h
     if sm_scale is None:
         sm_scale = 1.0 / float(np.sqrt(d))
-    g_pad = g if g % 8 == 0 else (g // 8 + 1) * 8
-    qg = q.reshape(tokens, kv_h, g, d)
-    if g_pad != g:
-        qg = jnp.pad(qg, ((0, 0), (0, 0), (0, g_pad - g), (0, 0)))
-    kc = jnp.swapaxes(k_cache, 0, 1)  # [KV_H, NB, BS, D]
-    vc = jnp.swapaxes(v_cache, 0, 1)
+    g_pad = _group_pad(g)
+    tiles = _ragged_tiles(tokens, kv_h, g_pad, d, block_size, width,
+                          k_cache.dtype.itemsize)
+    if tiles is None:
+        raise ValueError(
+            f"paged_attention_ragged: no tile of {kv_h} kv heads x {g_pad} "
+            f"rows x {d} fits VMEM; ask ragged_supported first")
+    pages, _ = tiles
+    # q crosses into the kernel as f32 [T + chunk, KV_H, Gp, D]: a token's
+    # head-group band is then whole (8, 128) tiles, so a chunk of tokens
+    # folds into MXU rows without a relayout; the spare chunk is what the
+    # last live chunk's DMA may run over
+    qg = q.reshape(tokens, kv_h, g, d).astype(jnp.float32)
+    qg = jnp.pad(qg, ((0, _RAGGED_Q_CHUNK), (0, 0), (0, g_pad - g), (0, 0)))
+    tok_lane = tok_lane.astype(jnp.int32)
+    q_lens = jnp.zeros((lanes,), jnp.int32).at[tok_lane].add(
+        (tok_pos >= 0).astype(jnp.int32))
+    q_starts = jnp.cumsum(q_lens) - q_lens
+    block_tables = block_tables.astype(jnp.int32)
     if k_scale is not None:
-        # [NB, KV_H, BS] -> [KV_H, NB, 1, BS]: each page's scales become a
-        # (1, BS) row, the one block shape Mosaic accepts for them (a
-        # (1, BS) block of a [.., NB, BS] plane is neither (8, 128)-
-        # divisible nor the array's own last two dims)
-        k_scale = jnp.swapaxes(k_scale, 0, 1)[:, :, None, :]
-        v_scale = jnp.swapaxes(v_scale, 0, 1)[:, :, None, :]
-    out = _ragged_call(qg, kc, vc, block_tables.astype(jnp.int32),
-                       kv_lens.astype(jnp.int32),
-                       tok_lane.astype(jnp.int32),
-                       tok_pos.astype(jnp.int32), float(sm_scale),
+        # the lane's scale rows in logical order, [B, KV_H, groups,
+        # pages*BS]: one (1, pages*BS) row per page group lies along the
+        # score tile's lanes, which no in-kernel gather of (KV_H, BS)
+        # pieces could give without a relayout. A table-wide gather, but
+        # of planes 1/D the pool's size.
+        groups = -(-width // pages)
+        padded = jnp.pad(block_tables, ((0, 0), (0, groups * pages - width)))
+
+        def window(scale):
+            return jnp.swapaxes(jnp.take(scale, padded, axis=0), 1, 2) \
+                .reshape(lanes, kv_h, groups, pages * block_size)
+
+        k_scale, v_scale = window(k_scale), window(v_scale)
+    exact_bf16 = q.dtype == jnp.bfloat16 and k_cache.dtype in (
+        jnp.bfloat16, jnp.int8)
+    out = _ragged_call(qg, k_cache, v_cache, block_tables,
+                       kv_lens.astype(jnp.int32), q_lens, q_starts,
+                       float(sm_scale), tiles,
+                       jnp.bfloat16 if exact_bf16 else jnp.float32,
                        k_scale, v_scale)
-    return out[:, :, :g, :].reshape(tokens, h, d)
+    return out[:tokens, :, :g, :].reshape(tokens, h, d).astype(q.dtype)
 
 
 # above this many packed tokens the ref tiles its per-token window
@@ -690,14 +869,25 @@ def verify_supported(q_shape, dtype) -> bool:
     return _support.float_dtype_ok(dtype)
 
 
-def ragged_supported(q_shape, dtype) -> bool:
-    """Gate for `paged_attention_ragged` (q: [T, H, D]). The per-tile
-    VMEM footprint is one token's head-group band — independent of T —
-    so only the head dim and dtype gate."""
+def ragged_supported(q_shape, dtype, cache_shape, cache_dtype,
+                     table_width) -> bool:
+    """Gate for `paged_attention_ragged` (q: [T, H, D]; `cache_shape` the
+    pool's [NB, KVH, BS, D]). The kernel's VMEM footprint is the page
+    double buffer — 2 slots x K and V x `pages` pages of all kv heads —
+    plus one query tile's f32 q and acc rows and lane-replicated m/l rows
+    (`_ragged_tiles`); it does not grow with T or the table's width. A
+    shape whose smallest tile (one 8-token chunk) does not fit beside the
+    page buffer is refused, besides the head dim and dtype."""
     if not _support.kernels_enabled():
         return False
     if len(q_shape) != 3:
         return False
     if q_shape[-1] > 256:
         return False
-    return _support.float_dtype_ok(dtype)
+    if not _support.float_dtype_ok(dtype):
+        return False
+    _, kv_h, block_size, d = cache_shape
+    g = q_shape[1] // kv_h
+    return _ragged_tiles(q_shape[0], kv_h, _group_pad(g), d, block_size,
+                         table_width, np.dtype(cache_dtype).itemsize) \
+        is not None

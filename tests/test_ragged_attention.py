@@ -9,6 +9,7 @@ Kernels run through the Pallas interpreter on CPU (FLAGS_pallas_interpret)
 import numpy as np
 import pytest
 
+import jax
 import jax.numpy as jnp
 
 from paddle_tpu.framework import flags
@@ -102,8 +103,9 @@ class TestRaggedKernelParity:
         assert float(np.abs(np.asarray(ref)[guard]).max()) == 0.0
 
     def test_decode_composition_matches_legacy_decode_kernel(self):
-        """A pure decode batch through the ragged kernel is bitwise the
-        legacy single-query decode kernel."""
+        """A pure decode batch through the ragged kernel is the legacy
+        single-query decode kernel to float rounding (the ragged kernel
+        folds several pages into one online-softmax step)."""
         rng = np.random.default_rng(4)
         kc, vc = _pool(rng)
         tables = jnp.asarray([[1, 2, 3], [4, 5, 6]], jnp.int32)
@@ -113,12 +115,13 @@ class TestRaggedKernelParity:
         out = pa.paged_attention_ragged(q, kc, vc, tables, kv_lens,
                                         lane, pos)
         legacy = pa.paged_attention(q, kc, vc, tables, kv_lens)
-        np.testing.assert_array_equal(np.asarray(out), np.asarray(legacy))
+        np.testing.assert_allclose(np.asarray(out), np.asarray(legacy),
+                                   atol=1e-6, rtol=1e-5)
 
     def test_verify_composition_matches_legacy_verify_kernel(self):
-        """A fixed q_len == S batch through the ragged kernel is bitwise
-        the legacy multi-query verify kernel — verify_step really is a
-        special case of the one kernel."""
+        """A fixed q_len == S batch through the ragged kernel is the
+        legacy multi-query verify kernel to float rounding — verify_step
+        really is a special case of the one kernel."""
         rng = np.random.default_rng(5)
         kc, vc = _pool(rng)
         tables = jnp.asarray([[1, 2, 3], [4, 5, 6]], jnp.int32)
@@ -129,8 +132,8 @@ class TestRaggedKernelParity:
         out = pa.paged_attention_ragged(qb.reshape(2 * s, 4, 32), kc, vc,
                                         tables, kv_lens, lane, pos)
         legacy = pa.paged_attention_verify(qb, kc, vc, tables, kv_lens)
-        np.testing.assert_array_equal(np.asarray(out).reshape(2, s, 4, 32),
-                                      np.asarray(legacy))
+        np.testing.assert_allclose(np.asarray(out).reshape(2, s, 4, 32),
+                                   np.asarray(legacy), atol=1e-6, rtol=1e-5)
 
     def test_chunk_at_block_boundaries(self):
         """q_len landing exactly on / one past a block boundary, and a
@@ -140,17 +143,174 @@ class TestRaggedKernelParity:
         tables = jnp.asarray([[1, 2, 3, 4]], jnp.int32)
         for q_len, kv_len in ((4, 4), (4, 8), (5, 8), (3, 11), (1, 12),
                               (8, 16), (7, 15)):
-            t = q_len + 2                      # +2 guard slots
+            t = 10                             # 2-9 guard slots
             lane, pos = pa.ragged_metadata(
                 jnp.asarray([q_len]), jnp.asarray([kv_len]), t)
             q = jnp.asarray(rng.normal(size=(t, 4, 32)), jnp.float32)
-            out = pa.paged_attention_ragged(
-                q, kc, vc, tables, jnp.asarray([kv_len]), lane, pos)
-            ref = pa.paged_attention_ragged_ref(
-                q, kc, vc, tables, jnp.asarray([kv_len]), lane, pos)
+            out = _ragged(q, kc, vc, tables, jnp.asarray([kv_len]),
+                          lane, pos)
+            ref = _ragged_ref(q, kc, vc, tables, jnp.asarray([kv_len]),
+                              lane, pos)
             np.testing.assert_allclose(
                 np.asarray(out), np.asarray(ref), atol=2e-5, rtol=2e-4,
                 err_msg=f"q_len={q_len} kv_len={kv_len}")
+
+
+# jitted once: cases that share a shape share one interpreted executable
+# (an eager call would trace and compile the kernel anew every time)
+_ragged = jax.jit(pa.paged_attention_ragged)
+_ragged_ref = jax.jit(pa.paged_attention_ragged_ref)
+
+
+def _live_case(seed, q_lens, kv_lens, t, *, kvh=2, h=8, bs=16, d=32,
+               w=128, nb=48, dtype=jnp.float32, quant=False, dead=0.0):
+    """A pool whose every block NOT among a lane's live pages — and so
+    every table entry past a lane's kv_len, which point at two such blocks
+    a lane — is filled with `dead`; live pages are normal draws. Returns
+    the kernel's positional args and its scale kwargs."""
+    from paddle_tpu.inference import kv_quant
+
+    rng = np.random.default_rng(seed)
+    b = len(q_lens)
+    live = [-(-kv // bs) for kv in kv_lens]
+    assert 1 + sum(live) + 2 * b <= nb
+    ids = rng.permutation(np.arange(1, nb))
+    tables = np.zeros((b, w), np.int32)
+    is_live = np.zeros(nb, bool)
+    at = 0
+    for i, n in enumerate(live):
+        tables[i, :n] = ids[at:at + n]
+        is_live[ids[at:at + n]] = True
+        tables[i, n:] = np.resize(ids[at + n:at + n + 2], w - n)
+        at += n + 2
+    k = rng.normal(size=(nb, kvh, bs, d)).astype(np.float32)
+    v = rng.normal(size=(nb, kvh, bs, d)).astype(np.float32)
+    kw = {}
+    if quant:
+        kc, ks = kv_quant.quantize_kv(jnp.asarray(k))
+        vc, vs = kv_quant.quantize_kv(jnp.asarray(v))
+        ks, vs = np.array(ks), np.array(vs)
+        ks[~is_live] = dead
+        vs[~is_live] = dead
+        kw = dict(k_scale=jnp.asarray(ks), v_scale=jnp.asarray(vs))
+    else:
+        k[~is_live] = dead
+        v[~is_live] = dead
+        kc, vc = jnp.asarray(k, dtype), jnp.asarray(v, dtype)
+    lane, pos = pa.ragged_metadata(jnp.asarray(q_lens, jnp.int32),
+                                   jnp.asarray(kv_lens, jnp.int32), t)
+    q = jnp.asarray(rng.normal(size=(t, h, d)), dtype)
+    return (q, kc, vc, jnp.asarray(tables),
+            jnp.asarray(kv_lens, jnp.int32), lane, pos), kw
+
+
+# What the lane-by-lane grid makes new, as (q_lens, kv_lens) of ONE shape:
+# 5 lanes, 80 packed slots (query tiles of 64, compute chunks of 8), a
+# table 128 wide against 1-14 live pages of 16, GQA 4:1.
+_LIVE_T = 80
+_LIVE_CASES = {
+    "wide_table_live_1_to_14": ([1, 1, 5, 1, 0], [3, 224, 100, 16, 0]),
+    # kv_len on and one past a page boundary, decode lanes and chunks
+    "page_boundary_decode": ([1, 1, 1, 1, 1], [16, 17, 32, 33, 48]),
+    "page_boundary_chunk": ([16, 17, 0, 0, 0], [32, 33, 0, 0, 0]),
+    # a 64-token chunk behind prior context (the prefix-hit first step)
+    "chunk64_with_prior_context": ([64, 1, 0, 0, 1], [100, 7, 0, 0, 40]),
+    # a lane longer than the 64-token query tile; lanes that straddle the
+    # 8-token chunks with empty lanes between; an all-guard tail
+    "straddles_query_tile": ([70, 0, 3, 0, 0], [75, 0, 3, 0, 0]),
+    "straddles_chunks_empty_middle": ([13, 0, 3, 0, 9], [29, 0, 3, 0, 40]),
+    "all_guard_tail": ([2, 1, 0, 0, 0], [18, 5, 0, 0, 0]),
+    "all_lanes_empty": ([0, 0, 0, 0, 0], [0, 0, 0, 0, 0]),
+}
+# other head groupings and page geometries: (q_lens, kv_lens, T, shape)
+_SHAPE_CASES = {
+    "mha_g1_pads_to_8": ([9, 1, 1], [25, 16, 40], 16, dict(kvh=4, h=4)),
+    "one_kv_head": ([9, 1, 1], [25, 16, 40], 16, dict(kvh=1, h=4)),
+    "group_of_8": ([9, 1], [25, 16], 16, dict(kvh=1, h=8)),
+    # fewer pages in the table than one 128-column group holds
+    "narrow_table": ([4, 1], [20, 33], 8, dict(w=3)),
+    "block_size_4": ([6, 1, 3], [9, 11, 13], 16, dict(bs=4, w=8)),
+}
+_KINDS = {"f32": (jnp.float32, False), "bf16": (jnp.bfloat16, False),
+          "int8kv": (jnp.bfloat16, True)}
+
+
+def _assert_matches_ref(args, kw, dtype):
+    out, ref = _ragged(*args, **kw), _ragged_ref(*args, **kw)
+    assert out.dtype == ref.dtype == dtype
+    tol = dict(atol=2e-5, rtol=2e-4) if dtype == jnp.float32 else \
+        dict(atol=2e-2, rtol=2e-2)
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(ref, np.float32), **tol)
+    guard = np.asarray(args[-1]) < 0
+    assert float(np.abs(np.asarray(out, np.float32))[guard].sum()) == 0.0
+
+
+class TestRaggedLiveWork:
+    """ISSUE 26: the kernel walks each lane's live pages and live tokens
+    only, whatever the table's width and the packed buffer's guard slots."""
+
+    @pytest.mark.parametrize("name", sorted(_LIVE_CASES))
+    @pytest.mark.parametrize("kind", sorted(_KINDS))
+    def test_matches_ref(self, name, kind):
+        dtype, quant = _KINDS[kind]
+        args, kw = _live_case(11, *_LIVE_CASES[name], _LIVE_T, dtype=dtype,
+                              quant=quant)
+        _assert_matches_ref(args, kw, dtype)
+
+    @pytest.mark.parametrize("name,kind",
+                             [(n, "f32") for n in sorted(_SHAPE_CASES)]
+                             + [("mha_g1_pads_to_8", "bf16"),
+                                ("mha_g1_pads_to_8", "int8kv")])
+    def test_matches_ref_other_shapes(self, name, kind):
+        dtype, quant = _KINDS[kind]
+        q_lens, kv_lens, t, shape = _SHAPE_CASES[name]
+        args, kw = _live_case(14, q_lens, kv_lens, t, dtype=dtype,
+                              quant=quant, **shape)
+        _assert_matches_ref(args, kw, dtype)
+
+    @pytest.mark.parametrize("kind", sorted(_KINDS))
+    @pytest.mark.parametrize("dead", [np.nan, np.inf])
+    def test_dead_pages_are_never_read(self, kind, dead):
+        """Every page past each lane's kv_len, and every block no lane
+        owns, poisoned: the output is finite and is what a clean pool
+        gives, bit for bit — nothing of a dead page enters the sums."""
+        dtype, quant = _KINDS[kind]
+        outs = []
+        for fill in (0.0, dead):
+            args, kw = _live_case(12, [1, 12, 0, 1, 3], [35, 44, 0, 16, 224],
+                                  _LIVE_T, dtype=dtype, quant=quant,
+                                  dead=fill)
+            outs.append(np.asarray(_ragged(*args, **kw), np.float32))
+        assert np.isfinite(outs[1]).all()
+        np.testing.assert_array_equal(outs[0], outs[1])
+
+    def test_grid_is_the_lane_count(self):
+        """The work bound: the `pallas_call`'s static grid is one step a
+        lane — no axis, and no product of axes, follows the packed token
+        budget T or the table's width, let alone T x max_blocks."""
+        args, _ = _live_case(13, *_LIVE_CASES["straddles_query_tile"],
+                             _LIVE_T)
+        jaxpr = jax.make_jaxpr(pa.paged_attention_ragged)(*args)
+        calls = [e for e in jaxpr.jaxpr.eqns
+                 if e.primitive.name == "pallas_call"]
+        assert len(calls) == 1
+        assert tuple(calls[0].params["grid_mapping"].grid) == (5,)
+        assert calls[0].params["name"] == "paged_attention_ragged"
+
+    def test_gate_refuses_a_tile_that_cannot_fit(self):
+        """`ragged_supported` derives the tile the kernel would run: the
+        cells' shape gets 8 pages x 64 tokens, the smoke's 32 kv heads 8
+        tokens, and one 8-token chunk of 512 kv heads x 8 rows is over
+        the VMEM budget, so the gate sends it to the XLA reference."""
+        assert pa._ragged_tiles(96, 8, 8, 128, 16, 128, 2) == (8, 64)
+        assert pa._ragged_tiles(72, 32, 8, 128, 16, 128, 2) == (8, 8)
+        assert pa._ragged_tiles(96, 512, 8, 128, 16, 128, 2) is None
+        bf16 = jnp.bfloat16
+        assert pa.ragged_supported((96, 32, 128), bf16, (64, 8, 16, 128),
+                                   bf16, 128)
+        assert not pa.ragged_supported((96, 512, 128), bf16,
+                                       (64, 512, 16, 128), bf16, 128)
 
 
 class TestRaggedWrite:
