@@ -1,0 +1,240 @@
+"""Serving engine for the DeepSeek-V3 architecture (`models/deepseek_v3.py`):
+an `EngineCore` whose paged pool holds ONE latent row a token a layer.
+
+- The weights are the model's own pytree, taken by reference in the layout
+  they are served in (routed experts stacked `[E, in, out]`): nothing is
+  stacked, concatenated or cast at build, so weights + pool are all the
+  device holds.
+- The pool is one donated array `[L, NB, BS, row]`, written in place; the
+  layer is an index. It never enters a `lax.scan` as per-layer inputs and
+  stacked outputs (what costs the Llama engine 84 % of its step, PERF.md):
+  the layers are unrolled and each scatters into, and reads from, the one
+  buffer. `row` is the latent width rounded up to whole 128-lane tiles
+  (576 -> 640, zero columns; `ops/pallas/paged_attention_mla.py` says why).
+- `ragged_step` is the one compiled step, `verify_step` a case of it; the
+  legacy `prefill` / `decode_step` / `generate` raise, as `ShardedEngine`'s
+  do. Guard slots (`q_len` 0) write nothing and reach no expert.
+- Expert load is counted inside the step, on the device, in donated
+  counters: no host fetch a step. `expert_load()` reads them.
+
+The engine transforms (`quantize_engine`, `shard_engine`, `attach_adapters`)
+look for a Llama or an MLP parameter layout and refuse this engine by its
+name; KV migration is refused here, by family.
+"""
+from __future__ import annotations
+
+import functools
+from typing import Dict
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from ..framework import monitor
+from ..models import deepseek_v3 as dsv3
+from ..ops.pallas import paged_attention_mla as pm
+from ..ops.pallas.paged_attention import ragged_metadata
+from . import kv_migrate
+from .cache import BlockCacheManager
+
+__all__ = ["DeepseekV3InferenceEngine"]
+
+FAMILY = "deepseek_v3"
+
+
+def _ragged_stack(params, pool, counters, tokens, q_lens, kv_lens, tables,
+                  *, cfg: dsv3.DeepseekV3Config):
+    """Packed tokens `[T]` + per-lane `(q_len, kv_len)` through the decoder:
+    `(logits [T, V] float32, pool, counters)`."""
+    t = tokens.shape[0]
+    nb, bs, row = pool.shape[1:]
+    rank = cfg.kv_lora_rank
+    kv_lens = kv_lens.astype(jnp.int32)
+    tables = tables.astype(jnp.int32)
+    tok_lane, tok_pos = ragged_metadata(q_lens, kv_lens, t)
+    live = tok_pos >= 0
+    pos = jnp.maximum(tok_pos, 0)
+    # a guard slot's row goes to a block past the pool: the scatter drops it
+    blk = jnp.where(live, tables[tok_lane, pos // bs], jnp.int32(nb))
+    off = pos % bs
+    with jax.named_scope("llama.rope"):
+        cos = jnp.take(params["rope_cos"], pos, axis=0)
+        sin = jnp.take(params["rope_sin"], pos, axis=0)
+    with jax.named_scope("llama.embed"):
+        x = jnp.take(params["model.embed_tokens.weight"], tokens, axis=0)
+
+    def attend_layer(i):
+        def attend(q_abs, rows):
+            nonlocal pool
+            with jax.named_scope("llama.kv_write"):
+                rows = jnp.pad(rows.astype(pool.dtype),
+                               ((0, 0), (0, row - rows.shape[-1])))
+                pool = pool.at[i, blk, off].set(rows, mode="drop")
+            with jax.named_scope("llama.attn"):
+                kernel = pm.paged_attention_mla if pm.mla_supported(
+                    q_abs.shape, pool.shape, pool.dtype, tables.shape[1],
+                    rank) else pm.paged_attention_mla_ref
+                return kernel(q_abs, pool, jnp.int32(i), tables, kv_lens,
+                              tok_lane, tok_pos, rank,
+                              cfg.qk_head_dim ** -0.5)
+        return attend
+
+    sizes = []
+    for i in range(cfg.num_hidden_layers):
+        x, n = dsv3.decoder_layer(x, dsv3.layer_params(params, i), cfg, cos,
+                                  sin, attend_layer(i), live)
+        sizes.append(jnp.zeros((cfg.n_routed_experts,), jnp.int32)
+                     if n is None else n)
+    sizes = jnp.stack(sizes)                                     # [L, E]
+    counters = {
+        "tokens": counters["tokens"] + sizes,
+        "touched": counters["touched"] + jnp.sum(sizes > 0, axis=1,
+                                                 dtype=jnp.int32),
+        "steps": counters["steps"] + 1,
+    }
+    return dsv3.head(x, params, cfg), pool, counters
+
+
+def _ragged_fn(params, pool, counters, tokens, q_lens, kv_lens, tables, *,
+               cfg):
+    # trace-time only, as the Llama engine's: the ragged step IS the serving
+    # decode program, and ragged_retraces pins "one executable whatever the
+    # batch's composition"
+    monitor.inc("serving.decode_retraces")
+    monitor.inc("serving.ragged_retraces")
+    return _ragged_stack(params, pool, counters, tokens, q_lens, kv_lens,
+                         tables, cfg=cfg)
+
+
+def _verify_fn(params, pool, counters, tokens, ctx_lens, tables, *, cfg):
+    """Speculative verify as a case of the ragged step: every lane a fixed
+    window of S tokens; logits fold back to `[B, S, V]`."""
+    monitor.inc("serving.verify_retraces")        # trace-time only
+    b, s = tokens.shape
+    logits, pool, counters = _ragged_stack(
+        params, pool, counters, tokens.reshape(b * s),
+        jnp.full((b,), s, jnp.int32), ctx_lens, tables, cfg=cfg)
+    return logits.reshape(b, s, -1), pool, counters
+
+
+class DeepseekV3InferenceEngine:
+    """`EngineCore` over `DeepseekV3ForCausalLM` with a paged latent cache.
+    Serves in the dtype the model's weights have."""
+
+    def __init__(self, model: dsv3.DeepseekV3ForCausalLM,
+                 max_batch_size: int = 8, num_blocks: int = 256,
+                 block_size: int = 16, max_blocks_per_seq: int = 16):
+        cfg = model.config
+        self.config = cfg
+        self.block_size = block_size
+        self.max_batch_size = max_batch_size
+        self.manager = BlockCacheManager(num_blocks, block_size,
+                                         max_blocks_per_seq)
+        cos, sin = dsv3.rope_tables(cfg, max_blocks_per_seq * block_size)
+        # the model's own arrays, by reference, beside the rope tables
+        self.params: Dict[str, jax.Array] = dict(
+            model.weight_tree(), rope_cos=cos, rope_sin=sin)
+        cdtype = self.params["model.embed_tokens.weight"].dtype
+        L, e = cfg.num_hidden_layers, cfg.n_routed_experts
+        self.row_width = -(-cfg.latent_dim // 128) * 128
+        self.pool = jnp.zeros((L, num_blocks, block_size, self.row_width),
+                              cdtype)
+        self.counters = {"tokens": jnp.zeros((L, e), jnp.int32),
+                         "touched": jnp.zeros((L,), jnp.int32),
+                         "steps": jnp.zeros((), jnp.int32)}
+        self._row_bytes = self.row_width * jnp.dtype(cdtype).itemsize
+        self.manager.set_kv_geometry(L * block_size * self._row_bytes, 16)
+
+        def step(fn):
+            bound = functools.partial(fn, cfg=cfg)
+            bound.__name__ = fn.__name__           # the XLA module's name
+            return jax.jit(bound, donate_argnums=(1, 2))
+
+        self._ragged = step(_ragged_fn)
+        self._verify = step(_verify_fn)
+        # COW copy (prefix caching): one latent block, every layer, donated;
+        # src/dst trace as scalars, so COWs never recompile
+        self._copy_block = jax.jit(
+            lambda p, s, d: p.at[:, d].set(p[:, s]), donate_argnums=(0,))
+
+    # ---- the EngineCore dispatch surface ----
+    def ragged_step(self, tokens: np.ndarray, q_lens: np.ndarray,
+                    kv_lens: np.ndarray, block_tables: np.ndarray):
+        """ONE fixed-shape step over a packed ragged batch (see
+        `EngineCore.ragged_step`): logits `[T, V]` float32."""
+        logits, self.pool, self.counters = self._ragged(
+            self.params, self.pool, self.counters,
+            np.asarray(tokens, np.int32), np.asarray(q_lens, np.int32),
+            np.asarray(kv_lens, np.int32), np.asarray(block_tables, np.int32))
+        return logits
+
+    def verify_step(self, tokens: np.ndarray, context_lens: np.ndarray,
+                    block_tables: np.ndarray):
+        """Multi-token verify (see `EngineCore.verify_step`): `[B, S, V]`."""
+        logits, self.pool, self.counters = self._verify(
+            self.params, self.pool, self.counters,
+            np.asarray(tokens, np.int32), np.asarray(context_lens, np.int32),
+            np.asarray(block_tables, np.int32))
+        return logits
+
+    def _no_legacy(self, entry: str):
+        raise RuntimeError(
+            f"{entry} is a legacy entry point; a {FAMILY} engine serves "
+            "through ragged_step/verify_step (the scheduler's only "
+            "dispatches)")
+
+    def prefill(self, *a, **k):
+        self._no_legacy("prefill")
+
+    def decode_step(self, *a, **k):
+        self._no_legacy("decode_step")
+
+    def generate(self, *a, **k):
+        self._no_legacy("generate")
+
+    # ---- hooks the scheduler and the cache manager look for ----
+    def copy_kv_block(self, src: int, dst: int) -> None:
+        """Copy one physical latent block, all layers (the manager's COW
+        hook when prefix caching is on)."""
+        self.pool = self._copy_block(self.pool, np.int32(src), np.int32(dst))
+
+    def kv_bytes_per_token(self) -> float:
+        """HBM bytes one cached token costs across all layers: one latent
+        row a layer, as stored."""
+        return float(self.config.num_hidden_layers * self._row_bytes)
+
+    def quant_info(self) -> dict:
+        """What `serving.quant.*` and `serving.kv_bytes_per_token` publish."""
+        return {"wbits": 16, "kv_bits": 16,
+                "kv_bytes_per_token": self.kv_bytes_per_token()}
+
+    def cost_card_args(self, phase: str):
+        fn = {"decode": self._ragged, "ragged": self._ragged,
+              "verify": self._verify}[phase]
+        return fn, (self.params, self.pool, self.counters)
+
+    def extract_kv_blocks(self, seq_id: int):
+        raise kv_migrate.KVMigrationError(
+            f"{FAMILY}: a latent (MLA) cache has no migration payload yet")
+
+    def inject_kv_blocks(self, seq_id: int, payload) -> None:
+        raise kv_migrate.KVMigrationError(
+            f"{FAMILY}: a latent (MLA) cache has no migration payload yet")
+
+    # ---- expert load ----
+    def expert_load(self) -> dict:
+        """The counters the step keeps on the device, fetched now: `tokens
+        [L, E]` routed to each expert since the engine was built, `touched
+        [L]` experts with at least one token summed over steps, `steps`.
+        Publishes `serving.moe.expert_tokens` (their sum) and the gauge
+        `serving.moe.load_max_over_mean` (busiest expert of an expert layer
+        against the mean one)."""
+        c = jax.device_get(self.counters)
+        tokens = np.asarray(c["tokens"], np.int64)
+        moe = tokens[self.config.first_k_dense_replace:]
+        monitor.set_value("serving.moe.expert_tokens", int(moe.sum()))
+        if moe.sum():
+            monitor.set_gauge("serving.moe.load_max_over_mean",
+                              round(float(moe.max() / moe.mean()), 3))
+        return {"tokens": tokens, "touched": np.asarray(c["touched"], np.int64),
+                "steps": int(c["steps"])}

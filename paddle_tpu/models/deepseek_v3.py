@@ -1,0 +1,419 @@
+"""DeepSeek-V3-architecture causal LM (`model_type: deepseek_v3`): latent
+attention (MLA) and a sigmoid-routed mixture of experts with shared experts.
+
+Written ONCE, as functions over a weight pytree (HuggingFace parameter names,
+linear weights `[in, out]`, the routed experts stacked on a leading axis:
+`mlp.experts.{gate,up,down}_proj.weight [E, in, out]`). The serving engine
+(`inference/deepseek_v3_runner.py`) runs `decoder_layer` with an `attend`
+that writes and reads its paged latent cache; `DeepseekV3ForCausalLM` is a
+thin holder of the pytree whose `forward` runs the same `decoder_layer` with
+a dense causal `attend` over the rows in flight. Nothing here is imported by
+`paddle_tpu` or by the Llama serving path.
+
+Equations, per token row x (published DeepSeek-V3 ones; `q_lora_rank` null):
+
+- MLA: `q = x W_q -> [heads, nope + rope]`; `a = x W_kva -> [rank + rope]`,
+  `c = RMSNorm(a[:rank])`, `k_rope = a[rank:]` shared by every head; RoPE on
+  `q_rope`, `k_rope` (`rope_interleave`: pairs de-interleaved, then the
+  rotate-half form). Served in the ABSORBED form: `W_kvb` split by head into
+  `W_UK, W_UV`; `q_lat = q_nope W_UK^T`; score `(q_lat . c + q_rope . k_rope)
+  * (nope + rope)^-0.5`; `o_lat = softmax . c`; `o = o_lat W_UV`. The cache
+  row of a token is `[c | rotated k_rope]`: key, and in its first `rank`
+  columns value.
+- Expert layer: `s = sigmoid(x W_g)` in float32; top-k of `s + bias`
+  (`n_group` 1: no group limit); weights `s[chosen] / (sum + 1e-20) *
+  routed_scaling_factor`; routed SwiGLU experts without capacity or drops
+  (tokens sorted by expert, a grouped matmul) plus one shared SwiGLU of
+  width `n_shared_experts * moe_intermediate_size` on every token. The first
+  `first_k_dense_replace` layers carry a dense SwiGLU instead.
+
+Device regions keep the one family of names `benchmark/program_trace.py` and
+docs/OBSERVABILITY.md read (`llama.*`), with `llama.mla_*` and `llama.moe*`
+for what is new.
+"""
+from __future__ import annotations
+
+import dataclasses
+from typing import Callable, Dict, Optional, Tuple
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from .. import nn
+from ..nn.parameter import Parameter
+from ..ops.pallas import grouped_matmul
+
+__all__ = ["DeepseekV3Config", "DeepseekV3ForCausalLM", "param_shapes",
+           "init_params", "decoder_layer", "model_forward", "rope_tables"]
+
+_scope = jax.named_scope
+
+
+@dataclasses.dataclass(frozen=True)
+class DeepseekV3Config:
+    """The published `config.json` keys this architecture reads. Frozen and
+    hashable: it is a static argument of the compiled step."""
+    vocab_size: int = 129280
+    hidden_size: int = 7168
+    intermediate_size: int = 18432
+    moe_intermediate_size: int = 2048
+    num_hidden_layers: int = 61
+    num_attention_heads: int = 128
+    kv_lora_rank: int = 512
+    qk_nope_head_dim: int = 128
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 128
+    n_routed_experts: int = 256
+    n_shared_experts: int = 1
+    num_experts_per_tok: int = 8
+    first_k_dense_replace: int = 3
+    routed_scaling_factor: float = 2.5
+    norm_topk_prob: bool = True
+    rms_norm_eps: float = 1e-6
+    rope_theta: float = 10000.0
+    rope_interleave: bool = True
+    max_position_embeddings: int = 4096
+
+    @property
+    def qk_head_dim(self) -> int:
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+    @property
+    def latent_dim(self) -> int:
+        """Numbers in one token's cache row of one layer: `[c | k_rope]`."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+    @classmethod
+    def from_hf(cls, cfg: dict) -> "DeepseekV3Config":
+        """From a published `config.json`. What this implementation does
+        not compute is refused here rather than silently dropped."""
+        refused = {
+            "q_lora_rank": cfg.get("q_lora_rank") is not None,
+            "rope_scaling": cfg.get("rope_scaling") is not None,
+            "n_group": cfg.get("n_group", 1) != 1,
+            "topk_group": cfg.get("topk_group", 1) != 1,
+            "scoring_func": cfg.get("scoring_func", "sigmoid") != "sigmoid",
+            "attention_bias": bool(cfg.get("attention_bias", False)),
+            "tie_word_embeddings": bool(cfg.get("tie_word_embeddings", False)),
+            "moe_layer_freq": cfg.get("moe_layer_freq", 1) != 1,
+            "hidden_act": cfg.get("hidden_act", "silu") != "silu",
+        }
+        bad = sorted(k for k, v in refused.items() if v)
+        if bad:
+            raise ValueError(
+                f"deepseek_v3: config keys {bad} ask for a mechanism this "
+                "implementation does not have")
+        names = {f.name for f in dataclasses.fields(cls)}
+        return cls(**{k: v for k, v in cfg.items() if k in names})
+
+
+# --- the weight pytree ---------------------------------------------------------
+
+def layer_shapes(cfg: DeepseekV3Config, i: int) -> Dict[str, Tuple[tuple, str]]:
+    """name -> (shape, "matrix" | "norm") of layer `i`'s weights."""
+    h, nh = cfg.hidden_size, cfg.num_attention_heads
+    out = {
+        "input_layernorm.weight": ((h,), "norm"),
+        "self_attn.q_proj.weight": ((h, nh * cfg.qk_head_dim), "matrix"),
+        "self_attn.kv_a_proj_with_mqa.weight": ((h, cfg.latent_dim), "matrix"),
+        "self_attn.kv_a_layernorm.weight": ((cfg.kv_lora_rank,), "norm"),
+        "self_attn.kv_b_proj.weight": (
+            (cfg.kv_lora_rank, nh * (cfg.qk_nope_head_dim + cfg.v_head_dim)),
+            "matrix"),
+        "self_attn.o_proj.weight": ((nh * cfg.v_head_dim, h), "matrix"),
+        "post_attention_layernorm.weight": ((h,), "norm"),
+    }
+    if i < cfg.first_k_dense_replace:
+        inter = cfg.intermediate_size
+        out.update({
+            "mlp.gate_proj.weight": ((h, inter), "matrix"),
+            "mlp.up_proj.weight": ((h, inter), "matrix"),
+            "mlp.down_proj.weight": ((inter, h), "matrix"),
+        })
+        return out
+    e, im = cfg.n_routed_experts, cfg.moe_intermediate_size
+    sh = cfg.n_shared_experts * im
+    out.update({
+        "mlp.gate.weight": ((h, e), "matrix"),
+        "mlp.gate.e_score_correction_bias": ((e,), "matrix"),
+        "mlp.experts.gate_proj.weight": ((e, h, im), "matrix"),
+        "mlp.experts.up_proj.weight": ((e, h, im), "matrix"),
+        "mlp.experts.down_proj.weight": ((e, im, h), "matrix"),
+        "mlp.shared_experts.gate_proj.weight": ((h, sh), "matrix"),
+        "mlp.shared_experts.up_proj.weight": ((h, sh), "matrix"),
+        "mlp.shared_experts.down_proj.weight": ((sh, h), "matrix"),
+    })
+    return out
+
+
+def param_shapes(cfg: DeepseekV3Config) -> Dict[str, Tuple[tuple, str]]:
+    """name -> (shape, kind) of the whole pytree."""
+    out = {"model.embed_tokens.weight":
+           ((cfg.vocab_size, cfg.hidden_size), "matrix")}
+    for i in range(cfg.num_hidden_layers):
+        for k, v in layer_shapes(cfg, i).items():
+            out[f"model.layers.{i}.{k}"] = v
+    out["model.norm.weight"] = ((cfg.hidden_size,), "norm")
+    out["lm_head.weight"] = ((cfg.hidden_size, cfg.vocab_size), "matrix")
+    return out
+
+
+def init_params(cfg: DeepseekV3Config, seed: int = 0, dtype=jnp.float32,
+                std: float = 0.02) -> Dict[str, jax.Array]:
+    """A pytree drawn on the device: matrices N(0, std^2), gains 1."""
+    key = jax.random.key(seed)
+    out = {}
+    for n, (name, (shape, kind)) in enumerate(sorted(param_shapes(cfg).items())):
+        if kind == "norm":
+            out[name] = jnp.ones(shape, dtype)
+        else:
+            out[name] = (jax.random.normal(jax.random.fold_in(key, n), shape,
+                                           jnp.float32) * std).astype(dtype)
+    return out
+
+
+def layer_params(params: Dict[str, jax.Array], i: int) -> Dict[str, jax.Array]:
+    pre = f"model.layers.{i}."
+    return {k[len(pre):]: v for k, v in params.items() if k.startswith(pre)}
+
+
+# --- the blocks ------------------------------------------------------------------
+
+def rms_norm(x, w, eps):
+    xf = x.astype(jnp.float32)
+    y = xf * jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
+    return (y * w.astype(jnp.float32)).astype(x.dtype)
+
+
+def rope_tables(cfg: DeepseekV3Config, positions: int):
+    """cos, sin `[positions, rope/2]` float32, from float64 angles."""
+    d = cfg.qk_rope_head_dim
+    inv = 1.0 / cfg.rope_theta ** (np.arange(0, d, 2, dtype=np.float64) / d)
+    ang = np.outer(np.arange(positions, dtype=np.float64), inv)
+    return (jnp.asarray(np.cos(ang), jnp.float32),
+            jnp.asarray(np.sin(ang), jnp.float32))
+
+
+def rope(x, cos, sin, interleave: bool):
+    """x `[T, ..., rope]`, cos/sin `[T, rope/2]` at the tokens' positions.
+    `interleave`: the published layout pairs (x0, x1), (x2, x3), ...; they
+    are de-interleaved to (first half, second half) and stay so (q and k
+    alike, so their products do not change)."""
+    if interleave:
+        x = jnp.concatenate([x[..., 0::2], x[..., 1::2]], axis=-1)
+    x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
+    shape = (cos.shape[0],) + (1,) * (x.ndim - 2) + (cos.shape[1],)
+    c, s = cos.reshape(shape), sin.reshape(shape)
+    return jnp.concatenate([x1 * c - x2 * s, x2 * c + x1 * s],
+                           axis=-1).astype(x.dtype)
+
+
+def _mm(x, w):
+    return jnp.einsum("...k,kn->...n", x, w.astype(x.dtype))
+
+
+def swiglu(x, gate_w, up_w, down_w):
+    g, u = _mm(x, gate_w), _mm(x, up_w)
+    return _mm(jax.nn.silu(g.astype(jnp.float32)).astype(x.dtype) * u, down_w)
+
+
+def mla(x, p, cfg: DeepseekV3Config, cos, sin, attend: Callable):
+    """The attention sub-block on rows `x [T, H]` in the absorbed form.
+    `attend(q_abs [T, heads, rank + rope], rows [T, rank + rope]) ->
+    o_lat [T, heads, rank]` owns the context: it stores this step's `rows`
+    and answers each query with `softmax(q . rows^T * scale) . rows[:, :rank]`
+    over its token's causal context. Returns the block's output `[T, H]`
+    (before the residual)."""
+    t = x.shape[0]
+    nh, nope, rd = cfg.num_attention_heads, cfg.qk_nope_head_dim, \
+        cfg.qk_rope_head_dim
+    rank, vd = cfg.kv_lora_rank, cfg.v_head_dim
+    with _scope("llama.mla_q"):
+        q = _mm(x, p["self_attn.q_proj.weight"]).reshape(t, nh, nope + rd)
+        q_nope, q_rope = q[..., :nope], q[..., nope:]
+    with _scope("llama.mla_kv_a"):
+        a = _mm(x, p["self_attn.kv_a_proj_with_mqa.weight"])
+        c = rms_norm(a[:, :rank], p["self_attn.kv_a_layernorm.weight"],
+                     cfg.rms_norm_eps)
+        k_rope = a[:, rank:]
+    with _scope("llama.rope"):
+        q_rope = rope(q_rope, cos, sin, cfg.rope_interleave)
+        k_rope = rope(k_rope, cos, sin, cfg.rope_interleave)
+    w_kvb = p["self_attn.kv_b_proj.weight"].reshape(rank, nh, nope + vd)
+    with _scope("llama.mla_absorb"):
+        q_lat = jnp.einsum("thn,chn->thc", q_nope,
+                           w_kvb[..., :nope].astype(x.dtype))
+        q_abs = jnp.concatenate([q_lat, q_rope], axis=-1)
+    o_lat = attend(q_abs, jnp.concatenate([c, k_rope], axis=-1))
+    with _scope("llama.mla_absorb"):
+        o = jnp.einsum("thc,chv->thv", o_lat.astype(x.dtype),
+                       w_kvb[..., nope:].astype(x.dtype))
+    with _scope("llama.o_proj"):
+        return _mm(o.reshape(t, nh * vd), p["self_attn.o_proj.weight"])
+
+
+def route(x, p, cfg: DeepseekV3Config):
+    """The router on rows `x [T, H]`: `(experts [T, k] int32, weights [T, k]
+    float32)`. Scores, the choice and the weights are float32 at the
+    `highest` matmul precision: a choice flips at a near-tie, and the
+    router's own arithmetic should not be what flips it."""
+    k = cfg.num_experts_per_tok
+    logits = jnp.dot(x.astype(jnp.float32),
+                     p["mlp.gate.weight"].astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    s = jax.nn.sigmoid(logits)
+    bias = p["mlp.gate.e_score_correction_bias"].astype(jnp.float32)
+    _, experts = jax.lax.top_k(s + bias, k)
+    w = jnp.take_along_axis(s, experts, axis=-1)
+    if cfg.norm_topk_prob:
+        w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+    return experts.astype(jnp.int32), w * cfg.routed_scaling_factor
+
+
+def routed_experts(x, experts, weights, live, p, cfg: DeepseekV3Config):
+    """The routed experts without capacity or drops. `x [T, H]`, `experts` /
+    `weights [T, k]`, `live [T]` bool (a row that is not live reaches no
+    expert and gets zeros). Rows are sorted by expert, the experts' SwiGLUs
+    are three grouped matmuls over the sorted rows (the kernel
+    `moe_grouped_matmul`, `ops/pallas/grouped_matmul.py`; off the TPU
+    `jax.lax.ragged_dot`; an expert with no row is not computed and its
+    matrices are not read), and each token's k answers are combined by
+    weight. Returns `(out [T, H], tokens_per_expert [E] int32)`.
+    """
+    t, k = experts.shape
+    e = cfg.n_routed_experts
+    with _scope("llama.moe_dispatch"):
+        flat = jnp.where(jnp.repeat(live, k), experts.reshape(t * k),
+                         jnp.int32(e))                  # dead rows sort last
+        order = jnp.argsort(flat).astype(jnp.int32)     # [T*k] sorted -> flat
+        sizes = jnp.zeros((e + 1,), jnp.int32).at[flat].add(1)[:e]
+        xs = jnp.take(x, order // k, axis=0)            # [T*k, H]
+    with _scope("llama.moe_experts"):
+        def rd(a, w):
+            if grouped_matmul.supported(w.shape, w.dtype):
+                return grouped_matmul.grouped_matmul(a, w, sizes)
+            return jax.lax.ragged_dot(a, w.astype(a.dtype), sizes,
+                                      preferred_element_type=jnp.float32)
+
+        g = rd(xs, p["mlp.experts.gate_proj.weight"])
+        u = rd(xs, p["mlp.experts.up_proj.weight"])
+        y = rd((jax.nn.silu(g) * u).astype(x.dtype),
+               p["mlp.experts.down_proj.weight"])       # [T*k, H] float32
+    with _scope("llama.moe_combine"):
+        back = jnp.zeros((t * k,), jnp.int32).at[order].set(
+            jnp.arange(t * k, dtype=jnp.int32))         # flat -> sorted
+        y = jnp.take(y, back, axis=0).reshape(t, k, -1)
+        # rows past the groups' sum are never written by the grouped
+        # matmul: select, never multiply, what a dead row holds
+        w = jnp.where(live[:, None], weights, 0.0)[..., None]
+        out = jnp.sum(jnp.where(live[:, None, None], y, 0.0) * w, axis=1)
+    return out.astype(x.dtype), sizes
+
+
+def moe(x, p, cfg: DeepseekV3Config, live):
+    """The expert layer's feed-forward on normed rows `x [T, H]`:
+    `(out, tokens_per_expert)`."""
+    with _scope("llama.moe"):
+        with _scope("llama.moe_router"):
+            experts, weights = route(x, p, cfg)
+        out, sizes = routed_experts(x, experts, weights, live, p, cfg)
+        with _scope("llama.moe_shared"):
+            out = out + swiglu(x, p["mlp.shared_experts.gate_proj.weight"],
+                               p["mlp.shared_experts.up_proj.weight"],
+                               p["mlp.shared_experts.down_proj.weight"])
+    return out, sizes
+
+
+def decoder_layer(x, p, cfg: DeepseekV3Config, cos, sin, attend, live):
+    """One decoder layer on rows `x [T, H]` (`p`: the layer's weights by
+    their names under `model.layers.<i>.`). Returns `(x, tokens_per_expert
+    [E] | None)`; None for a dense layer."""
+    with _scope("llama.layer"):
+        with _scope("llama.rms_norm"):
+            h = rms_norm(x, p["input_layernorm.weight"], cfg.rms_norm_eps)
+        x = x + mla(h, p, cfg, cos, sin, attend)
+        with _scope("llama.rms_norm"):
+            h = rms_norm(x, p["post_attention_layernorm.weight"],
+                         cfg.rms_norm_eps)
+        if "mlp.gate.weight" in p:
+            out, sizes = moe(h, p, cfg, live)
+            return x + out, sizes
+        with _scope("llama.mlp"):
+            return x + swiglu(h, p["mlp.gate_proj.weight"],
+                              p["mlp.up_proj.weight"],
+                              p["mlp.down_proj.weight"]), None
+
+
+def head(x, params, cfg: DeepseekV3Config):
+    """Final norm and the untied output head: float32 logits `[T, V]`."""
+    with _scope("llama.rms_norm"):
+        x = rms_norm(x, params["model.norm.weight"], cfg.rms_norm_eps)
+    with _scope("llama.head"):
+        # float32 straight from the accumulator: rounding the logits to
+        # bf16 first costs nothing less and ties the top of the vocabulary
+        return jnp.einsum("tk,kn->tn", x,
+                          params["lm_head.weight"].astype(x.dtype),
+                          preferred_element_type=jnp.float32)
+
+
+def dense_causal_attend(cfg: DeepseekV3Config):
+    """`attend` for one whole sequence in flight and no cache: token i sees
+    rows 0..i."""
+    def attend(q_abs, rows):
+        t = rows.shape[0]
+        s = jnp.einsum("thd,sd->hts", q_abs.astype(jnp.float32),
+                       rows.astype(jnp.float32)) * cfg.qk_head_dim ** -0.5
+        s = jnp.where(jnp.tril(jnp.ones((t, t), bool)), s, -jnp.inf)
+        return jnp.einsum("hts,sc->thc", jax.nn.softmax(s, axis=-1),
+                          rows[:, :cfg.kv_lora_rank].astype(jnp.float32))
+    return attend
+
+
+def model_forward(params, ids, cfg: DeepseekV3Config):
+    """ids `[S]` -> float32 logits `[S, V]`: one sequence, no cache."""
+    s = ids.shape[0]
+    cos, sin = rope_tables(cfg, s)
+    with _scope("llama.embed"):
+        x = jnp.take(params["model.embed_tokens.weight"], ids, axis=0)
+    live = jnp.ones((s,), bool)
+    attend = dense_causal_attend(cfg)
+    for i in range(cfg.num_hidden_layers):
+        x, _ = decoder_layer(x, layer_params(params, i), cfg, cos, sin,
+                             attend, live)
+    return head(x, params, cfg)
+
+
+class DeepseekV3ForCausalLM(nn.Layer):
+    """A thin holder of the weight pytree: every leaf is a `Parameter` under
+    its HuggingFace name, and `forward` is `model_forward`. `weights`
+    (name -> array, shapes as `param_shapes` gives them) are taken as they
+    are, without a copy; without them the pytree is drawn on the device."""
+
+    def __init__(self, config: DeepseekV3Config,
+                 weights: Optional[Dict[str, jax.Array]] = None,
+                 dtype=jnp.float32, seed: int = 0):
+        super().__init__()
+        self.config = config
+        if weights is None:
+            weights = init_params(config, seed, dtype)
+        want = {k: tuple(s) for k, (s, _) in param_shapes(config).items()}
+        have = {k: tuple(v.shape) for k, v in weights.items()}
+        if have != want:
+            raise ValueError(
+                "deepseek_v3: the weights are not this configuration's: "
+                f"{sorted(set(have.items()) ^ set(want.items()))[:8]}")
+        for name, w in weights.items():
+            self.add_parameter(name, Parameter(w, trainable=False, name=name))
+
+    def weight_tree(self) -> Dict[str, jax.Array]:
+        """name -> array, by reference."""
+        return {k: p._data for k, p in self._parameters.items()}
+
+    def forward(self, input_ids):
+        ids = getattr(input_ids, "_data", input_ids)
+        ids = jnp.asarray(ids, jnp.int32)
+        if ids.ndim == 1:
+            return model_forward(self.weight_tree(), ids, self.config)
+        return jax.vmap(lambda r: model_forward(self.weight_tree(), r,
+                                                self.config))(ids)

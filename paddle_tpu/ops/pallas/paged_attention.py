@@ -285,6 +285,27 @@ _RAGGED_VMEM_BUDGET = 10 << 20
 _RAGGED_Q_CHUNK = 8
 
 
+def online_softmax_step(s, m_prev, l_prev):
+    """One step of the online softmax on a masked f32 score tile `s
+    [rows, cols]` against the running max and sum `m_prev`, `l_prev
+    [rows, 128]` (lane-replicated): `(p [rows, cols], alpha [rows, 128],
+    m_new, l_new)`; the caller scales its accumulator by `alpha[:, :1]`
+    and adds `p @ v`. Shared by the ragged kernels of this package."""
+    m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+    p = jnp.exp(s - m_new[:, :1])
+    alpha = jnp.exp(m_prev - m_new)
+    return p, alpha, m_new, l_prev * alpha + p.sum(axis=-1, keepdims=True)
+
+
+def lane_spans(tok_lane, tok_pos, lanes):
+    """Each lane's `(q_lens, q_starts)` in the packed buffer, from the
+    per-token metadata `ragged_metadata` made (guard slots, pos -1, belong
+    to no lane)."""
+    q_lens = jnp.zeros((lanes,), jnp.int32).at[tok_lane.astype(jnp.int32)] \
+        .add((tok_pos >= 0).astype(jnp.int32))
+    return q_lens, jnp.cumsum(q_lens) - q_lens
+
+
 def _group_pad(g):
     """A query-head group's rows, padded to whole 8-row sublane tiles."""
     return -(-g // 8) * 8
@@ -454,15 +475,9 @@ def _ragged_kernel(kv_lens_ref, q_lens_ref, q_starts_ref, tables_ref,
                     # when the interpret-mode kernel is traced inside an
                     # x64-on outer program (see _decode_kernel)
                     s = jnp.where(live, s, jnp.float32(NEG_INF))
-                    m_prev = m_ref[h, rs, :]                 # (rows, 128)
-                    l_prev = l_ref[h, rs, :]
-                    m_new = jnp.maximum(
-                        m_prev, s.max(axis=-1, keepdims=True))
-                    p = jnp.exp(s - m_new[:, :1])
-                    alpha = jnp.exp(m_prev - m_new)
-                    l_ref[h, rs, :] = l_prev * alpha + p.sum(
-                        axis=-1, keepdims=True)
-                    m_ref[h, rs, :] = m_new
+                    p, alpha, m_ref[h, rs, :], l_ref[h, rs, :] = \
+                        online_softmax_step(s, m_ref[h, rs, :],
+                                            l_ref[h, rs, :])
                     if quantized:
                         # a masked slot's scale may be anything
                         p = jnp.where(live, p * vs, jnp.float32(0.0))
@@ -625,10 +640,7 @@ def paged_attention_ragged(q, k_cache, v_cache, block_tables, kv_lens,
     # last live chunk's DMA may run over
     qg = q.reshape(tokens, kv_h, g, d).astype(jnp.float32)
     qg = jnp.pad(qg, ((0, _RAGGED_Q_CHUNK), (0, 0), (0, g_pad - g), (0, 0)))
-    tok_lane = tok_lane.astype(jnp.int32)
-    q_lens = jnp.zeros((lanes,), jnp.int32).at[tok_lane].add(
-        (tok_pos >= 0).astype(jnp.int32))
-    q_starts = jnp.cumsum(q_lens) - q_lens
+    q_lens, q_starts = lane_spans(tok_lane, tok_pos, lanes)
     block_tables = block_tables.astype(jnp.int32)
     if k_scale is not None:
         # the lane's scale rows in logical order, [B, KV_H, groups,

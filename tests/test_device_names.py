@@ -65,9 +65,11 @@ def test_every_kernel_call_site_names_its_kernel(site):
 
 def test_kernel_names_are_distinct_and_cover_the_main_path():
     names = [n.value for _f, _l, n in SITES]
-    assert len(names) == len(set(names)) == 17
+    assert len(names) == len(set(names)) == 19
     assert {"paged_attention_ragged", "paged_attention_decode",
-            "paged_attention_verify", "flash_fwd", "flash_dq", "flash_dkv",
+            "paged_attention_verify", "paged_attention_mla",
+            "moe_grouped_matmul", "flash_fwd",
+            "flash_dq", "flash_dkv",
             "rms_norm", "fused_rope", "quant_matmul_int8",
             "quant_matmul_int4"} <= set(names)
 
