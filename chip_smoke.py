@@ -621,6 +621,14 @@ def kernel_cases(z):
                 normal(key, 1, (nb, kvh, bs, d)),
                 normal(key, 2, (nb, kvh, bs, d)), tables, kv_lens, lane, pos)
 
+    def write_args(key):
+        # the rows the cells' step writes: a chunk that crosses blocks,
+        # decode tokens, guard slots at the tail
+        _q, kc, vc, tables, _kv_lens, lane, pos = cell_args(key)
+        rows = (pos.shape[0],) + kc.shape[1:2] + kc.shape[3:]
+        return (normal(key, 3, rows), normal(key, 4, rows), kc, vc, tables,
+                lane, pos)
+
     def quantized(make):
         def args(key):
             q, kc, vc, *rest = make(key)
@@ -680,6 +688,8 @@ def kernel_cases(z):
         ("paged_attention_ragged int8-KV, the cells' shape",
          quantized(cell_args), ragged_q(pk.paged_attention_ragged),
          ragged_q(pk.paged_attention_ragged_ref)),
+        ("kv_write_ragged bf16, the cells' shape", write_args,
+         pk.write_kv_to_cache_ragged, pk.write_kv_to_cache_ragged_ref),
         ("paged_attention (legacy decode)",
          lambda key: decode_args(key, H, D),
          pk.paged_attention, pk.paged_attention_ref),

@@ -7,9 +7,9 @@ an `EngineCore` whose paged pool holds ONE latent row a token a layer.
   device holds.
 - The pool is one donated array `[L, NB, BS, row]`, written in place; the
   layer is an index. It never enters a `lax.scan` as per-layer inputs and
-  stacked outputs (what costs the Llama engine 84 % of its step, PERF.md):
-  the layers are unrolled and each scatters into, and reads from, the one
-  buffer. `row` is the latent width rounded up to whole 128-lane tiles
+  stacked outputs (what cost the Llama engine 84 % of its step until its
+  pool became the scan's carry, PERF.md PR 28): here the layers are
+  unrolled and each scatters into, and reads from, the one buffer. `row` is the latent width rounded up to whole 128-lane tiles
   (576 -> 640, zero columns; `ops/pallas/paged_attention_mla.py` says why).
 - `ragged_step` is the one compiled step, `verify_step` a case of it; the
   legacy `prefill` / `decode_step` / `generate` raise, as `ShardedEngine`'s

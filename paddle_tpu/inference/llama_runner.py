@@ -10,10 +10,16 @@ O(1) in depth and XLA pipelines HBM weight streaming with MXU compute.
 
 TPU-first choices:
 - paged KV cache ([L, num_blocks, kv_heads, block_size, D]) with the Pallas
-  decode kernel (`ops/pallas/paged_attention.py`); block tables are host
+  kernels (`ops/pallas/paged_attention.py`); block tables are host
   bookkeeping (`inference/cache.py`).
-- decode step jitted with the caches DONATED — the cache update is in-place
-  in HBM, no per-step reallocation.
+- every step jitted with the caches DONATED, and the pool stays where it
+  is: the layer scan streams the WEIGHTS as its `xs`, while the whole pool
+  is its carry and the layer index one more scanned operand. The ragged
+  step writes each live token's rows at `[layer, block, :, offset, :]` and
+  its kernel reads pages at `[layer, block]`, so no layer's pool is ever
+  sliced out of, stacked back into, reshaped or copied by the loop
+  (tests/test_inference.py pins the compiled step's temporaries under one
+  layer's pool). The legacy modes take their layer out and put it back.
 - static shapes everywhere: batch and max_blocks fixed at engine build.
 """
 from __future__ import annotations
@@ -579,9 +585,12 @@ class _StaticCfg:
         return self.__dict__ == o.__dict__
 
 
-def _layer_body(x, layer_in, *, cfg, positions, tables, ctx_lens, mode,
-                ragged_meta=None, kv_scales=None):
-    """One decoder layer on [B, S, H]; returns (x, (new_k_blocks, new_v_blocks)).
+def _layer_body(x, layer_in, pools, layer, *, cfg, positions, tables,
+                ctx_lens, mode, ragged_meta=None):
+    """Decoder layer `layer` (a traced int32 scalar) on [B, S, H], with its
+    weights `layer_in` and the WHOLE pool `pools` = (k_cache, v_cache)
+    [L, NB, KVH, BS, D]; returns (x, pools), the pool written at `layer`
+    and nowhere else.
 
     `mode`: "prefill" (dense causal SDPA over the in-flight tokens),
     "decode" (single-query paged attention), "verify" (S-query causal
@@ -591,16 +600,20 @@ def _layer_body(x, layer_in, *, cfg, positions, tables, ctx_lens, mode,
     token to its lane and absolute position, ctx_lens is per-lane
     kv_lens — ONE fixed-shape program for every batch composition).
 
-    `kv_scales` = (k_scale, v_scale) per-slot planes marks an int8
-    quantized KV pool (`inference/kv_quant.py`, ragged mode only):
-    writes quantize, attention dequantizes in-kernel, and the layer
-    returns (x, (kc, vc, ks, vs))."""
+    `pools` = (k_cache, v_cache, k_scale, v_scale), the per-slot scale
+    planes [L, NB, KVH, BS] beside the caches, marks an int8 quantized KV
+    pool (`inference/kv_quant.py`, ragged mode only): writes quantize,
+    attention dequantizes in-kernel, and all four come back.
+
+    Ragged mode never takes the layer's pool out: the write and the
+    kernel index the whole pool at `layer`. The legacy modes slice their
+    layer out and update it back, which is what they always cost."""
     import jax
     import jax.numpy as jnp
 
     from ..ops.pallas import paged_attention as pk
 
-    ln1, qkv_w, o_w, ln2, gu_w, down_w, kc, vc, cos, sin = layer_in
+    ln1, qkv_w, o_w, ln2, gu_w, down_w, cos, sin = layer_in
     b, s, hdim = x.shape
     nh, kvh, d = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
 
@@ -623,27 +636,19 @@ def _layer_body(x, layer_in, *, cfg, positions, tables, ctx_lens, mode,
 
     if mode == "ragged":
         tok_lane, tok_pos = ragged_meta
-        ks = vs = None
         with scope("llama.kv_write"):
-            if kv_scales is not None:
-                ks, vs = kv_scales
-                kc, vc, ks, vs = pk.write_kv_to_cache_ragged(
-                    k[0], v[0], kc, vc, tables, tok_lane, tok_pos,
-                    k_scale=ks, v_scale=vs)
-            else:
-                kc, vc = pk.write_kv_to_cache_ragged(
-                    k[0], v[0], kc, vc, tables, tok_lane, tok_pos)
+            pools = pk.write_kv_to_cache_ragged(
+                k[0], v[0], *pools[:2], tables, tok_lane, tok_pos,
+                *pools[2:], layer=layer)
         with scope("llama.attn"):
             qr = q[0]                                     # [T, NH, D]
-            if pk.ragged_supported((s, nh, d), qr.dtype, kc.shape,
-                                   kc.dtype, tables.shape[1]):
-                attn = pk.paged_attention_ragged(
-                    qr, kc, vc, tables, ctx_lens, tok_lane, tok_pos,
-                    k_scale=ks, v_scale=vs)
-            else:
-                attn = pk.paged_attention_ragged_ref(
-                    qr, kc, vc, tables, ctx_lens, tok_lane, tok_pos,
-                    k_scale=ks, v_scale=vs)
+            kc, vc = pools[:2]
+            kernel = pk.paged_attention_ragged if pk.ragged_supported(
+                (s, nh, d), qr.dtype, kc.shape, kc.dtype,
+                tables.shape[1]) else pk.paged_attention_ragged_ref
+            attn = kernel(qr, kc, vc, tables, ctx_lens, tok_lane, tok_pos,
+                          **dict(zip(("k_scale", "v_scale"), pools[2:])),
+                          layer=layer)
             attn = attn.reshape(1, s, nh * d).astype(x.dtype)
         tp = getattr(cfg, "tp", None)
         if tp is not None:
@@ -670,13 +675,15 @@ def _layer_body(x, layer_in, *, cfg, positions, tables, ctx_lens, mode,
                                             ntiles=tp.tiles, mm=_mm)
             else:
                 x = x + _mm(act, down_w)
-        if kv_scales is not None:
-            return x, (kc, vc, ks, vs)
-        return x, (kc, vc)
+        return x, pools
 
     with scope("llama.kv_write"):
         start = positions[:, 0].astype(jnp.int32)
+        kc, vc = (jax.lax.dynamic_index_in_dim(p, layer, 0, keepdims=False)
+                  for p in pools)
         kc, vc = pk.write_kv_to_cache(k, v, kc, vc, tables, start)
+        pools = tuple(jax.lax.dynamic_update_index_in_dim(p, c, layer, 0)
+                      for p, c in zip(pools, (kc, vc)))
 
     with scope("llama.attn"):
         if mode == "decode":
@@ -713,42 +720,38 @@ def _layer_body(x, layer_in, *, cfg, positions, tables, ctx_lens, mode,
         g, u = jnp.split(gu, 2, axis=-1)
         act = jax.nn.silu(g.astype(jnp.float32)).astype(g.dtype) * u
         x = x + _mm(act, down_w)
-    return x, (kc, vc)
+    return x, pools
 
 
 def _run_stack(params, k_cache, v_cache, x, positions, tables, ctx_lens,
                cfg, mode, ragged_meta=None, k_scale=None, v_scale=None):
+    """The decoder stack as one rolled `lax.scan`: the stacked weights and
+    the layer index are its `xs`, the activations AND the whole KV pool
+    its carry, so the (donated) pool is written where it lies and never
+    becomes a per-layer `xs` slice or a stacked `ys`. Returns (logits,
+    k_cache, v_cache[, k_scale, v_scale when the pool is int8])."""
     import jax
     import jax.numpy as jnp
 
     cos, sin = params["rope_cos"], params["rope_sin"]
-    quant_kv = k_scale is not None
+    pools = (k_cache, v_cache)
+    if k_scale is not None:
+        pools += (k_scale, v_scale)
 
     @jax.named_scope("llama.layer")
-    def body(x, layer_xs):
-        if quant_kv:
-            ln1, qkv_w, o_w, ln2, gu_w, down_w, kc, vc, ks, vs = layer_xs
-            x, carry = _layer_body(
-                x, (ln1, qkv_w, o_w, ln2, gu_w, down_w, kc, vc, cos, sin),
-                cfg=cfg, positions=positions, tables=tables,
-                ctx_lens=ctx_lens, mode=mode, ragged_meta=ragged_meta,
-                kv_scales=(ks, vs))
-            return x, carry
-        ln1, qkv_w, o_w, ln2, gu_w, down_w, kc, vc = layer_xs
-        x, (kc, vc) = _layer_body(
-            x, (ln1, qkv_w, o_w, ln2, gu_w, down_w, kc, vc, cos, sin),
-            cfg=cfg, positions=positions, tables=tables, ctx_lens=ctx_lens,
+    def body(carry, layer_xs):
+        x, pools = carry
+        *weights, layer = layer_xs
+        x, pools = _layer_body(
+            x, (*weights, cos, sin), pools, layer, cfg=cfg,
+            positions=positions, tables=tables, ctx_lens=ctx_lens,
             mode=mode, ragged_meta=ragged_meta)
-        return x, (kc, vc)
+        return (x, pools), None
 
     xs = (params["ln1"], params["qkv_w"], params["o_w"], params["ln2"],
-          params["gate_up_w"], params["down_w"], k_cache, v_cache)
-    if quant_kv:
-        xs = xs + (k_scale, v_scale)
-        x, (new_k, new_v, new_ks, new_vs) = jax.lax.scan(body, x, xs)
-    else:
-        x, (new_k, new_v) = jax.lax.scan(body, x, xs)
-        new_ks = new_vs = None
+          params["gate_up_w"], params["down_w"],
+          jnp.arange(k_cache.shape[0], dtype=jnp.int32))
+    (x, pools), _ = jax.lax.scan(body, (x, pools), xs)
     with jax.named_scope("llama.rms_norm"):
         x = _rms(x, params["final_norm"], cfg.eps)
     head = params.get("lm_head")
@@ -771,9 +774,7 @@ def _run_stack(params, k_cache, v_cache, x, positions, tables, ctx_lens,
             from ..distributed.tp_overlap import gather_columns
 
             logits = gather_columns(logits, tp.axis)
-    if quant_kv:
-        return logits, new_k, new_v, new_ks, new_vs
-    return logits, new_k, new_v
+    return (logits,) + pools
 
 
 def _prefill_fn(params, k_cache, v_cache, input_ids, tables, lens, *, cfg):

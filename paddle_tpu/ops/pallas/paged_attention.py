@@ -19,6 +19,10 @@ Kernel design (TPU-first, not a CUDA translation):
   scratch; the output is written once on the last block step.
 
 Caches use the reference layout ``[num_blocks, kv_heads, block_size, head_dim]``.
+The serving step's two functions, `write_kv_to_cache_ragged` and
+`paged_attention_ragged`, also take the engine's whole pool, that layout
+under a leading layer axis, with the layer an operand: they index
+``pool[layer, block]`` and never slice a layer out.
 """
 from __future__ import annotations
 
@@ -332,9 +336,9 @@ def _ragged_tiles(tokens, kv_h, g_pad, d, block_size, width, kv_itemsize):
     return pages, chunks * _RAGGED_Q_CHUNK
 
 
-def _ragged_kernel(kv_lens_ref, q_lens_ref, q_starts_ref, tables_ref,
-                   q_hbm, k_hbm, v_hbm, *rest, sm_scale, block_size, pages,
-                   q_tile, g_pad, quantized, mxu_dtype):
+def _ragged_kernel(layer_ref, kv_lens_ref, q_lens_ref, q_starts_ref,
+                   tables_ref, q_hbm, k_hbm, v_hbm, *rest, sm_scale,
+                   block_size, pages, q_tile, g_pad, quantized, mxu_dtype):
     """Ragged paged attention: ONE fixed-shape kernel for mixed
     prefill-chunk + decode + verify batches, whose work follows the live
     pages and live tokens of each lane.
@@ -343,8 +347,9 @@ def _ragged_kernel(kv_lens_ref, q_lens_ref, q_starts_ref, tables_ref,
     the packed query tokens [q_start, q_start + q_len) (lane-major, as
     `ragged_metadata` packs them) whose first sits at absolute position
     kv_len - q_len. The packed q buffer, the K/V pools (as stored,
-    [NB, KVH, BS, D]) and the output stay in HBM; the body moves what it
-    needs with its own DMAs:
+    [L, NB, KVH, BS, D], the layer one more prefetched scalar: a page is
+    `pool[layer, block]`, an index and never a slice) and the output stay
+    in HBM; the body moves what it needs with its own DMAs:
 
     - a lane's tokens go in tiles of `q_tile` (an empty lane issues none,
       guard slots past sum(q_lens) belong to no lane and cost nothing);
@@ -385,6 +390,7 @@ def _ragged_kernel(kv_lens_ref, q_lens_ref, q_starts_ref, tables_ref,
     cols = pages * block_size             # kv positions of one page group
     i32 = jnp.int32
     b = pl.program_id(0)
+    layer = layer_ref[0]
     kv_len = kv_lens_ref[b]
     q_len = q_lens_ref[b]
     q_start = q_starts_ref[b]
@@ -421,9 +427,11 @@ def _ragged_kernel(kv_lens_ref, q_lens_ref, q_starts_ref, tables_ref,
             for p in range(pages):
                 j = jnp.minimum(g * i32(pages) + i32(p), n_pages - 1)
                 blk = tables_ref[b, j]
-                yield pltpu.make_async_copy(k_hbm.at[blk], kbuf.at[slot, p],
+                yield pltpu.make_async_copy(k_hbm.at[layer, blk],
+                                            kbuf.at[slot, p],
                                             sem.at[0, slot])
-                yield pltpu.make_async_copy(v_hbm.at[blk], vbuf.at[slot, p],
+                yield pltpu.make_async_copy(v_hbm.at[layer, blk],
+                                            vbuf.at[slot, p],
                                             sem.at[1, slot])
 
         chunk_loop(n_chunks, lambda c: q_copy(c).start())
@@ -511,14 +519,15 @@ def _ragged_kernel(kv_lens_ref, q_lens_ref, q_starts_ref, tables_ref,
     jax.lax.fori_loop(0, n_tiles, lambda i, _: tile(i), None)
 
 
-def _ragged_call(q, k_cache, v_cache, block_tables, kv_lens, q_lens,
+def _ragged_call(q, k_cache, v_cache, layer, block_tables, kv_lens, q_lens,
                  q_starts, sm_scale, tiles, mxu_dtype, k_scale=None,
                  v_scale=None):
     """q: f32 [T + chunk, KV_H, Gp, D] packed tokens; caches as stored,
-    [NB, KV_H, BS, D] (int8 when the per-lane f32 scale windows
-    [B, KV_H, groups, pages*BS] ride along). Returns f32, q's shape."""
+    [L, NB, KV_H, BS, D], and `layer` int32 [1], which of them to read
+    (int8 when the per-lane f32 scale windows [B, KV_H, groups, pages*BS]
+    ride along). Returns f32, q's shape."""
     tokens, kv_h, g_pad, d = q.shape
-    block_size = k_cache.shape[2]
+    block_size = k_cache.shape[3]
     lanes = block_tables.shape[0]
     pages, q_tile = tiles
     rows = q_tile * g_pad
@@ -529,7 +538,7 @@ def _ragged_call(q, k_cache, v_cache, block_tables, kv_lens, q_lens,
     if k_scale is not None:
         window = pl.BlockSpec(
             (1,) + k_scale.shape[1:],
-            lambda b, lens, qlens, starts, tables: (b, 0, 0, 0))
+            lambda b, layer, lens, qlens, starts, tables: (b, 0, 0, 0))
         operands += [k_scale, v_scale]
         in_specs += [window, window]
     # the output buffer starts as zeros: guard rows are never written
@@ -538,7 +547,7 @@ def _ragged_call(q, k_cache, v_cache, block_tables, kv_lens, q_lens,
     page_buf = pltpu.VMEM((2, pages, kv_h, block_size, d), k_cache.dtype)
     lm = pltpu.VMEM((kv_h, rows, 128), jnp.float32)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,
+        num_scalar_prefetch=5,
         grid=(lanes,),
         in_specs=in_specs,
         out_specs=hbm,
@@ -557,12 +566,27 @@ def _ragged_call(q, k_cache, v_cache, block_tables, kv_lens, q_lens,
                           mxu_dtype=mxu_dtype),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(q.shape, jnp.float32),
-        input_output_aliases={4 + len(operands) - 1: 0},
+        input_output_aliases={5 + len(operands) - 1: 0},
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         name="paged_attention_ragged",
         interpret=_support.interpret_mode(),
-    )(kv_lens, q_lens, q_starts, block_tables, *operands)
+    )(layer, kv_lens, q_lens, q_starts, block_tables, *operands)
+
+
+def _layered(layer, k_cache, *pools):
+    """`(layer int32 [], k_cache, *pools)` with every pool `[L, ...]`. What
+    decides is the K pool's rank: `[L, NB, KVH, BS, D]` comes with the
+    `layer` to use; `[NB, KVH, BS, D]` is a pool of one layer, and the
+    leading axis of 1 it gets here is free."""
+    if k_cache.ndim == 5:
+        if layer is None:
+            raise ValueError("a [L, NB, KVH, BS, D] pool needs its `layer`")
+        return (jnp.asarray(layer, jnp.int32), k_cache) + pools
+    if layer is not None:
+        raise ValueError("a [NB, KVH, BS, D] pool is one layer: no `layer`")
+    return (jnp.int32(0),) + tuple(
+        None if p is None else p[None] for p in (k_cache,) + pools)
 
 
 def ragged_metadata(q_lens, kv_lens, num_tokens):
@@ -588,7 +612,7 @@ def ragged_metadata(q_lens, kv_lens, num_tokens):
 
 def paged_attention_ragged(q, k_cache, v_cache, block_tables, kv_lens,
                            tok_lane, tok_pos, sm_scale=None,
-                           k_scale=None, v_scale=None):
+                           k_scale=None, v_scale=None, layer=None):
     """Ragged paged attention over a packed query token buffer.
 
     ONE kernel for every serving batch composition: decode lanes
@@ -605,8 +629,9 @@ def paged_attention_ragged(q, k_cache, v_cache, block_tables, kv_lens,
          `ragged_metadata` packs them: lane i owns the contiguous slots
          after lane i-1's, its tokens at consecutive positions ending at
          kv_lens[i] - 1.
-      k_cache/v_cache: [num_blocks, kv_heads, block_size, head_dim], read
-         as stored.
+      k_cache/v_cache: [layers, num_blocks, kv_heads, block_size,
+         head_dim], read as stored, with `layer` (below); or one layer's
+         [num_blocks, kv_heads, block_size, head_dim], without.
       block_tables: [B, W] int32 physical block ids per lane; entries
          past a lane's live pages are never read.
       kv_lens: [B] int32 — tokens in cache per lane INCLUDING this
@@ -614,14 +639,20 @@ def paged_attention_ragged(q, k_cache, v_cache, block_tables, kv_lens,
       tok_lane/tok_pos: [T] int32 per-token owner lane / absolute
          position (-1 = guard slot, output forced to 0); the kernel
          takes each lane's (q_start, q_len) from them.
-      k_scale/v_scale: optional f32 [num_blocks, kv_heads, block_size]
-         per-slot scale planes for int8 quantized caches
-         (`inference/kv_quant.py`): dequantization then happens inside
-         the kernel body, on the score tile.
+      k_scale/v_scale: optional f32 [(layers,) num_blocks, kv_heads,
+         block_size] per-slot scale planes for int8 quantized caches
+         (`inference/kv_quant.py`), of the pools' rank less one:
+         dequantization then happens inside the kernel body, on the
+         score tile.
+      layer: which layer of a 5-D pool, an int or a traced int32 scalar
+         (one compiled kernel serves every layer: it rides scalar
+         prefetch, and a page's DMA source is `pool[layer, block]`).
     Returns [T, H, D]; guard rows are exact zeros.
     """
+    layer, k_cache, v_cache, k_scale, v_scale = _layered(
+        layer, k_cache, v_cache, k_scale, v_scale)
     tokens, h, d = q.shape
-    _, kv_h, block_size, _ = k_cache.shape
+    kv_h, block_size = k_cache.shape[2:4]
     lanes, width = block_tables.shape
     g = h // kv_h
     if sm_scale is None:
@@ -647,18 +678,18 @@ def paged_attention_ragged(q, k_cache, v_cache, block_tables, kv_lens,
         # pages*BS]: one (1, pages*BS) row per page group lies along the
         # score tile's lanes, which no in-kernel gather of (KV_H, BS)
         # pieces could give without a relayout. A table-wide gather, but
-        # of planes 1/D the pool's size.
+        # of planes 1/D the pool's size (and of this layer's alone).
         groups = -(-width // pages)
         padded = jnp.pad(block_tables, ((0, 0), (0, groups * pages - width)))
 
         def window(scale):
-            return jnp.swapaxes(jnp.take(scale, padded, axis=0), 1, 2) \
+            return jnp.swapaxes(scale[layer, padded], 1, 2) \
                 .reshape(lanes, kv_h, groups, pages * block_size)
 
         k_scale, v_scale = window(k_scale), window(v_scale)
     exact_bf16 = q.dtype == jnp.bfloat16 and k_cache.dtype in (
         jnp.bfloat16, jnp.int8)
-    out = _ragged_call(qg, k_cache, v_cache, block_tables,
+    out = _ragged_call(qg, k_cache, v_cache, layer.reshape(1), block_tables,
                        kv_lens.astype(jnp.int32), q_lens, q_starts,
                        float(sm_scale), tiles,
                        jnp.bfloat16 if exact_bf16 else jnp.float32,
@@ -674,7 +705,7 @@ _REF_TOKEN_TILE = 128
 
 def paged_attention_ragged_ref(q, k_cache, v_cache, block_tables, kv_lens,
                                tok_lane, tok_pos, sm_scale=None,
-                               k_scale=None, v_scale=None):
+                               k_scale=None, v_scale=None, layer=None):
     """XLA reference for the ragged kernel (also the CPU fallback).
 
     Same gather + masked-softmax structure as `paged_attention_ref`, per
@@ -683,20 +714,24 @@ def paged_attention_ragged_ref(q, k_cache, v_cache, block_tables, kv_lens,
     tiles so the gathered windows stay bounded — each row's reduction is
     unchanged, only how many rows are materialized at once.
 
-    `k_scale`/`v_scale` (f32 [NB, KVH, BS]) mark int8 quantized caches:
-    the gathered per-lane windows dequantize right after the gather —
-    only the gathered window is ever materialized in float, never the
-    pool."""
+    `k_scale`/`v_scale` (f32 [(L,) NB, KVH, BS]) mark int8 quantized
+    caches: the gathered per-lane windows dequantize right after the
+    gather — only the gathered window is ever materialized in float,
+    never the pool. Pools and `layer` as `paged_attention_ragged` takes
+    them: the windows are gathered at `[layer, block]`, the layer never
+    sliced out."""
+    layer, k_cache, v_cache, k_scale, v_scale = _layered(
+        layer, k_cache, v_cache, k_scale, v_scale)
     tokens, h, d = q.shape
-    nb, kv_h, bs, _ = k_cache.shape
+    kv_h, bs = k_cache.shape[2:4]
     g = h // kv_h
     if sm_scale is None:
         sm_scale = 1.0 / float(np.sqrt(d))
-    k = jnp.take(k_cache, block_tables, axis=0)   # [B, W, KV_H, BS, D]
-    v = jnp.take(v_cache, block_tables, axis=0)
+    k = k_cache[layer, block_tables]              # [B, W, KV_H, BS, D]
+    v = v_cache[layer, block_tables]
     if k_scale is not None:
-        ks = jnp.take(k_scale, block_tables, axis=0)   # [B, W, KV_H, BS]
-        vs = jnp.take(v_scale, block_tables, axis=0)
+        ks = k_scale[layer, block_tables]         # [B, W, KV_H, BS]
+        vs = v_scale[layer, block_tables]
         k = k.astype(jnp.float32) * ks[..., None]
         v = v.astype(jnp.float32) * vs[..., None]
     max_s = block_tables.shape[1] * bs
@@ -809,53 +844,219 @@ def write_kv_to_cache(k, v, k_cache, v_cache, block_tables, start_pos):
     return kc, vc
 
 
+# VMEM the ragged write sizes its block buffers against (K and V, one
+# block a token slot of a tile), beside the tile's f32 K/V rows
+_KV_WRITE_VMEM_BUDGET = 8 << 20
+
+
+def _kv_write_tile(tokens, kv_h, block_size, d, itemsize):
+    """Token slots a grid step of the ragged write handles: every slot may
+    own a K and a V block buffer, so as many as `_KV_WRITE_VMEM_BUDGET`
+    holds, in whole 8s, and no more than the packed buffer has."""
+    block_bytes = kv_h * block_size * max(d, 128) * itemsize
+    fit = _KV_WRITE_VMEM_BUDGET // (2 * block_bytes) // 8 * 8
+    return min(fit, -(-tokens // 8) * 8)
+
+
+def _kv_write_kernel(layer_ref, blk_ref, off_ref, slot_ref, k_ref, v_ref,
+                     *rest, tile, num_blocks):
+    """The ragged KV write, in place: read-modify-write of whole blocks.
+
+    Mosaic cannot DMA one row of a packed (bf16, int8) tile, and XLA's
+    scatter walks a token's rows one (token, head) at a time; a block
+    `pool[layer, block]` is contiguous, so it moves whole. A grid step owns
+    `tile` packed token slots. Token j's block is `blk_ref[j]` (a guard
+    slot's lies past the pool: skipped), its row `off_ref[j]`, and
+    `slot_ref[j]` names the tile's FIRST token of the same block: a lane's
+    tokens sit at consecutive positions, so the tokens of one block are
+    neighbours, and that first one's buffer collects the rows of all of
+    them. Fetch every first token's K and V block; wait; put each token's
+    rows into its block's buffer at `[:, off, :]` (a select on f32 copies
+    of the head's tile: exact); send every buffer back; wait. Blocks of
+    one tile are distinct, tiles run in order, so no write meets a
+    read."""
+    _, _, k_pool, v_pool, kbuf, vbuf, sem = rest
+    kv_h, block_size, d = kbuf.shape[1:]
+    i32 = jnp.int32
+    base = pl.program_id(0) * i32(tile)
+    layer = layer_ref[0]
+
+    def each(body, firsts_only):
+        def step(j, _):
+            blk = blk_ref[base + j]
+            go = blk < i32(num_blocks)
+            if firsts_only:
+                go = jnp.logical_and(go, slot_ref[base + j] == j)
+
+            @pl.when(go)
+            def _():
+                body(j, blk)
+
+        jax.lax.fori_loop(0, tile, step, None)
+
+    def move(method, back):
+        """Start or wait (`method`) the K and V block copies of a run's
+        first token: pool -> its buffer, or `back`."""
+        def body(j, blk):
+            for which, (pool, buf) in enumerate(((k_pool, kbuf),
+                                                 (v_pool, vbuf))):
+                ends = (pool.at[layer, blk], buf.at[j])
+                getattr(pltpu.make_async_copy(
+                    *(ends[::-1] if back else ends), sem.at[which]),
+                    method)()
+        each(body, firsts_only=True)
+
+    def put(j, _):
+        slot = slot_ref[base + j]
+        at_row = jax.lax.broadcasted_iota(
+            i32, (block_size, d), 0) == off_ref[base + j]
+        for rows_ref, buf in ((k_ref, kbuf), (v_ref, vbuf)):
+            rows = rows_ref[j]                              # (KV_H, D) f32
+            for h in range(kv_h):
+                new = jnp.broadcast_to(rows[h:h + 1], (block_size, d))
+                buf[slot, h] = jnp.where(
+                    at_row, new, buf[slot, h].astype(jnp.float32)
+                ).astype(buf.dtype)
+
+    move("start", back=False)
+    move("wait", back=False)
+    each(put, firsts_only=False)
+    move("start", back=True)
+    move("wait", back=True)
+
+
+def _kv_write_call(k, v, k_cache, v_cache, layer, blk, off):
+    """k/v [T, KV_H, D]; pools [L, NB, KV_H, BS, D], aliased to the
+    result; layer int32 []; blk/off int32 [T], a guard slot's blk = NB."""
+    tokens, kv_h, d = k.shape
+    nb, block_size = k_cache.shape[1], k_cache.shape[3]
+    tile = _kv_write_tile(tokens, kv_h, block_size, d,
+                          k_cache.dtype.itemsize)
+    pad = -tokens % tile
+    blk = jnp.pad(blk, (0, pad), constant_values=nb)
+    off = jnp.pad(off, (0, pad))
+    # the tile's first token of each run of equal blocks, for every token
+    j = jnp.arange(tile, dtype=jnp.int32)
+    by_tile = blk.reshape(-1, tile)
+    first = jnp.concatenate(
+        [jnp.ones_like(by_tile[:, :1], bool),
+         by_tile[:, 1:] != by_tile[:, :-1]], axis=1)
+    slot = jax.lax.cummax(jnp.where(first, j, 0), axis=1).reshape(-1)
+
+    def rows(x):                      # what `.at[].set` would have stored
+        x = x.astype(k_cache.dtype).astype(jnp.float32)
+        return jnp.pad(x, ((0, pad), (0, 0), (0, 0)))
+
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    new_rows = pl.BlockSpec((tile, kv_h, d), lambda i, *_: (i, 0, 0))
+    buf = pltpu.VMEM((tile, kv_h, block_size, d), k_cache.dtype)
+    pool = jax.ShapeDtypeStruct(k_cache.shape, k_cache.dtype)
+    return _support.pallas_call(
+        functools.partial(_kv_write_kernel, tile=tile, num_blocks=nb),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            grid=((tokens + pad) // tile,),
+            in_specs=[new_rows, new_rows, hbm, hbm],
+            out_specs=[hbm, hbm],
+            scratch_shapes=[buf, buf, pltpu.SemaphoreType.DMA((2,))],
+        ),
+        out_shape=[pool, pool],
+        input_output_aliases={6: 0, 7: 1},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        name="kv_write_ragged",
+        interpret=_support.interpret_mode(),
+    )(layer.reshape(1), blk, off, slot, rows(k), rows(v), k_cache, v_cache)
+
+
+def kv_write_supported(pool_shape, pool_dtype) -> bool:
+    """Gate for the ragged write's kernel (`pool_shape` the pool's
+    [(L,) NB, KVH, BS, D]): float pools whose block fits the buffers.
+    Int8 pools (values and scale planes) take the XLA scatter."""
+    if not _support.kernels_enabled():
+        return False
+    if not _support.float_dtype_ok(pool_dtype):
+        return False
+    kv_h, block_size, d = pool_shape[-3:]
+    return _kv_write_tile(8, kv_h, block_size, d,
+                          np.dtype(pool_dtype).itemsize) >= 8
+
+
+def _write_ragged(kernel, k, v, k_cache, v_cache, block_tables, tok_lane,
+                  tok_pos, k_scale, v_scale, layer):
+    from ...inference import kv_quant
+
+    one_layer = k_cache.ndim == 4
+    layer, k_cache, v_cache, k_scale, v_scale = _layered(
+        layer, k_cache, v_cache, k_scale, v_scale)
+    nb, kv_h, bs = k_cache.shape[1:4]
+    pos = jnp.maximum(tok_pos, 0)
+    # a guard slot's rows go to a block past the pool: dropped
+    blk = jnp.where(tok_pos >= 0, block_tables[tok_lane, pos // bs],
+                    jnp.int32(nb))
+    off = pos % bs
+    heads = jnp.arange(kv_h, dtype=jnp.int32)
+
+    def put(pool, rows):
+        # one row a (token, head): a window that spans the head axis makes
+        # the TPU compiler re-lay the whole pool out, and back, every layer
+        return pool.at[layer, blk[:, None], heads[None, :],
+                       off[:, None]].set(rows, mode="drop")
+
+    if kernel:
+        out = _kv_write_call(k, v, k_cache, v_cache, layer, blk, off)
+    elif k_scale is not None:
+        kq, ks_tok = kv_quant.quantize_kv(k)                  # [T,KVH,(D)]
+        vq, vs_tok = kv_quant.quantize_kv(v)
+        out = (put(k_cache, kq), put(v_cache, vq),
+               put(k_scale, ks_tok), put(v_scale, vs_tok))
+    else:
+        out = put(k_cache, k), put(v_cache, v)
+    return tuple(p[0] for p in out) if one_layer else tuple(out)
+
+
 def write_kv_to_cache_ragged(k, v, k_cache, v_cache, block_tables,
                              tok_lane, tok_pos, k_scale=None,
-                             v_scale=None):
-    """Scatter packed ragged K/V tokens into the block pool.
+                             v_scale=None, layer=None):
+    """Write packed ragged K/V tokens into the block pool, in place.
 
     k/v: [T, KV_H, D] — one new token per packed slot, landing at
     absolute position `tok_pos[t]` of lane `tok_lane[t]`'s block table.
-    Guard slots (tok_pos < 0) are routed to an out-of-bounds flat index,
-    which jnp scatter DROPS under jit — no guard-block lease needed for
-    the ragged write path. Returns updated (k_cache, v_cache).
+    k_cache/v_cache: the pool as stored, [L, NB, KV_H, BS, D], with the
+    `layer` to write (an int or a traced int32 scalar); or one layer's
+    [NB, KV_H, BS, D], without. Each live token's [KV_H, D] rows go
+    straight to `pool[layer, block, :, offset, :]`: the pool is never
+    reshaped, transposed or sliced, so a donated pool is written where
+    it lies — by the kernel `kv_write_ragged` where `kv_write_supported`
+    (float pools), by an XLA scatter otherwise
+    (`write_kv_to_cache_ragged_ref`, the same bytes). Guard slots
+    (tok_pos < 0) are given a block past the pool and write NOTHING — no
+    guard-block lease needed for the ragged write path. A lane's tokens
+    sit at consecutive positions (`ragged_metadata`), and no two lanes
+    write one block. Returns updated (k_cache, v_cache), of the rank
+    given.
 
     Quantize-on-write (`inference/kv_quant.py`): when `k_scale`/
-    `v_scale` planes (f32 [NB, KVH, BS]) ride along, each token's K/V
-    quantizes to int8 with its own per-head absmax scale and BOTH the
-    int8 values and the scale scatter at the same flat index — exact,
+    `v_scale` planes (f32 [(L,) NB, KVH, BS]) ride along, each token's
+    K/V quantizes to int8 with its own per-head absmax scale and BOTH the
+    int8 values and the scale scatter at the same indices — exact,
     collision-free (no shared block scalar to read-modify-write), and
     atomic with respect to the guard-slot drop. Returns (k_cache,
     v_cache, k_scale, v_scale) in that case."""
-    from ...inference import kv_quant
+    kernel = k_scale is None and kv_write_supported(k_cache.shape,
+                                                    k_cache.dtype)
+    return _write_ragged(kernel, k, v, k_cache, v_cache, block_tables,
+                         tok_lane, tok_pos, k_scale, v_scale, layer)
 
-    tokens, kv_h, d = k.shape
-    nb, _, bs, _ = k_cache.shape
-    pos = jnp.maximum(tok_pos, 0)
-    blk = block_tables[tok_lane, pos // bs]                   # [T]
-    flat = jnp.where(tok_pos >= 0, blk * bs + pos % bs,
-                     jnp.int32(nb * bs))                      # OOB -> drop
-    kc = k_cache.swapaxes(1, 2).reshape(nb * bs, kv_h, d)
-    vc = v_cache.swapaxes(1, 2).reshape(nb * bs, kv_h, d)
-    if k_scale is not None:
-        kq, ks_tok = kv_quant.quantize_kv(k)                  # [T,KVH,(D)]
-        vq, vs_tok = kv_quant.quantize_kv(v)
-        ks = k_scale.swapaxes(1, 2).reshape(nb * bs, kv_h)
-        vs = v_scale.swapaxes(1, 2).reshape(nb * bs, kv_h)
-        kc = kc.at[flat].set(kq)
-        vc = vc.at[flat].set(vq)
-        ks = ks.at[flat].set(ks_tok)
-        vs = vs.at[flat].set(vs_tok)
-        kc = kc.reshape(nb, bs, kv_h, d).swapaxes(1, 2)
-        vc = vc.reshape(nb, bs, kv_h, d).swapaxes(1, 2)
-        ks = ks.reshape(nb, bs, kv_h).swapaxes(1, 2)
-        vs = vs.reshape(nb, bs, kv_h).swapaxes(1, 2)
-        return kc, vc, ks, vs
-    kc = kc.at[flat].set(k)
-    vc = vc.at[flat].set(v)
-    kc = kc.reshape(nb, bs, kv_h, d).swapaxes(1, 2)
-    vc = vc.reshape(nb, bs, kv_h, d).swapaxes(1, 2)
-    return kc, vc
+
+def write_kv_to_cache_ragged_ref(k, v, k_cache, v_cache, block_tables,
+                                 tok_lane, tok_pos, k_scale=None,
+                                 v_scale=None, layer=None):
+    """XLA composite of `write_kv_to_cache_ragged` (also the CPU and the
+    int8 path): one scatter a pool at `[layer, block, head, offset]`, a
+    row of D numbers a (token, head), out-of-bounds rows dropped."""
+    return _write_ragged(False, k, v, k_cache, v_cache, block_tables,
+                         tok_lane, tok_pos, k_scale, v_scale, layer)
 
 
 def supported(q_shape, dtype) -> bool:
@@ -884,7 +1085,7 @@ def verify_supported(q_shape, dtype) -> bool:
 def ragged_supported(q_shape, dtype, cache_shape, cache_dtype,
                      table_width) -> bool:
     """Gate for `paged_attention_ragged` (q: [T, H, D]; `cache_shape` the
-    pool's [NB, KVH, BS, D]). The kernel's VMEM footprint is the page
+    pool's [(L,) NB, KVH, BS, D]). The kernel's VMEM footprint is the page
     double buffer — 2 slots x K and V x `pages` pages of all kv heads —
     plus one query tile's f32 q and acc rows and lane-replicated m/l rows
     (`_ragged_tiles`); it does not grow with T or the table's width. A
@@ -898,7 +1099,7 @@ def ragged_supported(q_shape, dtype, cache_shape, cache_dtype,
         return False
     if not _support.float_dtype_ok(dtype):
         return False
-    _, kv_h, block_size, d = cache_shape
+    kv_h, block_size, d = cache_shape[-3:]
     g = q_shape[1] // kv_h
     return _ragged_tiles(q_shape[0], kv_h, _group_pad(g), d, block_size,
                          table_width, np.dtype(cache_dtype).itemsize) \

@@ -65,8 +65,9 @@ def test_every_kernel_call_site_names_its_kernel(site):
 
 def test_kernel_names_are_distinct_and_cover_the_main_path():
     names = [n.value for _f, _l, n in SITES]
-    assert len(names) == len(set(names)) == 19
-    assert {"paged_attention_ragged", "paged_attention_decode",
+    assert len(names) == len(set(names)) == 20
+    assert {"paged_attention_ragged", "kv_write_ragged",
+            "paged_attention_decode",
             "paged_attention_verify", "paged_attention_mla",
             "moe_grouped_matmul", "flash_fwd",
             "flash_dq", "flash_dkv",
@@ -131,7 +132,8 @@ def test_serving_step_holds_its_regions(ragged_text):
     assert "module @jit__ragged_fn" in ragged_text
     assert re.search(r"llama\.layer/llama\.attn/paged_attention_ragged",
                      ragged_text)
-    assert re.search(r"llama\.layer/llama\.kv_write/", ragged_text)
+    assert re.search(r"llama\.layer/llama\.kv_write/kv_write_ragged",
+                     ragged_text)
 
 
 def test_training_step_holds_its_regions(train_text):

@@ -297,6 +297,38 @@ def test_llama_engine_generate_matches_eager_greedy():
     assert eng.manager.free_blocks == 32
 
 
+@pytest.mark.parametrize("kv_bits", [16, 8])
+@pytest.mark.parametrize("path", ["kernel", "xla"])
+def test_llama_ragged_step_never_copies_the_pool(kv_bits, path):
+    """The compiled ragged step holds no temporary the size of even ONE
+    layer's K pool, and every pool argument is aliased to its output: the
+    pool is the layer loop's carry, written at `[layer, block, :, offset]`
+    and read at `[layer, block]`, never sliced out, stacked back,
+    reshaped or copied. The pool here dwarfs everything else in the step
+    (6 tokens, hidden 32), so one stray copy of a layer breaks the bound;
+    the xs/ys construction this replaced held eight layers' worth."""
+    from paddle_tpu.inference import LlamaInferenceEngine
+    from paddle_tpu.models.llama import llama_tiny
+
+    flags.set_flags({"FLAGS_pallas_interpret": path == "kernel"})
+    paddle.seed(1)
+    model = llama_tiny(vocab=64, layers=3, hidden=32, heads=4, seq=64)
+    eng = LlamaInferenceEngine(model, max_batch_size=2, num_blocks=8192,
+                               block_size=8, max_blocks_per_seq=4,
+                               kv_bits=kv_bits)
+    fn, lead = eng.cost_card_args("ragged")
+    pools = lead[1:]
+    assert len(pools) == (4 if kv_bits == 8 else 2)
+    compiled = fn.lower(
+        *lead, np.zeros(6, np.int32), np.zeros(2, np.int32),
+        np.zeros(2, np.int32), np.zeros((2, 4), np.int32)).compile()
+    mem = compiled.memory_analysis()
+    one_layer_k = eng.k_cache[0].nbytes
+    assert mem.temp_size_in_bytes < one_layer_k, (
+        mem.temp_size_in_bytes, one_layer_k)
+    assert mem.alias_size_in_bytes == sum(p.nbytes for p in pools)
+
+
 def test_block_cache_manager():
     from paddle_tpu.inference import BlockCacheManager
 

@@ -628,6 +628,102 @@ def test_kernel_lowers_for_tpu_at_7b_width(case, monkeypatch):
     assert "tpu_custom_call" in text, name
 
 
+@pytest.fixture(scope="module")
+def v5e_chip():
+    """One described (not attached) v5e chip to compile for. Made inside a
+    fixture, never at import: only one process may load libtpu, and every
+    xdist worker imports this file."""
+    import os
+
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    os.environ.setdefault("TPU_SKIP_MDS_QUERY", "1")
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2",
+            chips_per_host_bounds=(2, 2, 1), num_slices=1)
+    except Exception as e:                       # no libtpu, or it is held
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.mark.parametrize("kv_bits", [16, 8])
+def test_llama_ragged_step_compiles_for_v5e_without_a_pool_copy(
+        kv_bits, v5e_chip, monkeypatch):
+    """The Llama engine's ragged step at the benchmark's Mistral-7B size
+    (12 layers x 4097 blocks x 8 kv heads x 16 x 128, 32 lanes + a 64-token
+    chunk), compiled by the installed libtpu for a v5e from shapes alone.
+    The TPU compiler decides layouts the CPU's never meets: a scatter whose
+    update window spans the kv-head axis made it re-lay the WHOLE pool out
+    and back in every layer. So: temporaries under one layer's K pool
+    (they were 4.03 GB of copies when the pool rode the layer scan as
+    xs/ys), the pools aliased to their outputs, the kernel in the program,
+    and the layer loop still rolled."""
+    import functools
+
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.inference import llama_runner as lr
+    from paddle_tpu.ops.pallas import _support
+
+    monkeypatch.setattr(_support, "backend", lambda: "tpu")
+    layers, hidden, inter, nh, kvh, d, vocab = 12, 4096, 14336, 32, 8, 128, \
+        32768
+    nb, bs, width, lanes, tokens = 4097, 16, 128, 32, 96
+
+    def arr(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e_chip)
+
+    params = {
+        "ln1": arr((layers, hidden)), "ln2": arr((layers, hidden)),
+        "qkv_w": arr((layers, hidden, (nh + 2 * kvh) * d)),
+        "o_w": arr((layers, nh * d, hidden)),
+        "gate_up_w": arr((layers, hidden, 2 * inter)),
+        "down_w": arr((layers, inter, hidden)),
+        "embed": arr((vocab, hidden)), "final_norm": arr((hidden,)),
+        "lm_head": arr((hidden, vocab)),
+        "rope_cos": arr((2048, d // 2), jnp.float32),
+        "rope_sin": arr((2048, d // 2), jnp.float32)}
+    pool = (layers, nb, kvh, bs, d)
+    if kv_bits == 8:
+        fn = lr._ragged_q_fn
+        pools = (arr(pool, jnp.int8),) * 2 + (arr(pool[:-1], jnp.float32),) * 2
+    else:
+        fn = lr._ragged_fn
+        pools = (arr(pool),) * 2
+
+    class Cfg:
+        num_attention_heads, num_key_value_heads, head_dim = nh, kvh, d
+        hidden_size, intermediate_size = hidden, inter
+        rms_norm_eps, tie_word_embeddings = 1e-5, False
+
+    step = functools.partial(fn, cfg=lr._StaticCfg(Cfg))
+    ints = [arr(shape, jnp.int32)
+            for shape in ((tokens,), (lanes,), (lanes,), (lanes, width))]
+    # a TPU executable cannot be read back from the persistent cache here
+    cache_was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        compiled = jax.jit(
+            step, donate_argnums=tuple(range(1, 1 + len(pools)))).trace(
+            params, *pools, *ints).lower(
+            lowering_platforms=("tpu",)).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache_was)
+    mem = compiled.memory_analysis()
+    pool_bytes = [int(np.prod(p.shape)) * jnp.dtype(p.dtype).itemsize
+                  for p in pools]
+    assert mem.temp_size_in_bytes < pool_bytes[0] // layers, (
+        mem.temp_size_in_bytes, pool_bytes[0] // layers)
+    assert mem.alias_size_in_bytes >= sum(pool_bytes)
+    text = compiled.as_text()
+    assert text.count("paged_attention_ragged") and "tpu_custom_call" in text
+    assert "while(" in text          # the program's size is O(1) in depth
+
+
 def test_gate_closes_for_gspmd_partitioned_operands():
     """JAX refuses to lower a Mosaic kernel inside a GSPMD-partitioned
     program ("Mosaic kernels cannot be automatically partitioned"), so the

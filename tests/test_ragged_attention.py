@@ -313,6 +313,98 @@ class TestRaggedLiveWork:
                                        (64, 512, 16, 128), bf16, 128)
 
 
+def _stack_layers(seed, at, layers, *pools):
+    """Each one-layer pool of `pools` as layer `at` of an `[L, ...]` pool
+    whose other layers hold other draws (never zeros: a read of the wrong
+    layer must show)."""
+    rng = np.random.default_rng(seed)
+
+    def other(p):
+        if p.dtype == jnp.int8:
+            return jnp.asarray(rng.integers(-127, 128, size=p.shape),
+                               jnp.int8)
+        return jnp.asarray(rng.normal(size=p.shape), p.dtype)
+
+    return [jnp.stack([p if i == at else other(p) for i in range(layers)])
+            for p in pools]
+
+
+class TestLayerOperand:
+    """ISSUE 28: the pool as stored, `[L, NB, KVH, BS, D]`, with the layer
+    an operand. The kernel and the write at layer `i` are bitwise the
+    one-layer call on `pool[i]`; no other layer is read or written."""
+
+    @pytest.mark.parametrize("fn", ["kernel", "ref"])
+    @pytest.mark.parametrize("kind", ["bf16", "int8kv"])
+    @pytest.mark.parametrize("layer", [0, 2])
+    def test_attention_at_layer_equals_one_layer_call(self, fn, kind, layer):
+        dtype, quant = _KINDS[kind]
+        args, kw = _live_case(21, *_LIVE_CASES["chunk64_with_prior_context"],
+                              _LIVE_T, dtype=dtype, quant=quant)
+        q, kc, vc, *rest = args
+        call = {"kernel": _ragged, "ref": _ragged_ref}[fn]
+        want = np.asarray(call(*args, **kw), np.float32)
+        kc5, vc5 = _stack_layers(22, layer, 3, kc, vc)
+        kw5 = dict(zip(kw, _stack_layers(23, layer, 3, *kw.values())))
+        # `layer` traced: one executable for every layer
+        got = call(q, kc5, vc5, *rest, layer=jnp.int32(layer), **kw5)
+        np.testing.assert_array_equal(np.asarray(got, np.float32), want)
+
+    @pytest.mark.parametrize("kind", ["bf16", "int8kv"])
+    @pytest.mark.parametrize("layer", [0, 2])
+    def test_write_at_layer_equals_one_layer_call(self, kind, layer):
+        """Rows land at `[layer, block, :, offset, :]` exactly as the
+        one-layer write puts them at `[block, :, offset, :]`; every other
+        layer keeps its bytes; a guard slot writes nothing anywhere."""
+        dtype, quant = _KINDS[kind]
+        rng = np.random.default_rng(24)
+        nb, kvh, bs, d, t = 12, 2, 4, 16, 8
+        tables = jnp.asarray([[1, 2, 3], [7, 5, 9]], jnp.int32)
+        # lane 0 a 5-token chunk at positions 3..7 (two blocks), lane 1 a
+        # decode token at position 9, two guard slots
+        lane, pos = pa.ragged_metadata(jnp.asarray([5, 1], jnp.int32),
+                                       jnp.asarray([8, 10], jnp.int32), t)
+        k = jnp.asarray(rng.normal(size=(t, kvh, d)), dtype)
+        v = jnp.asarray(rng.normal(size=(t, kvh, d)), dtype)
+        pool_dtype = jnp.int8 if quant else dtype
+        pools = [jnp.asarray(rng.integers(-9, 9, size=(nb, kvh, bs, d)),
+                             pool_dtype) for _ in range(2)]
+        kw = {}
+        if quant:
+            kw = dict(k_scale=jnp.asarray(rng.random((nb, kvh, bs)),
+                                          jnp.float32),
+                      v_scale=jnp.asarray(rng.random((nb, kvh, bs)),
+                                          jnp.float32))
+        write = jax.jit(pa.write_kv_to_cache_ragged)
+        want = write(k, v, *pools, tables, lane, pos, **kw)
+        before = _stack_layers(25, layer, 3, *pools, *kw.values())
+        kw5 = dict(zip(kw, before[2:]))
+        got = write(k, v, *before[:2], tables, lane, pos,
+                    layer=jnp.int32(layer), **kw5)
+        assert len(got) == len(want) == (4 if quant else 2)
+        for new, old, one in zip(got, before, want):
+            assert new.shape == old.shape and new.dtype == old.dtype
+            np.testing.assert_array_equal(np.asarray(new[layer]),
+                                          np.asarray(one))
+            for other in set(range(3)) - {layer}:
+                np.testing.assert_array_equal(np.asarray(new[other]),
+                                              np.asarray(old[other]))
+            # six live tokens changed six (block, offset) slots, the two
+            # guard slots none
+            changed = (np.asarray(new, np.float32)
+                       != np.asarray(old, np.float32))
+            changed = changed.reshape(3, nb, kvh, bs, -1).any(axis=(2, 4))
+            assert changed.sum() == 6 and changed[layer].sum() == 6
+
+    def test_rank_decides_and_layer_must_match_it(self):
+        args, _ = _live_case(26, [1, 1], [5, 9], 8, w=4, nb=16)
+        with pytest.raises(ValueError, match="one layer"):
+            pa.paged_attention_ragged(*args, layer=0)
+        q, kc, vc, *rest = args
+        with pytest.raises(ValueError, match="needs its `layer`"):
+            pa.paged_attention_ragged(q, kc[None], vc[None], *rest)
+
+
 class TestRaggedWrite:
     def test_scatter_lands_at_positions_and_drops_guards(self):
         rng = np.random.default_rng(7)
