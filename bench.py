@@ -319,54 +319,6 @@ def _eager_microbench():
     return out
 
 
-def _decode_microbench(on_tpu: bool):
-    """bf16 vs int8-weight-only decode throughput (round-3 VERDICT item 2
-    'done' bar). 7B layer shapes on TPU (2 layers fit comfortably), tiny
-    shapes on CPU; reports tokens/sec for both weight formats."""
-    import time
-
-    import jax
-    import numpy as np
-
-    from paddle_tpu.inference.llama_runner import LlamaInferenceEngine
-    from paddle_tpu.models import llama_7b_shaped, llama_tiny
-
-    model = llama_7b_shaped(num_layers=2) if on_tpu else \
-        llama_tiny(layers=2, hidden=128, heads=4, seq=64)
-    model.eval()
-    batch = 8 if on_tpu else 2
-    prompt = np.ones((batch, 8), np.int32)
-    out = {}
-    for mode, kw in (("bf16", {"dtype": "bfloat16"} if on_tpu else {}),
-                     ("int8", ({"dtype": "bfloat16"} if on_tpu else {})
-                      | {"weight_only": "int8"})):
-        eng = LlamaInferenceEngine(model, max_batch_size=batch,
-                                   num_blocks=batch * 16 + 8, **kw)
-        tables = np.zeros((batch, eng.manager.max_blocks_per_seq), np.int32)
-        for b in range(batch):
-            tables[b] = np.arange(eng.manager.max_blocks_per_seq) \
-                + b * eng.manager.max_blocks_per_seq
-        logits = eng.prefill(prompt, tables)
-        toks = np.argmax(np.asarray(logits), axis=-1).astype(np.int32)
-        lens = np.full((batch,), prompt.shape[1], np.int32)
-        # warm the decode executable
-        l2 = eng.decode_step(toks, lens, tables)
-        jax.block_until_ready(l2)
-        steps = 32 if on_tpu else 8
-        t0 = time.perf_counter()
-        for i in range(steps):
-            l2 = eng.decode_step(toks, lens + 1 + i, tables)
-        jax.block_until_ready(l2)
-        dt = (time.perf_counter() - t0) / steps
-        out[f"{mode}_decode_tok_per_sec"] = round(batch / dt, 1)
-        out[f"{mode}_decode_step_ms"] = round(dt * 1e3, 2)
-        del eng
-    if out.get("bf16_decode_step_ms"):
-        out["int8_speedup"] = round(
-            out["bf16_decode_step_ms"] / out["int8_decode_step_ms"], 2)
-    return out
-
-
 def _drive_poisson(fe, arrivals, submit_one):
     """Open-loop Poisson driver shared by the throughput and overload
     scenarios: submit each request at its arrival offset (sleeping only
@@ -429,7 +381,6 @@ def serving_throughput_main():
         fe.submit(rng.integers(1, 128, n).tolist(), max_new_tokens=2)
     fe.run_until_idle(max_steps=500)
     monitor.reset("serving.decode_retraces")
-    monitor.reset("serving.prefill_retraces")
     # warmup requests paid the compiles; their latencies/occupancy are not
     # the trace's, and counters are deltas from here
     fe.metrics.reset_window()
@@ -469,8 +420,6 @@ def serving_throughput_main():
         "decode_steps": monitor.get("serving.decode_steps") - base_steps,
         "decode_retraces_after_warmup":
             monitor.get("serving.decode_retraces"),
-        "prefill_retraces_after_warmup":
-            monitor.get("serving.prefill_retraces"),
         "poisson_mean_gap_ms": mean_gap_s * 1e3,
         "device": device,
         "device": jax.devices()[0].device_kind or "cpu",
@@ -725,8 +674,8 @@ def serving_spec_main():
             fe.submit(rng.integers(1, 128, n).tolist(), max_new_tokens=3)
         fe.run_until_idle(max_steps=500)
         fe.metrics.reset_window()
-        for c in ("serving.decode_retraces", "serving.prefill_retraces",
-                  "serving.verify_retraces", "serving.sample_retraces"):
+        for c in ("serving.decode_retraces", "serving.verify_retraces",
+                  "serving.sample_retraces"):
             monitor.reset(c)
         base_tok = monitor.get("serving.tokens_generated")
         hs = [fe.submit(p, max_new_tokens=g)
@@ -878,65 +827,16 @@ def serving_mixed_main():
     }
     chunked["tpot_degradation_x"] = round(
         chunked["prefill_tpot_p99_ms"] / chunked["steady_tpot_p99_ms"], 3)
-    # monolithic contrast: the PRE-ISSUE-10 architecture — per-request
-    # full-prompt prefill as its own dispatch, decode lanes blocked for
-    # its whole wall. Driven on raw engine calls (the old scheduler's
-    # shapes): steady [B] decode steps, then ONE [1, long_len] prefill.
-    eng = build_engine()
-    mgr = eng.manager
-    sids = list(range(6))
-    for sid in sids:
-        mgr.allocate(sid, 12)
-    maxb = mgr.max_blocks_per_seq
-    tb = np.zeros((8, maxb), np.int32)
-    tb[:6] = mgr.block_table_array(sids)
-    pad = np.zeros((8, 12), np.int32)
-    pad[:6] = rng.integers(1, 128, (6, 12))
-    logits = eng.prefill(pad, tb, np.full((8,), 12, np.int32))
-    toks = np.argmax(np.asarray(logits), -1).astype(np.int32)
-    for sid in sids:
-        mgr.append_token(sid)
-    lens = np.full((8,), 1, np.int32)
-    lens[:6] = [mgr.seq_len(s) for s in sids]
-    import jax as _jax
-
-    _jax.block_until_ready(eng.decode_step(toks, lens, tb))  # warm
-    m_steady = []
-    for _ in range(40):
-        t0 = time.perf_counter()
-        _jax.block_until_ready(eng.decode_step(toks, lens, tb))
-        m_steady.append(time.perf_counter() - t0)
-    mgr.allocate(7, long_len)
-    tb1 = mgr.block_table_array([7])
-    long_ids = rng.integers(1, 128, (1, long_len)).astype(np.int32)
-    # warm once: the measured stall is the steady-state dispatch, not
-    # the compile (the old bucket family compiled once per bucket too)
-    _jax.block_until_ready(eng.prefill(long_ids, tb1,
-                                       np.asarray([long_len], np.int32)))
-    t0 = time.perf_counter()
-    _jax.block_until_ready(eng.prefill(long_ids, tb1,
-                                       np.asarray([long_len], np.int32)))
-    mono_prefill_s = time.perf_counter() - t0
-    mono = {
-        "steady_tpot_p99_ms": round(p99(m_steady) * 1e3, 3),
-        "stall_step_ms": round(mono_prefill_s * 1e3, 3),
-    }
-    mono["tpot_degradation_x"] = round(
-        mono_prefill_s / p99(m_steady), 3)
-
     # hard in-run checks: the acceptance contract
     assert chunked["tpot_degradation_x"] < 1.5, \
         f"chunked prefill stalls decode: {chunked['tpot_degradation_x']}x"
     assert retraces == 0, \
         f"ragged step retraced {retraces}x mid-serving (prompt-length " \
         f"shaped executables are back)"
-    assert mono["tpot_degradation_x"] > chunked["tpot_degradation_x"], \
-        "monolithic baseline shows no stall: the contrast is meaningless"
     extras = {
         "long_prompt_tokens": long_len,
         "prefill_chunk_tokens": chunk,
         "chunked": chunked,
-        "monolithic": mono,
         "tpot_p99_during_prefill_ms": chunked["prefill_tpot_p99_ms"],
         "tpot_degradation_x": chunked["tpot_degradation_x"],
         "device": device,
@@ -947,8 +847,7 @@ def serving_mixed_main():
         "value": chunked["decode_tok_s_during_prefill"],
         "unit": f"decode tok/s while a {long_len}-token prompt prefills "
                 f"(TPOT p99 {chunked['prefill_tpot_p99_ms']} ms = "
-                f"{chunked['tpot_degradation_x']}x steady; monolithic "
-                f"stall {mono['stall_step_ms']} ms)",
+                f"{chunked['tpot_degradation_x']}x steady)",
         "vs_baseline": None,
         "extras": extras,
     }, "serving_mixed")
@@ -2140,142 +2039,6 @@ def serving_disagg_main():
     }, "serving_disagg")
 
 
-@scenario("kernel_micro", 300)
-def kernel_micro_main():
-    """`python bench.py kernel_micro` — paged-attention kernel microbench
-    (ROADMAP item 5's missing kernel scenario): ragged vs legacy
-    decode/verify dispatch wall time across batch compositions. On TPU
-    this times the Pallas kernels; on CPU the XLA reference paths (the
-    production fallback), platform-tagged like every other scenario.
-    The ragged kernel takes one grid step a lane and walks that lane's
-    live pages and live tokens; the legacy pair still visit batch x kv
-    heads x table width, so the `*_vs_legacy_x` ratios grow with the
-    table's dead width (here the tables are 8 pages, most of them live).
-
-    Extras also carry `tp_ragged_cost` (ISSUE 16): the TP-sharded ragged
-    executable's XLA cost card next to the single-chip one — lowering
-    the SPMD program via `ShardedEngine.cost_card_args` reports PER-CHIP
-    FLOPs/bytes, so the %peak math stops counting the replicated
-    illusion. The CPU backend is forced to 8 virtual devices before jax
-    initializes so the tp=2 mesh always forms (real multi-device
-    backends use their own devices)."""
-    flags = os.environ.get("XLA_FLAGS", "")
-    if "xla_force_host_platform_device_count" not in flags:
-        os.environ["XLA_FLAGS"] = (
-            flags + " --xla_force_host_platform_device_count=8").strip()
-    device = _scenario_setup("kernel_micro")
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-
-    from paddle_tpu.ops.pallas import paged_attention as pa
-
-    on_tpu = jax.devices()[0].platform != "cpu"
-    rng = np.random.default_rng(0)
-    NB, KVH, BS, D, H = 128, 2, 16, 64, 8
-    B, MAXB = 8, 8
-    kc = jnp.asarray(rng.normal(size=(NB, KVH, BS, D)), jnp.float32)
-    vc = jnp.asarray(rng.normal(size=(NB, KVH, BS, D)), jnp.float32)
-    tables = jnp.asarray(rng.permutation(NB - 1)[:B * MAXB].reshape(
-        B, MAXB) + 1, jnp.int32)
-
-    decode_fn = pa.paged_attention if on_tpu else pa.paged_attention_ref
-    verify_fn = (pa.paged_attention_verify if on_tpu
-                 else pa.paged_attention_verify_ref)
-    ragged_fn = (pa.paged_attention_ragged if on_tpu
-                 else pa.paged_attention_ragged_ref)
-
-    def timed(fn, *args, reps=50):
-        f = jax.jit(fn)
-        jax.block_until_ready(f(*args))
-        t0 = time.perf_counter()
-        for _ in range(reps):
-            out = f(*args)
-        jax.block_until_ready(out)
-        return (time.perf_counter() - t0) / reps * 1e6   # us/dispatch
-
-    def ragged_args(q_lens, kv_lens, t):
-        lane, pos = pa.ragged_metadata(jnp.asarray(q_lens, jnp.int32),
-                                       jnp.asarray(kv_lens, jnp.int32), t)
-        q = jnp.asarray(rng.normal(size=(t, H, D)), jnp.float32)
-        return q, kc, vc, tables, jnp.asarray(kv_lens, jnp.int32), lane, pos
-
-    out = {}
-    # composition 1: pure decode, 8 lanes
-    kv = [97, 64, 33, 120, 8, 77, 50, 101]
-    q1 = jnp.asarray(rng.normal(size=(B, H, D)), jnp.float32)
-    out["decode_legacy_us"] = timed(decode_fn, q1, kc, vc, tables,
-                                    jnp.asarray(kv, jnp.int32))
-    out["decode_ragged_us"] = timed(ragged_fn, *ragged_args([1] * B, kv, B))
-    # composition 2: mixed — 7 decode lanes + one 32-token chunk (the
-    # serving hot shape; no legacy equivalent in ONE dispatch)
-    mixed_q = [1] * 7 + [32]
-    mixed_kv = kv[:7] + [96]
-    t_mixed = 7 + 32
-    out["mixed_ragged_us"] = timed(
-        ragged_fn, *ragged_args(mixed_q, mixed_kv, t_mixed))
-    out["mixed_ragged_tok_s"] = round(t_mixed / out["mixed_ragged_us"]
-                                      * 1e6)
-    # composition 3: verify window, 8 lanes x 5 tokens
-    S = 5
-    qv = jnp.asarray(rng.normal(size=(B, S, H, D)), jnp.float32)
-    kv_v = [k + S for k in kv]
-    out["verify_legacy_us"] = timed(verify_fn, qv, kc, vc, tables,
-                                    jnp.asarray(kv_v, jnp.int32))
-    out["verify_ragged_us"] = timed(
-        ragged_fn, *ragged_args([S] * B, kv_v, B * S))
-    for k in out:
-        if k.endswith("_us"):
-            out[k] = round(out[k], 1)
-    out["decode_ragged_vs_legacy_x"] = round(
-        out["decode_legacy_us"] / out["decode_ragged_us"], 3)
-    out["verify_ragged_vs_legacy_x"] = round(
-        out["verify_legacy_us"] / out["verify_ragged_us"], 3)
-    extras = dict(out, device=device, shapes={
-        "blocks": NB, "block_size": BS, "kv_heads": KVH, "heads": H,
-        "head_dim": D, "lanes": B, "impl": "pallas" if on_tpu else
-        "xla_ref"})
-    # ---- TP-sharded ragged executable: per-chip cost card (ISSUE 16).
-    # The same ragged step, single-chip vs tp=2: per-chip FLOPs must be
-    # the sharded fraction, not the replicated total.
-    try:
-        from paddle_tpu.observability import costs as _costs
-        from paddle_tpu.serving import MLPLMEngine, shard_engine
-
-        ekw = dict(vocab_size=128, hidden=32, max_batch_size=8,
-                   num_blocks=64, block_size=4, max_blocks_per_seq=8,
-                   seed=0)
-        rag = (np.zeros((16,), np.int32), np.ones((8,), np.int32),
-               np.ones((8,), np.int32),
-               np.zeros((8, 8), np.int32))
-
-        def card_of(engine):
-            fn, lead = engine.cost_card_args("ragged")
-            c = _costs.card_from_lowered(fn, *lead, *rag)
-            return {"flops_per_step": c.flops,
-                    "bytes_accessed_per_step": c.bytes_accessed}
-
-        single = card_of(MLPLMEngine(**ekw))
-        tp2 = card_of(shard_engine(MLPLMEngine(**ekw), tp=2,
-                                   overlap=True, overlap_tiles=2))
-        extras["tp_ragged_cost"] = {
-            "single_chip": single, "tp2_per_chip": tp2,
-            "per_chip_flops_fraction": round(
-                tp2["flops_per_step"] / single["flops_per_step"], 3)
-            if single["flops_per_step"] else None}
-    except Exception as e:  # evidence, not the gated contract
-        extras["tp_ragged_cost"] = f"{type(e).__name__}: {str(e)[:120]}"
-    _emit_report({
-        "metric": "kernel_micro_paged_attention",
-        "value": out["mixed_ragged_tok_s"],
-        "unit": f"ragged tok/s on the mixed 7-decode+32-chunk dispatch "
-                f"(decode ragged/legacy {out['decode_ragged_vs_legacy_x']}"
-                f"x, verify {out['verify_ragged_vs_legacy_x']}x)",
-        "vs_baseline": None,
-        "extras": extras,
-    }, "kernel_micro")
-
-
 @scenario("dryrun_multichip", 300)
 def dryrun_multichip_main():
     """`python bench.py dryrun_multichip` — the 8-virtual-device CPU mesh
@@ -2736,10 +2499,6 @@ def train_mfu_main():
 
     # Eager dispatch microbench (round-3 VERDICT weak-item 1)
     extras["eager_dispatch"] = _eager_microbench()
-    gc.collect()
-
-    # bf16 vs int8 weight-only decode (round-3 VERDICT item 2)
-    extras["weight_only_decode"] = _decode_microbench(on_tpu)
     gc.collect()
 
     # flash-vs-sdpa microbench on the measured attention shape

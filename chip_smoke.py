@@ -640,14 +640,19 @@ def kernel_cases(z):
         return lambda q, kq, ks, vq, vs, *rest: fn(
             q, kq, vq, *rest, k_scale=ks, v_scale=vs)
 
-    def decode_args(key, *q_shape):
-        # context lengths from the window itself (one token, or the S of a
-        # verify pass, all of which the cache already counts) to full
+    def decode_args(key, window=1):
+        # every lane `window` tokens (one, or the S of a verify pass, all
+        # of which the cache already counts), contexts from that to full
         kc, vc, tables = pool(key)
-        lo = q_shape[0] if len(q_shape) == 3 else 1
-        lens = jnp.asarray([lo + (cap - lo) * i // (B - 1)
+        lens = jnp.asarray([window + (cap - window) * i // (B - 1)
                             for i in range(B)], i32)
-        return normal(key, 0, (B,) + q_shape), kc, vc, tables, lens
+        return normal(key, 0, (B * window, H, D)), kc, vc, tables, lens
+
+    def window_args(key):
+        # the verify step's composition: 4 tokens a lane
+        q, kc, vc, tables, lens = decode_args(key, 4)
+        lane, pos = pk.ragged_metadata(jnp.full((B,), 4, i32), lens, B * 4)
+        return q, kc, vc, tables, lens, lane, pos
 
     def with_grads(attention):
         def run(q, k, v, g):
@@ -690,12 +695,10 @@ def kernel_cases(z):
          ragged_q(pk.paged_attention_ragged_ref)),
         ("kv_write_ragged bf16, the cells' shape", write_args,
          pk.write_kv_to_cache_ragged, pk.write_kv_to_cache_ragged_ref),
-        ("paged_attention (legacy decode)",
-         lambda key: decode_args(key, H, D),
+        ("paged_attention_ragged bf16, a verify window a lane", window_args,
+         pk.paged_attention_ragged, pk.paged_attention_ragged_ref),
+        ("paged_attention (q_len 1 of the ragged kernel)", decode_args,
          pk.paged_attention, pk.paged_attention_ref),
-        ("paged_attention_verify",
-         lambda key: decode_args(key, 4, H, D),
-         pk.paged_attention_verify, pk.paged_attention_verify_ref),
         # one sequence of the batch: the composite holds the [H, S, S]
         # scores and their cotangents in f32, 13 GiB of temporaries at b=2
         # (the kernel at the full batch is inside the train step above)

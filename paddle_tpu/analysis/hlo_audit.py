@@ -233,7 +233,7 @@ def _exe_ragged_decode():
     q_lens = np.array([1, 1, 2, 0], np.int32)
     kv_lens = np.array([3, 1, 2, 0], np.int32)
     tables = np.zeros((B, 4), np.int32)
-    return eng._ragged, (eng.params, eng.cache, tokens, q_lens, kv_lens,
+    return eng._ragged, (eng.params, eng.pools, tokens, q_lens, kv_lens,
                          tables)
 
 
@@ -250,7 +250,7 @@ def _exe_verify():
     tokens = np.zeros((B, S), np.int32)
     ctx = np.full((B,), S, np.int32)
     tables = np.zeros((B, 4), np.int32)
-    return eng._verify, (eng.params, eng.cache, tokens, ctx, tables)
+    return eng._verify, (eng.params, eng.pools, tokens, ctx, tables)
 
 
 def _exe_sampler():
@@ -290,8 +290,8 @@ def _exe_ragged_decode_quant():
     q_lens = np.array([1, 1, 2, 0], np.int32)
     kv_lens = np.array([3, 1, 2, 0], np.int32)
     tables = np.zeros((B, 4), np.int32)
-    return eng._ragged, (eng.params, eng.cache, eng.cache_scale, tokens,
-                         q_lens, kv_lens, tables)
+    return eng._ragged, (eng.params, eng.pools, tokens, q_lens, kv_lens,
+                         tables)
 
 
 def _exe_ragged_decode_lora():
@@ -441,7 +441,7 @@ def _exe_kv_extract():
     eng = MLPLMEngine(vocab_size=64, hidden=16, max_batch_size=4,
                       num_blocks=16, block_size=4, max_blocks_per_seq=4)
     idx = np.zeros((4,), np.int32)
-    return eng._kv_gather, (eng.cache, idx)
+    return eng._kv_gather, (eng.pools, idx)
 
 
 def _exe_kv_inject():
@@ -458,9 +458,9 @@ def _exe_kv_inject():
     eng = MLPLMEngine(vocab_size=64, hidden=16, max_batch_size=4,
                       num_blocks=16, block_size=4, max_blocks_per_seq=4)
     idx = np.zeros((4,), np.int32)
-    slab = np.zeros((4,) + tuple(eng.cache.shape[1:]),
-                    np.dtype(eng.cache.dtype))
-    return eng._kv_scatter, (eng.cache, idx, slab)
+    cache, = eng.pools
+    slab = np.zeros((4,) + tuple(cache.shape[1:]), np.dtype(cache.dtype))
+    return eng._kv_scatter, (eng.pools, idx, (slab,))
 
 
 EXECUTABLES = {

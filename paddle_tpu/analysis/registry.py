@@ -42,10 +42,8 @@ HOT_PATHS = {
     ("serving/scheduler.py", "Scheduler._commit_token"),
     ("serving/frontend.py", "ServingFrontend.step"),
     ("serving/engine.py", "MLPLMEngine.ragged_step"),
-    ("serving/engine.py", "MLPLMEngine.decode_step"),
     ("serving/engine.py", "MLPLMEngine.verify_step"),
     ("inference/llama_runner.py", "LlamaInferenceEngine.ragged_step"),
-    ("inference/llama_runner.py", "LlamaInferenceEngine.decode_step"),
     ("inference/llama_runner.py", "LlamaInferenceEngine.verify_step"),
     ("ops/sampling.py", "sample_tokens"),
     ("inference/cache.py", "BlockCacheManager.append_tokens"),
@@ -53,8 +51,7 @@ HOT_PATHS = {
     # PR 14's quantized pools extend them to move int8 blocks + scale
     # planes in one donated executable — still one dispatch, no per-call
     # host conversions allowed
-    ("serving/engine.py", "MLPLMEngine.copy_kv_block"),
-    ("inference/llama_runner.py", "LlamaInferenceEngine.copy_kv_block"),
+    ("inference/kv_migrate.py", "PagedPools.copy_kv_block"),
     # the TP-sharded dispatch surfaces (ISSUE 16): every token of every
     # multichip serving run crosses these — the shard_map program is one
     # dispatch; stray host work here multiplies by tp chips' worth of
@@ -62,14 +59,12 @@ HOT_PATHS = {
     ("serving/tp.py", "ShardedEngine.ragged_step"),
     ("serving/tp.py", "ShardedEngine.verify_step"),
     ("serving/tp.py", "ShardedEngine._dispatch"),
-    ("serving/tp.py", "ShardedEngine.copy_kv_block"),
     # the multi-LoRA dispatch surfaces (ISSUE 18): every token of every
     # multi-adapter serving run crosses these; the per-lane adapter-slot
     # install runs before EVERY ragged/verify round — stray per-call
     # imports or host conversions here tax every tenant at once
     ("serving/lora.py", "LoRAEngine.ragged_step"),
     ("serving/lora.py", "LoRAEngine.verify_step"),
-    ("serving/lora.py", "LoRAEngine.copy_kv_block"),
     ("serving/lora.py", "LoRAEngine.set_lane_adapters"),
     ("serving/scheduler.py", "Scheduler._install_lane_adapters"),
     # the elastic supervisor's per-step heartbeat: one membership-store
@@ -81,12 +76,8 @@ HOT_PATHS = {
     # relocation — per-call host conversions or blocking I/O here would
     # put a wall between the tiers; the disagg pump wraps them once per
     # router step
-    ("serving/engine.py", "MLPLMEngine.extract_kv_blocks"),
-    ("serving/engine.py", "MLPLMEngine.inject_kv_blocks"),
-    ("inference/llama_runner.py", "LlamaInferenceEngine.extract_kv_blocks"),
-    ("inference/llama_runner.py", "LlamaInferenceEngine.inject_kv_blocks"),
-    ("serving/tp.py", "ShardedEngine.extract_kv_blocks"),
-    ("serving/tp.py", "ShardedEngine.inject_kv_blocks"),
+    ("inference/kv_migrate.py", "PagedPools.extract_kv_blocks"),
+    ("inference/kv_migrate.py", "PagedPools.inject_kv_blocks"),
     ("serving/disagg.py", "DisaggRouter._pump_handoffs"),
 }
 

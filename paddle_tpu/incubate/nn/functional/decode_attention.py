@@ -222,7 +222,8 @@ def block_multihead_attention(
                                       kc, vc, tables, start)
         ctx = jnp.asarray(dec + 1, jnp.int32)
         qd = q.reshape(b, h, d)
-        if pk.supported(qd.shape, qd.dtype):
+        if pk.ragged_supported(qd.shape, qd.dtype, kc.shape, kc.dtype,
+                               tables.shape[1]):
             out = pk.paged_attention(qd, kc, vc, tables, ctx)
         else:
             out = pk.paged_attention_ref(qd, kc, vc, tables, ctx)

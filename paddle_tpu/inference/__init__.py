@@ -11,9 +11,9 @@ Components:
   copy-on-write sharing (cache.py).
 - `RadixPrefixCache`: shared-prefix radix tree over the paged pool —
   committed KV reused across requests/sessions (prefix_cache.py).
-- `LlamaInferenceEngine` / `GenerationConfig`: fused scan-over-layers
-  prefill+decode programs with the Pallas paged-attention kernel
-  (llama_runner.py).
+- `LlamaInferenceEngine` / `GenerationConfig`: ONE fused
+  scan-over-layers ragged step with the Pallas paged-attention kernel
+  (llama_runner.py); `generate()` is a host loop over it (generate.py).
 """
 from .cache import BlockCacheManager, KVCacheExhausted, SequenceTooLong
 from .prefix_cache import RadixPrefixCache

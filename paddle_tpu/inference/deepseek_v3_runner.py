@@ -11,9 +11,9 @@ an `EngineCore` whose paged pool holds ONE latent row a token a layer.
   pool became the scan's carry, PERF.md PR 28): here the layers are
   unrolled and each scatters into, and reads from, the one buffer. `row` is the latent width rounded up to whole 128-lane tiles
   (576 -> 640, zero columns; `ops/pallas/paged_attention_mla.py` says why).
-- `ragged_step` is the one compiled step, `verify_step` a case of it; the
-  legacy `prefill` / `decode_step` / `generate` raise, as `ShardedEngine`'s
-  do. Guard slots (`q_len` 0) write nothing and reach no expert.
+- `ragged_step` is the one compiled step, `verify_step` a case of it and
+  `generate` a host loop over it (`inference/generate.py`). Guard slots
+  (`q_len` 0) write nothing and reach no expert.
 - Expert load is counted inside the step, on the device, in donated
   counters: no host fetch a step. `expert_load()` reads them.
 
@@ -36,6 +36,7 @@ from ..ops.pallas import paged_attention_mla as pm
 from ..ops.pallas.paged_attention import ragged_metadata
 from . import kv_migrate
 from .cache import BlockCacheManager
+from .generate import generate
 
 __all__ = ["DeepseekV3InferenceEngine"]
 
@@ -177,20 +178,7 @@ class DeepseekV3InferenceEngine:
             np.asarray(block_tables, np.int32))
         return logits
 
-    def _no_legacy(self, entry: str):
-        raise RuntimeError(
-            f"{entry} is a legacy entry point; a {FAMILY} engine serves "
-            "through ragged_step/verify_step (the scheduler's only "
-            "dispatches)")
-
-    def prefill(self, *a, **k):
-        self._no_legacy("prefill")
-
-    def decode_step(self, *a, **k):
-        self._no_legacy("decode_step")
-
-    def generate(self, *a, **k):
-        self._no_legacy("generate")
+    generate = generate
 
     # ---- hooks the scheduler and the cache manager look for ----
     def copy_kv_block(self, src: int, dst: int) -> None:

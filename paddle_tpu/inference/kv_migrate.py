@@ -12,13 +12,17 @@ replicas.
 
 Layout contract (who owns what):
 
-- Engines own the device work. `extract_kv_blocks(seq_id)` /
-  `inject_kv_blocks(seq_id, payload)` live on `MLPLMEngine`,
-  `LlamaInferenceEngine`, and `ShardedEngine`; each builds its
-  gather/scatter jits ONCE at construction. The gather is NOT donated
-  (the source pool lives on — extraction is a copy); the scatter
-  donates the destination pool like every other pool-mutating
-  executable.
+- Engines own the pools: `self.pools`, a tuple of device arrays paged
+  along one block axis (`(k, v)`, with `(k_scale, v_scale)` when int8;
+  `(cache,)` or `(cache, cache_scale)` for the MLP engine).
+- `PagedPools` (below) owns what is done with whole blocks of such a
+  tuple, for `MLPLMEngine`, `LlamaInferenceEngine`, `LoRAEngine` and
+  `ShardedEngine` alike: `copy_kv_block`, `extract_kv_blocks(seq_id)`,
+  `inject_kv_blocks(seq_id, payload)`, one jit each, built ONCE at
+  construction, every pool of the tuple in the same executable. The
+  gather is NOT donated (the source pool lives on — extraction is a
+  copy); copy and scatter donate the pools like every other
+  pool-mutating executable.
 - This module owns the wire format: the versioned header, the
   fixed-shape index padding, and the pre-inject validation.
 
@@ -44,7 +48,7 @@ from typing import Any, Dict, Mapping, Sequence
 import numpy as np
 
 __all__ = ["PAYLOAD_VERSION", "KVMigrationError", "KVBlockPayload",
-           "pad_block_indices", "check_header"]
+           "PagedPools", "pad_block_indices", "check_header"]
 
 PAYLOAD_VERSION = 1
 
@@ -140,3 +144,79 @@ def check_header(header: Mapping[str, Any],
                 f"payload header mismatch on {key!r}: payload has "
                 f"{header[key]!r}, target engine expects "
                 f"{expected[key]!r}")
+
+
+class PagedPools:
+    """Whole-block operations over an engine's `self.pools`.
+
+    The engine supplies `pools` (a tuple of arrays whose block axis is
+    the `axis` given to `_build_block_ops`), `manager`, `_mig_header`
+    (its geometry, validated against a payload's) and `_slab_names` (the
+    payload's name for each pool, in order). Every pool of the tuple
+    moves in the same executable, so an int8 block and its scale rows
+    never tear apart; indices trace as int32, so nothing here retraces."""
+
+    def _build_block_ops(self, axis: int) -> None:
+        import jax
+
+        lead = (slice(None),) * axis
+        self._copy_block = jax.jit(
+            lambda pools, s, d: jax.tree.map(
+                lambda p: p.at[lead + (d,)].set(p[lead + (s,)]), pools),
+            donate_argnums=(0,))
+        self._kv_gather = jax.jit(
+            lambda pools, i: jax.tree.map(lambda p: p[lead + (i,)], pools))
+        self._kv_scatter = jax.jit(
+            lambda pools, i, slabs: jax.tree.map(
+                lambda p, s: p.at[lead + (i,)].set(s), pools, slabs),
+            donate_argnums=(0,))
+
+    def copy_kv_block(self, src: int, dst: int) -> None:
+        """Copy one physical block, every layer and plane
+        (`BlockCacheManager` COW hook — the scheduler wires it when
+        prefix caching is on). Positions past the writer's divergence
+        point are overwritten or never attended."""
+        self.pools = self._copy_block(self.pools, np.int32(src),
+                                      np.int32(dst))
+
+    def extract_kv_blocks(self, seq_id: int) -> KVBlockPayload:
+        """Export `seq_id`'s committed blocks as ONE device gather (the
+        disaggregated handoff / KV-shipping relocation, ISSUE 17). The
+        source pools are untouched; indices pad to the fixed
+        `max_blocks_per_seq` shape, so every sequence length rides one
+        compiled executable. Under TP the slabs stay sharded, each chip
+        contributing its slice."""
+        mgr = self.manager
+        blocks = mgr.blocks_of(seq_id)
+        if not blocks:
+            raise KVMigrationError(
+                f"sequence {seq_id} holds no KV blocks on this engine")
+        idx = pad_block_indices(blocks, mgr.max_blocks_per_seq)
+        header = dict(self._mig_header, num_blocks=len(blocks),
+                      num_tokens=mgr.seq_len(seq_id))
+        return KVBlockPayload(header, dict(zip(
+            self._slab_names, self._kv_gather(self.pools, idx))))
+
+    def inject_kv_blocks(self, seq_id: int, payload: KVBlockPayload) -> None:
+        """Import a migrated payload under `seq_id`: typed header
+        validation BEFORE any allocation, the manager's typed capacity
+        errors propagate from `allocate`, one donated scatter writes
+        every pool; any failure after allocation frees the blocks, so a
+        failed inject never leaks. The payload's slabs are not donated
+        (one payload can stream to several workers)."""
+        mgr = self.manager
+        check_header(payload.header, self._mig_header)
+        blocks = mgr.allocate(seq_id, payload.num_tokens)
+        try:
+            if len(blocks) != payload.num_blocks:
+                raise KVMigrationError(
+                    f"payload carries {payload.num_blocks} blocks but "
+                    f"{payload.num_tokens} tokens allocate "
+                    f"{len(blocks)} here")
+            idx = pad_block_indices(blocks, mgr.max_blocks_per_seq)
+            self.pools = self._kv_scatter(
+                self.pools, idx,
+                tuple(payload.slabs[n] for n in self._slab_names))
+        except Exception:
+            mgr.free(seq_id)
+            raise

@@ -42,7 +42,6 @@ GATED_METRICS: Dict[str, List[Tuple]] = {
     # prefill window must not grow
     "serving_mixed": [("value", "higher"),
                       ("extras.tpot_p99_during_prefill_ms", "lower")],
-    "kernel_micro": [("value", "higher")],
     # shared-prefix radix caching (ROADMAP item 1): throughput on the
     # 80 %-shared-prefix trace and tail TTFT of the shared requests
     # (the population the cache exists for) must not regress; the

@@ -1,4 +1,4 @@
-"""Pallas TPU paged (block) KV-cache attention — the decode kernel.
+"""Pallas TPU paged (block) KV-cache attention.
 
 TPU-native equivalent of the reference's paged-attention CUDA kernel
 (`paddle/phi/kernels/fusion/gpu/block_multi_head_attention_kernel.cu`, python
@@ -7,16 +7,15 @@ pool of fixed-size blocks; each sequence owns a list of block ids (its block
 table), so cache memory is allocated in O(block_size) granules instead of one
 max-seqlen slab per sequence.
 
-Kernel design (TPU-first, not a CUDA translation):
-- grid = (batch, kv_heads, max_blocks_per_seq); the block table and context
-  lengths ride scalar prefetch (SMEM) so the K/V ``BlockSpec`` index maps can
-  gather the *physical* block for each (seq, logical-block) pair — the gather
-  happens in the pipeline's DMA engine, not in the kernel body.
-- GQA is native: the q block is the whole query-head group [G, D] for one kv
-  head, so the kernel's matmuls are (G×D)·(D×BS) on the MXU with no KV
-  repetition in HBM.
-- online softmax (flash-style) accumulates across logical blocks in VMEM
-  scratch; the output is written once on the last block step.
+ONE kernel body, `_ragged_kernel` (TPU-first, not a CUDA translation):
+- grid = (lanes,); lengths, spans and the block table ride scalar prefetch
+  (SMEM), q, the pools and the output stay in HBM and the body DMAs a lane's
+  live pages and live tokens, so its work follows them, never the table's
+  width. Decode (`q_len` 1), prefill chunks and verify windows share it;
+  `paged_attention(q [B, H, D], ...)` is its `q_len == 1` case.
+- GQA is native: a query-head group is a band of MXU rows against one kv
+  head's page group, with no KV repetition in HBM.
+- online softmax (flash-style) accumulates across page groups in VMEM.
 
 Caches use the reference layout ``[num_blocks, kv_heads, block_size, head_dim]``.
 The serving step's two functions, `write_kv_to_cache_ragged` and
@@ -37,248 +36,6 @@ from jax.experimental.pallas import tpu as pltpu
 from . import _support
 
 NEG_INF = -1e30
-
-
-def _decode_kernel(lens_ref, tables_ref, q_ref, k_ref, v_ref, o_ref,
-                   acc_ref, m_ref, l_ref, *, sm_scale, block_size):
-    b = pl.program_id(0)
-    j = pl.program_id(2)
-    nb = pl.num_programs(2)
-
-    @pl.when(j == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    ctx_len = lens_ref[b]
-
-    @pl.when(j * block_size < ctx_len)
-    def _compute():
-        q = q_ref[0, 0].astype(jnp.float32) * sm_scale      # (G, D)
-        k = k_ref[0, 0].astype(jnp.float32)                 # (BS, D)
-        v = v_ref[0, 0].astype(jnp.float32)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)  # (G, BS)
-        pos = j * block_size + jax.lax.broadcasted_iota(
-            jnp.int32, s.shape, 1)
-        # typed scalar: a python-float NEG_INF weak-types to f64 when the
-        # interpret-mode kernel is traced inside an x64-on outer program
-        s = jnp.where(pos < ctx_len, s, jnp.float32(NEG_INF))
-        m_prev = m_ref[...][:, 0]
-        l_prev = l_ref[...][:, 0]
-        m_new = jnp.maximum(m_prev, s.max(axis=-1))
-        p = jnp.exp(s - m_new[:, None])
-        alpha = jnp.exp(m_prev - m_new)
-        l_new = l_prev * alpha + p.sum(axis=-1)
-        acc_ref[...] = acc_ref[...] * alpha[:, None] + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-        m_ref[...] = m_new[:, None]
-        l_ref[...] = l_new[:, None]
-
-    @pl.when(j == nb - 1)
-    def _finish():
-        l = l_ref[...][:, 0]
-        l_safe = jnp.where(l == 0.0, jnp.float32(1.0), l)
-        o_ref[0, 0] = (acc_ref[...] / l_safe[:, None]).astype(o_ref.dtype)
-
-
-def _decode_call(q, k_cache, v_cache, block_tables, context_lens, sm_scale):
-    """q: [B, KV_H, G, D] (G padded); caches: [KV_H, NB, BS, D]."""
-    batch, kv_h, g, d = q.shape
-    block_size = k_cache.shape[2]
-    max_blocks = block_tables.shape[1]
-
-    kern = functools.partial(_decode_kernel, sm_scale=sm_scale,
-                             block_size=block_size)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(batch, kv_h, max_blocks),
-        in_specs=[
-            pl.BlockSpec((1, 1, g, d),
-                         lambda b, h, j, lens, tables: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, block_size, d),
-                         lambda b, h, j, lens, tables: (h, tables[b, j], 0, 0)),
-            pl.BlockSpec((1, 1, block_size, d),
-                         lambda b, h, j, lens, tables: (h, tables[b, j], 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, g, d),
-                               lambda b, h, j, lens, tables: (b, h, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((g, d), jnp.float32),
-            pltpu.VMEM((g, 1), jnp.float32),
-            pltpu.VMEM((g, 1), jnp.float32),
-        ],
-    )
-    return _support.pallas_call(
-        kern,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((batch, kv_h, g, d), q.dtype),
-        name="paged_attention_decode",
-        interpret=_support.interpret_mode(),
-    )(context_lens, block_tables, q, k_cache, v_cache)
-
-
-def paged_attention(q, k_cache, v_cache, block_tables, context_lens,
-                    sm_scale=None):
-    """Decode-step paged attention over raw arrays.
-
-    Args:
-      q: [B, H, D] — one query token per sequence.
-      k_cache/v_cache: [num_blocks, kv_heads, block_size, head_dim].
-      block_tables: [B, max_blocks_per_seq] int32 physical block ids (pad 0).
-      context_lens: [B] int32 — tokens already in cache (incl. current).
-    Returns [B, H, D].
-    """
-    batch, h, d = q.shape
-    kv_h = k_cache.shape[1]
-    g = h // kv_h
-    if sm_scale is None:
-        sm_scale = 1.0 / float(np.sqrt(d))
-    # [B, H, D] -> [B, KV_H, G, D], pad the group dim to the 8-row sublane
-    # tile so the MXU matmul has a full tile even for MHA (G=1).
-    qg = q.reshape(batch, kv_h, g, d)
-    g_pad = max(g, 8)
-    if g_pad != g:
-        qg = jnp.pad(qg, ((0, 0), (0, 0), (0, g_pad - g), (0, 0)))
-    kc = jnp.swapaxes(k_cache, 0, 1)  # [KV_H, NB, BS, D]
-    vc = jnp.swapaxes(v_cache, 0, 1)
-    out = _decode_call(qg, kc, vc, block_tables.astype(jnp.int32),
-                       context_lens.astype(jnp.int32), float(sm_scale))
-    return out[:, :, :g, :].reshape(batch, h, d)
-
-
-def _verify_kernel(lens_ref, tables_ref, q_ref, k_ref, v_ref, o_ref,
-                   acc_ref, m_ref, l_ref, *, sm_scale, block_size,
-                   num_queries, g_pad):
-    """Multi-query causal decode kernel (speculative-decode verify pass).
-
-    Same online-softmax structure as `_decode_kernel`, but the q block holds
-    S query tokens × G head-group rows: row r is query s = r // g_pad, whose
-    absolute position is ctx_len - S + s, so its causal limit is
-    `pos <= ctx_len - S + s` — one extra iota against the same score tile.
-    """
-    b = pl.program_id(0)
-    j = pl.program_id(2)
-    nb = pl.num_programs(2)
-
-    @pl.when(j == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    ctx_len = lens_ref[b]
-
-    @pl.when(j * block_size < ctx_len)
-    def _compute():
-        q = q_ref[0, 0].astype(jnp.float32) * sm_scale      # (S*G, D)
-        k = k_ref[0, 0].astype(jnp.float32)                 # (BS, D)
-        v = v_ref[0, 0].astype(jnp.float32)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-        pos = j * block_size + jax.lax.broadcasted_iota(
-            jnp.int32, s.shape, 1)
-        row = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-        # typed scalars: python ints weak-type to i64 when the interpret-
-        # mode kernel is traced inside an x64-on outer program (see the
-        # NEG_INF note in _decode_kernel)
-        qpos = (ctx_len - jnp.int32(num_queries)
-                + row // jnp.int32(g_pad))                  # per-row limit
-        s = jnp.where(pos <= qpos, s, jnp.float32(NEG_INF))
-        m_prev = m_ref[...][:, 0]
-        l_prev = l_ref[...][:, 0]
-        m_new = jnp.maximum(m_prev, s.max(axis=-1))
-        p = jnp.exp(s - m_new[:, None])
-        alpha = jnp.exp(m_prev - m_new)
-        l_new = l_prev * alpha + p.sum(axis=-1)
-        acc_ref[...] = acc_ref[...] * alpha[:, None] + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-        m_ref[...] = m_new[:, None]
-        l_ref[...] = l_new[:, None]
-
-    @pl.when(j == nb - 1)
-    def _finish():
-        l = l_ref[...][:, 0]
-        l_safe = jnp.where(l == 0.0, jnp.float32(1.0), l)
-        o_ref[0, 0] = (acc_ref[...] / l_safe[:, None]).astype(o_ref.dtype)
-
-
-def _verify_call(q, k_cache, v_cache, block_tables, context_lens, sm_scale,
-                 num_queries, g_pad):
-    """q: [B, KV_H, S*Gp, D]; caches: [KV_H, NB, BS, D]."""
-    batch, kv_h, rows, d = q.shape
-    block_size = k_cache.shape[2]
-    max_blocks = block_tables.shape[1]
-
-    kern = functools.partial(_verify_kernel, sm_scale=sm_scale,
-                             block_size=block_size, num_queries=num_queries,
-                             g_pad=g_pad)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(batch, kv_h, max_blocks),
-        in_specs=[
-            pl.BlockSpec((1, 1, rows, d),
-                         lambda b, h, j, lens, tables: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, block_size, d),
-                         lambda b, h, j, lens, tables: (h, tables[b, j], 0, 0)),
-            pl.BlockSpec((1, 1, block_size, d),
-                         lambda b, h, j, lens, tables: (h, tables[b, j], 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, rows, d),
-                               lambda b, h, j, lens, tables: (b, h, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((rows, d), jnp.float32),
-            pltpu.VMEM((rows, 1), jnp.float32),
-            pltpu.VMEM((rows, 1), jnp.float32),
-        ],
-    )
-    return _support.pallas_call(
-        kern,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((batch, kv_h, rows, d), q.dtype),
-        name="paged_attention_verify",
-        interpret=_support.interpret_mode(),
-    )(context_lens, block_tables, q, k_cache, v_cache)
-
-
-def paged_attention_verify(q, k_cache, v_cache, block_tables, context_lens,
-                           sm_scale=None):
-    """Batched multi-token verify attention over the paged KV cache.
-
-    The speculative-decode verify pass: S tokens per sequence (the pending
-    token + K drafts) attend causally against the paged cache, whose last S
-    positions are the tokens themselves (already written via
-    `write_kv_to_cache`).
-
-    Args:
-      q: [B, S, H, D] — query token i of row b sits at absolute position
-         context_lens[b] - S + i and attends to positions <= its own.
-      k_cache/v_cache: [num_blocks, kv_heads, block_size, head_dim].
-      block_tables: [B, max_blocks_per_seq] int32 physical block ids.
-      context_lens: [B] int32 — tokens in cache INCLUDING all S new ones.
-    Returns [B, S, H, D].
-    """
-    batch, s, h, d = q.shape
-    kv_h = k_cache.shape[1]
-    g = h // kv_h
-    if sm_scale is None:
-        sm_scale = 1.0 / float(np.sqrt(d))
-    # [B, S, H, D] -> [B, KV_H, S*Gp, D]: group queries by kv head, pad the
-    # group dim so each query's row band is sublane-aligned and the kernel
-    # can recover the query index as row // g_pad.
-    g_pad = g if g % 8 == 0 else (g // 8 + 1) * 8
-    qg = jnp.swapaxes(q.reshape(batch, s, kv_h, g, d), 1, 2)  # [B,KVH,S,G,D]
-    if g_pad != g:
-        qg = jnp.pad(qg, ((0, 0), (0, 0), (0, 0), (0, g_pad - g), (0, 0)))
-    qg = qg.reshape(batch, kv_h, s * g_pad, d)
-    kc = jnp.swapaxes(k_cache, 0, 1)  # [KV_H, NB, BS, D]
-    vc = jnp.swapaxes(v_cache, 0, 1)
-    out = _verify_call(qg, kc, vc, block_tables.astype(jnp.int32),
-                       context_lens.astype(jnp.int32), float(sm_scale),
-                       s, g_pad)
-    out = out.reshape(batch, kv_h, s, g_pad, d)[:, :, :, :g, :]
-    return jnp.swapaxes(out, 1, 2).reshape(batch, s, h, d)
 
 
 # VMEM the ragged kernel sizes its buffers against: the page double buffer
@@ -481,7 +238,7 @@ def _ragged_kernel(layer_ref, kv_lens_ref, q_lens_ref, q_starts_ref,
                         pos0 + c * i32(qc) + tok, kv_len - 1)
                     # typed scalars: python numbers weak-type to 64 bits
                     # when the interpret-mode kernel is traced inside an
-                    # x64-on outer program (see _decode_kernel)
+                    # x64-on outer program
                     s = jnp.where(live, s, jnp.float32(NEG_INF))
                     p, alpha, m_ref[h, rs, :], l_ref[h, rs, :] = \
                         online_softmax_step(s, m_ref[h, rs, :],
@@ -769,31 +526,24 @@ def paged_attention_ragged_ref(q, k_cache, v_cache, block_tables, kv_lens,
     return out.reshape(tokens, h, d).astype(q.dtype)
 
 
-def paged_attention_verify_ref(q, k_cache, v_cache, block_tables,
-                               context_lens, sm_scale=None):
-    """XLA reference for the verify pass (also the CPU fallback)."""
-    batch, s, h, d = q.shape
-    nb, kv_h, bs, _ = k_cache.shape
-    g = h // kv_h
-    if sm_scale is None:
-        sm_scale = 1.0 / float(np.sqrt(d))
-    k = jnp.take(k_cache, block_tables, axis=0)
-    v = jnp.take(v_cache, block_tables, axis=0)
-    max_s = block_tables.shape[1] * bs
-    k = jnp.swapaxes(k, 2, 3).reshape(batch, max_s, kv_h, d)
-    v = jnp.swapaxes(v, 2, 3).reshape(batch, max_s, kv_h, d)
-    qg = jnp.swapaxes(q.reshape(batch, s, kv_h, g, d), 1, 2)  # [B,KVH,S,G,D]
-    sc = jnp.einsum("bhqgd,bshd->bhqgs", qg.astype(jnp.float32),
-                    k.astype(jnp.float32),
-                    preferred_element_type=jnp.float32) * sm_scale
-    wpos = jnp.arange(max_s, dtype=jnp.int32)
-    qpos = (context_lens[:, None] - s
-            + jnp.arange(s, dtype=jnp.int32)[None, :])       # [B, S]
-    mask = wpos[None, None, :] <= qpos[:, :, None]           # [B, S, W]
-    sc = jnp.where(mask[:, None, :, None, :], sc, NEG_INF)
-    p = jax.nn.softmax(sc, axis=-1)
-    out = jnp.einsum("bhqgs,bshd->bhqgd", p, v.astype(jnp.float32))
-    return jnp.swapaxes(out, 1, 2).reshape(batch, s, h, d).astype(q.dtype)
+def paged_attention(q, k_cache, v_cache, block_tables, context_lens,
+                    sm_scale=None):
+    """Decode-step paged attention over raw arrays: the `q_len == 1` case
+    of `paged_attention_ragged` (lane i's one token at position
+    `context_lens[i] - 1`), behind the same gate, `ragged_supported`.
+
+    Args:
+      q: [B, H, D] — one query token per sequence.
+      k_cache/v_cache: [num_blocks, kv_heads, block_size, head_dim].
+      block_tables: [B, max_blocks_per_seq] int32 physical block ids (pad 0).
+      context_lens: [B] int32 — tokens already in cache (incl. current).
+    Returns [B, H, D].
+    """
+    context_lens = context_lens.astype(jnp.int32)
+    return paged_attention_ragged(
+        q, k_cache, v_cache, block_tables, context_lens,
+        jnp.arange(q.shape[0], dtype=jnp.int32), context_lens - 1, sm_scale)
+
 
 
 def paged_attention_ref(q, k_cache, v_cache, block_tables, context_lens,
@@ -1058,28 +808,6 @@ def write_kv_to_cache_ragged_ref(k, v, k_cache, v_cache, block_tables,
     return _write_ragged(False, k, v, k_cache, v_cache, block_tables,
                          tok_lane, tok_pos, k_scale, v_scale, layer)
 
-
-def supported(q_shape, dtype) -> bool:
-    if not _support.kernels_enabled():
-        return False
-    if len(q_shape) != 3:
-        return False
-    if q_shape[-1] > 256:
-        return False
-    return _support.float_dtype_ok(dtype)
-
-
-def verify_supported(q_shape, dtype) -> bool:
-    """Gate for `paged_attention_verify` (q: [B, S, H, D])."""
-    if not _support.kernels_enabled():
-        return False
-    if len(q_shape) != 4:
-        return False
-    if q_shape[-1] > 256:
-        return False
-    if q_shape[1] > 64:          # S*Gp rows must stay a small VMEM tile
-        return False
-    return _support.float_dtype_ok(dtype)
 
 
 def ragged_supported(q_shape, dtype, cache_shape, cache_dtype,

@@ -538,7 +538,6 @@ class Profiler:
             f"{g('serving.decode_steps')} decode steps "
             f"(+{g('serving.prefill_tokens')} prefill tokens / "
             f"{g('serving.prefills')} prefills); retraces: "
-            f"prefill={g('serving.prefill_retraces')}, "
             f"decode={g('serving.decode_retraces')}",
             f"  occupancy avg {g('serving.batch_occupancy_avg_pct')}%, "
             f"KV util {g('serving.kv_utilization_pct')}% "

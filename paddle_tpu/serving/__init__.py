@@ -9,8 +9,8 @@ per-sequence block tables, and let host-side scheduling — not XLA
 recompilation — absorb all request churn.
 
 Components:
-- `EngineCore` (engine.py): the model-agnostic prefill/decode protocol
-  (stacked params + paged KV + fixed max-batch decode step).
+- `EngineCore` (engine.py): the model-agnostic engine protocol
+  (stacked params + one paged pool tuple + ONE fixed-shape ragged step).
   `LlamaInferenceEngine` is the flagship implementation; `MLPLMEngine`
   is a deliberately tiny second model family proving the scheduler is
   model-agnostic.

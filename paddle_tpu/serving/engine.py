@@ -2,21 +2,27 @@
 
 Generalizes `inference.llama_runner.LlamaInferenceEngine` into the contract
 the continuous-batching scheduler programs against. An engine owns stacked
-model params and a paged KV(-like) cache and exposes exactly two compiled
-entry points:
+model params and `self.pools`, one tuple of paged KV(-like) arrays, and
+exposes ONE compiled way into its model:
 
-- `prefill(input_ids [B, S], block_tables [B, MAXB], lens [B])` — run the
-  prompt, write the cache through the block tables, return next-token
-  logits [B, V] gathered at `lens-1` (rows may be right-padded to a bucket
-  length so the compile count is O(#buckets), not O(#prompt lengths));
-- `decode_step(tokens [B], context_lens [B], block_tables [B, MAXB])` —
-  one fixed-shape step over the ragged batch (B == max_batch_size always;
-  the scheduler pads empty slots), returning logits [B, V].
+- `ragged_step(tokens [T], q_lens [B], kv_lens [B], block_tables [B, MAXB])`
+  — one fixed-shape step over a packed ragged batch (prefill chunks and
+  decode lanes alike; the scheduler pads empty lanes with `q_len` 0),
+  returning logits [T, V];
+- `verify_step(tokens [B, S], context_lens [B], block_tables)` is its
+  `q_len == S` case (speculative decoding), and `generate(input_ids)` a
+  host loop over it (`inference/generate.py`).
 
-Both must be shape-stable so the serving steady state never recompiles
-(the Ragged-Paged-Attention shape discipline, PAPERS.md). Engines bump
-`monitor.inc("serving.prefill_retraces"/"serving.decode_retraces")` at
-TRACE time inside their jitted fns so tests can assert exactly that.
+Beside it: `copy_kv_block(src, dst)` (the manager's COW hook) and
+`extract_kv_blocks(seq_id)` / `inject_kv_blocks(seq_id, payload)` (KV
+migration), each one donated executable over the whole pool tuple
+(`inference.kv_migrate.PagedPools`).
+
+Every compiled entry is shape-stable so the serving steady state never
+recompiles (the Ragged-Paged-Attention shape discipline, PAPERS.md).
+Engines bump `monitor.inc("serving.decode_retraces"/
+"serving.ragged_retraces"/"serving.verify_retraces")` at TRACE time inside
+their jitted fns so tests can assert exactly that.
 
 Failure contract (docs/SERVING.md "Failure semantics"): an engine may
 raise from any entry point — the scheduler's typed fault boundary
@@ -37,12 +43,14 @@ smoke engine.
 from __future__ import annotations
 
 import functools
-from typing import Optional, Protocol, runtime_checkable
+import functools
+from typing import Protocol, runtime_checkable
 
 import numpy as np
 
 from ..inference import kv_migrate
 from ..inference.cache import BlockCacheManager
+from ..inference.generate import generate
 
 __all__ = ["EngineCore", "MLPLMEngine"]
 
@@ -53,14 +61,6 @@ class EngineCore(Protocol):
 
     max_batch_size: int
     manager: BlockCacheManager
-
-    def prefill(self, input_ids: np.ndarray, block_tables: np.ndarray,
-                lens: Optional[np.ndarray] = None) -> np.ndarray:
-        ...
-
-    def decode_step(self, tokens: np.ndarray, context_lens: np.ndarray,
-                    block_tables: np.ndarray) -> np.ndarray:
-        ...
 
     def verify_step(self, tokens: np.ndarray, context_lens: np.ndarray,
                     block_tables: np.ndarray) -> np.ndarray:
@@ -86,65 +86,18 @@ class EngineCore(Protocol):
         ...
 
 
-def _mlp_prefill(params, cache, input_ids, tables, lens, *, block_size):
-    import jax.numpy as jnp
-
-    from ..framework import monitor
-
-    monitor.inc("serving.prefill_retraces")  # trace-time only
-    b, s = input_ids.shape
-    x = jnp.take(params["embed"], input_ids, axis=0)        # [B, S, D]
-    pos = jnp.arange(s, dtype=jnp.int32)
-    blocks = jnp.take_along_axis(tables, (pos // block_size)[None, :],
-                                 axis=1)                     # [B, S]
-    offs = jnp.broadcast_to(pos % block_size, (b, s))
-    cache = cache.at[blocks.reshape(-1), offs.reshape(-1)].set(
-        x.reshape(b * s, -1))
-    mask = (pos[None, :] < lens[:, None]).astype(x.dtype)    # [B, S]
-    mean = (x * mask[..., None]).sum(1) / jnp.maximum(
-        mask.sum(1, keepdims=True), 1.0)
-    idx = jnp.clip(lens - 1, 0, s - 1)
-    last = jnp.take_along_axis(x, idx[:, None, None].astype(jnp.int32),
-                               axis=1)[:, 0]
-    logits = _mlp_head(params, last, mean)
-    return logits.astype(jnp.float32), cache
-
-
-def _mlp_decode(params, cache, tokens, ctx_lens, tables, *, block_size):
-    import jax.numpy as jnp
-
-    from ..framework import monitor
-
-    monitor.inc("serving.decode_retraces")  # trace-time only
-    b = tokens.shape[0]
-    maxb = tables.shape[1]
-    x = jnp.take(params["embed"], tokens, axis=0)            # [B, D]
-    pos = jnp.maximum(ctx_lens - 1, 0)
-    blocks = jnp.take_along_axis(tables, (pos // block_size)[:, None],
-                                 axis=1)[:, 0]
-    cache = cache.at[blocks, pos % block_size].set(x)
-    window = jnp.take(cache, tables.reshape(-1), axis=0).reshape(
-        b, maxb * block_size, -1)                            # [B, W, D]
-    wpos = jnp.arange(maxb * block_size, dtype=jnp.int32)
-    mask = (wpos[None, :] < ctx_lens[:, None]).astype(x.dtype)
-    mean = (window * mask[..., None]).sum(1) / jnp.maximum(
-        mask.sum(1, keepdims=True), 1.0)
-    logits = _mlp_head(params, x, mean)
-    return logits.astype(jnp.float32), cache
-
-
-def _mlp_ragged_stack(params, cache, tokens, q_lens, kv_lens, tables, *,
-                      block_size, cache_scale=None, tp=None):
+def _mlp_ragged_stack(params, pools, tokens, q_lens, kv_lens, tables, *,
+                      block_size, tp=None):
     """Shared ragged body: packed tokens [T] + per-lane (q_len, kv_len)
     metadata. Token t embeds, writes its embedding at its absolute
     position (guard slots' writes are OOB-dropped), and conditions on
-    (own embedding, masked mean of its lane's window through `tok_pos`)
-    — exactly what a sequence of decode_step calls computes.
+    (own embedding, masked mean of its lane's window through `tok_pos`).
 
-    `cache_scale` ([NB, BS] f32) marks an int8-quantized embedding pool
+    `pools` is `(cache,)`, or `(cache, cache_scale)` with the scale plane
+    ([NB, BS] f32) of an int8-quantized embedding pool
     (`inference/kv_quant.py`): writes quantize per slot, the gathered
     window dequantizes right after the gather — the float pool never
-    exists. Returns (logits, cache[, cache_scale]).
+    exists. Returns (logits, pools).
 
     `tp` (`distributed.tp_overlap.TPInfo`, set by `serving/tp.py` when
     the body runs inside shard_map) marks a feature-sharded pool: each
@@ -158,6 +111,7 @@ def _mlp_ragged_stack(params, cache, tokens, q_lens, kv_lens, tables, *,
     from ..inference import kv_quant
     from ..ops.pallas.paged_attention import ragged_metadata
 
+    cache = pools[0]
     t = tokens.shape[0]
     nb = cache.shape[0]
     maxb = tables.shape[1]
@@ -174,18 +128,21 @@ def _mlp_ragged_stack(params, cache, tokens, q_lens, kv_lens, tables, *,
     pos = jnp.maximum(tok_pos, 0)
     blocks = tables[tok_lane, pos // block_size]             # [T]
     blocks = jnp.where(tok_pos >= 0, blocks, jnp.int32(nb))  # OOB -> drop
-    if cache_scale is not None:
+    if len(pools) == 2:
+        cache_scale = pools[1]
         q, s = kv_quant.quantize_kv(x)                       # [T, D] / [T]
         if tp is not None:
             q = jax.lax.dynamic_slice_in_dim(q, off, dl, axis=1)
         cache = cache.at[blocks, pos % block_size].set(q)
         cache_scale = cache_scale.at[blocks, pos % block_size].set(s)
+        pools = (cache, cache_scale)
         window = kv_quant.dequantize_kv(
             jnp.take(cache, tables, axis=0),
             jnp.take(cache_scale, tables, axis=0)).reshape(
                 tables.shape[0], maxb * block_size, -1)      # [B, W, D]
     else:
         cache = cache.at[blocks, pos % block_size].set(x_loc)
+        pools = (cache,)
         window = jnp.take(cache, tables, axis=0).reshape(
             tables.shape[0], maxb * block_size, -1)          # [B, W, D]
     window = jnp.take(window, tok_lane, axis=0)              # [T, W, D]
@@ -194,12 +151,10 @@ def _mlp_ragged_stack(params, cache, tokens, q_lens, kv_lens, tables, *,
     mean = (window * mask[..., None]).sum(1) / jnp.maximum(
         mask.sum(1, keepdims=True), 1.0)                     # [T, D]
     logits = _mlp_head(params, x_loc, mean, tp=tp)
-    if cache_scale is not None:
-        return logits.astype(jnp.float32), cache, cache_scale
-    return logits.astype(jnp.float32), cache
+    return logits.astype(jnp.float32), pools
 
 
-def _mlp_ragged(params, cache, tokens, q_lens, kv_lens, tables, *,
+def _mlp_ragged(params, pools, tokens, q_lens, kv_lens, tables, *,
                 block_size, tp=None):
     from ..framework import monitor
 
@@ -208,24 +163,11 @@ def _mlp_ragged(params, cache, tokens, q_lens, kv_lens, tables, *,
     # ragged_retraces pins the one-executable-per-composition claim
     monitor.inc("serving.decode_retraces")
     monitor.inc("serving.ragged_retraces")
-    return _mlp_ragged_stack(params, cache, tokens, q_lens, kv_lens,
+    return _mlp_ragged_stack(params, pools, tokens, q_lens, kv_lens,
                              tables, block_size=block_size, tp=tp)
 
 
-def _mlp_ragged_q(params, cache, cache_scale, tokens, q_lens, kv_lens,
-                  tables, *, block_size, tp=None):
-    """The int8-pool ragged step (`kv_bits=8`): the scale plane rides
-    (and is donated) alongside the cache."""
-    from ..framework import monitor
-
-    monitor.inc("serving.decode_retraces")  # trace-time (see _mlp_ragged)
-    monitor.inc("serving.ragged_retraces")
-    return _mlp_ragged_stack(params, cache, tokens, q_lens, kv_lens,
-                             tables, block_size=block_size,
-                             cache_scale=cache_scale, tp=tp)
-
-
-def _mlp_verify(params, cache, tokens, ctx_lens, tables, *, block_size,
+def _mlp_verify(params, pools, tokens, ctx_lens, tables, *, block_size,
                 tp=None):
     """Speculative verify as a special case of the ragged step: every
     lane is a fixed q_len == S window of the packed buffer."""
@@ -236,27 +178,10 @@ def _mlp_verify(params, cache, tokens, ctx_lens, tables, *, block_size,
     monitor.inc("serving.verify_retraces")  # trace-time only
     b, s = tokens.shape
     q_lens = jnp.full((b,), s, jnp.int32)
-    logits, cache = _mlp_ragged_stack(
-        params, cache, tokens.reshape(b * s), q_lens,
+    logits, pools = _mlp_ragged_stack(
+        params, pools, tokens.reshape(b * s), q_lens,
         ctx_lens.astype(jnp.int32), tables, block_size=block_size, tp=tp)
-    return logits.reshape(b, s, -1), cache
-
-
-def _mlp_verify_q(params, cache, cache_scale, tokens, ctx_lens, tables, *,
-                  block_size, tp=None):
-    """Verify over the int8 pool (rides the quantized ragged stack)."""
-    import jax.numpy as jnp
-
-    from ..framework import monitor
-
-    monitor.inc("serving.verify_retraces")  # trace-time only
-    b, s = tokens.shape
-    q_lens = jnp.full((b,), s, jnp.int32)
-    logits, cache, cache_scale = _mlp_ragged_stack(
-        params, cache, tokens.reshape(b * s), q_lens,
-        ctx_lens.astype(jnp.int32), tables, block_size=block_size,
-        cache_scale=cache_scale, tp=tp)
-    return logits.reshape(b, s, -1), cache, cache_scale
+    return logits.reshape(b, s, -1), pools
 
 
 def _mlp_mm(h, w):
@@ -307,13 +232,13 @@ def _mlp_head(params, last, mean, tp=None):
     return logits
 
 
-class MLPLMEngine:
+class MLPLMEngine(kv_migrate.PagedPools):
     """Bag-of-embeddings MLP LM over the paged cache (EngineCore #2).
 
-    The "KV" cache is [num_blocks, block_size, D] token embeddings; decode
-    conditions on (last-token embedding, masked mean of the context window
+    The "KV" cache is [num_blocks, block_size, D] token embeddings; a token
+    conditions on (its own embedding, masked mean of the context window
     gathered through the block table). Same paged bookkeeping, same
-    fixed-shape decode discipline as the Llama engine, ~1000x smaller.
+    fixed-shape step discipline as the Llama engine, ~1000x smaller.
     """
 
     def __init__(self, vocab_size: int = 256, hidden: int = 32,
@@ -348,73 +273,34 @@ class MLPLMEngine:
             "w2": init(2 * d, vocab_size),
             "b2": jnp.zeros((vocab_size,), jnp.float32),
         }
-        # the "KV" pool: per-token embeddings, paged; int8 + per-slot
+        # the "KV" pool tuple: per-token embeddings, paged; int8 + per-slot
         # scale plane under kv_bits=8 (inference/kv_quant.py)
         if self.kv_bits == 8:
-            self.cache = jnp.zeros((num_blocks, block_size, d), jnp.int8)
-            self.cache_scale = jnp.zeros((num_blocks, block_size),
-                                         jnp.float32)
+            self.pools = (jnp.zeros((num_blocks, block_size, d), jnp.int8),
+                          jnp.zeros((num_blocks, block_size), jnp.float32))
             bpb = block_size * d * 1 + block_size * 4
         else:
-            self.cache = jnp.zeros((num_blocks, block_size, d),
-                                   jnp.float32)
-            self.cache_scale = None
+            self.pools = (jnp.zeros((num_blocks, block_size, d),
+                                    jnp.float32),)
             bpb = block_size * d * 4
+        self._slab_names = ("cache", "scale")[:len(self.pools)]
         self._kv_bytes_per_token = bpb / block_size
         self.manager.set_kv_geometry(bpb, self.kv_bits)
-        self._prefill = jax.jit(
-            functools.partial(_mlp_prefill, block_size=block_size),
+        self._ragged = jax.jit(
+            functools.partial(_mlp_ragged, block_size=block_size),
             donate_argnums=(1,))
-        self._decode = jax.jit(
-            functools.partial(_mlp_decode, block_size=block_size),
+        self._verify = jax.jit(
+            functools.partial(_mlp_verify, block_size=block_size),
             donate_argnums=(1,))
-        if self.kv_bits == 8:
-            self._verify = jax.jit(
-                functools.partial(_mlp_verify_q, block_size=block_size),
-                donate_argnums=(1, 2))
-            self._ragged = jax.jit(
-                functools.partial(_mlp_ragged_q, block_size=block_size),
-                donate_argnums=(1, 2))
-            # COW copy moves the int8 block and its scale row in ONE
-            # donated executable — q + scale can never tear apart
-            self._copy_block_q = jax.jit(
-                lambda c, cs, s, d: (c.at[d].set(c[s]),
-                                     cs.at[d].set(cs[s])),
-                donate_argnums=(0, 1))
-        else:
-            self._verify = jax.jit(
-                functools.partial(_mlp_verify, block_size=block_size),
-                donate_argnums=(1,))
-            self._ragged = jax.jit(
-                functools.partial(_mlp_ragged, block_size=block_size),
-                donate_argnums=(1,))
-        # COW device copy (prefix caching): one traced executable, the
-        # cache donated so the copy is in-place-ish; src/dst are traced
-        # int32 scalars, so repeated COWs never recompile
-        self._copy_block = jax.jit(lambda c, s, d: c.at[d].set(c[s]),
-                                   donate_argnums=(0,))
-        # KV migration (inference/kv_migrate.py): fixed-shape gather/
-        # scatter over [max_blocks_per_seq] padded index vectors — the
-        # gather is NOT donated (the source pool lives on; extraction
-        # is a copy), the scatter donates the destination pool; int8
-        # pools move the scale plane in the same executable so q +
-        # scale can never tear apart in flight
-        if self.kv_bits == 8:
-            self._kv_gather = jax.jit(lambda c, cs, i: (c[i], cs[i]))
-            self._kv_scatter = jax.jit(
-                lambda c, cs, i, sc, ss: (c.at[i].set(sc),
-                                          cs.at[i].set(ss)),
-                donate_argnums=(0, 1))
-        else:
-            self._kv_gather = jax.jit(lambda c, i: c[i])
-            self._kv_scatter = jax.jit(lambda c, i, sc: c.at[i].set(sc),
-                                       donate_argnums=(0,))
+        # COW copy and KV migration over the block axis (axis 0):
+        # `kv_migrate.PagedPools`
+        self._build_block_ops(0)
         self._mig_header = {
             "version": kv_migrate.PAYLOAD_VERSION, "engine": "mlp",
             "block_size": block_size,
             "max_blocks_per_seq": max_blocks_per_seq,
             "kv_bits": self.kv_bits, "tp": 1, "hidden": hidden,
-            "dtype": str(self.cache.dtype),
+            "dtype": str(self.pools[0].dtype),
         }
 
     def kv_bytes_per_token(self) -> float:
@@ -433,77 +319,6 @@ class MLPLMEngine:
         return {"wbits": wb, "kv_bits": self.kv_bits,
                 "kv_bytes_per_token": self._kv_bytes_per_token}
 
-    def copy_kv_block(self, src: int, dst: int) -> None:
-        """Copy one physical cache block (`BlockCacheManager` COW hook —
-        wired by the scheduler when prefix caching is on). The block's
-        whole [block_size, D] slab moves (int8 pools move the scale row
-        atomically in the same executable); positions past the writer's
-        divergence point are overwritten or never attended (masked by
-        context length)."""
-        if self.kv_bits == 8:
-            self.cache, self.cache_scale = self._copy_block_q(
-                self.cache, self.cache_scale, np.int32(src),
-                np.int32(dst))
-            return
-        self.cache = self._copy_block(self.cache, np.int32(src),
-                                      np.int32(dst))
-
-    def extract_kv_blocks(self, seq_id: int) -> kv_migrate.KVBlockPayload:
-        """Export `seq_id`'s committed KV blocks as ONE device gather
-        (the disaggregated-serving handoff / KV-shipping relocation
-        export, ISSUE 17). The source pool is untouched (gather is not
-        donated) — extraction is a copy, so the caller decides when to
-        release the source sequence. The block-index vector is padded
-        to the fixed `max_blocks_per_seq` shape, so every sequence
-        length rides the same compiled executable (zero retraces)."""
-        mgr = self.manager
-        blocks = mgr.blocks_of(seq_id)
-        if not blocks:
-            raise kv_migrate.KVMigrationError(
-                f"sequence {seq_id} holds no KV blocks on this engine")
-        idx = kv_migrate.pad_block_indices(blocks, mgr.max_blocks_per_seq)
-        header = dict(self._mig_header, num_blocks=len(blocks),
-                      num_tokens=mgr.seq_len(seq_id))
-        if self.kv_bits == 8:
-            slab, sscale = self._kv_gather(self.cache, self.cache_scale,
-                                           idx)
-            return kv_migrate.KVBlockPayload(
-                header, {"cache": slab, "scale": sscale})
-        return kv_migrate.KVBlockPayload(
-            header, {"cache": self._kv_gather(self.cache, idx)})
-
-    def inject_kv_blocks(self, seq_id: int,
-                         payload: kv_migrate.KVBlockPayload) -> None:
-        """Import a migrated payload under `seq_id`: validate the header
-        (typed `KVMigrationError` BEFORE any allocation), allocate the
-        block run (the manager's typed `KVCacheExhausted`/
-        `SequenceTooLong` propagate), then scatter the slabs in one
-        donated executable. Any failure after allocation frees the
-        just-allocated blocks — a failed inject never leaks. The
-        payload's slabs are not donated, so the same payload can stream
-        to several workers (cross-replica prefix reuse)."""
-        mgr = self.manager
-        kv_migrate.check_header(payload.header, self._mig_header)
-        blocks = mgr.allocate(seq_id, payload.num_tokens)
-        try:
-            if len(blocks) != payload.num_blocks:
-                raise kv_migrate.KVMigrationError(
-                    f"payload carries {payload.num_blocks} blocks but "
-                    f"{payload.num_tokens} tokens allocate "
-                    f"{len(blocks)} here")
-            idx = kv_migrate.pad_block_indices(blocks,
-                                               mgr.max_blocks_per_seq)
-            if self.kv_bits == 8:
-                self.cache, self.cache_scale = self._kv_scatter(
-                    self.cache, self.cache_scale, idx,
-                    payload.slabs["cache"], payload.slabs["scale"])
-            else:
-                self.cache = self._kv_scatter(self.cache, idx,
-                                              payload.slabs["cache"])
-        except Exception:
-            mgr.free(seq_id)
-            raise
-
     def respawn(self) -> "MLPLMEngine":
         """Build a fresh engine with IDENTICAL weights (seed-derived) and
         an empty cache/pool — the watchdog `engine_factory` for this
@@ -513,75 +328,29 @@ class MLPLMEngine:
     def cost_card_args(self, phase: str):
         """Observability hook (`observability.costs.ensure_engine_card`):
         the jitted executable behind `phase` plus the leading arguments
-        the scheduler never sees (params, cache). The scheduler appends
-        its own call arrays and lowers the pair for
+        the scheduler never sees (params, the pool tuple). The scheduler
+        appends its own call arrays and lowers the pair for
         `cost_analysis()`/`memory_analysis()` — compiler-reported FLOPs
         per dispatch, cached alongside the executable. Optional on
         EngineCore: engines without it simply have no CostCard. The
         serving "decode" phase maps to the ragged step (the scheduler's
-        only decode program); "decode_legacy" keeps the single-token
-        executable reachable for microbenches."""
-        fn = {"prefill": self._prefill, "decode": self._ragged,
-              "ragged": self._ragged, "decode_legacy": self._decode,
+        only decode program)."""
+        fn = {"decode": self._ragged, "ragged": self._ragged,
               "verify": self._verify}[phase]
-        if self.kv_bits == 8:
-            if phase not in ("decode", "ragged", "verify"):
-                # no legal executable pairs the legacy fns with an int8
-                # pool (see LlamaInferenceEngine.cost_card_args)
-                raise KeyError(
-                    f"{phase!r} has no executable on a kv_bits=8 engine")
-            return fn, (self.params, self.cache, self.cache_scale)
-        return fn, (self.params, self.cache)
-
-    def _require_full_kv(self, entry: str):
-        if self.kv_bits != 16:
-            raise RuntimeError(
-                f"{entry} is a legacy full-precision entry point; a "
-                f"kv_bits={self.kv_bits} engine serves through "
-                "ragged_step/verify_step (the scheduler's only dispatches)")
-
-    def prefill(self, input_ids: np.ndarray, block_tables: np.ndarray,
-                lens: Optional[np.ndarray] = None) -> np.ndarray:
-        self._require_full_kv("prefill")
-        ids = np.asarray(input_ids, np.int32)
-        b, s = ids.shape
-        if lens is None:
-            lens = np.full((b,), s, np.int32)
-        # args go to the jit as exact-dtype numpy: the C++ dispatch path
-        # transfers them far cheaper than per-arg host-side jnp.asarray
-        # device_put calls — this discipline (shared with
-        # ops/sampling.py) is worth ~1 ms/arg on the decode hot loop
-        logits, self.cache = self._prefill(
-            self.params, self.cache, ids,
-            np.asarray(block_tables, np.int32),
-            np.asarray(lens, np.int32))
-        return logits
-
-    def decode_step(self, tokens: np.ndarray, context_lens: np.ndarray,
-                    block_tables: np.ndarray) -> np.ndarray:
-        self._require_full_kv("decode_step")
-        logits, self.cache = self._decode(
-            self.params, self.cache, np.asarray(tokens, np.int32),
-            np.asarray(context_lens, np.int32),
-            np.asarray(block_tables, np.int32))
-        return logits
+        return fn, (self.params, self.pools)
 
     def verify_step(self, tokens: np.ndarray, context_lens: np.ndarray,
                     block_tables: np.ndarray) -> np.ndarray:
         """Multi-token verify pass; see `EngineCore.verify_step`. Token i
         of row b lands at position context_lens[b] - S + i and conditions
-        on (its own embedding, masked mean through its position) — exactly
-        what a sequence of S `decode_step` calls would compute. Rides the
-        ragged step (q_len == S per lane)."""
-        if self.kv_bits == 8:
-            logits, self.cache, self.cache_scale = self._verify(
-                self.params, self.cache, self.cache_scale,
-                np.asarray(tokens, np.int32),
-                np.asarray(context_lens, np.int32),
-                np.asarray(block_tables, np.int32))
-            return logits
-        logits, self.cache = self._verify(
-            self.params, self.cache, np.asarray(tokens, np.int32),
+        on (its own embedding, masked mean through its position). Rides
+        the ragged step (q_len == S per lane)."""
+        # args go to the jit as exact-dtype numpy: the C++ dispatch path
+        # transfers them far cheaper than per-arg host-side jnp.asarray
+        # device_put calls — this discipline (shared with
+        # ops/sampling.py) is worth ~1 ms/arg on the decode hot loop
+        logits, self.pools = self._verify(
+            self.params, self.pools, np.asarray(tokens, np.int32),
             np.asarray(context_lens, np.int32),
             np.asarray(block_tables, np.int32))
         return logits
@@ -590,17 +359,10 @@ class MLPLMEngine:
                     kv_lens: np.ndarray,
                     block_tables: np.ndarray) -> np.ndarray:
         """Packed ragged step; see `EngineCore.ragged_step`."""
-        if self.kv_bits == 8:
-            logits, self.cache, self.cache_scale = self._ragged(
-                self.params, self.cache, self.cache_scale,
-                np.asarray(tokens, np.int32),
-                np.asarray(q_lens, np.int32),
-                np.asarray(kv_lens, np.int32),
-                np.asarray(block_tables, np.int32))
-            return logits
-        logits, self.cache = self._ragged(
-            self.params, self.cache, np.asarray(tokens, np.int32),
-            np.asarray(q_lens, np.int32),
-            np.asarray(kv_lens, np.int32),
+        logits, self.pools = self._ragged(
+            self.params, self.pools, np.asarray(tokens, np.int32),
+            np.asarray(q_lens, np.int32), np.asarray(kv_lens, np.int32),
             np.asarray(block_tables, np.int32))
         return logits
+
+    generate = generate

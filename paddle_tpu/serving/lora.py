@@ -60,6 +60,7 @@ import numpy as np
 from ..framework import monitor
 from ..inference import kv_migrate
 from ..inference.cache import BlockCacheManager
+from ..inference.generate import generate
 
 __all__ = [
     "attach_adapters", "LoRAEngine", "AdapterPool", "lora_mm",
@@ -121,11 +122,11 @@ def lora_mm(x, w, base_mm):
     return y + jnp.einsum("...tr,trn->...tn", xa, b)
 
 
-def _swap_lora(params: dict, pools: dict, ids) -> dict:
+def _swap_lora(params: dict, adapters: dict, ids) -> dict:
     """Rebuild the params pytree with every target weight replaced by
     the `{"w","la","lb","ids"}` epilogue dict `lora_mm` consumes."""
     out = dict(params)
-    for key, pl in pools.items():
+    for key, pl in adapters.items():
         out[key] = {"w": params[key], "la": pl["a"], "lb": pl["b"],
                     "ids": ids}
     return out
@@ -145,45 +146,30 @@ def _lane_ids(q_lens, kv_lens, num_tokens, lane_slots):
 
 # ---- wrapper jit bodies -------------------------------------------------
 # Each computes per-token ids, swaps the target weights, and calls the
-# BASE engine fn — so the base retrace counters bump at OUR trace time
-# and the zero-recompile suite's assertions carry over unchanged. The
-# `serving.lora.switch_retraces` bump is trace-time too: adapter ids are
-# data, so any post-warmup bump means an adapter switch recompiled.
+# BASE engine's step `base` (`_ragged_fn` / `_verify_fn`, or the MLP
+# engine's pair, its static arguments already bound) — so the base
+# retrace counters bump at OUR trace time and the zero-recompile suite's
+# assertions carry over unchanged. The `serving.lora.switch_retraces`
+# bump is trace-time too: adapter ids are data, so any post-warmup bump
+# means an adapter switch recompiled. `nlayers` is the leading axis of a
+# stacked engine's weights (they ride `lax.scan` xs, and so must the
+# ids), None for a flat one.
 
-def _llama_lora_ragged(params, pools, k_cache, v_cache, lane_slots,
-                       tokens, q_lens, kv_lens, tables, *, cfg, nlayers):
+def _lora_ragged(params, adapters, pools, lane_slots, tokens, q_lens,
+                 kv_lens, tables, *, base, nlayers):
     import jax.numpy as jnp
-
-    from ..inference.llama_runner import _ragged_fn
 
     monitor.inc("serving.lora.switch_retraces")  # trace-time only
     ids = _lane_ids(q_lens, kv_lens, tokens.shape[0], lane_slots)
-    # params ride lax.scan xs (leading L axis) — broadcast ids to match
-    ids = jnp.broadcast_to(ids[None, :], (nlayers, tokens.shape[0]))
-    return _ragged_fn(_swap_lora(params, pools, ids), k_cache, v_cache,
-                      tokens, q_lens, kv_lens, tables, cfg=cfg)
+    if nlayers is not None:
+        ids = jnp.broadcast_to(ids[None, :], (nlayers, tokens.shape[0]))
+    return base(_swap_lora(params, adapters, ids), pools, tokens, q_lens,
+                kv_lens, tables)
 
 
-def _llama_lora_ragged_q(params, pools, k_cache, v_cache, k_scale,
-                         v_scale, lane_slots, tokens, q_lens, kv_lens,
-                         tables, *, cfg, nlayers):
+def _lora_verify(params, adapters, pools, lane_slots, tokens, ctx_lens,
+                 tables, *, base, nlayers):
     import jax.numpy as jnp
-
-    from ..inference.llama_runner import _ragged_q_fn
-
-    monitor.inc("serving.lora.switch_retraces")  # trace-time only
-    ids = _lane_ids(q_lens, kv_lens, tokens.shape[0], lane_slots)
-    ids = jnp.broadcast_to(ids[None, :], (nlayers, tokens.shape[0]))
-    return _ragged_q_fn(_swap_lora(params, pools, ids), k_cache, v_cache,
-                        k_scale, v_scale, tokens, q_lens, kv_lens,
-                        tables, cfg=cfg)
-
-
-def _llama_lora_verify(params, pools, k_cache, v_cache, lane_slots,
-                       tokens, ctx_lens, tables, *, cfg, nlayers):
-    import jax.numpy as jnp
-
-    from ..inference.llama_runner import _verify_fn
 
     monitor.inc("serving.lora.switch_retraces")  # trace-time only
     b, s = tokens.shape
@@ -191,76 +177,10 @@ def _llama_lora_verify(params, pools, k_cache, v_cache, lane_slots,
     # ragged stack — mirror that exact metadata here
     q_lens = jnp.full((b,), s, jnp.int32)
     ids = _lane_ids(q_lens, ctx_lens.astype(jnp.int32), b * s, lane_slots)
-    ids = jnp.broadcast_to(ids[None, :], (nlayers, b * s))
-    return _verify_fn(_swap_lora(params, pools, ids), k_cache, v_cache,
-                      tokens, ctx_lens, tables, cfg=cfg)
-
-
-def _llama_lora_verify_q(params, pools, k_cache, v_cache, k_scale,
-                         v_scale, lane_slots, tokens, ctx_lens, tables,
-                         *, cfg, nlayers):
-    import jax.numpy as jnp
-
-    from ..inference.llama_runner import _verify_q_fn
-
-    monitor.inc("serving.lora.switch_retraces")  # trace-time only
-    b, s = tokens.shape
-    q_lens = jnp.full((b,), s, jnp.int32)
-    ids = _lane_ids(q_lens, ctx_lens.astype(jnp.int32), b * s, lane_slots)
-    ids = jnp.broadcast_to(ids[None, :], (nlayers, b * s))
-    return _verify_q_fn(_swap_lora(params, pools, ids), k_cache, v_cache,
-                        k_scale, v_scale, tokens, ctx_lens, tables,
-                        cfg=cfg)
-
-
-def _mlp_lora_ragged(params, pools, cache, lane_slots, tokens, q_lens,
-                     kv_lens, tables, *, block_size):
-    from .engine import _mlp_ragged
-
-    monitor.inc("serving.lora.switch_retraces")  # trace-time only
-    ids = _lane_ids(q_lens, kv_lens, tokens.shape[0], lane_slots)
-    return _mlp_ragged(_swap_lora(params, pools, ids), cache, tokens,
-                       q_lens, kv_lens, tables, block_size=block_size)
-
-
-def _mlp_lora_ragged_q(params, pools, cache, cache_scale, lane_slots,
-                       tokens, q_lens, kv_lens, tables, *, block_size):
-    from .engine import _mlp_ragged_q
-
-    monitor.inc("serving.lora.switch_retraces")  # trace-time only
-    ids = _lane_ids(q_lens, kv_lens, tokens.shape[0], lane_slots)
-    return _mlp_ragged_q(_swap_lora(params, pools, ids), cache,
-                         cache_scale, tokens, q_lens, kv_lens, tables,
-                         block_size=block_size)
-
-
-def _mlp_lora_verify(params, pools, cache, lane_slots, tokens, ctx_lens,
-                     tables, *, block_size):
-    import jax.numpy as jnp
-
-    from .engine import _mlp_verify
-
-    monitor.inc("serving.lora.switch_retraces")  # trace-time only
-    b, s = tokens.shape
-    q_lens = jnp.full((b,), s, jnp.int32)
-    ids = _lane_ids(q_lens, ctx_lens.astype(jnp.int32), b * s, lane_slots)
-    return _mlp_verify(_swap_lora(params, pools, ids), cache, tokens,
-                       ctx_lens, tables, block_size=block_size)
-
-
-def _mlp_lora_verify_q(params, pools, cache, cache_scale, lane_slots,
-                       tokens, ctx_lens, tables, *, block_size):
-    import jax.numpy as jnp
-
-    from .engine import _mlp_verify_q
-
-    monitor.inc("serving.lora.switch_retraces")  # trace-time only
-    b, s = tokens.shape
-    q_lens = jnp.full((b,), s, jnp.int32)
-    ids = _lane_ids(q_lens, ctx_lens.astype(jnp.int32), b * s, lane_slots)
-    return _mlp_verify_q(_swap_lora(params, pools, ids), cache,
-                         cache_scale, tokens, ctx_lens, tables,
-                         block_size=block_size)
+    if nlayers is not None:
+        ids = jnp.broadcast_to(ids[None, :], (nlayers, b * s))
+    return base(_swap_lora(params, adapters, ids), pools, tokens,
+                ctx_lens, tables)
 
 
 # ---- the paged adapter pool --------------------------------------------
@@ -527,15 +447,14 @@ class AdapterPool:
 
 # ---- the engine wrapper -------------------------------------------------
 
-class LoRAEngine:
+class LoRAEngine(kv_migrate.PagedPools):
     """`EngineCore` over a base engine plus a paged adapter pool: the
-    scheduler's three dispatch surfaces (`ragged_step`, `verify_step`,
-    `copy_kv_block`) re-jitted with the per-lane LoRA epilogue, fresh
-    paged bookkeeping (own `BlockCacheManager` + zeroed KV pools — the
-    base engine's donated executables stay valid), and the observability
-    hooks (`cost_card_args`, `quant_info`, `lora_info`). Legacy
-    single-sequence entry points raise: the ragged path is the only
-    serving program, and it is the only one that carries adapter ids."""
+    scheduler's dispatch surfaces (`ragged_step`, `verify_step`) re-jitted
+    with the per-lane LoRA epilogue, fresh paged bookkeeping (own
+    `BlockCacheManager` + zeroed KV pools — the base engine's donated
+    executables stay valid; `copy_kv_block` and KV migration are the
+    base's pure block executables over THIS engine's pools), and the
+    observability hooks (`cost_card_args`, `quant_info`, `lora_info`)."""
 
     def __init__(self, base, pool_slots: int = 8,
                  rank_buckets: Tuple[int, ...] = DEFAULT_RANK_BUCKETS):
@@ -598,7 +517,7 @@ class LoRAEngine:
         self.adapter_pool = AdapterPool(self, pool_slots, rank_buckets)
         self.zero_slot = self.adapter_pool.pool_slots
         S, R = self.zero_slot + 1, self.adapter_pool.rank_max
-        self._pools = {}
+        self._adapters = {}
         for key, (k, n) in self._lora_targets.items():
             if self._kind == "llama":
                 a = jnp.zeros((self._nlayers, S, k, R), jnp.float32)
@@ -606,7 +525,7 @@ class LoRAEngine:
             else:
                 a = jnp.zeros((S, k, R), jnp.float32)
                 b = jnp.zeros((S, R, n), jnp.float32)
-            self._pools[key] = {"a": a, "b": b}
+            self._adapters[key] = {"a": a, "b": b}
         # slot scatter: ONE traced executable per pool-tensor shape
         # (slot is a traced scalar — uploads never recompile)
         if self._kind == "llama":
@@ -621,50 +540,28 @@ class LoRAEngine:
         self._default_lease: Optional[str] = None
 
         if self._kind == "llama":
-            from ..inference.llama_runner import _StaticCfg
+            from ..inference.llama_runner import (_ragged_fn, _StaticCfg,
+                                                  _verify_fn)
 
-            scfg = _StaticCfg(base.config)
-            if self.kv_bits == 8:
-                self.k_cache = jnp.zeros_like(base.k_cache)
-                self.v_cache = jnp.zeros_like(base.v_cache)
-                self.k_scale = jnp.zeros_like(base.k_scale)
-                self.v_scale = jnp.zeros_like(base.v_scale)
-                self._ragged = jax.jit(functools.partial(
-                    _llama_lora_ragged_q, cfg=scfg,
-                    nlayers=self._nlayers), donate_argnums=(2, 3, 4, 5))
-                self._verify = jax.jit(functools.partial(
-                    _llama_lora_verify_q, cfg=scfg,
-                    nlayers=self._nlayers), donate_argnums=(2, 3, 4, 5))
-            else:
-                self.k_cache = jnp.zeros_like(base.k_cache)
-                self.v_cache = jnp.zeros_like(base.v_cache)
-                self.k_scale = self.v_scale = None
-                self._ragged = jax.jit(functools.partial(
-                    _llama_lora_ragged, cfg=scfg,
-                    nlayers=self._nlayers), donate_argnums=(2, 3))
-                self._verify = jax.jit(functools.partial(
-                    _llama_lora_verify, cfg=scfg,
-                    nlayers=self._nlayers), donate_argnums=(2, 3))
+            bases, static = (_ragged_fn, _verify_fn), {
+                "cfg": _StaticCfg(base.config)}
         else:
-            bs = base.block_size
-            if self.kv_bits == 8:
-                self.cache = jnp.zeros_like(base.cache)
-                self.cache_scale = jnp.zeros_like(base.cache_scale)
-                self._ragged = jax.jit(functools.partial(
-                    _mlp_lora_ragged_q, block_size=bs),
-                    donate_argnums=(2, 3))
-                self._verify = jax.jit(functools.partial(
-                    _mlp_lora_verify_q, block_size=bs),
-                    donate_argnums=(2, 3))
-            else:
-                self.cache = jnp.zeros_like(base.cache)
-                self.cache_scale = None
-                self._ragged = jax.jit(functools.partial(
-                    _mlp_lora_ragged, block_size=bs),
-                    donate_argnums=(2,))
-                self._verify = jax.jit(functools.partial(
-                    _mlp_lora_verify, block_size=bs),
-                    donate_argnums=(2,))
+            from .engine import _mlp_ragged, _mlp_verify
+
+            bases, static = (_mlp_ragged, _mlp_verify), {
+                "block_size": base.block_size}
+        self.pools = jax.tree.map(jnp.zeros_like, base.pools)
+        self._ragged, self._verify = (
+            jax.jit(functools.partial(
+                fn, base=functools.partial(b, **static),
+                nlayers=self._nlayers), donate_argnums=(2,))
+            for fn, b in zip((_lora_ragged, _lora_verify), bases))
+        # the base's block executables are pure: over THIS engine's
+        # pools they cost no extra trace
+        self._copy_block, self._kv_gather, self._kv_scatter = (
+            base._copy_block, base._kv_gather, base._kv_scatter)
+        self._mig_header = base._mig_header
+        self._slab_names = base._slab_names
         gb = getattr(base.manager, "bytes_per_block", None)
         if gb:
             self.manager.set_kv_geometry(gb, self.kv_bits)
@@ -675,7 +572,7 @@ class LoRAEngine:
         across every target pool tensor (donated, fixed-shape)."""
         s = np.int32(slot)
         for key, (a, b) in padded.items():
-            pl = self._pools[key]
+            pl = self._adapters[key]
             pl["a"] = self._slot_set(pl["a"], a, s)
             pl["b"] = self._slot_set(pl["b"], b, s)
 
@@ -711,107 +608,22 @@ class LoRAEngine:
 
     # -- EngineCore dispatch surfaces --
     def ragged_step(self, tokens, q_lens, kv_lens, block_tables):
-        if self._kind == "llama":
-            if self.kv_bits == 8:
-                (logits, self.k_cache, self.v_cache, self.k_scale,
-                 self.v_scale) = self._ragged(
-                    self.params, self._pools, self.k_cache, self.v_cache,
-                    self.k_scale, self.v_scale, self._lane_slots,
-                    np.asarray(tokens, np.int32),
-                    np.asarray(q_lens, np.int32),
-                    np.asarray(kv_lens, np.int32),
-                    np.asarray(block_tables, np.int32))
-                return logits
-            logits, self.k_cache, self.v_cache = self._ragged(
-                self.params, self._pools, self.k_cache, self.v_cache,
-                self._lane_slots, np.asarray(tokens, np.int32),
-                np.asarray(q_lens, np.int32),
-                np.asarray(kv_lens, np.int32),
-                np.asarray(block_tables, np.int32))
-            return logits
-        if self.kv_bits == 8:
-            logits, self.cache, self.cache_scale = self._ragged(
-                self.params, self._pools, self.cache, self.cache_scale,
-                self._lane_slots, np.asarray(tokens, np.int32),
-                np.asarray(q_lens, np.int32),
-                np.asarray(kv_lens, np.int32),
-                np.asarray(block_tables, np.int32))
-            return logits
-        logits, self.cache = self._ragged(
-            self.params, self._pools, self.cache, self._lane_slots,
+        logits, self.pools = self._ragged(
+            self.params, self._adapters, self.pools, self._lane_slots,
             np.asarray(tokens, np.int32), np.asarray(q_lens, np.int32),
             np.asarray(kv_lens, np.int32),
             np.asarray(block_tables, np.int32))
         return logits
 
     def verify_step(self, tokens, context_lens, block_tables):
-        if self._kind == "llama":
-            if self.kv_bits == 8:
-                (logits, self.k_cache, self.v_cache, self.k_scale,
-                 self.v_scale) = self._verify(
-                    self.params, self._pools, self.k_cache, self.v_cache,
-                    self.k_scale, self.v_scale, self._lane_slots,
-                    np.asarray(tokens, np.int32),
-                    np.asarray(context_lens, np.int32),
-                    np.asarray(block_tables, np.int32))
-                return logits
-            logits, self.k_cache, self.v_cache = self._verify(
-                self.params, self._pools, self.k_cache, self.v_cache,
-                self._lane_slots, np.asarray(tokens, np.int32),
-                np.asarray(context_lens, np.int32),
-                np.asarray(block_tables, np.int32))
-            return logits
-        if self.kv_bits == 8:
-            logits, self.cache, self.cache_scale = self._verify(
-                self.params, self._pools, self.cache, self.cache_scale,
-                self._lane_slots, np.asarray(tokens, np.int32),
-                np.asarray(context_lens, np.int32),
-                np.asarray(block_tables, np.int32))
-            return logits
-        logits, self.cache = self._verify(
-            self.params, self._pools, self.cache, self._lane_slots,
+        logits, self.pools = self._verify(
+            self.params, self._adapters, self.pools, self._lane_slots,
             np.asarray(tokens, np.int32),
             np.asarray(context_lens, np.int32),
             np.asarray(block_tables, np.int32))
         return logits
 
-    def copy_kv_block(self, src: int, dst: int) -> None:
-        """COW hook over THIS engine's pools (the base's jitted copy
-        lambdas are pure — reusing them costs no extra trace)."""
-        b = self.base
-        if self._kind == "llama":
-            if self.kv_bits == 8:
-                (self.k_cache, self.v_cache, self.k_scale,
-                 self.v_scale) = b._copy_block_q(
-                    self.k_cache, self.v_cache, self.k_scale,
-                    self.v_scale, np.int32(src), np.int32(dst))
-                return
-            self.k_cache, self.v_cache = b._copy_block(
-                self.k_cache, self.v_cache, np.int32(src), np.int32(dst))
-            return
-        if self.kv_bits == 8:
-            self.cache, self.cache_scale = b._copy_block_q(
-                self.cache, self.cache_scale, np.int32(src),
-                np.int32(dst))
-            return
-        self.cache = b._copy_block(self.cache, np.int32(src),
-                                   np.int32(dst))
-
-    # -- legacy entries: the ragged path is the only serving program --
-    def _no_legacy(self, entry: str):
-        raise RuntimeError(
-            f"{entry} has no per-lane adapter identity; a LoRA engine "
-            "serves through ragged_step/verify_step (the scheduler's "
-            "only dispatches)")
-
-    def prefill(self, *a, **kw):
-        self._no_legacy("prefill")
-
-    def decode_step(self, *a, **kw):
-        self._no_legacy("decode_step")
-
-    def generate(self, *a, **kw):
-        self._no_legacy("generate")
+    generate = generate
 
     # -- observability / lifecycle --
     def quant_info(self) -> Dict[str, object]:
@@ -824,21 +636,11 @@ class LoRAEngine:
         return self.base.kv_bytes_per_token()
 
     def cost_card_args(self, phase: str):
-        """Cost-card hook: the LoRA executables take (params, pools,
-        caches..., lane_slots) ahead of the scheduler's call arrays."""
+        """Cost-card hook: the LoRA executables take (params, adapters,
+        pools, lane_slots) ahead of the scheduler's call arrays."""
         fn = {"decode": self._ragged, "ragged": self._ragged,
               "verify": self._verify}[phase]
-        if self._kind == "llama":
-            if self.kv_bits == 8:
-                return fn, (self.params, self._pools, self.k_cache,
-                            self.v_cache, self.k_scale, self.v_scale,
-                            self._lane_slots)
-            return fn, (self.params, self._pools, self.k_cache,
-                        self.v_cache, self._lane_slots)
-        if self.kv_bits == 8:
-            return fn, (self.params, self._pools, self.cache,
-                        self.cache_scale, self._lane_slots)
-        return fn, (self.params, self._pools, self.cache,
+        return fn, (self.params, self._adapters, self.pools,
                     self._lane_slots)
 
     def respawn(self) -> "LoRAEngine":
@@ -862,71 +664,6 @@ class LoRAEngine:
             fresh.adapter_pool.pin(name)
         fresh.adapter_pool._publish()
         return fresh
-
-    # -- KV migration (fleet relocation / disaggregated handoff) --
-    def extract_kv_blocks(self, seq_id: int) -> kv_migrate.KVBlockPayload:
-        mgr = self.manager
-        blocks = mgr.blocks_of(seq_id)
-        if not blocks:
-            raise kv_migrate.KVMigrationError(
-                f"sequence {seq_id} holds no KV blocks on this engine")
-        idx = kv_migrate.pad_block_indices(blocks, mgr.max_blocks_per_seq)
-        header = dict(self.base._mig_header, num_blocks=len(blocks),
-                      num_tokens=mgr.seq_len(seq_id))
-        b = self.base
-        if self._kind == "llama":
-            if self.kv_bits == 8:
-                sk, sv, sks, svs = b._kv_gather(
-                    self.k_cache, self.v_cache, self.k_scale,
-                    self.v_scale, idx)
-                return kv_migrate.KVBlockPayload(
-                    header, {"k": sk, "v": sv, "k_scale": sks,
-                             "v_scale": svs})
-            sk, sv = b._kv_gather(self.k_cache, self.v_cache, idx)
-            return kv_migrate.KVBlockPayload(header, {"k": sk, "v": sv})
-        if self.kv_bits == 8:
-            slab, ss = b._kv_gather(self.cache, self.cache_scale, idx)
-            return kv_migrate.KVBlockPayload(
-                header, {"cache": slab, "scale": ss})
-        return kv_migrate.KVBlockPayload(
-            header, {"cache": b._kv_gather(self.cache, idx)})
-
-    def inject_kv_blocks(self, seq_id: int,
-                         payload: kv_migrate.KVBlockPayload) -> None:
-        mgr = self.manager
-        kv_migrate.check_header(payload.header, self.base._mig_header)
-        blocks = mgr.allocate(seq_id, payload.num_tokens)
-        try:
-            if len(blocks) != payload.num_blocks:
-                raise kv_migrate.KVMigrationError(
-                    f"payload carries {payload.num_blocks} blocks but "
-                    f"{payload.num_tokens} tokens allocate "
-                    f"{len(blocks)} here")
-            idx = kv_migrate.pad_block_indices(blocks,
-                                               mgr.max_blocks_per_seq)
-            b = self.base
-            if self._kind == "llama":
-                if self.kv_bits == 8:
-                    (self.k_cache, self.v_cache, self.k_scale,
-                     self.v_scale) = b._kv_scatter(
-                        self.k_cache, self.v_cache, self.k_scale,
-                        self.v_scale, idx, payload.slabs["k"],
-                        payload.slabs["v"], payload.slabs["k_scale"],
-                        payload.slabs["v_scale"])
-                else:
-                    self.k_cache, self.v_cache = b._kv_scatter(
-                        self.k_cache, self.v_cache, idx,
-                        payload.slabs["k"], payload.slabs["v"])
-            elif self.kv_bits == 8:
-                self.cache, self.cache_scale = b._kv_scatter(
-                    self.cache, self.cache_scale, idx,
-                    payload.slabs["cache"], payload.slabs["scale"])
-            else:
-                self.cache = b._kv_scatter(self.cache, idx,
-                                           payload.slabs["cache"])
-        except Exception:
-            mgr.free(seq_id)
-            raise
 
 
 def attach_adapters(engine, pool_slots: int = 8,

@@ -10,7 +10,8 @@ Everything is double-published:
   `summary()` can report p50/p99 TTFT and mean TPOT (percentiles can't
   be rebuilt from monotonic counters).
 
-Retrace counters (`serving.prefill_retraces` / `serving.decode_retraces`)
+Retrace counters (`serving.decode_retraces` / `serving.ragged_retraces` /
+`serving.verify_retraces`)
 are bumped by the ENGINES at jit-trace time (see serving/engine.py); this
 module only reads them. In steady state they must stay flat.
 """

@@ -128,48 +128,45 @@ class DraftEngineProposer:
     """Draft-model proposer over a second (small) `EngineCore`.
 
     The draft engine keeps its own paged cache in sync with each verified
-    context: catch-up tokens are fed through single-token `decode_step`
-    calls (writing their KV), then K proposals are decoded greedily and
-    the cache is `trim`med back to the verified length — rejected
-    speculation never pollutes the draft state. All failures (draft pool
-    exhausted, sequence over the draft's length cap) degrade to "no
-    proposal", never to an error on the serving path."""
+    context through its `ragged_step`, one lane a call: the first context
+    goes in as one step (`q_len` = its length, the packed buffer padded to
+    a length bucket so the compile count stays bounded; guard slots write
+    nothing), catch-up tokens and the K greedy proposals as `q_len = 1`
+    steps, and the cache is `trim`med back to the verified length —
+    rejected speculation never pollutes the draft state. All failures
+    (draft pool exhausted, sequence over the draft's length cap) degrade
+    to "no proposal", never to an error on the serving path."""
 
     def __init__(self, engine):
         self.engine = engine
         self._synced: Dict[int, int] = {}   # seq_id -> tokens in draft cache
 
     # -- helpers ----------------------------------------------------------
-    def _decode_one(self, token: int, seq_id: int) -> np.ndarray:
+    def _step(self, seq_id: int, tokens: np.ndarray, n: int) -> np.ndarray:
+        """The last live row's logits [V] of one ragged step that appends
+        `tokens[:n]` to `seq_id`, whose cache then holds `seq_len` tokens."""
         mgr = self.engine.manager
-        tables = mgr.block_table_array([seq_id])
-        lens = np.asarray([mgr.seq_len(seq_id)], np.int32)
-        return np.asarray(self.engine.decode_step(
-            np.asarray([token], np.int32), lens, tables))
+        logits = self.engine.ragged_step(
+            tokens, np.asarray([n], np.int32),
+            np.asarray([mgr.seq_len(seq_id)], np.int32),
+            mgr.block_table_array([seq_id]))
+        return np.asarray(logits[n - 1])
+
+    def _decode_one(self, token: int, seq_id: int) -> np.ndarray:
+        return self._step(seq_id, np.asarray([token], np.int32), 1)
 
     def _prefill(self, seq_id: int, ctx: np.ndarray) -> np.ndarray:
-        """Bucket-padded prefill (bounded compile count) + trim."""
         mgr = self.engine.manager
         n = len(ctx)
+        mgr.allocate(seq_id, n)     # typed SequenceTooLong over the cap
+        self._synced[seq_id] = n    # from here on release() frees the lease
         cap = mgr.max_blocks_per_seq * mgr.block_size
-        if n > cap:
-            # context outgrew the draft cache's per-sequence cap: raise so
-            # propose() degrades to "no proposal" (the doubling loop below
-            # would otherwise saturate at cap < n and spin forever)
-            raise SequenceTooLong(mgr.blocks_needed(n),
-                                  mgr.max_blocks_per_seq)
         bucket = mgr.block_size
         while bucket < n:
             bucket = min(bucket * 2, cap)
-        mgr.allocate(seq_id, bucket)
-        padded = np.zeros((1, bucket), np.int32)
-        padded[0, :n] = ctx
-        tables = mgr.block_table_array([seq_id])
-        logits = np.asarray(self.engine.prefill(
-            padded, tables, lens=np.asarray([n], np.int32)))
-        mgr.trim(seq_id, n)
-        self._synced[seq_id] = n
-        return logits
+        padded = np.zeros((bucket,), np.int32)
+        padded[:n] = ctx
+        return self._step(seq_id, padded, n)
 
     # -- Proposer protocol -------------------------------------------------
     def propose(self, seq_id: int, context: np.ndarray,
@@ -195,14 +192,14 @@ class DraftEngineProposer:
                         logits = self._decode_one(int(ctx[j]), seq_id)
                     self._synced[seq_id] = n
             # greedy draft rollout; proposal KV is trimmed away below
-            props = [int(np.argmax(logits[0]))]
+            props = [int(np.argmax(logits))]
             while len(props) < k:
                 try:
                     mgr.append_token(seq_id)
                 except (KVCacheExhausted, SequenceTooLong):
                     break
                 logits = self._decode_one(props[-1], seq_id)
-                props.append(int(np.argmax(logits[0])))
+                props.append(int(np.argmax(logits)))
             mgr.trim(seq_id, n)
             return props
         except Exception:

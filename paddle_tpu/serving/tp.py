@@ -61,6 +61,7 @@ from ..distributed.process_mesh import ProcessMesh
 from ..distributed.tp_overlap import TPInfo
 from ..inference import kv_migrate
 from ..inference.cache import BlockCacheManager
+from ..inference.generate import generate
 from ..observability import comms
 
 __all__ = ["ShardingConfigError", "shard_engine", "ShardedEngine"]
@@ -264,15 +265,17 @@ def shard_engine(engine, mesh: Optional[ProcessMesh] = None, *,
                          overlap_tiles=int(overlap_tiles))
 
 
-class ShardedEngine:
-    """TP-sharded `EngineCore`: the serving scheduler's three dispatch
-    surfaces (`ragged_step`, `verify_step`, `copy_kv_block`) over
-    shard_map'd executables, plus the observability hooks
-    (`cost_card_args` lowers the SPMD program, so the CostCard reports
-    PER-CHIP FLOPs; `quant_info` reports per-chip KV bytes). Legacy
-    single-chip entry points (`prefill`/`decode_step`/`generate`)
-    raise, mirroring the kv_bits=8 discipline — the ragged path is the
-    only serving program."""
+class ShardedEngine(kv_migrate.PagedPools):
+    """TP-sharded `EngineCore`: the serving scheduler's dispatch surfaces
+    (`ragged_step`, `verify_step`) over shard_map'd executables,
+    `copy_kv_block` and KV migration over the sharded pool tuple
+    (`kv_migrate.PagedPools`: block ids are logical, the sharded
+    head/feature axis is untouched, so every chip moves its own slice
+    with no collective, and a payload's slabs stay TP-sharded — the
+    header's `tp` pins that they only inject into an identically-sharded
+    engine), plus the observability hooks (`cost_card_args` lowers the
+    SPMD program, so the CostCard reports PER-CHIP FLOPs; `quant_info`
+    reports per-chip KV bytes)."""
 
     def __init__(self, base, pmesh: ProcessMesh, *, tp: int, dp: int,
                  kind: str, overlap: bool, overlap_tiles: int):
@@ -308,12 +311,10 @@ class ShardedEngine:
                         for k, x in v.items()}
             return jax.device_put(v, NamedSharding(jmesh, spec))
 
-        kv8 = self.kv_bits == 8
         if kind == "llama":
             from ..inference import kv_quant
             from ..inference.llama_runner import (_StaticCfg, _ragged_fn,
-                                                  _ragged_q_fn, _verify_fn,
-                                                  _verify_q_fn)
+                                                  _verify_fn)
 
             cfg = base.config
             nh, kvh, d = (cfg.num_attention_heads, cfg.num_key_value_heads,
@@ -336,17 +337,9 @@ class ShardedEngine:
                 pspec["lm_head"] = _wspec(p["lm_head"], "col")
             self.params = {k: put(v, pspec[k]) for k, v in p.items()}
             kvspec = P(None, None, "tp", None, None)
-            sspec = P(None, None, "tp", None)
-            if kv8:
-                self._pools = [put(base.k_cache, kvspec),
-                               put(base.v_cache, kvspec),
-                               put(base.k_scale, sspec),
-                               put(base.v_scale, sspec)]
-                poolspec = (kvspec, kvspec, sspec, sspec)
-            else:
-                self._pools = [put(base.k_cache, kvspec),
-                               put(base.v_cache, kvspec)]
-                poolspec = (kvspec, kvspec)
+            # int8 scale planes split with their heads
+            poolspec = (kvspec, kvspec, P(None, None, "tp", None),
+                        P(None, None, "tp", None))[:len(base.pools)]
             lcfg = _StaticCfg(cfg)
             lcfg.num_heads //= tp
             lcfg.num_kv_heads //= tp
@@ -354,32 +347,19 @@ class ShardedEngine:
             lspec = R if (overlap or not vocab_sharded) else P(None, "tp")
             vspec = R if (overlap or not vocab_sharded) \
                 else P(None, None, "tp")
-            ragged = functools.partial(_ragged_q_fn if kv8 else _ragged_fn,
-                                       cfg=lcfg)
-            verify = functools.partial(_verify_q_fn if kv8 else _verify_fn,
-                                       cfg=lcfg)
+            ragged = functools.partial(_ragged_fn, cfg=lcfg)
+            verify = functools.partial(_verify_fn, cfg=lcfg)
             geom = dict(base._kv_geom)
             geom["kv_heads"] //= tp
             self._kv_bytes_per_token = kv_quant.kv_bytes_per_token(**geom)
             self.manager.set_kv_geometry(
                 kv_quant.kv_bytes_per_block(**geom), self.kv_bits)
-            if kv8:
-                # COW moves the int8 block and its scale rows atomically
-                # (head axis sharded on both — shardings propagate)
-                self._copy = jax.jit(
-                    lambda k, v, ks, vs, s, d: (
-                        k.at[:, d].set(k[:, s]), v.at[:, d].set(v[:, s]),
-                        ks.at[:, d].set(ks[:, s]),
-                        vs.at[:, d].set(vs[:, s])),
-                    donate_argnums=(0, 1, 2, 3))
-            else:
-                self._copy = jax.jit(
-                    lambda k, v, s, d: (k.at[:, d].set(k[:, s]),
-                                        v.at[:, d].set(v[:, s])),
-                    donate_argnums=(0, 1))
+            header = {"engine": "llama",
+                      "num_layers": geom["num_layers"],
+                      "kv_heads": base._kv_geom["kv_heads"],
+                      "head_dim": geom["head_dim"]}
         else:
-            from .engine import (_mlp_ragged, _mlp_ragged_q, _mlp_verify,
-                                 _mlp_verify_q)
+            from .engine import _mlp_ragged, _mlp_verify
 
             d = int(base.params["embed"].shape[1])
             p = dict(base.params)
@@ -389,93 +369,46 @@ class ShardedEngine:
                      "w2": _wspec(p["w2"], "col"),
                      "b2": P("tp")}
             self.params = {k: put(v, pspec[k]) for k, v in p.items()}
-            cspec = P(None, None, "tp")
-            if kv8:
-                # the int8 scale plane stays REPLICATED: absmax is over
-                # the FULL feature vector (bitwise parity), so every
-                # shard holds every slot's scale
-                self._pools = [put(base.cache, cspec),
-                               put(base.cache_scale, R)]
-                poolspec = (cspec, R)
-            else:
-                self._pools = [put(base.cache, cspec)]
-                poolspec = (cspec,)
+            # the int8 scale plane stays REPLICATED: absmax is over
+            # the FULL feature vector (bitwise parity), so every
+            # shard holds every slot's scale
+            poolspec = (P(None, None, "tp"), R)[:len(base.pools)]
             lspec = R if overlap else P(None, "tp")
             vspec = R if overlap else P(None, None, "tp")
-            ragged = functools.partial(_mlp_ragged_q if kv8 else _mlp_ragged,
+            ragged = functools.partial(_mlp_ragged,
                                        block_size=base.block_size,
                                        tp=self.tpinfo)
-            verify = functools.partial(_mlp_verify_q if kv8 else _mlp_verify,
+            verify = functools.partial(_mlp_verify,
                                        block_size=base.block_size,
                                        tp=self.tpinfo)
-            bpb = (base.block_size * (d // tp) + base.block_size * 4) \
-                if kv8 else base.block_size * (d // tp) * 4
+            bpb = base.block_size * (d // tp) * base.pools[0].dtype.itemsize \
+                + base.block_size * 4 * (len(base.pools) - 1)
             self._kv_bytes_per_token = bpb / base.block_size
             self.manager.set_kv_geometry(bpb, self.kv_bits)
-            if kv8:
-                self._copy = jax.jit(
-                    lambda c, cs, s, d: (c.at[d].set(c[s]),
-                                         cs.at[d].set(cs[s])),
-                    donate_argnums=(0, 1))
-            else:
-                self._copy = jax.jit(lambda c, s, d: c.at[d].set(c[s]),
-                                     donate_argnums=(0,))
+            header = {"engine": "mlp", "hidden": d}
 
-        donate = tuple(range(1, 1 + len(self._pools)))
+        self.pools = tuple(put(p, s) for p, s in zip(base.pools, poolspec))
         self._ragged = jax.jit(jax.shard_map(
             ragged, mesh=jmesh,
-            in_specs=(pspec,) + poolspec + (R, R, R, R),
-            out_specs=(lspec,) + poolspec,
-            check_vma=False), donate_argnums=donate)
+            in_specs=(pspec, poolspec, R, R, R, R),
+            out_specs=(lspec, poolspec),
+            check_vma=False), donate_argnums=(1,))
         self._verify = jax.jit(jax.shard_map(
             verify, mesh=jmesh,
-            in_specs=(pspec,) + poolspec + (R, R, R),
-            out_specs=(vspec,) + poolspec,
-            check_vma=False), donate_argnums=donate)
+            in_specs=(pspec, poolspec, R, R, R),
+            out_specs=(vspec, poolspec),
+            check_vma=False), donate_argnums=(1,))
         self._step_label = f"serving.ragged_step_tp{tp}"
-        # KV migration (inference/kv_migrate.py, ISSUE 17): the gather/
-        # scatter index the LOGICAL block axis, which is unsharded in
-        # both layouts — the compiled programs move each chip's slice
-        # locally with ZERO collectives, and the slabs stay sharded
-        # end-to-end (per-shard export; the header's `tp` pins that a
-        # payload only injects into an identically-partitioned engine).
-        # Gather NOT donated (the source pool lives on); scatter
-        # donates every destination pool.
-        npools = len(self._pools)
-        if kind == "llama":
-            self._kv_gather = jax.jit(
-                lambda *a: tuple(p[:, a[-1]] for p in a[:-1]))
-            self._kv_scatter = jax.jit(
-                lambda *a: tuple(
-                    p.at[:, a[npools]].set(s)
-                    for p, s in zip(a[:npools], a[npools + 1:])),
-                donate_argnums=tuple(range(npools)))
-            g0 = base._kv_geom
-            self._mig_header = {
-                "version": kv_migrate.PAYLOAD_VERSION, "engine": "llama",
-                "block_size": base.block_size,
-                "max_blocks_per_seq": self.manager.max_blocks_per_seq,
-                "kv_bits": self.kv_bits, "tp": tp,
-                "num_layers": g0["num_layers"],
-                "kv_heads": g0["kv_heads"], "head_dim": g0["head_dim"],
-                "dtype": str(self._pools[0].dtype),
-            }
-        else:
-            self._kv_gather = jax.jit(
-                lambda *a: tuple(p[a[-1]] for p in a[:-1]))
-            self._kv_scatter = jax.jit(
-                lambda *a: tuple(
-                    p.at[a[npools]].set(s)
-                    for p, s in zip(a[:npools], a[npools + 1:])),
-                donate_argnums=tuple(range(npools)))
-            self._mig_header = {
-                "version": kv_migrate.PAYLOAD_VERSION, "engine": "mlp",
-                "block_size": base.block_size,
-                "max_blocks_per_seq": self.manager.max_blocks_per_seq,
-                "kv_bits": self.kv_bits, "tp": tp,
-                "hidden": int(base.params["embed"].shape[1]),
-                "dtype": str(self._pools[0].dtype),
-            }
+        # COW copy and KV migration index the LOGICAL block axis, which is
+        # unsharded in both layouts: shardings propagate through the
+        # jits, each chip moves its own slice
+        self._build_block_ops(1 if kind == "llama" else 0)
+        self._slab_names = tuple(f"p{i}" for i in range(len(self.pools)))
+        self._mig_header = dict(
+            header, version=kv_migrate.PAYLOAD_VERSION,
+            block_size=base.block_size,
+            max_blocks_per_seq=self.manager.max_blocks_per_seq,
+            kv_bits=self.kv_bits, tp=tp, dtype=str(self.pools[0].dtype))
 
     # ---- observability surface ----
     def tp_summary(self) -> dict:
@@ -504,10 +437,10 @@ class ShardedEngine:
         pair reports PER-CHIP FLOPs (XLA cost analysis is per-device for
         SPMD programs) — the %peak math stops counting the replicated
         illusion. Phases without a TP executable raise KeyError (the
-        caller tombstones), like the kv_bits=8 engines."""
+        caller tombstones)."""
         fn = {"decode": self._ragged, "ragged": self._ragged,
               "verify": self._verify}[phase]
-        return fn, (self.params, *self._pools)
+        return fn, (self.params, self.pools)
 
     # ---- the EngineCore dispatch surface ----
     def ragged_step(self, tokens: np.ndarray, q_lens: np.ndarray,
@@ -537,9 +470,8 @@ class ShardedEngine:
                               block_tables)
 
     def _dispatch(self, fn, obs_on, *args):
-        out = fn(self.params, *self._pools,
-                 *(np.asarray(a, np.int32) for a in args))
-        logits, self._pools = out[0], list(out[1:])
+        logits, self.pools = fn(self.params, self.pools,
+                                *(np.asarray(a, np.int32) for a in args))
         if self.overlap:
             if obs_on:
                 self._jax.block_until_ready(logits)
@@ -556,70 +488,4 @@ class ShardedEngine:
             return assembled
         return np.asarray(logits)
 
-    def copy_kv_block(self, src: int, dst: int) -> None:
-        """COW hook: block ids are logical and the copy moves every
-        shard's slice of the block (the sharded head/feature axis is
-        untouched) — radix/refcount semantics identical to single-chip."""
-        self._pools = list(self._copy(*self._pools, np.int32(src),
-                                      np.int32(dst)))
-
-    def extract_kv_blocks(self, seq_id: int) -> kv_migrate.KVBlockPayload:
-        """Export `seq_id`'s blocks from every pool plane in ONE device
-        gather; the slabs stay TP-sharded (each chip contributes its
-        head/feature slice — per-shard export) and the header's `tp`
-        pins the partitioning, so a payload only ever injects into an
-        identically-sharded engine. Source pools untouched."""
-        mgr = self.manager
-        blocks = mgr.blocks_of(seq_id)
-        if not blocks:
-            raise kv_migrate.KVMigrationError(
-                f"sequence {seq_id} holds no KV blocks on this engine")
-        idx = kv_migrate.pad_block_indices(blocks, mgr.max_blocks_per_seq)
-        header = dict(self._mig_header, num_blocks=len(blocks),
-                      num_tokens=mgr.seq_len(seq_id))
-        slabs = self._kv_gather(*self._pools, idx)
-        return kv_migrate.KVBlockPayload(
-            header, {f"p{i}": s for i, s in enumerate(slabs)})
-
-    def inject_kv_blocks(self, seq_id: int,
-                         payload: kv_migrate.KVBlockPayload) -> None:
-        """Import a migrated payload under `seq_id`: typed header
-        validation (including the `tp` degree) BEFORE any allocation,
-        typed capacity errors from `allocate`, one donated scatter per
-        call; post-allocation failure frees the blocks. The jit
-        re-establishes each slab's sharding, so source and target pools
-        stay partition-identical without host round-trips."""
-        mgr = self.manager
-        kv_migrate.check_header(payload.header, self._mig_header)
-        blocks = mgr.allocate(seq_id, payload.num_tokens)
-        try:
-            if len(blocks) != payload.num_blocks:
-                raise kv_migrate.KVMigrationError(
-                    f"payload carries {payload.num_blocks} blocks but "
-                    f"{payload.num_tokens} tokens allocate "
-                    f"{len(blocks)} here")
-            idx = kv_migrate.pad_block_indices(blocks,
-                                               mgr.max_blocks_per_seq)
-            slabs = [payload.slabs[f"p{i}"]
-                     for i in range(len(self._pools))]
-            self._pools = list(self._kv_scatter(*self._pools, idx,
-                                                *slabs))
-        except Exception:
-            mgr.free(seq_id)
-            raise
-
-    # ---- legacy single-chip entries ----
-    def _no_legacy(self, entry: str):
-        raise RuntimeError(
-            f"{entry} is a single-chip legacy entry point; a TP-sharded "
-            "engine serves through ragged_step/verify_step (the "
-            "scheduler's only dispatches)")
-
-    def prefill(self, *a, **k):
-        self._no_legacy("prefill")
-
-    def decode_step(self, *a, **k):
-        self._no_legacy("decode_step")
-
-    def generate(self, *a, **k):
-        self._no_legacy("generate")
+    generate = generate

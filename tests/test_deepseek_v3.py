@@ -340,14 +340,12 @@ def _refusals():
                                lambda e: e.extract_kv_blocks(0)),
         "kv_migrate.inject": (kv_migrate.KVMigrationError,
                               lambda e: e.inject_kv_blocks(0, None)),
-        "prefill": (RuntimeError, lambda e: e.prefill(None, None)),
-        "decode_step": (RuntimeError, lambda e: e.decode_step(None, None, None)),
     }
 
 
 @pytest.mark.parametrize("transform", [
     "quantize_engine", "shard_engine", "attach_adapters",
-    "kv_migrate.extract", "kv_migrate.inject", "prefill", "decode_step"])
+    "kv_migrate.extract", "kv_migrate.inject"])
 def test_transforms_refuse_the_family_by_name(transform):
     error, call = _refusals()[transform]
     with pytest.raises(error, match="(?i)deepseek_?v3"):

@@ -65,10 +65,9 @@ def test_every_kernel_call_site_names_its_kernel(site):
 
 def test_kernel_names_are_distinct_and_cover_the_main_path():
     names = [n.value for _f, _l, n in SITES]
-    assert len(names) == len(set(names)) == 20
+    assert len(names) == len(set(names)) == 18
     assert {"paged_attention_ragged", "kv_write_ragged",
-            "paged_attention_decode",
-            "paged_attention_verify", "paged_attention_mla",
+            "paged_attention_mla",
             "moe_grouped_matmul", "flash_fwd",
             "flash_dq", "flash_dkv",
             "rms_norm", "fused_rope", "quant_matmul_int8",
@@ -107,7 +106,7 @@ def ragged_text(tiny):
     flags.set_flags({"FLAGS_pallas_interpret": True})
     try:
         lowered = eng._ragged.lower(
-            eng.params, eng.k_cache, eng.v_cache, np.zeros((12,), np.int32),
+            eng.params, eng.pools, np.zeros((12,), np.int32),
             np.zeros((4,), np.int32), np.zeros((4,), np.int32),
             np.zeros((4, 8), np.int32))
     finally:

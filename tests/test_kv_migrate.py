@@ -394,10 +394,10 @@ class TestPrefixStreaming:
         self._publish_on(fe1)
         mgr = fe1.scheduler.engine.manager
         free0 = mgr.free_blocks
-        cache0 = np.asarray(fe1.scheduler.engine.cache).copy()
+        cache0 = np.asarray(fe1.scheduler.engine.pools[0]).copy()
         fe1.scheduler.export_prefix(self.PROMPT)
         assert mgr.free_blocks == free0         # transient lease freed
-        assert np.array_equal(np.asarray(fe1.scheduler.engine.cache),
+        assert np.array_equal(np.asarray(fe1.scheduler.engine.pools[0]),
                               cache0)
         mgr.check_consistency()
 

@@ -342,12 +342,6 @@ class TestMultiAdapterServing:
         assert h2.status is RequestStatus.REJECTED
         assert h2.finish_reason == "no_adapter_pool"
 
-    def test_legacy_entry_points_raise(self):
-        eng = _mlp_lora()
-        for entry in ("prefill", "decode_step", "generate"):
-            with pytest.raises(RuntimeError, match="ragged_step"):
-                getattr(eng, entry)()
-
     def test_respawn_carries_registry_and_pins(self):
         eng = _mlp_lora(seed=3, pool_slots=2)
         for i in range(2):

@@ -689,10 +689,8 @@ def test_llama_ragged_step_compiles_for_v5e_without_a_pool_copy(
         "rope_sin": arr((2048, d // 2), jnp.float32)}
     pool = (layers, nb, kvh, bs, d)
     if kv_bits == 8:
-        fn = lr._ragged_q_fn
         pools = (arr(pool, jnp.int8),) * 2 + (arr(pool[:-1], jnp.float32),) * 2
     else:
-        fn = lr._ragged_fn
         pools = (arr(pool),) * 2
 
     class Cfg:
@@ -700,16 +698,15 @@ def test_llama_ragged_step_compiles_for_v5e_without_a_pool_copy(
         hidden_size, intermediate_size = hidden, inter
         rms_norm_eps, tie_word_embeddings = 1e-5, False
 
-    step = functools.partial(fn, cfg=lr._StaticCfg(Cfg))
+    step = functools.partial(lr._ragged_fn, cfg=lr._StaticCfg(Cfg))
     ints = [arr(shape, jnp.int32)
             for shape in ((tokens,), (lanes,), (lanes,), (lanes, width))]
     # a TPU executable cannot be read back from the persistent cache here
     cache_was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     try:
-        compiled = jax.jit(
-            step, donate_argnums=tuple(range(1, 1 + len(pools)))).trace(
-            params, *pools, *ints).lower(
+        compiled = jax.jit(step, donate_argnums=(1,)).trace(
+            params, pools, *ints).lower(
             lowering_platforms=("tpu",)).compile()
     finally:
         jax.config.update("jax_enable_compilation_cache", cache_was)

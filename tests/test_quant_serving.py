@@ -383,20 +383,10 @@ class TestQuantizeEngine:
 
         assert run(spec=True) == run(spec=False)
 
-    def test_legacy_entry_points_raise_on_kv8(self):
-        eng = MLPLMEngine(kv_bits=8)
-        with pytest.raises(RuntimeError, match="ragged_step"):
-            eng.prefill(np.zeros((1, 4), np.int32), np.zeros((1, 8),
-                                                            np.int32))
-        with pytest.raises(RuntimeError, match="ragged_step"):
-            eng.decode_step(np.zeros((1,), np.int32),
-                            np.ones((1,), np.int32),
-                            np.zeros((1, 8), np.int32))
-
     def test_respawn_keeps_quant_pool(self):
         eng = MLPLMEngine(kv_bits=8)
         fresh = eng.respawn()
-        assert fresh.kv_bits == 8 and fresh.cache.dtype == np.int8
+        assert fresh.kv_bits == 8 and fresh.pools[0].dtype == np.int8
 
     def test_quant_gauges_and_profiler_section(self):
         from paddle_tpu.profiler import profiler as prof_mod
@@ -565,18 +555,6 @@ class TestLlamaQuant:
         w = eng.params["qkv_w"]
         assert isinstance(w, dict) and "q4" in w
         assert eng.quant_info()["wbits"] == 4
-
-    def test_legacy_paths_raise_on_kv8(self, llama_model):
-        eng = _llama_engine(llama_model, kv_bits=8)
-        with pytest.raises(RuntimeError, match="ragged_step"):
-            eng.prefill(np.zeros((1, 4), np.int32),
-                        np.zeros((1, 16), np.int32))
-        free_before = eng.manager.free_blocks
-        with pytest.raises(RuntimeError, match="ragged_step"):
-            eng.generate(np.zeros((1, 4), np.int32))
-        # the guard must fire BEFORE generate() allocates: a raise after
-        # the lease would strand the blocks forever (review regression)
-        assert eng.manager.free_blocks == free_before
 
 
 # ---------------------------------------------------------------------------

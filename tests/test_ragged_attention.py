@@ -102,10 +102,10 @@ class TestRaggedKernelParity:
         assert float(np.abs(np.asarray(out)[guard]).max()) == 0.0
         assert float(np.abs(np.asarray(ref)[guard]).max()) == 0.0
 
-    def test_decode_composition_matches_legacy_decode_kernel(self):
-        """A pure decode batch through the ragged kernel is the legacy
-        single-query decode kernel to float rounding (the ragged kernel
-        folds several pages into one online-softmax step)."""
+    def test_decode_composition_matches_decode_reference(self):
+        """A pure decode batch through the ragged kernel is the
+        single-query XLA reference `paged_attention_ref` to float
+        rounding, and `paged_attention` is that composition."""
         rng = np.random.default_rng(4)
         kc, vc = _pool(rng)
         tables = jnp.asarray([[1, 2, 3], [4, 5, 6]], jnp.int32)
@@ -114,14 +114,18 @@ class TestRaggedKernelParity:
         lane, pos = pa.ragged_metadata(jnp.asarray([1, 1]), kv_lens, 2)
         out = pa.paged_attention_ragged(q, kc, vc, tables, kv_lens,
                                         lane, pos)
-        legacy = pa.paged_attention(q, kc, vc, tables, kv_lens)
-        np.testing.assert_allclose(np.asarray(out), np.asarray(legacy),
+        ref = pa.paged_attention_ref(q, kc, vc, tables, kv_lens)
+        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    atol=1e-6, rtol=1e-5)
+        np.testing.assert_array_equal(
+            np.asarray(pa.paged_attention(q, kc, vc, tables, kv_lens)),
+            np.asarray(out))
 
-    def test_verify_composition_matches_legacy_verify_kernel(self):
-        """A fixed q_len == S batch through the ragged kernel is the
-        legacy multi-query verify kernel to float rounding — verify_step
-        really is a special case of the one kernel."""
+    def test_verify_composition_matches_per_row_decode_reference(self):
+        """A fixed q_len == S batch through the ragged kernel is, row by
+        row, `paged_attention_ref` over the context truncated at that
+        row, to float rounding — verify_step really is a special case of
+        the one kernel."""
         rng = np.random.default_rng(5)
         kc, vc = _pool(rng)
         tables = jnp.asarray([[1, 2, 3], [4, 5, 6]], jnp.int32)
@@ -131,9 +135,12 @@ class TestRaggedKernelParity:
         lane, pos = pa.ragged_metadata(jnp.asarray([s, s]), kv_lens, 2 * s)
         out = pa.paged_attention_ragged(qb.reshape(2 * s, 4, 32), kc, vc,
                                         tables, kv_lens, lane, pos)
-        legacy = pa.paged_attention_verify(qb, kc, vc, tables, kv_lens)
-        np.testing.assert_allclose(np.asarray(out).reshape(2, s, 4, 32),
-                                   np.asarray(legacy), atol=1e-6, rtol=1e-5)
+        out = np.asarray(out).reshape(2, s, 4, 32)
+        for i in range(s):
+            row = pa.paged_attention_ref(qb[:, i], kc, vc, tables,
+                                         kv_lens - (s - 1 - i))
+            np.testing.assert_allclose(out[:, i], np.asarray(row),
+                                       atol=1e-6, rtol=1e-5)
 
     def test_chunk_at_block_boundaries(self):
         """q_len landing exactly on / one past a block boundary, and a
@@ -451,10 +458,10 @@ class TestRaggedWrite:
 
 class TestEngineRaggedStep:
     """Engine-level semantics shared by both EngineCore implementations:
-    chunked ragged prefill+decode == legacy prefill+decode, bitwise."""
+    a prompt fed in chunks == the prompt fed whole, bitwise."""
 
     @pytest.mark.parametrize("which", ["mlp", "llama"])
-    def test_chunked_ragged_equals_legacy_paths(self, which):
+    def test_chunked_ragged_equals_whole_prompt_step(self, which):
         import paddle_tpu as paddle
 
         if which == "mlp":
@@ -480,48 +487,34 @@ class TestEngineRaggedStep:
 
         rng = np.random.default_rng(9)
         prompt = rng.integers(1, 64, 9).astype(np.int32)
-
-        # legacy: monolithic prefill + one decode_step
-        eng = build()
-        eng.manager.allocate(-1, 1)            # guard block
-        guard = eng.manager.block_table_array([-1])[0, 0]
-        eng.manager.allocate(0, 9)
-        tb = eng.manager.block_table_array([0])
-        lg = np.asarray(eng.prefill(np.pad(prompt, (0, 3))[None], tb,
-                                    np.asarray([9], np.int32)))
-        tok = int(np.argmax(lg[0]))
-        eng.manager.append_tokens(0, 1)
-        tbl = np.vstack([eng.manager.block_table_array([0])[0],
-                         np.full(8, guard, np.int32)])
-        dl = np.asarray(eng.decode_step(
-            np.asarray([tok, 0], np.int32), np.asarray([10, 1], np.int32),
-            tbl))
-
-        # ragged: 4+5 chunked prefill + one q_len==1 round, same T
-        eng2 = build()
-        eng2.manager.allocate(-1, 1)
-        eng2.manager.allocate(0, 0)
         T, B = 10, 2
 
-        def step(toks, q, kv):
-            tokens = np.zeros(T, np.int32)
-            tokens[:len(toks)] = toks
-            tb2 = np.full((B, 8), guard, np.int32)
-            tb2[0] = eng2.manager.block_table_array([0])[0]
-            return np.asarray(eng2.ragged_step(
-                tokens, np.asarray(q, np.int32), np.asarray(kv, np.int32),
-                tb2))
+        def stepper(eng):
+            eng.manager.allocate(-1, 1)        # guard block
+            guard = eng.manager.block_table_array([-1])[0, 0]
+            eng.manager.allocate(0, 0)
 
-        eng2.manager.append_tokens(0, 4)
+            def step(toks, q, kv):
+                eng.manager.append_tokens(0, len(toks))
+                tokens = np.zeros(T, np.int32)
+                tokens[:len(toks)] = toks
+                tb = np.full((B, 8), guard, np.int32)
+                tb[0] = eng.manager.block_table_array([0])[0]
+                return np.asarray(eng.ragged_step(
+                    tokens, np.asarray(q, np.int32),
+                    np.asarray(kv, np.int32), tb))
+            return step
+
+        # whole: the 9-token prompt as ONE step, then one q_len==1 round
+        whole = stepper(build())
+        lg = whole(prompt, [9, 0], [9, 0])
+        tok = int(np.argmax(lg[8]))
+        dl = whole([tok], [1, 0], [10, 0])
+
+        # chunked: 4+5 tokens, then the same q_len==1 round, same T
+        step = stepper(build())
         step(prompt[:4], [4, 0], [4, 0])
-        eng2.manager.append_tokens(0, 5)
         out = step(prompt[4:9], [5, 0], [9, 0])
-        # chunked-ragged == monolithic prefill up to attention-order
-        # float noise (the llama prefill path is dense SDPA; MLP is
-        # bitwise) — greedy picks must agree exactly
-        np.testing.assert_allclose(lg[0], out[4], atol=5e-6, rtol=1e-5)
-        assert int(np.argmax(out[4])) == tok
-        eng2.manager.append_tokens(0, 1)
+        np.testing.assert_array_equal(lg[8], out[4])
         out2 = step([tok], [1, 0], [10, 0])
-        np.testing.assert_allclose(dl[0], out2[0], atol=5e-6, rtol=1e-5)
-        assert int(np.argmax(out2[0])) == int(np.argmax(dl[0]))
+        np.testing.assert_array_equal(dl[0], out2[0])

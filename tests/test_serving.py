@@ -230,12 +230,10 @@ class TestScheduler:
         assert monitor.get("serving.decode_retraces") >= 1  # warmed up
 
         monitor.reset("serving.decode_retraces")
-        monitor.reset("serving.prefill_retraces")
         hs = [fe.submit(p, max_new_tokens=6) for p in prompts(8, rng)]
         fe.run_until_idle(max_steps=500)
         assert all(h.finished for h in hs)
         assert monitor.get("serving.decode_retraces") == 0
-        assert monitor.get("serving.prefill_retraces") == 0
 
     def test_eos_stops_early(self, engine):
         fe = ServingFrontend(engine)
@@ -660,6 +658,71 @@ def test_llama_serving_matches_generate(llama_model):
     assert [h.tokens for h in hs] == ref
 
 
+def _generate_engine(kind, llama_model):
+    """Identical weights on every call, a fresh pool and manager."""
+    from paddle_tpu.serving import shard_engine
+    from paddle_tpu.serving.lora import attach_adapters, random_adapter
+
+    def llama(**kw):
+        return LlamaInferenceEngine(llama_model, max_batch_size=4,
+                                    num_blocks=48, block_size=4,
+                                    max_blocks_per_seq=8, **kw)
+
+    if kind == "llama":
+        return llama()
+    if kind == "llama-int8-kv":
+        return llama(kv_bits=8)
+    if kind == "mlp":
+        return make_mlp_engine()
+    if kind == "lora":
+        eng = attach_adapters(make_mlp_engine(), pool_slots=2)
+        eng.adapter_pool.register("a", random_adapter(eng, rank=4, seed=1))
+        eng.use_adapter("a")
+        return eng
+    if kind == "tp2":
+        return shard_engine(llama(), tp=2)
+    from paddle_tpu.inference.deepseek_v3_runner import \
+        DeepseekV3InferenceEngine
+    from paddle_tpu.models import deepseek_v3 as dsv3
+
+    cfg = dsv3.DeepseekV3Config.from_hf(dict(
+        vocab_size=VOCAB, hidden_size=64, intermediate_size=128,
+        moe_intermediate_size=32, num_hidden_layers=2,
+        num_attention_heads=4, kv_lora_rank=32, q_lora_rank=None,
+        qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16,
+        n_routed_experts=8, n_shared_experts=2, num_experts_per_tok=2,
+        first_k_dense_replace=1, routed_scaling_factor=2.448,
+        norm_topk_prob=True, rms_norm_eps=1e-6, rope_theta=10000.0,
+        rope_interleave=True, rope_scaling=None,
+        max_position_embeddings=64, n_group=1, topk_group=1,
+        scoring_func="sigmoid"))
+    model = dsv3.DeepseekV3ForCausalLM(
+        cfg, weights=dsv3.init_params(cfg, 3, np.float32, 0.08))
+    return DeepseekV3InferenceEngine(model, max_batch_size=4, num_blocks=48,
+                                     block_size=4, max_blocks_per_seq=8)
+
+
+@pytest.mark.parametrize("kind", ["llama", "llama-int8-kv", "mlp", "lora",
+                                  "tp2", "kanana"])
+def test_generate_rides_the_ragged_step(kind, llama_model):
+    """`generate()` is one host loop over `ragged_step` for every engine:
+    its greedy tokens are the tokens the scheduler serves for the same
+    prompts (chunked prefill, continuous batching), and it gives every
+    block back."""
+    rng = np.random.default_rng(4)
+    ps = rng.integers(1, VOCAB, (3, 9)).astype(np.int32)
+    eng = _generate_engine(kind, llama_model)
+    free = eng.manager.free_blocks
+    out = eng.generate(ps, max_new_tokens=6)
+    assert out.shape == (3, 15) and (out[:, :9] == ps).all()
+    assert eng.manager.free_blocks == free and eng.manager.num_seqs == 0
+    fe = ServingFrontend(_generate_engine(kind, llama_model),
+                         prefill_chunk_tokens=5)
+    hs = [fe.submit(p.tolist(), max_new_tokens=6) for p in ps]
+    fe.run_until_idle(max_steps=400)
+    assert [h.tokens for h in hs] == out[:, 9:].tolist()
+
+
 # ---------------------------------------------------------------------------
 # Metrics / observability
 # ---------------------------------------------------------------------------
@@ -886,7 +949,7 @@ class TestSpeculative:
 
     def test_zero_retraces_in_steady_state(self, engine_factory):
         """Fixed-K fixed-shape verify + fused sampling: after a warmup
-        round, long speculative runs never retrace prefill/verify/sample."""
+        round, long speculative runs never retrace step/verify/sample."""
         fe = ServingFrontend(
             engine_factory(),
             spec=SpecDecodeConfig(NGramProposer(), num_draft_tokens=3))
@@ -894,14 +957,14 @@ class TestSpeculative:
         for n in (2, 5, 9, 14):   # cover the prefill buckets + spec shapes
             fe.submit(rng.integers(1, VOCAB, n).tolist(), max_new_tokens=3)
         fe.run_until_idle(max_steps=300)
-        for c in ("serving.prefill_retraces", "serving.verify_retraces",
-                  "serving.sample_retraces", "serving.decode_retraces"):
+        for c in ("serving.verify_retraces", "serving.sample_retraces",
+                  "serving.decode_retraces"):
             monitor.reset(c)
         hs = [fe.submit(p, max_new_tokens=6) for p in _rep_prompts(10)]
         fe.run_until_idle(max_steps=2000)
         assert all(h.status is RequestStatus.FINISHED for h in hs)
-        for c in ("serving.prefill_retraces", "serving.verify_retraces",
-                  "serving.sample_retraces", "serving.decode_retraces"):
+        for c in ("serving.verify_retraces", "serving.sample_retraces",
+                  "serving.decode_retraces"):
             assert monitor.get(c) == 0, f"{c} = {monitor.get(c)}"
 
     def test_acceptance_metrics_published(self):

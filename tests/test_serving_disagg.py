@@ -156,15 +156,13 @@ class TestHandoff:
             hs = [r.submit(p, max_new_tokens=5) for p in ps]
             r.run_until_idle()
             assert all(h.finished for h in hs)
-            pre = monitor.get("serving.prefill_retraces")
             dec = monitor.get("serving.decode_retraces")
-            # a second identical burst: every executable (prefill lane,
-            # decode lane, KV gather, KV scatter) is already compiled on
+            # a second identical burst: every executable (the ragged
+            # step, KV gather, KV scatter) is already compiled on
             # BOTH tiers — zero retraces anywhere
             hs = [r.submit(p, max_new_tokens=5) for p in ps]
             r.run_until_idle()
             assert all(h.finished for h in hs)
-            assert monitor.get("serving.prefill_retraces") == pre
             assert monitor.get("serving.decode_retraces") == dec
             assert monitor.get("fleet.handoffs") == 2 * len(ps)
         finally:

@@ -70,8 +70,7 @@ def drive(fe, warm_prompts, prompts, monitor):
     assert monitor.get("serving.ragged_retraces") >= 1 \
         or monitor.get("serving.verify_retraces") >= 1, "never compiled?"
 
-    for c in ("serving.decode_retraces", "serving.prefill_retraces",
-              "serving.ragged_retraces",
+    for c in ("serving.decode_retraces", "serving.ragged_retraces",
               "serving.verify_retraces", "serving.sample_retraces"):
         monitor.reset(c)
     fe.metrics.reset_window()   # warmup latencies are not the smoke's
