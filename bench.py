@@ -432,16 +432,17 @@ def serving_throughput_main():
     # tick once more, which must not look like a steady-state recompile)
     try:
         from paddle_tpu.observability import costs as _costs
+        from paddle_tpu.ops.sampling import step_args
 
         # the serving decode program is the ragged step: lower it at the
         # scheduler's packed shapes (T = lanes + chunk budget)
         fn, leading = engine.cost_card_args("decode")
         B = engine.max_batch_size
         T = fe.scheduler.ragged_tokens
-        card = _costs.card_from_lowered(
-            fn, *leading, np.zeros((T,), np.int32),
+        card = _costs.card_from_lowered(fn, *leading, *step_args(
+            np.zeros((T,), np.int32),
             np.ones((B,), np.int32), np.ones((B,), np.int32),
-            np.zeros((B, engine.manager.max_blocks_per_seq), np.int32))
+            np.zeros((B, engine.manager.max_blocks_per_seq), np.int32)))
         if card.flops:
             dsteps = max(extras["decode_steps"], 1)
             extras["decode_cost"] = {
@@ -1452,9 +1453,9 @@ def serving_fleet_main():
         def __getattr__(self, name):
             return getattr(self._inner, name)
 
-        def ragged_step(self, *args):
+        def sampled_step(self, *args):
             t0 = time.perf_counter()
-            out = self._inner.ragged_step(*args)
+            out = self._inner.sampled_step(*args)
             jax.block_until_ready(out)
             time.sleep(max(0.0, self._lat
                            - (time.perf_counter() - t0)))
@@ -1630,9 +1631,9 @@ def serving_tp_main():
         def __getattr__(self, name):
             return getattr(self._inner, name)
 
-        def ragged_step(self, *args):
+        def sampled_step(self, *args):
             t0 = time.perf_counter()
-            out = self._inner.ragged_step(*args)
+            out = self._inner.sampled_step(*args)
             jax.block_until_ready(out)
             time.sleep(max(0.0, self._lat - (time.perf_counter() - t0)))
             return out
@@ -1755,10 +1756,11 @@ def serving_tp_main():
     }
     try:
         from paddle_tpu.observability import costs as _costs
+        from paddle_tpu.ops.sampling import step_args
 
         eng = ab_engines["overlap"]
         fn, lead = eng.cost_card_args("ragged")
-        args = (*lead, *(np.asarray(a, np.int32) for a in ab_args(0)))
+        args = (*lead, *step_args(*ab_args(0)))
         extras["hlo_collectives"] = comms.hlo_comm_census(
             fn.lower(*args).compile().as_text())
         card = _costs.card_from_lowered(fn, *args)
@@ -1852,12 +1854,13 @@ def serving_disagg_main():
         def __getattr__(self, name):
             return getattr(self._inner, name)
 
-        def ragged_step(self, tokens, q_lens, kv_lens, tables):
+        def sampled_step(self, tokens, lanes, tables, temperature):
             t0 = time.perf_counter()
-            out = self._inner.ragged_step(tokens, q_lens, kv_lens, tables)
+            out = self._inner.sampled_step(tokens, lanes, tables,
+                                           temperature)
             jax.block_until_ready(out)
             compute = time.perf_counter() - t0
-            q = np.asarray(q_lens)
+            q = np.asarray(lanes)[:, 0]           # q_lens
             target = self._decode_s + self._tok_s * int(q[q > 1].sum())
             time.sleep(max(0.0, target - compute))
             # the DEVICE wall is the simulated profile (or the real

@@ -217,13 +217,14 @@ def lowered_custom_calls(engine, T):
     import numpy as np
 
     import bench
+    from paddle_tpu.ops.sampling import step_args
 
     B, W = engine.max_batch_size, engine.manager.max_blocks_per_seq
     fn, lead = engine.cost_card_args("ragged")
     i32 = np.int32
-    return bench._count_pallas_calls(
-        fn, *lead, np.zeros(T, i32), np.zeros(B, i32), np.zeros(B, i32),
-        np.zeros((B, W), i32))
+    return bench._count_pallas_calls(fn, *lead, *step_args(
+        np.zeros(T, i32), np.zeros(B, i32), np.zeros(B, i32),
+        np.zeros((B, W), i32)))
 
 
 def replay_logits(engine, seq, n_prompt, n_decode, T, chunk):

@@ -224,6 +224,7 @@ def _exe_ragged_decode():
     cost card lowers."""
     import numpy as np
 
+    from ..ops.sampling import step_args
     from ..serving.engine import MLPLMEngine
 
     eng = MLPLMEngine(vocab_size=64, hidden=16, max_batch_size=4,
@@ -233,8 +234,8 @@ def _exe_ragged_decode():
     q_lens = np.array([1, 1, 2, 0], np.int32)
     kv_lens = np.array([3, 1, 2, 0], np.int32)
     tables = np.zeros((B, 4), np.int32)
-    return eng._ragged, (eng.params, eng.pools, tokens, q_lens, kv_lens,
-                         tables)
+    return eng._ragged, (eng.params, eng.pools,
+                         *step_args(tokens, q_lens, kv_lens, tables))
 
 
 def _exe_verify():
@@ -278,6 +279,7 @@ def _exe_ragged_decode_quant():
     device-side by construction, and this entry keeps them that way."""
     import numpy as np
 
+    from ..ops.sampling import step_args
     from ..serving.engine import MLPLMEngine
     from ..serving.quant import quantize_engine
 
@@ -290,8 +292,8 @@ def _exe_ragged_decode_quant():
     q_lens = np.array([1, 1, 2, 0], np.int32)
     kv_lens = np.array([3, 1, 2, 0], np.int32)
     tables = np.zeros((B, 4), np.int32)
-    return eng._ragged, (eng.params, eng.pools, tokens, q_lens, kv_lens,
-                         tables)
+    return eng._ragged, (eng.params, eng.pools,
+                         *step_args(tokens, q_lens, kv_lens, tables))
 
 
 def _exe_ragged_decode_lora():
@@ -305,6 +307,7 @@ def _exe_ragged_decode_lora():
     the base decode program across ANY adapter mix."""
     import numpy as np
 
+    from ..ops.sampling import step_args
     from ..serving.engine import MLPLMEngine
     from ..serving.lora import attach_adapters, random_adapter
 
@@ -320,7 +323,7 @@ def _exe_ragged_decode_lora():
     kv_lens = np.array([3, 1, 2, 0], np.int32)
     tables = np.zeros((B, 4), np.int32)
     fn, lead = eng.cost_card_args("ragged")
-    return fn, (*lead, tokens, q_lens, kv_lens, tables)
+    return fn, (*lead, *step_args(tokens, q_lens, kv_lens, tables))
 
 
 def _exe_ragged_decode_tp():
@@ -335,6 +338,7 @@ def _exe_ragged_decode_tp():
     CPU topology before importing jax)."""
     import numpy as np
 
+    from ..ops.sampling import step_args
     from ..serving.engine import MLPLMEngine
     from ..serving.tp import shard_engine
 
@@ -348,7 +352,7 @@ def _exe_ragged_decode_tp():
     kv_lens = np.array([3, 1, 2, 0], np.int32)
     tables = np.zeros((B, 4), np.int32)
     fn, lead = eng.cost_card_args("ragged")
-    return fn, (*lead, tokens, q_lens, kv_lens, tables)
+    return fn, (*lead, *step_args(tokens, q_lens, kv_lens, tables))
 
 
 def _exe_verify_tp():

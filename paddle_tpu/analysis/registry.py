@@ -41,11 +41,15 @@ HOT_PATHS = {
     ("serving/scheduler.py", "Scheduler._decode_spec"),
     ("serving/scheduler.py", "Scheduler._commit_token"),
     ("serving/frontend.py", "ServingFrontend.step"),
-    ("serving/engine.py", "MLPLMEngine.ragged_step"),
+    ("serving/engine.py", "MLPLMEngine.sampled_step"),
     ("serving/engine.py", "MLPLMEngine.verify_step"),
-    ("inference/llama_runner.py", "LlamaInferenceEngine.ragged_step"),
+    ("inference/llama_runner.py", "LlamaInferenceEngine.sampled_step"),
     ("inference/llama_runner.py", "LlamaInferenceEngine.verify_step"),
     ("ops/sampling.py", "sample_tokens"),
+    ("ops/sampling.py", "ragged_step"),
+    ("ops/sampling.py", "pack_lanes"),
+    ("inference/deepseek_v3_runner.py",
+     "DeepseekV3InferenceEngine.sampled_step"),
     ("inference/cache.py", "BlockCacheManager.append_tokens"),
     # the COW block-copy hooks run mid-decode under prefix sharing, and
     # PR 14's quantized pools extend them to move int8 blocks + scale
@@ -56,14 +60,14 @@ HOT_PATHS = {
     # multichip serving run crosses these — the shard_map program is one
     # dispatch; stray host work here multiplies by tp chips' worth of
     # traffic
-    ("serving/tp.py", "ShardedEngine.ragged_step"),
+    ("serving/tp.py", "ShardedEngine.sampled_step"),
     ("serving/tp.py", "ShardedEngine.verify_step"),
     ("serving/tp.py", "ShardedEngine._dispatch"),
     # the multi-LoRA dispatch surfaces (ISSUE 18): every token of every
     # multi-adapter serving run crosses these; the per-lane adapter-slot
     # install runs before EVERY ragged/verify round — stray per-call
     # imports or host conversions here tax every tenant at once
-    ("serving/lora.py", "LoRAEngine.ragged_step"),
+    ("serving/lora.py", "LoRAEngine.sampled_step"),
     ("serving/lora.py", "LoRAEngine.verify_step"),
     ("serving/lora.py", "LoRAEngine.set_lane_adapters"),
     ("serving/scheduler.py", "Scheduler._install_lane_adapters"),

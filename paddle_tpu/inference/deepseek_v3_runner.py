@@ -11,8 +11,10 @@ an `EngineCore` whose paged pool holds ONE latent row a token a layer.
   pool became the scan's carry, PERF.md PR 28): here the layers are
   unrolled and each scatters into, and reads from, the one buffer. `row` is the latent width rounded up to whole 128-lane tiles
   (576 -> 640, zero columns; `ops/pallas/paged_attention_mla.py` says why).
-- `ragged_step` is the one compiled step, `verify_step` a case of it and
-  `generate` a host loop over it (`inference/generate.py`). Guard slots
+- `sampled_step` is the one compiled step, ending in the NaN screen and
+  the sampler (`ops/sampling.with_tail`); `ragged_step` is its logits,
+  `verify_step` a case of it and `generate` a host loop over it
+  (`inference/generate.py`). Guard slots
   (`q_len` 0) write nothing and reach no expert.
 - Expert load is counted inside the step, on the device, in donated
   counters: no host fetch a step. `expert_load()` reads them.
@@ -32,6 +34,7 @@ import numpy as np
 
 from ..framework import monitor
 from ..models import deepseek_v3 as dsv3
+from ..ops import sampling
 from ..ops.pallas import paged_attention_mla as pm
 from ..ops.pallas.paged_attention import ragged_metadata
 from . import kv_migrate
@@ -146,12 +149,14 @@ class DeepseekV3InferenceEngine:
         self._row_bytes = self.row_width * jnp.dtype(cdtype).itemsize
         self.manager.set_kv_geometry(L * block_size * self._row_bytes, 16)
 
-        def step(fn):
+        def step(fn, wrap=lambda f: f):
             bound = functools.partial(fn, cfg=cfg)
             bound.__name__ = fn.__name__           # the XLA module's name
-            return jax.jit(bound, donate_argnums=(1, 2))
+            return jax.jit(wrap(bound), donate_argnums=(1, 2))
 
-        self._ragged = step(_ragged_fn)
+        # the screen, the row gather and the sampler end the step's one
+        # program (`ops/sampling.with_tail`)
+        self._ragged = step(_ragged_fn, sampling.with_tail)
         self._verify = step(_verify_fn)
         # COW copy (prefix caching): one latent block, every layer, donated;
         # src/dst trace as scalars, so COWs never recompile
@@ -159,15 +164,17 @@ class DeepseekV3InferenceEngine:
             lambda p, s, d: p.at[:, d].set(p[:, s]), donate_argnums=(0,))
 
     # ---- the EngineCore dispatch surface ----
-    def ragged_step(self, tokens: np.ndarray, q_lens: np.ndarray,
-                    kv_lens: np.ndarray, block_tables: np.ndarray):
-        """ONE fixed-shape step over a packed ragged batch (see
-        `EngineCore.ragged_step`): logits `[T, V]` float32."""
-        logits, self.pool, self.counters = self._ragged(
+    def sampled_step(self, tokens: np.ndarray, lanes: np.ndarray,
+                     block_tables: np.ndarray, temperature: np.ndarray):
+        """ONE fixed-shape step over a packed ragged batch, sampled (see
+        `EngineCore.sampled_step`): `(sampled [2, B] int32, logits [T, V]
+        float32)`, both on the device."""
+        sampled, logits, self.pool, self.counters = self._ragged(
             self.params, self.pool, self.counters,
-            np.asarray(tokens, np.int32), np.asarray(q_lens, np.int32),
-            np.asarray(kv_lens, np.int32), np.asarray(block_tables, np.int32))
-        return logits
+            *sampling.call_arrays(tokens, lanes, block_tables, temperature))
+        return sampled, logits
+
+    ragged_step = sampling.ragged_step
 
     def verify_step(self, tokens: np.ndarray, context_lens: np.ndarray,
                     block_tables: np.ndarray):

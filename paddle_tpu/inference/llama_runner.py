@@ -21,9 +21,11 @@ TPU-first choices:
   of, stacked back into, reshaped or copied by the loop
   (tests/test_inference.py pins the compiled step's temporaries under one
   layer's pool).
-- ONE compiled step: `ragged_step` is the only way into the model
-  (`verify_step` is its `q_len == S` case, `generate` a host loop over
-  it). What a pool is made of is known where it is allocated and where
+- ONE compiled step: `sampled_step` is the only way into the model; its
+  program ends in the NaN screen, the gather of each lane's last row and
+  the sampler (`ops/sampling.with_tail`), so a serving round is one
+  program and one fetch. `ragged_step` is the same program's logits,
+  `verify_step` its `q_len == S` case, `generate` a host loop over it. What a pool is made of is known where it is allocated and where
   its migration header is written, nowhere else.
 - static shapes everywhere: batch and max_blocks fixed at engine build.
 """
@@ -34,6 +36,7 @@ import functools
 import numpy as np
 
 from ..models.llama import LlamaForCausalLM
+from ..ops import sampling
 from . import kv_migrate
 from .cache import BlockCacheManager
 from .generate import GenerationConfig, generate
@@ -146,9 +149,9 @@ def _rope_half(x, cos, sin):
 class LlamaInferenceEngine(kv_migrate.PagedPools):
     """Batch inference over LlamaForCausalLM with a paged KV cache.
 
-    `ragged_step` is the one jitted program (`verify_step` a case of it);
-    `generate` runs the host-side loop over it (sampling + block-table
-    bookkeeping, `inference/generate.py`).
+    `sampled_step` is the one jitted program (`ragged_step` its logits,
+    `verify_step` a case of it); `generate` runs the host-side loop over
+    it (sampling + block-table bookkeeping, `inference/generate.py`).
     """
 
     def __init__(self, model: LlamaForCausalLM, max_batch_size: int = 8,
@@ -208,15 +211,17 @@ class LlamaInferenceEngine(kv_migrate.PagedPools):
         self.manager.set_kv_geometry(
             kv_quant.kv_bytes_per_block(**self._kv_geom), self.kv_bits)
 
-        def step(fn):
+        def step(fn, wrap=lambda f: f):
             # a bare partial has no name and the XLA module would be
             # `jit__unknown`; with the function's it is `jit__ragged_fn`,
             # which is how a profile's "XLA Modules" line tells the steps
             bound = functools.partial(fn, cfg=_StaticCfg(cfg))
             bound.__name__ = fn.__name__
-            return jax.jit(bound, donate_argnums=(1,))
+            return jax.jit(wrap(bound), donate_argnums=(1,))
 
-        self._ragged = step(_ragged_fn)
+        # the serving step ends in the screen, the row gather and the
+        # sampler (`ops/sampling.with_tail`): one program a round
+        self._ragged = step(_ragged_fn, sampling.with_tail)
         self._verify = step(_verify_fn)
         # COW copy and KV migration over the block axis (axis 1, all
         # layers at once): `kv_migrate.PagedPools`
@@ -259,27 +264,33 @@ class LlamaInferenceEngine(kv_migrate.PagedPools):
                 "kv_bytes_per_token": self.kv_bytes_per_token()}
 
     # ---- public API (the serving EngineCore surface) ----
-    def ragged_step(self, tokens: np.ndarray, q_lens: np.ndarray,
-                    kv_lens: np.ndarray, block_tables: np.ndarray):
-        """ONE fixed-shape step over a packed ragged batch — the serving
-        scheduler's only decode-path program (chunked prefill + decode
-        lanes fused; see docs/SERVING.md "Ragged batching").
+    def sampled_step(self, tokens: np.ndarray, lanes: np.ndarray,
+                     block_tables: np.ndarray, temperature: np.ndarray):
+        """ONE fixed-shape step over a packed ragged batch, sampled — the
+        serving scheduler's only decode-path program (chunked prefill +
+        decode lanes fused; see docs/SERVING.md "Ragged batching").
 
         tokens [T] int32: packed lane-major query tokens; lane i owns
         slots [sum(q_lens[:i]), sum(q_lens[:i]) + q_lens[i]), its token j
         landing at position `kv_lens[i] - q_lens[i] + j` (kv_lens counts
         the cache INCLUDING this step's tokens; q_lens[i] == 0 marks an
-        empty lane). Returns logits [T, V]; rows at guard slots past
-        sum(q_lens) are meaningless and must be ignored (their KV writes
-        are dropped, their attention output is forced to zero).
-        Shape-stable in everything but T, which the scheduler fixes at
-        `max_batch_size + prefill_chunk_tokens` — one compiled
+        empty lane). `lanes` [B, 6] int32 carries q_lens, kv_lens and
+        each lane's last packed row, top_k, seed and draw index
+        (`ops/sampling.LANE_COLS`); `temperature` [B] float32. Returns
+        `(sampled, logits)`, both left on the device: `sampled` [2, B]
+        int32, each lane's token and whether its band's logits are all
+        finite (`ops/sampling.step_tail`); logits [T, V], whose rows at
+        guard slots past sum(q_lens) are meaningless and must be ignored
+        (their KV writes are dropped, their attention output is forced to
+        zero). Shape-stable in everything but T, which the scheduler
+        fixes at `max_batch_size + prefill_chunk_tokens` — one compiled
         executable regardless of batch composition or prompt length."""
-        logits, self.pools = self._ragged(
-            self.params, self.pools, np.asarray(tokens, np.int32),
-            np.asarray(q_lens, np.int32), np.asarray(kv_lens, np.int32),
-            np.asarray(block_tables, np.int32))
-        return logits
+        sampled, logits, self.pools = self._ragged(
+            self.params, self.pools,
+            *sampling.call_arrays(tokens, lanes, block_tables, temperature))
+        return sampled, logits
+
+    ragged_step = sampling.ragged_step
 
     def verify_step(self, tokens: np.ndarray, context_lens: np.ndarray,
                     block_tables: np.ndarray):

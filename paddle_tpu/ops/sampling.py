@@ -18,6 +18,13 @@ Determinism: lane b / slot s draws with key
 number of tokens the request has drawn so far — reproducible across runs,
 preemptions, and batch-slot churn (the lane index never enters the key).
 Greedy lanes (temperature <= 0) take a pure argmax and ignore the RNG.
+
+The plain serving round runs none of this as a program of its own: every
+engine compiles its ragged step through `with_tail`, so the NaN screen,
+the gather of each lane's last row and the sampler above are the END of
+the step's one program (`step_tail`), and what crosses to the host is one
+`[2, B]` int32 array. `sample_tokens` stays for the speculative verify
+round, whose `[B, S, V]` logits it samples whole.
 """
 from __future__ import annotations
 
@@ -25,7 +32,13 @@ import functools
 
 import numpy as np
 
-__all__ = ["sample_tokens"]
+__all__ = ["sample_tokens", "step_tail", "with_tail", "pack_lanes",
+           "call_arrays", "step_args", "ragged_step", "LANE_COLS"]
+
+# the per-lane int32 block of a sampled step, one column each: with
+# `tokens`, `tables` and `temperature` it is everything a round sends
+LANE_COLS = ("q_len", "kv_len", "row", "top_k", "seed", "draw_idx")
+_Q_LEN, _KV_LEN, _ROW, _TOP_K, _SEED, _DRAW = range(len(LANE_COLS))
 
 
 def _sample_fn(logits, temperature, top_k, seeds, draw_idx):
@@ -105,3 +118,84 @@ def sample_tokens(logits, temperature, top_k, seeds, draw_idx) -> np.ndarray:
         np.asarray(draw_idx, np.int32))
     out = np.asarray(out, np.int32)
     return out[:, 0] if squeeze else out
+
+
+def pack_lanes(q_lens, kv_lens, rows=None, top_k=0, seeds=0,
+               draw_idx=0) -> np.ndarray:
+    """The `[B, 6]` int32 lane block (`LANE_COLS`). `rows` is each lane's
+    LAST packed row, by default where `ragged_metadata` packs it."""
+    q_lens = np.asarray(q_lens, np.int32)
+    lanes = np.zeros((q_lens.shape[0], len(LANE_COLS)), np.int32)
+    lanes[:, _Q_LEN] = q_lens
+    lanes[:, _KV_LEN] = kv_lens
+    lanes[:, _ROW] = (np.maximum(np.cumsum(q_lens) - 1, 0) if rows is None
+                      else rows)
+    lanes[:, _TOP_K], lanes[:, _SEED], lanes[:, _DRAW] = top_k, seeds, draw_idx
+    return lanes
+
+
+def step_tail(logits, lanes, temperature):
+    """The end of every engine's ragged step, traced inside its one jit:
+    logits [T, V] float32, `lanes` [B, 6] int32 (`LANE_COLS`), temperature
+    [B] float32 -> `[2, B]` int32, row 0 the token sampled from each lane's
+    last packed row (`_sample_fn`, as `sample_tokens` runs it at S == 1),
+    row 1 whether every logit of the lane's WHOLE packed band (rows
+    `row - q_len + 1 .. row`) is finite: a NaN in an early row of a chunk
+    convicts that lane and no other; an empty lane reads finite."""
+    import jax
+    import jax.numpy as jnp
+
+    q_lens, rows = lanes[:, _Q_LEN], lanes[:, _ROW]
+    with jax.named_scope("llama.nan_screen"):
+        bad = jnp.cumsum(~jnp.isfinite(logits).all(axis=-1), dtype=jnp.int32)
+        bad = jnp.concatenate([jnp.zeros((1,), jnp.int32), bad])
+        finite = bad[rows + 1] == bad[rows + 1 - q_lens]
+    with jax.named_scope("sampler"):
+        picked = _sample_fn(logits[rows][:, None, :], temperature,
+                            lanes[:, _TOP_K], lanes[:, _SEED],
+                            lanes[:, _DRAW])[:, 0]
+    return jnp.stack([picked, finite.astype(jnp.int32)])
+
+
+def with_tail(logits_step):
+    """An engine's logits step `(*state, tokens, q_lens, kv_lens, tables)
+    -> (logits [T, V], *state)` as the sampled step `(*state, tokens,
+    lanes, tables, temperature) -> (sampled [2, B], logits, *state)`:
+    `step_tail` over the same logits, for `jax.jit` to compile as ONE
+    program. Whatever leads the arguments (params, pools, adapters,
+    counters) passes through, so donation indices stay the engine's."""
+    def _ragged_fn(*args):
+        *state, tokens, lanes, tables, temperature = args
+        logits, *state = logits_step(*state, tokens, lanes[:, _Q_LEN],
+                                     lanes[:, _KV_LEN], tables)
+        return (step_tail(logits, lanes, temperature), logits, *state)
+
+    # the function's name is the XLA module's: `jit__ragged_fn`, which is
+    # how a profile tells the serving step (docs/OBSERVABILITY.md)
+    return _ragged_fn
+
+
+def call_arrays(tokens, lanes, block_tables, temperature):
+    """A sampled step's four call arrays as exact-dtype numpy: they go to
+    the jit raw, the C++ dispatch path transfers them far cheaper than
+    per-argument host-side `device_put` calls (this is the decode loop)."""
+    return (np.asarray(tokens, np.int32), np.asarray(lanes, np.int32),
+            np.asarray(block_tables, np.int32),
+            np.asarray(temperature, np.float32))
+
+
+def step_args(tokens, q_lens, kv_lens, block_tables):
+    """`call_arrays` for greedy lanes sampling their last packed rows:
+    what `ragged_step` sends, and what lowers the step at those shapes."""
+    q_lens = np.asarray(q_lens, np.int32)
+    return call_arrays(tokens, pack_lanes(q_lens, kv_lens), block_tables,
+                       np.zeros(q_lens.shape, np.float32))
+
+
+def ragged_step(engine, tokens, q_lens, kv_lens, block_tables):
+    """`EngineCore.ragged_step`, bound by every engine class: the logits
+    `[T, V]` of one step, for `generate`, proposers and checks that sample
+    on the host. The SAME compiled program as the scheduler's round
+    (`sampled_step`), its lanes greedy and its tokens left on the device."""
+    return engine.sampled_step(
+        *step_args(tokens, q_lens, kv_lens, block_tables))[1]

@@ -5,10 +5,15 @@ the continuous-batching scheduler programs against. An engine owns stacked
 model params and `self.pools`, one tuple of paged KV(-like) arrays, and
 exposes ONE compiled way into its model:
 
-- `ragged_step(tokens [T], q_lens [B], kv_lens [B], block_tables [B, MAXB])`
-  — one fixed-shape step over a packed ragged batch (prefill chunks and
-  decode lanes alike; the scheduler pads empty lanes with `q_len` 0),
-  returning logits [T, V];
+- `sampled_step(tokens [T], lanes [B, 6], block_tables [B, MAXB],
+  temperature [B])` — one fixed-shape step over a packed ragged batch
+  (prefill chunks and decode lanes alike; the scheduler pads empty lanes
+  with `q_len` 0) that ENDS in the NaN screen, the gather of each lane's
+  last row and the sampler (`ops/sampling.with_tail`), returning
+  `(sampled [2, B], logits [T, V])` on the device: a scheduler round is
+  this one program and one fetch of `sampled`;
+- `ragged_step(tokens [T], q_lens [B], kv_lens [B], block_tables)` is the
+  same program's logits (`ops/sampling.ragged_step`);
 - `verify_step(tokens [B, S], context_lens [B], block_tables)` is its
   `q_len == S` case (speculative decoding), and `generate(input_ids)` a
   host loop over it (`inference/generate.py`).
@@ -43,7 +48,6 @@ smoke engine.
 from __future__ import annotations
 
 import functools
-import functools
 from typing import Protocol, runtime_checkable
 
 import numpy as np
@@ -51,6 +55,7 @@ import numpy as np
 from ..inference import kv_migrate
 from ..inference.cache import BlockCacheManager
 from ..inference.generate import generate
+from ..ops import sampling
 
 __all__ = ["EngineCore", "MLPLMEngine"]
 
@@ -73,16 +78,28 @@ class EngineCore(Protocol):
         of it by both in-tree engines."""
         ...
 
+    def sampled_step(self, tokens: np.ndarray, lanes: np.ndarray,
+                     block_tables: np.ndarray, temperature: np.ndarray):
+        """ONE fixed-shape step over a packed ragged batch: tokens [T]
+        lane-major (lane i owns q_lens[i] consecutive slots, token j at
+        position kv_lens[i] - q_lens[i] + j; q_len 0 = empty lane);
+        `lanes` [B, 6] int32 holds q_lens, kv_lens, each lane's last
+        packed row, top_k, seed and draw index
+        (`ops/sampling.LANE_COLS`), `temperature` [B] float32. Returns
+        `(sampled [2, B] int32, logits [T, V])`, both on the device:
+        each lane's sampled token and whether its whole band's logits
+        are finite (`ops/sampling.step_tail`). The serving scheduler's
+        only decode-path dispatch — decode lanes and chunked-prefill
+        tokens share it, so the steady state holds ONE executable with
+        no prompt-length or bucket shape family."""
+        ...
+
     def ragged_step(self, tokens: np.ndarray, q_lens: np.ndarray,
                     kv_lens: np.ndarray,
                     block_tables: np.ndarray) -> np.ndarray:
-        """ONE fixed-shape step over a packed ragged batch: tokens [T]
-        lane-major (lane i owns q_lens[i] consecutive slots, token j at
-        position kv_lens[i] - q_lens[i] + j; q_len 0 = empty lane),
-        returns logits [T, V]. The serving scheduler's only decode-path
-        dispatch — decode lanes and chunked-prefill tokens share it, so
-        the steady state holds ONE executable with no prompt-length or
-        bucket shape family."""
+        """`sampled_step`'s logits [T, V], for callers that sample on
+        the host (`generate`, proposers, checks): the same compiled
+        program with greedy lanes (`ops/sampling.ragged_step`)."""
         ...
 
 
@@ -286,8 +303,11 @@ class MLPLMEngine(kv_migrate.PagedPools):
         self._slab_names = ("cache", "scale")[:len(self.pools)]
         self._kv_bytes_per_token = bpb / block_size
         self.manager.set_kv_geometry(bpb, self.kv_bits)
+        # the step ends in the screen, the row gather and the sampler
+        # (`ops/sampling.with_tail`): one program a round
         self._ragged = jax.jit(
-            functools.partial(_mlp_ragged, block_size=block_size),
+            sampling.with_tail(
+                functools.partial(_mlp_ragged, block_size=block_size)),
             donate_argnums=(1,))
         self._verify = jax.jit(
             functools.partial(_mlp_verify, block_size=block_size),
@@ -355,14 +375,14 @@ class MLPLMEngine(kv_migrate.PagedPools):
             np.asarray(block_tables, np.int32))
         return logits
 
-    def ragged_step(self, tokens: np.ndarray, q_lens: np.ndarray,
-                    kv_lens: np.ndarray,
-                    block_tables: np.ndarray) -> np.ndarray:
-        """Packed ragged step; see `EngineCore.ragged_step`."""
-        logits, self.pools = self._ragged(
-            self.params, self.pools, np.asarray(tokens, np.int32),
-            np.asarray(q_lens, np.int32), np.asarray(kv_lens, np.int32),
-            np.asarray(block_tables, np.int32))
-        return logits
+    def sampled_step(self, tokens: np.ndarray, lanes: np.ndarray,
+                     block_tables: np.ndarray, temperature: np.ndarray):
+        """Packed ragged step, sampled; see `EngineCore.sampled_step`."""
+        sampled, logits, self.pools = self._ragged(
+            self.params, self.pools,
+            *sampling.call_arrays(tokens, lanes, block_tables, temperature))
+        return sampled, logits
+
+    ragged_step = sampling.ragged_step
 
     generate = generate

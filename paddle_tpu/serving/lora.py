@@ -61,6 +61,7 @@ from ..framework import monitor
 from ..inference import kv_migrate
 from ..inference.cache import BlockCacheManager
 from ..inference.generate import generate
+from ..ops import sampling
 
 __all__ = [
     "attach_adapters", "LoRAEngine", "AdapterPool", "lora_mm",
@@ -551,11 +552,15 @@ class LoRAEngine(kv_migrate.PagedPools):
             bases, static = (_mlp_ragged, _mlp_verify), {
                 "block_size": base.block_size}
         self.pools = jax.tree.map(jnp.zeros_like, base.pools)
-        self._ragged, self._verify = (
-            jax.jit(functools.partial(
-                fn, base=functools.partial(b, **static),
-                nlayers=self._nlayers), donate_argnums=(2,))
+        ragged, verify = (
+            functools.partial(fn, base=functools.partial(b, **static),
+                              nlayers=self._nlayers)
             for fn, b in zip((_lora_ragged, _lora_verify), bases))
+        # the ragged step ends in the base engines' tail: one program a
+        # round here too (`ops/sampling.with_tail`)
+        self._ragged = jax.jit(sampling.with_tail(ragged),
+                               donate_argnums=(2,))
+        self._verify = jax.jit(verify, donate_argnums=(2,))
         # the base's block executables are pure: over THIS engine's
         # pools they cost no extra trace
         self._copy_block, self._kv_gather, self._kv_scatter = (
@@ -607,13 +612,13 @@ class LoRAEngine(kv_migrate.PagedPools):
         return self.adapter_pool.stats()
 
     # -- EngineCore dispatch surfaces --
-    def ragged_step(self, tokens, q_lens, kv_lens, block_tables):
-        logits, self.pools = self._ragged(
+    def sampled_step(self, tokens, lanes, block_tables, temperature):
+        sampled, logits, self.pools = self._ragged(
             self.params, self._adapters, self.pools, self._lane_slots,
-            np.asarray(tokens, np.int32), np.asarray(q_lens, np.int32),
-            np.asarray(kv_lens, np.int32),
-            np.asarray(block_tables, np.int32))
-        return logits
+            *sampling.call_arrays(tokens, lanes, block_tables, temperature))
+        return sampled, logits
+
+    ragged_step = sampling.ragged_step
 
     def verify_step(self, tokens, context_lens, block_tables):
         logits, self.pools = self._verify(

@@ -126,6 +126,16 @@ class ServingMetrics:
         # leading class so the counter space stays bounded
         monitor.inc(f"serving.engine_restarts.{reason.split(':', 1)[0]}")
 
+    def on_step_program(self):
+        """The scheduler launched one device program (an engine step; on
+        the speculative round also its NaN screen and its sampler). With
+        `on_step_fetch`: a plain round costs exactly one of each."""
+        monitor.inc("serving.step.programs")
+
+    def on_step_fetch(self):
+        """The scheduler blocked on one device-to-host fetch."""
+        monitor.inc("serving.step.fetches")
+
     def on_prefill_chunk(self, num_tokens: int):
         """`num_tokens` of pending-prompt context entered the cache via
         one ragged-step chunk (chunked prefill)."""

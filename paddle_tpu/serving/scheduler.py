@@ -28,7 +28,7 @@ Policy (documented in docs/SERVING.md):
   fast SHED responses instead of collapsing TTFT for everyone.
 - chunked prefill: every step packs the decode lanes (one token each)
   plus at most `prefill_chunk_tokens` of pending-prompt tokens into ONE
-  fixed-shape `engine.ragged_step` dispatch over a packed token buffer
+  fixed-shape `engine.sampled_step` dispatch over a packed token buffer
   of `max_batch_size + prefill_chunk_tokens` slots. A 32k-token prompt
   advances chunk-by-chunk while decode lanes keep emitting a token
   every step — prefill can no longer stall decode TPOT, and the steady
@@ -65,8 +65,12 @@ Policy (documented in docs/SERVING.md):
   output is token-for-token identical to plain decode.
 
 Sampling (both paths) is the device-side fused batched sampler
-(`ops/sampling.py`): temperature/top-k/Gumbel-max under one jit with a
-per-request counter-based RNG — no per-lane host numpy in the loop.
+(`ops/sampling.py`): temperature/top-k/Gumbel-max with a per-request
+counter-based RNG — no per-lane host numpy in the loop. On the plain
+round it is the tail of the engine step's own program, with the NaN
+screen and the gather of each lane's last row: one program and one host
+fetch a round (docs/SERVING.md "One program, one fetch"); the
+speculative round runs screen and sampler as programs of their own.
 """
 from __future__ import annotations
 
@@ -85,7 +89,7 @@ from ..framework.retry import Budget, retry_call
 from ..inference.cache import KVCacheExhausted, SequenceTooLong
 from ..inference.kv_migrate import KVMigrationError
 from ..inference.prefix_cache import RadixPrefixCache
-from ..ops.sampling import sample_tokens
+from ..ops.sampling import pack_lanes, ragged_step, sample_tokens
 from ..resilience import faults as _faults
 from .engine import EngineCore
 from .lora import AdapterPoolExhausted
@@ -310,7 +314,6 @@ class Scheduler:
         self._pending_stall: Optional[str] = None
         self._broken: Optional[str] = None   # rebind failed mid-restart
         self._finite_fn = None               # jitted NaN screen, lazy
-        self._gather_fn = None               # jitted last-row gather, lazy
         self._last_decode_dt: Optional[float] = None
         self._chunk_progress = 0             # prefill tokens last round
         self._prefix_enabled = bool(prefix_cache)
@@ -858,6 +861,7 @@ class Scheduler:
         t0 = self._clock()
         try:
             out = fn(*args)
+            self.metrics.on_step_program()
         finally:
             dt = self._clock() - t0
             if self._wd is not None and dt > self._wd.stall_timeout_s:
@@ -938,11 +942,11 @@ class Scheduler:
 
     def _finite_rows(self, logits) -> np.ndarray:
         """Row-finiteness mask reduced ON DEVICE (`[..., V] -> [...]`
-        bool): the per-step NaN screen must not materialize the full
-        logits on host — at a realistic vocab that is a multi-MB D2H
-        copy per decode step, taxing exactly the hot path the fused
-        sampler keeps device-resident. One trace per logits rank, cached
-        for the scheduler's lifetime."""
+        bool), for the speculative round (the plain round's screen is
+        the tail of its step's program): the NaN screen must not
+        materialize the full logits on host — at a realistic vocab that
+        is a multi-MB D2H copy per step. One trace per logits rank,
+        cached for the scheduler's lifetime."""
         import jax
 
         if self._finite_fn is None:
@@ -954,7 +958,10 @@ class Scheduler:
 
             self._finite_fn = jax.jit(nan_screen)
         with RecordEvent("sched.screen"):
-            return np.asarray(self._finite_fn(logits))
+            finite = np.asarray(self._finite_fn(logits))
+        self.metrics.on_step_program()
+        self.metrics.on_step_fetch()
+        return finite
 
     def _isolated(self, req: Request, reason: str, phase: str,
                   slot: Optional[int] = None, in_slot: bool = True):
@@ -1431,21 +1438,13 @@ class Scheduler:
                               tokens_kept=len(req.generated))
         return True
 
-    def _gather_rows(self, logits, rows: np.ndarray):
-        """Device-side gather of each lane's last-token row: [T, V] ->
-        [B, V] without materializing the packed logits on host (same
-        rationale as `_finite_rows`). One trace, cached."""
-        import jax
-
-        if self._gather_fn is None:
-            self._gather_fn = jax.jit(lambda x, idx: x[idx])
-        return self._gather_fn(logits, rows)
-
     def _decode(self, now: float) -> int:
         """One ragged round: decode lanes (one token each) plus up to
         `prefill_chunk_tokens` pending-prompt tokens, packed into ONE
-        fixed-shape `engine.ragged_step` dispatch. Returns decode tokens
-        committed (prefill progress is tracked separately)."""
+        fixed-shape `engine.sampled_step` dispatch, whose program also
+        screens and samples: the round blocks once, on `[2, B]` tokens
+        and finiteness flags. Returns decode tokens committed (prefill
+        progress is tracked separately)."""
         self._chunk_progress = 0
         if self.spec is not None:
             return self._decode_spec(now)
@@ -1528,6 +1527,21 @@ class Scheduler:
                     continue
                 tables[i] = mgr.block_table_array([req.seq_id])[0]
             all_lanes = decode_lanes + [(i, r) for i, r, _n, _p in chunks]
+            # the step samples every lane's LAST packed row itself (fixed
+            # [B] shape): decode lanes commit their token; a prefill lane
+            # samples only on the round its final chunk completes (counter
+            # draw_idx 0 — exactly the draw sequential decode would make).
+            # Which lanes those are is known before the dispatch.
+            lane_sample: List[Optional[Request]] = [None] * B
+            for i, req in decode_lanes:
+                lane_sample[i] = req
+            for i, req, n, _p in chunks:
+                if req._prefill_pos + n >= len(req._prefill_ctx) \
+                        and req._last is None:
+                    lane_sample[i] = req
+            temps, topks, seeds, draws = self._sampling_arrays(lane_sample)
+            lanes = pack_lanes(q_lens, kv_lens, rows, topks, seeds, draws)
+
         def probe(i, req):
             """Replay ONE lane of the failed step (same fixed shapes, so
             no recompile; KV writes are position-indexed and idempotent
@@ -1546,7 +1560,7 @@ class Scheduler:
             # the lane's WHOLE packed band: a NaN confined to an earlier
             # chunk row must still convict this lane (the caller's
             # finiteness check reduces over everything returned)
-            return np.asarray(self.engine.ragged_step(t, q, kv, tb))[:n]
+            return np.asarray(ragged_step(self.engine, t, q, kv, tb))[:n]
 
         def rollback(survivors):
             # undo this round's growth so the next round replays cleanly
@@ -1559,27 +1573,33 @@ class Scheduler:
                              prefill_tokens=sum(n for _i, _r, n, _p
                                                 in chunks),
                              decode_lanes=len(decode_lanes)):
-                logits, flagged = self._dispatch(
-                    "decode", self.engine.ragged_step, tokens, q_lens,
-                    kv_lens, tables)
+                (sampled, _logits), flagged = self._dispatch(
+                    "decode", self.engine.sampled_step, tokens, lanes,
+                    tables, temps)
         except Exception as e:
             self._step_fault("decode", e, all_lanes, probe=probe,
                              rollback=rollback)
             return 0
+        try:
+            _faults.check("serve.sample")
+            with RecordEvent("sched.sample"):
+                # the round's ONE blocking fetch: every lane's token and
+                # its band's finiteness flag, screened and sampled by the
+                # step's own program; the logits stay on the device
+                picked, finite = np.asarray(sampled)
+                self.metrics.on_step_fetch()
+        except Exception as e:
+            self._step_fault("sample", e, all_lanes, rollback=rollback)
+            return 0
         if flagged or self.nan_checks:
+            finite = finite.astype(bool)
             if flagged:              # injection path: poison one lane
-                arr = np.array(logits)
-                arr[int(rows[all_lanes[0][0]])] = np.nan
-                logits = arr
-                finite = np.isfinite(arr).all(axis=-1)
-            else:                    # hot path: [T] bool fetch only
-                finite = self._finite_rows(logits)
+                finite[all_lanes[0][0]] = False
             for i, req in all_lanes:
-                n = int(q_lens[i])
-                start = int(rows[i]) - n + 1
-                if not bool(np.asarray(finite[start:start + n]).all()):
+                if not finite[i]:
                     # the garbage KV went into this lane's own blocks;
-                    # freeing the sequence discards it
+                    # freeing the sequence discards it (its token is
+                    # never read)
                     self._isolated(req, "nan_logits", "decode", slot=i)
             all_lanes = [(i, r) for i, r in all_lanes
                          if self.slots[i] is r]
@@ -1590,25 +1610,6 @@ class Scheduler:
             chunks = [(i, r, n, p) for i, r, n, p in chunks
                       if self.slots[i] is r]
         t_tok = self._clock()
-        # fused device sampling over every lane's LAST packed row (fixed
-        # [B, V] shape): decode lanes commit their token; a prefill lane
-        # samples only on the round its final chunk completes (counter
-        # draw_idx 0 — exactly the draw sequential decode would make)
-        lane_sample: List[Optional[Request]] = [None] * B
-        for i, req in decode_lanes:
-            lane_sample[i] = req
-        for i, req, n, _p in chunks:
-            if req._prefill_pos + n >= len(req._prefill_ctx) \
-                    and req._last is None:
-                lane_sample[i] = req
-        try:
-            _faults.check("serve.sample")
-            with RecordEvent("sched.sample"):
-                picked = sample_tokens(self._gather_rows(logits, rows),
-                                       *self._sampling_arrays(lane_sample))
-        except Exception as e:
-            self._step_fault("sample", e, all_lanes, rollback=rollback)
-            return 0
         self._step_faults = 0   # a full dispatch+sample round succeeded
         with RecordEvent("sched.commit"):
             produced = 0
@@ -1814,6 +1815,8 @@ class Scheduler:
             with RecordEvent("sched.sample"):
                 picked = sample_tokens(logits,
                                        *self._sampling_arrays(lane_reqs))
+            self.metrics.on_step_program()
+            self.metrics.on_step_fetch()
         except Exception as e:
             self._step_fault("sample", e,
                              [(i, r) for i, r, _t, _p, _f in lanes],

@@ -63,6 +63,7 @@ from ..inference import kv_migrate
 from ..inference.cache import BlockCacheManager
 from ..inference.generate import generate
 from ..observability import comms
+from ..ops import sampling
 
 __all__ = ["ShardingConfigError", "shard_engine", "ShardedEngine"]
 
@@ -388,11 +389,14 @@ class ShardedEngine(kv_migrate.PagedPools):
             header = {"engine": "mlp", "hidden": d}
 
         self.pools = tuple(put(p, s) for p, s in zip(base.pools, poolspec))
-        self._ragged = jax.jit(jax.shard_map(
+        # the tail (screen, row gather, sampler) follows the shard_map in
+        # the SAME jit, over whatever layout the logits leave it in: vocab
+        # shards in sequential mode, replicated rows under overlap
+        self._ragged = jax.jit(sampling.with_tail(jax.shard_map(
             ragged, mesh=jmesh,
             in_specs=(pspec, poolspec, R, R, R, R),
             out_specs=(lspec, poolspec),
-            check_vma=False), donate_argnums=(1,))
+            check_vma=False)), donate_argnums=(1,))
         self._verify = jax.jit(jax.shard_map(
             verify, mesh=jmesh,
             in_specs=(pspec, poolspec, R, R, R),
@@ -443,49 +447,51 @@ class ShardedEngine(kv_migrate.PagedPools):
         return fn, (self.params, self.pools)
 
     # ---- the EngineCore dispatch surface ----
-    def ragged_step(self, tokens: np.ndarray, q_lens: np.ndarray,
-                    kv_lens: np.ndarray,
-                    block_tables: np.ndarray) -> np.ndarray:
-        """Packed ragged step (see `EngineCore.ragged_step`), TP-sharded.
-        With observability on, the dispatch runs inside a
+    def sampled_step(self, tokens: np.ndarray, lanes: np.ndarray,
+                     block_tables: np.ndarray, temperature: np.ndarray):
+        """Packed ragged step, sampled (see `EngineCore.sampled_step`),
+        TP-sharded. With observability on, the dispatch runs inside a
         `comms.step_overlap` window — overlap mode exposes ~0 collective
         ms (everything is in-program), sequential mode's host logit
         assembly is recorded as an exposed all_gather."""
+        args = sampling.call_arrays(tokens, lanes, block_tables, temperature)
         if _obs.enabled():
             with comms.step_overlap(self._step_label):
-                return self._dispatch(self._ragged, True, tokens, q_lens,
-                                      kv_lens, block_tables)
-        return self._dispatch(self._ragged, False, tokens, q_lens,
-                              kv_lens, block_tables)
+                return self._dispatch(self._ragged, True, *args)
+        return self._dispatch(self._ragged, False, *args)
+
+    ragged_step = sampling.ragged_step
 
     def verify_step(self, tokens: np.ndarray, context_lens: np.ndarray,
                     block_tables: np.ndarray) -> np.ndarray:
         """Speculative verify (see `EngineCore.verify_step`), TP-sharded
         — rides the same sharded ragged stack, so spec == plain under TP."""
+        args = [np.asarray(a, np.int32)
+                for a in (tokens, context_lens, block_tables)]
         if _obs.enabled():
             with comms.step_overlap(self._step_label):
-                return self._dispatch(self._verify, True, tokens,
-                                      context_lens, block_tables)
-        return self._dispatch(self._verify, False, tokens, context_lens,
-                              block_tables)
+                return self._dispatch(self._verify, True, *args)
+        return self._dispatch(self._verify, False, *args)
 
     def _dispatch(self, fn, obs_on, *args):
-        logits, self.pools = fn(self.params, self.pools,
-                                *(np.asarray(a, np.int32) for a in args))
+        """Run one step executable over exact-dtype call arrays; returns
+        what it returns ahead of the pools: the verify step's logits, the
+        ragged step's `(sampled, logits)`."""
+        *out, self.pools = fn(self.params, self.pools, *args)
+        logits = out[-1]
         if self.overlap:
             if obs_on:
                 self._jax.block_until_ready(logits)
-            return logits
-        # sequential-collective baseline: the vocab shards cross to the
-        # host and reassemble here, fully exposed — the leg the tiled
-        # in-program psums + device all-gather delete
-        self._jax.block_until_ready(logits)
-        if _obs.enabled():
+        else:
+            # sequential-collective baseline: the vocab shards cross to
+            # the host and reassemble here, fully exposed — the leg the
+            # tiled in-program psums + device all-gather delete
+            self._jax.block_until_ready(logits)
             t0 = time.perf_counter()
-            assembled = np.asarray(logits)
-            comms.record("all_gather", self.tp, assembled.nbytes, t0,
-                         time.perf_counter() - t0)
-            return assembled
-        return np.asarray(logits)
+            out[-1] = np.asarray(logits)
+            if _obs.enabled():
+                comms.record("all_gather", self.tp, out[-1].nbytes, t0,
+                             time.perf_counter() - t0)
+        return out[0] if len(out) == 1 else tuple(out)
 
     generate = generate

@@ -66,17 +66,18 @@ class Recording(DeepseekV3InferenceEngine):
         super().__init__(*a, **k)
         self.rows, self.slots_of = [], None
 
-    def ragged_step(self, tokens, q_lens, kv_lens, tables):
-        logits = np.asarray(super().ragged_step(tokens, q_lens, kv_lens,
-                                                tables))
+    def sampled_step(self, tokens, lanes, tables, temperature):
+        sampled, logits = super().sampled_step(tokens, lanes, tables,
+                                               temperature)
+        logits = np.asarray(logits)
         cursor = 0
-        for lane, (n, kv) in enumerate(zip(q_lens, kv_lens)):
+        for lane, (n, kv) in enumerate(lanes[:, :2]):
             req = self.slots_of()[lane]
             for j in range(int(n)):
                 self.rows.append((req.req_id, int(kv) - int(n) + j,
                                   logits[cursor + j]))
             cursor += int(n)
-        return logits
+        return sampled, logits
 
 
 def serve(params, prompts, new_tokens, num_blocks=4 * 8 + 1, engine=Recording):

@@ -21,7 +21,8 @@ PKG = os.path.dirname(paddle_tpu.__file__)
 SHARED = {"llama.embed", "llama.layer", "llama.rms_norm", "llama.qkv",
           "llama.rope", "llama.attn", "llama.o_proj", "llama.mlp",
           "llama.head"}
-SERVING = SHARED | {"llama.kv_write"}
+# the serving step ends in its own NaN screen and sampler (`with_tail`)
+SERVING = SHARED | {"llama.kv_write", "llama.nan_screen"}
 TRAINING = SHARED | {"llama.loss"}
 
 
@@ -99,16 +100,17 @@ def ragged_text(tiny):
     """The tiny ragged serving step, lowered with the kernel in it (the
     Pallas interpreter stands in for Mosaic off the TPU)."""
     from paddle_tpu.inference import LlamaInferenceEngine
+    from paddle_tpu.ops.sampling import step_args
 
     tiny.eval()
     eng = LlamaInferenceEngine(tiny, max_batch_size=4, num_blocks=48,
                                block_size=4, max_blocks_per_seq=8)
     flags.set_flags({"FLAGS_pallas_interpret": True})
     try:
-        lowered = eng._ragged.lower(
-            eng.params, eng.pools, np.zeros((12,), np.int32),
+        lowered = eng._ragged.lower(eng.params, eng.pools, *step_args(
+            np.zeros((12,), np.int32),
             np.zeros((4,), np.int32), np.zeros((4,), np.int32),
-            np.zeros((4, 8), np.int32))
+            np.zeros((4, 8), np.int32)))
     finally:
         flags.set_flags({"FLAGS_pallas_interpret": False})
     return lowered.as_text(debug_info=True)
@@ -133,6 +135,9 @@ def test_serving_step_holds_its_regions(ragged_text):
                      ragged_text)
     assert re.search(r"llama\.layer/llama\.kv_write/kv_write_ragged",
                      ragged_text)
+    # the tail: the screen and the sampler are regions of THIS module
+    assert re.search(r'"jit\(_ragged_fn\)/llama\.nan_screen/', ragged_text)
+    assert re.search(r'"jit\(_ragged_fn\)/sampler/', ragged_text)
 
 
 def test_training_step_holds_its_regions(train_text):

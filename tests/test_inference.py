@@ -328,6 +328,7 @@ def test_llama_ragged_step_never_copies_the_pool(kv_bits, path):
     the xs/ys construction this replaced held eight layers' worth."""
     from paddle_tpu.inference import LlamaInferenceEngine
     from paddle_tpu.models.llama import llama_tiny
+    from paddle_tpu.ops.sampling import step_args
 
     flags.set_flags({"FLAGS_pallas_interpret": path == "kernel"})
     paddle.seed(1)
@@ -338,9 +339,9 @@ def test_llama_ragged_step_never_copies_the_pool(kv_bits, path):
     fn, lead = eng.cost_card_args("ragged")
     pools = lead[1]
     assert len(pools) == (4 if kv_bits == 8 else 2)
-    compiled = fn.lower(
-        *lead, np.zeros(6, np.int32), np.zeros(2, np.int32),
-        np.zeros(2, np.int32), np.zeros((2, 4), np.int32)).compile()
+    compiled = fn.lower(*lead, *step_args(
+        np.zeros(6, np.int32), np.zeros(2, np.int32),
+        np.zeros(2, np.int32), np.zeros((2, 4), np.int32))).compile()
     mem = compiled.memory_analysis()
     one_layer_k = pools[0][0].nbytes
     assert mem.temp_size_in_bytes < one_layer_k, (

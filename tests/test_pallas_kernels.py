@@ -667,6 +667,7 @@ def test_llama_ragged_step_compiles_for_v5e_without_a_pool_copy(
     import jax.numpy as jnp
 
     from paddle_tpu.inference import llama_runner as lr
+    from paddle_tpu.ops import sampling
     from paddle_tpu.ops.pallas import _support
 
     monkeypatch.setattr(_support, "backend", lambda: "tpu")
@@ -698,9 +699,13 @@ def test_llama_ragged_step_compiles_for_v5e_without_a_pool_copy(
         hidden_size, intermediate_size = hidden, inter
         rms_norm_eps, tie_word_embeddings = 1e-5, False
 
-    step = functools.partial(lr._ragged_fn, cfg=lr._StaticCfg(Cfg))
-    ints = [arr(shape, jnp.int32)
-            for shape in ((tokens,), (lanes,), (lanes,), (lanes, width))]
+    # the step as the engine jits it: the stack, then the screen, the row
+    # gather and the sampler (`ops/sampling.with_tail`)
+    step = sampling.with_tail(
+        functools.partial(lr._ragged_fn, cfg=lr._StaticCfg(Cfg)))
+    ints = [arr((tokens,), jnp.int32), arr((lanes, len(sampling.LANE_COLS)),
+                                           jnp.int32),
+            arr((lanes, width), jnp.int32), arr((lanes,), jnp.float32)]
     # a TPU executable cannot be read back from the persistent cache here
     cache_was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
@@ -719,6 +724,11 @@ def test_llama_ragged_step_compiles_for_v5e_without_a_pool_copy(
     text = compiled.as_text()
     assert text.count("paged_attention_ragged") and "tpu_custom_call" in text
     assert "while(" in text          # the program's size is O(1) in depth
+    # ONE program a round: tokens + flags, the logits and the pools come out
+    # of it, and nothing crosses to the host inside it
+    outs = jax.tree.leaves(compiled.out_info)
+    assert [tuple(o.shape) for o in outs[:2]] == [(2, lanes), (tokens, vocab)]
+    assert outs[0].dtype == jnp.int32 and len(outs) == 2 + len(pools)
 
 
 def test_gate_closes_for_gspmd_partitioned_operands():

@@ -18,11 +18,13 @@ from paddle_tpu.serving import (MLPLMEngine, NGramProposer, ServingFrontend,
 
 VOCAB = 64
 # every span of the serving path that an ordinary run produces
-# (`sched.preempt` needs KV pressure and has a case of its own)
+# (`sched.preempt` needs KV pressure and has a case of its own). The plain
+# round screens and samples inside the step's program and blocks once, in
+# `sched.sample`; `sched.screen` is the speculative round's first fetch.
 NAMES = {"frontend.submit", "sched.step", "sched.expire", "sched.admit",
          "sched.admit_one", "sched.grow", "sched.pack", "sched.dispatch",
-         "sched.screen", "sched.sample", "sched.commit", "sched.first_token",
-         "sched.finish"}
+         "sched.sample", "sched.commit", "sched.first_token", "sched.finish"}
+SPEC_ONLY = {"sched.screen"}
 
 
 class CountingMetrics(ServingMetrics):
@@ -86,12 +88,24 @@ def traced(request, tmp_path_factory):
         handles = drive(fe)
     finally:
         jax.profiler.stop_trace()
-    return {"spans": read_spans(trace_dir), "handles": handles, "hook": hook}
+    return {"spans": read_spans(trace_dir), "handles": handles, "hook": hook,
+            "path": request.param}
 
 
 def test_every_name_of_the_contract_is_there(traced):
-    assert NAMES <= {s[0] for s in traced["spans"]}
-    assert not {s[0] for s in traced["spans"]} - NAMES - {"sched.preempt"}
+    want = NAMES | (SPEC_ONLY if traced["path"] == "spec" else set())
+    assert {s[0] for s in traced["spans"]} - {"sched.preempt"} == want
+
+
+def test_a_round_blocks_where_its_spans_say(traced):
+    """One `sched.dispatch` and one `sched.sample` a dispatched step. The
+    plain round blocks there once, for the step's tokens and flags; the
+    speculative round first in `sched.screen`, for its NaN screen."""
+    count = {n: sum(s[0] == n for s in traced["spans"])
+             for n in ("sched.dispatch", "sched.sample", "sched.screen")}
+    steps = traced["hook"].steps
+    assert count["sched.dispatch"] == count["sched.sample"] == steps
+    assert count["sched.screen"] == (steps if traced["path"] == "spec" else 0)
 
 
 def test_children_lie_inside_a_step(traced):

@@ -326,7 +326,7 @@ class TestFaultIsolation:
             def __getattr__(self, name):
                 return getattr(inner, name)
 
-            def ragged_step(self, tokens, q_lens, kv_lens, tables):
+            def sampled_step(self, tokens, lanes, tables, temperature):
                 if self.victim is not None:
                     try:
                         vrow = inner.manager.block_table_array(
@@ -337,7 +337,8 @@ class TestFaultIsolation:
                             int(r[0]) == int(vrow[0])
                             for r in np.asarray(tables)):
                         raise RuntimeError("victim lane poisons the step")
-                return inner.ragged_step(tokens, q_lens, kv_lens, tables)
+                return inner.sampled_step(tokens, lanes, tables,
+                                          temperature)
 
         eng = VictimEngine()
         fe = ServingFrontend(eng)

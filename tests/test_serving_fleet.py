@@ -239,7 +239,7 @@ class TestRelocation:
             def __getattr__(self, name):
                 return getattr(self._inner, name)
 
-            def ragged_step(self, *a):
+            def sampled_step(self, *a):
                 raise faults.InjectedIOError("poisoned engine")
 
         r = FleetRouter(

@@ -22,6 +22,7 @@ import numpy as np
 import pytest
 
 from paddle_tpu.framework import monitor
+from paddle_tpu.ops.sampling import step_args
 from paddle_tpu.serving import (MLPLMEngine, NGramProposer, RequestStatus,
                                 ServingFrontend, ServingMetrics,
                                 ShardedEngine, ShardingConfigError,
@@ -331,9 +332,9 @@ class TestShardedSurfaces:
         assert s["tp"] == 2 and s["overlap"] and s["tiles"] == 3
         assert s["mesh"]["dim_names"] == ["dp", "tp"]
         fn, lead = sh.cost_card_args("ragged")
-        out = fn(*lead, *(np.asarray(a, np.int32)
-                          for a in _ragged_batch(0)))
-        assert np.asarray(out[0]).shape[-1] == 64
+        sampled, logits, _pools = fn(*lead, *step_args(*_ragged_batch(0)))
+        assert np.asarray(sampled).shape == (2, 4)
+        assert np.asarray(logits).shape[-1] == 64
         with pytest.raises(KeyError):
             sh.cost_card_args("prefill")
 
