@@ -48,10 +48,14 @@ class Compared:
     def ok(self):
         return bool(self.rows) and all(r[3] for r in self.rows)
 
-    def print(self):
+    def print(self, file=None):
         for name, value, limit, ok in self.rows:
             print(f"compare {name}: {value:.6g} (limit {limit:.6g}) "
-                  f"{'ok' if ok else 'NOT OK'}", flush=True)
+                  f"{'ok' if ok else 'NOT OK'}", file=file, flush=True)
+
+    def as_dict(self):
+        return {name: {"value": value, "limit": limit}
+                for name, value, limit, _ in self.rows}
 
 
 def _leaves_f32(cfg, seed, dtype, prefix):

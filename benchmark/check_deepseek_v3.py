@@ -9,6 +9,13 @@ at a time, never from the program. It never holds logits for a whole
 sequence (10,000 positions x 128,256 columns are 5 GB): the head runs on the
 served positions only, a block of rows at a time, and only the three numbers
 a position needs are kept.
+
+It runs beside the built engine, in the 3 GiB that leaves, so what it holds
+on the device may not grow with what the window served: the sequences'
+hidden states wait on the host between layers, one sequence is on the device
+at a time, and the head's weights (0.49 GiB) are made only after the last
+layer's are gone. A width of the deployment's `context_tokens` fits
+(PERF.md section 2), and nothing longer can be served.
 """
 from __future__ import annotations
 
@@ -33,12 +40,18 @@ def _leaf(seed, dtype, name, shapes):
 
 def reference_hidden(cfg, seed, ids, dtype, quant=None):
     """ids [N, S] -> the last layer's output before the final norm, float32
-    [N, S, H]: one layer's weights at a time, one sequence at a time."""
+    [S, H] a sequence, ON THE HOST: one layer's weights at a time, and
+    beside them one sequence on the device at a time (in, then out); the
+    others wait in host memory. So what the device holds does not grow with
+    the number of sequences sampled, and with their width only by one
+    sequence and the step's own temporaries."""
     shapes = ref.param_shapes(cfg)
     cos, sin = ref.rope_tables(cfg, ids.shape[1])
     embed = _leaf(seed, dtype, "model.embed_tokens.weight", shapes)
-    xs = [jnp.take(embed, jnp.asarray(row), axis=0).astype(jnp.float32)
-          for row in ids]
+    # np.array, a copy: where host and device share memory (a CPU rehearsal)
+    # np.asarray is a view that keeps the device's array alive
+    xs = [np.array(jnp.take(embed, jnp.asarray(row), axis=0)
+                   .astype(jnp.float32)) for row in ids]
     del embed
     step = jax.jit(lambda x, p: ref.layer(x, p, cfg, cos, sin, quant,
                                           flips=True))
@@ -46,13 +59,18 @@ def reference_hidden(cfg, seed, ids, dtype, quant=None):
     for i in range(cfg["num_hidden_layers"]):
         p = {k: _leaf(seed, dtype, f"model.layers.{i}.{k}", shapes)
              for k in ref.layer_shapes(cfg, i)}
-        xs, share = zip(*(step(x, p) for x in xs))
+        share = []
+        for r, x in enumerate(xs):
+            y, s = step(x, p)
+            xs[r] = np.array(y)
+            share.append(float(s))
+            del y       # or it stays on the device beside the next sequence
         flips.append(float(np.mean(share)))
         del p
     print("    reference" + (f" ({quant})" if quant else "") + ": rows whose "
           "top-k set changes when the layer's input is rounded to bf16, by "
           "layer: " + ", ".join(f"{100 * f:.2f} %" for f in flips), flush=True)
-    return list(xs)
+    return xs
 
 
 def served_gap(cfg, seed, dtype, samples, pad_to, control=None):
@@ -67,6 +85,11 @@ def served_gap(cfg, seed, dtype, samples, pad_to, control=None):
     ids = np.zeros((len(samples), width), np.int32)
     for r, (p, t) in enumerate(samples):
         ids[r, :len(p) + len(t)] = list(p) + list(t)
+    # the layers first, with nothing of the head on the device: a width of
+    # `context_tokens` has to fit beside the engine (PERF.md section 2)
+    low = (reference_hidden(cfg, seed, ids, dtype, "int8")
+           if control == "ref-int8" else None)
+    xs = reference_hidden(cfg, seed, ids, dtype)
     shapes = ref.param_shapes(cfg)
     norm_w = _leaf(seed, dtype, "model.norm.weight", shapes)
     head_w = _leaf(seed, dtype, "lm_head.weight", shapes)
@@ -74,10 +97,12 @@ def served_gap(cfg, seed, dtype, samples, pad_to, control=None):
     chunk = ref._block(cfg["vocab_size"], VOCAB_CHUNK)
 
     @functools.partial(jax.jit, static_argnames=("quant",))
-    def stats(x, toks, quant=None):
+    def head_stats(x, toks, norm_w, head_w, quant=None):
         """x [rows, H], toks [rows] -> per row (best logit, the logit of
         `toks`, std over the vocabulary, the best token), the head taken
-        `chunk` columns at a time."""
+        `chunk` columns at a time. The head's weights are arguments: closed
+        over, they are compiled into the program as a constant of 0.5 GB,
+        which no compile cache keeps, so every run compiled it anew."""
         rows = x.shape[0]
 
         def columns(j, acc):
@@ -103,22 +128,22 @@ def served_gap(cfg, seed, dtype, samples, pad_to, control=None):
         mean = s1 / cfg["vocab_size"]
         return top, picked, jnp.sqrt(s2 / cfg["vocab_size"] - mean * mean), best
 
+    stats = functools.partial(head_stats, norm_w=norm_w, head_w=head_w)
+
     def served_rows(xs):
         for r, (p, t) in enumerate(samples):
             rows = xs[r][len(p) - 1:len(p) - 1 + len(t)]
             pad = (-len(t)) % HEAD_ROWS
-            rows = jnp.pad(rows, ((0, pad), (0, 0)))
+            rows = np.pad(rows, ((0, pad), (0, 0)))
             yield r, len(t), rows.reshape(-1, HEAD_ROWS, rows.shape[-1])
 
     toks = {r: np.asarray(t, np.int64) for r, (p, t) in enumerate(samples)}
-    if control == "ref-int8":
-        low = reference_hidden(cfg, seed, ids, dtype, "int8")
+    if low is not None:
         for r, n, blocks in served_rows(low):
             none = jnp.zeros((HEAD_ROWS,), jnp.int32)
             toks[r] = np.concatenate(
-                [np.asarray(stats(b, none, "int8")[3]) for b in blocks])[:n]
+                [np.asarray(stats(b, none, quant="int8")[3]) for b in blocks])[:n]
         del low
-    xs = reference_hidden(cfg, seed, ids, dtype)
     worst, total, count = 0.0, 0.0, 0
     for r, n, blocks in served_rows(xs):
         t = np.full((blocks.shape[0] * HEAD_ROWS,), -1, np.int64)
@@ -136,8 +161,9 @@ def served_gap(cfg, seed, dtype, samples, pad_to, control=None):
 
 def reference_logits(cfg, seed, ids, dtype, quant=None):
     """ids [N, S] -> float32 logits [N, S, V]; for small sizes (tests)."""
+    xs = reference_hidden(cfg, seed, ids, dtype, quant)
     shapes = ref.param_shapes(cfg)
     norm_w = _leaf(seed, dtype, "model.norm.weight", shapes)
     head_w = _leaf(seed, dtype, "lm_head.weight", shapes)
     return jnp.stack([ref.head_logits(x, norm_w, head_w, cfg, quant)
-                      for x in reference_hidden(cfg, seed, ids, dtype, quant)])
+                      for x in xs])

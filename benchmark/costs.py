@@ -53,3 +53,16 @@ def ragged_attention_bytes(cfg: dict, kv_lens, q_lens,
     live = [(k, q) for k, q in zip(kv_lens, q_lens) if q > 0]
     return float(sum(k for k, _ in live) * kv_row
                  + sum(q for _, q in live) * q_row)
+
+
+def serve_flops(cfg: dict, tokens: float, sampled: float, pairs: float) -> float:
+    """Model FLOPs a serving window needs for what it processed: `tokens`
+    rows (prompt or decoded) through every layer's projections, `sampled`
+    rows through the head (a prompt's other rows need no logits, whatever
+    the program computes for them), 2 FLOPs a multiplied parameter; and
+    attention's score and update, 4 x heads x head size a layer for each of
+    the `pairs` (query token, token of its own causal context)."""
+    h = cfg["hidden_size"]
+    head = cfg["vocab_size"] * h
+    return (2.0 * (tokens * (matmul_params(cfg) - head) + sampled * head)
+            + 4.0 * cfg["num_hidden_layers"] * h * pairs)

@@ -32,16 +32,21 @@ def ragged_attention_bytes(cfg: dict, kv_lens, q_lens,
                  + sum(q for _, q in live) * q_row)
 
 
+def pair_flops(cfg: dict) -> float:
+    """FLOPs of one (query token, context token) pair in one layer, absorbed
+    form: every head's score over the row's whole width and its update over
+    the value columns."""
+    return 2.0 * cfg["num_attention_heads"] * (
+        2 * cfg["kv_lora_rank"] + cfg["qk_rope_head_dim"])
+
+
 def ragged_attention_flops(cfg: dict, kv_lens, q_lens) -> float:
-    """FLOPs of the same call: per query token and context token, every
-    head's score over the row's whole width and its update over the value
-    columns. A chunk's later tokens see more context than its first; the
+    """FLOPs of the same call: `pair_flops` per query token and context
+    token. A chunk's later tokens see more context than its first; the
     count takes each query token's own causal context."""
-    nh, rank = cfg["num_attention_heads"], cfg["kv_lora_rank"]
-    per_pair = 2.0 * nh * (2 * rank + cfg["qk_rope_head_dim"])
     pairs = sum(q * k - q * (q - 1) / 2.0
                 for k, q in zip(kv_lens, q_lens) if q > 0)
-    return per_pair * pairs
+    return pair_flops(cfg) * pairs
 
 
 def expert_bytes(cfg: dict, experts_touched: float, rows: float,
@@ -52,3 +57,28 @@ def expert_bytes(cfg: dict, experts_touched: float, rows: float,
     h, im = cfg["hidden_size"], cfg["moe_intermediate_size"]
     return float(experts_touched * 3 * h * im * dtype_bytes
                  + rows * (2 * h + 2 * im) * dtype_bytes)
+
+
+def active_params(cfg: dict) -> int:
+    """Parameters one token is multiplied by in the layers: the attention
+    projections, and a dense SwiGLU or the router, the shared experts and
+    the `num_experts_per_tok` routed experts it is sent to."""
+    h, nh, rank = cfg["hidden_size"], cfg["num_attention_heads"], cfg["kv_lora_rank"]
+    rd, nope, vd = (cfg["qk_rope_head_dim"], cfg["qk_nope_head_dim"],
+                    cfg["v_head_dim"])
+    attn = (h * nh * (nope + rd) + h * (rank + rd) + rank * nh * (nope + vd)
+            + nh * vd * h)
+    dense = cfg["first_k_dense_replace"]
+    experts = cfg["num_experts_per_tok"] + cfg["n_shared_experts"]
+    moe = h * cfg["n_routed_experts"] + experts * 3 * h * cfg["moe_intermediate_size"]
+    return (cfg["num_hidden_layers"] * attn + dense * 3 * h * cfg["intermediate_size"]
+            + (cfg["num_hidden_layers"] - dense) * moe)
+
+
+def serve_flops(cfg: dict, tokens: float, sampled: float, pairs: float) -> float:
+    """`costs.serve_flops` for this architecture: a token meets only the
+    experts it is routed to, and a (query, context) pair costs what
+    `ragged_attention_flops` counts for it (the absorbed form)."""
+    return (2.0 * (tokens * active_params(cfg)
+                   + sampled * cfg["vocab_size"] * cfg["hidden_size"])
+            + cfg["num_hidden_layers"] * pair_flops(cfg) * pairs)

@@ -16,8 +16,8 @@ sample drawn from a fixed seed). The run's seed draws their ORDER (which
 prompt meets which answer length at which instant) and the token ids; no
 request stops early. So seeds differ in order, never in the amount of work.
 Order still matters to whoever waits: at 0.8 of the knee the tail of TTFT
-over a window's 82 requests runs from 2.3 s to 5.3 s between orders (PERF.md,
-PR 24), which is why no TTFT statistic is an end-to-end metric yet.
+over a window's requests swings with their order (PERF.md section 2), which
+is why no TTFT statistic is an end-to-end metric yet.
 """
 from __future__ import annotations
 

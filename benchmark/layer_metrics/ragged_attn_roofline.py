@@ -1,7 +1,9 @@
-"""Kernels. `attn_kernel_roofline`'s arithmetic (the bytes the algorithm
-needs in the traced steps at the published HBM rate; bytes-bound) over the
-device time of the kernel `paged_attention_ragged` told by its name, not by
-its being the step's only Pallas call."""
+"""Kernels. The least time the chip could take for the bytes the algorithm
+needs in the traced steps (K and V of the live contexts once a step and
+layer, q in and o out: `costs.ragged_attention_bytes`) at the published
+HBM rate, over the device time of the kernel `paged_attention_ragged`, told
+by its name. Bytes-bound: at one query row a lane the FLOPs are a hundredth
+of what the bytes allow."""
 import program_trace
 
 

@@ -1,6 +1,6 @@
 """Scheduler. Per traced `sched.step` span: its wall less its waiting spans
-(`sched.dispatch`, `sched.screen`, `sched.sample`, where the host waits for
-the device): the scheduler's own Python, in ms a step."""
+(`sched.dispatch`, the round's one launch, and `sched.sample`, its one fetch:
+where the host waits for the device): the scheduler's own Python, in ms a step."""
 import program_trace
 
 

@@ -36,8 +36,10 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 SPAN_PREFIXES = ("frontend.", "sched.")
 STEP = "sched.step"
 # the spans whose body is an engine call or a host fetch: there the host
-# waits for the device; the rest of `sched.step` is the scheduler's Python
-WAITING = ("sched.dispatch", "sched.screen", "sched.sample")
+# waits for the device; the rest of `sched.step` is the scheduler's Python.
+# (A round is one launch and one fetch since PR 30; `sched.screen` is a span
+# of the speculative round alone, which no cell runs.)
+WAITING = ("sched.dispatch", "sched.sample")
 # what counts as a region of the program in an operation's scope path
 REGION_PREFIX = "llama."
 REGIONS = ("sampler", "adamw")

@@ -182,7 +182,11 @@ def main(argv=None):
              "failed": out["failed"],
              "would_report": sorted(result["metrics"])}), flush=True)
         return result
+    # each number compared beside its limit: the result's last key, and the
+    # last lines of standard error (what the driver keeps of a run at fault)
+    result["compared"] = compared.as_dict()
     print(json.dumps(result), flush=True)
+    compared.print(sys.stderr)
     return result
 
 

@@ -86,8 +86,10 @@ def run(job):
 
 
 def warm_up(fe, dep):
-    """One request longer than a chunk compiles the step, the NaN screen,
-    the row gather and the sampler; nothing else is ever used."""
+    """One request longer than a chunk compiles the engine's one program,
+    `jit__ragged_fn` (the step with the NaN screen, the row gather and the
+    sampler as its tail), at the one shape every round has; nothing else is
+    ever used."""
     from paddle_tpu.serving import RequestStatus
 
     t = time.perf_counter()
@@ -131,11 +133,19 @@ def drive(job, fe, hook):
         return time.perf_counter() - paused[0]
 
     def stop_trace():
+        """The profiler's stop, and the first program after it (`settled`:
+        the step that followed a chat-open trace took 5.96 s at 25.6
+        requests/s, PR 34, and its backlog was the traced run's TTFT), both
+        outside the window's clock."""
         nonlocal tracing
         t = time.perf_counter()
         jax.profiler.stop_trace()
-        paused[0] += time.perf_counter() - t
+        settled(0).block_until_ready()
+        took = time.perf_counter() - t
+        paused[0] += took
         tracing = span.on = False
+        print(f"    trace stopped and written in {took:.1f} s, outside the "
+              f"window's clock", flush=True)
 
     def submit(r, now):
         with span("submit"):
@@ -185,8 +195,22 @@ def drive(job, fe, hook):
 
     tracing = False
     if job["trace"]:
+        settled = jax.jit(lambda x: x + 1)
+        settled(0).block_until_ready()         # compiled in set-up
         jax.profiler.start_trace(job["trace_dir"])
         tracing = span.on = True
+    # the interpreter's full garbage collections inside the window: each
+    # stops the host for as long as the process has objects to walk
+    gc_ms, gc_began = [], [0.0]
+
+    def on_gc(phase, info):
+        if info["generation"] == 2:
+            if phase == "start":
+                gc_began[0] = time.perf_counter()
+            else:
+                gc_ms.append((time.perf_counter() - gc_began[0]) * 1e3)
+
+    gc.callbacks.append(on_gc)
     hook.counting = True
     t0 = clk()
     job["window_started"](t0)
@@ -231,6 +255,7 @@ def drive(job, fe, hook):
                 [1] * len(running)) * cfg["num_hidden_layers"])
     hook.counting = False
     closed = clk() - t0
+    gc.callbacks.remove(on_gc)
     if tracing:
         stop_trace()
     stats = jax.devices()[0].memory_stats() or {}
@@ -275,9 +300,25 @@ def drive(job, fe, hook):
             late_ms=[(rel(r.t_submit) - r.due) * 1e3 for r in everyone],
             queue_wait_ms=[(rel(r.t_admit) - r.due) * 1e3
                            for r in everyone if r.t_admit is not None])
+    # what the window processed, for `step_mfu`: a decoded token attends
+    # its whole context, a prompt prefilled in the window every causal pair
+    # (a prompt that straddles an edge of the window is counted whole or
+    # not at all, by where its first token falls)
+    rows = sampled = pairs = 0
+    for r in done + live:
+        p = len(r.spec["prompt"])
+        for i, s in enumerate(r.stamps):
+            if rel(s) < 0:
+                continue
+            sampled += 1
+            if i:
+                rows, pairs = rows + 1, pairs + p + i
+            elif rel(r.t_submit) >= 0:
+                rows, pairs = rows + p, pairs + p * (p + 1) // 2
+    rec["served_flops"] = job["costs"].serve_flops(cfg, rows, sampled, pairs)
     moved = {c: (monitor.get(c) or 0) - before[c] for c in watched}
     rec.update(
-        step_ms=step_ms, window_s=closed, lanes=lanes,
+        step_ms=step_ms, window_s=closed, lanes=lanes, gc_ms=gc_ms,
         queue_depth=queue_depth,
         hook_steps=hook.steps, prefill_tokens=hook.prefill_tokens,
         decode_lanes=hook.decode_lanes,
@@ -288,8 +329,11 @@ def drive(job, fe, hook):
     finished = sum(r.handle.status is RequestStatus.FINISHED
                    for r in everyone)
     # a stalled step (the host's neighbours, the runtime) shows here first
-    print(f"    slowest steps, ms: "
-          f"{[round(x) for x in sorted(step_ms)[-5:]]}", flush=True)
+    slowest = sorted(zip(step_ms, (at for at, _ in queue_depth)))[-5:]
+    print("    slowest steps, ms (ending at s): " + ", ".join(
+        f"{ms:.0f} ({at:.1f})" for ms, at in slowest), flush=True)
+    print(f"    full garbage collections in the window: {len(gc_ms)}, ms: "
+          f"{[round(x) for x in gc_ms]}", flush=True)
     print(f"    window {closed:.2f} s: {len(everyone)} requests attempted, "
           f"{finished} finished, {failed} failed, {len(step_ms)} steps, "
           f"counters moved {moved}", flush=True)

@@ -1,19 +1,21 @@
 """`correct` has to come out false when the timed path is broken underneath.
 Each test breaks the program where it produces its result and drives the
 rest of a run (everything but the harness's look for a chip)."""
-import numpy as np
 
 
 def test_altered_token_is_refused(rehearse, monkeypatch):
-    """Serving: every sampled token moved by one where it is produced."""
-    from paddle_tpu.serving import scheduler
+    """Serving: every sampled token moved by one where it is produced, in
+    the tail of the engine's compiled step (`ops/sampling.step_tail`; the
+    scheduler samples nothing on the host since PR 30)."""
+    from paddle_tpu.ops import sampling
 
-    real = scheduler.sample_tokens
+    real = sampling.step_tail
 
-    def off_by_one(logits, *a, **k):
-        return (np.asarray(real(logits, *a, **k)) + 1) % logits.shape[-1]
+    def off_by_one(logits, lanes, temperature):
+        sampled = real(logits, lanes, temperature)
+        return sampled.at[0].set((sampled[0] + 1) % logits.shape[-1])
 
-    monkeypatch.setattr(scheduler, "sample_tokens", off_by_one)
+    monkeypatch.setattr(sampling, "step_tail", off_by_one)
     result, out = rehearse("mistral7b-chat-open")
     assert result["correct"] is False
     assert "served_token_widest_gap" in out and "NOT OK" in out

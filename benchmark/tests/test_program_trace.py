@@ -239,9 +239,12 @@ def test_no_program_span_no_value(name, monkeypatch):
 # (nine steps; ms on the profiler's clock, as printed to four decimals)
 STEP_WALLS = [5.4575, 4.1048, 4.4337, 4.2954, 4.0918, 3.9761, 4.0282,
               4.2494, 3.8034]
-# per step: sched.dispatch + sched.screen + sched.sample
-WAITING = [5.1172, 3.9502, 4.1339, 4.0378, 3.8266, 3.6869, 3.8430, 4.0343,
-           3.6175]
+# per step: sched.dispatch + sched.sample. The trace was recorded before
+# PR 30, when a round also held a `sched.screen` span (0.7613-1.1260 ms);
+# that name has left `program_trace.WAITING` (no cell's round emits it), so
+# in THIS trace the screen's wait reads as the scheduler's own time
+WAITING = [4.2075, 3.1863, 3.0079, 3.1373, 2.8876, 2.9048, 3.0154, 3.2567,
+           2.8561]
 US = 1e-6
 BUSY_S = 0.001599123
 # device time, us, summed by hand over `describe`'s operation lines
@@ -297,7 +300,7 @@ def test_walls_and_self_times(pt):
 
 
 def test_span_readers(rec):
-    # (sum of walls 38.4403 less sum of waiting 36.2474) / 9 steps
+    # (sum of walls 38.4403 less sum of waiting 28.4596) / 9 steps
     assert reader("sched_host_ms_per_step")(rec) == pytest.approx(
         (sum(STEP_WALLS) - sum(WAITING)) / 9, abs=1e-3)
     # steps 1, 6, 9 admit or finish: median 3.9761; the six others:
@@ -330,7 +333,7 @@ def test_idle_under_the_scheduler(pt, rec):
     assert got == pytest.approx(1e3 * want / 9, rel=1e-6)
     # nearly all of the scheduler's own time leaves the device idle at this
     # size (a step's device work is 0.1 ms), and never more than all of it
-    assert 0.8 * 0.2437 < got <= reader("sched_host_ms_per_step")(rec)
+    assert 0.8 * 1.1090 < got <= reader("sched_host_ms_per_step")(rec)
     assert pt.gaps_outside_spans() == []
     assert pt.idle_s(pt.busy[0][0], pt.busy[0][1]) == pytest.approx(0.0)
 
@@ -376,13 +379,10 @@ def test_share_readers(rec, name, micros, capsys):
 
 
 def test_roofline_by_name(rec):
-    """`attn_kernel_roofline`'s arithmetic over the kernel told by name:
-    1 MB needed at 819 GB/s is 1.221 us, over 420.2 us in the kernel. The
-    old reader, which takes every Pallas call of the trace for the ragged
-    kernel, reads lower here, where the train step's kernels are in the
-    same trace."""
+    """The roofline's arithmetic over the kernel told by name: 1 MB needed
+    at 819 GB/s is 1.221 us, over 420.2 us in the kernel (the train step's
+    Pallas kernels, which are in the same trace, are not counted)."""
     rec = dict(rec, attn_bytes_traced=1e6, peaks={"hbm_bytes_per_s": 8.19e11})
     got = reader("ragged_attn_roofline")(rec)
     assert got == pytest.approx(100.0 * (1e6 / 8.19e11) / (RAGGED_US * US),
                                 rel=1e-3)
-    assert reader("attn_kernel_roofline")(rec) < 0.7 * got

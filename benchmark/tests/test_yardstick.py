@@ -2,6 +2,7 @@
 order under every seed, the peaks table, FLOPs and bytes."""
 import json
 import os
+import sys
 
 import numpy as np
 import pytest
@@ -69,3 +70,34 @@ def test_unknown_device_is_an_error():
     assert costs.peaks("TPU v5 lite")["hbm_bytes_per_s"] == 819e9
     with pytest.raises(KeyError):
         costs.peaks("TPU v9")
+
+
+def test_serve_flops():
+    """By hand from the published widths. Mistral, depth 12: a layer
+    multiplies 2 x 4096^2 + 2 x 4096 x 1024 + 3 x 4096 x 14336 parameters
+    (+ two norms), the head 32768 x 4096; one decoded row over a context
+    of 100 tokens. Kanana, depth 7: attention 26,345,472 a layer, the dense
+    layer 3 x 2048 x 6144, an expert layer the router and 6 + 2 experts of
+    3 x 2048 x 768; a pair 2 x 32 heads x (2 x 512 + 64) a layer."""
+    cfg = _config("mistral-7b-v0.3-serve")
+    body = 12 * (33_554_432 + 8_388_608 + 176_160_768 + 8_192) + 4_096
+    assert costs.serve_flops(cfg, 1, 1, 100) == \
+        2 * (body + 134_217_728) + 4 * 12 * 4096 * 100
+    # a prompt of 64 rows prefilled: one sampled row, 64 x 65 / 2 pairs
+    assert costs.serve_flops(cfg, 64, 1, 2080) == \
+        2 * (64 * body + 134_217_728) + 4 * 12 * 4096 * 2080
+    sys.modules.setdefault("costs", costs)       # as run.py has imported it
+    mla = load("costs_deepseek_v3.py", "benchmark_costs_dsv3_t")
+    cfg = _config("kanana-2-30b-a3b-serve")
+    assert mla.active_params(cfg) == 7 * 26_345_472 + 37_748_736 + 6 * 38_010_880
+    assert mla.serve_flops(cfg, 1, 1, 8000) == \
+        2 * (450_232_320 + 262_668_288) + 7 * 69_632 * 8000
+
+
+def test_step_mfu_reads_flops_over_window_and_peak():
+    read = load("layer_metrics/step_mfu.py", "benchmark_step_mfu_t").read
+    rec = {"served_flops": 1.97e14, "window_s": 10.0,
+           "peaks": {"bf16_flops_per_s": 1.97e14}}
+    assert read(rec) == pytest.approx(10.0)
+    assert read(dict(rec, peaks=None)) is None       # a rehearsal: no device
+    assert read(dict(rec, served_flops=0.0)) is None
