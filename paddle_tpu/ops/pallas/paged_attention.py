@@ -13,6 +13,11 @@ ONE kernel body, `_ragged_kernel` (TPU-first, not a CUDA translation):
   live pages and live tokens, so its work follows them, never the table's
   width. Decode (`q_len` 1), prefill chunks and verify windows share it;
   `paged_attention(q [B, H, D], ...)` is its `q_len == 1` case.
+- the chunk of query tokens a lane is computed in follows the lane's own
+  `q_len`: `_RAGGED_Q_CHUNK` tokens, and ONE for a decode lane, which so
+  pays for one token's rows (its head group, padded to 8) and not for 64.
+  The body is instantiated at both sizes inside the one kernel; what picks
+  is the prefetched scalar `q_lens[b]`, nothing a caller sets.
 - GQA is native: a query-head group is a band of MXU rows against one kv
   head's page group, with no KV repetition in HBM.
 - online softmax (flash-style) accumulates across page groups in VMEM.
@@ -111,7 +116,17 @@ def _ragged_kernel(layer_ref, kv_lens_ref, q_lens_ref, q_starts_ref,
     - a lane's tokens go in tiles of `q_tile` (an empty lane issues none,
       guard slots past sum(q_lens) belong to no lane and cost nothing);
       a tile's q rows come in, and its output rows leave, in chunks of
-      `_RAGGED_Q_CHUNK` tokens, only the live ones;
+      `qc` tokens, only the live ones. `qc` is `_RAGGED_Q_CHUNK`, and 1
+      for a decode lane (`q_len == 1`): `lane(qc, ...)` below is the whole
+      body at one chunk size, instantiated at both, and a lane runs the
+      one its `q_len` names, on the first token's slice of the same
+      scratch. The arithmetic of a row does not depend on how many rows
+      share its tile, so a decode lane's output is bitwise what the
+      8-token chunk gave it, for `g_pad` MXU rows a kv head and page group
+      where that took 64; and its one chunk needs no loop, so a group's kv
+      heads run as straight-line code whose chains of matmul, softmax and
+      matmul interleave (inside a rolled loop each waits for the last:
+      that, not the rows, was a decode lane's cost; PERF.md, PR 37);
     - per tile a rolled loop walks the lane's live pages — up to the
       tile's last query position, never the table's width — `pages` at a
       time, double-buffered: one DMA brings a page for ALL kv heads (it
@@ -119,8 +134,9 @@ def _ragged_kernel(layer_ref, kv_lens_ref, q_lens_ref, q_starts_ref,
       The tail group's missing pages re-fetch the last live one and are
       masked, so no dead page is ever read;
     - per kv head the [rows, D] x [D, pages*BS] score tile and the
-      [rows, pages*BS] x [pages*BS, D] update run per live chunk with the
-      online softmax in f32 (m/l lane-replicated scratch). Scores take
+      [rows, pages*BS] x [pages*BS, D] update (rows = qc * g_pad) run per
+      live chunk with the online softmax in f32 (m/l lane-replicated
+      scratch). Scores take
       the MXU in `mxu_dtype`: the operands' own bf16 when q and the pool
       are bf16/int8 (products of bf16 values are exact in the f32
       accumulator), f32 otherwise; `sm_scale` is applied to the f32
@@ -129,7 +145,8 @@ def _ragged_kernel(layer_ref, kv_lens_ref, q_lens_ref, q_starts_ref,
     Output rows of a tile's last chunk past the lane's tokens are written
     as zeros; the next lane, processed after it, overwrites the ones it
     owns, and the output buffer starts zeroed (aliased input), so guard
-    rows come back exact zeros.
+    rows come back exact zeros. (A decode lane's chunk is its one token:
+    it writes no row but its own.)
 
     Quantized KV (`inference/kv_quant.py` layout): K/V arrive as int8 and
     `rest` leads with the lane's per-slot f32 scale rows in logical order
@@ -142,8 +159,6 @@ def _ragged_kernel(layer_ref, kv_lens_ref, q_lens_ref, q_starts_ref,
         rest = rest[2:]
     _, o_hbm, qbuf, kbuf, vbuf, acc_ref, m_ref, l_ref, sem = rest
     kv_h, d = kbuf.shape[2], kbuf.shape[4]
-    qc = _RAGGED_Q_CHUNK
-    rows = qc * g_pad                     # MXU rows of one compute chunk
     cols = pages * block_size             # kv positions of one page group
     i32 = jnp.int32
     b = pl.program_id(0)
@@ -152,128 +167,149 @@ def _ragged_kernel(layer_ref, kv_lens_ref, q_lens_ref, q_starts_ref,
     q_len = q_lens_ref[b]
     q_start = q_starts_ref[b]
 
-    def chunk_loop(n, body):
-        jax.lax.fori_loop(0, n, lambda c, _: body(c), None)
+    def lane(qc, n_tiles):
+        """`n_tiles` tiles of the lane at `qc` query tokens a compute
+        chunk."""
+        rows = qc * g_pad                 # MXU rows of one compute chunk
 
-    def toks(c):                          # chunk c's tokens of the tile
-        return pl.ds(c * i32(qc), qc)
+        def chunk_loop(n, body):
+            if qc == 1:
+                # a decode lane's one token is its one chunk: no loop, so
+                # a page group's kv heads are straight-line code whose
+                # matmul -> softmax -> matmul chains Mosaic interleaves
+                body(i32(0))
+            else:
+                jax.lax.fori_loop(0, n, lambda c, _: body(c), None)
 
-    def band(c):                          # ... and their MXU rows
-        return pl.ds(pl.multiple_of(c * i32(rows), rows), rows)
+        def toks(c):                      # chunk c's tokens of the tile
+            return pl.ds(c * i32(qc), qc)
 
-    def tile(i):
-        t0 = i * i32(q_tile)              # the tile's first token, in-lane
-        n_tok = jnp.minimum(q_len - t0, i32(q_tile))
-        n_chunks = pl.cdiv(n_tok, i32(qc))
-        pos0 = kv_len - q_len + t0        # its absolute position
-        n_pages = pl.cdiv(pos0 + n_tok, i32(block_size))
-        n_groups = pl.cdiv(n_pages, i32(pages))
+        def band(c):                      # ... and their MXU rows
+            return pl.ds(pl.multiple_of(c * i32(rows), rows), rows)
 
-        def q_copy(c):
-            return pltpu.make_async_copy(
-                q_hbm.at[pl.ds(q_start + t0 + c * i32(qc), qc)],
-                qbuf.at[toks(c)], sem.at[2, 0])
+        def tile(i):
+            t0 = i * i32(q_tile)          # the tile's first token, in-lane
+            n_tok = jnp.minimum(q_len - t0, i32(q_tile))
+            n_chunks = pl.cdiv(n_tok, i32(qc))
+            pos0 = kv_len - q_len + t0    # its absolute position
+            n_pages = pl.cdiv(pos0 + n_tok, i32(block_size))
+            n_groups = pl.cdiv(n_pages, i32(pages))
 
-        def o_copy(c):
-            return pltpu.make_async_copy(
-                acc_ref.at[toks(c)],
-                o_hbm.at[pl.ds(q_start + t0 + c * i32(qc), qc)],
-                sem.at[2, 1])
+            def q_copy(c):
+                return pltpu.make_async_copy(
+                    q_hbm.at[pl.ds(q_start + t0 + c * i32(qc), qc)],
+                    qbuf.at[toks(c)], sem.at[2, 0])
 
-        def page_copies(g, slot):
-            for p in range(pages):
-                j = jnp.minimum(g * i32(pages) + i32(p), n_pages - 1)
-                blk = tables_ref[b, j]
-                yield pltpu.make_async_copy(k_hbm.at[layer, blk],
-                                            kbuf.at[slot, p],
-                                            sem.at[0, slot])
-                yield pltpu.make_async_copy(v_hbm.at[layer, blk],
-                                            vbuf.at[slot, p],
-                                            sem.at[1, slot])
+            def o_copy(c):
+                return pltpu.make_async_copy(
+                    acc_ref.at[toks(c)],
+                    o_hbm.at[pl.ds(q_start + t0 + c * i32(qc), qc)],
+                    sem.at[2, 1])
 
-        chunk_loop(n_chunks, lambda c: q_copy(c).start())
-        for cp in page_copies(i32(0), 0):
-            cp.start()
+            def page_copies(g, slot):
+                for p in range(pages):
+                    j = jnp.minimum(g * i32(pages) + i32(p), n_pages - 1)
+                    blk = tables_ref[b, j]
+                    yield pltpu.make_async_copy(k_hbm.at[layer, blk],
+                                                kbuf.at[slot, p],
+                                                sem.at[0, slot])
+                    yield pltpu.make_async_copy(v_hbm.at[layer, blk],
+                                                vbuf.at[slot, p],
+                                                sem.at[1, slot])
 
-        def init(c):
-            acc_ref[toks(c)] = jnp.zeros(
-                (qc,) + acc_ref.shape[1:], jnp.float32)
-            m_ref[:, band(c), :] = jnp.full(
-                (kv_h, rows, 128), NEG_INF, jnp.float32)
-            l_ref[:, band(c), :] = jnp.zeros(
-                (kv_h, rows, 128), jnp.float32)
+            chunk_loop(n_chunks, lambda c: q_copy(c).start())
+            for cp in page_copies(i32(0), 0):
+                cp.start()
 
-        chunk_loop(n_chunks, init)
-        chunk_loop(n_chunks, lambda c: q_copy(c).wait())
+            def init(c):
+                acc_ref[toks(c)] = jnp.zeros(
+                    (qc,) + acc_ref.shape[1:], jnp.float32)
+                m_ref[:, band(c), :] = jnp.full(
+                    (kv_h, rows, 128), NEG_INF, jnp.float32)
+                l_ref[:, band(c), :] = jnp.zeros(
+                    (kv_h, rows, 128), jnp.float32)
 
-        def group(g, _):
-            slot = g % 2
+            chunk_loop(n_chunks, init)
+            chunk_loop(n_chunks, lambda c: q_copy(c).wait())
 
-            @pl.when(g + 1 < n_groups)
-            def _prefetch():
-                for cp in page_copies(g + 1, 1 - slot):
-                    cp.start()
+            def group(g, _):
+                slot = g % 2
 
-            for cp in page_copies(g, slot):
-                cp.wait()
-            kv_pos = g * i32(cols) + jax.lax.broadcasted_iota(
-                i32, (rows, cols), 1)
-            tok = jax.lax.broadcasted_iota(i32, (rows, cols), 0) // i32(g_pad)
-            for h in range(kv_h):
-                k = kbuf[slot, :, h].reshape(cols, d).astype(mxu_dtype)
-                v = vbuf[slot, :, h].reshape(cols, d).astype(jnp.float32)
-                if quantized:
-                    ks = ks_ref[0, h, pl.ds(g, 1), :]        # (1, cols)
-                    vs = vs_ref[0, h, pl.ds(g, 1), :]
+                @pl.when(g + 1 < n_groups)
+                def _prefetch():
+                    for cp in page_copies(g + 1, 1 - slot):
+                        cp.start()
 
-                def chunk(c, h=h, k=k, v=v):
-                    ts, rs = toks(c), band(c)
-                    q = qbuf[ts, h].reshape(rows, d).astype(mxu_dtype)
-                    s = jax.lax.dot_general(
-                        q, k, (((1,), (1,)), ((), ())),
-                        preferred_element_type=jnp.float32) * sm_scale
+                for cp in page_copies(g, slot):
+                    cp.wait()
+                kv_pos = g * i32(cols) + jax.lax.broadcasted_iota(
+                    i32, (rows, cols), 1)
+                tok = jax.lax.broadcasted_iota(
+                    i32, (rows, cols), 0) // i32(g_pad)
+                for h in range(kv_h):
+                    k = kbuf[slot, :, h].reshape(cols, d).astype(mxu_dtype)
+                    v = vbuf[slot, :, h].reshape(cols, d).astype(jnp.float32)
                     if quantized:
-                        s = s * ks
-                    live = kv_pos <= jnp.minimum(
-                        pos0 + c * i32(qc) + tok, kv_len - 1)
-                    # typed scalars: python numbers weak-type to 64 bits
-                    # when the interpret-mode kernel is traced inside an
-                    # x64-on outer program
-                    s = jnp.where(live, s, jnp.float32(NEG_INF))
-                    p, alpha, m_ref[h, rs, :], l_ref[h, rs, :] = \
-                        online_softmax_step(s, m_ref[h, rs, :],
-                                            l_ref[h, rs, :])
-                    if quantized:
-                        # a masked slot's scale may be anything
-                        p = jnp.where(live, p * vs, jnp.float32(0.0))
-                    pv = jax.lax.dot_general(
-                        p, v, (((1,), (0,)), ((), ())),
-                        preferred_element_type=jnp.float32)
-                    acc = acc_ref[ts, h].reshape(rows, d)
-                    acc_ref[ts, h] = (acc * alpha[:, :1] + pv).reshape(
-                        qc, g_pad, d)
+                        ks = ks_ref[0, h, pl.ds(g, 1), :]        # (1, cols)
+                        vs = vs_ref[0, h, pl.ds(g, 1), :]
 
-                chunk_loop(n_chunks, chunk)
+                    def chunk(c, h=h, k=k, v=v):
+                        ts, rs = toks(c), band(c)
+                        q = qbuf[ts, h].reshape(rows, d).astype(mxu_dtype)
+                        s = jax.lax.dot_general(
+                            q, k, (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32) * sm_scale
+                        if quantized:
+                            s = s * ks
+                        live = kv_pos <= jnp.minimum(
+                            pos0 + c * i32(qc) + tok, kv_len - 1)
+                        # typed scalars: python numbers weak-type to 64 bits
+                        # when the interpret-mode kernel is traced inside an
+                        # x64-on outer program
+                        s = jnp.where(live, s, jnp.float32(NEG_INF))
+                        p, alpha, m_ref[h, rs, :], l_ref[h, rs, :] = \
+                            online_softmax_step(s, m_ref[h, rs, :],
+                                                l_ref[h, rs, :])
+                        if quantized:
+                            # a masked slot's scale may be anything
+                            p = jnp.where(live, p * vs, jnp.float32(0.0))
+                        pv = jax.lax.dot_general(
+                            p, v, (((1,), (0,)), ((), ())),
+                            preferred_element_type=jnp.float32)
+                        acc = acc_ref[ts, h].reshape(rows, d)
+                        acc_ref[ts, h] = (acc * alpha[:, :1] + pv).reshape(
+                            qc, g_pad, d)
 
-        jax.lax.fori_loop(0, n_groups, group, None)
+                    chunk_loop(n_chunks, chunk)
 
-        def finish(c):
-            ts, rs = toks(c), band(c)
-            owned = (c * i32(qc) + jax.lax.broadcasted_iota(
-                i32, (rows, d), 0) // i32(g_pad)) < n_tok
-            for h in range(kv_h):
-                l = l_ref[h, rs, :][:, :1]
-                l_safe = jnp.where(l == 0.0, jnp.float32(1.0), l)
-                out = acc_ref[ts, h].reshape(rows, d) / l_safe
-                acc_ref[ts, h] = jnp.where(
-                    owned, out, jnp.float32(0.0)).reshape(qc, g_pad, d)
-            o_copy(c).start()
+            jax.lax.fori_loop(0, n_groups, group, None)
 
-        chunk_loop(n_chunks, finish)
-        chunk_loop(n_chunks, lambda c: o_copy(c).wait())
+            def finish(c):
+                ts, rs = toks(c), band(c)
+                owned = (c * i32(qc) + jax.lax.broadcasted_iota(
+                    i32, (rows, d), 0) // i32(g_pad)) < n_tok
+                for h in range(kv_h):
+                    l = l_ref[h, rs, :][:, :1]
+                    l_safe = jnp.where(l == 0.0, jnp.float32(1.0), l)
+                    out = acc_ref[ts, h].reshape(rows, d) / l_safe
+                    acc_ref[ts, h] = jnp.where(
+                        owned, out, jnp.float32(0.0)).reshape(qc, g_pad, d)
+                o_copy(c).start()
 
+            chunk_loop(n_chunks, finish)
+            chunk_loop(n_chunks, lambda c: o_copy(c).wait())
+
+        jax.lax.fori_loop(0, n_tiles, lambda i, _: tile(i), None)
+
+    # ONE of the two instantiations computes a lane, the one its own q_len
+    # names; the other's trip count is zero (both are, for an empty lane).
+    # Trip counts and not `pl.when`: under a `cond` the interpreter's CPU
+    # program copies the whole pool (tests/test_inference.py, the step
+    # never copies it)
     n_tiles = jnp.where(kv_len > 0, pl.cdiv(q_len, i32(q_tile)), i32(0))
-    jax.lax.fori_loop(0, n_tiles, lambda i, _: tile(i), None)
+    decode = q_len == 1
+    lane(1, jnp.where(decode, n_tiles, i32(0)))
+    lane(_RAGGED_Q_CHUNK, jnp.where(decode, i32(0), n_tiles))
 
 
 def _ragged_call(q, k_cache, v_cache, layer, block_tables, kv_lens, q_lens,

@@ -649,19 +649,15 @@ def v5e_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
-@pytest.mark.parametrize("kv_bits", [16, 8])
-def test_llama_ragged_step_compiles_for_v5e_without_a_pool_copy(
-        kv_bits, v5e_chip, monkeypatch):
+@pytest.fixture(scope="module", params=[16, 8])
+def v5e_ragged_step(request, v5e_chip):
     """The Llama engine's ragged step at the benchmark's Mistral-7B size
     (12 layers x 4097 blocks x 8 kv heads x 16 x 128, 32 lanes + a 64-token
-    chunk), compiled by the installed libtpu for a v5e from shapes alone.
-    The TPU compiler decides layouts the CPU's never meets: a scatter whose
-    update window spans the kv-head axis made it re-lay the WHOLE pool out
-    and back in every layer. So: temporaries under one layer's K pool
-    (they were 4.03 GB of copies when the pool rode the layer scan as
-    xs/ys), the pools aliased to their outputs, the kernel in the program,
-    and the layer loop still rolled."""
+    chunk: the batch-decode cell's shapes), lowered and compiled by the
+    installed libtpu for a v5e from shapes alone, over a bf16 or an int8
+    pool."""
     import functools
+    import types
 
     import jax
     import jax.numpy as jnp
@@ -670,7 +666,6 @@ def test_llama_ragged_step_compiles_for_v5e_without_a_pool_copy(
     from paddle_tpu.ops import sampling
     from paddle_tpu.ops.pallas import _support
 
-    monkeypatch.setattr(_support, "backend", lambda: "tpu")
     layers, hidden, inter, nh, kvh, d, vocab = 12, 4096, 14336, 32, 8, 128, \
         32768
     nb, bs, width, lanes, tokens = 4097, 16, 128, 32, 96
@@ -689,7 +684,7 @@ def test_llama_ragged_step_compiles_for_v5e_without_a_pool_copy(
         "rope_cos": arr((2048, d // 2), jnp.float32),
         "rope_sin": arr((2048, d // 2), jnp.float32)}
     pool = (layers, nb, kvh, bs, d)
-    if kv_bits == 8:
+    if request.param == 8:
         pools = (arr(pool, jnp.int8),) * 2 + (arr(pool[:-1], jnp.float32),) * 2
     else:
         pools = (arr(pool),) * 2
@@ -710,25 +705,64 @@ def test_llama_ragged_step_compiles_for_v5e_without_a_pool_copy(
     cache_was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     try:
-        compiled = jax.jit(step, donate_argnums=(1,)).trace(
-            params, pools, *ints).lower(
-            lowering_platforms=("tpu",)).compile()
+        with pytest.MonkeyPatch.context() as mp:
+            mp.setattr(_support, "backend", lambda: "tpu")
+            lowered = jax.jit(step, donate_argnums=(1,)).trace(
+                params, pools, *ints).lower(lowering_platforms=("tpu",))
+            compiled = lowered.compile()
     finally:
         jax.config.update("jax_enable_compilation_cache", cache_was)
-    mem = compiled.memory_analysis()
+    return types.SimpleNamespace(
+        kv_bits=request.param, lowered=lowered.as_text(), compiled=compiled,
+        pools=pools, layers=layers, lanes=lanes, tokens=tokens, vocab=vocab)
+
+
+def test_llama_ragged_step_compiles_for_v5e_without_a_pool_copy(
+        v5e_ragged_step):
+    """The TPU compiler decides layouts the CPU's never meets: a scatter
+    whose update window spans the kv-head axis made it re-lay the WHOLE
+    pool out and back in every layer. So: temporaries under one layer's K
+    pool (they were 4.03 GB of copies when the pool rode the layer scan as
+    xs/ys), the pools aliased to their outputs, the kernel in the program,
+    and the layer loop still rolled."""
+    import jax
+    import jax.numpy as jnp
+
+    step, pools = v5e_ragged_step, v5e_ragged_step.pools
+    mem = step.compiled.memory_analysis()
     pool_bytes = [int(np.prod(p.shape)) * jnp.dtype(p.dtype).itemsize
                   for p in pools]
-    assert mem.temp_size_in_bytes < pool_bytes[0] // layers, (
-        mem.temp_size_in_bytes, pool_bytes[0] // layers)
+    assert mem.temp_size_in_bytes < pool_bytes[0] // step.layers, (
+        mem.temp_size_in_bytes, pool_bytes[0] // step.layers)
     assert mem.alias_size_in_bytes >= sum(pool_bytes)
-    text = compiled.as_text()
+    text = step.compiled.as_text()
     assert text.count("paged_attention_ragged") and "tpu_custom_call" in text
     assert "while(" in text          # the program's size is O(1) in depth
     # ONE program a round: tokens + flags, the logits and the pools come out
     # of it, and nothing crosses to the host inside it
-    outs = jax.tree.leaves(compiled.out_info)
-    assert [tuple(o.shape) for o in outs[:2]] == [(2, lanes), (tokens, vocab)]
+    outs = jax.tree.leaves(step.compiled.out_info)
+    assert [tuple(o.shape) for o in outs[:2]] == [
+        (2, step.lanes), (step.tokens, step.vocab)]
     assert outs[0].dtype == jnp.int32 and len(outs) == 2 + len(pools)
+
+
+def test_llama_ragged_step_holds_one_attention_kernel(v5e_ragged_step):
+    """ISSUE 37: a decode lane's one-token tile and the chunked body are
+    ONE Mosaic kernel under ONE name, so the readers that match the name
+    `paged_attention_ragged` (`ragged_attn_share.*`,
+    `ragged_attn_roofline.batch`) see all of the attention's time. The
+    step holds it once, in the layer scan's body; the only other kernel is
+    the bf16 pool's write (an int8 pool takes the XLA scatter)."""
+    import re
+
+    want = ["kv_write_ragged"] * (v5e_ragged_step.kv_bits == 16) \
+        + ["paged_attention_ragged"]
+    assert sorted(re.findall(r'kernel_name = "(\w+)"',
+                             v5e_ragged_step.lowered)) == want
+    calls = re.findall(
+        r'%([\w.-]+) = [^\n]*custom_call_target="tpu_custom_call"',
+        v5e_ragged_step.compiled.as_text())
+    assert sorted(re.sub(r"[.\d]+$", "", c) for c in calls) == want
 
 
 def test_gate_closes_for_gspmd_partitioned_operands():

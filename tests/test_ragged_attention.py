@@ -6,6 +6,8 @@ padding), the ragged KV scatter, and engine-level ragged_step semantics.
 Kernels run through the Pallas interpreter on CPU (FLAGS_pallas_interpret)
 — same kernel code compiles via Mosaic on TPU.
 """
+import functools
+
 import numpy as np
 import pytest
 
@@ -228,6 +230,15 @@ _LIVE_CASES = {
     "straddles_chunks_empty_middle": ([13, 0, 3, 0, 9], [29, 0, 3, 0, 40]),
     "all_guard_tail": ([2, 1, 0, 0, 0], [18, 5, 0, 0, 0]),
     "all_lanes_empty": ([0, 0, 0, 0, 0], [0, 0, 0, 0, 0]),
+    # decode lanes (`q_len == 1`: the one-token tile) between a prefill
+    # chunk and verify windows; with empty lanes first, in the middle and
+    # last; and as the LAST lane of a full buffer, its token the last row
+    "decode_between_chunk_and_windows": ([1, 40, 1, 4, 1],
+                                         [129, 40, 15, 60, 224]),
+    "decode_with_empty_first_middle_last": ([0, 1, 0, 3, 0],
+                                            [0, 33, 0, 19, 0]),
+    "decode_lane_last_fills_the_buffer": ([64, 8, 4, 3, 1],
+                                          [64, 24, 100, 3, 130]),
 }
 # other head groupings and page geometries: (q_lens, kv_lens, T, shape)
 _SHAPE_CASES = {
@@ -278,32 +289,63 @@ class TestRaggedLiveWork:
 
     @pytest.mark.parametrize("kind", sorted(_KINDS))
     @pytest.mark.parametrize("dead", [np.nan, np.inf])
-    def test_dead_pages_are_never_read(self, kind, dead):
+    @pytest.mark.parametrize("q_lens", [[1, 12, 0, 1, 3], [1, 1, 0, 1, 1]],
+                             ids=["mixed", "decode"])
+    def test_dead_pages_are_never_read(self, kind, dead, q_lens):
         """Every page past each lane's kv_len, and every block no lane
         owns, poisoned: the output is finite and is what a clean pool
-        gives, bit for bit — nothing of a dead page enters the sums."""
+        gives, bit for bit — nothing of a dead page enters the sums, in
+        the chunked body or in a decode lane's one-token tile."""
         dtype, quant = _KINDS[kind]
         outs = []
         for fill in (0.0, dead):
-            args, kw = _live_case(12, [1, 12, 0, 1, 3], [35, 44, 0, 16, 224],
+            args, kw = _live_case(12, q_lens, [35, 44, 0, 16, 224],
                                   _LIVE_T, dtype=dtype, quant=quant,
                                   dead=fill)
             outs.append(np.asarray(_ragged(*args, **kw), np.float32))
         assert np.isfinite(outs[1]).all()
         np.testing.assert_array_equal(outs[0], outs[1])
 
+    @staticmethod
+    def _the_pallas_call():
+        """The traced function's `pallas_call` equation: there is ONE."""
+        args, _ = _live_case(13, *_LIVE_CASES["straddles_query_tile"],
+                             _LIVE_T)
+        jaxpr = jax.make_jaxpr(pa.paged_attention_ragged)(*args)
+        call, = [e for e in jaxpr.jaxpr.eqns
+                 if e.primitive.name == "pallas_call"]
+        return call
+
     def test_grid_is_the_lane_count(self):
         """The work bound: the `pallas_call`'s static grid is one step a
         lane — no axis, and no product of axes, follows the packed token
         budget T or the table's width, let alone T x max_blocks."""
-        args, _ = _live_case(13, *_LIVE_CASES["straddles_query_tile"],
-                             _LIVE_T)
-        jaxpr = jax.make_jaxpr(pa.paged_attention_ragged)(*args)
-        calls = [e for e in jaxpr.jaxpr.eqns
-                 if e.primitive.name == "pallas_call"]
-        assert len(calls) == 1
-        assert tuple(calls[0].params["grid_mapping"].grid) == (5,)
-        assert calls[0].params["name"] == "paged_attention_ragged"
+        call = self._the_pallas_call()
+        assert tuple(call.params["grid_mapping"].grid) == (5,)
+        assert call.params["name"] == "paged_attention_ragged"
+
+    def test_one_kernel_holds_a_decode_tile_and_a_chunk_tile(self):
+        """ISSUE 37: the ONE `pallas_call` holds its body twice, under a
+        test of the lane's `q_len`: the score and update matmuls of a
+        decode lane at `g_pad` = 8 MXU rows (one token's head group), of
+        every other lane at `_RAGGED_Q_CHUNK` tokens' 64; both against
+        the same 128-position page group, for each of the 2 kv heads."""
+        call = self._the_pallas_call()
+        dots = []
+
+        def walk(j):
+            for e in j.eqns:
+                if e.primitive.name == "dot_general":
+                    dots.append(tuple(v.aval.shape for v in e.invars))
+                for sub in jax.core.jaxprs_in_params(e.params):
+                    walk(sub)
+
+        walk(call.params["jaxpr"])
+        rows = sorted({lhs[0] for lhs, _ in dots})
+        assert rows == [8, 8 * pa._RAGGED_Q_CHUNK]
+        for r in rows:
+            assert sorted(d for d in dots if d[0][0] == r) == \
+                sorted(2 * [((r, 32), (128, 32)), ((r, 128), (128, 32))])
 
     def test_gate_refuses_a_tile_that_cannot_fit(self):
         """`ragged_supported` derives the tile the kernel would run: the
@@ -410,6 +452,128 @@ class TestLayerOperand:
         q, kc, vc, *rest = args
         with pytest.raises(ValueError, match="needs its `layer`"):
             pa.paged_attention_ragged(q, kc[None], vc[None], *rest)
+
+
+# The decode tile's cases: five decode lanes a call, one a context length
+# (block 16, so a page group is 8 pages = 128 tokens; the table 12 wide)
+_DECODE_BS, _DECODE_W, _DECODE_T = 16, 12, 16
+_DECODE_CTX = {"one_token": 1, "block_less_one": _DECODE_BS - 1,
+               "one_block": _DECODE_BS, "page_group_plus_one": 129,
+               "full_table": _DECODE_W * _DECODE_BS}
+
+
+@functools.cache
+def _decode_and_control(kind, g, layer):
+    """(decode, control, ref, value row) as float32 numpy, one row a
+    context of `_DECODE_CTX`, for the serving kinds (bf16 q over a bf16 or
+    an int8 pool). `decode`: each token as a `q_len == 1` lane,
+    which takes the kernel's one-token tile. `control`: the same token as
+    the LAST of a two-token lane over the same pages, which takes the
+    `_RAGGED_Q_CHUNK` body (no lane of two tokens fits a context of one:
+    that lane stays a decode lane, and its control is the value row
+    itself). Same pool, same tables, same shapes: ONE executable serves
+    both calls, what differs is the data `q_lens`."""
+    dtype, quant = _KINDS[kind]
+    kv_lens = list(_DECODE_CTX.values())
+    lanes = len(kv_lens)
+    (q, kc, vc, tables, kv, lane, pos), kw = _live_case(
+        31, [1] * lanes, kv_lens, _DECODE_T, kvh=2, h=2 * g,
+        bs=_DECODE_BS, w=_DECODE_W, dtype=dtype, quant=quant)
+    row = np.asarray(vc[tables[0, 0], :, 0], np.float32)       # [KVH, D]
+    if quant:
+        row = row * np.asarray(kw["v_scale"][tables[0, 0], :, 0])[:, None]
+    kc, vc = _stack_layers(32, layer, 3, kc, vc)
+    kw = dict(zip(kw, _stack_layers(33, layer, 3, *kw.values())),
+              layer=jnp.int32(layer))
+    decode = _ragged(q, kc, vc, tables, kv, lane, pos, **kw)
+    ref = _ragged_ref(q, kc, vc, tables, kv, lane, pos, **kw)
+    two = [1] + [2] * (lanes - 1)
+    last = np.cumsum(two) - 1                  # each lane's last token
+    q2 = jnp.roll(q, 3, axis=0).at[last].set(q[:lanes])
+    lane2, pos2 = pa.ragged_metadata(jnp.asarray(two, jnp.int32), kv,
+                                     _DECODE_T)
+    control = _ragged(q2, kc, vc, tables, kv, lane2, pos2, **kw)
+    assert decode.dtype == control.dtype == ref.dtype == dtype
+    first = np.arange(lanes)
+    return (np.asarray(decode, np.float32)[first],
+            np.asarray(control, np.float32)[last],
+            np.asarray(ref, np.float32)[first], np.repeat(row, g, axis=0))
+
+
+class TestDecodeTile:
+    """ISSUE 37: a `q_len == 1` lane is computed in a one-token tile
+    (`g_pad` MXU rows a kv head and page group, not 8 tokens' worth). It
+    is the same arithmetic a row: a decode lane's output is, to the last
+    bit, what the chunked body gives the same token."""
+
+    @pytest.mark.parametrize("ctx", list(_DECODE_CTX))
+    @pytest.mark.parametrize("layer", [0, 2])
+    @pytest.mark.parametrize("g", [1, 2, 4])
+    @pytest.mark.parametrize("kind", ["bf16", "int8kv"])
+    def test_decode_lane_is_the_chunked_body_bit_for_bit(self, kind, g,
+                                                         layer, ctx):
+        decode, control, ref, row = _decode_and_control(kind, g, layer)
+        i = list(_DECODE_CTX).index(ctx)
+        if ctx == "one_token":
+            # softmax over one position is 1.0: the (dequantized) value
+            # row itself, rounded once to the output's dtype
+            want = np.asarray(jnp.asarray(row, _KINDS[kind][0]), np.float32)
+            np.testing.assert_array_equal(decode[i], want)
+        np.testing.assert_array_equal(decode[i], control[i])
+        np.testing.assert_allclose(decode[i], ref[i], atol=2e-2, rtol=2e-2)
+
+    @pytest.mark.parametrize("kind", ["f32", "bf16"])
+    def test_last_decode_lane_stays_inside_the_buffer(self, kind):
+        """A decode lane moves ONE token of q in and one of o out: as the
+        last lane of a buffer with no spare chunk behind it (the kernel's
+        own call, below the public function's padding) its token, the
+        buffer's last row, is still the reference's."""
+        dtype, _ = _KINDS[kind]
+        t, kvh, g, d = 8, 2, 4, 32
+        args, _ = _live_case(34, [1] * t, [5, 16, 17, 1, 130, 40, 192, 64],
+                             t, kvh=kvh, h=kvh * g, bs=_DECODE_BS,
+                             w=_DECODE_W, nb=64, d=d, dtype=dtype)
+        q, kc, vc, tables, kv, _, _ = args
+        tiles = pa._ragged_tiles(t, kvh, 8, d, _DECODE_BS, _DECODE_W,
+                                 kc.dtype.itemsize)
+        qg = jnp.pad(q.reshape(t, kvh, g, d).astype(jnp.float32),
+                     ((0, 0), (0, 0), (0, 8 - g), (0, 0)))
+        out = pa._ragged_call(
+            qg, kc[None], vc[None], jnp.zeros((1,), jnp.int32), tables, kv,
+            jnp.ones((t,), jnp.int32), jnp.arange(t, dtype=jnp.int32),
+            1.0 / float(np.sqrt(d)), tiles,
+            jnp.float32 if kind == "f32" else jnp.bfloat16)
+        assert out.shape == qg.shape
+        got = out[:, :, :g].reshape(t, kvh * g, d).astype(dtype)
+        np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                      np.asarray(_ragged(*args), np.float32))
+
+    @pytest.mark.parametrize("kind", ["f32", "bf16"])
+    def test_paged_attention_is_its_decode_lanes(self, kind):
+        """`paged_attention(q [B, H, D])`, every lane a decode lane: row
+        for row and bit for bit the chunked body's answer for the same
+        tokens (each the last of a two-token lane)."""
+        dtype, _ = _KINDS[kind]
+        kv_lens = [9, 16, 17, 40]
+        lanes = len(kv_lens)
+        (q, kc, vc, tables, kv, _, _), _ = _live_case(
+            35, [1] * lanes, kv_lens, 2 * lanes, w=4, nb=32, dtype=dtype)
+        out = jax.jit(pa.paged_attention)(q[:lanes], kc, vc, tables, kv)
+        last = 2 * np.arange(lanes) + 1
+        q2 = jnp.roll(q, 1, axis=0).at[last].set(q[:lanes])
+        lane2, pos2 = pa.ragged_metadata(
+            jnp.full((lanes,), 2, jnp.int32), kv, 2 * lanes)
+        control = _ragged(q2, kc, vc, tables, kv, lane2, pos2)
+        assert out.dtype == dtype and out.shape == (lanes, 8, 32)
+        got = np.asarray(out, np.float32)
+        want = np.asarray(control, np.float32)[last]
+        if kind == "f32":
+            # to the last bits only: the CPU's f32 gemm picks its
+            # blocking, and so its order of summation, by the row count
+            # (the MXU does not: PERF.md has the chip's bitwise check)
+            np.testing.assert_allclose(got, want, atol=1e-6, rtol=0)
+        else:
+            np.testing.assert_array_equal(got, want)
 
 
 class TestRaggedWrite:
