@@ -17,6 +17,14 @@ trigger COPY-ON-WRITE (`append_tokens`): the writer gets a private copy
 keeps the original bytes — a divergent `append` after a `trim` into a
 shared region can never corrupt a sibling's context.
 
+GROUPS OF LAYERS: a model whose layer kinds keep different amounts of
+context (full attention beside a sliding window) gives the manager further
+block groups, each with a block-id space, a free list and a table a
+sequence of its own; a windowed group gives a block back once it lies wholly
+behind the window of the committed length (`_BlockGroup`). Sharing,
+copy-on-write and the reclaimer are the FIRST group's; a manager of one
+group, which is every other engine's, is all of the above and nothing more.
+
 Exhaustion is a *scheduling event*, not a crash: `allocate`/`append_token`
 raise the typed `KVCacheExhausted` (pool empty) or `SequenceTooLong`
 (per-sequence block cap), which the continuous-batching scheduler
@@ -65,12 +73,15 @@ class KVCacheExhausted(RuntimeError):
     Subclasses RuntimeError so pre-existing callers keep working.
     """
 
-    def __init__(self, need: int, free: int, total: int):
+    def __init__(self, need: int, free: int, total: int,
+                 group: Optional[str] = None):
         self.need = need
         self.free = free
         self.total = total
+        self.group = group       # which block group ran out (None: the one)
+        pool = "pool" if group is None else f"pool of group {group!r}"
         super().__init__(
-            f"KV cache pool exhausted: need {need} block(s), "
+            f"KV cache {pool} exhausted: need {need} block(s), "
             f"{free}/{total} free")
 
 
@@ -82,20 +93,193 @@ class SequenceTooLong(ValueError):
     Subclasses ValueError so pre-existing callers keep working.
     """
 
-    def __init__(self, need_blocks: int, max_blocks: int):
+    def __init__(self, need_blocks: int, max_blocks: int,
+                 group: Optional[str] = None):
         self.need_blocks = need_blocks
         self.max_blocks = max_blocks
+        self.group = group
+        where = "" if group is None else f" in group {group!r}"
         super().__init__(
-            f"sequence needs {need_blocks} blocks > max_blocks_per_seq "
-            f"{max_blocks}")
+            f"sequence needs {need_blocks} blocks{where} > "
+            f"max_blocks_per_seq {max_blocks}")
+
+
+class _BlockGroup:
+    """One FURTHER group of layers beside the manager's first: a block-id
+    space, a free list and a table a sequence of its own. The layers of a
+    group share one pool on the device (`[layers_in_group, num_blocks,
+    ...]`), so a model whose layers keep different amounts of context (full
+    attention beside a sliding window) has one group a kind.
+
+    `window`: how many positions a query of these layers still sees, its
+    own among them (None: all). A windowed group gives a block back once it
+    lies WHOLLY behind the window of the next query the sequence can ever
+    ask: released by the length the sequence had BEFORE the append that is
+    being accounted (what is committed), never by the appended length, so a
+    `trim` back to any length since (a rejected speculation, a failed
+    round's rollback) needs no block that is gone. A released entry of the
+    table holds -1 and comes out of `block_table_array` as padding; the
+    attention kernel starts its page walk behind it and never reads it.
+
+    No sharing here: a block is free or leased by one sequence (the radix
+    prefix cache is refused over such an engine)."""
+
+    def __init__(self, name: str, num_blocks: int, block_size: int,
+                 window: Optional[int]):
+        if window is not None and window < 1:
+            raise ValueError(f"group {name!r}: window {window} < 1")
+        self.name = name
+        self.num_blocks = int(num_blocks)
+        self.block_size = block_size
+        self.window = window
+        self._free: List[int] = list(range(self.num_blocks - 1, -1, -1))
+        self._tables: Dict[int, List[int]] = {}
+        self._head: Dict[int, int] = {}     # leading entries given back
+        self.bytes_per_block: Optional[int] = None
+        self.kv_bits = 16
+        self.released = 0                   # blocks given back behind windows
+
+    @property
+    def free_blocks(self) -> int:
+        return len(self._free)
+
+    def _covering(self, num_tokens: int) -> int:
+        """Blocks that cover `num_tokens` positions (a sequence holds one
+        from its first token on)."""
+        return max(1, -(-num_tokens // self.block_size))
+
+    def first_needed(self, num_tokens: int) -> int:
+        """The first logical block a query at position `num_tokens` (the
+        next one of a sequence that long) still sees."""
+        if self.window is None:
+            return 0
+        return max(0, num_tokens - self.window + 1) // self.block_size
+
+    def blocks_needed(self, num_tokens: int, step: int = 1) -> int:
+        """Blocks a sequence of `num_tokens` holds at most at once when it
+        grows `step` tokens an append."""
+        total = self._covering(num_tokens)
+        if self.window is None:
+            return total
+        return min(total, (self.window + step - 2) // self.block_size + 2)
+
+    def seq_blocks(self, seq_id) -> int:
+        return len(self._tables.get(seq_id, ())) - self._head.get(seq_id, 0)
+
+    def blocks_of(self, seq_id) -> Tuple[int, ...]:
+        return tuple(self._tables.get(seq_id, ())[self._head.get(seq_id, 0):])
+
+    def can_allocate(self, num_tokens: int) -> bool:
+        return len(self._free) >= self._covering(num_tokens)
+
+    def check_allocate(self, num_tokens: int) -> None:
+        need = self._covering(num_tokens)
+        if need > len(self._free):
+            raise KVCacheExhausted(need, len(self._free), self.num_blocks,
+                                   self.name)
+
+    def allocate(self, seq_id, num_tokens: int) -> None:
+        self._tables[seq_id] = [self._free.pop()
+                                for _ in range(self._covering(num_tokens))]
+        self._head[seq_id] = 0
+
+    def _plan(self, seq_id, old_len: int, new_len: int):
+        table = self._tables[seq_id]
+        head = max(self._head[seq_id], min(self.first_needed(old_len),
+                                           len(table)))
+        need = self._covering(new_len) - len(table)
+        return table, head, max(need, 0)
+
+    def check_append(self, seq_id, old_len: int, new_len: int) -> None:
+        """Raises what `append` would run into; changes nothing."""
+        _table, head, need = self._plan(seq_id, old_len, new_len)
+        free = len(self._free) + head - self._head[seq_id]
+        if need > free:
+            raise KVCacheExhausted(need, free, self.num_blocks, self.name)
+
+    def append(self, seq_id, old_len: int, new_len: int) -> None:
+        """Give back what lies behind the window at `old_len`, then lease
+        up to `new_len` (the caller ran `check_append`)."""
+        table, head, need = self._plan(seq_id, old_len, new_len)
+        gone = head - self._head[seq_id]
+        if gone:
+            for i in range(self._head[seq_id], head):
+                self._free.append(table[i])
+                table[i] = -1
+            self._head[seq_id] = head
+            self.released += gone
+            _monitor_inc("serving.kv.window_blocks_released", gone)
+        for _ in range(need):
+            table.append(self._free.pop())
+
+    def check_trim(self, seq_id, num_tokens: int) -> None:
+        if self.first_needed(num_tokens) < self._head[seq_id]:
+            raise ValueError(
+                f"trim to {num_tokens} tokens needs block "
+                f"{self.first_needed(num_tokens)} of group {self.name!r}, "
+                f"released behind its window of {self.window}")
+
+    def trim(self, seq_id, num_tokens: int) -> None:
+        table = self._tables[seq_id]
+        keep = max(self._covering(num_tokens), self._head[seq_id])
+        while len(table) > keep:
+            self._free.append(table.pop())
+
+    def free(self, seq_id) -> None:
+        head = self._head.pop(seq_id)
+        self._free.extend(self._tables.pop(seq_id)[head:])
+
+    def utilization(self, guard_ids) -> float:
+        guard = sum(self.seq_blocks(sid) for sid in guard_ids)
+        used = self.num_blocks - len(self._free) - guard
+        return max(0, used) / max(self.num_blocks - guard, 1)
+
+    def check_consistency(self) -> None:
+        free = self._free
+        assert len(free) == len(set(free)), \
+            f"group {self.name}: duplicate free-list entry"
+        live = [b for sid, t in self._tables.items()
+                for b in t[self._head[sid]:]]
+        assert len(live) == len(set(live)), \
+            f"group {self.name}: a block leased twice"
+        assert not set(free) & set(live), \
+            f"group {self.name}: block both free and leased"
+        assert len(free) + len(live) == self.num_blocks, \
+            f"group {self.name}: pool accounting broken: {len(free)} free " \
+            f"+ {len(live)} live != {self.num_blocks}"
+        for sid, t in self._tables.items():
+            head = self._head[sid]
+            assert all(b == -1 for b in t[:head]) and \
+                all(b >= 0 for b in t[head:]), \
+                f"group {self.name}: seq {sid}'s released entries are " \
+                "not its leading ones"
 
 
 class BlockCacheManager:
+    """Block tables over one group of layers or several.
+
+    The FIRST group is what every engine has: `num_blocks` blocks that keep
+    every token, refcounted, shareable, copy-on-write (all of the module
+    docstring). `further_groups` — `(name, num_blocks, window)` each, given
+    by an engine whose layer types keep different amounts of context —
+    add block-id spaces of their own (`_BlockGroup`): a sequence then has
+    one table a group, `allocate`/`append_tokens`/`trim`/`free` move all of
+    them or none, and the calls that ask about blocks take `group=` (0, the
+    first, by default). `block_table_array` lays a sequence's tables side
+    by side, `[n, n_groups * max_blocks_per_seq]`: the one 2-D table every
+    engine's step takes, of which an engine with several groups reads its
+    own columns. With no further group every call is what it always was.
+    """
+
     def __init__(self, num_blocks: int, block_size: int,
-                 max_blocks_per_seq: int):
+                 max_blocks_per_seq: int, name: Optional[str] = None,
+                 further_groups=()):
         self.num_blocks = num_blocks
         self.block_size = block_size
         self.max_blocks_per_seq = max_blocks_per_seq
+        self.name = name               # the first group's, for messages
+        self._further: Tuple[_BlockGroup, ...] = tuple(
+            _BlockGroup(n, nb, block_size, w) for n, nb, w in further_groups)
         self._free: List[int] = list(range(num_blocks - 1, -1, -1))
         self._tables: Dict[int, List[int]] = {}
         self._lens: Dict[int, int] = {}
@@ -137,6 +321,37 @@ class BlockCacheManager:
     @property
     def num_seqs(self) -> int:
         return len(self._tables)
+
+    # ---- groups of layers ----
+    @property
+    def n_groups(self) -> int:
+        return 1 + len(self._further)
+
+    @property
+    def group_names(self) -> Tuple[str, ...]:
+        return (self.name or "kv",) + tuple(g.name for g in self._further)
+
+    @property
+    def table_width(self) -> int:
+        """Columns of `block_table_array`: every group's table, side by
+        side."""
+        return self.n_groups * self.max_blocks_per_seq
+
+    def group_window(self, group: int = 0) -> Optional[int]:
+        return self._further[group - 1].window if group else None
+
+    def num_blocks_of(self, group: int = 0) -> int:
+        return self._further[group - 1].num_blocks if group \
+            else self.num_blocks
+
+    def free_blocks_of(self, group: int = 0) -> int:
+        return self._further[group - 1].free_blocks if group \
+            else len(self._free)
+
+    def blocks_released(self, group: int) -> int:
+        """Blocks a windowed group has given back behind its window since
+        the manager was built."""
+        return self._further[group - 1].released
 
     # ---- refcounted block primitives ----
     def _take_free(self) -> int:
@@ -187,13 +402,22 @@ class BlockCacheManager:
         self._cow_hook = hook
 
     def set_kv_geometry(self, bytes_per_block: int,
-                        kv_bits: int = 16) -> None:
-        """Register the device-side byte cost of one pool block (across
-        K+V, all layers, INCLUDING any quantization scale planes) and
-        the KV element width. Engines call this at construction
-        (`inference/kv_quant.kv_bytes_per_block` owns the formula)."""
+                        kv_bits: int = 16, group: int = 0) -> None:
+        """Register the device-side byte cost of one pool block of
+        `group` (across K+V, the group's layers, INCLUDING any
+        quantization scale planes) and the KV element width. Engines call
+        this at construction (`inference/kv_quant.kv_bytes_per_block`
+        owns the formula)."""
+        if group:
+            g = self._further[group - 1]
+            g.bytes_per_block, g.kv_bits = int(bytes_per_block), int(kv_bits)
+            return
         self._bytes_per_block = int(bytes_per_block)
         self._kv_bits = int(kv_bits)
+
+    def bytes_per_block_of(self, group: int = 0) -> Optional[int]:
+        return self._further[group - 1].bytes_per_block if group \
+            else self._bytes_per_block
 
     @property
     def kv_bits(self) -> int:
@@ -240,8 +464,8 @@ class BlockCacheManager:
     def _guard_blocks(self) -> int:
         return sum(len(self._tables[sid]) for sid in self._guard_ids)
 
-    def utilization(self) -> float:
-        """Fraction of the usable pool currently held by REAL demand.
+    def utilization(self, group: int = 0) -> float:
+        """Fraction of `group`'s usable pool currently held by REAL demand.
 
         Counted over PHYSICAL blocks — a block shared by N leases is one
         block of pressure, not N (per-lease summing would inflate past
@@ -251,13 +475,17 @@ class BlockCacheManager:
         cache-held reclaimable blocks: the prefix tree surrenders them
         on demand, so they are free capacity wearing a cache hat — the
         watermark ladder must not shed over them."""
+        if group:
+            return self._further[group - 1].utilization(self._guard_ids)
         guard = self._guard_blocks()
         used = self.num_blocks - len(self._free) - guard \
             - self.reclaimable_blocks()
         return max(0, used) / max(self.num_blocks - guard, 1)
 
-    def fragmentation(self) -> Dict:
-        """Fragmentation view of the pool (observability/memory.py):
+    def fragmentation(self, group: int = 0) -> Dict:
+        """Fragmentation view of the pool (observability/memory.py); of a
+        further group, what such a group has (no sharing, no per-sequence
+        waste worth a line: its blocks are a window's, not a context's):
 
         - per-sequence leased-vs-used blocks and token counts (`per_seq`);
         - token-level internal fragmentation: leased block capacity vs
@@ -274,6 +502,20 @@ class BlockCacheManager:
           position-indexed, but the predictor of allocator behavior on
           backends with contiguous KV layouts).
         """
+        if group:
+            g = self._further[group - 1]
+            leased = g.num_blocks - g.free_blocks
+            bpb = g.bytes_per_block
+            return {
+                "group": g.name, "window": g.window,
+                "num_blocks": g.num_blocks, "block_size": self.block_size,
+                "kv_bits": g.kv_bits, "bytes_per_block": bpb,
+                "pool_bytes": bpb * g.num_blocks if bpb else None,
+                "leased_bytes": bpb * leased if bpb else None,
+                "free_blocks": g.free_blocks, "leased_blocks": leased,
+                "released_blocks": g.released,
+                "utilization": round(g.utilization(self._guard_ids), 4),
+            }
         free = sorted(self._free)
         largest_run = run = 0
         prev = None
@@ -331,12 +573,19 @@ class BlockCacheManager:
             "per_seq": per_seq,
         }
 
-    def blocks_needed(self, num_tokens: int) -> int:
+    def blocks_needed(self, num_tokens: int, group: int = 0,
+                      step: int = 1) -> int:
+        """Blocks of `group` a sequence of `num_tokens` holds at once; of
+        a windowed group, at most what its window and an append of `step`
+        tokens span."""
+        if group:
+            return self._further[group - 1].blocks_needed(num_tokens, step)
         return max(1, (num_tokens + self.block_size - 1) // self.block_size)
 
     def can_allocate(self, num_tokens: int) -> bool:
         return len(self._free) + self.reclaimable_blocks() \
-            >= self.blocks_needed(num_tokens)
+            >= self.blocks_needed(num_tokens) \
+            and all(g.can_allocate(num_tokens) for g in self._further)
 
     def allocate(self, seq_id: int, num_tokens: int) -> List[int]:
         """Reserve blocks for a new sequence of `num_tokens` tokens.
@@ -350,11 +599,16 @@ class BlockCacheManager:
         _chaos("serve.cache")
         need = self.blocks_needed(num_tokens)
         if need > self.max_blocks_per_seq:
-            raise SequenceTooLong(need, self.max_blocks_per_seq)
+            raise SequenceTooLong(need, self.max_blocks_per_seq, self.name)
+        for g in self._further:
+            g.check_allocate(num_tokens)
         self._ensure_free(need)
         if need > len(self._free):
-            raise KVCacheExhausted(need, len(self._free), self.num_blocks)
+            raise KVCacheExhausted(need, len(self._free), self.num_blocks,
+                                   self.name)
         blocks = [self._take_free() for _ in range(need)]
+        for g in self._further:
+            g.allocate(seq_id, num_tokens)
         self._tables[seq_id] = blocks
         self._lens[seq_id] = num_tokens
         if self._is_guard(seq_id):
@@ -371,6 +625,11 @@ class BlockCacheManager:
         inside the last shared block)."""
         if seq_id in self._tables:
             raise ValueError(f"sequence {seq_id} already allocated")
+        if self._further:
+            raise ValueError(
+                "adopt: shared blocks are the first group's alone; a "
+                f"manager with the groups {self.group_names} has no prefix "
+                "lease yet")
         if len(blocks) > self.max_blocks_per_seq:
             raise SequenceTooLong(len(blocks), self.max_blocks_per_seq)
         if num_tokens > len(blocks) * self.block_size:
@@ -422,16 +681,22 @@ class BlockCacheManager:
         extra = 1 if cow_idx is not None else 0
         if need > 0 and len(table) + need > self.max_blocks_per_seq:
             raise SequenceTooLong(len(table) + need,
-                                  self.max_blocks_per_seq)
+                                  self.max_blocks_per_seq, self.name)
+        for g in self._further:            # all groups grow, or none
+            g.check_append(seq_id, old_len, new_len)
         if max(need, 0) + extra > len(self._free):
             self._ensure_free(max(need, 0) + extra)
         if max(need, 0) + extra > len(self._free):
             raise KVCacheExhausted(max(need, 0) + extra, len(self._free),
-                                   self.num_blocks)
+                                   self.num_blocks, self.name)
         if cow_idx is not None:
             self._cow(seq_id, cow_idx)
         for _ in range(max(need, 0)):
             table.append(self._take_free())
+        for g in self._further:
+            # a windowed group gives back what lies behind its window at
+            # `old_len`, the committed length (see `_BlockGroup`)
+            g.append(seq_id, old_len, new_len)
         self._lens[seq_id] = new_len
 
     def _cow(self, seq_id: int, idx: int) -> int:
@@ -463,31 +728,41 @@ class BlockCacheManager:
         divergent append COWs it."""
         if num_tokens > self._lens[seq_id]:
             raise ValueError("trim can only shrink a sequence")
+        for g in self._further:
+            g.check_trim(seq_id, num_tokens)
         keep = self.blocks_needed(num_tokens)
         table = self._tables[seq_id]
         while len(table) > keep:
             self._release(table.pop())
+        for g in self._further:
+            g.trim(seq_id, num_tokens)
         self._lens[seq_id] = num_tokens
 
     def free(self, seq_id: int) -> None:
         for b in self._tables.pop(seq_id):
             self._release(b)
+        for g in self._further:
+            g.free(seq_id)
         self._lens.pop(seq_id)
         self._guard_ids.discard(seq_id)
 
     def seq_len(self, seq_id: int) -> int:
         return self._lens[seq_id]
 
-    def seq_blocks(self, seq_id: int) -> int:
-        """Number of physical blocks currently leased by `seq_id` (0 for
-        an unknown sequence). Lets the serving watchdog audit for leaks
-        without reaching into private tables."""
+    def seq_blocks(self, seq_id: int, group: int = 0) -> int:
+        """Number of physical blocks of `group` currently leased by
+        `seq_id` (0 for an unknown sequence). Lets the serving watchdog
+        audit for leaks without reaching into private tables."""
+        if group:
+            return self._further[group - 1].seq_blocks(seq_id)
         return len(self._tables.get(seq_id, ()))
 
-    def blocks_of(self, seq_id: int) -> Tuple[int, ...]:
-        """The physical block ids leased by `seq_id` in logical order
-        (empty for an unknown sequence) — the prefix tree's publish
+    def blocks_of(self, seq_id: int, group: int = 0) -> Tuple[int, ...]:
+        """The physical block ids of `group` leased by `seq_id` in logical
+        order (empty for an unknown sequence) — the prefix tree's publish
         input and the leak auditor's unique-set input."""
+        if group:
+            return self._further[group - 1].blocks_of(seq_id)
         return tuple(self._tables.get(seq_id, ()))
 
     def check_consistency(self, external: Optional[Dict[int, int]] = None):
@@ -499,6 +774,10 @@ class BlockCacheManager:
         AssertionError naming the broken invariant (a double-freed
         shared block shows up here as a duplicate free-list entry or a
         refcount mismatch)."""
+        for g in self._further:
+            g.check_consistency()
+            assert set(g._tables) == set(self._tables), \
+                f"group {g.name}: its sequences are not the first group's"
         free = self._free
         assert len(free) == len(set(free)), "duplicate free-list entry"
         assert not (set(free) & set(self._refs)), \
@@ -525,14 +804,21 @@ class BlockCacheManager:
                     f"{self._refs.get(b, 0)}"
 
     def block_table_array(self, seq_ids, pad: int = 0) -> np.ndarray:
-        """Dense [len(seq_ids), max_blocks_per_seq] int32 table.
+        """Dense [len(seq_ids), table_width] int32 table: every group's
+        table of a sequence side by side, `max_blocks_per_seq` columns
+        each (one group: `[n, max_blocks_per_seq]`, as ever).
 
-        `pad` fills entries past each sequence's allocation (default 0).
-        The speculative verify pass pads with the scheduler's guard block
-        so fixed-shape writes past a short lane's allocation land in a
-        sacrificial block instead of physical block 0."""
-        out = np.full((len(seq_ids), self.max_blocks_per_seq), pad, np.int32)
+        `pad` fills entries past each sequence's allocation, and those a
+        windowed group has released (default 0). The speculative verify
+        pass pads with the scheduler's guard block so fixed-shape writes
+        past a short lane's allocation land in a sacrificial block instead
+        of physical block 0."""
+        width = self.max_blocks_per_seq
+        out = np.full((len(seq_ids), self.table_width), pad, np.int32)
         for i, sid in enumerate(seq_ids):
             t = self._tables[sid]
             out[i, :len(t)] = t
+            for n, g in enumerate(self._further, 1):
+                head, t = g._head[sid], g._tables[sid]
+                out[i, n * width + head:n * width + len(t)] = t[head:]
         return out

@@ -1,0 +1,285 @@
+"""Serving engine for the Cohere2-MoE architecture (`models/cohere2_moe.py`):
+an `EngineCore` whose layers are of two kinds that keep different amounts of
+context, so it has TWO paged pools and two block tables a sequence.
+
+- The groups come from the model: `full_attention` layers keep every token
+  (group 0, `"full"`), `sliding_attention` layers only the last
+  `sliding_window` (group 1, `"window"`, whose blocks the manager gives back
+  behind the window: `inference/cache.py`). A model of one kind has one
+  group. Nothing here is an option.
+- The pools are ONE donated tuple `(k_full, v_full, k_window, v_window)`,
+  each `[layers of the kind, blocks of the group, kv heads, block, head]`,
+  written in place and indexed `[layer_in_group, block]`; the layers are
+  unrolled (as the DeepSeek-V3 engine's). The scheduler hands every engine
+  one 2-D table; this one reads its groups' columns of it,
+  `tables[:, g * W:(g + 1) * W]` (`BlockCacheManager.block_table_array`).
+- One attention kernel for both kinds, `paged_attention_ragged`, the window
+  one more prefetched scalar (0 for a full layer); the scopes
+  `llama.attn_window` / `llama.attn_full` tell its calls apart in a trace.
+- The weights are the model's own pytree, by reference; the expert layer
+  holds `config.held_experts` of the router's experts (`[held, in, out]`).
+- `sampled_step` is the one compiled step, ending in the NaN screen and the
+  sampler (`ops/sampling.with_tail`); `ragged_step` is its logits,
+  `verify_step` a case of it and `generate` a host loop over it.
+- Expert load is counted inside the step, on the device, in donated
+  counters; `expert_load()` reads them.
+
+The engine transforms (`quantize_engine`, `shard_engine`, `attach_adapters`)
+look for a Llama or an MLP parameter layout and refuse this engine by its
+name; KV migration is refused here, by family, and the scheduler refuses the
+radix prefix cache and speculative decoding over a windowed group.
+"""
+from __future__ import annotations
+
+import functools
+from typing import Dict
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from ..framework import monitor
+from ..models import cohere2_moe as c2
+from ..ops import sampling
+from ..ops.pallas import paged_attention as pk
+from . import kv_migrate
+from .cache import BlockCacheManager
+from .generate import generate
+
+__all__ = ["Cohere2MoeInferenceEngine"]
+
+FAMILY = "cohere2_moe"
+# a group a layer kind, in the order of the pools and of the table's columns
+GROUPS = ((c2.FULL, "full"), (c2.SLIDING, "window"))
+
+
+def _groups(cfg: c2.Cohere2MoeConfig):
+    """`(kind, name, layers of the kind)` for the kinds the model has."""
+    return tuple((kind, name, cfg.layers_of(kind)) for kind, name in GROUPS
+                 if cfg.layers_of(kind))
+
+
+def _ragged_stack(params, pools, counters, tokens, q_lens, kv_lens, tables,
+                  *, cfg: c2.Cohere2MoeConfig):
+    """Packed tokens `[T]` + per-lane `(q_len, kv_len)` through the decoder:
+    `(logits [T, V] float32, pools, counters)`."""
+    t = tokens.shape[0]
+    kv_lens = kv_lens.astype(jnp.int32)
+    tables = tables.astype(jnp.int32)
+    groups = _groups(cfg)
+    width = tables.shape[1] // len(groups)
+    tok_lane, tok_pos = pk.ragged_metadata(q_lens, kv_lens, t)
+    live = tok_pos >= 0
+    with jax.named_scope("llama.rope"):
+        pos = jnp.maximum(tok_pos, 0)
+        cos = jnp.take(params["rope_cos"], pos, axis=0)
+        sin = jnp.take(params["rope_sin"], pos, axis=0)
+    with jax.named_scope("llama.embed"):
+        x = jnp.take(params["model.embed_tokens.weight"], tokens, axis=0)
+    pools = list(pools)
+    where = {}        # layer -> (its group, its index in the group's pool)
+    for g, (_kind, _name, layers) in enumerate(groups):
+        where.update({layer: (g, n) for n, layer in enumerate(layers)})
+
+    def attend_layer(i, kind):
+        g, n = where[i]
+        table = tables[:, g * width:(g + 1) * width]
+        window = cfg.sliding_window if kind == c2.SLIDING else 0
+        scope = "llama.attn_window" if window else "llama.attn_full"
+
+        def attend(q, k, v):
+            with jax.named_scope("llama.kv_write"):
+                pools[2 * g], pools[2 * g + 1] = pk.write_kv_to_cache_ragged(
+                    k, v, pools[2 * g], pools[2 * g + 1], table, tok_lane,
+                    tok_pos, layer=n)
+            with jax.named_scope(scope):
+                kc, vc = pools[2 * g], pools[2 * g + 1]
+                kernel = pk.paged_attention_ragged if pk.ragged_supported(
+                    q.shape, q.dtype, kc.shape, kc.dtype, width) \
+                    else pk.paged_attention_ragged_ref
+                return kernel(q, kc, vc, table, kv_lens, tok_lane, tok_pos,
+                              layer=n, window=window)
+        return attend
+
+    sizes = []
+    for i, kind in enumerate(cfg.layer_types):
+        x, n = c2.decoder_layer(x, c2.layer_params(params, i), cfg, kind, cos,
+                                sin, attend_layer(i, kind), live)
+        sizes.append(n)
+    sizes = jnp.stack(sizes)                                     # [L, E]
+    first, count = cfg.held
+    counters = {
+        "tokens": counters["tokens"] + sizes,
+        "touched": counters["touched"] + jnp.sum(
+            sizes[:, first:first + count] > 0, axis=1, dtype=jnp.int32),
+        "steps": counters["steps"] + 1,
+    }
+    return c2.head(x, params, cfg), tuple(pools), counters
+
+
+def _ragged_fn(params, pools, counters, tokens, q_lens, kv_lens, tables, *,
+               cfg):
+    # trace-time only, as every engine's: the ragged step IS the serving
+    # decode program, and ragged_retraces pins "one executable whatever the
+    # batch's composition"
+    monitor.inc("serving.decode_retraces")
+    monitor.inc("serving.ragged_retraces")
+    return _ragged_stack(params, pools, counters, tokens, q_lens, kv_lens,
+                         tables, cfg=cfg)
+
+
+def _verify_fn(params, pools, counters, tokens, ctx_lens, tables, *, cfg):
+    """Speculative verify as a case of the ragged step: every lane a fixed
+    window of S tokens; logits fold back to `[B, S, V]`."""
+    monitor.inc("serving.verify_retraces")        # trace-time only
+    b, s = tokens.shape
+    logits, pools, counters = _ragged_stack(
+        params, pools, counters, tokens.reshape(b * s),
+        jnp.full((b,), s, jnp.int32), ctx_lens, tables, cfg=cfg)
+    return logits.reshape(b, s, -1), pools, counters
+
+
+class Cohere2MoeInferenceEngine:
+    """`EngineCore` over `Cohere2MoeForCausalLM` with a pool a layer kind.
+    Serves in the dtype the model's weights have.
+
+    `num_blocks` sizes the pool of the layers that keep every token;
+    `window_blocks` that of the sliding-window layers beside them (a lane
+    holds at most `(window + tokens a step - 2) // block_size + 2` of its
+    blocks at once; by default every lane's whole table fits, plus the
+    guard block)."""
+
+    def __init__(self, model: c2.Cohere2MoeForCausalLM,
+                 max_batch_size: int = 8, num_blocks: int = 256,
+                 block_size: int = 16, max_blocks_per_seq: int = 16,
+                 window_blocks: int = None):
+        cfg = model.config
+        self.config = cfg
+        self.block_size = block_size
+        self.max_batch_size = max_batch_size
+        groups = _groups(cfg)
+        self.group_names = tuple(name for _k, name, _l in groups)
+        kvh, d = cfg.num_key_value_heads, cfg.head_dim
+        cos, sin = c2.rope_tables(cfg, max_blocks_per_seq * block_size)
+        # the model's own arrays, by reference, beside the rope tables
+        self.params: Dict[str, jax.Array] = dict(
+            model.weight_tree(), rope_cos=cos, rope_sin=sin)
+        cdtype = self.params["model.embed_tokens.weight"].dtype
+        # the first group keeps every block (a model of sliding layers
+        # alone has one group, which the kernel's window still bounds);
+        # the window group beside a full one releases behind its window
+        if window_blocks is None:
+            window_blocks = max_batch_size * max_blocks_per_seq + 1
+        sizes = [(groups[0][1], num_blocks, None)] + [
+            (name, window_blocks, cfg.sliding_window)
+            for _kind, name, _layers in groups[1:]]
+        self.manager = BlockCacheManager(
+            num_blocks, block_size, max_blocks_per_seq, name=sizes[0][0],
+            further_groups=sizes[1:])
+        itemsize = jnp.dtype(cdtype).itemsize
+        pools = []
+        self._group_bytes_per_token = {}
+        for g, ((_kind, name, layers), (_n, nb, _w)) in enumerate(
+                zip(groups, sizes)):
+            shape = (len(layers), nb, kvh, block_size, d)
+            pools += [jnp.zeros(shape, cdtype), jnp.zeros(shape, cdtype)]
+            per_token = 2 * len(layers) * kvh * d * itemsize
+            self._group_bytes_per_token[name] = per_token
+            self.manager.set_kv_geometry(per_token * block_size, 16, group=g)
+        self.pools = tuple(pools)
+        L, e = cfg.num_hidden_layers, cfg.num_experts
+        self.counters = {"tokens": jnp.zeros((L, e), jnp.int32),
+                         "touched": jnp.zeros((L,), jnp.int32),
+                         "steps": jnp.zeros((), jnp.int32)}
+
+        def step(fn, wrap=lambda f: f):
+            bound = functools.partial(fn, cfg=cfg)
+            bound.__name__ = fn.__name__           # the XLA module's name
+            return jax.jit(wrap(bound), donate_argnums=(1, 2))
+
+        # the screen, the row gather and the sampler end the step's one
+        # program (`ops/sampling.with_tail`)
+        self._ragged = step(_ragged_fn, sampling.with_tail)
+        self._verify = step(_verify_fn)
+
+    # ---- the EngineCore dispatch surface ----
+    def sampled_step(self, tokens: np.ndarray, lanes: np.ndarray,
+                     block_tables: np.ndarray, temperature: np.ndarray):
+        """ONE fixed-shape step over a packed ragged batch, sampled (see
+        `EngineCore.sampled_step`): `(sampled [2, B] int32, logits [T, V]
+        float32)`, both on the device. `block_tables` `[B, n_groups * W]`:
+        every group's table of a lane, side by side."""
+        sampled, logits, self.pools, self.counters = self._ragged(
+            self.params, self.pools, self.counters,
+            *sampling.call_arrays(tokens, lanes, block_tables, temperature))
+        return sampled, logits
+
+    ragged_step = sampling.ragged_step
+
+    def verify_step(self, tokens: np.ndarray, context_lens: np.ndarray,
+                    block_tables: np.ndarray):
+        """Multi-token verify (see `EngineCore.verify_step`): `[B, S, V]`."""
+        logits, self.pools, self.counters = self._verify(
+            self.params, self.pools, self.counters,
+            np.asarray(tokens, np.int32), np.asarray(context_lens, np.int32),
+            np.asarray(block_tables, np.int32))
+        return logits
+
+    generate = generate
+
+    # ---- hooks the scheduler and the cache manager look for ----
+    def kv_bytes_per_token(self, group: str = None) -> float:
+        """HBM bytes one cached token costs in `group`'s pool (K + V over
+        the group's layers). Without a group: what a token costs for as
+        long as its sequence lives, the first group's; a windowed group's
+        bytes are a token's only while it lies inside the window, and are
+        reported under the group's own name (`quant_info`)."""
+        return float(self._group_bytes_per_token[
+            self.group_names[0] if group is None else group])
+
+    def quant_info(self) -> dict:
+        """What `serving.quant.*` and `serving.kv_bytes_per_token[.<group>]`
+        publish."""
+        return {"wbits": 16, "kv_bits": 16,
+                "kv_bytes_per_token": self.kv_bytes_per_token(),
+                "kv_bytes_per_token_by_group": dict(
+                    self._group_bytes_per_token)}
+
+    def cost_card_args(self, phase: str):
+        fn = {"decode": self._ragged, "ragged": self._ragged,
+              "verify": self._verify}[phase]
+        return fn, (self.params, self.pools, self.counters)
+
+    def extract_kv_blocks(self, seq_id: int):
+        raise kv_migrate.KVMigrationError(
+            f"{FAMILY}: two pools of different geometry (a window's and a "
+            "context's) have no migration payload yet")
+
+    def inject_kv_blocks(self, seq_id: int, payload) -> None:
+        raise kv_migrate.KVMigrationError(
+            f"{FAMILY}: two pools of different geometry (a window's and a "
+            "context's) have no migration payload yet")
+
+    # ---- expert load ----
+    def expert_load(self) -> dict:
+        """The counters the step keeps on the device, fetched now: `tokens
+        [L, E]` routed to each of the ROUTER's experts since the engine was
+        built (held and absent alike), `touched [L]` HELD experts with at
+        least one token summed over steps, `steps`. Publishes
+        `serving.moe.expert_tokens` (assignments that fell on a held
+        expert), `serving.moe.held_assignment_share` (their share of all
+        assignments) and the gauge `serving.moe.load_max_over_mean`
+        (busiest held expert of a layer against the mean one)."""
+        c = jax.device_get(self.counters)
+        tokens = np.asarray(c["tokens"], np.int64)
+        first, count = self.config.held
+        mine = tokens[:, first:first + count]
+        monitor.set_value("serving.moe.expert_tokens", int(mine.sum()))
+        if tokens.sum():
+            monitor.set_gauge("serving.moe.held_assignment_share",
+                              round(float(mine.sum() / tokens.sum()), 4))
+        if mine.sum():
+            monitor.set_gauge("serving.moe.load_max_over_mean",
+                              round(float(mine.max() / mine.mean()), 3))
+        return {"tokens": tokens, "touched": np.asarray(c["touched"], np.int64),
+                "steps": int(c["steps"]), "held": (first, count)}

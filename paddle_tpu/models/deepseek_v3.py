@@ -271,7 +271,8 @@ def route(x, p, cfg: DeepseekV3Config):
     return experts.astype(jnp.int32), w * cfg.routed_scaling_factor
 
 
-def routed_experts(x, experts, weights, live, p, cfg: DeepseekV3Config):
+def routed_experts(x, experts, weights, live, p, cfg,
+                   held: Optional[Tuple[int, int]] = None):
     """The routed experts without capacity or drops. `x [T, H]`, `experts` /
     `weights [T, k]`, `live [T]` bool (a row that is not live reaches no
     expert and gets zeros). Rows are sorted by expert, the experts' SwiGLUs
@@ -280,20 +281,37 @@ def routed_experts(x, experts, weights, live, p, cfg: DeepseekV3Config):
     `jax.lax.ragged_dot`; an expert with no row is not computed and its
     matrices are not read), and each token's k answers are combined by
     weight. Returns `(out [T, H], tokens_per_expert [E] int32)`.
+
+    `held = (first, count)`: the layer is told which experts it holds (the
+    chip's share under expert parallelism): `p`'s stacked matrices are those
+    of experts `first .. first + count - 1` alone, `[count, in, out]`. The
+    router keeps its `cfg.n_routed_experts` outputs and its choice; an
+    assignment to an expert that is not held is what a dead row is: sorted
+    last, never computed, adding nothing, so `out` is the part of the
+    layer's result that the held experts give. `tokens_per_expert` counts
+    held and absent alike. None: every expert is held, and the program is
+    what it was before the layer could be told.
     """
     t, k = experts.shape
     e = cfg.n_routed_experts
+    first, count = (0, e) if held is None else held
     with _scope("llama.moe_dispatch"):
         flat = jnp.where(jnp.repeat(live, k), experts.reshape(t * k),
                          jnp.int32(e))                  # dead rows sort last
-        order = jnp.argsort(flat).astype(jnp.int32)     # [T*k] sorted -> flat
         sizes = jnp.zeros((e + 1,), jnp.int32).at[flat].add(1)[:e]
+        if (first, count) == (0, e):
+            here, mine = None, sizes
+        else:
+            here = (flat >= first) & (flat < first + count)      # [T*k]
+            flat = jnp.where(here, flat - first, jnp.int32(count))
+            mine = sizes[first:first + count]
+        order = jnp.argsort(flat).astype(jnp.int32)     # [T*k] sorted -> flat
         xs = jnp.take(x, order // k, axis=0)            # [T*k, H]
     with _scope("llama.moe_experts"):
         def rd(a, w):
             if grouped_matmul.supported(w.shape, w.dtype):
-                return grouped_matmul.grouped_matmul(a, w, sizes)
-            return jax.lax.ragged_dot(a, w.astype(a.dtype), sizes,
+                return grouped_matmul.grouped_matmul(a, w, mine)
+            return jax.lax.ragged_dot(a, w.astype(a.dtype), mine,
                                       preferred_element_type=jnp.float32)
 
         g = rd(xs, p["mlp.experts.gate_proj.weight"])
@@ -306,8 +324,10 @@ def routed_experts(x, experts, weights, live, p, cfg: DeepseekV3Config):
         y = jnp.take(y, back, axis=0).reshape(t, k, -1)
         # rows past the groups' sum are never written by the grouped
         # matmul: select, never multiply, what a dead row holds
-        w = jnp.where(live[:, None], weights, 0.0)[..., None]
-        out = jnp.sum(jnp.where(live[:, None, None], y, 0.0) * w, axis=1)
+        keep = live[:, None] if here is None \
+            else live[:, None] & here.reshape(t, k)
+        w = jnp.where(keep, weights, 0.0)[..., None]
+        out = jnp.sum(jnp.where(keep[..., None], y, 0.0) * w, axis=1)
     return out.astype(x.dtype), sizes
 
 

@@ -98,8 +98,8 @@ def _ragged_tiles(tokens, kv_h, g_pad, d, block_size, width, kv_itemsize):
     return pages, chunks * _RAGGED_Q_CHUNK
 
 
-def _ragged_kernel(layer_ref, kv_lens_ref, q_lens_ref, q_starts_ref,
-                   tables_ref, q_hbm, k_hbm, v_hbm, *rest, sm_scale,
+def _ragged_kernel(layer_ref, window_ref, kv_lens_ref, q_lens_ref,
+                   q_starts_ref, tables_ref, q_hbm, k_hbm, v_hbm, *rest, sm_scale,
                    block_size, pages, q_tile, g_pad, quantized, mxu_dtype):
     """Ragged paged attention: ONE fixed-shape kernel for mixed
     prefill-chunk + decode + verify batches, whose work follows the live
@@ -132,7 +132,14 @@ def _ragged_kernel(layer_ref, kv_lens_ref, q_lens_ref, q_starts_ref,
       time, double-buffered: one DMA brings a page for ALL kv heads (it
       is contiguous in the pool), so a page is fetched once per tile.
       The tail group's missing pages re-fetch the last live one and are
-      masked, so no dead page is ever read;
+      masked, so no dead page is ever read. A sliding-window layer
+      (`window_ref[0]` > 0, one more prefetched scalar: a query sees the
+      `window` positions that end at its own) bounds the walk from the
+      other end: it starts at the page group that holds the tile's first
+      query's oldest visible position, and that group's pages behind it
+      re-fetch the first live one and are masked, so a page the cache
+      manager has released behind the window is never read. With window 0
+      the walk, the DMAs and every row's arithmetic are what they were;
     - per kv head the [rows, D] x [D, pages*BS] score tile and the
       [rows, pages*BS] x [pages*BS, D] update (rows = qc * g_pad) run per
       live chunk with the online softmax in f32 (m/l lane-replicated
@@ -140,7 +147,8 @@ def _ragged_kernel(layer_ref, kv_lens_ref, q_lens_ref, q_starts_ref,
       the MXU in `mxu_dtype`: the operands' own bf16 when q and the pool
       are bf16/int8 (products of bf16 values are exact in the f32
       accumulator), f32 otherwise; `sm_scale` is applied to the f32
-      scores. Causal mask per row: `kv_pos <= position` and < kv_len.
+      scores. Causal mask per row: `kv_pos <= position` and < kv_len,
+      and `kv_pos > position - window` in a window layer.
 
     Output rows of a tile's last chunk past the lane's tokens are written
     as zeros; the next lane, processed after it, overwrites the ones it
@@ -163,9 +171,13 @@ def _ragged_kernel(layer_ref, kv_lens_ref, q_lens_ref, q_starts_ref,
     i32 = jnp.int32
     b = pl.program_id(0)
     layer = layer_ref[0]
+    window = window_ref[0]
     kv_len = kv_lens_ref[b]
     q_len = q_lens_ref[b]
     q_start = q_starts_ref[b]
+    # how far behind itself a query sees: its window, or (window 0) further
+    # than any position lies
+    reach = jnp.where(window > 0, window, i32(1 << 30))
 
     def lane(qc, n_tiles):
         """`n_tiles` tiles of the lane at `qc` query tokens a compute
@@ -194,6 +206,9 @@ def _ragged_kernel(layer_ref, kv_lens_ref, q_lens_ref, q_starts_ref,
             pos0 = kv_len - q_len + t0    # its absolute position
             n_pages = pl.cdiv(pos0 + n_tok, i32(block_size))
             n_groups = pl.cdiv(n_pages, i32(pages))
+            # the first page the tile's first query still sees, and its group
+            page0 = jnp.maximum(pos0 - reach + 1, 0) // i32(block_size)
+            group0 = page0 // i32(pages)
 
             def q_copy(c):
                 return pltpu.make_async_copy(
@@ -208,7 +223,7 @@ def _ragged_kernel(layer_ref, kv_lens_ref, q_lens_ref, q_starts_ref,
 
             def page_copies(g, slot):
                 for p in range(pages):
-                    j = jnp.minimum(g * i32(pages) + i32(p), n_pages - 1)
+                    j = jnp.clip(g * i32(pages) + i32(p), page0, n_pages - 1)
                     blk = tables_ref[b, j]
                     yield pltpu.make_async_copy(k_hbm.at[layer, blk],
                                                 kbuf.at[slot, p],
@@ -218,7 +233,7 @@ def _ragged_kernel(layer_ref, kv_lens_ref, q_lens_ref, q_starts_ref,
                                                 sem.at[1, slot])
 
             chunk_loop(n_chunks, lambda c: q_copy(c).start())
-            for cp in page_copies(i32(0), 0):
+            for cp in page_copies(group0, 0):
                 cp.start()
 
             def init(c):
@@ -233,7 +248,7 @@ def _ragged_kernel(layer_ref, kv_lens_ref, q_lens_ref, q_starts_ref,
             chunk_loop(n_chunks, lambda c: q_copy(c).wait())
 
             def group(g, _):
-                slot = g % 2
+                slot = (g - group0) % 2
 
                 @pl.when(g + 1 < n_groups)
                 def _prefetch():
@@ -261,8 +276,9 @@ def _ragged_kernel(layer_ref, kv_lens_ref, q_lens_ref, q_starts_ref,
                             preferred_element_type=jnp.float32) * sm_scale
                         if quantized:
                             s = s * ks
-                        live = kv_pos <= jnp.minimum(
-                            pos0 + c * i32(qc) + tok, kv_len - 1)
+                        pos = jnp.minimum(pos0 + c * i32(qc) + tok,
+                                          kv_len - 1)
+                        live = (kv_pos <= pos) & (kv_pos > pos - reach)
                         # typed scalars: python numbers weak-type to 64 bits
                         # when the interpret-mode kernel is traced inside an
                         # x64-on outer program
@@ -282,7 +298,7 @@ def _ragged_kernel(layer_ref, kv_lens_ref, q_lens_ref, q_starts_ref,
 
                     chunk_loop(n_chunks, chunk)
 
-            jax.lax.fori_loop(0, n_groups, group, None)
+            jax.lax.fori_loop(group0, n_groups, group, None)
 
             def finish(c):
                 ts, rs = toks(c), band(c)
@@ -312,11 +328,12 @@ def _ragged_kernel(layer_ref, kv_lens_ref, q_lens_ref, q_starts_ref,
     lane(_RAGGED_Q_CHUNK, jnp.where(decode, i32(0), n_tiles))
 
 
-def _ragged_call(q, k_cache, v_cache, layer, block_tables, kv_lens, q_lens,
-                 q_starts, sm_scale, tiles, mxu_dtype, k_scale=None,
+def _ragged_call(q, k_cache, v_cache, layer, window, block_tables, kv_lens,
+                 q_lens, q_starts, sm_scale, tiles, mxu_dtype, k_scale=None,
                  v_scale=None):
     """q: f32 [T + chunk, KV_H, Gp, D] packed tokens; caches as stored,
-    [L, NB, KV_H, BS, D], and `layer` int32 [1], which of them to read
+    [L, NB, KV_H, BS, D], `layer` int32 [1], which of them to read, and
+    `window` int32 [1], the layer's sliding window (0: none)
     (int8 when the per-lane f32 scale windows [B, KV_H, groups, pages*BS]
     ride along). Returns f32, q's shape."""
     tokens, kv_h, g_pad, d = q.shape
@@ -329,18 +346,19 @@ def _ragged_call(q, k_cache, v_cache, layer, block_tables, kv_lens, q_lens,
     operands = [q, k_cache, v_cache]
     in_specs = [hbm, hbm, hbm]
     if k_scale is not None:
-        window = pl.BlockSpec(
+        scale_rows = pl.BlockSpec(
             (1,) + k_scale.shape[1:],
-            lambda b, layer, lens, qlens, starts, tables: (b, 0, 0, 0))
+            lambda b, layer, window, lens, qlens, starts, tables:
+            (b, 0, 0, 0))
         operands += [k_scale, v_scale]
-        in_specs += [window, window]
+        in_specs += [scale_rows, scale_rows]
     # the output buffer starts as zeros: guard rows are never written
     operands.append(jnp.zeros(q.shape, jnp.float32))
     in_specs.append(hbm)
     page_buf = pltpu.VMEM((2, pages, kv_h, block_size, d), k_cache.dtype)
     lm = pltpu.VMEM((kv_h, rows, 128), jnp.float32)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=5,
+        num_scalar_prefetch=6,
         grid=(lanes,),
         in_specs=in_specs,
         out_specs=hbm,
@@ -359,12 +377,12 @@ def _ragged_call(q, k_cache, v_cache, layer, block_tables, kv_lens, q_lens,
                           mxu_dtype=mxu_dtype),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(q.shape, jnp.float32),
-        input_output_aliases={5 + len(operands) - 1: 0},
+        input_output_aliases={6 + len(operands) - 1: 0},
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         name="paged_attention_ragged",
         interpret=_support.interpret_mode(),
-    )(layer, kv_lens, q_lens, q_starts, block_tables, *operands)
+    )(layer, window, kv_lens, q_lens, q_starts, block_tables, *operands)
 
 
 def _layered(layer, k_cache, *pools):
@@ -403,9 +421,15 @@ def ragged_metadata(q_lens, kv_lens, num_tokens):
     return lane, jnp.where(valid, pos, jnp.int32(-1))
 
 
+def _window_scalar(window):
+    """`window` (None, an int or a traced scalar) as int32 []; 0 = none."""
+    return jnp.asarray(0 if window is None else window, jnp.int32)
+
+
 def paged_attention_ragged(q, k_cache, v_cache, block_tables, kv_lens,
                            tok_lane, tok_pos, sm_scale=None,
-                           k_scale=None, v_scale=None, layer=None):
+                           k_scale=None, v_scale=None, layer=None,
+                           window=None):
     """Ragged paged attention over a packed query token buffer.
 
     ONE kernel for every serving batch composition: decode lanes
@@ -440,6 +464,12 @@ def paged_attention_ragged(q, k_cache, v_cache, block_tables, kv_lens,
       layer: which layer of a 5-D pool, an int or a traced int32 scalar
          (one compiled kernel serves every layer: it rides scalar
          prefetch, and a page's DMA source is `pool[layer, block]`).
+      window: a sliding-window layer's window, an int or a traced int32
+         scalar (it rides scalar prefetch too: one compiled kernel for
+         window and full layers): a query at position i sees the keys at
+         `i - window < j <= i`, and table entries of pages wholly behind
+         the window of the lane's first query are never read (the cache
+         manager has released them). None or 0: every key up to i.
     Returns [T, H, D]; guard rows are exact zeros.
     """
     layer, k_cache, v_cache, k_scale, v_scale = _layered(
@@ -475,14 +505,15 @@ def paged_attention_ragged(q, k_cache, v_cache, block_tables, kv_lens,
         groups = -(-width // pages)
         padded = jnp.pad(block_tables, ((0, 0), (0, groups * pages - width)))
 
-        def window(scale):
+        def by_lane(scale):
             return jnp.swapaxes(scale[layer, padded], 1, 2) \
                 .reshape(lanes, kv_h, groups, pages * block_size)
 
-        k_scale, v_scale = window(k_scale), window(v_scale)
+        k_scale, v_scale = by_lane(k_scale), by_lane(v_scale)
     exact_bf16 = q.dtype == jnp.bfloat16 and k_cache.dtype in (
         jnp.bfloat16, jnp.int8)
-    out = _ragged_call(qg, k_cache, v_cache, layer.reshape(1), block_tables,
+    out = _ragged_call(qg, k_cache, v_cache, layer.reshape(1),
+                       _window_scalar(window).reshape(1), block_tables,
                        kv_lens.astype(jnp.int32), q_lens, q_starts,
                        float(sm_scale), tiles,
                        jnp.bfloat16 if exact_bf16 else jnp.float32,
@@ -498,7 +529,8 @@ _REF_TOKEN_TILE = 128
 
 def paged_attention_ragged_ref(q, k_cache, v_cache, block_tables, kv_lens,
                                tok_lane, tok_pos, sm_scale=None,
-                               k_scale=None, v_scale=None, layer=None):
+                               k_scale=None, v_scale=None, layer=None,
+                               window=None):
     """XLA reference for the ragged kernel (also the CPU fallback).
 
     Same gather + masked-softmax structure as `paged_attention_ref`, per
@@ -510,9 +542,10 @@ def paged_attention_ragged_ref(q, k_cache, v_cache, block_tables, kv_lens,
     `k_scale`/`v_scale` (f32 [(L,) NB, KVH, BS]) mark int8 quantized
     caches: the gathered per-lane windows dequantize right after the
     gather — only the gathered window is ever materialized in float,
-    never the pool. Pools and `layer` as `paged_attention_ragged` takes
-    them: the windows are gathered at `[layer, block]`, the layer never
-    sliced out."""
+    never the pool. Pools, `layer` and `window` as
+    `paged_attention_ragged` takes them: the windows are gathered at
+    `[layer, block]`, the layer never sliced out; what a released table
+    entry points at is gathered and masked."""
     layer, k_cache, v_cache, k_scale, v_scale = _layered(
         layer, k_cache, v_cache, k_scale, v_scale)
     tokens, h, d = q.shape
@@ -531,6 +564,8 @@ def paged_attention_ragged_ref(q, k_cache, v_cache, block_tables, kv_lens,
     k = jnp.swapaxes(k, 2, 3).reshape(block_tables.shape[0], max_s, kv_h, d)
     v = jnp.swapaxes(v, 2, 3).reshape(block_tables.shape[0], max_s, kv_h, d)
     wpos = jnp.arange(max_s, dtype=jnp.int32)
+    window = _window_scalar(window)
+    reach = jnp.where(window > 0, window, jnp.int32(1 << 30))
 
     def tile(args):
         qg, lane, pos = args                      # [t, KV_H, G, D] / [t]
@@ -539,7 +574,8 @@ def paged_attention_ragged_ref(q, k_cache, v_cache, block_tables, kv_lens,
         s = jnp.einsum("thgd,tshd->thgs", qg.astype(jnp.float32),
                        kt.astype(jnp.float32),
                        preferred_element_type=jnp.float32) * sm_scale
-        mask = wpos[None, :] <= pos[:, None]                 # [t, max_s]
+        mask = (wpos[None, :] <= pos[:, None]) \
+            & (wpos[None, :] > pos[:, None] - reach)         # [t, max_s]
         s = jnp.where(mask[:, None, None, :], s, NEG_INF)
         p = jax.nn.softmax(s, axis=-1)
         out = jnp.einsum("thgs,tshd->thgd", p, vt.astype(jnp.float32))

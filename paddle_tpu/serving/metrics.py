@@ -216,6 +216,14 @@ class ServingMetrics:
         if bpt is not None:
             monitor.set_gauge("serving.kv_bytes_per_token",
                               round(float(bpt), 1))
+        # an engine with several block groups: what a token costs in each,
+        # under the group's name (a windowed group's bytes are a token's
+        # only while it lies inside the window; the plain gauge above is
+        # the first group's, what a token costs for as long as it lives)
+        for group, b in (info.get("kv_bytes_per_token_by_group")
+                         or {}).items():
+            monitor.set_gauge(f"serving.kv_bytes_per_token.{group}",
+                              round(float(b), 1))
 
     # ---- multi-LoRA serving ----
     def on_lora(self, info: dict):

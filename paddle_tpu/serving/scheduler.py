@@ -352,6 +352,21 @@ class Scheduler:
             except ValueError:
                 pad_id -= 1
         self._pad_seq_id = pad_id
+        # an engine whose layer kinds keep different amounts of context
+        # has further block groups (`BlockCacheManager`): the guard leases
+        # one block in each, and what is built on ONE table a sequence
+        # refuses such an engine by name
+        self._further_pads = [(g, mgr.blocks_of(pad_id, g)[0])
+                              for g in range(1, mgr.n_groups)]
+        if self._further_pads and (self._prefix_enabled
+                                   or self.spec is not None):
+            what = "the radix prefix cache" if self._prefix_enabled \
+                else "speculative decoding"
+            raise ValueError(
+                f"{type(self.engine).__name__}: {what} over the block "
+                f"groups {mgr.group_names} is not implemented: a windowed "
+                "group releases blocks a shared prefix or a verify window's "
+                "rollback would have to keep")
         # What one sequence can ever hold: pool minus the guard (and minus
         # blocks other users of a shared engine already lease).
         self._usable_blocks = min(mgr.free_blocks, mgr.max_blocks_per_seq)
@@ -442,6 +457,13 @@ class Scheduler:
         # +1: the sequence must be able to hold at least one generated token
         if mgr.blocks_needed(len(req.prompt) + 1) > self._usable_blocks:
             return self._reject(req, "prompt_too_long")
+        for g, _pad in self._further_pads:
+            # a further group holds a window's worth of a prompt at most,
+            # and that has to fit its pool beside the guard block
+            if mgr.blocks_needed(len(req.prompt) + 1, g,
+                                 self.prefill_chunk_tokens) \
+                    > mgr.num_blocks_of(g) - 1:
+                return self._reject(req, "prompt_too_long")
         if req.adapter is not None:
             # typed submit-time rejection beats an admission-time fault:
             # an unknown adapter can never become leasable by waiting
@@ -1220,7 +1242,7 @@ class Scheduler:
             need = 0 if resident \
                 else mgr.blocks_needed(len(ctx)) - hit_blocks
             headroom = mgr.free_blocks + mgr.reclaimable_blocks() - debt
-            if need > headroom:
+            if need > headroom or self._further_short(mgr, ctx):
                 break                  # blocks return as runners finish
             if self._slo is not None:
                 reserve = self._slo.total_reserve_excluding(
@@ -1339,6 +1361,22 @@ class Scheduler:
                                   if req.t_submit is not None else None)
         return admitted
 
+    def _further_short(self, mgr, ctx) -> bool:
+        """The admission test of `_admit` in every FURTHER block group
+        (none for an engine of one layer kind): what the context holds of
+        the group at once, a window's worth at most, against the group's
+        free blocks less what the lanes still prefilling will take."""
+        step = self.prefill_chunk_tokens
+        for g, _pad in self._further_pads:
+            debt = sum(
+                max(0, mgr.blocks_needed(len(r._prefill_ctx), g, step)
+                    - mgr.seq_blocks(r.seq_id, g))
+                for r in self.slots if r is not None and r.prefilling)
+            if mgr.blocks_needed(len(ctx), g, step) \
+                    > mgr.free_blocks_of(g) - debt:
+                return True
+        return False
+
     def _grow_chunk(self, req: Request, slot: int, want: int) -> int:
         """Reserve cache slots for the next `want` prefill-chunk tokens.
         Under pool pressure the chunk shrinks to what the free pool (plus
@@ -1369,17 +1407,28 @@ class Scheduler:
                 # block), plus whatever the free pool still has
                 slack = mgr.seq_blocks(req.seq_id) * mgr.block_size \
                     - mgr.seq_len(req.seq_id)
-                fit = mgr.free_blocks * mgr.block_size + slack
+                # `e.free`: what the group that ran out (`e.group`) has
+                fit = e.free * mgr.block_size + slack
                 if 1 <= fit < want:
                     want = fit
                     continue
                 if _obs.enabled():
                     self._obs_oom("kv_exhausted", need=e.need, free=e.free,
-                                  total=e.total, seq_id=req.seq_id)
+                                  total=e.total, seq_id=req.seq_id,
+                                  group=e.group)
                 if not self._preempt_one(exclude=req):
                     # sole lane over an externally-held pool: wait (the
                     # stall detectors own the pathological case)
                     return 0
+
+    def _pad_tables(self, mgr, lanes: int) -> np.ndarray:
+        """`[lanes, table_width]` block tables that point every entry at
+        the guard block (of its own group, where the engine has several)."""
+        tables = np.full((lanes, mgr.table_width), self._pad_block, np.int32)
+        for g, pad in self._further_pads:
+            w = mgr.max_blocks_per_seq
+            tables[:, g * w:(g + 1) * w] = pad
+        return tables
 
     @staticmethod
     def _sampling_arrays(reqs):
@@ -1496,8 +1545,7 @@ class Scheduler:
             tokens = np.zeros((T,), np.int32)
             q_lens = np.zeros((B,), np.int32)
             kv_lens = np.zeros((B,), np.int32)
-            tables = np.full((B, mgr.max_blocks_per_seq), self._pad_block,
-                             np.int32)
+            tables = self._pad_tables(mgr, B)
             rows = np.zeros((B,), np.int32)   # last packed row per lane
             decode_set = {i for i, _r in decode_lanes}
             chunk_of = {i: (n, p) for i, _r, n, p in chunks}
@@ -1554,8 +1602,7 @@ class Scheduler:
             q[i] = n
             kv = np.zeros((B,), np.int32)
             kv[i] = kv_lens[i]
-            tb = np.full((B, mgr.max_blocks_per_seq), self._pad_block,
-                         np.int32)
+            tb = self._pad_tables(mgr, B)
             tb[i] = tables[i]
             # the lane's WHOLE packed band: a NaN confined to an earlier
             # chunk row must still convict this lane (the caller's

@@ -765,6 +765,67 @@ def test_llama_ragged_step_holds_one_attention_kernel(v5e_ragged_step):
     assert sorted(re.sub(r"[.\d]+$", "", c) for c in calls) == want
 
 
+def test_cohere2_moe_step_compiles_for_v5e_at_the_cell_s_size(v5e_chip):
+    """The Command A+ engine's whole ragged step at the benchmark cell's
+    size (4 layers at published widths, 16 of 128 experts held, a full pool
+    of 9,600 blocks and a window pool of 2,337, 32 lanes + a 512-token
+    chunk), compiled by the installed libtpu for a v5e from shapes alone: a
+    16-row GQA band in the ragged kernel, two pools with two tables, the
+    window as a prefetched scalar. Both pools are aliased to their outputs,
+    the step's temporaries stay far under a pool, and weights + pools +
+    logits fit the chip."""
+    import functools
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.inference import cohere2_moe_runner as cr
+    from paddle_tpu.models import cohere2_moe as c2
+    from paddle_tpu.ops import sampling
+    from paddle_tpu.ops.pallas import _support
+
+    cfg = c2.Cohere2MoeConfig(vocab_size=32768, num_hidden_layers=4,
+                              layer_types=(c2.SLIDING,) * 3 + (c2.FULL,),
+                              held_experts=(0, 16))
+    lanes, tokens, width = 32, 32 + 512, 640
+
+    def arr(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=v5e_chip)
+
+    params = {k: arr(s) for k, (s, _) in c2.param_shapes(cfg).items()}
+    params["rope_cos"] = params["rope_sin"] = arr((width * 64, 64), jnp.float32)
+    pools = (arr((1, 9600, 8, 64, 128)),) * 2 + (arr((3, 2337, 8, 64, 128)),) * 2
+    counters = {"tokens": arr((4, 128), jnp.int32),
+                "touched": arr((4,), jnp.int32), "steps": arr((), jnp.int32)}
+    step = sampling.with_tail(functools.partial(cr._ragged_fn, cfg=cfg))
+    ints = [arr((tokens,), jnp.int32),
+            arr((lanes, len(sampling.LANE_COLS)), jnp.int32),
+            arr((lanes, 2 * width), jnp.int32), arr((lanes,), jnp.float32)]
+    cache_was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        with pytest.MonkeyPatch.context() as mp:
+            mp.setattr(_support, "backend", lambda: "tpu")
+            lowered = jax.jit(step, donate_argnums=(1, 2)).trace(
+                params, pools, counters, *ints).lower(
+                    lowering_platforms=("tpu",))
+            compiled = lowered.compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache_was)
+    kernels = re.findall(r'kernel_name = "(\w+)"', lowered.as_text())
+    assert sorted(set(kernels)) == ["kv_write_ragged", "moe_grouped_matmul",
+                                    "paged_attention_ragged"]
+    assert kernels.count("paged_attention_ragged") == 4      # one a layer
+    mem = compiled.memory_analysis()
+    pool_bytes = sum(int(np.prod(p.shape)) * 2 for p in pools)
+    assert mem.alias_size_in_bytes >= pool_bytes
+    assert mem.temp_size_in_bytes < 256 << 20, mem.temp_size_in_bytes
+    held = mem.argument_size_in_bytes + mem.output_size_in_bytes \
+        - mem.alias_size_in_bytes + mem.temp_size_in_bytes
+    assert held < 14.5e9, held          # of the chip's 16.9 GB
+
+
 def test_gate_closes_for_gspmd_partitioned_operands():
     """JAX refuses to lower a Mosaic kernel inside a GSPMD-partitioned
     program ("Mosaic kernels cannot be automatically partitioned"), so the

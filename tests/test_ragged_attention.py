@@ -539,7 +539,8 @@ class TestDecodeTile:
         qg = jnp.pad(q.reshape(t, kvh, g, d).astype(jnp.float32),
                      ((0, 0), (0, 0), (0, 8 - g), (0, 0)))
         out = pa._ragged_call(
-            qg, kc[None], vc[None], jnp.zeros((1,), jnp.int32), tables, kv,
+            qg, kc[None], vc[None], jnp.zeros((1,), jnp.int32),
+            jnp.zeros((1,), jnp.int32), tables, kv,       # layer 0, no window
             jnp.ones((t,), jnp.int32), jnp.arange(t, dtype=jnp.int32),
             1.0 / float(np.sqrt(d)), tiles,
             jnp.float32 if kind == "f32" else jnp.bfloat16)
