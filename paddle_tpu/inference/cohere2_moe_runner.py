@@ -42,7 +42,7 @@ from ..framework import monitor
 from ..models import cohere2_moe as c2
 from ..ops import sampling
 from ..ops.pallas import paged_attention as pk
-from . import kv_migrate
+from . import kv_migrate, live_prefix
 from .cache import BlockCacheManager
 from .generate import generate
 
@@ -60,9 +60,12 @@ def _groups(cfg: c2.Cohere2MoeConfig):
 
 
 def _ragged_stack(params, pools, counters, tokens, q_lens, kv_lens, tables,
-                  *, cfg: c2.Cohere2MoeConfig):
+                  *, cfg: c2.Cohere2MoeConfig, narrow: bool = False):
     """Packed tokens `[T]` + per-lane `(q_len, kv_len)` through the decoder:
-    `(logits [T, V] float32, pools, counters)`."""
+    `(logits [T, V] float32, pools, counters)`. `narrow`: a step whose live
+    rows number at most its lanes runs the layers' row-wise segments over
+    that prefix of the packed buffer (`live_prefix.rowwise`; the choice is
+    made on the device, from `q_lens`)."""
     t = tokens.shape[0]
     kv_lens = kv_lens.astype(jnp.int32)
     tables = tables.astype(jnp.int32)
@@ -70,6 +73,9 @@ def _ragged_stack(params, pools, counters, tokens, q_lens, kv_lens, tables,
     width = tables.shape[1] // len(groups)
     tok_lane, tok_pos = pk.ragged_metadata(q_lens, kv_lens, t)
     live = tok_pos >= 0
+    lanes = q_lens.shape[0]
+    n_live = jnp.sum(q_lens.astype(jnp.int32))
+    rowwise = live_prefix.rowwise(n_live, lanes if narrow else None, t)
     with jax.named_scope("llama.rope"):
         pos = jnp.maximum(tok_pos, 0)
         cos = jnp.take(params["rope_cos"], pos, axis=0)
@@ -104,7 +110,7 @@ def _ragged_stack(params, pools, counters, tokens, q_lens, kv_lens, tables,
     sizes = []
     for i, kind in enumerate(cfg.layer_types):
         x, n = c2.decoder_layer(x, c2.layer_params(params, i), cfg, kind, cos,
-                                sin, attend_layer(i, kind), live)
+                                sin, attend_layer(i, kind), live, rowwise)
         sizes.append(n)
     sizes = jnp.stack(sizes)                                     # [L, E]
     first, count = cfg.held
@@ -113,6 +119,8 @@ def _ragged_stack(params, pools, counters, tokens, q_lens, kv_lens, tables,
         "touched": counters["touched"] + jnp.sum(
             sizes[:, first:first + count] > 0, axis=1, dtype=jnp.int32),
         "steps": counters["steps"] + 1,
+        "narrow_steps": counters["narrow_steps"] + (
+            (n_live <= lanes).astype(jnp.int32) if narrow else 0),
     }
     return c2.head(x, params, cfg), tuple(pools), counters
 
@@ -125,7 +133,7 @@ def _ragged_fn(params, pools, counters, tokens, q_lens, kv_lens, tables, *,
     monitor.inc("serving.decode_retraces")
     monitor.inc("serving.ragged_retraces")
     return _ragged_stack(params, pools, counters, tokens, q_lens, kv_lens,
-                         tables, cfg=cfg)
+                         tables, cfg=cfg, narrow=True)
 
 
 def _verify_fn(params, pools, counters, tokens, ctx_lens, tables, *, cfg):
@@ -190,7 +198,8 @@ class Cohere2MoeInferenceEngine:
         L, e = cfg.num_hidden_layers, cfg.num_experts
         self.counters = {"tokens": jnp.zeros((L, e), jnp.int32),
                          "touched": jnp.zeros((L,), jnp.int32),
-                         "steps": jnp.zeros((), jnp.int32)}
+                         "steps": jnp.zeros((), jnp.int32),
+                         "narrow_steps": jnp.zeros((), jnp.int32)}
 
         def step(fn, wrap=lambda f: f):
             bound = functools.partial(fn, cfg=cfg)
@@ -265,11 +274,13 @@ class Cohere2MoeInferenceEngine:
         """The counters the step keeps on the device, fetched now: `tokens
         [L, E]` routed to each of the ROUTER's experts since the engine was
         built (held and absent alike), `touched [L]` HELD experts with at
-        least one token summed over steps, `steps`. Publishes
+        least one token summed over steps, `steps`, `narrow_steps` (those
+        whose row-wise work ran over the live prefix). Publishes
         `serving.moe.expert_tokens` (assignments that fell on a held
         expert), `serving.moe.held_assignment_share` (their share of all
-        assignments) and the gauge `serving.moe.load_max_over_mean`
-        (busiest held expert of a layer against the mean one)."""
+        assignments) and the gauges `serving.moe.load_max_over_mean`
+        (busiest held expert of a layer against the mean one) and
+        `serving.step.live_prefix_share` (`narrow_steps / steps`)."""
         c = jax.device_get(self.counters)
         tokens = np.asarray(c["tokens"], np.int64)
         first, count = self.config.held
@@ -281,5 +292,10 @@ class Cohere2MoeInferenceEngine:
         if mine.sum():
             monitor.set_gauge("serving.moe.load_max_over_mean",
                               round(float(mine.max() / mine.mean()), 3))
+        steps, narrow = int(c["steps"]), int(c["narrow_steps"])
+        if steps:
+            monitor.set_gauge("serving.step.live_prefix_share",
+                              round(narrow / steps, 4))
         return {"tokens": tokens, "touched": np.asarray(c["touched"], np.int64),
-                "steps": int(c["steps"]), "held": (first, count)}
+                "steps": steps, "narrow_steps": narrow,
+                "held": (first, count)}

@@ -37,7 +37,7 @@ from ..models import deepseek_v3 as dsv3
 from ..ops import sampling
 from ..ops.pallas import paged_attention_mla as pm
 from ..ops.pallas.paged_attention import ragged_metadata
-from . import kv_migrate
+from . import kv_migrate, live_prefix
 from .cache import BlockCacheManager
 from .generate import generate
 
@@ -47,9 +47,12 @@ FAMILY = "deepseek_v3"
 
 
 def _ragged_stack(params, pool, counters, tokens, q_lens, kv_lens, tables,
-                  *, cfg: dsv3.DeepseekV3Config):
+                  *, cfg: dsv3.DeepseekV3Config, narrow: bool = False):
     """Packed tokens `[T]` + per-lane `(q_len, kv_len)` through the decoder:
-    `(logits [T, V] float32, pool, counters)`."""
+    `(logits [T, V] float32, pool, counters)`. `narrow`: a step whose live
+    rows number at most its lanes runs the layers' row-wise segments over
+    that prefix of the packed buffer (`live_prefix.rowwise`; the choice is
+    made on the device, from `q_lens`)."""
     t = tokens.shape[0]
     nb, bs, row = pool.shape[1:]
     rank = cfg.kv_lora_rank
@@ -57,6 +60,9 @@ def _ragged_stack(params, pool, counters, tokens, q_lens, kv_lens, tables,
     tables = tables.astype(jnp.int32)
     tok_lane, tok_pos = ragged_metadata(q_lens, kv_lens, t)
     live = tok_pos >= 0
+    lanes = q_lens.shape[0]
+    n_live = jnp.sum(q_lens.astype(jnp.int32))
+    rowwise = live_prefix.rowwise(n_live, lanes if narrow else None, t)
     pos = jnp.maximum(tok_pos, 0)
     # a guard slot's row goes to a block past the pool: the scatter drops it
     blk = jnp.where(live, tables[tok_lane, pos // bs], jnp.int32(nb))
@@ -86,7 +92,7 @@ def _ragged_stack(params, pool, counters, tokens, q_lens, kv_lens, tables,
     sizes = []
     for i in range(cfg.num_hidden_layers):
         x, n = dsv3.decoder_layer(x, dsv3.layer_params(params, i), cfg, cos,
-                                  sin, attend_layer(i), live)
+                                  sin, attend_layer(i), live, rowwise)
         sizes.append(jnp.zeros((cfg.n_routed_experts,), jnp.int32)
                      if n is None else n)
     sizes = jnp.stack(sizes)                                     # [L, E]
@@ -95,6 +101,8 @@ def _ragged_stack(params, pool, counters, tokens, q_lens, kv_lens, tables,
         "touched": counters["touched"] + jnp.sum(sizes > 0, axis=1,
                                                  dtype=jnp.int32),
         "steps": counters["steps"] + 1,
+        "narrow_steps": counters["narrow_steps"] + (
+            (n_live <= lanes).astype(jnp.int32) if narrow else 0),
     }
     return dsv3.head(x, params, cfg), pool, counters
 
@@ -107,7 +115,7 @@ def _ragged_fn(params, pool, counters, tokens, q_lens, kv_lens, tables, *,
     monitor.inc("serving.decode_retraces")
     monitor.inc("serving.ragged_retraces")
     return _ragged_stack(params, pool, counters, tokens, q_lens, kv_lens,
-                         tables, cfg=cfg)
+                         tables, cfg=cfg, narrow=True)
 
 
 def _verify_fn(params, pool, counters, tokens, ctx_lens, tables, *, cfg):
@@ -145,7 +153,8 @@ class DeepseekV3InferenceEngine:
                               cdtype)
         self.counters = {"tokens": jnp.zeros((L, e), jnp.int32),
                          "touched": jnp.zeros((L,), jnp.int32),
-                         "steps": jnp.zeros((), jnp.int32)}
+                         "steps": jnp.zeros((), jnp.int32),
+                         "narrow_steps": jnp.zeros((), jnp.int32)}
         self._row_bytes = self.row_width * jnp.dtype(cdtype).itemsize
         self.manager.set_kv_geometry(L * block_size * self._row_bytes, 16)
 
@@ -220,10 +229,12 @@ class DeepseekV3InferenceEngine:
     def expert_load(self) -> dict:
         """The counters the step keeps on the device, fetched now: `tokens
         [L, E]` routed to each expert since the engine was built, `touched
-        [L]` experts with at least one token summed over steps, `steps`.
-        Publishes `serving.moe.expert_tokens` (their sum) and the gauge
+        [L]` experts with at least one token summed over steps, `steps`,
+        `narrow_steps` (those whose row-wise work ran over the live prefix).
+        Publishes `serving.moe.expert_tokens` (their sum) and the gauges
         `serving.moe.load_max_over_mean` (busiest expert of an expert layer
-        against the mean one)."""
+        against the mean one) and `serving.step.live_prefix_share`
+        (`narrow_steps / steps`)."""
         c = jax.device_get(self.counters)
         tokens = np.asarray(c["tokens"], np.int64)
         moe = tokens[self.config.first_k_dense_replace:]
@@ -231,5 +242,9 @@ class DeepseekV3InferenceEngine:
         if moe.sum():
             monitor.set_gauge("serving.moe.load_max_over_mean",
                               round(float(moe.max() / moe.mean()), 3))
+        steps, narrow = int(c["steps"]), int(c["narrow_steps"])
+        if steps:
+            monitor.set_gauge("serving.step.live_prefix_share",
+                              round(narrow / steps, 4))
         return {"tokens": tokens, "touched": np.asarray(c["touched"], np.int64),
-                "steps": int(c["steps"])}
+                "steps": steps, "narrow_steps": narrow}

@@ -33,7 +33,8 @@ The expert layer is TOLD which experts it holds (`held_experts = (first,
 count)`, the chip's share under expert parallelism): the router keeps its
 `num_experts` outputs and its top-k, the stacked matrices are the held
 experts' alone, and the routed sum is the held experts' part
-(`models/deepseek_v3.routed_experts`, the one dropless expert layer both
+(`models/deepseek_v3.moe_dispatch / moe_experts / moe_combine`, the one
+dropless expert layer both
 architectures run: it stays where it was written because the Kanana path
 imports it from there and moving it would change that file for no gain).
 
@@ -56,7 +57,8 @@ import numpy as np
 
 from .. import nn
 from ..nn.parameter import Parameter
-from .deepseek_v3 import _mm, layer_params, routed_experts, swiglu
+from .deepseek_v3 import (_mm, layer_params, moe_combine, moe_dispatch,
+                          moe_experts, whole)
 
 __all__ = ["Cohere2MoeConfig", "Cohere2MoeForCausalLM", "param_shapes",
            "init_params", "decoder_layer", "model_forward", "rope_tables"]
@@ -90,7 +92,7 @@ class Cohere2MoeConfig:
     # experts `first .. first + count - 1` of every layer live here
     held_experts: Optional[Tuple[int, int]] = None
 
-    # what `deepseek_v3.routed_experts` asks a config for
+    # what `deepseek_v3.dispatch` asks a config for
     @property
     def n_routed_experts(self) -> int:
         return self.num_experts
@@ -226,12 +228,14 @@ def rope_pairs(x, cos, sin):
     return out.reshape(x.shape).astype(x.dtype)
 
 
-def attention(h, p, cfg: Cohere2MoeConfig, kind: str, cos, sin,
-              attend: Callable):
-    """The attention sub-block on normed rows `h [T, H]` of a layer of
-    `kind`. `attend(q [T, heads, D], k, v [T, kv heads, D]) -> [T, heads,
-    D]` owns the context: it stores this step's k and v and answers each
-    query over what its layer type lets it see."""
+def qkv(h, p, cfg: Cohere2MoeConfig, kind: str, cos, sin):
+    """The attention sub-block before its context, on normed rows `h [T,
+    H]` of a layer of `kind`: `(q [T, heads, D], k, v [T, kv heads, D])`,
+    q and k turned where the layer has a position embedding. They go to
+    the layer's `attend(q, k, v) -> [T, heads, D]`, which owns the context:
+    it stores this step's k and v and answers each query over what its
+    layer type lets it see; `o_proj` takes its answer on. Everything in the
+    sub-block but `attend` maps a row to a row."""
     t = h.shape[0]
     nh, kvh, d = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim
     with _scope("llama.qkv"):
@@ -241,9 +245,15 @@ def attention(h, p, cfg: Cohere2MoeConfig, kind: str, cos, sin,
     if kind == SLIDING:
         with _scope("llama.rope"):
             q, k = rope_pairs(q, cos, sin), rope_pairs(k, cos, sin)
-    a = attend(q, k, v)
+    return q, k, v
+
+
+def o_proj(a, p, cfg: Cohere2MoeConfig, dtype):
+    """The attention sub-block after its context: `a [T, heads, D]` -> `[T,
+    H]` in `dtype`."""
     with _scope("llama.o_proj"):
-        return _mm(a.reshape(t, nh * d).astype(h.dtype),
+        return _mm(a.reshape(a.shape[0], cfg.num_attention_heads
+                             * cfg.head_dim).astype(dtype),
                    p["self_attn.o_proj.weight"])
 
 
@@ -262,34 +272,38 @@ def route(h, p, cfg: Cohere2MoeConfig):
     return experts.astype(jnp.int32), w
 
 
-def moe(h, p, cfg: Cohere2MoeConfig, live):
-    """The feed-forward on normed rows `h [T, H]`: the held experts' part of
-    the routed sum plus the shared experts' mean. `(out, tokens_per_expert
-    [num_experts])`."""
-    with _scope("llama.moe"):
-        with _scope("llama.moe_router"):
-            experts, weights = route(h, p, cfg)
-        out, sizes = routed_experts(h, experts, weights, live, p, cfg,
-                                    cfg.held)
-        with _scope("llama.moe_shared"):
-            shared = swiglu(h, p["mlp.shared_experts.gate_proj.weight"],
-                            p["mlp.shared_experts.up_proj.weight"],
-                            p["mlp.shared_experts.down_proj.weight"])
-            out = out + (shared.astype(jnp.float32)
-                         / cfg.num_shared_experts).astype(out.dtype)
-    return out, sizes
-
-
 def decoder_layer(x, p, cfg: Cohere2MoeConfig, kind: str, cos, sin, attend,
-                  live):
+                  live, rowwise: Callable = whole):
     """One decoder layer of `kind` on rows `x [T, H]`: `(x, tokens_per_expert
-    [num_experts])`."""
+    [num_experts])`.
+
+    A layer is *segment -> `attend` and the experts -> segment*, and a
+    segment maps a row to a row: `rowwise(segment)` may run it over fewer
+    rows than `T` (the serving step's live prefix,
+    `inference/live_prefix.py`). `attend`, which owns the context, and the
+    experts' grouped matmuls, whose cost follows the experts touched, always
+    take the whole packed buffer; the block is parallel, so neither waits
+    for the other."""
     with _scope("llama.layer"):
-        with _scope("llama.rms_norm"):
-            h = layer_norm(x, p["input_layernorm.weight"], cfg.layer_norm_eps)
-        attn = attention(h, p, cfg, kind, cos, sin, attend)
-        ffn, sizes = moe(h, p, cfg, live)
-        return x + attn + ffn, sizes
+        def before(x, cos, sin, live):
+            with _scope("llama.rms_norm"):
+                h = layer_norm(x, p["input_layernorm.weight"],
+                               cfg.layer_norm_eps)
+            heads = qkv(h, p, cfg, kind, cos, sin)
+            sorted_rows, counts = moe_dispatch(h, p, cfg, live, cfg.held,
+                                               route)
+            return (h, heads) + sorted_rows, counts
+
+        def after(x, h, a, y, order, keep, weights):
+            ffn = moe_combine(h, y, order, keep, weights, p,
+                              cfg.num_shared_experts)
+            return x + o_proj(a, p, cfg, x.dtype) + ffn, None
+
+        (h, (q, k, v), xs, order, keep, weights), (mine, sizes) = rowwise(
+            before)(x, cos, sin, live)
+        x, _ = rowwise(after)(x, h, attend(q, k, v), moe_experts(xs, mine, p),
+                              order, keep, weights)
+        return x, sizes
 
 
 def head(x, params, cfg: Cohere2MoeConfig):

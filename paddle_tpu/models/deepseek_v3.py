@@ -218,17 +218,26 @@ def swiglu(x, gate_w, up_w, down_w):
     return _mm(jax.nn.silu(g.astype(jnp.float32)).astype(x.dtype) * u, down_w)
 
 
-def mla(x, p, cfg: DeepseekV3Config, cos, sin, attend: Callable):
-    """The attention sub-block on rows `x [T, H]` in the absorbed form.
-    `attend(q_abs [T, heads, rank + rope], rows [T, rank + rope]) ->
-    o_lat [T, heads, rank]` owns the context: it stores this step's `rows`
-    and answers each query with `softmax(q . rows^T * scale) . rows[:, :rank]`
-    over its token's causal context. Returns the block's output `[T, H]`
-    (before the residual)."""
+def _w_kvb(p, cfg: DeepseekV3Config):
+    """`W_kvb` split by head: `[rank, heads, nope + v]`."""
+    return p["self_attn.kv_b_proj.weight"].reshape(
+        cfg.kv_lora_rank, cfg.num_attention_heads,
+        cfg.qk_nope_head_dim + cfg.v_head_dim)
+
+
+def mla_query(x, p, cfg: DeepseekV3Config, cos, sin):
+    """The attention sub-block before its context, on normed rows `x [T,
+    H]`: `(q_abs [T, heads, rank + rope], rows [T, rank + rope])`, the
+    absorbed queries and this step's cache rows `[c | rotated k_rope]`.
+    They go to the layer's `attend(q_abs, rows) -> o_lat [T, heads, rank]`,
+    which owns the context: it stores `rows` and answers each query with
+    `softmax(q . rows^T * scale) . rows[:, :rank]` over its token's causal
+    context; `mla_output` takes `o_lat` on. Everything in the sub-block but
+    `attend` maps a row to a row."""
     t = x.shape[0]
     nh, nope, rd = cfg.num_attention_heads, cfg.qk_nope_head_dim, \
         cfg.qk_rope_head_dim
-    rank, vd = cfg.kv_lora_rank, cfg.v_head_dim
+    rank = cfg.kv_lora_rank
     with _scope("llama.mla_q"):
         q = _mm(x, p["self_attn.q_proj.weight"]).reshape(t, nh, nope + rd)
         q_nope, q_rope = q[..., :nope], q[..., nope:]
@@ -240,17 +249,24 @@ def mla(x, p, cfg: DeepseekV3Config, cos, sin, attend: Callable):
     with _scope("llama.rope"):
         q_rope = rope(q_rope, cos, sin, cfg.rope_interleave)
         k_rope = rope(k_rope, cos, sin, cfg.rope_interleave)
-    w_kvb = p["self_attn.kv_b_proj.weight"].reshape(rank, nh, nope + vd)
     with _scope("llama.mla_absorb"):
         q_lat = jnp.einsum("thn,chn->thc", q_nope,
-                           w_kvb[..., :nope].astype(x.dtype))
+                           _w_kvb(p, cfg)[..., :nope].astype(x.dtype))
         q_abs = jnp.concatenate([q_lat, q_rope], axis=-1)
-    o_lat = attend(q_abs, jnp.concatenate([c, k_rope], axis=-1))
+    return q_abs, jnp.concatenate([c, k_rope], axis=-1)
+
+
+def mla_output(o_lat, p, cfg: DeepseekV3Config, dtype):
+    """The attention sub-block after its context: `o_lat [T, heads, rank]`
+    -> the block's output `[T, H]` in `dtype` (before the residual)."""
+    t = o_lat.shape[0]
     with _scope("llama.mla_absorb"):
-        o = jnp.einsum("thc,chv->thv", o_lat.astype(x.dtype),
-                       w_kvb[..., nope:].astype(x.dtype))
+        o = jnp.einsum(
+            "thc,chv->thv", o_lat.astype(dtype),
+            _w_kvb(p, cfg)[..., cfg.qk_nope_head_dim:].astype(dtype))
     with _scope("llama.o_proj"):
-        return _mm(o.reshape(t, nh * vd), p["self_attn.o_proj.weight"])
+        return _mm(o.reshape(t, cfg.num_attention_heads * cfg.v_head_dim),
+                   p["self_attn.o_proj.weight"])
 
 
 def route(x, p, cfg: DeepseekV3Config):
@@ -271,27 +287,21 @@ def route(x, p, cfg: DeepseekV3Config):
     return experts.astype(jnp.int32), w * cfg.routed_scaling_factor
 
 
-def routed_experts(x, experts, weights, live, p, cfg,
-                   held: Optional[Tuple[int, int]] = None):
-    """The routed experts without capacity or drops. `x [T, H]`, `experts` /
-    `weights [T, k]`, `live [T]` bool (a row that is not live reaches no
-    expert and gets zeros). Rows are sorted by expert, the experts' SwiGLUs
-    are three grouped matmuls over the sorted rows (the kernel
-    `moe_grouped_matmul`, `ops/pallas/grouped_matmul.py`; off the TPU
-    `jax.lax.ragged_dot`; an expert with no row is not computed and its
-    matrices are not read), and each token's k answers are combined by
-    weight. Returns `(out [T, H], tokens_per_expert [E] int32)`.
+def dispatch(x, experts, live, cfg, held: Optional[Tuple[int, int]] = None):
+    """The routed experts' rows, sorted by expert. `x [T, H]`, `experts [T,
+    k]`, `live [T]` bool (a row that is not live reaches no expert).
+    Returns `(xs [T*k, H], order [T*k], keep [T, k])`, the rows of `x`
+    sorted by the expert they go to, `order[s]` the assignment (row * k +
+    choice) at sorted place `s`, `keep` the assignments that reach an
+    expert held here; and `(mine [count], sizes [E])`, the rows of each
+    HELD expert and of each of the router's experts. Dead rows and
+    assignments to an absent expert sort last.
 
     `held = (first, count)`: the layer is told which experts it holds (the
-    chip's share under expert parallelism): `p`'s stacked matrices are those
-    of experts `first .. first + count - 1` alone, `[count, in, out]`. The
-    router keeps its `cfg.n_routed_experts` outputs and its choice; an
-    assignment to an expert that is not held is what a dead row is: sorted
-    last, never computed, adding nothing, so `out` is the part of the
-    layer's result that the held experts give. `tokens_per_expert` counts
-    held and absent alike. None: every expert is held, and the program is
-    what it was before the layer could be told.
-    """
+    chip's share under expert parallelism). The router keeps its
+    `cfg.n_routed_experts` outputs and its choice; an assignment to an
+    expert that is not held is what a dead row is. `sizes` counts held and
+    absent alike. None: every expert is held."""
     t, k = experts.shape
     e = cfg.n_routed_experts
     first, count = (0, e) if held is None else held
@@ -299,14 +309,27 @@ def routed_experts(x, experts, weights, live, p, cfg,
         flat = jnp.where(jnp.repeat(live, k), experts.reshape(t * k),
                          jnp.int32(e))                  # dead rows sort last
         sizes = jnp.zeros((e + 1,), jnp.int32).at[flat].add(1)[:e]
+        keep = live[:, None]
         if (first, count) == (0, e):
-            here, mine = None, sizes
+            mine = sizes
         else:
             here = (flat >= first) & (flat < first + count)      # [T*k]
             flat = jnp.where(here, flat - first, jnp.int32(count))
             mine = sizes[first:first + count]
+            keep = keep & here.reshape(t, k)
         order = jnp.argsort(flat).astype(jnp.int32)     # [T*k] sorted -> flat
         xs = jnp.take(x, order // k, axis=0)            # [T*k, H]
+    return (xs, order, jnp.broadcast_to(keep, (t, k))), (mine, sizes)
+
+
+def expert_ffn(xs, mine, p):
+    """The experts' SwiGLUs on rows sorted by expert: three grouped matmuls
+    (the kernel `moe_grouped_matmul`, `ops/pallas/grouped_matmul.py`; off
+    the TPU `jax.lax.ragged_dot`). `xs [M, H]`, `mine [count]` rows an
+    expert, their sum at most M; an expert with no row is not computed and
+    its matrices are not read, rows past the sum cost nothing and hold
+    whatever. Returns `[M, H]` float32. Its cost follows the experts
+    touched, not M."""
     with _scope("llama.moe_experts"):
         def rd(a, w):
             if grouped_matmul.supported(w.shape, w.dtype):
@@ -316,53 +339,113 @@ def routed_experts(x, experts, weights, live, p, cfg,
 
         g = rd(xs, p["mlp.experts.gate_proj.weight"])
         u = rd(xs, p["mlp.experts.up_proj.weight"])
-        y = rd((jax.nn.silu(g) * u).astype(x.dtype),
-               p["mlp.experts.down_proj.weight"])       # [T*k, H] float32
+        return rd((jax.nn.silu(g) * u).astype(xs.dtype),
+                  p["mlp.experts.down_proj.weight"])    # [M, H] float32
+
+
+def combine(y, order, keep, weights, dtype):
+    """Each token's k answers, combined by weight: `y [T*k, H]` sorted as
+    `order` says, `keep`, `weights [T, k]` -> `[T, H]` in `dtype`."""
+    t, k = weights.shape
     with _scope("llama.moe_combine"):
         back = jnp.zeros((t * k,), jnp.int32).at[order].set(
             jnp.arange(t * k, dtype=jnp.int32))         # flat -> sorted
         y = jnp.take(y, back, axis=0).reshape(t, k, -1)
         # rows past the groups' sum are never written by the grouped
         # matmul: select, never multiply, what a dead row holds
-        keep = live[:, None] if here is None \
-            else live[:, None] & here.reshape(t, k)
         w = jnp.where(keep, weights, 0.0)[..., None]
         out = jnp.sum(jnp.where(keep[..., None], y, 0.0) * w, axis=1)
-    return out.astype(x.dtype), sizes
+    return out.astype(dtype)
 
 
-def moe(x, p, cfg: DeepseekV3Config, live):
-    """The expert layer's feed-forward on normed rows `x [T, H]`:
-    `(out, tokens_per_expert)`."""
+def moe_dispatch(x, p, cfg, live, held=None, router: Callable = None):
+    """An expert layer's feed-forward up to its experts, on normed rows `x
+    [T, H]`: the router (`route`, or the architecture's own) and
+    `dispatch`. `((xs, order, keep, weights), (mine, tokens_per_expert))`."""
     with _scope("llama.moe"):
         with _scope("llama.moe_router"):
-            experts, weights = route(x, p, cfg)
-        out, sizes = routed_experts(x, experts, weights, live, p, cfg)
+            experts, weights = (router or route)(x, p, cfg)
+        sorted_rows, counts = dispatch(x, experts, live, cfg, held)
+    return sorted_rows + (weights,), counts
+
+
+def moe_experts(xs, mine, p):
+    """The experts themselves (`expert_ffn`) under the layer's scope."""
+    with _scope("llama.moe"):
+        return expert_ffn(xs, mine, p)
+
+
+def moe_combine(x, y, order, keep, weights, p, mean_of: int = 1):
+    """An expert layer's feed-forward after its experts: `combine`, plus the
+    shared experts' SwiGLU on `x` (their matrices side by side: their sum,
+    or with `mean_of` their mean). `[T, H]`."""
+    with _scope("llama.moe"):
+        out = combine(y, order, keep, weights, x.dtype)
         with _scope("llama.moe_shared"):
-            out = out + swiglu(x, p["mlp.shared_experts.gate_proj.weight"],
-                               p["mlp.shared_experts.up_proj.weight"],
-                               p["mlp.shared_experts.down_proj.weight"])
-    return out, sizes
+            shared = swiglu(x, p["mlp.shared_experts.gate_proj.weight"],
+                            p["mlp.shared_experts.up_proj.weight"],
+                            p["mlp.shared_experts.down_proj.weight"])
+            if mean_of != 1:
+                shared = (shared.astype(jnp.float32)
+                          / mean_of).astype(out.dtype)
+            return out + shared
 
 
-def decoder_layer(x, p, cfg: DeepseekV3Config, cos, sin, attend, live):
+def whole(fn: Callable) -> Callable:
+    """`rowwise` where every row is live (no cache, a verify window): `fn`
+    itself."""
+    return fn
+
+
+def decoder_layer(x, p, cfg: DeepseekV3Config, cos, sin, attend, live,
+                  rowwise: Callable = whole):
     """One decoder layer on rows `x [T, H]` (`p`: the layer's weights by
     their names under `model.layers.<i>.`). Returns `(x, tokens_per_expert
-    [E] | None)`; None for a dense layer."""
+    [E] | None)`; None for a dense layer.
+
+    A layer is *segment -> `attend` -> segment* (an expert layer's second
+    segment again *-> experts -> segment*), and a segment maps a row to a
+    row: `rowwise(segment)` may run it over fewer rows than `T` (the serving
+    step's live prefix, `inference/live_prefix.py`). `attend`, which owns
+    the context, and the experts' grouped matmuls, whose cost follows the
+    experts touched, always take the whole packed buffer."""
     with _scope("llama.layer"):
-        with _scope("llama.rms_norm"):
-            h = rms_norm(x, p["input_layernorm.weight"], cfg.rms_norm_eps)
-        x = x + mla(h, p, cfg, cos, sin, attend)
-        with _scope("llama.rms_norm"):
-            h = rms_norm(x, p["post_attention_layernorm.weight"],
-                         cfg.rms_norm_eps)
-        if "mlp.gate.weight" in p:
-            out, sizes = moe(h, p, cfg, live)
-            return x + out, sizes
-        with _scope("llama.mlp"):
-            return x + swiglu(h, p["mlp.gate_proj.weight"],
-                              p["mlp.up_proj.weight"],
-                              p["mlp.down_proj.weight"]), None
+        def query(x, cos, sin):
+            with _scope("llama.rms_norm"):
+                h = rms_norm(x, p["input_layernorm.weight"],
+                             cfg.rms_norm_eps)
+            return mla_query(h, p, cfg, cos, sin), None
+
+        def attended(x, o_lat):
+            x = x + mla_output(o_lat, p, cfg, x.dtype)
+            with _scope("llama.rms_norm"):
+                return x, rms_norm(x, p["post_attention_layernorm.weight"],
+                                   cfg.rms_norm_eps)
+
+        def dense(x, o_lat):
+            x, h = attended(x, o_lat)
+            with _scope("llama.mlp"):
+                return x + swiglu(h, p["mlp.gate_proj.weight"],
+                                  p["mlp.up_proj.weight"],
+                                  p["mlp.down_proj.weight"]), None
+
+        def routed(x, o_lat, live):
+            x, h = attended(x, o_lat)
+            sorted_rows, counts = moe_dispatch(h, p, cfg, live)
+            return (x, h) + sorted_rows, counts
+
+        def combined(x, h, y, order, keep, weights):
+            return x + moe_combine(h, y, order, keep, weights, p), None
+
+        (q_abs, rows), _ = rowwise(query)(x, cos, sin)
+        o_lat = attend(q_abs, rows)
+        if "mlp.gate.weight" not in p:
+            return rowwise(dense)(x, o_lat)
+        (x, h, xs, order, keep, weights), (mine, sizes) = rowwise(routed)(
+            x, o_lat, live)
+        x, _ = rowwise(combined)(x, h, moe_experts(xs, mine, p), order, keep,
+                                 weights)
+        return x, sizes
 
 
 def head(x, params, cfg: DeepseekV3Config):

@@ -22,6 +22,7 @@ from paddle_tpu.inference.cohere2_moe_runner import Cohere2MoeInferenceEngine
 from paddle_tpu.models import cohere2_moe as c2
 from paddle_tpu.models import deepseek_v3 as dsv3
 from paddle_tpu.serving import RequestStatus, ServingFrontend
+from test_deepseek_v3 import routed_experts
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -182,16 +183,17 @@ def test_the_shares_add_up_to_the_uncut_layer(rng):
         want = ref.layer(x, p, HF, kind, cos, sin, moe=ref.every_expert_moe,
                          attend=ref.dense_attention)
         h = c2.layer_norm(x, p["input_layernorm.weight"], 1e-5)
-        attn = c2.attention(h, p, config(None), kind, cos, sin,
-                            c2.dense_attend(config(None), kind))
+        attn = c2.o_proj(c2.dense_attend(config(None), kind)(
+            *c2.qkv(h, p, config(None), kind, cos, sin)), p, config(None),
+            h.dtype)
         routed = 0.0
         for first in range(0, HF["num_experts"], 2):     # four chips of two
             cfg = config((first, 2))
             mine = dict(p, **{k: v[first:first + 2] for k, v in p.items()
                               if k.startswith("mlp.experts.")})
             experts, weights = c2.route(h, mine, cfg)
-            part, sizes = dsv3.routed_experts(h, experts, weights, live, mine,
-                                              cfg, (first, 2))
+            part, sizes = routed_experts(h, experts, weights, live, mine,
+                                         cfg, (first, 2))
             assert int(sizes.sum()) == 40 * HF["num_experts_per_tok"]
             routed = routed + part
         shared = dsv3.swiglu(h, *(p[k] for k in ref.SHARED)) \
@@ -201,18 +203,19 @@ def test_the_shares_add_up_to_the_uncut_layer(rng):
 
 
 def test_every_expert_held_is_the_program_there_was(rng):
-    """`routed_experts` told that it holds every expert traces the program
-    it traced before it could be told (Kanana's), and gives the same bits."""
+    """The routed experts told that they hold every expert trace the
+    program they traced before they could be told (Kanana's), and give the
+    same bits."""
     from test_deepseek_v3 import CFG, make_params as kanana_params
 
     p = dsv3.layer_params(kanana_params(), 2)
     x = jnp.asarray(rng.normal(size=(24, CFG.hidden_size)), jnp.float32)
     live = jnp.arange(24) < 20
     experts, weights = dsv3.route(x, p, CFG)
-    told = jax.jit(lambda x: dsv3.routed_experts(
+    told = jax.jit(lambda x: routed_experts(
         x, experts, weights, live, p, CFG, (0, CFG.n_routed_experts)))
-    plain = jax.jit(lambda x: dsv3.routed_experts(x, experts, weights, live,
-                                                  p, CFG))
+    plain = jax.jit(lambda x: routed_experts(x, experts, weights, live, p,
+                                             CFG))
     for a, b in zip(told(x), plain(x)):
         assert np.array_equal(np.asarray(a), np.asarray(b))
     strip = lambda t: "\n".join(                              # noqa: E731

@@ -155,7 +155,8 @@ def test_absorbed_mla_is_the_expanded_form(rng):
     p = dsv3.layer_params(make_params(), 1)
     x = jnp.asarray(rng.normal(size=(48, CFG.hidden_size)), jnp.float32)
     cos, sin = dsv3.rope_tables(CFG, 48)
-    got = dsv3.mla(x, p, CFG, cos, sin, dsv3.dense_causal_attend(CFG))
+    got = dsv3.mla_output(dsv3.dense_causal_attend(CFG)(
+        *dsv3.mla_query(x, p, CFG, cos, sin)), p, CFG, x.dtype)
     want = ref.attention(x, p, HF, *ref.rope_tables(HF, 48))
     np.testing.assert_allclose(got, want, atol=2e-5, rtol=0)
 
@@ -267,6 +268,15 @@ def _every_expert(x, experts, weights, p):
     return out
 
 
+def routed_experts(x, experts, weights, live, p, cfg, held=None):
+    """The routed experts as a layer runs them: `dispatch`, `expert_ffn`,
+    `combine`. `(out [T, H], tokens_per_expert [E])`."""
+    (xs, order, keep), (mine, sizes) = dsv3.dispatch(x, experts, live, cfg,
+                                                     held)
+    return dsv3.combine(dsv3.expert_ffn(xs, mine, p), order, keep, weights,
+                        x.dtype), sizes
+
+
 @pytest.mark.parametrize("case", ["routed", "all to one expert", "guard rows"])
 def test_grouped_experts_are_the_every_expert_form(case, rng):
     p = dsv3.layer_params(make_params(), 2)
@@ -278,7 +288,7 @@ def test_grouped_experts_are_the_every_expert_form(case, rng):
         experts = jnp.broadcast_to(jnp.asarray([5, 2], jnp.int32), (t, k))
     elif case == "guard rows":
         live = jnp.arange(t) < 17
-    got, sizes = dsv3.routed_experts(x, experts, weights, live, p, CFG)
+    got, sizes = routed_experts(x, experts, weights, live, p, CFG)
     want = jnp.where(live[:, None], _every_expert(x, experts, weights, p), 0.0)
     np.testing.assert_allclose(got, want, atol=2e-6, rtol=0)
     assert int(sizes.sum()) == int(live.sum()) * k, "no drop, no guard row"

@@ -797,7 +797,8 @@ def test_cohere2_moe_step_compiles_for_v5e_at_the_cell_s_size(v5e_chip):
     params["rope_cos"] = params["rope_sin"] = arr((width * 64, 64), jnp.float32)
     pools = (arr((1, 9600, 8, 64, 128)),) * 2 + (arr((3, 2337, 8, 64, 128)),) * 2
     counters = {"tokens": arr((4, 128), jnp.int32),
-                "touched": arr((4,), jnp.int32), "steps": arr((), jnp.int32)}
+                "touched": arr((4,), jnp.int32), "steps": arr((), jnp.int32),
+                "narrow_steps": arr((), jnp.int32)}
     step = sampling.with_tail(functools.partial(cr._ragged_fn, cfg=cfg))
     ints = [arr((tokens,), jnp.int32),
             arr((lanes, len(sampling.LANE_COLS)), jnp.int32),
@@ -824,6 +825,111 @@ def test_cohere2_moe_step_compiles_for_v5e_at_the_cell_s_size(v5e_chip):
     held = mem.argument_size_in_bytes + mem.output_size_in_bytes \
         - mem.alias_size_in_bytes + mem.temp_size_in_bytes
     assert held < 14.5e9, held          # of the chip's 16.9 GB
+
+
+def test_deepseek_v3_step_keeps_its_live_prefix_switches_on_the_v5e(v5e_chip):
+    """ISSUE 39: the Kanana-shaped step (published attention and expert
+    widths; depth, experts, vocabulary and pool cut), 32 lanes + a 512-token
+    chunk, compiled by the installed libtpu for a v5e from shapes alone. The
+    row-wise segments' switches are still control flow in the compiled
+    program (two `conditional`s a dense layer, three an expert layer: XLA
+    has not turned them into selects that compute both widths); each layer's
+    ONE `paged_attention_mla`, the pool's write and the experts' grouped
+    matmuls, which cost what is live whatever the buffer's width, lie
+    outside them and are in the program once; the pool is aliased to its
+    output, and the temporaries are those of the program with no switch plus
+    at most the padded row outputs."""
+    import functools
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.inference import deepseek_v3_runner as dr
+    from paddle_tpu.inference import live_prefix
+    from paddle_tpu.models import deepseek_v3 as dsv3
+    from paddle_tpu.ops import sampling
+    from paddle_tpu.ops.pallas import _support
+
+    cfg = dsv3.DeepseekV3Config(
+        vocab_size=8192, hidden_size=2048, intermediate_size=6144,
+        moe_intermediate_size=768, num_hidden_layers=2,
+        num_attention_heads=32, n_routed_experts=16, n_shared_experts=2,
+        num_experts_per_tok=6, first_k_dense_replace=1)
+    layers, lanes, tokens, width, bs = 2, 32, 32 + 512, 16, 64
+
+    def arr(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=v5e_chip)
+
+    params = {k: arr(s) for k, (s, _) in dsv3.param_shapes(cfg).items()}
+    params["rope_cos"] = params["rope_sin"] = arr((width * bs, 32),
+                                                  jnp.float32)
+    pool = arr((layers, lanes * width + 1, bs, 640))
+    counters = {"tokens": arr((layers, 16), jnp.int32),
+                "touched": arr((layers,), jnp.int32),
+                "steps": arr((), jnp.int32),
+                "narrow_steps": arr((), jnp.int32)}
+    ints = [arr((tokens,), jnp.int32),
+            arr((lanes, len(sampling.LANE_COLS)), jnp.int32),
+            arr((lanes, width), jnp.int32), arr((lanes,), jnp.float32)]
+
+    def compile_step():
+        step = sampling.with_tail(functools.partial(dr._ragged_fn, cfg=cfg))
+        return jax.jit(step, donate_argnums=(1, 2)).trace(
+            params, pool, counters, *ints).lower(
+                lowering_platforms=("tpu",)).compile()
+
+    cache_was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        with pytest.MonkeyPatch.context() as mp:
+            mp.setattr(_support, "backend", lambda: "tpu")
+            compiled = compile_step()
+            mp.setattr(live_prefix, "rowwise",
+                       lambda n_live, narrow, t: dsv3.whole)
+            unswitched = compile_step()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache_was)
+
+    text = compiled.as_text()
+    bodies, name = {}, None              # computation -> its instructions
+    for line in text.splitlines():
+        head = re.match(r"(?:ENTRY )?%([\w.-]+) \(", line)
+        if head and line.rstrip().endswith("{"):
+            name = head.group(1)
+            bodies[name] = []
+        elif name is not None:
+            bodies[name].append(line)
+    # (the compiler moves a neighbour's ops into a branch and then drops
+    # the switch's own scope path: the sampler's greedy switch is the other)
+    switches = [ln for body in bodies.values() for ln in body
+                if " conditional(" in ln and "sampler/cond" not in ln]
+    assert len(switches) == 2 + 3 * (layers - 1), len(switches)
+    inside = set()
+    for ln in switches:
+        inside.update(n.strip().lstrip("%") for n in re.search(
+            r"branch_computations=\{([^}]*)\}", ln).group(1).split(","))
+    assert len(inside) == 2 * len(switches)
+
+    def kernels(names):
+        return sorted(re.sub(r"[.\d]+$", "", k) for n in names
+                      for k in re.findall(
+                          r'%([\w.-]+) = [^\n]*custom_call_target='
+                          r'"tpu_custom_call"', "\n".join(bodies[n])))
+
+    assert kernels(inside) == []
+    assert kernels(set(bodies) - inside) == \
+        ["moe_grouped_matmul"] * (3 * (layers - 1)) \
+        + ["paged_attention_mla"] * layers
+    assert "llama.layer/cond" not in unswitched.as_text()
+    mem, plain = compiled.memory_analysis(), unswitched.memory_analysis()
+    pool_bytes = int(np.prod(pool.shape)) * 2
+    assert mem.alias_size_in_bytes >= pool_bytes
+    # the switch's outputs are buffers where the one width fused them away:
+    # the padded q_abs, the cache rows and a hidden state a layer at most
+    q_abs = tokens * 32 * 640 * 2
+    assert mem.temp_size_in_bytes <= plain.temp_size_in_bytes + 2 * q_abs, (
+        mem.temp_size_in_bytes, plain.temp_size_in_bytes)
 
 
 def test_gate_closes_for_gspmd_partitioned_operands():
