@@ -101,44 +101,42 @@ def check(ok, what):
 # --- compile accounting -------------------------------------------------------
 
 class Phases:
-    """Names the phases and, per phase, how many programs JAX compiled, how
-    many came out of the persistent cache, and the seconds spent in
-    `backend_compile` (which wraps the cache lookup, so a warm run shows as
-    fewer seconds). Seconds are printed on the chip only."""
+    """Names the phases and, per phase, what the program's own compile
+    record (`paddle_tpu.observability.compile_trace`, fed by JAX's events)
+    counted: programs compiled, how many came out of the persistent cache,
+    and the seconds tracing, lowering and in `backend_compile` (which wraps
+    the cache lookup, so a warm run shows as fewer seconds). Seconds are
+    printed on the chip only."""
 
     def __init__(self, on_chip):
-        import jax
-
         self.on_chip = on_chip
-        self.compile_s = 0.0
-        self.compiles = 0
-        self.hits = 0
         self.done = []
-        jax.monitoring.register_event_duration_secs_listener(self._duration)
-        jax.monitoring.register_event_listener(self._event)
 
-    def _duration(self, event, seconds, **_):
-        if event == "/jax/core/compile/backend_compile_duration":
-            self.compile_s += seconds
-            self.compiles += 1
+    @property
+    def compile_s(self):
+        from paddle_tpu.framework import monitor
 
-    def _event(self, event, **_):
-        if event == "/jax/compilation_cache/cache_hits":
-            self.hits += 1
+        return monitor.get("compile.backend_s")
 
     @contextlib.contextmanager
     def __call__(self, name):
+        from paddle_tpu.framework import monitor
+
         print(f"[{name}]", flush=True)
-        s0, n0, h0, t0 = (self.compile_s, self.compiles, self.hits,
-                          time.perf_counter())
+        keys = ("programs", "cache_hits", "trace_s", "lower_s", "backend_s")
+        before = [monitor.get("compile." + k) for k in keys]
+        t0 = time.perf_counter()
         yield
-        line = (f"[{name}] passed: {self.compiles - n0} programs compiled, "
-                f"{self.hits - h0} from the cache")
+        n, hits, trace_s, lower_s, backend_s = (
+            monitor.get("compile." + k) - b for k, b in zip(keys, before))
+        line = (f"[{name}] passed: {n} programs compiled, {hits} from the "
+                "cache")
         if self.on_chip:
             import jax
 
             peak = jax.devices()[0].memory_stats()["peak_bytes_in_use"]
-            line += (f", compile {self.compile_s - s0:.1f} s, "
+            line += (f", trace {trace_s:.1f} s, lowering {lower_s:.1f} s, "
+                     f"compile {backend_s:.1f} s, "
                      f"wall {time.perf_counter() - t0:.1f} s, device 0 "
                      f"peak so far {peak / 2**30:.2f} GiB")
         print(line, flush=True)

@@ -7,9 +7,18 @@ Distributed: GSPMD over `jax.sharding.Mesh` (dp/mp/pp/sep/sharding/ep axes).
 """
 from __future__ import annotations
 
-import os as _os
+import time as _time
 
-import jax as _jax
+_import_began = _time.time()    # `startup.import_s`: this line to the last
+
+import os as _os  # noqa: E402
+
+import jax as _jax  # noqa: E402
+
+# the compile record listens to JAX from here on
+from .observability import compile_trace as _compile_trace  # noqa: E402
+
+_compile_trace.install()
 
 # Multi-process rendezvous must happen BEFORE anything initialises the XLA
 # backend, and importing this package touches devices (Tensor machinery), so
@@ -149,3 +158,6 @@ def in_dynamic_mode() -> bool:
         return not _s.in_static_mode()
     except Exception:
         return True
+
+
+_compile_trace.stamp("startup.import", _import_began)

@@ -119,7 +119,7 @@ OBS_PAYLOAD_PRODUCERS = {
     "comms.record", "comms.step_overlap", "comms.chrome_events",
     "memory.dump_oom",
     "compile_trace.note_retrace", "compile_trace.note_signature",
-    "compile_trace.on_compile",
+    "compile_trace.on_compile", "compile_trace.attach",
 }
 
 # How a gate reads in source: a call to any of these (e.g.

@@ -153,25 +153,26 @@ def _log_compile(kind, name, key):
 
 
 def _obs_trace_compile(cache, key, fn, kind, name):
-    """Observability hook on an executable-cache miss: record the compile
-    (with a retrace-cause diff against the nearest cached signature for
-    the same op) and time the FIRST call — trace+compile happen lazily
-    there. The wrapper swaps the raw jitted fn back into the cache after
-    that call, so steady-state dispatch pays nothing. No-op (returns `fn`
-    unwrapped) while observability is disabled — the cold compile path is
-    the only place this is even consulted."""
+    """Observability hook on an executable-cache miss: diff the structure
+    key against the nearest cached signature of the same op (the retrace
+    cause) and, after the FIRST call — trace+compile happen lazily there —
+    attach kind, op, key and cause to the record JAX's own events made of
+    it (`observability.compile_trace`). The wrapper swaps the raw jitted
+    fn back into the cache after that call, so steady-state dispatch pays
+    nothing. No-op (returns `fn` unwrapped) while observability is
+    disabled — the cold compile path is the only place this is even
+    consulted."""
     from .. import observability as _obs
 
     if not _obs.enabled():
         return fn
-    import time as _time
-
-    rec = _obs.compile_trace.on_compile(kind, name, key)
+    cause = _obs.compile_trace.on_compile(kind, name, key)
 
     def first_call(*args, **kw):
-        t0 = _time.perf_counter()
+        since = _obs.compile_trace.mark()
         out = fn(*args, **kw)
-        rec.wall_s = _time.perf_counter() - t0
+        _obs.compile_trace.attach(since, kind=kind, op=name, key=key,
+                                  cause=cause)
         cache[key] = fn
         return out
 

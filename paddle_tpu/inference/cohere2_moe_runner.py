@@ -32,6 +32,7 @@ radix prefix cache and speculative decoding over a windowed group.
 from __future__ import annotations
 
 import functools
+import time
 from typing import Dict
 
 import jax
@@ -40,6 +41,7 @@ import numpy as np
 
 from ..framework import monitor
 from ..models import cohere2_moe as c2
+from ..observability import compile_trace
 from ..ops import sampling
 from ..ops.pallas import paged_attention as pk
 from . import kv_migrate, live_prefix
@@ -161,6 +163,7 @@ class Cohere2MoeInferenceEngine:
                  max_batch_size: int = 8, num_blocks: int = 256,
                  block_size: int = 16, max_blocks_per_seq: int = 16,
                  window_blocks: int = None):
+        began = time.time()     # `engine.build_s`: this line to the last
         cfg = model.config
         self.config = cfg
         self.block_size = block_size
@@ -210,6 +213,7 @@ class Cohere2MoeInferenceEngine:
         # program (`ops/sampling.with_tail`)
         self._ragged = step(_ragged_fn, sampling.with_tail)
         self._verify = step(_verify_fn)
+        compile_trace.stamp("engine.build", began)
 
     # ---- the EngineCore dispatch surface ----
     def sampled_step(self, tokens: np.ndarray, lanes: np.ndarray,

@@ -26,6 +26,7 @@ name; KV migration is refused here, by family.
 from __future__ import annotations
 
 import functools
+import time
 from typing import Dict
 
 import jax
@@ -34,6 +35,7 @@ import numpy as np
 
 from ..framework import monitor
 from ..models import deepseek_v3 as dsv3
+from ..observability import compile_trace
 from ..ops import sampling
 from ..ops.pallas import paged_attention_mla as pm
 from ..ops.pallas.paged_attention import ragged_metadata
@@ -136,6 +138,7 @@ class DeepseekV3InferenceEngine:
     def __init__(self, model: dsv3.DeepseekV3ForCausalLM,
                  max_batch_size: int = 8, num_blocks: int = 256,
                  block_size: int = 16, max_blocks_per_seq: int = 16):
+        began = time.time()     # `engine.build_s`: this line to the last
         cfg = model.config
         self.config = cfg
         self.block_size = block_size
@@ -171,6 +174,7 @@ class DeepseekV3InferenceEngine:
         # src/dst trace as scalars, so COWs never recompile
         self._copy_block = jax.jit(
             lambda p, s, d: p.at[:, d].set(p[:, s]), donate_argnums=(0,))
+        compile_trace.stamp("engine.build", began)
 
     # ---- the EngineCore dispatch surface ----
     def sampled_step(self, tokens: np.ndarray, lanes: np.ndarray,

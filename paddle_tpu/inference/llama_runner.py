@@ -32,10 +32,12 @@ TPU-first choices:
 from __future__ import annotations
 
 import functools
+import time
 
 import numpy as np
 
 from ..models.llama import LlamaForCausalLM
+from ..observability import compile_trace
 from ..ops import sampling
 from . import kv_migrate
 from .cache import BlockCacheManager
@@ -172,6 +174,7 @@ class LlamaInferenceEngine(kv_migrate.PagedPools):
         import jax
         import jax.numpy as jnp
 
+        began = time.time()     # `engine.build_s`: this line to the last
         cfg = model.config
         self.config = cfg
         self.block_size = block_size
@@ -234,6 +237,7 @@ class LlamaInferenceEngine(kv_migrate.PagedPools):
             "kv_heads": kvh, "head_dim": d,
             "dtype": str(self.pools[0].dtype),
         }
+        compile_trace.stamp("engine.build", began)
 
     def cost_card_args(self, phase: str):
         """Observability hook (`observability.costs.ensure_engine_card`):

@@ -6,12 +6,15 @@ said "how many" but never "why", and a faulted request's lifecycle could
 only be reconstructed from print statements. This package is the layer
 that lets every perf claim be *derived* instead of asserted:
 
-- **Compile & retrace tracing** (`compile_trace.py`): every executable
-  compile records wall time and a structure-key signature; a retrace
-  additionally records a human-readable diff against the nearest cached
-  entry — which aval shape/dtype or static arg changed. Wired into
-  `core.dispatch` (eager/lazy executables) and the serving scheduler
-  (engine prefill/decode/verify signatures).
+- **Compile & retrace tracing** (`compile_trace.py`): ONE record a
+  top-level compile, fed by JAX's own monitoring events and always on
+  (which function, seconds tracing, lowering, compiling or reading the
+  persistent cache); under `enable()` a retrace additionally carries a
+  human-readable diff against the nearest cached signature — which aval
+  shape/dtype or static arg changed — attached by `core.dispatch`
+  (eager/lazy executables) and the serving scheduler (engine dispatch
+  signatures). The stamps of a set-up that are not JAX's live beside it
+  (`stamp`: `startup.import_s`, `engine.build_s`).
 - **XLA cost-based accounting** (`costs.py`): `CostCard` wraps
   `lower().compile().cost_analysis()/memory_analysis()` — compiler-
   reported FLOPs, bytes accessed, and memory footprint per executable,
@@ -35,10 +38,11 @@ that lets every perf claim be *derived* instead of asserted:
   `flight_oom_*.jsonl` dump on KV exhaustion / backend allocation
   failure.
 
-Everything is OFF by default and costs nothing while off: instrumented
-sites check one module-level bool (`enabled()`); no span is allocated, no
-signature is built, and `cost_analysis()` is never invoked when disabled
-(asserted by tests/test_observability.py).
+Everything but the compile record is OFF by default and costs nothing
+while off: instrumented sites check one module-level bool (`enabled()`);
+no span is allocated, no signature is built, and `cost_analysis()` is
+never invoked when disabled (asserted by tests/test_observability.py).
+The compile record's listeners run only when JAX compiles.
 """
 from __future__ import annotations
 

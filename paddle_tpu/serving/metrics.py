@@ -303,7 +303,11 @@ class ServingMetrics:
             round(self._spec_produced / max(self._spec_steps, 1), 2))
 
     def on_step(self, occupancy: float, kv_utilization: float,
-                queue_depth: int, decoded: bool = True):
+                queue_depth: int, decoded: bool = True,
+                wall_s: float = 0.0):
+        # every round's wall, warm-up and lane fill included: what a
+        # set-up spent stepping (`setup_fill_s`) is this less the window's
+        monitor.inc("serving.step.wall_s", wall_s)
         # occupancy averages over DECODE steps only — idle polling rounds
         # (no sequence in flight) say nothing about batching efficiency
         if decoded:

@@ -761,7 +761,10 @@ class Scheduler:
         number of tokens produced this step."""
         self._step_index += 1
         with RecordEvent("sched.step", step=self._step_index):
-            now = self._clock() if now is None else now
+            # the round's own start, for its wall: a caller's `now` (a
+            # replayed or synthetic clock) only decides the scheduling
+            began = self._clock()
+            now = began if now is None else now
             finish_mark = self._finish_events
             with RecordEvent("sched.expire"):
                 self._expire(now)
@@ -790,7 +793,8 @@ class Scheduler:
                 occupancy=produced / len(self.slots),
                 kv_utilization=mgr.utilization(),
                 queue_depth=len(self.waiting),
-                decoded=produced > 0)
+                decoded=produced > 0,
+                wall_s=self._clock() - began)
             return produced
 
     @property
@@ -880,6 +884,7 @@ class Scheduler:
             # trace-time counter snapshot: a bump during the call below
             # means THIS dispatch retraced — its signature diff is the why
             retraces_before = _monitor.get(f"serving.{phase}_retraces")
+            compiles_before = _obs.compile_trace.mark()
         t0 = self._clock()
         try:
             out = fn(*args)
@@ -898,20 +903,23 @@ class Scheduler:
             # (a verify dispatch commits up to K+1 per lane).
             self._last_decode_dt = dt
         if obs_on:
-            self._obs_dispatch(phase, args, t0, dt, retraces_before)
+            self._obs_dispatch(phase, args, t0, dt, retraces_before,
+                               compiles_before)
         return out, flagged
 
     def _obs_dispatch(self, phase: str, args, t0: float, dt: float,
-                      retraces_before: int):
+                      retraces_before: int, compiles_before: int):
         """Observability bookkeeping for one successful dispatch: retrace
         cause attribution (signature diff vs the previous dispatch of the
-        same phase), the engine-track timeline span, per-executable call
+        same phase, put on the compile record the dispatch made since
+        `compiles_before`), the engine-track timeline span, per-executable call
         accounting, and — once per phase — the XLA CostCard. Only ever
         called with observability enabled."""
         name = f"serve.{phase}"
         sig = tuple((np.shape(a), str(np.asarray(a).dtype)) for a in args)
         if _monitor.get(f"serving.{phase}_retraces") > retraces_before:
-            cause = _obs.compile_trace.note_retrace(name, sig)
+            cause = _obs.compile_trace.note_retrace(name, sig,
+                                                    compiles_before)
             if cause is not None:   # None = first trace: not a retrace
                 _monitor.inc(f"serving.{phase}_retrace_causes."
                              + ("shape" if "shape" in cause else
