@@ -115,9 +115,11 @@ class _BlockGroup:
     own among them (None: all). A windowed group gives a block back once it
     lies WHOLLY behind the window of the next query the sequence can ever
     ask: released by the length the sequence had BEFORE the append that is
-    being accounted (what is committed), never by the appended length, so a
-    `trim` back to any length since (a rejected speculation, a failed
-    round's rollback) needs no block that is gone. A released entry of the
+    being accounted, less what the caller says is still in flight of it
+    (`append_tokens(in_flight=)`: what is committed), never by the appended
+    length, so a `trim` back to any length since (a rejected speculation,
+    the rollback of a failed round and of the one launched behind it)
+    needs no block that is gone. A released entry of the
     table holds -1 and comes out of `block_table_array` as padding; the
     attention kernel starts its page walk behind it and never reads it.
 
@@ -645,10 +647,15 @@ class BlockCacheManager:
         """Account one generated token; grows the table on block boundary."""
         self.append_tokens(seq_id, 1)
 
-    def append_tokens(self, seq_id: int, n: int) -> None:
+    def append_tokens(self, seq_id: int, n: int, in_flight: int = 0) -> None:
         """Account `n` new tokens at once (the speculative-decode grow path:
         one pending token + K draft tokens per step), growing the block
         table across as many block boundaries as needed.
+
+        `in_flight`: how many of the sequence's tokens so far belong to a
+        round whose result the caller has not seen (it may still `trim`
+        them away): a windowed group releases behind the window of the
+        length less these, the committed one.
 
         Copy-on-write: when the first new token lands inside a block
         whose refcount is >1 (a shared prefix leased from the radix
@@ -682,8 +689,9 @@ class BlockCacheManager:
         if need > 0 and len(table) + need > self.max_blocks_per_seq:
             raise SequenceTooLong(len(table) + need,
                                   self.max_blocks_per_seq, self.name)
+        committed = old_len - in_flight
         for g in self._further:            # all groups grow, or none
-            g.check_append(seq_id, old_len, new_len)
+            g.check_append(seq_id, committed, new_len)
         if max(need, 0) + extra > len(self._free):
             self._ensure_free(max(need, 0) + extra)
         if max(need, 0) + extra > len(self._free):
@@ -695,8 +703,8 @@ class BlockCacheManager:
             table.append(self._take_free())
         for g in self._further:
             # a windowed group gives back what lies behind its window at
-            # `old_len`, the committed length (see `_BlockGroup`)
-            g.append(seq_id, old_len, new_len)
+            # the committed length (see `_BlockGroup`)
+            g.append(seq_id, committed, new_len)
         self._lens[seq_id] = new_len
 
     def _cow(self, seq_id: int, idx: int) -> int:

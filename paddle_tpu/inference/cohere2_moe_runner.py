@@ -212,6 +212,7 @@ class Cohere2MoeInferenceEngine:
         # the screen, the row gather and the sampler end the step's one
         # program (`ops/sampling.with_tail`)
         self._ragged = step(_ragged_fn, sampling.with_tail)
+        self.last_sampled = None    # the last step's `sampled`, on device
         self._verify = step(_verify_fn)
         compile_trace.stamp("engine.build", began)
 
@@ -224,7 +225,9 @@ class Cohere2MoeInferenceEngine:
         every group's table of a lane, side by side."""
         sampled, logits, self.pools, self.counters = self._ragged(
             self.params, self.pools, self.counters,
-            *sampling.call_arrays(tokens, lanes, block_tables, temperature))
+            *sampling.call_arrays(tokens, lanes, block_tables, temperature,
+                                  self.last_sampled))
+        self.last_sampled = sampled
         return sampled, logits
 
     ragged_step = sampling.ragged_step

@@ -169,6 +169,7 @@ class DeepseekV3InferenceEngine:
         # the screen, the row gather and the sampler end the step's one
         # program (`ops/sampling.with_tail`)
         self._ragged = step(_ragged_fn, sampling.with_tail)
+        self.last_sampled = None    # the last step's `sampled`, on device
         self._verify = step(_verify_fn)
         # COW copy (prefix caching): one latent block, every layer, donated;
         # src/dst trace as scalars, so COWs never recompile
@@ -184,7 +185,9 @@ class DeepseekV3InferenceEngine:
         float32)`, both on the device."""
         sampled, logits, self.pool, self.counters = self._ragged(
             self.params, self.pool, self.counters,
-            *sampling.call_arrays(tokens, lanes, block_tables, temperature))
+            *sampling.call_arrays(tokens, lanes, block_tables, temperature,
+                                  self.last_sampled))
+        self.last_sampled = sampled
         return sampled, logits
 
     ragged_step = sampling.ragged_step

@@ -225,6 +225,7 @@ class LlamaInferenceEngine(kv_migrate.PagedPools):
         # the serving step ends in the screen, the row gather and the
         # sampler (`ops/sampling.with_tail`): one program a round
         self._ragged = step(_ragged_fn, sampling.with_tail)
+        self.last_sampled = None    # the last step's `sampled`, on device
         self._verify = step(_verify_fn)
         # COW copy and KV migration over the block axis (axis 1, all
         # layers at once): `kv_migrate.PagedPools`
@@ -291,7 +292,9 @@ class LlamaInferenceEngine(kv_migrate.PagedPools):
         executable regardless of batch composition or prompt length."""
         sampled, logits, self.pools = self._ragged(
             self.params, self.pools,
-            *sampling.call_arrays(tokens, lanes, block_tables, temperature))
+            *sampling.call_arrays(tokens, lanes, block_tables, temperature,
+                                  self.last_sampled))
+        self.last_sampled = sampled
         return sampled, logits
 
     ragged_step = sampling.ragged_step

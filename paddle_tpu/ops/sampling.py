@@ -25,6 +25,13 @@ the gather of each lane's last row and the sampler above are the END of
 the step's one program (`step_tail`), and what crosses to the host is one
 `[2, B]` int32 array. `sample_tokens` stays for the speculative verify
 round, whose `[B, S, V]` logits it samples whole.
+
+A round's decode tokens need not cross to the host between two rounds:
+`with_tail` reads a token `-(b + 1)` as "what lane `b` sampled in this
+engine's previous sampled step", out of that step's `sampled`, which every
+engine keeps on the device (`last_sampled`) and `call_arrays` sends back
+in. The scheduler launches round n+1 on such tokens before it has fetched
+round n (docs/SERVING.md "A round in flight").
 """
 from __future__ import annotations
 
@@ -33,7 +40,8 @@ import functools
 import numpy as np
 
 __all__ = ["sample_tokens", "step_tail", "with_tail", "pack_lanes",
-           "call_arrays", "step_args", "ragged_step", "LANE_COLS"]
+           "call_arrays", "step_args", "ragged_step", "fed_token",
+           "LANE_COLS"]
 
 # the per-lane int32 block of a sampled step, one column each: with
 # `tokens`, `tables` and `temperature` it is everything a round sends
@@ -157,15 +165,34 @@ def step_tail(logits, lanes, temperature):
     return jnp.stack([picked, finite.astype(jnp.int32)])
 
 
+def fed_token(lane: int) -> int:
+    """The token id that stands for "what `lane` sampled in the engine's
+    previous sampled step" (`with_tail` resolves it on the device)."""
+    return -(lane + 1)
+
+
 def with_tail(logits_step):
     """An engine's logits step `(*state, tokens, q_lens, kv_lens, tables)
     -> (logits [T, V], *state)` as the sampled step `(*state, tokens,
-    lanes, tables, temperature) -> (sampled [2, B], logits, *state)`:
+    lanes, tables, temperature, fed) -> (sampled [2, B], logits, *state)`:
     `step_tail` over the same logits, for `jax.jit` to compile as ONE
     program. Whatever leads the arguments (params, pools, adapters,
-    counters) passes through, so donation indices stay the engine's."""
+    counters) passes through, so donation indices stay the engine's.
+
+    `fed` `[2, B]` int32 is the `sampled` of the engine's previous call
+    (zeros before the first): before the stack, a token `fed_token(b)` is
+    replaced by `fed[0, b]`, so a decode lane can take the token it has
+    just sampled without the host ever reading it. Same shape and dtype
+    every call, and no token is negative unless a caller makes it so:
+    still one executable, computing what it always computed."""
     def _ragged_fn(*args):
-        *state, tokens, lanes, tables, temperature = args
+        import jax
+        import jax.numpy as jnp
+
+        *state, tokens, lanes, tables, temperature, fed = args
+        with jax.named_scope("llama.feed"):
+            lane = jnp.clip(-tokens - 1, 0, fed.shape[1] - 1)
+            tokens = jnp.where(tokens < 0, fed[0][lane], tokens)
         logits, *state = logits_step(*state, tokens, lanes[:, _Q_LEN],
                                      lanes[:, _KV_LEN], tables)
         return (step_tail(logits, lanes, temperature), logits, *state)
@@ -175,18 +202,24 @@ def with_tail(logits_step):
     return _ragged_fn
 
 
-def call_arrays(tokens, lanes, block_tables, temperature):
-    """A sampled step's four call arrays as exact-dtype numpy: they go to
-    the jit raw, the C++ dispatch path transfers them far cheaper than
-    per-argument host-side `device_put` calls (this is the decode loop)."""
-    return (np.asarray(tokens, np.int32), np.asarray(lanes, np.int32),
+def call_arrays(tokens, lanes, block_tables, temperature, fed=None):
+    """A sampled step's call arrays: the round's four as exact-dtype numpy
+    (they go to the jit raw, the C++ dispatch path transfers them far
+    cheaper than per-argument host-side `device_put` calls: this is the
+    decode loop), then `fed`, the engine's `last_sampled`, as it lies on
+    the device (zeros when nothing precedes: lowering, a fresh engine)."""
+    lanes = np.asarray(lanes, np.int32)
+    if fed is None:
+        fed = np.zeros((2, lanes.shape[0]), np.int32)
+    return (np.asarray(tokens, np.int32), lanes,
             np.asarray(block_tables, np.int32),
-            np.asarray(temperature, np.float32))
+            np.asarray(temperature, np.float32), fed)
 
 
 def step_args(tokens, q_lens, kv_lens, block_tables):
-    """`call_arrays` for greedy lanes sampling their last packed rows:
-    what `ragged_step` sends, and what lowers the step at those shapes."""
+    """`call_arrays` for greedy lanes sampling their last packed rows, fed
+    nothing: what `ragged_step` sends (the engine adds its own `fed`), and
+    what lowers the step at those shapes."""
     q_lens = np.asarray(q_lens, np.int32)
     return call_arrays(tokens, pack_lanes(q_lens, kv_lens), block_tables,
                        np.zeros(q_lens.shape, np.float32))
@@ -197,5 +230,5 @@ def ragged_step(engine, tokens, q_lens, kv_lens, block_tables):
     `[T, V]` of one step, for `generate`, proposers and checks that sample
     on the host. The SAME compiled program as the scheduler's round
     (`sampled_step`), its lanes greedy and its tokens left on the device."""
-    return engine.sampled_step(
-        *step_args(tokens, q_lens, kv_lens, block_tables))[1]
+    *round_arrays, _fed = step_args(tokens, q_lens, kv_lens, block_tables)
+    return engine.sampled_step(*round_arrays)[1]

@@ -545,6 +545,17 @@ class Profiler:
             f"queue depth {g('serving.queue_depth')} "
             f"(peak {g('serving.queue_depth_peak')})",
         ]
+        if g("serving.step.programs"):
+            # a plain round is one program and one fetch, launched before
+            # the round before it is fetched (docs/SERVING.md "A round in
+            # flight")
+            lines.append(
+                f"  rounds: {g('serving.step.programs')} programs, "
+                f"{g('serving.step.fetches')} fetches, "
+                f"{g('serving.step.overlapped')} overlapped (share "
+                f"{g('serving.step.overlap_share')}), "
+                f"{g('serving.step.wasted_lanes')} wasted lanes, "
+                f"{g('serving.step.forced_settles')} forced settles")
         if g("serving.ttft_p50_ms"):
             lines.append(
                 f"  TTFT p50 {g('serving.ttft_p50_ms')} ms / "

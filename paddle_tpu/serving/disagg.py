@@ -46,7 +46,7 @@ is empty (availability beats specialization).
 from __future__ import annotations
 
 import enum
-from typing import Callable
+from typing import Callable, List
 
 from ..framework import monitor as _monitor
 from ..resilience import faults as _faults
@@ -119,18 +119,26 @@ class DisaggRouter(FleetRouter):
         moved = 0
         for src in [r for r in self._replicas
                     if r.alive and not r.draining and r.role == "prefill"]:
-            ready = [fh for fh in self._handles
-                     if fh._replica is src
-                     and not fh._req.status.terminal
-                     and fh._req.status is RequestStatus.RUNNING
-                     and not fh._req.prefilling
-                     and fh._req.generated]
-            for fh in ready:
+            # a session leaves with its next token in flight: commit it
+            # first (it may END the session, which then stays here), and
+            # only then list who leaves
+            if not self._prefilled(src) or not self._settle_replica(src):
+                continue
+            for fh in self._prefilled(src):
                 if self._handoff_one(src, fh):
                     moved += 1
                 if not src.alive:
                     break               # chaos killed the source mid-pump
         return moved
+
+    def _prefilled(self, src: ReplicaHandle) -> List[FleetHandle]:
+        """`src`'s running sessions whose prompt is in the cache and whose
+        first token is committed: ready to leave the prefill tier."""
+        return [fh for fh in self._handles
+                if fh._replica is src
+                and fh._req.status is RequestStatus.RUNNING
+                and not fh._req.prefilling
+                and fh._req.generated]
 
     def _handoff_one(self, src: ReplicaHandle, fh: FleetHandle) -> bool:
         """One PREFILLING -> HANDOFF -> DECODING transition; every
@@ -157,6 +165,10 @@ class DisaggRouter(FleetRouter):
             # extraction edge failed (chaos raise / engine fault):
             # fall through to the fold fallback below
             _monitor.inc("fleet.handoff_faults")
+        if req.status.terminal:
+            # ended by the settle that precedes an extraction: it ends here
+            fh._handoff_state = HandoffState.PREFILLING
+            return False
         src.frontend.release(req)
         placed = False
         if payload is not None:

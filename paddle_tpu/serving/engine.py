@@ -11,7 +11,11 @@ exposes ONE compiled way into its model:
   with `q_len` 0) that ENDS in the NaN screen, the gather of each lane's
   last row and the sampler (`ops/sampling.with_tail`), returning
   `(sampled [2, B], logits [T, V])` on the device: a scheduler round is
-  this one program and one fetch of `sampled`;
+  this one program and one fetch of `sampled`. The engine keeps that
+  `sampled` as `last_sampled` and hands it to its next step, where a token
+  `ops/sampling.fed_token(b)` reads lane `b`'s out of it: the scheduler
+  launches a round before it has fetched the one before
+  (docs/SERVING.md "A round in flight");
 - `ragged_step(tokens [T], q_lens [B], kv_lens [B], block_tables)` is the
   same program's logits (`ops/sampling.ragged_step`);
 - `verify_step(tokens [B, S], context_lens [B], block_tables)` is its
@@ -66,6 +70,9 @@ class EngineCore(Protocol):
 
     max_batch_size: int
     manager: BlockCacheManager
+    # the `sampled` of the last `sampled_step`, still on the device (None
+    # before the first): what a `fed_token` of the next step reads
+    last_sampled: object
 
     def verify_step(self, tokens: np.ndarray, context_lens: np.ndarray,
                     block_tables: np.ndarray) -> np.ndarray:
@@ -309,6 +316,7 @@ class MLPLMEngine(kv_migrate.PagedPools):
             sampling.with_tail(
                 functools.partial(_mlp_ragged, block_size=block_size)),
             donate_argnums=(1,))
+        self.last_sampled = None    # the last step's `sampled`, on device
         self._verify = jax.jit(
             functools.partial(_mlp_verify, block_size=block_size),
             donate_argnums=(1,))
@@ -380,7 +388,9 @@ class MLPLMEngine(kv_migrate.PagedPools):
         """Packed ragged step, sampled; see `EngineCore.sampled_step`."""
         sampled, logits, self.pools = self._ragged(
             self.params, self.pools,
-            *sampling.call_arrays(tokens, lanes, block_tables, temperature))
+            *sampling.call_arrays(tokens, lanes, block_tables, temperature,
+                                  self.last_sampled))
+        self.last_sampled = sampled
         return sampled, logits
 
     ragged_step = sampling.ragged_step

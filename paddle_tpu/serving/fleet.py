@@ -323,7 +323,7 @@ class FleetRouter:
             return
         rep.draining = True
         _monitor.inc("fleet.drains")
-        if relocate:
+        if relocate and self._settle_replica(rep):
             for fh in [fh for fh in self._handles
                        if fh._replica is rep
                        and not fh._req.status.terminal]:
@@ -331,6 +331,20 @@ class FleetRouter:
                     continue            # over budget: finish in place
                 self._relocate(fh, reason="drain", live_source=True)
         self._publish_gauges()
+
+    def _settle_replica(self, rep: ReplicaHandle) -> bool:
+        """Commit `rep`'s round in flight BEFORE its live requests are
+        listed for a move (a drain, a handoff). The settle commits each
+        lane's token, and that token can end its request (EOS, a stop, a
+        NaN conviction): a request it ended stays ended and is never
+        moved. A settle that raises is a step that raised, so the replica
+        is dead and `fail_replica` has relocated what it held. Returns
+        whether the replica still lives."""
+        try:
+            rep.frontend.scheduler.settle()
+        except Exception:
+            self.fail_replica(rep.replica_id, reason="step_raised")
+        return rep.alive
 
     def fail_replica(self, replica_id: str,
                      reason: str = "killed") -> List[FleetHandle]:
@@ -615,13 +629,21 @@ class FleetRouter:
         """Best-effort KV export from a still-live source replica.
         Returns a `KVBlockPayload` or None (engine without the
         primitive, no resident blocks, or an extraction fault) — None
-        just means the relocation re-prefills."""
+        just means the relocation re-prefills. The scheduler's round in
+        flight is settled first, which can END `req` (or any other
+        request of the replica): the caller looks at `req.status` again
+        before it moves anything."""
         try:
             eng = src.frontend.scheduler.engine
             extract = getattr(eng, "extract_kv_blocks", None)
             if extract is None:
                 return None
             if eng.manager.seq_blocks(req.seq_id) <= 0:
+                return None
+            # a round in flight holds the sequence's last token and its
+            # KV write: commit it before the length and blocks are read
+            src.frontend.scheduler.settle()
+            if req.status.terminal:
                 return None
             return extract(req.seq_id)
         except Exception:
@@ -695,6 +717,11 @@ class FleetRouter:
                 and not req.status.terminal:
             # extract BEFORE release: release frees the source blocks
             payload = self._extract_payload(src, req)
+            if req.status.terminal:
+                # the settle before the extraction committed the token in
+                # flight and it ended the request (EOS, a conviction): the
+                # stream ends there, on the source, and nothing moves
+                return
         if live_source and src is not None:
             src.frontend.release(req)
         carried = list(req.generated)

@@ -560,6 +560,7 @@ class LoRAEngine(kv_migrate.PagedPools):
         # round here too (`ops/sampling.with_tail`)
         self._ragged = jax.jit(sampling.with_tail(ragged),
                                donate_argnums=(2,))
+        self.last_sampled = None    # the last step's `sampled`, on device
         self._verify = jax.jit(verify, donate_argnums=(2,))
         # the base's block executables are pure: over THIS engine's
         # pools they cost no extra trace
@@ -615,7 +616,9 @@ class LoRAEngine(kv_migrate.PagedPools):
     def sampled_step(self, tokens, lanes, block_tables, temperature):
         sampled, logits, self.pools = self._ragged(
             self.params, self._adapters, self.pools, self._lane_slots,
-            *sampling.call_arrays(tokens, lanes, block_tables, temperature))
+            *sampling.call_arrays(tokens, lanes, block_tables, temperature,
+                                  self.last_sampled))
+        self.last_sampled = sampled
         return sampled, logits
 
     ragged_step = sampling.ragged_step

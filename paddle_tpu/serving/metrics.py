@@ -61,6 +61,8 @@ class ServingMetrics:
         self._finishes = 0
         self._spec_steps = 0
         self._spec_produced = 0
+        self._plain_rounds = 0
+        self._overlapped = 0
 
     def reset_window(self):
         """Drop latency samples and the occupancy accumulator (e.g. at a
@@ -75,6 +77,8 @@ class ServingMetrics:
         self._finishes = 0
         self._spec_steps = 0
         self._spec_produced = 0
+        self._plain_rounds = 0
+        self._overlapped = 0
 
     # ---- request lifecycle ----
     def on_submit(self):
@@ -135,6 +139,30 @@ class ServingMetrics:
     def on_step_fetch(self):
         """The scheduler blocked on one device-to-host fetch."""
         monitor.inc("serving.step.fetches")
+
+    def on_round_launched(self, overlapped: bool):
+        """A plain round was launched, `overlapped` while the one before
+        it was still unfetched (its host work then runs under the
+        device's): the share of such rounds is the gauge."""
+        self._plain_rounds += 1
+        if overlapped:
+            self._overlapped += 1
+            monitor.inc("serving.step.overlapped")
+        monitor.set_gauge("serving.step.overlap_share",
+                          round(self._overlapped / self._plain_rounds, 4))
+
+    def on_forced_settle(self):
+        """A round in flight was settled BEFORE the next launch because
+        the engine's `last_sampled` was not that round's `sampled` (someone
+        else stepped the engine, or a wrapper does not forward the
+        attribute): that launch fed no token on the device."""
+        monitor.inc("serving.step.forced_settles")
+
+    def on_wasted_lanes(self, n: int):
+        """`n` lanes of a settled round belonged to requests that had left
+        their slots since its launch (finished by EOS on the token
+        before, cancelled, preempted, convicted): computed, never read."""
+        monitor.inc("serving.step.wasted_lanes", n)
 
     def on_prefill_chunk(self, num_tokens: int):
         """`num_tokens` of pending-prompt context entered the cache via

@@ -71,6 +71,12 @@ round it is the tail of the engine step's own program, with the NaN
 screen and the gather of each lane's last row: one program and one host
 fetch a round (docs/SERVING.md "One program, one fetch"); the
 speculative round runs screen and sampler as programs of their own.
+
+A plain round is LAUNCHED by one `step()` and SETTLED (fetched, screened,
+committed) by the next, after that one has launched its own: the decode
+lanes' tokens go from round to round on the device, and the scheduler's
+Python, the launch and the fetch run under the device's work
+(docs/SERVING.md "A round in flight").
 """
 from __future__ import annotations
 
@@ -89,7 +95,8 @@ from ..framework.retry import Budget, retry_call
 from ..inference.cache import KVCacheExhausted, SequenceTooLong
 from ..inference.kv_migrate import KVMigrationError
 from ..inference.prefix_cache import RadixPrefixCache
-from ..ops.sampling import pack_lanes, ragged_step, sample_tokens
+from ..ops.sampling import (call_arrays, fed_token, pack_lanes, ragged_step,
+                            sample_tokens)
 from ..resilience import faults as _faults
 from .engine import EngineCore
 from .lora import AdapterPoolExhausted
@@ -237,6 +244,53 @@ class Request:
         return (self.t_finish - self.t_first_token) / (len(self.generated) - 1)
 
 
+class _Lane:
+    """One request's share of a plain round between the round's launch and
+    its settle: `n` tokens from cache length `pre_len` on, a prefill chunk
+    or one decode token, and whether the round samples a token for it."""
+
+    __slots__ = ("slot", "req", "admit_seq", "n", "pre_len", "chunk",
+                 "samples")
+
+    def __init__(self, slot: int, req: Request, n: int, pre_len: int,
+                 chunk: bool, samples: bool):
+        self.slot, self.req, self.admit_seq = slot, req, req._admit_seq
+        self.n, self.pre_len = n, pre_len
+        self.chunk, self.samples = chunk, samples
+
+    def holds(self, sched: "Scheduler") -> bool:
+        """The request still sits in the slot it was launched from, on the
+        same admission: what left since (finished, cancelled, preempted,
+        convicted) has its lane's result discarded."""
+        return sched.slots[self.slot] is self.req \
+            and self.req._admit_seq == self.admit_seq
+
+
+class _Round:
+    """A plain round in flight: launched, not yet settled. `sampled` is
+    the step's `[2, B]` result, still on the device."""
+
+    __slots__ = ("lanes", "sampled", "flagged", "began", "probe", "error")
+
+    def __init__(self, lanes):
+        self.lanes = lanes            # slot -> _Lane, in slot order
+        self.sampled = None
+        self.flagged = False          # an injected fault poisons one lane
+        self.began = 0.0              # the dispatch's start, `_clock`
+        self.error = None             # what the dispatch raised, and
+        self.probe = None             # what then replays one lane alone
+
+    def pairs(self):
+        return [(i, ln.req) for i, ln in self.lanes.items()]
+
+
+class _SettleFirst(Exception):
+    """Growth met the pool's or a sequence's limit while a round is in
+    flight: who may grow, and who is preempted or finished for it, is
+    decided on committed state, so the launch undoes what it has grown,
+    the round in flight is settled, and the launch starts over."""
+
+
 class Scheduler:
     """Admits requests into decode slots and drives fixed-shape steps."""
 
@@ -283,6 +337,9 @@ class Scheduler:
         # the chunk budget — FIXED for the scheduler's lifetime, so the
         # ragged step is one compiled executable
         self.ragged_tokens = engine.max_batch_size + self.prefill_chunk_tokens
+        # what a windowed block group holds of a lane beyond its window:
+        # the chunk being appended and the one still in flight before it
+        self._window_step = 2 * self.prefill_chunk_tokens
         self.engine_factory = engine_factory
         self.nan_checks = nan_checks
         self._overload = OverloadController(admission) if admission else None
@@ -316,6 +373,12 @@ class Scheduler:
         self._finite_fn = None               # jitted NaN screen, lazy
         self._last_decode_dt: Optional[float] = None
         self._chunk_progress = 0             # prefill tokens last round
+        # the plain round launched and not yet settled (docs/SERVING.md
+        # "A round in flight"), when the one before it was settled, and
+        # the decode tokens this `step()` has committed
+        self._launched: Optional[_Round] = None
+        self._settled_at = 0.0
+        self._produced = 0
         self._prefix_enabled = bool(prefix_cache)
         self._prefix_tree: Optional[RadixPrefixCache] = None
         self._slo = slo
@@ -460,8 +523,7 @@ class Scheduler:
         for g, _pad in self._further_pads:
             # a further group holds a window's worth of a prompt at most,
             # and that has to fit its pool beside the guard block
-            if mgr.blocks_needed(len(req.prompt) + 1, g,
-                                 self.prefill_chunk_tokens) \
+            if mgr.blocks_needed(len(req.prompt) + 1, g, self._window_step) \
                     > mgr.num_blocks_of(g) - 1:
                 return self._reject(req, "prompt_too_long")
         if req.adapter is not None:
@@ -516,6 +578,7 @@ class Scheduler:
         skipped: an import carries already-spent prefill work, and
         turning it away would discard it (capacity pressure still
         rejects through the queue/pool checks)."""
+        self.settle()
         now = self._clock() if now is None else now
         if req.t_submit is None:
             req.t_submit = now
@@ -635,6 +698,7 @@ class Scheduler:
         extract = getattr(self.engine, "extract_kv_blocks", None)
         if tree is None or extract is None:
             return None
+        self.settle()
         blocks, hit = tree.match_export(tokens)
         if not blocks:
             return None
@@ -662,6 +726,7 @@ class Scheduler:
         inject = getattr(self.engine, "inject_kv_blocks", None)
         if tree is None or inject is None:
             return 0
+        self.settle()
         toks = np.asarray(tokens).reshape(-1).tolist()
         n = int(payload.num_tokens)
         if n < 1 or len(toks) < n:
@@ -757,8 +822,13 @@ class Scheduler:
     # ---- the step ----
     def step(self, now: Optional[float] = None) -> int:
         """One scheduling round: expire deadlines, admit into free slots,
-        run one fixed-shape decode over the occupied slots. Returns the
-        number of tokens produced this step."""
+        LAUNCH one fixed-shape step over the occupied slots, then SETTLE
+        the step launched by the call before (docs/SERVING.md "A round in
+        flight"). Returns the number of tokens it committed, which are
+        that earlier launch's: the first call after a lull commits none,
+        and a call with nothing to launch settles what is in flight.
+        `idle` stays false until the last launch is settled. (A
+        speculative scheduler launches and settles in the same call.)"""
         self._step_index += 1
         with RecordEvent("sched.step", step=self._step_index):
             # the round's own start, for its wall: a caller's `now` (a
@@ -771,12 +841,14 @@ class Scheduler:
             with RecordEvent("sched.admit"):
                 admitted = self._admit(now)
             produced = self._decode(now)
-            # progress = tokens, prefill-chunk advancement, admissions, or
-            # terminal transitions; a non-idle scheduler sustaining zero
+            # progress = tokens, prefill-chunk advancement, admissions,
+            # terminal transitions, or a round launched (its tokens come
+            # with the next call); a non-idle scheduler sustaining zero
             # progress is wedged — the watchdog's restart trigger and
             # `EngineStalled`'s evidence
             if produced > 0 or admitted > 0 or self._chunk_progress > 0 \
-                    or self._finish_events > finish_mark:
+                    or self._finish_events > finish_mark \
+                    or self._launched is not None:
                 self._zero_progress = 0
             else:
                 self._zero_progress += 1
@@ -803,7 +875,8 @@ class Scheduler:
 
     @property
     def idle(self) -> bool:
-        return self.num_running == 0 and not self.waiting
+        return self.num_running == 0 and not self.waiting \
+            and self._launched is None
 
     @property
     def zero_progress_steps(self) -> int:
@@ -930,6 +1003,8 @@ class Scheduler:
         _obs.costs.record_call(name, dt)
         # the card lowers the engine fn once (one extra trace, charged to
         # the counters AFTER the snapshot above — never misattributed)
+        if phase == "decode":
+            args = call_arrays(*args)    # with the `fed` the engine adds
         _obs.costs.ensure_engine_card(name, self.engine, phase, args)
 
     def _obs_req(self, req: Request, name: str, t0: Optional[float] = None,
@@ -1070,6 +1145,9 @@ class Scheduler:
         # raising would burn TWO budget units (escalation restart, then
         # the stale pending stall restarting the fresh engine)
         self._pending_stall = None
+        # a round in flight dies with the engine: its lanes' requests
+        # re-queue below with the tokens committed so far
+        self._launched = None
         if _obs.enabled():
             # post-mortem evidence FIRST: the ring holds the rounds that
             # led here, and the rebuild below may fail everything
@@ -1374,7 +1452,7 @@ class Scheduler:
         (none for an engine of one layer kind): what the context holds of
         the group at once, a window's worth at most, against the group's
         free blocks less what the lanes still prefilling will take."""
-        step = self.prefill_chunk_tokens
+        step = self._window_step
         for g, _pad in self._further_pads:
             debt = sum(
                 max(0, mgr.blocks_needed(len(r._prefill_ctx), g, step)
@@ -1391,11 +1469,14 @@ class Scheduler:
         the last leased block's slack) holds before anyone is preempted —
         the prefill analog of `_grow_n`'s drop-the-drafts degrade.
         Returns tokens reserved (0 = nothing this round, or the request
-        left the batch)."""
+        left the batch). Raises `_SettleFirst` where it would preempt or
+        finish while a round is in flight."""
         mgr = self.engine.manager
+        ahead = self._ahead(slot, req)
+        held = ahead.n if ahead is not None else 0
         while True:
             try:
-                mgr.append_tokens(req.seq_id, want)
+                mgr.append_tokens(req.seq_id, want, in_flight=held)
                 return want
             except SequenceTooLong:
                 cap = mgr.max_blocks_per_seq * mgr.block_size \
@@ -1403,6 +1484,8 @@ class Scheduler:
                 if cap >= 1:
                     want = min(want, cap)
                     continue
+                if self._launched is not None:
+                    raise _SettleFirst from None
                 # unreachable for submit-screened prompts (ctx + 1 fits
                 # the per-seq cap); terminal rather than a spin if an
                 # engine swap shrank the cap under a live request
@@ -1412,7 +1495,8 @@ class Scheduler:
             except KVCacheExhausted as e:
                 # capacity already in hand: the leased blocks' unused
                 # tail (a fresh admission holds one ENTIRELY empty
-                # block), plus whatever the free pool still has
+                # block), plus whatever the free pool still has. A
+                # shorter chunk moves no one else, so it needs no settle
                 slack = mgr.seq_blocks(req.seq_id) * mgr.block_size \
                     - mgr.seq_len(req.seq_id)
                 # `e.free`: what the group that ran out (`e.group`) has
@@ -1420,6 +1504,8 @@ class Scheduler:
                 if 1 <= fit < want:
                     want = fit
                     continue
+                if self._launched is not None:
+                    raise _SettleFirst from None
                 if _obs.enabled():
                     self._obs_oom("kv_exhausted", need=e.need, free=e.free,
                                   total=e.total, seq_id=req.seq_id,
@@ -1496,57 +1582,153 @@ class Scheduler:
         return True
 
     def _decode(self, now: float) -> int:
-        """One ragged round: decode lanes (one token each) plus up to
-        `prefill_chunk_tokens` pending-prompt tokens, packed into ONE
-        fixed-shape `engine.sampled_step` dispatch, whose program also
-        screens and samples: the round blocks once, on `[2, B]` tokens
-        and finiteness flags. Returns decode tokens committed (prefill
-        progress is tracked separately)."""
+        """One plain round's worth of `step()`: LAUNCH the next round
+        (grow, pack, dispatch: `_launch`), then SETTLE the one launched
+        before it (fetch `[2, B]`, screen, commit: `_settle`), so the
+        host's work for round n+1 and round n's fetch run under the
+        device's work. Returns the decode tokens committed, which are the
+        older round's (prefill progress is tracked separately)."""
         self._chunk_progress = 0
+        self._produced = 0
         if self.spec is not None:
+            # a speculative scheduler launches no plain round, so none is
+            # ever in flight here
             return self._decode_spec(now)
+        flying = self._launched
+        if flying is not None \
+                and self.engine.last_sampled is not flying.sampled:
+            # the engine has stepped for someone else since (a shared
+            # engine, a caller's `generate`), or a wrapper hides the
+            # engine's array: its last `sampled` is not this round's, so
+            # no lane can be fed on the device. Counted, because a
+            # deployment that always lands here serves with no overlap
+            self.metrics.on_forced_settle()
+            self.settle()
+        newer = self._launch()         # may settle `_launched` under pressure
+        older, self._launched = self._launched, newer
+        if older is not None:
+            self._settle(older)        # a fetch fault drops `newer` too
+        if newer is not None and newer.error is not None \
+                and self._launched is newer:
+            # the dispatch raised; the round before it is settled by now,
+            # so today's probe replays each lane on the host's tokens
+            self._launched = None
+            self._step_fault("decode", newer.error, newer.pairs(),
+                             probe=newer.probe,
+                             rollback=lambda _s: self._rollback(
+                                 newer.lanes.values()))
+        return self._produced
+
+    def settle(self) -> int:
+        """Fetch and commit the round in flight, if there is one: what
+        reads a running sequence's KV, length or pending token from
+        outside a round (a session's or a prefix's export and import, KV
+        migration) calls this first. Returns the tokens it committed."""
+        rnd, self._launched = self._launched, None
+        if rnd is None:
+            return 0
+        before = self._produced
+        self._settle(rnd)
+        return self._produced - before
+
+    def _ahead(self, slot: int, req: Request) -> Optional["_Lane"]:
+        """`req`'s lane in the round still in flight, or None."""
+        rnd = self._launched
+        lane = rnd.lanes.get(slot) if rnd is not None else None
+        return lane if lane is not None and lane.holds(self) else None
+
+    def _rollback(self, lanes) -> None:
+        """Undo the growth of `lanes` (a newer round's before an older
+        one's) where the request still holds its slot, so that the next
+        round replays it cleanly from the length before the oldest."""
+        mgr = self.engine.manager
+        for lane in lanes:
+            if lane.holds(self):
+                mgr.trim(lane.req.seq_id, lane.pre_len)
+
+    def _grow_lanes(self, active, plan) -> None:
+        """Grow the launch's lanes and append them to `plan`: `(lane,
+        first)` in slot order, `first` a chunk's start in the request's
+        context or a decode lane's token (`fed_token(slot)` where it is
+        still on the device). Raises `_SettleFirst` where growth meets a
+        limit with a round in flight: `plan` then holds what was grown."""
+        mgr = self.engine.manager
+        budget = self.prefill_chunk_tokens
+        for i, req in active:
+            if self.slots[i] is not req:
+                continue
+            ahead = self._ahead(i, req)
+            pos = req._prefill_pos + (ahead.n if ahead is not None
+                                      and ahead.chunk else 0)
+            rem = len(req._prefill_ctx) - pos
+            pre_len = mgr.seq_len(req.seq_id)
+            if rem > 0:
+                if budget <= 0:
+                    continue           # next step's budget serves it
+                try:
+                    got = self._grow_chunk(req, i, min(rem, budget))
+                except _SettleFirst:
+                    raise
+                except Exception:      # injected/corrupt cache state
+                    self._isolated(req, "engine_fault:cache", "cache",
+                                   slot=i)
+                    continue
+                if got:
+                    budget -= got
+                    plan.append((_Lane(
+                        i, req, got, pre_len, chunk=True,
+                        samples=got == rem and req._last is None), pos))
+                continue
+            fed = ahead is not None and ahead.samples
+            if fed and len(req.generated) + 1 \
+                    >= req.sampling.max_new_tokens:
+                continue               # its token in flight is its last
+            try:
+                ok = self._grow(req, i)
+            except _SettleFirst:
+                raise
+            except Exception:          # injected/corrupt cache state:
+                self._isolated(req, "engine_fault:cache", "cache",
+                               slot=i)
+                continue               # attribution is trivial
+            if ok:
+                plan.append((_Lane(i, req, 1, pre_len, chunk=False,
+                                   samples=True),
+                             fed_token(i) if fed else req._last))
+
+    def _launch(self) -> Optional["_Round"]:
+        """Grow, pack and dispatch one ragged round: decode lanes (one
+        token each) plus up to `prefill_chunk_tokens` pending-prompt
+        tokens, in ONE fixed-shape `engine.sampled_step` whose program
+        also screens and samples. Nothing here waits for the device.
+
+        With a round still in flight (`_launched`) a lane is planned from
+        what is known of it without its token: its chunk's end, its
+        cache length and draw index with the token in flight counted, and
+        whether that token is its last by `max_new_tokens` (then it has no
+        lane here). The token itself is fed on the device
+        (`ops/sampling.fed_token`). Returns the round, None when there was
+        nothing to launch; a round whose dispatch raised comes back with
+        `error` set and its growth in place, for `_decode` to attribute."""
         active = [(i, r) for i, r in enumerate(self.slots) if r is not None]
         if not active:
-            return 0
+            return None
         mgr = self.engine.manager
         with RecordEvent("sched.grow"):
             # grow (and possibly preempt) before building the batch arrays
-            decode_lanes = []              # (slot, req)
-            chunks = []                    # (slot, req, n_tokens, pre_len)
-            budget = self.prefill_chunk_tokens
-            for i, req in active:
-                if self.slots[i] is not req:
-                    continue
-                if req.prefilling:
-                    if budget <= 0:
-                        continue           # next step's budget serves it
-                    rem = len(req._prefill_ctx) - req._prefill_pos
-                    pre_len = mgr.seq_len(req.seq_id)
-                    try:
-                        got = self._grow_chunk(req, i, min(rem, budget))
-                    except Exception:      # injected/corrupt cache state
-                        self._isolated(req, "engine_fault:cache", "cache",
-                                       slot=i)
-                        continue
-                    if got:
-                        budget -= got
-                        chunks.append((i, req, got, pre_len))
-                else:
-                    try:
-                        ok = self._grow(req, i)
-                    except Exception:      # injected/corrupt cache state:
-                        self._isolated(req, "engine_fault:cache", "cache",
-                                       slot=i)
-                        continue           # attribution is trivial
-                    if ok:
-                        decode_lanes.append((i, req))
+            plan = []
+            try:
+                self._grow_lanes(active, plan)
+            except _SettleFirst:
+                self._rollback(ln for ln, _first in plan)
+                self.settle()
+                plan.clear()
+                self._grow_lanes(active, plan)
             # growth-path preemptions may have evicted earlier entries
-            decode_lanes = [(i, r) for i, r in decode_lanes
-                            if self.slots[i] is r]
-            chunks = [(i, r, n, p) for i, r, n, p in chunks
-                      if self.slots[i] is r]
-        if not decode_lanes and not chunks:
-            return 0
+            plan = [(ln, first) for ln, first in plan
+                    if self.slots[ln.slot] is ln.req]
+        if not plan:
+            return None
         with RecordEvent("sched.pack"):
             B = len(self.slots)
             T = self.ragged_tokens
@@ -1555,57 +1737,46 @@ class Scheduler:
             kv_lens = np.zeros((B,), np.int32)
             tables = self._pad_tables(mgr, B)
             rows = np.zeros((B,), np.int32)   # last packed row per lane
-            decode_set = {i for i, _r in decode_lanes}
-            chunk_of = {i: (n, p) for i, _r, n, p in chunks}
-            pre_lens = {}                     # seq_id -> pre-round cache len
-            cursor = 0
-            for i in range(B):                # slot order = packing order
-                req = self.slots[i]
-                if req is None:
-                    continue
-                if i in decode_set:
-                    tokens[cursor] = req._last
-                    q_lens[i] = 1
-                    kv_lens[i] = mgr.seq_len(req.seq_id)
-                    pre_lens[req.seq_id] = int(kv_lens[i]) - 1
-                    rows[i] = cursor
-                    cursor += 1
-                elif i in chunk_of:
-                    n, p = chunk_of[i]
-                    tokens[cursor:cursor + n] = req._prefill_ctx[
-                        req._prefill_pos:req._prefill_pos + n]
-                    q_lens[i] = n
-                    kv_lens[i] = mgr.seq_len(req.seq_id)   # == p + n
-                    pre_lens[req.seq_id] = p
-                    rows[i] = cursor + n - 1
-                    cursor += n
-                else:
-                    continue
-                tables[i] = mgr.block_table_array([req.seq_id])[0]
-            all_lanes = decode_lanes + [(i, r) for i, r, _n, _p in chunks]
             # the step samples every lane's LAST packed row itself (fixed
             # [B] shape): decode lanes commit their token; a prefill lane
             # samples only on the round its final chunk completes (counter
             # draw_idx 0 — exactly the draw sequential decode would make).
             # Which lanes those are is known before the dispatch.
             lane_sample: List[Optional[Request]] = [None] * B
-            for i, req in decode_lanes:
-                lane_sample[i] = req
-            for i, req, n, _p in chunks:
-                if req._prefill_pos + n >= len(req._prefill_ctx) \
-                        and req._last is None:
+            fed_lanes = []
+            cursor = 0
+            for lane, first in plan:          # slot order = packing order
+                i, req, n = lane.slot, lane.req, lane.n
+                if lane.chunk:
+                    tokens[cursor:cursor + n] = req._prefill_ctx[
+                        first:first + n]
+                else:
+                    tokens[cursor] = first
+                    if first < 0:
+                        fed_lanes.append(i)
+                q_lens[i] = n
+                kv_lens[i] = mgr.seq_len(req.seq_id)   # == pre_len + n
+                rows[i] = cursor + n - 1
+                cursor += n
+                tables[i] = mgr.block_table_array([req.seq_id])[0]
+                if lane.samples:
                     lane_sample[i] = req
             temps, topks, seeds, draws = self._sampling_arrays(lane_sample)
+            draws[fed_lanes] += 1          # the token in flight is drawn
             lanes = pack_lanes(q_lens, kv_lens, rows, topks, seeds, draws)
+        rnd = _Round({ln.slot: ln for ln, _first in plan})
 
         def probe(i, req):
             """Replay ONE lane of the failed step (same fixed shapes, so
             no recompile; KV writes are position-indexed and idempotent
-            with the retry)."""
+            with the retry). The round before it is settled by now, so a
+            token that was fed on the device is the request's pending one."""
             n = int(q_lens[i])
             start = int(rows[i]) - n + 1
             t = np.zeros((T,), np.int32)
             t[:n] = tokens[start:start + n]
+            if t[0] < 0:
+                t[0] = req._last
             q = np.zeros((B,), np.int32)
             q[i] = n
             kv = np.zeros((B,), np.int32)
@@ -1617,75 +1788,103 @@ class Scheduler:
             # finiteness check reduces over everything returned)
             return np.asarray(ragged_step(self.engine, t, q, kv, tb))[:n]
 
-        def rollback(survivors):
-            # undo this round's growth so the next round replays cleanly
-            for i, r in survivors:
-                mgr.trim(r.seq_id, pre_lens[r.seq_id])
-
         self._install_lane_adapters()
+        overlapped = self._launched is not None
+        rnd.began = self._clock()
         try:
             with RecordEvent("sched.dispatch", phase="decode",
-                             prefill_tokens=sum(n for _i, _r, n, _p
-                                                in chunks),
-                             decode_lanes=len(decode_lanes)):
-                (sampled, _logits), flagged = self._dispatch(
+                             prefill_tokens=sum(ln.n for ln, _first in plan
+                                                if ln.chunk),
+                             decode_lanes=sum(not ln.chunk
+                                              for ln, _first in plan)):
+                (rnd.sampled, _logits), rnd.flagged = self._dispatch(
                     "decode", self.engine.sampled_step, tokens, lanes,
                     tables, temps)
         except Exception as e:
-            self._step_fault("decode", e, all_lanes, probe=probe,
-                             rollback=rollback)
-            return 0
+            rnd.error, rnd.probe = e, probe
+            return rnd
+        self.metrics.on_round_launched(overlapped)
+        return rnd
+
+    def _settle(self, rnd: "_Round") -> None:
+        """The other half of a plain round: block on its `[2, B]` tokens
+        and finiteness flags (the round's ONE fetch), screen, commit. A
+        lane whose request left its slot since the launch (finished on the
+        token before by EOS, cancelled, preempted, convicted) is
+        discarded: its token is never read, its KV write landed in blocks
+        that were its own and went back with the sequence."""
+        t_fetch = self._clock()
         try:
             _faults.check("serve.sample")
             with RecordEvent("sched.sample"):
-                # the round's ONE blocking fetch: every lane's token and
-                # its band's finiteness flag, screened and sampled by the
-                # step's own program; the logits stay on the device
-                picked, finite = np.asarray(sampled)
+                # every lane's token and its band's finiteness flag,
+                # screened and sampled by the step's own program; the
+                # logits stay on the device
+                picked, finite = np.asarray(rnd.sampled)
                 self.metrics.on_step_fetch()
         except Exception as e:
-            self._step_fault("sample", e, all_lanes, rollback=rollback)
-            return 0
-        if flagged or self.nan_checks:
+            # the round launched behind this one read tokens that no one
+            # will ever see: both go, back to the lengths before this one
+            behind, self._launched = self._launched, None
+            lanes = list(rnd.lanes.values())
+            if behind is not None:
+                lanes = list(behind.lanes.values()) + lanes
+            holders = {ln.slot: ln.req for ln in reversed(lanes)}
+            self._step_fault("sample", e, list(holders.items()),
+                             rollback=lambda _s: self._rollback(lanes))
+            return
+        t_tok = self._clock()
+        # the round's wall, what TPOT is priced at: from its launch, or
+        # from the settle before it where it queued behind that round on
+        # the device, to here. The watchdog's budget is held against what
+        # the scheduler WAITED: the fetch here, like the dispatch before
+        # it (`_dispatch`), never the caller's time between two steps (a
+        # slow consumer of `stream()` is no stalled engine)
+        self._last_decode_dt = t_tok - max(rnd.began, self._settled_at)
+        self._settled_at = t_tok
+        if self._wd is not None \
+                and t_tok - t_fetch > self._wd.stall_timeout_s:
+            self.metrics.on_stall()
+            self._pending_stall = "step_timeout:sample"
+        live = [ln for ln in rnd.lanes.values() if ln.holds(self)]
+        if len(live) < len(rnd.lanes):
+            self.metrics.on_wasted_lanes(len(rnd.lanes) - len(live))
+        if live and (rnd.flagged or self.nan_checks):
             finite = finite.astype(bool)
-            if flagged:              # injection path: poison one lane
-                finite[all_lanes[0][0]] = False
-            for i, req in all_lanes:
-                if not finite[i]:
+            if rnd.flagged:          # injection path: poison one lane
+                finite[live[0].slot] = False
+            for ln in live:
+                if not finite[ln.slot]:
                     # the garbage KV went into this lane's own blocks;
                     # freeing the sequence discards it (its token is
-                    # never read)
-                    self._isolated(req, "nan_logits", "decode", slot=i)
-            all_lanes = [(i, r) for i, r in all_lanes
-                         if self.slots[i] is r]
-            if not all_lanes:
-                return 0
-            decode_lanes = [(i, r) for i, r in decode_lanes
-                            if self.slots[i] is r]
-            chunks = [(i, r, n, p) for i, r, n, p in chunks
-                      if self.slots[i] is r]
-        t_tok = self._clock()
+                    # never read, nor what a lane behind it made of it)
+                    self._isolated(ln.req, "nan_logits", "decode",
+                                   slot=ln.slot)
+            live = [ln for ln in live if ln.holds(self)]
+        if not live:
+            return
         self._step_faults = 0   # a full dispatch+sample round succeeded
         with RecordEvent("sched.commit"):
-            produced = 0
-            for i, req in decode_lanes:
-                if self.slots[i] is not req:   # cancelled by a stream_cb
+            decode_lanes = [ln for ln in live if not ln.chunk]
+            produced = chunk_tokens = 0
+            for ln in decode_lanes:
+                if not ln.holds(self):         # cancelled by a stream_cb
                     continue                   # earlier in this very loop
                 produced += 1
-                self._commit_token(req, int(picked[i]), i, t_tok,
-                                   obs_decode=True)
-            chunk_tokens = 0
-            for i, req, n, _p in chunks:
-                if self.slots[i] is not req:   # cancelled mid-commit
+                self._commit_token(ln.req, int(picked[ln.slot]), ln.slot,
+                                   t_tok, obs_decode=True)
+            for ln in live:
+                if not ln.chunk or not ln.holds(self):   # or mid-commit
                     continue
-                chunk_tokens += n
-                self._commit_chunk(req, n, i, t_tok, picked[i])
-        self._chunk_progress = chunk_tokens
+                chunk_tokens += ln.n
+                self._commit_chunk(ln.req, ln.n, ln.slot, t_tok,
+                                   picked[ln.slot])
+        self._chunk_progress += chunk_tokens
+        self._produced += produced
         self.metrics.on_ragged_step(chunk_tokens, len(decode_lanes))
         if decode_lanes:
             self._record_tpot(len(decode_lanes), produced)
             self.metrics.on_decode(produced)
-        return produced
 
     # ---- speculative decoding ----
     def _grow_n(self, req: Request, slot: int, want: int) -> int:
@@ -1693,11 +1892,14 @@ class Scheduler:
         tokens. Degrades before it preempts: on pressure the drafts are
         dropped first (want -> 1, plain decode growth), THEN the normal
         preempt/finish policy applies. Returns slots reserved (0 if the
-        request left the batch)."""
+        request left the batch). Raises `_SettleFirst` where it would
+        preempt or finish while a round is in flight."""
         mgr = self.engine.manager
+        ahead = self._ahead(slot, req)
+        held = ahead.n if ahead is not None else 0
         while True:
             try:
-                mgr.append_tokens(req.seq_id, want)
+                mgr.append_tokens(req.seq_id, want, in_flight=held)
                 return want
             except SequenceTooLong:
                 cap = mgr.max_blocks_per_seq * mgr.block_size \
@@ -1705,6 +1907,8 @@ class Scheduler:
                 if cap >= 1:
                     want = min(want, cap)
                     continue
+                if self._launched is not None:
+                    raise _SettleFirst from None
                 self._finish(req, RequestStatus.FINISHED, "length_cap",
                              slot=slot)
                 return 0
@@ -1712,6 +1916,8 @@ class Scheduler:
                 if want > 1:
                     want = 1
                     continue
+                if self._launched is not None:
+                    raise _SettleFirst from None
                 if _obs.enabled():
                     # real pressure (a single-token grow failed): snapshot
                     # the memory picture BEFORE the preempt/finish below

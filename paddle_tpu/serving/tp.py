@@ -397,6 +397,10 @@ class ShardedEngine(kv_migrate.PagedPools):
             in_specs=(pspec, poolspec, R, R, R, R),
             out_specs=(lspec, poolspec),
             check_vma=False)), donate_argnums=(1,))
+        # the last step's `sampled`, replicated as the step leaves it: a
+        # host array first would key a second executable
+        self.last_sampled = put(
+            np.zeros((2, self.max_batch_size), np.int32), R)
         self._verify = jax.jit(jax.shard_map(
             verify, mesh=jmesh,
             in_specs=(pspec, poolspec, R, R, R),
@@ -454,11 +458,15 @@ class ShardedEngine(kv_migrate.PagedPools):
         `comms.step_overlap` window — overlap mode exposes ~0 collective
         ms (everything is in-program), sequential mode's host logit
         assembly is recorded as an exposed all_gather."""
-        args = sampling.call_arrays(tokens, lanes, block_tables, temperature)
+        args = sampling.call_arrays(tokens, lanes, block_tables, temperature,
+                                    self.last_sampled)
         if _obs.enabled():
             with comms.step_overlap(self._step_label):
-                return self._dispatch(self._ragged, True, *args)
-        return self._dispatch(self._ragged, False, *args)
+                out = self._dispatch(self._ragged, True, *args)
+        else:
+            out = self._dispatch(self._ragged, False, *args)
+        self.last_sampled = out[0]
+        return out
 
     ragged_step = sampling.ragged_step
 

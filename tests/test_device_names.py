@@ -22,7 +22,7 @@ SHARED = {"llama.embed", "llama.layer", "llama.rms_norm", "llama.qkv",
           "llama.rope", "llama.attn", "llama.o_proj", "llama.mlp",
           "llama.head"}
 # the serving step ends in its own NaN screen and sampler (`with_tail`)
-SERVING = SHARED | {"llama.kv_write", "llama.nan_screen"}
+SERVING = SHARED | {"llama.kv_write", "llama.nan_screen", "llama.feed"}
 TRAINING = SHARED | {"llama.loss"}
 
 

@@ -292,6 +292,9 @@ class TestImportSession:
         req = h._req
         while len(req.generated) < 3:
             fe1.step()
+        # reading a running sequence's KV from outside a round: the round
+        # in flight is committed first (`Scheduler.settle`)
+        fe1.scheduler.settle()
         carried = list(req.generated)
         payload = fe1.scheduler.engine.extract_kv_blocks(req.seq_id)
         assert fe1.release(h)

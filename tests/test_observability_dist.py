@@ -299,8 +299,11 @@ def test_oom_flight_dump_on_injected_exhaustion(tmp_path):
         block_size=4, max_blocks_per_seq=8))
     rng = np.random.default_rng(0)
     # the injected KVCacheExhausted fires on a single-token grow — the
-    # "real pressure" branch that preempts and must dump forensics first
-    faults.inject("serve.cache", after_n=4, times=1,
+    # "real pressure" branch that preempts and must dump forensics first.
+    # Twice: with a round in flight the first one only makes the launch
+    # settle that round and start over; pressure that is still there on
+    # committed state is what dumps and preempts
+    faults.inject("serve.cache", after_n=4, times=2,
                   exc=KVCacheExhausted(1, 0, 24))
     try:
         hs = [fe.submit(rng.integers(1, 64, 5).tolist(), max_new_tokens=8)

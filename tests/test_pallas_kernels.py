@@ -700,7 +700,8 @@ def v5e_ragged_step(request, v5e_chip):
         functools.partial(lr._ragged_fn, cfg=lr._StaticCfg(Cfg)))
     ints = [arr((tokens,), jnp.int32), arr((lanes, len(sampling.LANE_COLS)),
                                            jnp.int32),
-            arr((lanes, width), jnp.int32), arr((lanes,), jnp.float32)]
+            arr((lanes, width), jnp.int32), arr((lanes,), jnp.float32),
+            arr((2, lanes), jnp.int32)]    # `fed`: the last step's `sampled`
     # a TPU executable cannot be read back from the persistent cache here
     cache_was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
@@ -802,7 +803,8 @@ def test_cohere2_moe_step_compiles_for_v5e_at_the_cell_s_size(v5e_chip):
     step = sampling.with_tail(functools.partial(cr._ragged_fn, cfg=cfg))
     ints = [arr((tokens,), jnp.int32),
             arr((lanes, len(sampling.LANE_COLS)), jnp.int32),
-            arr((lanes, 2 * width), jnp.int32), arr((lanes,), jnp.float32)]
+            arr((lanes, 2 * width), jnp.int32), arr((lanes,), jnp.float32),
+            arr((2, lanes), jnp.int32)]
     cache_was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     try:
@@ -871,7 +873,8 @@ def test_deepseek_v3_step_keeps_its_live_prefix_switches_on_the_v5e(v5e_chip):
                 "narrow_steps": arr((), jnp.int32)}
     ints = [arr((tokens,), jnp.int32),
             arr((lanes, len(sampling.LANE_COLS)), jnp.int32),
-            arr((lanes, width), jnp.int32), arr((lanes,), jnp.float32)]
+            arr((lanes, width), jnp.int32), arr((lanes,), jnp.float32),
+            arr((2, lanes), jnp.int32)]
 
     def compile_step():
         step = sampling.with_tail(functools.partial(dr._ragged_fn, cfg=cfg))

@@ -292,9 +292,12 @@ class TestSchedulerPrefixCache:
         fe.submit(prompt, max_new_tokens=2)
         fe.run_until_idle()
         h = fe.submit(prompt, max_new_tokens=4)
+        pre0 = monitor.get("serving.prefill_tokens")
         fe.step()                          # admission + the ONE chunk
+        fe.step()                          # ... whose token this one commits
         assert len(h.tokens) >= 1, \
-            "full prefix hit must produce the first token in one step"
+            "full prefix hit must produce the first token in one round"
+        assert monitor.get("serving.prefill_tokens") - pre0 == 1
 
     def test_preempted_work_republishes_and_rehits(self):
         # publish-at-preempt: the victim's committed KV enters the tree,

@@ -5,6 +5,8 @@ retrace counters), timeout/cancel paths, 2-model `EngineCore` genericity
 (Llama + MLP-LM through the SAME scheduler assertions), and the
 `Config.enable_profile` predictor wiring.
 """
+import itertools
+
 import numpy as np
 import pytest
 
@@ -348,8 +350,12 @@ class TestFrontend:
 
     def test_running_deadline_expires(self):
         eng = make_mlp_engine(max_batch=2, num_blocks=32)
-        fe = ServingFrontend(eng)
-        h = fe.submit([1, 2, 3], max_new_tokens=10 ** 6, timeout_s=0.2)
+        # a clock that moves a tick a reading: the deadline then spans a
+        # few rounds whatever the first one's compile takes (a token
+        # still in flight when it strikes is discarded, not committed)
+        ticks = itertools.count()
+        fe = ServingFrontend(eng, clock=lambda: 0.01 * next(ticks))
+        h = fe.submit([1, 2, 3], max_new_tokens=10 ** 6, timeout_s=0.5)
         for _ in range(10 ** 6):
             fe.step()
             if h.finished:
@@ -581,14 +587,18 @@ class TestChunkedPrefill:
 
     def test_batch_composition_gauges_published(self):
         fe = ServingFrontend(make_mlp_engine(), prefill_chunk_tokens=4)
+        # the gauges are a round's, published when it is settled: by the
+        # step() after the one that launched it
         fe.submit(list(range(1, 11)), max_new_tokens=2)
         fe.step()                        # first chunk round: 4 tokens
+        fe.step()                        # second launched, first settled
         assert monitor.get("serving.step_prefill_tokens") == 4
         assert monitor.get("serving.step_decode_lanes") == 0
         fe.run_until_idle(max_steps=100)
         fe.submit([1, 2], max_new_tokens=3)
         fe.step()                        # 2-token chunk, no decode lane
         fe.step()                        # pure decode round
+        fe.step()                        # ... settled
         assert monitor.get("serving.step_prefill_tokens") == 0
         assert monitor.get("serving.step_decode_lanes") == 1
 

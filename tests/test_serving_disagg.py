@@ -325,6 +325,9 @@ class TestResidentKVLifecycle:
         req = h._req
         while len(req.generated) < 2:
             fe1.step()
+        # reading a running sequence's KV from outside a round: the round
+        # in flight is committed first (`Scheduler.settle`)
+        fe1.scheduler.settle()
         payload = fe1.scheduler.engine.extract_kv_blocks(req.seq_id)
         fe1.release(h)
         return req, payload
