@@ -71,8 +71,8 @@ def _reports(metric, cell):
 
 def _reader(name):
     """A per-layer metric's reader: `layer_metrics/<name>.py`, or the file
-    of the name up to its last dot, so that `step_ms_p50.open` and
-    `step_ms_p50.batch` are read by the one `step_ms_p50.py`."""
+    of the name up to its last dot, so that `step_ms_p50.gap`, `.serve`
+    and `.train` are read by the one `step_ms_p50.py`."""
     for stem in (name, name.rpartition(".")[0]):
         path = os.path.join(HERE, "layer_metrics", stem + ".py")
         if stem and os.path.exists(path):

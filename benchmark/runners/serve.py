@@ -115,6 +115,10 @@ def drive(job, fe, hook):
     watched = ("serving.ragged_retraces", "serving.step_faults",
                "serving.isolated_faults", "serving.engine_restarts")
     before = {c: monitor.get(c) or 0 for c in watched}
+    # the round in flight's counters (PR 43), over the window alone: a
+    # counter never bumped is not in the registry and reads 0
+    rounds = ("serving.step.programs", "serving.step.overlapped",
+              "serving.step.wasted_lanes")
 
     plan = job["generator"].make(job["traffic"], job["seed"], seconds,
                                  cfg["vocab_size"])
@@ -212,6 +216,7 @@ def drive(job, fe, hook):
 
     gc.callbacks.append(on_gc)
     hook.counting = True
+    rounds_before = {c: monitor.get(c) or 0 for c in rounds}
     t0 = clk()
     job["window_started"](t0)
     for r in live:
@@ -255,6 +260,8 @@ def drive(job, fe, hook):
                 [1] * len(running)) * cfg["num_hidden_layers"])
     hook.counting = False
     closed = clk() - t0
+    rounds_moved = {c.rpartition(".")[2]: (monitor.get(c) or 0)
+                    - rounds_before[c] for c in rounds}
     gc.callbacks.remove(on_gc)
     if tracing:
         stop_trace()
@@ -321,7 +328,7 @@ def drive(job, fe, hook):
         step_ms=step_ms, window_s=closed, lanes=lanes, gc_ms=gc_ms,
         queue_depth=queue_depth,
         hook_steps=hook.steps, prefill_tokens=hook.prefill_tokens,
-        decode_lanes=hook.decode_lanes,
+        decode_lanes=hook.decode_lanes, rounds=rounds_moved,
         kv_blocks_peak=kv_peak, kv_blocks=mgr.num_blocks,
         trace_steps=trace_steps, attn_bytes_traced=float(sum(bytes_per_step)),
         span_names=("submit", "fe.step", "poll", "wait"),

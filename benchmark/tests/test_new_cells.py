@@ -37,7 +37,7 @@ def test_traced_walk_reads_the_counters(rehearse, cell):
         assert cell in per_layer[name]["workloads"]
         assert per_layer[name]["source"] != "device_trace"
     if cell == "kanana2-longctx-decode":
-        assert result["metrics"]["moe_load_max_over_mean.kanana"]["value"] >= 1
+        assert result["metrics"]["moe_load_max_over_mean.serve"]["value"] >= 1
 
 
 def test_every_new_entry_has_its_files():

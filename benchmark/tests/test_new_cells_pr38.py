@@ -51,10 +51,14 @@ def test_every_new_entry_has_its_files():
         bench = json.load(f)
     here = os.path.join(ROOT, "benchmark")
     run = load("run.py", "benchmark_run_pr38")
+    every = {w["name"] for w in bench["workloads"]}
+    moved = {e["name"]: set(e.get("workloads", every))
+             for e in bench["end_to_end"]}
     for m in bench["per_layer"]:
         if set(m["workloads"]) & set(CELLS):
             assert os.path.exists(run._reader(m["name"]))
-            assert len(m["workloads"]) == 1
+            # every cell of an entry reports the metric the entry moves
+            assert set(m["workloads"]) <= moved[m["moves"]], m["name"]
     for w in bench["workloads"]:
         if w["name"] in CELLS:
             assert len(w["why"]) <= 200

@@ -128,20 +128,19 @@ def test_the_log_prints_the_whole_record(capsys):
 
 
 # ---- the entries ---------------------------------------------------------------
-# `.shared`: ONE entry over the cells its `workloads` lists. A cell PR 38
-# added takes single-cell entries (`test_new_cells_pr38.py` demands it).
+# `.shared`: ONE entry a quantity over the cells its `workloads` lists, in
+# the order of `workloads` in the file (PR 44 folded the single-cell
+# `.cmdaplus` and `.docqa` twins into them).
 SHARED = ["mistral7b-chat-open", "yi6b-train-4k", "mistral7b-batch-decode",
-          "mistral7b-long-prefill", "kanana2-longctx-decode"]
+          "mistral7b-long-prefill", "kanana2-longctx-decode",
+          "commandaplus-mixedctx-decode", "kanana2-docqa-open"]
 SERVING = [c for c in SHARED if c != "yi6b-train-4k"]
-BACKLOG = ["mistral7b-batch-decode", "kanana2-longctx-decode"]
+BACKLOG = ["mistral7b-batch-decode", "kanana2-longctx-decode",
+           "commandaplus-mixedctx-decode"]
 ENTRIES = {
     "setup_import_s.shared": SHARED, "setup_build_s.shared": SERVING,
     "setup_trace_s.shared": SHARED, "setup_lower_s.shared": SHARED,
     "setup_compile_s.shared": SHARED, "setup_fill_s.shared": BACKLOG,
-    **{f"setup_{q}_s.cmdaplus": ["commandaplus-mixedctx-decode"]
-       for q in ("import", "trace", "lower", "compile", "fill")},
-    **{f"setup_{q}_s.docqa": ["kanana2-docqa-open"]
-       for q in ("import", "trace", "lower", "compile")},
 }
 
 
@@ -169,26 +168,27 @@ def test_the_entries_name_cells_that_exist_and_move_setup_s():
                 if cell in m["workloads"]}
         assert {"setup_import_s", "setup_trace_s", "setup_lower_s",
                 "setup_compile_s"} <= read, cell
-    for cell in BACKLOG + ["commandaplus-mixedctx-decode"]:
+    for cell in BACKLOG:
         assert any(cell in m["workloads"] for m in mine
                    if m["name"].startswith("setup_fill_s.")), cell
 
 
 # ---- the rehearsal ------------------------------------------------------------
-@pytest.mark.parametrize("cell, suffix, quantities", [
-    ("mistral7b-chat-open", "shared",
-     ("import", "build", "trace", "lower", "compile")),
-    ("mistral7b-batch-decode", "shared", QUANTITIES),
-    ("yi6b-train-4k", "shared", ("import", "trace", "lower", "compile")),
+@pytest.mark.parametrize("cell, quantities", [
+    ("mistral7b-chat-open", ("import", "build", "trace", "lower", "compile")),
+    ("mistral7b-batch-decode", QUANTITIES),
+    ("yi6b-train-4k", ("import", "trace", "lower", "compile")),
+    ("commandaplus-mixedctx-decode", QUANTITIES),
+    ("kanana2-docqa-open", ("import", "build", "trace", "lower", "compile")),
 ])
 def test_traced_walk_would_report_the_new_names(rehearse, monkeypatch, cell,
-                                                suffix, quantities):
+                                                quantities):
     monkeypatch.setattr(setup_record, "_printed", False)
     result, out = rehearse(cell, "--trace", "1")
     assert result["correct"] is True, out
     last = json.loads(out.strip().splitlines()[-1][len("REHEARSAL "):])
     mine = {n for n in last["would_report"] if n.startswith("setup_")}
-    assert mine == {f"setup_{q}_s.{suffix}" for q in quantities}
+    assert mine == {f"setup_{q}_s.shared" for q in quantities}
     for name in mine:
         assert result["metrics"][name]["value"] > 0, name
     assert "set-up: import" in out and "backend" in out
