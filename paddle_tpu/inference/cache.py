@@ -25,6 +25,16 @@ behind the window of the committed length (`_BlockGroup`). Sharing,
 copy-on-write and the reclaimer are the FIRST group's; a manager of one
 group, which is every other engine's, is all of the above and nothing more.
 
+A STATE GROUP (`BlockCacheManager.state_group`): a model whose layers keep
+one fixed-size recurrent state a sequence, not keys and values a token, has
+its memory reckoned in SLOTS. A sequence holds exactly one for its whole
+life, however long it grows (`append_tokens` takes nothing, the slot goes
+back at `free`), its table is the slot (`block_table_array` is `[n, 1]`),
+and what bounds its length is the engine's position table alone. A state
+cannot be shortened: `trim` below a sequence's own length raises
+`StateNotTrimmable`. It is the same bookkeeping with one block a sequence
+that spans every position, so everything that counts blocks counts slots.
+
 Exhaustion is a *scheduling event*, not a crash: `allocate`/`append_token`
 raise the typed `KVCacheExhausted` (pool empty) or `SequenceTooLong`
 (per-sequence block cap), which the continuous-batching scheduler
@@ -40,7 +50,8 @@ from typing import Callable, Dict, List, Optional, Tuple
 
 import numpy as np
 
-__all__ = ["BlockCacheManager", "KVCacheExhausted", "SequenceTooLong"]
+__all__ = ["BlockCacheManager", "KVCacheExhausted", "SequenceTooLong",
+           "StateNotTrimmable"]
 
 
 def _chaos(site: str) -> None:
@@ -102,6 +113,19 @@ class SequenceTooLong(ValueError):
         super().__init__(
             f"sequence needs {need_blocks} blocks{where} > "
             f"max_blocks_per_seq {max_blocks}")
+
+
+class StateNotTrimmable(ValueError):
+    """`trim` asked a state group to forget tokens: a recurrent state has
+    taken them in and cannot give them back. The caller restarts the
+    sequence from its tokens instead (`serving/scheduler.py`)."""
+
+    def __init__(self, seq_id, have: int, want: int, group: Optional[str]):
+        self.seq_id, self.have, self.want, self.group = \
+            seq_id, have, want, group
+        super().__init__(
+            f"sequence {seq_id}: the state group {group!r} holds {have} "
+            f"tokens and cannot be trimmed to {want}")
 
 
 class _BlockGroup:
@@ -280,6 +304,7 @@ class BlockCacheManager:
         self.block_size = block_size
         self.max_blocks_per_seq = max_blocks_per_seq
         self.name = name               # the first group's, for messages
+        self.state = False             # `state_group`: slots, not blocks
         self._further: Tuple[_BlockGroup, ...] = tuple(
             _BlockGroup(n, nb, block_size, w) for n, nb, w in further_groups)
         self._free: List[int] = list(range(num_blocks - 1, -1, -1))
@@ -315,6 +340,17 @@ class BlockCacheManager:
                 mod.register_kv_manager(self)
             except Exception:
                 pass
+
+    @classmethod
+    def state_group(cls, slots: int, context_tokens: int,
+                    name: str = "state") -> "BlockCacheManager":
+        """A manager whose one group is of kind STATE (module docstring):
+        `slots + 1` ids (one for a scheduler's guard), one a sequence, each
+        good for `context_tokens` positions. The kind comes from the engine
+        that builds it, as a further group's window does."""
+        mgr = cls(slots + 1, context_tokens, 1, name=name)
+        mgr.state = True
+        return mgr
 
     @property
     def free_blocks(self) -> int:
@@ -627,6 +663,10 @@ class BlockCacheManager:
         inside the last shared block)."""
         if seq_id in self._tables:
             raise ValueError(f"sequence {seq_id} already allocated")
+        if self.state:
+            raise ValueError(
+                f"adopt: a slot of the state group {self.name!r} is one "
+                "sequence's; a state has no shared prefix to lease")
         if self._further:
             raise ValueError(
                 "adopt: shared blocks are the first group's alone; a "
@@ -736,6 +776,11 @@ class BlockCacheManager:
         divergent append COWs it."""
         if num_tokens > self._lens[seq_id]:
             raise ValueError("trim can only shrink a sequence")
+        if self.state:
+            if num_tokens < self._lens[seq_id]:
+                raise StateNotTrimmable(seq_id, self._lens[seq_id],
+                                        num_tokens, self.name)
+            return
         for g in self._further:
             g.check_trim(seq_id, num_tokens)
         keep = self.blocks_needed(num_tokens)
@@ -744,6 +789,18 @@ class BlockCacheManager:
             self._release(table.pop())
         for g in self._further:
             g.trim(seq_id, num_tokens)
+        self._lens[seq_id] = num_tokens
+
+    def unappend(self, seq_id: int, num_tokens: int) -> None:
+        """Take back `append_tokens` down to `num_tokens`. For block groups
+        this is `trim`. A state group allows it as bookkeeping alone: the
+        caller vouches that the tokens beyond `num_tokens` never reached
+        the device (a launch that grew its lanes and was not dispatched),
+        or it could not be taken back at all (`StateNotTrimmable`)."""
+        if not self.state:
+            return self.trim(seq_id, num_tokens)
+        if num_tokens > self._lens[seq_id]:
+            raise ValueError("unappend can only shrink a sequence")
         self._lens[seq_id] = num_tokens
 
     def free(self, seq_id: int) -> None:

@@ -556,6 +556,15 @@ class Profiler:
                 f"{g('serving.step.overlap_share')}), "
                 f"{g('serving.step.wasted_lanes')} wasted lanes, "
                 f"{g('serving.step.forced_settles')} forced settles")
+        if g("serving.state.bytes_per_seq"):
+            # an engine over a state group: a sequence holds one slot of
+            # recurrent state, whatever its length (docs/SERVING.md "A
+            # state group")
+            lines.append(
+                f"  state: {g('serving.state.slots_in_use')} slots in use, "
+                f"{g('serving.state.bytes_per_seq')} bytes a sequence, "
+                f"{g('serving.state.resets')} started from zero, "
+                f"{g('serving.state.restarts')} restarted")
         if g("serving.ttft_p50_ms"):
             lines.append(
                 f"  TTFT p50 {g('serving.ttft_p50_ms')} ms / "

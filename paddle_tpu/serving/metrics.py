@@ -158,6 +158,18 @@ class ServingMetrics:
         attribute): that launch fed no token on the device."""
         monitor.inc("serving.step.forced_settles")
 
+    def on_state_slots(self, in_use: int):
+        """Slots of a state group that sequences hold (the guard's is not
+        one of them), after a scheduling round."""
+        monitor.set_gauge("serving.state.slots_in_use", in_use)
+
+    def on_state_restart(self):
+        """A lane of a state group was restarted from its tokens (freed and
+        re-queued at the front) where a block group's would have been
+        trimmed: a recurrent state cannot forget what a failed round fed
+        it."""
+        monitor.inc("serving.state.restarts")
+
     def on_wasted_lanes(self, n: int):
         """`n` lanes of a settled round belonged to requests that had left
         their slots since its launch (finished by EOS on the token
@@ -252,6 +264,11 @@ class ServingMetrics:
                          or {}).items():
             monitor.set_gauge(f"serving.kv_bytes_per_token.{group}",
                               round(float(b), 1))
+        # an engine over a state group: what one resident sequence holds,
+        # whatever its length (its `kv_bytes_per_token` is 0)
+        if info.get("state_bytes_per_seq") is not None:
+            monitor.set_gauge("serving.state.bytes_per_seq",
+                              int(info["state_bytes_per_seq"]))
 
     # ---- multi-LoRA serving ----
     def on_lora(self, info: dict):

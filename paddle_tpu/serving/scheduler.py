@@ -421,6 +421,17 @@ class Scheduler:
         # refuses such an engine by name
         self._further_pads = [(g, mgr.blocks_of(pad_id, g)[0])
                               for g in range(1, mgr.n_groups)]
+        # a STATE group: a sequence's memory is one slot whose recurrent
+        # state cannot be trimmed, shared or replayed into
+        self._state = bool(getattr(mgr, "state", False))
+        if self._state and (self._prefix_enabled or self.spec is not None):
+            what = "the radix prefix cache" if self._prefix_enabled \
+                else "speculative decoding"
+            raise ValueError(
+                f"{type(self.engine).__name__}: {what} over the state group "
+                f"{mgr.group_names[0]!r} is not implemented: a recurrent "
+                "state has no blocks to share at a prefix boundary and no "
+                "snapshot for a verify window's rollback")
         if self._further_pads and (self._prefix_enabled
                                    or self.spec is not None):
             what = "the radix prefix cache" if self._prefix_enabled \
@@ -867,6 +878,9 @@ class Scheduler:
                 queue_depth=len(self.waiting),
                 decoded=produced > 0,
                 wall_s=self._clock() - began)
+            if self._state:
+                self.metrics.on_state_slots(
+                    mgr.num_blocks - mgr.free_blocks - 1)
             return produced
 
     @property
@@ -1085,13 +1099,16 @@ class Scheduler:
         roll back their cache bookkeeping (`rollback`) and replay next
         round — deterministically, since decode KV writes are
         position-indexed and idempotent. No culprit = transient: retried
-        under `step_retries`, then escalated to the watchdog."""
+        under `step_retries`, then escalated to the watchdog. Over a state
+        group no lane is replayed (a replay would feed a state its tokens
+        twice): the survivors' `rollback` restarts them from their tokens."""
         lanes = [(i, r) for i, r in lanes if self.slots[i] is r]
         culpable = []
         if isinstance(exc, EngineStepError) and exc.seq_ids:
             ids = set(exc.seq_ids)
             culpable = [(i, r) for i, r in lanes if r.seq_id in ids]
-        elif probe is not None and not isinstance(exc, _faults.InjectedFault):
+        elif probe is not None and not self._state \
+                and not isinstance(exc, _faults.InjectedFault):
             # an untargeted injected fault models a transient dispatch
             # failure — probing real hardware state would find nothing
             for i, r in lanes:
@@ -1637,14 +1654,38 @@ class Scheduler:
         lane = rnd.lanes.get(slot) if rnd is not None else None
         return lane if lane is not None and lane.holds(self) else None
 
-    def _rollback(self, lanes) -> None:
+    def _rollback(self, lanes, dispatched: bool = True) -> None:
         """Undo the growth of `lanes` (a newer round's before an older
         one's) where the request still holds its slot, so that the next
-        round replays it cleanly from the length before the oldest."""
+        round replays it cleanly from the length before the oldest. Over a
+        state group a lane whose tokens were `dispatched` cannot be taken
+        back (the state has them, or may have): the lane is restarted from
+        its tokens instead."""
         mgr = self.engine.manager
-        for lane in lanes:
-            if lane.holds(self):
-                mgr.trim(lane.req.seq_id, lane.pre_len)
+        held = [lane for lane in lanes if lane.holds(self)]
+        if self._state and dispatched:
+            # newest admission first, so the oldest ends at the queue's front
+            for lane in sorted(held, key=lambda ln: -ln.admit_seq):
+                if lane.holds(self):       # one request, two rounds' lanes
+                    self._restart_lane(lane.req, lane.slot)
+            return
+        for lane in held:
+            mgr.unappend(lane.req.seq_id, lane.pre_len)
+
+    def _restart_lane(self, req: Request, slot: int) -> None:
+        """Free a state group's slot and re-queue its request at the front
+        with its tokens kept: the re-prefill starts from a zero state at
+        position 0, which is the only way back for a recurrent state."""
+        with RecordEvent("sched.state_restart", req=req.req_id):
+            self.engine.manager.free(req.seq_id)
+            self._adapter_release(req)
+            self.slots[slot] = None
+            req.status = RequestStatus.PREEMPTED
+            self._queue_push(req, front=True)
+            self.metrics.on_state_restart()
+            if _obs.enabled():
+                self._obs_req(req, "preempted", reason="state_restart",
+                              tokens_kept=len(req.generated))
 
     def _grow_lanes(self, active, plan) -> None:
         """Grow the launch's lanes and append them to `plan`: `(lane,
@@ -1720,7 +1761,8 @@ class Scheduler:
             try:
                 self._grow_lanes(active, plan)
             except _SettleFirst:
-                self._rollback(ln for ln, _first in plan)
+                # grown, never dispatched: the bookkeeping alone goes back
+                self._rollback([ln for ln, _first in plan], dispatched=False)
                 self.settle()
                 plan.clear()
                 self._grow_lanes(active, plan)

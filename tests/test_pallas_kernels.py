@@ -829,6 +829,75 @@ def test_cohere2_moe_step_compiles_for_v5e_at_the_cell_s_size(v5e_chip):
     assert held < 14.5e9, held          # of the chip's 16.9 GB
 
 
+def test_brumby_step_compiles_for_v5e_at_the_cell_s_size(v5e_chip):
+    """The Brumby engine's whole ragged step at the benchmark cell's size (6
+    layers at published widths, the whole vocabulary, 33 state slots, 32
+    lanes + a 128-token chunk), compiled by the installed libtpu for a v5e
+    from shapes alone: a `power_retention_update` and a
+    `power_retention_chunk` a layer and no other kernel; the whole donated
+    state aliased to its outputs and no second copy of any of it among the
+    temporaries (they are the logits and a few activations); `phi` in no HBM
+    buffer (the only arrays with the feature axis's 65 x 128 tiles are `S`
+    and `z` themselves); weights + state + logits fit the chip."""
+    import functools
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.inference import brumby_runner as br
+    from paddle_tpu.models import brumby as bm
+    from paddle_tpu.ops import sampling
+    from paddle_tpu.ops.pallas import _support
+
+    cfg = bm.BrumbyConfig(num_hidden_layers=6)
+    lanes, tokens, slots = 32, 32 + 128, 33
+
+    def arr(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=v5e_chip)
+
+    params = {k: arr(s, jnp.float32 if kind == "bias" else jnp.bfloat16)
+              for k, (s, kind) in bm.param_shapes(cfg).items()}
+    params["rope_cos"] = params["rope_sin"] = arr((32768, 64), jnp.float32)
+    s_shape, z_shape = bm.state_shapes(cfg, slots)
+    assert s_shape == (6, 33, 8, 65, 128, 128) and z_shape == s_shape[:-1]
+    state = (arr(s_shape, jnp.float32), arr(z_shape, jnp.float32),
+             arr((slots,), jnp.int32), arr((), jnp.int32))
+    step = sampling.with_tail(functools.partial(br._ragged_fn, cfg=cfg))
+    ints = [arr((tokens,), jnp.int32),
+            arr((lanes, len(sampling.LANE_COLS)), jnp.int32),
+            arr((lanes, 1), jnp.int32), arr((lanes,), jnp.float32),
+            arr((2, lanes), jnp.int32)]
+    cache_was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        with pytest.MonkeyPatch.context() as mp:
+            mp.setattr(_support, "backend", lambda: "tpu")
+            lowered = jax.jit(step, donate_argnums=(1,)).trace(
+                params, state, *ints).lower(lowering_platforms=("tpu",))
+            compiled = lowered.compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache_was)
+    kernels = re.findall(r'kernel_name = "(\w+)"', lowered.as_text())
+    assert sorted(kernels) == ["power_retention_chunk"] * 6 \
+        + ["power_retention_update"] * 6
+    mem = compiled.memory_analysis()
+    state_bytes = 4 * (int(np.prod(s_shape)) + int(np.prod(z_shape)))
+    logits = tokens * cfg.vocab_size * 4
+    assert mem.alias_size_in_bytes >= state_bytes
+    assert mem.temp_size_in_bytes < logits + (64 << 20), mem.temp_size_in_bytes
+    text = compiled.as_text()
+    tiled = set(re.findall(r"(?:f32|bf16)\[([\d,]*65,128[\d,]*)\]", text))
+    # `S`, `z`, and slices of `z` by layer on its way out: nothing with a
+    # token axis beside the tiles
+    assert tiled and all(re.fullmatch(r"[1-6],33,8,65,128(,128)?", t)
+                         for t in tiled), tiled
+    assert not re.findall(r"\w+\[[\d,]*8320[\d,]*\]", text)
+    held = mem.argument_size_in_bytes + mem.output_size_in_bytes \
+        - mem.alias_size_in_bytes + mem.temp_size_in_bytes
+    assert held < 14.5e9, held          # of the chip's 16.9 GB
+
+
 def test_deepseek_v3_step_keeps_its_live_prefix_switches_on_the_v5e(v5e_chip):
     """ISSUE 39: the Kanana-shaped step (published attention and expert
     widths; depth, experts, vocabulary and pool cut), 32 lanes + a 512-token

@@ -1,10 +1,12 @@
 """One run of the UNEDITED benchmark in this process, then the program's own
 counters of the same process, printed after the benchmark's result line.
 
-`BENCHMARK.json`'s `per_layer` list is full, so a counter that no metric
-reads (the round in flight's `serving.step.overlapped`, `overlap_share`,
-`wasted_lanes`, `forced_settles`; `narrow_steps` before them) is read this
-way in a builder's chip runs: `benchmark/run.py` runs under `runpy` with
+A counter that no per-layer metric of `BENCHMARK.json` reads yet (the round
+in flight's `serving.step.forced_settles`, a state group's
+`serving.state.*`; `serving.step.overlapped` and `wasted_lanes` were read
+this way until PR 44 gave them `round_overlap_share.*` and
+`wasted_lanes_per_step.*`) is read this way in a builder's chip runs, on
+either side of a comparison: `benchmark/run.py` runs under `runpy` with
 its own arguments, and the `framework.monitor` registry it filled is
 printed as one `COUNTERS {...}` line. Nothing under `benchmark/` is
 touched and the result line is the benchmark's own.
