@@ -226,8 +226,9 @@ def lowered_custom_calls(engine, T):
 
 
 def replay_logits(engine, seq, n_prompt, n_decode, T, chunk):
-    """Prefill-then-decode through the cache, outside the scheduler but on
-    its compiled step (same fixed shapes): `seq[:n_prompt]` prefilled in
+    """Prefill-then-decode through the cache, outside the scheduler, on the
+    all-rows program of its step (`ragged_step`: the same stack and head,
+    the same fixed shapes, every row's logits): `seq[:n_prompt]` prefilled in
     `chunk`-token ragged steps on lane 0, then `n_decode` teacher-forced
     decode steps. Returns [1 + n_decode, V]: the first-token logits and one
     row per decode step."""

@@ -486,7 +486,7 @@ def _traced_functions(module: Module) -> Dict[ast.AST, Set[str]]:
         pos = [a.arg for a in args.posonlyargs + args.args]
         statics = set(site.static_names) | set(site.bound_kwargs)
         # kwonly params are static by repo convention (bound via partial
-        # at the jit site: `partial(_mlp_ragged, block_size=...)`)
+        # at the jit site: `partial(_mlp_ragged_stack, block_size=...)`)
         statics.update(a.arg for a in args.kwonlyargs)
         statics.update(registry.STATIC_PARAM_NAMES)
         for i in site.static_idx:

@@ -21,9 +21,11 @@ unrolled as the two MoE engines', and nothing is paged.
   so the step's own screen flags the lane (`ops/sampling.step_tail`) and the
   scheduler fails the request. A state cannot be trimmed, so nothing here
   replays a token.
-- `sampled_step` is the one compiled step, ending in the NaN screen and the
-  sampler (`ops/sampling.with_tail`); `ragged_step` is its logits and
-  `generate` a host loop over it. `verify_step` raises: a verify window's
+- `sampled_step` is a round's one compiled step, ending in the NaN screen,
+  the head over the sampled rows and the sampler (`ops/sampling.with_tail`);
+  `ragged_step` is the same stack with the head over every row (a program
+  of its own, `ops/sampling.all_rows`) and `generate` a host loop over it.
+  `verify_step` raises: a verify window's
   rollback needs a snapshot of the state.
 
 The engine transforms (`quantize_engine`, `shard_engine`, `attach_adapters`)
@@ -80,14 +82,10 @@ def _retain(state, layer, cfg, slot, rows, update, chunk, fresh, tok_lane):
     return retain
 
 
-def _ragged_fn(params, state, tokens, q_lens, kv_lens, tables, *, cfg):
+def _ragged_stack(params, state, tokens, q_lens, kv_lens, tables, *, cfg):
     """Packed tokens `[T]` + per-lane `(q_len, kv_len)` through the decoder:
-    `(logits [T, V] float32, state)`."""
-    # trace-time only, as every engine's: the ragged step IS the serving
-    # decode program, and ragged_retraces pins "one executable whatever the
-    # batch's composition"
-    monitor.inc("serving.decode_retraces")
-    monitor.inc("serving.ragged_retraces")
+    `(hidden [T, H] before the final norm, state)`, the `stack` of
+    `ops/sampling.with_tail`."""
     S, z, length, resets = state
     t = tokens.shape[0]
     q_lens, kv_lens = q_lens.astype(jnp.int32), kv_lens.astype(jnp.int32)
@@ -117,7 +115,13 @@ def _ragged_fn(params, state, tokens, q_lens, kv_lens, tables, *, cfg):
     at = jnp.where(sound, slot, length.shape[0])       # others are dropped
     state = (held[0], held[1], length.at[at].set(kv_lens, mode="drop"),
              resets + jnp.sum(fresh & sound, dtype=jnp.int32))
-    return bm.head(x, params, cfg), state
+    return x, state
+
+
+def _head(state, x, lane, *, cfg):
+    """The `head` of `ops/sampling.with_tail`: the final norm and the output
+    matmul over the rows it is given; `state[0]` is the params."""
+    return bm.head(x, state[0], cfg)
 
 
 class BrumbyInferenceEngine:
@@ -155,11 +159,16 @@ class BrumbyInferenceEngine:
                       jnp.zeros((slots + 1,), jnp.int32),
                       jnp.zeros((), jnp.int32))
         self.manager.set_kv_geometry(self.state_bytes_per_seq(), 32)
-        bound = functools.partial(_ragged_fn, cfg=cfg)
-        bound.__name__ = _ragged_fn.__name__           # the XLA module's name
-        # the screen, the row gather and the sampler end the step's one
-        # program (`ops/sampling.with_tail`)
-        self._ragged = jax.jit(sampling.with_tail(bound), donate_argnums=(1,))
+        stack = functools.partial(_ragged_stack, cfg=cfg)
+        head = functools.partial(_head, cfg=cfg)
+        # the screen, the row gather, the head over the sampled rows and
+        # the sampler end the round's one program (`ops/sampling.with_tail`);
+        # `_logits` is the same stack with the head over every row,
+        # compiled when `ragged_step` first calls it
+        self._ragged = jax.jit(sampling.with_tail(stack, head),
+                               donate_argnums=(1,))
+        self._logits = jax.jit(sampling.all_rows(stack, head),
+                               donate_argnums=(1,))
         self.last_sampled = None    # the last step's `sampled`, on device
         compile_trace.stamp("engine.build", began)
 
@@ -167,15 +176,18 @@ class BrumbyInferenceEngine:
     def sampled_step(self, tokens: np.ndarray, lanes: np.ndarray,
                      block_tables: np.ndarray, temperature: np.ndarray):
         """ONE fixed-shape step over a packed ragged batch, sampled (see
-        `EngineCore.sampled_step`): `(sampled [2, B] int32, logits [T, V]
-        float32)`, both on the device. `block_tables` `[B, 1]`: each lane's
-        state slot."""
-        sampled, logits, self.state = self._ragged(
-            self.params, self.state,
-            *sampling.call_arrays(tokens, lanes, block_tables, temperature,
-                                  self.last_sampled))
-        self.last_sampled = sampled
-        return sampled, logits
+        `EngineCore.sampled_step`): `sampled [2, B] int32`, on the device.
+        `block_tables` `[B, 1]`: each lane's state slot."""
+        self.last_sampled = self._run(
+            self._ragged, *sampling.call_arrays(
+                tokens, lanes, block_tables, temperature, self.last_sampled))
+        return self.last_sampled
+
+    def _run(self, fn, *arrays):
+        """One of the step programs over this engine's state, which it
+        replaces; what the program returns ahead of it."""
+        out, self.state = fn(self.params, self.state, *arrays)
+        return out
 
     ragged_step = sampling.ragged_step
 

@@ -18,9 +18,11 @@ context, so it has TWO paged pools and two block tables a sequence.
   `llama.attn_window` / `llama.attn_full` tell its calls apart in a trace.
 - The weights are the model's own pytree, by reference; the expert layer
   holds `config.held_experts` of the router's experts (`[held, in, out]`).
-- `sampled_step` is the one compiled step, ending in the NaN screen and the
-  sampler (`ops/sampling.with_tail`); `ragged_step` is its logits,
-  `verify_step` a case of it and `generate` a host loop over it.
+- `sampled_step` is a round's one compiled step, ending in the NaN screen,
+  the head over the sampled rows and the sampler (`ops/sampling.with_tail`);
+  `ragged_step` is the same stack with the head over every row (a program
+  of its own, `ops/sampling.all_rows`), `verify_step` a case of the stack
+  and `generate` a host loop over `ragged_step`.
 - Expert load is counted inside the step, on the device, in donated
   counters; `expert_load()` reads them.
 
@@ -124,18 +126,13 @@ def _ragged_stack(params, pools, counters, tokens, q_lens, kv_lens, tables,
         "narrow_steps": counters["narrow_steps"] + (
             (n_live <= lanes).astype(jnp.int32) if narrow else 0),
     }
-    return c2.head(x, params, cfg), tuple(pools), counters
+    return x, tuple(pools), counters
 
 
-def _ragged_fn(params, pools, counters, tokens, q_lens, kv_lens, tables, *,
-               cfg):
-    # trace-time only, as every engine's: the ragged step IS the serving
-    # decode program, and ragged_retraces pins "one executable whatever the
-    # batch's composition"
-    monitor.inc("serving.decode_retraces")
-    monitor.inc("serving.ragged_retraces")
-    return _ragged_stack(params, pools, counters, tokens, q_lens, kv_lens,
-                         tables, cfg=cfg, narrow=True)
+def _head(state, x, lane, *, cfg):
+    """The `head` of `ops/sampling.with_tail`: the final norm and the tied
+    output matmul over the rows it is given; `state[0]` is the params."""
+    return c2.head(x, state[0], cfg)
 
 
 def _verify_fn(params, pools, counters, tokens, ctx_lens, tables, *, cfg):
@@ -143,10 +140,10 @@ def _verify_fn(params, pools, counters, tokens, ctx_lens, tables, *, cfg):
     window of S tokens; logits fold back to `[B, S, V]`."""
     monitor.inc("serving.verify_retraces")        # trace-time only
     b, s = tokens.shape
-    logits, pools, counters = _ragged_stack(
+    x, pools, counters = _ragged_stack(
         params, pools, counters, tokens.reshape(b * s),
         jnp.full((b,), s, jnp.int32), ctx_lens, tables, cfg=cfg)
-    return logits.reshape(b, s, -1), pools, counters
+    return c2.head(x, params, cfg).reshape(b, s, -1), pools, counters
 
 
 class Cohere2MoeInferenceEngine:
@@ -204,42 +201,50 @@ class Cohere2MoeInferenceEngine:
                          "steps": jnp.zeros((), jnp.int32),
                          "narrow_steps": jnp.zeros((), jnp.int32)}
 
-        def step(fn, wrap=lambda f: f):
-            bound = functools.partial(fn, cfg=cfg)
-            bound.__name__ = fn.__name__           # the XLA module's name
-            return jax.jit(wrap(bound), donate_argnums=(1, 2))
-
-        # the screen, the row gather and the sampler end the step's one
-        # program (`ops/sampling.with_tail`)
-        self._ragged = step(_ragged_fn, sampling.with_tail)
+        stack = functools.partial(_ragged_stack, cfg=cfg, narrow=True)
+        head = functools.partial(_head, cfg=cfg)
+        verify = functools.partial(_verify_fn, cfg=cfg)
+        verify.__name__ = _verify_fn.__name__      # the XLA module's name
+        # the screen, the row gather, the head over the sampled rows and
+        # the sampler end the round's one program (`ops/sampling.with_tail`);
+        # `_logits` is the same stack with the head over every row,
+        # compiled when `ragged_step` first calls it
+        self._ragged = jax.jit(sampling.with_tail(stack, head),
+                               donate_argnums=(1, 2))
+        self._logits = jax.jit(sampling.all_rows(stack, head),
+                               donate_argnums=(1, 2))
         self.last_sampled = None    # the last step's `sampled`, on device
-        self._verify = step(_verify_fn)
+        self._verify = jax.jit(verify, donate_argnums=(1, 2))
         compile_trace.stamp("engine.build", began)
 
     # ---- the EngineCore dispatch surface ----
     def sampled_step(self, tokens: np.ndarray, lanes: np.ndarray,
                      block_tables: np.ndarray, temperature: np.ndarray):
         """ONE fixed-shape step over a packed ragged batch, sampled (see
-        `EngineCore.sampled_step`): `(sampled [2, B] int32, logits [T, V]
-        float32)`, both on the device. `block_tables` `[B, n_groups * W]`:
-        every group's table of a lane, side by side."""
-        sampled, logits, self.pools, self.counters = self._ragged(
-            self.params, self.pools, self.counters,
-            *sampling.call_arrays(tokens, lanes, block_tables, temperature,
-                                  self.last_sampled))
-        self.last_sampled = sampled
-        return sampled, logits
+        `EngineCore.sampled_step`): `sampled [2, B] int32`, on the device.
+        `block_tables` `[B, n_groups * W]`: every group's table of a lane,
+        side by side."""
+        self.last_sampled = self._run(
+            self._ragged, *sampling.call_arrays(
+                tokens, lanes, block_tables, temperature, self.last_sampled))
+        return self.last_sampled
+
+    def _run(self, fn, *arrays):
+        """One of the step programs over this engine's state, which it
+        replaces; what the program returns ahead of it."""
+        out, self.pools, self.counters = fn(self.params, self.pools,
+                                            self.counters, *arrays)
+        return out
 
     ragged_step = sampling.ragged_step
 
     def verify_step(self, tokens: np.ndarray, context_lens: np.ndarray,
                     block_tables: np.ndarray):
         """Multi-token verify (see `EngineCore.verify_step`): `[B, S, V]`."""
-        logits, self.pools, self.counters = self._verify(
-            self.params, self.pools, self.counters,
-            np.asarray(tokens, np.int32), np.asarray(context_lens, np.int32),
+        return self._run(
+            self._verify, np.asarray(tokens, np.int32),
+            np.asarray(context_lens, np.int32),
             np.asarray(block_tables, np.int32))
-        return logits
 
     generate = generate
 

@@ -11,9 +11,11 @@ an `EngineCore` whose paged pool holds ONE latent row a token a layer.
   pool became the scan's carry, PERF.md PR 28): here the layers are
   unrolled and each scatters into, and reads from, the one buffer. `row` is the latent width rounded up to whole 128-lane tiles
   (576 -> 640, zero columns; `ops/pallas/paged_attention_mla.py` says why).
-- `sampled_step` is the one compiled step, ending in the NaN screen and
-  the sampler (`ops/sampling.with_tail`); `ragged_step` is its logits,
-  `verify_step` a case of it and `generate` a host loop over it
+- `sampled_step` is a round's one compiled step, ending in the NaN screen,
+  the head over the sampled rows and the sampler (`ops/sampling.with_tail`);
+  `ragged_step` is the same stack with the head over every row (a program
+  of its own, `ops/sampling.all_rows`), `verify_step` a case of the stack
+  and `generate` a host loop over `ragged_step`
   (`inference/generate.py`). Guard slots
   (`q_len` 0) write nothing and reach no expert.
 - Expert load is counted inside the step, on the device, in donated
@@ -51,7 +53,8 @@ FAMILY = "deepseek_v3"
 def _ragged_stack(params, pool, counters, tokens, q_lens, kv_lens, tables,
                   *, cfg: dsv3.DeepseekV3Config, narrow: bool = False):
     """Packed tokens `[T]` + per-lane `(q_len, kv_len)` through the decoder:
-    `(logits [T, V] float32, pool, counters)`. `narrow`: a step whose live
+    `(hidden [T, H] before the final norm, pool, counters)`, the `stack` of
+    `ops/sampling.with_tail`. `narrow`: a step whose live
     rows number at most its lanes runs the layers' row-wise segments over
     that prefix of the packed buffer (`live_prefix.rowwise`; the choice is
     made on the device, from `q_lens`)."""
@@ -106,18 +109,13 @@ def _ragged_stack(params, pool, counters, tokens, q_lens, kv_lens, tables,
         "narrow_steps": counters["narrow_steps"] + (
             (n_live <= lanes).astype(jnp.int32) if narrow else 0),
     }
-    return dsv3.head(x, params, cfg), pool, counters
+    return x, pool, counters
 
 
-def _ragged_fn(params, pool, counters, tokens, q_lens, kv_lens, tables, *,
-               cfg):
-    # trace-time only, as the Llama engine's: the ragged step IS the serving
-    # decode program, and ragged_retraces pins "one executable whatever the
-    # batch's composition"
-    monitor.inc("serving.decode_retraces")
-    monitor.inc("serving.ragged_retraces")
-    return _ragged_stack(params, pool, counters, tokens, q_lens, kv_lens,
-                         tables, cfg=cfg, narrow=True)
+def _head(state, x, lane, *, cfg):
+    """The `head` of `ops/sampling.with_tail`: the final norm and the output
+    matmul over the rows it is given; `state[0]` is the params."""
+    return dsv3.head(x, state[0], cfg)
 
 
 def _verify_fn(params, pool, counters, tokens, ctx_lens, tables, *, cfg):
@@ -125,10 +123,10 @@ def _verify_fn(params, pool, counters, tokens, ctx_lens, tables, *, cfg):
     window of S tokens; logits fold back to `[B, S, V]`."""
     monitor.inc("serving.verify_retraces")        # trace-time only
     b, s = tokens.shape
-    logits, pool, counters = _ragged_stack(
+    x, pool, counters = _ragged_stack(
         params, pool, counters, tokens.reshape(b * s),
         jnp.full((b,), s, jnp.int32), ctx_lens, tables, cfg=cfg)
-    return logits.reshape(b, s, -1), pool, counters
+    return dsv3.head(x, params, cfg).reshape(b, s, -1), pool, counters
 
 
 class DeepseekV3InferenceEngine:
@@ -161,16 +159,20 @@ class DeepseekV3InferenceEngine:
         self._row_bytes = self.row_width * jnp.dtype(cdtype).itemsize
         self.manager.set_kv_geometry(L * block_size * self._row_bytes, 16)
 
-        def step(fn, wrap=lambda f: f):
-            bound = functools.partial(fn, cfg=cfg)
-            bound.__name__ = fn.__name__           # the XLA module's name
-            return jax.jit(wrap(bound), donate_argnums=(1, 2))
-
-        # the screen, the row gather and the sampler end the step's one
-        # program (`ops/sampling.with_tail`)
-        self._ragged = step(_ragged_fn, sampling.with_tail)
+        stack = functools.partial(_ragged_stack, cfg=cfg, narrow=True)
+        head = functools.partial(_head, cfg=cfg)
+        verify = functools.partial(_verify_fn, cfg=cfg)
+        verify.__name__ = _verify_fn.__name__      # the XLA module's name
+        # the screen, the row gather, the head over the sampled rows and
+        # the sampler end the round's one program (`ops/sampling.with_tail`);
+        # `_logits` is the same stack with the head over every row,
+        # compiled when `ragged_step` first calls it
+        self._ragged = jax.jit(sampling.with_tail(stack, head),
+                               donate_argnums=(1, 2))
+        self._logits = jax.jit(sampling.all_rows(stack, head),
+                               donate_argnums=(1, 2))
         self.last_sampled = None    # the last step's `sampled`, on device
-        self._verify = step(_verify_fn)
+        self._verify = jax.jit(verify, donate_argnums=(1, 2))
         # COW copy (prefix caching): one latent block, every layer, donated;
         # src/dst trace as scalars, so COWs never recompile
         self._copy_block = jax.jit(
@@ -181,25 +183,28 @@ class DeepseekV3InferenceEngine:
     def sampled_step(self, tokens: np.ndarray, lanes: np.ndarray,
                      block_tables: np.ndarray, temperature: np.ndarray):
         """ONE fixed-shape step over a packed ragged batch, sampled (see
-        `EngineCore.sampled_step`): `(sampled [2, B] int32, logits [T, V]
-        float32)`, both on the device."""
-        sampled, logits, self.pool, self.counters = self._ragged(
-            self.params, self.pool, self.counters,
-            *sampling.call_arrays(tokens, lanes, block_tables, temperature,
-                                  self.last_sampled))
-        self.last_sampled = sampled
-        return sampled, logits
+        `EngineCore.sampled_step`): `sampled [2, B] int32`, on the device."""
+        self.last_sampled = self._run(
+            self._ragged, *sampling.call_arrays(
+                tokens, lanes, block_tables, temperature, self.last_sampled))
+        return self.last_sampled
+
+    def _run(self, fn, *arrays):
+        """One of the step programs over this engine's state, which it
+        replaces; what the program returns ahead of it."""
+        out, self.pool, self.counters = fn(self.params, self.pool,
+                                           self.counters, *arrays)
+        return out
 
     ragged_step = sampling.ragged_step
 
     def verify_step(self, tokens: np.ndarray, context_lens: np.ndarray,
                     block_tables: np.ndarray):
         """Multi-token verify (see `EngineCore.verify_step`): `[B, S, V]`."""
-        logits, self.pool, self.counters = self._verify(
-            self.params, self.pool, self.counters,
-            np.asarray(tokens, np.int32), np.asarray(context_lens, np.int32),
+        return self._run(
+            self._verify, np.asarray(tokens, np.int32),
+            np.asarray(context_lens, np.int32),
             np.asarray(block_tables, np.int32))
-        return logits
 
     generate = generate
 

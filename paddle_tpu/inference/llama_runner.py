@@ -21,12 +21,15 @@ TPU-first choices:
   of, stacked back into, reshaped or copied by the loop
   (tests/test_inference.py pins the compiled step's temporaries under one
   layer's pool).
-- ONE compiled step: `sampled_step` is the only way into the model; its
-  program ends in the NaN screen, the gather of each lane's last row and
-  the sampler (`ops/sampling.with_tail`), so a serving round is one
-  program and one fetch. `ragged_step` is the same program's logits,
-  `verify_step` its `q_len == S` case, `generate` a host loop over it. What a pool is made of is known where it is allocated and where
-  its migration header is written, nowhere else.
+- ONE compiled step a round: `sampled_step`'s program ends in the NaN
+  screen, the gather of each lane's last hidden row, the head over those
+  `B` rows and the sampler (`ops/sampling.with_tail`), so a serving round
+  is one program and one fetch and makes no `[T, V]` array. `ragged_step`
+  is the same stack with the head over every row (`ops/sampling.all_rows`,
+  a program of its own that no round runs), `verify_step` the stack's
+  `q_len == S` case, `generate` a host loop over `ragged_step`. What a
+  pool is made of is known where it is allocated and where its migration
+  header is written, nowhere else.
 - static shapes everywhere: batch and max_blocks fixed at engine build.
 """
 from __future__ import annotations
@@ -151,9 +154,10 @@ def _rope_half(x, cos, sin):
 class LlamaInferenceEngine(kv_migrate.PagedPools):
     """Batch inference over LlamaForCausalLM with a paged KV cache.
 
-    `sampled_step` is the one jitted program (`ragged_step` its logits,
-    `verify_step` a case of it); `generate` runs the host-side loop over
-    it (sampling + block-table bookkeeping, `inference/generate.py`).
+    `sampled_step` is a round's one jitted program (`ragged_step` the
+    same stack's logits over every row, `verify_step` a case of it);
+    `generate` runs the host-side loop over `ragged_step` (sampling +
+    block-table bookkeeping, `inference/generate.py`).
     """
 
     def __init__(self, model: LlamaForCausalLM, max_batch_size: int = 8,
@@ -214,19 +218,24 @@ class LlamaInferenceEngine(kv_migrate.PagedPools):
         self.manager.set_kv_geometry(
             kv_quant.kv_bytes_per_block(**self._kv_geom), self.kv_bits)
 
-        def step(fn, wrap=lambda f: f):
-            # a bare partial has no name and the XLA module would be
-            # `jit__unknown`; with the function's it is `jit__ragged_fn`,
-            # which is how a profile's "XLA Modules" line tells the steps
-            bound = functools.partial(fn, cfg=_StaticCfg(cfg))
-            bound.__name__ = fn.__name__
-            return jax.jit(wrap(bound), donate_argnums=(1,))
-
-        # the serving step ends in the screen, the row gather and the
-        # sampler (`ops/sampling.with_tail`): one program a round
-        self._ragged = step(_ragged_fn, sampling.with_tail)
+        stack, head, verify = (
+            functools.partial(fn, cfg=_StaticCfg(cfg))
+            for fn in (_ragged_stack, _head, _verify_fn))
+        # a bare partial has no name and the XLA module would be
+        # `jit__unknown`; with the function's it is `jit__verify_fn` (the
+        # tail's wrappers bring theirs: `jit__ragged_fn`), which is how a
+        # profile's "XLA Modules" line tells the steps
+        verify.__name__ = _verify_fn.__name__
+        # the serving step ends in the screen, the row gather, the head
+        # over the sampled rows and the sampler (`ops/sampling.with_tail`):
+        # one program a round; `_logits` is the same stack with the head
+        # over every row, compiled when `ragged_step` first calls it
+        self._ragged = jax.jit(sampling.with_tail(stack, head),
+                               donate_argnums=(1,))
+        self._logits = jax.jit(sampling.all_rows(stack, head),
+                               donate_argnums=(1,))
         self.last_sampled = None    # the last step's `sampled`, on device
-        self._verify = step(_verify_fn)
+        self._verify = jax.jit(verify, donate_argnums=(1,))
         # COW copy and KV migration over the block axis (axis 1, all
         # layers at once): `kv_migrate.PagedPools`
         self._build_block_ops(1)
@@ -282,20 +291,24 @@ class LlamaInferenceEngine(kv_migrate.PagedPools):
         empty lane). `lanes` [B, 6] int32 carries q_lens, kv_lens and
         each lane's last packed row, top_k, seed and draw index
         (`ops/sampling.LANE_COLS`); `temperature` [B] float32. Returns
-        `(sampled, logits)`, both left on the device: `sampled` [2, B]
-        int32, each lane's token and whether its band's logits are all
-        finite (`ops/sampling.step_tail`); logits [T, V], whose rows at
-        guard slots past sum(q_lens) are meaningless and must be ignored
-        (their KV writes are dropped, their attention output is forced to
-        zero). Shape-stable in everything but T, which the scheduler
-        fixes at `max_batch_size + prefill_chunk_tokens` — one compiled
-        executable regardless of batch composition or prompt length."""
-        sampled, logits, self.pools = self._ragged(
-            self.params, self.pools,
-            *sampling.call_arrays(tokens, lanes, block_tables, temperature,
-                                  self.last_sampled))
-        self.last_sampled = sampled
-        return sampled, logits
+        `sampled` [2, B] int32, left on the device: each lane's token and
+        whether its band is all finite (`ops/sampling.step_tail`). The
+        head runs over the `B` sampled rows alone; rows at guard slots
+        past sum(q_lens) are meaningless and ignored (their KV writes are
+        dropped, their attention output is forced to zero). Shape-stable
+        in everything but T, which the scheduler fixes at `max_batch_size
+        + prefill_chunk_tokens` — one compiled executable regardless of
+        batch composition or prompt length."""
+        self.last_sampled = self._run(
+            self._ragged, *sampling.call_arrays(
+                tokens, lanes, block_tables, temperature, self.last_sampled))
+        return self.last_sampled
+
+    def _run(self, fn, *arrays):
+        """One of the step programs over this engine's state, which it
+        replaces; what the program returns ahead of it."""
+        out, self.pools = fn(self.params, self.pools, *arrays)
+        return out
 
     ragged_step = sampling.ragged_step
 
@@ -311,11 +324,9 @@ class LlamaInferenceEngine(kv_migrate.PagedPools):
         logits [B, S, V]: row i is the distribution for the token AFTER
         tokens[:, i] — rows 0..S-2 verify the drafts, row S-1 samples the
         bonus token when every draft is accepted."""
-        logits, self.pools = self._verify(
-            self.params, self.pools, np.asarray(tokens, np.int32),
-            np.asarray(context_lens, np.int32),
-            np.asarray(block_tables, np.int32))
-        return logits
+        return self._run(self._verify, np.asarray(tokens, np.int32),
+                         np.asarray(context_lens, np.int32),
+                         np.asarray(block_tables, np.int32))
 
     generate = generate
 
@@ -433,7 +444,7 @@ def _run_stack(params, pools, x, positions, tables, ctx_lens, cfg,
     the layer index are its `xs`, the activations AND the whole KV pool
     tuple its carry, so the (donated) pool is written where it lies and
     never becomes a per-layer `xs` slice or a stacked `ys`. Returns
-    (logits, pools)."""
+    (x [1, T, H] before the final norm, pools)."""
     import jax
     import jax.numpy as jnp
 
@@ -453,19 +464,31 @@ def _run_stack(params, pools, x, positions, tables, ctx_lens, cfg,
           params["gate_up_w"], params["down_w"],
           jnp.arange(pools[0].shape[0], dtype=jnp.int32))
     (x, pools), _ = jax.lax.scan(body, (x, pools), xs)
+    return x, pools
+
+
+def _head(state, x, lane, *, cfg):
+    """The `head` of `ops/sampling.with_tail`: the final norm and the
+    output matmul over the rows `x` [N, H] it is given (a round's `B`
+    sampled rows, or all `T`), as float32 logits [N, V]. `state[0]` is the
+    params; a row's `lane` changes nothing here."""
+    import jax
+    import jax.numpy as jnp
+
+    params = state[0]
     with jax.named_scope("llama.rms_norm"):
         x = _rms(x, params["final_norm"], cfg.eps)
     head = params.get("lm_head")
     with jax.named_scope("llama.head"):
         if head is None:
-            logits = jnp.einsum("bsh,vh->bsv", x,
+            logits = jnp.einsum("sh,vh->sv", x,
                                 params["embed"].astype(x.dtype))
         elif isinstance(head, dict):
             # weight-only-quantized head (serving/quant.py): the vocab
             # gemm is the largest single matmul of a decode step
             logits = _mm(x, head)
         else:
-            logits = jnp.einsum("bsh,hv->bsv", x, head.astype(x.dtype))
+            logits = jnp.einsum("sh,hv->sv", x, head.astype(x.dtype))
         tp = getattr(cfg, "tp", None)
         if tp is not None and tp.gather_logits and head is not None:
             # column-parallel head (tied heads stay replicated): each
@@ -475,13 +498,14 @@ def _run_stack(params, pools, x, positions, tables, ctx_lens, cfg,
             from ..distributed.tp_overlap import gather_columns
 
             logits = gather_columns(logits, tp.axis)
-    return logits, pools
+    return logits.astype(jnp.float32)
 
 
-def _ragged_stack(params, pools, tokens, q_lens, kv_lens, tables, cfg):
+def _ragged_stack(params, pools, tokens, q_lens, kv_lens, tables, *, cfg):
     """Shared body of the ragged and verify entry points: packed tokens
     [T] + per-lane (q_len, kv_len) metadata through the decoder stack.
-    Returns (logits [T, V] float32, pools)."""
+    Returns (hidden [T, H] before the final norm, pools): the `stack` of
+    `ops/sampling.with_tail`, whose `head` is `_head`."""
     import jax
     import jax.numpy as jnp
 
@@ -492,25 +516,10 @@ def _ragged_stack(params, pools, tokens, q_lens, kv_lens, tables, cfg):
     with jax.named_scope("llama.embed"):
         x = jnp.take(params["embed"], tokens[None, :], axis=0)  # [1, T, H]
     positions = jnp.maximum(tok_pos, 0)[None, :]             # [1, T]
-    logits, pools = _run_stack(
+    x, pools = _run_stack(
         params, pools, x, positions, tables, kv_lens.astype(jnp.int32),
         cfg, ragged_meta=(tok_lane, tok_pos))
-    return logits[0].astype(jnp.float32), pools              # [T, V]
-
-
-def _ragged_fn(params, pools, tokens, q_lens, kv_lens, tables, *, cfg):
-    from ..framework import monitor
-
-    # Trace-time side effects: they bump once per (re)trace, never at run
-    # time — the serving tests assert they stay flat after warmup. The
-    # ragged step IS the serving decode program, so it owns the
-    # decode_retraces counter the zero-recompile suite asserts on;
-    # ragged_retraces additionally pins "ONE executable regardless of
-    # batch composition / prompt length".
-    monitor.inc("serving.decode_retraces")
-    monitor.inc("serving.ragged_retraces")
-    return _ragged_stack(params, pools, tokens, q_lens, kv_lens, tables,
-                         cfg)
+    return x[0], pools                                       # [T, H]
 
 
 def _verify_fn(params, pools, tokens, ctx_lens, tables, *, cfg):
@@ -524,7 +533,6 @@ def _verify_fn(params, pools, tokens, ctx_lens, tables, *, cfg):
     monitor.inc("serving.verify_retraces")  # trace-time only
     b, s = tokens.shape
     q_lens = jnp.full((b,), s, jnp.int32)
-    logits, pools = _ragged_stack(params, pools, tokens.reshape(b * s),
-                                  q_lens, ctx_lens.astype(jnp.int32),
-                                  tables, cfg)
-    return logits.reshape(b, s, -1), pools                   # [B, S, V]
+    x, pools = _ragged_stack(params, pools, tokens.reshape(b * s), q_lens,
+                             ctx_lens.astype(jnp.int32), tables, cfg=cfg)
+    return _head((params,), x, None, cfg=cfg).reshape(b, s, -1), pools

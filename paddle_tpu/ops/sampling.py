@@ -21,10 +21,13 @@ Greedy lanes (temperature <= 0) take a pure argmax and ignore the RNG.
 
 The plain serving round runs none of this as a program of its own: every
 engine compiles its ragged step through `with_tail`, so the NaN screen,
-the gather of each lane's last row and the sampler above are the END of
-the step's one program (`step_tail`), and what crosses to the host is one
-`[2, B]` int32 array. `sample_tokens` stays for the speculative verify
-round, whose `[B, S, V]` logits it samples whole.
+the gather of each lane's last row, the output head over those `B` rows and
+the sampler above are the END of the step's one program (`step_tail`), and
+what crosses to the host is one `[2, B]` int32 array: no `[T, V]` array is
+ever made. `sample_tokens` stays for the speculative verify round, whose
+`[B, S, V]` logits it samples whole. A caller that wants every packed row's
+logits (`generate`, proposers, a fault probe) takes the engine's SECOND
+program, `all_rows` over the same stack and head (`ragged_step`).
 
 A round's decode tokens need not cross to the host between two rounds:
 `with_tail` reads a token `-(b + 1)` as "what lane `b` sampled in this
@@ -39,9 +42,11 @@ import functools
 
 import numpy as np
 
-__all__ = ["sample_tokens", "step_tail", "with_tail", "pack_lanes",
-           "call_arrays", "step_args", "ragged_step", "fed_token",
-           "LANE_COLS"]
+from ..framework import monitor
+
+__all__ = ["sample_tokens", "step_tail", "with_tail", "all_rows",
+           "pack_lanes", "call_arrays", "step_args", "ragged_step",
+           "fed_token", "LANE_COLS"]
 
 # the per-lane int32 block of a sampled step, one column each: with
 # `tokens`, `tables` and `temperature` it is everything a round sends
@@ -53,8 +58,6 @@ def _sample_fn(logits, temperature, top_k, seeds, draw_idx):
     """logits [B,S,V] f32; temperature [B]; top_k [B]; seeds/draw_idx [B]."""
     import jax
     import jax.numpy as jnp
-
-    from ..framework import monitor
 
     monitor.inc("serving.sample_retraces")  # trace-time only
     b, s, v = logits.shape
@@ -142,24 +145,33 @@ def pack_lanes(q_lens, kv_lens, rows=None, top_k=0, seeds=0,
     return lanes
 
 
-def step_tail(logits, lanes, temperature):
-    """The end of every engine's ragged step, traced inside its one jit:
-    logits [T, V] float32, `lanes` [B, 6] int32 (`LANE_COLS`), temperature
-    [B] float32 -> `[2, B]` int32, row 0 the token sampled from each lane's
-    last packed row (`_sample_fn`, as `sample_tokens` runs it at S == 1),
-    row 1 whether every logit of the lane's WHOLE packed band (rows
-    `row - q_len + 1 .. row`) is finite: a NaN in an early row of a chunk
-    convicts that lane and no other; an empty lane reads finite."""
-    import jax
+def _band_finite(hidden, lanes):
+    """`hidden` [T, H] a row a packed token, `lanes` [B, 6]: whether every
+    value of each lane's WHOLE packed band (rows `row - q_len + 1 .. row`)
+    is finite, [B] bool. An empty lane reads finite."""
     import jax.numpy as jnp
 
     q_lens, rows = lanes[:, _Q_LEN], lanes[:, _ROW]
+    bad = jnp.cumsum(~jnp.isfinite(hidden).all(axis=-1), dtype=jnp.int32)
+    bad = jnp.concatenate([jnp.zeros((1,), jnp.int32), bad])
+    return bad[rows + 1] == bad[rows + 1 - q_lens]
+
+
+def step_tail(logits, lanes, temperature):
+    """The sampled rows' logits to a round's `[2, B]` int32, traced inside
+    the step's one jit: logits [B, V] float32, lane b's LAST packed row's
+    (`with_tail` runs the head over those rows alone), `lanes` [B, 6] int32
+    (`LANE_COLS`), temperature [B] float32. Row 0 is the token sampled from
+    each lane's row (`_sample_fn`, as `sample_tokens` runs it at S == 1),
+    row 1 whether every logit of it is finite; an empty lane, whose row is
+    some other lane's, reads finite."""
+    import jax
+    import jax.numpy as jnp
+
     with jax.named_scope("llama.nan_screen"):
-        bad = jnp.cumsum(~jnp.isfinite(logits).all(axis=-1), dtype=jnp.int32)
-        bad = jnp.concatenate([jnp.zeros((1,), jnp.int32), bad])
-        finite = bad[rows + 1] == bad[rows + 1 - q_lens]
+        finite = jnp.isfinite(logits).all(axis=-1) | (lanes[:, _Q_LEN] == 0)
     with jax.named_scope("sampler"):
-        picked = _sample_fn(logits[rows][:, None, :], temperature,
+        picked = _sample_fn(logits[:, None, :], temperature,
                             lanes[:, _TOP_K], lanes[:, _SEED],
                             lanes[:, _DRAW])[:, 0]
     return jnp.stack([picked, finite.astype(jnp.int32)])
@@ -171,13 +183,25 @@ def fed_token(lane: int) -> int:
     return -(lane + 1)
 
 
-def with_tail(logits_step):
-    """An engine's logits step `(*state, tokens, q_lens, kv_lens, tables)
-    -> (logits [T, V], *state)` as the sampled step `(*state, tokens,
-    lanes, tables, temperature, fed) -> (sampled [2, B], logits, *state)`:
-    `step_tail` over the same logits, for `jax.jit` to compile as ONE
-    program. Whatever leads the arguments (params, pools, adapters,
-    counters) passes through, so donation indices stay the engine's.
+def with_tail(stack, head):
+    """An engine's two halves as its sampled step. `stack` `(*state,
+    tokens, q_lens, kv_lens, tables) -> (hidden [T, H], *state)` runs the
+    layers; `head` `(state, rows [N, H], lane [N]) -> logits [N, V]
+    float32` is the last norm and the output matmul over whatever rows it
+    is given, `state` the step's leading arguments as a tuple and `lane`
+    the lane each row belongs to. The sampled step is `(*state, tokens,
+    lanes, tables, temperature, fed) -> (sampled [2, B], *state)`, for
+    `jax.jit` to compile as ONE program: the head runs over each lane's
+    LAST packed row alone, `[B, H]`, and `step_tail` samples its `[B, V]`
+    logits; no `[T, V]` array is made. Whatever leads the arguments
+    (params, pools, adapters, counters) passes through, so donation
+    indices stay the engine's.
+
+    `sampled[1]`, a lane's flag, says whether every final hidden value of
+    its WHOLE packed band and every logit of its last row is finite: a NaN
+    in an early row of a chunk convicts that lane and no other (a hidden
+    row that is not finite has no finite logit, so the band's hidden rows
+    stand for the band's logits); an empty lane reads finite.
 
     `fed` `[2, B]` int32 is the `sampled` of the engine's previous call
     (zeros before the first): before the stack, a token `fed_token(b)` is
@@ -189,17 +213,48 @@ def with_tail(logits_step):
         import jax
         import jax.numpy as jnp
 
+        # trace-time only: this IS the serving decode program, so it owns
+        # the decode_retraces counter the zero-recompile suite asserts on;
+        # ragged_retraces pins "ONE executable whatever the batch's
+        # composition or a prompt's length"
+        monitor.inc("serving.decode_retraces")
+        monitor.inc("serving.ragged_retraces")
         *state, tokens, lanes, tables, temperature, fed = args
         with jax.named_scope("llama.feed"):
             lane = jnp.clip(-tokens - 1, 0, fed.shape[1] - 1)
             tokens = jnp.where(tokens < 0, fed[0][lane], tokens)
-        logits, *state = logits_step(*state, tokens, lanes[:, _Q_LEN],
-                                     lanes[:, _KV_LEN], tables)
-        return (step_tail(logits, lanes, temperature), logits, *state)
+        hidden, *out = stack(*state, tokens, lanes[:, _Q_LEN],
+                             lanes[:, _KV_LEN], tables)
+        with jax.named_scope("llama.nan_screen"):
+            band = _band_finite(hidden, lanes)
+        with jax.named_scope("sampler"):
+            last = hidden[lanes[:, _ROW]]                      # [B, H]
+        logits = head(tuple(state), last,
+                      jnp.arange(last.shape[0], dtype=jnp.int32))  # [B, V]
+        sampled = step_tail(logits, lanes, temperature)
+        return (sampled.at[1].multiply(band.astype(jnp.int32)), *out)
 
     # the function's name is the XLA module's: `jit__ragged_fn`, which is
     # how a profile tells the serving step (docs/OBSERVABILITY.md)
     return _ragged_fn
+
+
+def all_rows(stack, head):
+    """The same `stack` and `head` (see `with_tail`) as the program that
+    returns every packed row's logits: `(*state, tokens, q_lens, kv_lens,
+    tables) -> (logits [T, V] float32, *state)`. An executable of its own
+    (`jit__logits_fn`), traced when `ragged_step` first calls it: no round
+    of the scheduler does."""
+    def _logits_fn(*args):
+        from .pallas.paged_attention import ragged_metadata
+
+        monitor.inc("serving.logits_retraces")  # trace-time only
+        *state, tokens, q_lens, kv_lens, tables = args
+        hidden, *out = stack(*args)
+        lane, _pos = ragged_metadata(q_lens, kv_lens, tokens.shape[0])
+        return (head(tuple(state), hidden, lane), *out)
+
+    return _logits_fn
 
 
 def call_arrays(tokens, lanes, block_tables, temperature, fed=None):
@@ -218,8 +273,7 @@ def call_arrays(tokens, lanes, block_tables, temperature, fed=None):
 
 def step_args(tokens, q_lens, kv_lens, block_tables):
     """`call_arrays` for greedy lanes sampling their last packed rows, fed
-    nothing: what `ragged_step` sends (the engine adds its own `fed`), and
-    what lowers the step at those shapes."""
+    nothing: what lowers the sampled step at those shapes."""
     q_lens = np.asarray(q_lens, np.int32)
     return call_arrays(tokens, pack_lanes(q_lens, kv_lens), block_tables,
                        np.zeros(q_lens.shape, np.float32))
@@ -227,8 +281,11 @@ def step_args(tokens, q_lens, kv_lens, block_tables):
 
 def ragged_step(engine, tokens, q_lens, kv_lens, block_tables):
     """`EngineCore.ragged_step`, bound by every engine class: the logits
-    `[T, V]` of one step, for `generate`, proposers and checks that sample
-    on the host. The SAME compiled program as the scheduler's round
-    (`sampled_step`), its lanes greedy and its tokens left on the device."""
-    *round_arrays, _fed = step_args(tokens, q_lens, kv_lens, block_tables)
-    return engine.sampled_step(*round_arrays)[1]
+    `[T, V]` of one step over every packed row, for `generate`, proposers,
+    probes and checks that sample on the host. The engine's all-rows
+    program (`all_rows`), over the state the sampled step leaves and
+    takes: `engine._run` threads it."""
+    monitor.inc("serving.step.all_rows_calls")
+    return engine._run(engine._logits, *(
+        np.asarray(a, np.int32)
+        for a in (tokens, q_lens, kv_lens, block_tables)))

@@ -555,7 +555,9 @@ class Profiler:
                 f"{g('serving.step.overlapped')} overlapped (share "
                 f"{g('serving.step.overlap_share')}), "
                 f"{g('serving.step.wasted_lanes')} wasted lanes, "
-                f"{g('serving.step.forced_settles')} forced settles")
+                f"{g('serving.step.forced_settles')} forced settles; "
+                f"{g('serving.step.all_rows_calls')} all-rows calls "
+                f"(logits retraces {g('serving.logits_retraces')})")
         if g("serving.state.bytes_per_seq"):
             # an engine over a state group: a sequence holds one slot of
             # recurrent state, whatever its length (docs/SERVING.md "A

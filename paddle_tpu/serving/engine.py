@@ -3,24 +3,28 @@
 Generalizes `inference.llama_runner.LlamaInferenceEngine` into the contract
 the continuous-batching scheduler programs against. An engine owns stacked
 model params and `self.pools`, one tuple of paged KV(-like) arrays, and
-exposes ONE compiled way into its model:
+exposes ONE compiled program a round:
 
 - `sampled_step(tokens [T], lanes [B, 6], block_tables [B, MAXB],
   temperature [B])` — one fixed-shape step over a packed ragged batch
   (prefill chunks and decode lanes alike; the scheduler pads empty lanes
   with `q_len` 0) that ENDS in the NaN screen, the gather of each lane's
-  last row and the sampler (`ops/sampling.with_tail`), returning
-  `(sampled [2, B], logits [T, V])` on the device: a scheduler round is
-  this one program and one fetch of `sampled`. The engine keeps that
-  `sampled` as `last_sampled` and hands it to its next step, where a token
+  last hidden row, the output head over those `B` rows and the sampler
+  (`ops/sampling.with_tail`), returning `sampled [2, B]` on the device: a
+  scheduler round is this one program and one fetch of `sampled`, and no
+  `[T, V]` array is made. The engine keeps that `sampled` as
+  `last_sampled` and hands it to its next step, where a token
   `ops/sampling.fed_token(b)` reads lane `b`'s out of it: the scheduler
   launches a round before it has fetched the one before
   (docs/SERVING.md "A round in flight");
 - `ragged_step(tokens [T], q_lens [B], kv_lens [B], block_tables)` is the
-  same program's logits (`ops/sampling.ragged_step`);
-- `verify_step(tokens [B, S], context_lens [B], block_tables)` is its
-  `q_len == S` case (speculative decoding), and `generate(input_ids)` a
-  host loop over it (`inference/generate.py`).
+  same stack with the head over every row, `[T, V]` logits: a second
+  executable (`ops/sampling.all_rows`) that no round runs, compiled when
+  a prober first calls it (`ops/sampling.ragged_step`);
+- `verify_step(tokens [B, S], context_lens [B], block_tables)` is the
+  stack's `q_len == S` case (speculative decoding), and
+  `generate(input_ids)` a host loop over `ragged_step`
+  (`inference/generate.py`).
 
 Beside it: `copy_kv_block(src, dst)` (the manager's COW hook) and
 `extract_kv_blocks(seq_id)` / `inject_kv_blocks(seq_id, payload)` (KV
@@ -29,9 +33,10 @@ migration), each one donated executable over the whole pool tuple
 
 Every compiled entry is shape-stable so the serving steady state never
 recompiles (the Ragged-Paged-Attention shape discipline, PAPERS.md).
-Engines bump `monitor.inc("serving.decode_retraces"/
-"serving.ragged_retraces"/"serving.verify_retraces")` at TRACE time inside
-their jitted fns so tests can assert exactly that.
+The round's program bumps `serving.decode_retraces` and
+`serving.ragged_retraces`, the all-rows one `serving.logits_retraces`
+(`ops/sampling`), an engine's verify `serving.verify_retraces`, each at
+TRACE time inside the jitted function, so tests can assert exactly that.
 
 Failure contract (docs/SERVING.md "Failure semantics"): an engine may
 raise from any entry point — the scheduler's typed fault boundary
@@ -93,9 +98,9 @@ class EngineCore(Protocol):
         `lanes` [B, 6] int32 holds q_lens, kv_lens, each lane's last
         packed row, top_k, seed and draw index
         (`ops/sampling.LANE_COLS`), `temperature` [B] float32. Returns
-        `(sampled [2, B] int32, logits [T, V])`, both on the device:
-        each lane's sampled token and whether its whole band's logits
-        are finite (`ops/sampling.step_tail`). The serving scheduler's
+        `sampled [2, B] int32`, on the device: each lane's sampled token
+        and whether its whole band is finite (`ops/sampling.step_tail`);
+        the head runs over the `B` sampled rows. The serving scheduler's
         only decode-path dispatch — decode lanes and chunked-prefill
         tokens share it, so the steady state holds ONE executable with
         no prompt-length or bucket shape family."""
@@ -104,9 +109,10 @@ class EngineCore(Protocol):
     def ragged_step(self, tokens: np.ndarray, q_lens: np.ndarray,
                     kv_lens: np.ndarray,
                     block_tables: np.ndarray) -> np.ndarray:
-        """`sampled_step`'s logits [T, V], for callers that sample on
-        the host (`generate`, proposers, checks): the same compiled
-        program with greedy lanes (`ops/sampling.ragged_step`)."""
+        """Every packed row's logits [T, V], for callers that sample on
+        the host (`generate`, proposers, probes, checks): the sampled
+        step's stack with the head over all rows, an executable of its
+        own that no round runs (`ops/sampling.ragged_step`)."""
         ...
 
 
@@ -121,7 +127,9 @@ def _mlp_ragged_stack(params, pools, tokens, q_lens, kv_lens, tables, *,
     ([NB, BS] f32) of an int8-quantized embedding pool
     (`inference/kv_quant.py`): writes quantize per slot, the gathered
     window dequantizes right after the gather — the float pool never
-    exists. Returns (logits, pools).
+    exists. Returns (hidden [T, 2D], pools), a token's own embedding
+    beside its window's mean: the `stack` of `ops/sampling.with_tail`,
+    whose `head` is `_mlp_head`.
 
     `tp` (`distributed.tp_overlap.TPInfo`, set by `serving/tp.py` when
     the body runs inside shard_map) marks a feature-sharded pool: each
@@ -174,21 +182,7 @@ def _mlp_ragged_stack(params, pools, tokens, q_lens, kv_lens, tables, *,
     mask = (wpos[None, :] <= tok_pos[:, None]).astype(x.dtype)
     mean = (window * mask[..., None]).sum(1) / jnp.maximum(
         mask.sum(1, keepdims=True), 1.0)                     # [T, D]
-    logits = _mlp_head(params, x_loc, mean, tp=tp)
-    return logits.astype(jnp.float32), pools
-
-
-def _mlp_ragged(params, pools, tokens, q_lens, kv_lens, tables, *,
-                block_size, tp=None):
-    from ..framework import monitor
-
-    # trace-time only — the ragged step IS the serving decode program
-    # (decode_retraces keeps the zero-recompile suite's counter name);
-    # ragged_retraces pins the one-executable-per-composition claim
-    monitor.inc("serving.decode_retraces")
-    monitor.inc("serving.ragged_retraces")
-    return _mlp_ragged_stack(params, pools, tokens, q_lens, kv_lens,
-                             tables, block_size=block_size, tp=tp)
+    return jnp.concatenate([x_loc, mean], axis=-1), pools    # [T, 2D]
 
 
 def _mlp_verify(params, pools, tokens, ctx_lens, tables, *, block_size,
@@ -202,10 +196,10 @@ def _mlp_verify(params, pools, tokens, ctx_lens, tables, *, block_size,
     monitor.inc("serving.verify_retraces")  # trace-time only
     b, s = tokens.shape
     q_lens = jnp.full((b,), s, jnp.int32)
-    logits, pools = _mlp_ragged_stack(
+    h, pools = _mlp_ragged_stack(
         params, pools, tokens.reshape(b * s), q_lens,
         ctx_lens.astype(jnp.int32), tables, block_size=block_size, tp=tp)
-    return logits.reshape(b, s, -1), pools
+    return _mlp_head((params,), h, None, tp=tp).reshape(b, s, -1), pools
 
 
 def _mlp_mm(h, w):
@@ -228,23 +222,25 @@ def _mlp_mm(h, w):
     return dequant_matmul(h, w["q"], w["s"])
 
 
-def _mlp_head(params, last, mean, tp=None):
-    """`gelu([last, mean] @ w1 + b1) @ w2 + b2`.
+def _mlp_head(state, h, lane, *, tp=None):
+    """The `head` of `ops/sampling.with_tail` over the rows `h` [N, 2D] it
+    is given (`state[0]` the params): `gelu(h @ w1 + b1) @ w2 + b2` as
+    float32 logits [N, V].
 
-    Under TP (`tp` set, inside shard_map): `last`/`mean` are the local
-    feature slices, `w1` is the matching row-parallel shard (rows
-    permuted by `serving/tp.py` so shard s holds [last_s, mean_s]) whose
-    partial sums psum-reduce tile-by-tile — tile k's collective overlaps
-    tile k+1's gemm (`distributed/tp_overlap.py`) — and `w2`/`b2` are
+    Under TP (`tp` set, inside shard_map): `h` holds the local feature
+    slices, `w1` is the matching row-parallel shard (rows permuted by
+    `serving/tp.py` so shard s holds [last_s, mean_s]) whose partial sums
+    psum-reduce tile-by-tile — tile k's collective overlaps tile k+1's
+    gemm (`distributed/tp_overlap.py`) — and `w2`/`b2` are
     column-parallel vocab shards; `tp.gather_logits` finishes with an
     in-program all-gather so the sampler sees replicated logits."""
     import jax
     import jax.numpy as jnp
 
-    h = jnp.concatenate([last, mean], axis=-1)
+    params = state[0]
     if tp is None:
         h = jax.nn.gelu(_mlp_mm(h, params["w1"]) + params["b1"])
-        return _mlp_mm(h, params["w2"]) + params["b2"]
+        return (_mlp_mm(h, params["w2"]) + params["b2"]).astype(jnp.float32)
     from ..distributed.tp_overlap import gather_columns, row_parallel_matmul
 
     h = jax.nn.gelu(
@@ -253,7 +249,7 @@ def _mlp_head(params, last, mean, tp=None):
     logits = _mlp_mm(h, params["w2"]) + params["b2"]
     if tp.gather_logits:
         logits = gather_columns(logits, tp.axis)
-    return logits
+    return logits.astype(jnp.float32)
 
 
 class MLPLMEngine(kv_migrate.PagedPools):
@@ -310,12 +306,15 @@ class MLPLMEngine(kv_migrate.PagedPools):
         self._slab_names = ("cache", "scale")[:len(self.pools)]
         self._kv_bytes_per_token = bpb / block_size
         self.manager.set_kv_geometry(bpb, self.kv_bits)
-        # the step ends in the screen, the row gather and the sampler
-        # (`ops/sampling.with_tail`): one program a round
-        self._ragged = jax.jit(
-            sampling.with_tail(
-                functools.partial(_mlp_ragged, block_size=block_size)),
-            donate_argnums=(1,))
+        # the step ends in the screen, the row gather, the head over the
+        # sampled rows and the sampler (`ops/sampling.with_tail`): one
+        # program a round; `_logits` is the same stack with the head over
+        # every row, compiled when `ragged_step` first calls it
+        stack = functools.partial(_mlp_ragged_stack, block_size=block_size)
+        self._ragged = jax.jit(sampling.with_tail(stack, _mlp_head),
+                               donate_argnums=(1,))
+        self._logits = jax.jit(sampling.all_rows(stack, _mlp_head),
+                               donate_argnums=(1,))
         self.last_sampled = None    # the last step's `sampled`, on device
         self._verify = jax.jit(
             functools.partial(_mlp_verify, block_size=block_size),
@@ -377,21 +376,23 @@ class MLPLMEngine(kv_migrate.PagedPools):
         # transfers them far cheaper than per-arg host-side jnp.asarray
         # device_put calls — this discipline (shared with
         # ops/sampling.py) is worth ~1 ms/arg on the decode hot loop
-        logits, self.pools = self._verify(
-            self.params, self.pools, np.asarray(tokens, np.int32),
-            np.asarray(context_lens, np.int32),
-            np.asarray(block_tables, np.int32))
-        return logits
+        return self._run(self._verify, np.asarray(tokens, np.int32),
+                         np.asarray(context_lens, np.int32),
+                         np.asarray(block_tables, np.int32))
 
     def sampled_step(self, tokens: np.ndarray, lanes: np.ndarray,
                      block_tables: np.ndarray, temperature: np.ndarray):
         """Packed ragged step, sampled; see `EngineCore.sampled_step`."""
-        sampled, logits, self.pools = self._ragged(
-            self.params, self.pools,
-            *sampling.call_arrays(tokens, lanes, block_tables, temperature,
-                                  self.last_sampled))
-        self.last_sampled = sampled
-        return sampled, logits
+        self.last_sampled = self._run(
+            self._ragged, *sampling.call_arrays(
+                tokens, lanes, block_tables, temperature, self.last_sampled))
+        return self.last_sampled
+
+    def _run(self, fn, *arrays):
+        """One of the step programs over this engine's state, which it
+        replaces; what the program returns ahead of it."""
+        out, self.pools = fn(self.params, self.pools, *arrays)
+        return out
 
     ragged_step = sampling.ragged_step
 

@@ -147,17 +147,18 @@ def _lane_ids(q_lens, kv_lens, num_tokens, lane_slots):
 
 # ---- wrapper jit bodies -------------------------------------------------
 # Each computes per-token ids, swaps the target weights, and calls the
-# BASE engine's step `base` (`_ragged_fn` / `_verify_fn`, or the MLP
-# engine's pair, its static arguments already bound) — so the base
-# retrace counters bump at OUR trace time and the zero-recompile suite's
-# assertions carry over unchanged. The `serving.lora.switch_retraces`
-# bump is trace-time too: adapter ids are data, so any post-warmup bump
-# means an adapter switch recompiled. `nlayers` is the leading axis of a
-# stacked engine's weights (they ride `lax.scan` xs, and so must the
-# ids), None for a flat one.
+# BASE engine's function `base` (its stack, its head or its verify step,
+# the Llama engine's or the MLP engine's, its static arguments already
+# bound), so the tail's and the verify step's retrace counters bump at OUR
+# trace time and the zero-recompile suite's assertions carry over
+# unchanged. The `serving.lora.switch_retraces` bump is trace-time too:
+# adapter ids are data, so any post-warmup bump means an adapter switch
+# recompiled. `nlayers` is the leading axis of a stacked engine's weights
+# (they ride `lax.scan` xs, and so must the ids), None for a flat one.
 
-def _lora_ragged(params, adapters, pools, lane_slots, tokens, q_lens,
-                 kv_lens, tables, *, base, nlayers):
+def _lora_stack(params, adapters, pools, lane_slots, tokens, q_lens,
+                kv_lens, tables, *, base, nlayers):
+    """The `stack` of `ops/sampling.with_tail` over the base engine's."""
     import jax.numpy as jnp
 
     monitor.inc("serving.lora.switch_retraces")  # trace-time only
@@ -166,6 +167,14 @@ def _lora_ragged(params, adapters, pools, lane_slots, tokens, q_lens,
         ids = jnp.broadcast_to(ids[None, :], (nlayers, tokens.shape[0]))
     return base(_swap_lora(params, adapters, ids), pools, tokens, q_lens,
                 kv_lens, tables)
+
+
+def _lora_head(state, x, lane, *, base):
+    """The `head` of `ops/sampling.with_tail` over the base engine's: a
+    row's adapter is its lane's (the MLP engine's head weights are
+    adapted; the Llama engine's head reads none of the swapped weights)."""
+    params, adapters, _pools, lane_slots = state
+    return base((_swap_lora(params, adapters, lane_slots[lane]),), x, lane)
 
 
 def _lora_verify(params, adapters, pools, lane_slots, tokens, ctx_lens,
@@ -541,24 +550,29 @@ class LoRAEngine(kv_migrate.PagedPools):
         self._default_lease: Optional[str] = None
 
         if self._kind == "llama":
-            from ..inference.llama_runner import (_ragged_fn, _StaticCfg,
-                                                  _verify_fn)
+            from ..inference import llama_runner as lr
 
-            bases, static = (_ragged_fn, _verify_fn), {
-                "cfg": _StaticCfg(base.config)}
+            stack, head, verify = (
+                functools.partial(fn, cfg=lr._StaticCfg(base.config))
+                for fn in (lr._ragged_stack, lr._head, lr._verify_fn))
         else:
-            from .engine import _mlp_ragged, _mlp_verify
+            from .engine import _mlp_head, _mlp_ragged_stack, _mlp_verify
 
-            bases, static = (_mlp_ragged, _mlp_verify), {
-                "block_size": base.block_size}
+            stack, verify = (
+                functools.partial(fn, block_size=base.block_size)
+                for fn in (_mlp_ragged_stack, _mlp_verify))
+            head = _mlp_head
         self.pools = jax.tree.map(jnp.zeros_like, base.pools)
-        ragged, verify = (
-            functools.partial(fn, base=functools.partial(b, **static),
-                              nlayers=self._nlayers)
-            for fn, b in zip((_lora_ragged, _lora_verify), bases))
-        # the ragged step ends in the base engines' tail: one program a
-        # round here too (`ops/sampling.with_tail`)
-        self._ragged = jax.jit(sampling.with_tail(ragged),
+        stack, verify = (
+            functools.partial(fn, base=b, nlayers=self._nlayers)
+            for fn, b in ((_lora_stack, stack), (_lora_verify, verify)))
+        head = functools.partial(_lora_head, base=head)
+        # the round ends in the base engines' tail: one program a round
+        # here too (`ops/sampling.with_tail`), and `_logits` the same stack
+        # with the head over every row (`ops/sampling.all_rows`)
+        self._ragged = jax.jit(sampling.with_tail(stack, head),
+                               donate_argnums=(2,))
+        self._logits = jax.jit(sampling.all_rows(stack, head),
                                donate_argnums=(2,))
         self.last_sampled = None    # the last step's `sampled`, on device
         self._verify = jax.jit(verify, donate_argnums=(2,))
@@ -614,22 +628,24 @@ class LoRAEngine(kv_migrate.PagedPools):
 
     # -- EngineCore dispatch surfaces --
     def sampled_step(self, tokens, lanes, block_tables, temperature):
-        sampled, logits, self.pools = self._ragged(
-            self.params, self._adapters, self.pools, self._lane_slots,
-            *sampling.call_arrays(tokens, lanes, block_tables, temperature,
-                                  self.last_sampled))
-        self.last_sampled = sampled
-        return sampled, logits
+        self.last_sampled = self._run(
+            self._ragged, *sampling.call_arrays(
+                tokens, lanes, block_tables, temperature, self.last_sampled))
+        return self.last_sampled
+
+    def _run(self, fn, *arrays):
+        """One of the step programs over this engine's state, which it
+        replaces; what the program returns ahead of it."""
+        out, self.pools = fn(self.params, self._adapters, self.pools,
+                             self._lane_slots, *arrays)
+        return out
 
     ragged_step = sampling.ragged_step
 
     def verify_step(self, tokens, context_lens, block_tables):
-        logits, self.pools = self._verify(
-            self.params, self._adapters, self.pools, self._lane_slots,
-            np.asarray(tokens, np.int32),
-            np.asarray(context_lens, np.int32),
-            np.asarray(block_tables, np.int32))
-        return logits
+        return self._run(self._verify, np.asarray(tokens, np.int32),
+                         np.asarray(context_lens, np.int32),
+                         np.asarray(block_tables, np.int32))
 
     generate = generate
 

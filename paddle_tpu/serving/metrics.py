@@ -11,9 +11,14 @@ Everything is double-published:
   be rebuilt from monotonic counters).
 
 Retrace counters (`serving.decode_retraces` / `serving.ragged_retraces` /
-`serving.verify_retraces`)
-are bumped by the ENGINES at jit-trace time (see serving/engine.py); this
-module only reads them. In steady state they must stay flat.
+`serving.verify_retraces` / `serving.logits_retraces`)
+are bumped by the ENGINES' programs at jit-trace time (see
+serving/engine.py); this module only reads them. In steady state they must
+stay flat. `serving.step.all_rows_calls` counts, on the host, the calls of
+an engine's all-rows program (`ops/sampling.ragged_step`: `generate`,
+proposers, a fault probe): with `serving.logits_retraces` it says whether
+that second program engaged, and a run that serves plain rounds and never
+probes reads 0 for both.
 """
 from __future__ import annotations
 
@@ -397,6 +402,10 @@ class ServingMetrics:
         # (ttft_seconds_bucket_*) stays out of summary() — callers key on
         # exact metric names
         out = monitor.snapshot("serving.", include_histograms=False)
+        # whether the all-rows program engaged, said even where it did not
+        for name in ("serving.step.all_rows_calls",
+                     "serving.logits_retraces"):
+            out.setdefault(name, 0)
         out["serving.ttft_p50_ms"] = _r(_pct(self.ttft_s, 50))
         out["serving.ttft_p99_ms"] = _r(_pct(self.ttft_s, 99))
         out["serving.tpot_mean_ms"] = _r(

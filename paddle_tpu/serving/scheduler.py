@@ -95,7 +95,7 @@ from ..framework.retry import Budget, retry_call
 from ..inference.cache import KVCacheExhausted, SequenceTooLong
 from ..inference.kv_migrate import KVMigrationError
 from ..inference.prefix_cache import RadixPrefixCache
-from ..ops.sampling import (call_arrays, fed_token, pack_lanes, ragged_step,
+from ..ops.sampling import (call_arrays, fed_token, pack_lanes,
                             sample_tokens)
 from ..resilience import faults as _faults
 from .engine import EngineCore
@@ -1828,7 +1828,7 @@ class Scheduler:
             # the lane's WHOLE packed band: a NaN confined to an earlier
             # chunk row must still convict this lane (the caller's
             # finiteness check reduces over everything returned)
-            return np.asarray(ragged_step(self.engine, t, q, kv, tb))[:n]
+            return np.asarray(self.engine.ragged_step(t, q, kv, tb))[:n]
 
         self._install_lane_adapters()
         overlapped = self._launched is not None
@@ -1839,7 +1839,7 @@ class Scheduler:
                                                 if ln.chunk),
                              decode_lanes=sum(not ln.chunk
                                               for ln, _first in plan)):
-                (rnd.sampled, _logits), rnd.flagged = self._dispatch(
+                rnd.sampled, rnd.flagged = self._dispatch(
                     "decode", self.engine.sampled_step, tokens, lanes,
                     tables, temps)
         except Exception as e:

@@ -314,8 +314,8 @@ class ShardedEngine(kv_migrate.PagedPools):
 
         if kind == "llama":
             from ..inference import kv_quant
-            from ..inference.llama_runner import (_StaticCfg, _ragged_fn,
-                                                  _verify_fn)
+            from ..inference.llama_runner import (_head, _ragged_stack,
+                                                  _StaticCfg, _verify_fn)
 
             cfg = base.config
             nh, kvh, d = (cfg.num_attention_heads, cfg.num_key_value_heads,
@@ -348,8 +348,11 @@ class ShardedEngine(kv_migrate.PagedPools):
             lspec = R if (overlap or not vocab_sharded) else P(None, "tp")
             vspec = R if (overlap or not vocab_sharded) \
                 else P(None, None, "tp")
-            ragged = functools.partial(_ragged_fn, cfg=lcfg)
-            verify = functools.partial(_verify_fn, cfg=lcfg)
+            stack, head, verify = (functools.partial(fn, cfg=lcfg) for fn in
+                                   (_ragged_stack, _head, _verify_fn))
+            # the row-parallel gemms' psums leave every shard the whole
+            # hidden row
+            hspec = R
             geom = dict(base._kv_geom)
             geom["kv_heads"] //= tp
             self._kv_bytes_per_token = kv_quant.kv_bytes_per_token(**geom)
@@ -360,7 +363,7 @@ class ShardedEngine(kv_migrate.PagedPools):
                       "kv_heads": base._kv_geom["kv_heads"],
                       "head_dim": geom["head_dim"]}
         else:
-            from .engine import _mlp_ragged, _mlp_verify
+            from .engine import _mlp_head, _mlp_ragged_stack, _mlp_verify
 
             d = int(base.params["embed"].shape[1])
             p = dict(base.params)
@@ -376,12 +379,14 @@ class ShardedEngine(kv_migrate.PagedPools):
             poolspec = (P(None, None, "tp"), R)[:len(base.pools)]
             lspec = R if overlap else P(None, "tp")
             vspec = R if overlap else P(None, None, "tp")
-            ragged = functools.partial(_mlp_ragged,
-                                       block_size=base.block_size,
-                                       tp=self.tpinfo)
-            verify = functools.partial(_mlp_verify,
-                                       block_size=base.block_size,
-                                       tp=self.tpinfo)
+            stack, verify = (
+                functools.partial(fn, block_size=base.block_size,
+                                  tp=self.tpinfo)
+                for fn in (_mlp_ragged_stack, _mlp_verify))
+            head = functools.partial(_mlp_head, tp=self.tpinfo)
+            # each shard's [own embedding, window mean] feature slices,
+            # side by side in the order of `w1`'s permuted rows
+            hspec = P(None, "tp")
             bpb = base.block_size * (d // tp) * base.pools[0].dtype.itemsize \
                 + base.block_size * 4 * (len(base.pools) - 1)
             self._kv_bytes_per_token = bpb / base.block_size
@@ -389,14 +394,26 @@ class ShardedEngine(kv_migrate.PagedPools):
             header = {"engine": "mlp", "hidden": d}
 
         self.pools = tuple(put(p, s) for p, s in zip(base.pools, poolspec))
-        # the tail (screen, row gather, sampler) follows the shard_map in
-        # the SAME jit, over whatever layout the logits leave it in: vocab
+        # the stack is one shard_map and the head another, in the SAME
+        # jit: between them the tail gathers each lane's last hidden row
+        # (`ops/sampling.with_tail`), so the head's gemms and its
+        # collective run over `B` rows, and the screen and the sampler
+        # follow over whatever layout the logits leave the head in: vocab
         # shards in sequential mode, replicated rows under overlap
-        self._ragged = jax.jit(sampling.with_tail(jax.shard_map(
-            ragged, mesh=jmesh,
-            in_specs=(pspec, poolspec, R, R, R, R),
-            out_specs=(lspec, poolspec),
-            check_vma=False)), donate_argnums=(1,))
+        stack = jax.shard_map(
+            stack, mesh=jmesh, in_specs=(pspec, poolspec, R, R, R, R),
+            out_specs=(hspec, poolspec), check_vma=False)
+        rows_head = jax.shard_map(
+            lambda params, x: head((params,), x, None), mesh=jmesh,
+            in_specs=(pspec, hspec), out_specs=lspec, check_vma=False)
+
+        def sharded_head(state, x, lane):
+            return rows_head(state[0], x)
+
+        self._ragged = jax.jit(sampling.with_tail(stack, sharded_head),
+                               donate_argnums=(1,))
+        self._logits = jax.jit(sampling.all_rows(stack, sharded_head),
+                               donate_argnums=(1,))
         # the last step's `sampled`, replicated as the step leaves it: a
         # host array first would key a second executable
         self.last_sampled = put(
@@ -454,19 +471,11 @@ class ShardedEngine(kv_migrate.PagedPools):
     def sampled_step(self, tokens: np.ndarray, lanes: np.ndarray,
                      block_tables: np.ndarray, temperature: np.ndarray):
         """Packed ragged step, sampled (see `EngineCore.sampled_step`),
-        TP-sharded. With observability on, the dispatch runs inside a
-        `comms.step_overlap` window — overlap mode exposes ~0 collective
-        ms (everything is in-program), sequential mode's host logit
-        assembly is recorded as an exposed all_gather."""
-        args = sampling.call_arrays(tokens, lanes, block_tables, temperature,
-                                    self.last_sampled)
-        if _obs.enabled():
-            with comms.step_overlap(self._step_label):
-                out = self._dispatch(self._ragged, True, *args)
-        else:
-            out = self._dispatch(self._ragged, False, *args)
-        self.last_sampled = out[0]
-        return out
+        TP-sharded: `sampled [2, B]`, replicated, on the device."""
+        self.last_sampled = self._run(
+            self._ragged, *sampling.call_arrays(
+                tokens, lanes, block_tables, temperature, self.last_sampled))
+        return self.last_sampled
 
     ragged_step = sampling.ragged_step
 
@@ -474,32 +483,42 @@ class ShardedEngine(kv_migrate.PagedPools):
                     block_tables: np.ndarray) -> np.ndarray:
         """Speculative verify (see `EngineCore.verify_step`), TP-sharded
         — rides the same sharded ragged stack, so spec == plain under TP."""
-        args = [np.asarray(a, np.int32)
-                for a in (tokens, context_lens, block_tables)]
+        return self._run(self._verify, *(
+            np.asarray(a, np.int32)
+            for a in (tokens, context_lens, block_tables)))
+
+    def _run(self, fn, *args):
+        """One of the step programs over this engine's pools, which it
+        replaces; what the program returns ahead of them. With
+        observability on, the dispatch runs inside a `comms.step_overlap`
+        window — overlap mode exposes ~0 collective ms (everything is
+        in-program), sequential mode's host logit assembly is recorded as
+        an exposed all_gather."""
         if _obs.enabled():
             with comms.step_overlap(self._step_label):
-                return self._dispatch(self._verify, True, *args)
-        return self._dispatch(self._verify, False, *args)
+                return self._dispatch(fn, True, *args)
+        return self._dispatch(fn, False, *args)
 
     def _dispatch(self, fn, obs_on, *args):
-        """Run one step executable over exact-dtype call arrays; returns
-        what it returns ahead of the pools: the verify step's logits, the
-        ragged step's `(sampled, logits)`."""
-        *out, self.pools = fn(self.params, self.pools, *args)
-        logits = out[-1]
-        if self.overlap:
+        """Run one step executable over exact-dtype call arrays. The
+        round's `sampled` stays on the device in either mode (its sampler
+        ran in-program, over the head's `B` rows); logits (the verify
+        step's, the all-rows program's) leave sequential mode through
+        the host."""
+        out, self.pools = fn(self.params, self.pools, *args)
+        if self.overlap or fn is self._ragged:
             if obs_on:
-                self._jax.block_until_ready(logits)
-        else:
-            # sequential-collective baseline: the vocab shards cross to
-            # the host and reassemble here, fully exposed — the leg the
-            # tiled in-program psums + device all-gather delete
-            self._jax.block_until_ready(logits)
-            t0 = time.perf_counter()
-            out[-1] = np.asarray(logits)
-            if _obs.enabled():
-                comms.record("all_gather", self.tp, out[-1].nbytes, t0,
-                             time.perf_counter() - t0)
-        return out[0] if len(out) == 1 else tuple(out)
+                self._jax.block_until_ready(out)
+            return out
+        # sequential-collective baseline: the vocab shards cross to
+        # the host and reassemble here, fully exposed — the leg the
+        # tiled in-program psums + device all-gather delete
+        self._jax.block_until_ready(out)
+        t0 = time.perf_counter()
+        out = np.asarray(out)
+        if _obs.enabled():
+            comms.record("all_gather", self.tp, out.nbytes, t0,
+                         time.perf_counter() - t0)
+        return out
 
     generate = generate

@@ -31,6 +31,7 @@ from paddle_tpu.models import brumby as bm
 from paddle_tpu.ops.pallas import power_retention as pr
 from paddle_tpu.resilience import faults
 from paddle_tpu.serving import RequestStatus, ServingFrontend
+from test_sampled_step import all_rows_round
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -83,9 +84,12 @@ class Recording(BrumbyInferenceEngine):
         self.rows, self.slots_of = [], None
 
     def sampled_step(self, tokens, lanes, tables, temperature):
-        sampled, logits = super().sampled_step(tokens, lanes, tables,
-                                               temperature)
-        logits = np.asarray(logits)
+        # every packed row's logits come from the all-rows program, and a
+        # state cannot take a step twice: this engine's round IS that
+        # program, sampled on the host as the tail would
+        sampled, logits = all_rows_round(self, tokens, lanes, tables,
+                                         temperature)
+        sampled = self.last_sampled = jnp.asarray(sampled)
         cursor = 0
         for lane, (n, kv) in enumerate(lanes[:, :2]):
             req = self.slots_of()[lane]
@@ -93,7 +97,7 @@ class Recording(BrumbyInferenceEngine):
                 self.rows.append((req.req_id, int(kv) - int(n) + j,
                                   logits[cursor + j]))
             cursor += int(n)
-        return sampled, logits
+        return sampled
 
 
 def build(params=None, engine=BrumbyInferenceEngine, lanes=LANES, **kw):
@@ -294,7 +298,7 @@ def test_a_mismatched_length_flags_the_lane_and_leaves_the_state(rng,
     assert list(after[2][1:3]) == [6, 6]
     assert np.abs(after[0][:, 2] - before[0][:, 2]).max() > 0
     # the sampled step's own flag row says so too
-    sampled, _ = eng.sampled_step(
+    sampled = eng.sampled_step(
         np.asarray([a[-1], 9] + [0] * 16, np.int32),
         np.asarray([[1, 6, 0, 0, 0, 0], [1, 7, 1, 0, 0, 0]], np.int32),
         np.asarray([[1], [2]], np.int32), np.zeros((2,), np.float32))
@@ -365,7 +369,13 @@ def test_the_round_in_flight_equals_generate(rng):
 
 def test_one_step_whatever_the_batch(rng):
     before = monitor.get("serving.ragged_retraces") or 0
-    serve(prompts_of(rng, [3, 40, 17, 1, 22, 9]), 6)
+    prompts = prompts_of(rng, [3, 40, 17, 1, 22, 9])
+    _eng, _fe, got = serve(prompts, 6, engine=BrumbyInferenceEngine)
+    assert monitor.get("serving.ragged_retraces") == before + 1
+    # and the round (the head over the sampled rows) serves the tokens that
+    # the all-rows program's logits sample to on the host (`Recording`)
+    _eng, _fe, want = serve(prompts, 6)
+    assert [h.tokens for h in got] == [h.tokens for h in want]
     assert monitor.get("serving.ragged_retraces") == before + 1
 
 

@@ -23,6 +23,7 @@ from paddle_tpu.models import cohere2_moe as c2
 from paddle_tpu.models import deepseek_v3 as dsv3
 from paddle_tpu.serving import RequestStatus, ServingFrontend
 from test_deepseek_v3 import routed_experts
+from test_sampled_step import all_rows_round
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -91,9 +92,13 @@ class Recording(Cohere2MoeInferenceEngine):
         self.rows, self.slots_of = [], None
 
     def sampled_step(self, tokens, lanes, tables, temperature):
-        sampled, logits = super().sampled_step(tokens, lanes, tables,
-                                               temperature)
-        logits = np.asarray(logits)
+        # every packed row's logits come from the all-rows program, on the
+        # same step (a cache write is indexed by position: made twice, it
+        # is made once); the round itself must sample what they sample to
+        want, logits = all_rows_round(self, tokens, lanes, tables,
+                                      temperature)
+        sampled = super().sampled_step(tokens, lanes, tables, temperature)
+        np.testing.assert_array_equal(np.asarray(sampled), want)
         cursor = 0
         for lane, (n, kv) in enumerate(lanes[:, :2]):
             req = self.slots_of()[lane]
@@ -101,7 +106,7 @@ class Recording(Cohere2MoeInferenceEngine):
                 self.rows.append((req.req_id, int(kv) - int(n) + j,
                                   logits[cursor + j]))
             cursor += int(n)
-        return sampled, logits
+        return sampled
 
 
 BS, CHUNK, LANES, WIDTH = 8, 16, 4, 16           # block, chunk, lanes, table

@@ -25,6 +25,7 @@ from paddle_tpu.ops.pallas import grouped_matmul as gm
 from paddle_tpu.ops.pallas import paged_attention_mla as pm
 from paddle_tpu.ops.pallas.paged_attention import ragged_metadata
 from paddle_tpu.serving import RequestStatus, ServingFrontend
+from test_sampled_step import all_rows_round
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -67,9 +68,13 @@ class Recording(DeepseekV3InferenceEngine):
         self.rows, self.slots_of = [], None
 
     def sampled_step(self, tokens, lanes, tables, temperature):
-        sampled, logits = super().sampled_step(tokens, lanes, tables,
-                                               temperature)
-        logits = np.asarray(logits)
+        # every packed row's logits come from the all-rows program, on the
+        # same step (a cache write is indexed by position: made twice, it
+        # is made once); the round itself must sample what they sample to
+        want, logits = all_rows_round(self, tokens, lanes, tables,
+                                      temperature)
+        sampled = super().sampled_step(tokens, lanes, tables, temperature)
+        np.testing.assert_array_equal(np.asarray(sampled), want)
         cursor = 0
         for lane, (n, kv) in enumerate(lanes[:, :2]):
             req = self.slots_of()[lane]
@@ -77,7 +82,7 @@ class Recording(DeepseekV3InferenceEngine):
                 self.rows.append((req.req_id, int(kv) - int(n) + j,
                                   logits[cursor + j]))
             cursor += int(n)
-        return sampled, logits
+        return sampled
 
 
 def serve(params, prompts, new_tokens, num_blocks=4 * 8 + 1, engine=Recording):

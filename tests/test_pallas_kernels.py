@@ -695,9 +695,11 @@ def v5e_ragged_step(request, v5e_chip):
         rms_norm_eps, tie_word_embeddings = 1e-5, False
 
     # the step as the engine jits it: the stack, then the screen, the row
-    # gather and the sampler (`ops/sampling.with_tail`)
-    step = sampling.with_tail(
-        functools.partial(lr._ragged_fn, cfg=lr._StaticCfg(Cfg)))
+    # gather, the head over the sampled rows and the sampler
+    # (`ops/sampling.with_tail`)
+    step = sampling.with_tail(*(
+        functools.partial(fn, cfg=lr._StaticCfg(Cfg))
+        for fn in (lr._ragged_stack, lr._head)))
     ints = [arr((tokens,), jnp.int32), arr((lanes, len(sampling.LANE_COLS)),
                                            jnp.int32),
             arr((lanes, width), jnp.int32), arr((lanes,), jnp.float32),
@@ -739,12 +741,14 @@ def test_llama_ragged_step_compiles_for_v5e_without_a_pool_copy(
     text = step.compiled.as_text()
     assert text.count("paged_attention_ragged") and "tpu_custom_call" in text
     assert "while(" in text          # the program's size is O(1) in depth
-    # ONE program a round: tokens + flags, the logits and the pools come out
-    # of it, and nothing crosses to the host inside it
+    # ONE program a round: tokens + flags and the pools come out of it, no
+    # row-by-vocabulary array does (the head runs over the sampled rows),
+    # and nothing crosses to the host inside it
     outs = jax.tree.leaves(step.compiled.out_info)
-    assert [tuple(o.shape) for o in outs[:2]] == [
-        (2, step.lanes), (step.tokens, step.vocab)]
-    assert outs[0].dtype == jnp.int32 and len(outs) == 2 + len(pools)
+    assert tuple(outs[0].shape) == (2, step.lanes)
+    assert outs[0].dtype == jnp.int32 and len(outs) == 1 + len(pools)
+    assert f"f32[{step.tokens},{step.vocab}]" not in text
+    assert f"f32[{step.lanes},{step.vocab}]" in text
 
 
 def test_llama_ragged_step_holds_one_attention_kernel(v5e_ragged_step):
@@ -800,7 +804,9 @@ def test_cohere2_moe_step_compiles_for_v5e_at_the_cell_s_size(v5e_chip):
     counters = {"tokens": arr((4, 128), jnp.int32),
                 "touched": arr((4,), jnp.int32), "steps": arr((), jnp.int32),
                 "narrow_steps": arr((), jnp.int32)}
-    step = sampling.with_tail(functools.partial(cr._ragged_fn, cfg=cfg))
+    step = sampling.with_tail(
+        functools.partial(cr._ragged_stack, cfg=cfg, narrow=True),
+        functools.partial(cr._head, cfg=cfg))
     ints = [arr((tokens,), jnp.int32),
             arr((lanes, len(sampling.LANE_COLS)), jnp.int32),
             arr((lanes, 2 * width), jnp.int32), arr((lanes,), jnp.float32),
@@ -863,7 +869,8 @@ def test_brumby_step_compiles_for_v5e_at_the_cell_s_size(v5e_chip):
     assert s_shape == (6, 33, 8, 65, 128, 128) and z_shape == s_shape[:-1]
     state = (arr(s_shape, jnp.float32), arr(z_shape, jnp.float32),
              arr((slots,), jnp.int32), arr((), jnp.int32))
-    step = sampling.with_tail(functools.partial(br._ragged_fn, cfg=cfg))
+    step = sampling.with_tail(functools.partial(br._ragged_stack, cfg=cfg),
+                              functools.partial(br._head, cfg=cfg))
     ints = [arr((tokens,), jnp.int32),
             arr((lanes, len(sampling.LANE_COLS)), jnp.int32),
             arr((lanes, 1), jnp.int32), arr((lanes,), jnp.float32),
@@ -946,7 +953,9 @@ def test_deepseek_v3_step_keeps_its_live_prefix_switches_on_the_v5e(v5e_chip):
             arr((2, lanes), jnp.int32)]
 
     def compile_step():
-        step = sampling.with_tail(functools.partial(dr._ragged_fn, cfg=cfg))
+        step = sampling.with_tail(
+            functools.partial(dr._ragged_stack, cfg=cfg, narrow=True),
+            functools.partial(dr._head, cfg=cfg))
         return jax.jit(step, donate_argnums=(1, 2)).trace(
             params, pool, counters, *ints).lower(
                 lowering_platforms=("tpu",)).compile()

@@ -376,21 +376,34 @@ def test_a_fed_round_is_the_same_executable(kind, models):
 
 
 def test_a_token_is_fed_only_from_this_engine_s_last_step(models):
-    """Whoever steps the engine between two of the scheduler's rounds
-    (a caller's `generate`, another scheduler) takes `last_sampled`: the
-    scheduler sees it and settles before it launches."""
+    """Whoever runs a sampled step of the engine between two of the
+    scheduler's rounds (another scheduler) takes `last_sampled`: the
+    scheduler sees it and settles before it launches. The all-rows program
+    (a caller's `generate`, a probe) samples nothing and takes nothing."""
     reqs = requests("mlp", n=3)
     _, clean = serve(make_engine("mlp", models), reqs, settled=True)
+    ServingMetrics.reset_monitor()
+
+    def empty_step(eng):
+        zeros = np.zeros((LANES,), np.int32)
+        return (np.zeros((LANES + CHUNK,), np.int32), zeros, zeros,
+                np.zeros((LANES, eng.manager.table_width), np.int32))
+
+    def foreign_rows(fe, handles, step):
+        if step % 3 == 0:
+            eng = fe.scheduler.engine
+            eng.ragged_step(*empty_step(eng))
+
+    _, got = serve(make_engine("mlp", models), reqs, between=foreign_rows)
+    assert streams(got) == streams(clean)
+    assert monitor.get("serving.step.forced_settles") == 0
+    assert monitor.get("serving.step.all_rows_calls") > 2
     ServingMetrics.reset_monitor()
 
     def foreign_step(fe, handles, step):
         if step % 3 == 0:
             eng = fe.scheduler.engine
-            mgr = eng.manager
-            zeros = np.zeros((LANES,), np.int32)
-            eng.ragged_step(np.zeros((LANES + CHUNK,), np.int32), zeros,
-                            zeros, np.zeros((LANES, mgr.table_width),
-                                            np.int32))
+            eng.sampled_step(*sampling.step_args(*empty_step(eng))[:4])
 
     _, got = serve(make_engine("mlp", models), reqs, between=foreign_step)
     assert streams(got) == streams(clean)
@@ -551,11 +564,11 @@ def test_with_tail_feeds_a_lane_its_own_last_token():
     buffer holds; no negative token, the step it always was."""
     seen = {}
 
-    def logits_step(tokens, q_lens, kv_lens, tables):
+    def stack(tokens, q_lens, kv_lens, tables):
         seen["tokens"] = tokens
         return jnp.zeros((tokens.shape[0], 8), jnp.float32),
 
-    step = sampling.with_tail(logits_step)
+    step = sampling.with_tail(stack, lambda state, rows, lane: rows)
     lanes = sampling.pack_lanes([1, 0, 1, 2], [3, 0, 5, 4])
     fed = np.array([[7, 1, 2, 3], [1, 1, 1, 1]], np.int32)
     tokens = np.array([sampling.fed_token(2), sampling.fed_token(0), 4, 6,

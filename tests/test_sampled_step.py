@@ -1,14 +1,20 @@
 """The serving step ends in its own tail (`ops/sampling.with_tail`): the NaN
-screen, the gather of each lane's last row and the sampler are the end of
-every engine's ONE compiled step, and a scheduler round is that one program
-and one host fetch of `[2, B]` int32 (docs/SERVING.md "One program, one
-fetch"). Over the MLP engine, a tiny Llama and a tiny DeepSeek-V3:
+screen, the gather of each lane's last hidden row, the output head over
+those `B` rows and the sampler are the end of every engine's ONE compiled
+round, and a scheduler round is that one program and one host fetch of
+`[2, B]` int32: it makes no `[T, V]` array (docs/SERVING.md "One program,
+one fetch"). Every packed row's logits come from a second program over the
+same stack and head (`ops/sampling.all_rows`, `ragged_step`). Over the MLP
+engine, a tiny Llama and a tiny DeepSeek-V3:
 
-- the step's tokens are what `sample_tokens` draws from the same logits'
-  gathered rows, in a crafted mixed round and in every round of a served
-  trace with greedy, temperature and top-k lanes across a preemption;
+- the round's `sampled` is what the all-rows program's logits sample to on
+  the host (`sample_tokens` over each lane's last row, its band finite), in
+  a crafted mixed round and in every round of a served trace with greedy,
+  temperature and top-k lanes across a preemption;
 - `serving.step.programs` and `serving.step.fetches` rise by exactly one a
-  round and nothing retraces as the batch's composition changes;
+  round and nothing retraces as the batch's composition changes; the
+  all-rows program counts its own traces and calls, 0 where nobody probes;
+- the lowered round has no `[T, V]` array among its outputs;
 - a NaN in an EARLY row of one lane's chunk fails that lane and no other;
 - a token altered where it is now produced (inside the tail) is served.
 """
@@ -18,17 +24,22 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import jax
+
 from paddle_tpu.framework import monitor
 from paddle_tpu.inference import LlamaInferenceEngine
+from paddle_tpu.inference import deepseek_v3_runner as dr
+from paddle_tpu.inference import llama_runner as lr
 from paddle_tpu.inference.deepseek_v3_runner import DeepseekV3InferenceEngine
 from paddle_tpu.models import deepseek_v3 as dsv3
 from paddle_tpu.models import llama_tiny
 from paddle_tpu.ops import sampling
-from paddle_tpu.ops.sampling import pack_lanes, sample_tokens
+from paddle_tpu.ops.pallas.paged_attention import ragged_metadata
+from paddle_tpu.ops.sampling import pack_lanes, sample_tokens, step_args
 from paddle_tpu.resilience import faults
 from paddle_tpu.serving import (MLPLMEngine, RequestStatus, ServingFrontend,
                                 ServingMetrics)
-from paddle_tpu.serving.engine import _mlp_ragged
+from paddle_tpu.serving.engine import _mlp_head, _mlp_ragged_stack
 
 VOCAB = 64
 LANES, BLOCK, MAXB, CHUNK = 4, 4, 8, 8
@@ -80,8 +91,29 @@ class CountingMetrics(ServingMetrics):
         self.rounds += 1
 
 
+def all_rows_round(eng, tokens, lanes, tables, temperature):
+    """What a round samples, by the all-rows program and the host: every
+    packed row's logits `[T, V]` and the `[2, B]` that `step_tail`'s
+    contract makes of them (`sample_tokens` over each lane's last row;
+    whether its whole band is finite). A token fed on the device is read
+    out of the engine's `last_sampled` first."""
+    tokens, lanes = np.array(tokens, np.int32), np.asarray(lanes)
+    fed = np.flatnonzero(tokens < 0)
+    if fed.size:
+        tokens[fed] = np.asarray(eng.last_sampled)[0][-tokens[fed] - 1]
+    logits = np.asarray(eng.ragged_step(tokens, lanes[:, 0], lanes[:, 1],
+                                        tables))
+    picked = sample_tokens(logits[lanes[:, 2]], temperature, lanes[:, 3],
+                           lanes[:, 4], lanes[:, 5])
+    finite = [np.isfinite(logits[row - q + 1:row + 1]).all()
+              for q, row in lanes[:, [0, 2]]]
+    return np.stack([picked, finite]).astype(np.int32), logits
+
+
 class Recording:
-    """An engine, remembering what every sampled step took and gave."""
+    """An engine, remembering what every sampled step took and gave, beside
+    what the all-rows program makes of the same step (its cache writes are
+    indexed by position: made twice, they are made once)."""
 
     def __init__(self, inner):
         self._inner = inner
@@ -91,18 +123,13 @@ class Recording:
         return getattr(self._inner, name)
 
     def sampled_step(self, tokens, lanes, tables, temperature):
-        sampled, logits = self._inner.sampled_step(tokens, lanes, tables,
-                                                   temperature)
+        want, _logits = all_rows_round(self._inner, tokens, lanes, tables,
+                                       temperature)
+        sampled = self._inner.sampled_step(tokens, lanes, tables,
+                                           temperature)
         self.rounds.append((np.array(lanes), np.array(temperature),
-                            np.asarray(sampled), np.asarray(logits)))
-        return sampled, logits
-
-
-def host_tokens(logits, lanes, temperature):
-    """What the scheduler computed before the fold: the rows gathered, then
-    `sample_tokens` over them."""
-    return sample_tokens(logits[lanes[:, 2]], temperature, lanes[:, 3],
-                         lanes[:, 4], lanes[:, 5])
+                            np.asarray(sampled), want))
+        return sampled
 
 
 # ---- one crafted round, at the engine ---------------------------------------
@@ -135,20 +162,22 @@ def test_a_mixed_round_samples_what_sample_tokens_would(kind, mode, models):
                      else [0.0] * 4, np.float32)
     lanes = pack_lanes(q_lens, pre + q_lens, rows, top_k=[0, 5, 0, 0],
                        seeds=[1, 22, 0, 333], draw_idx=[6, 0, 0, 0])
-    sampled, logits = eng.sampled_step(tokens, lanes, tables(), temps)
-    picked, finite = np.asarray(sampled)
-    logits = np.asarray(logits)
-    assert picked.dtype == np.int32 and picked.shape == (LANES,)
-    np.testing.assert_array_equal(picked, host_tokens(logits, lanes, temps))
+    want, logits = all_rows_round(eng, tokens, lanes, tables(), temps)
+    picked, finite = sampled = np.asarray(
+        eng.sampled_step(tokens, lanes, tables(), temps))
+    assert sampled.dtype == np.int32 and sampled.shape == (2, LANES)
+    np.testing.assert_array_equal(sampled, want)
     assert finite.tolist() == [1, 1, 1, 1]
     # greedy lanes are the argmax of their own last row, whatever the others
     for lane in (0, 2):
         assert picked[lane] == logits[rows[lane]].argmax()
-    # and the logits-returning form is the same compiled program
-    before = monitor.get("serving.ragged_retraces")
+    # the all-rows form is another program: it leaves the round's counter
+    # where it was, and was traced once for this shape, whatever lanes hold
+    names = ("serving.ragged_retraces", "serving.logits_retraces")
+    assert [monitor.get(n) for n in names] == [1, 1]
     eng.ragged_step(tokens, np.zeros_like(q_lens), np.zeros_like(q_lens),
                     tables())
-    assert monitor.get("serving.ragged_retraces") == before
+    assert [monitor.get(n) for n in names] == [1, 1]
 
 
 # ---- every round of a served trace ------------------------------------------
@@ -180,9 +209,8 @@ def test_every_served_round_matches_sample_tokens_across_a_preemption(
     assert monitor.get("serving.preemptions") > 0
     assert [h.tokens for h in got] == [h.tokens for h in want]
     shapes = set()
-    for lanes, temps, sampled, logits in roomy.rounds + tight.rounds:
-        np.testing.assert_array_equal(sampled[0],
-                                      host_tokens(logits, lanes, temps))
+    for lanes, temps, sampled, want in roomy.rounds + tight.rounds:
+        np.testing.assert_array_equal(sampled, want)
         assert sampled[1].all()
         q = lanes[:, 0]
         shapes.add((int((q == 1).sum()) > 0, int((q > 1).sum()) > 0,
@@ -223,6 +251,9 @@ def test_a_round_is_one_program_and_one_fetch(kind, models):
         == monitor.get("serving.step.fetches")
     assert monitor.get("serving.ragged_retraces") == 0
     assert monitor.get("serving.sample_retraces") == 0
+    # nobody probed: the all-rows program was neither traced nor called
+    assert hook.summary()["serving.step.all_rows_calls"] == 0 \
+        == hook.summary()["serving.logits_retraces"]
     assert all(h.status is RequestStatus.FINISHED for h in handles)
 
 
@@ -247,53 +278,85 @@ def test_a_speculative_round_still_counts_three_programs_and_two_fetches():
 
 # ---- the screen --------------------------------------------------------------
 def test_the_tail_convicts_the_lane_whose_band_holds_the_nan():
-    logits = np.zeros((12, VOCAB), np.float32)
-    logits[np.arange(12), np.arange(12)] = 1.0         # row r's argmax is r
+    hidden = np.zeros((12, VOCAB), np.float32)
+    hidden[np.arange(12), np.arange(12)] = 1.0         # row r's argmax is r
     q_lens = np.array([1, 5, 0, 3], np.int32)
     lanes = pack_lanes(q_lens, q_lens + 2)
     assert lanes[:, 2].tolist() == [0, 5, 5, 8]
+
+    def tail(hidden, head=lambda state, rows, lane: rows):
+        # the head sees the four sampled rows: the screen reads the bands
+        step = sampling.with_tail(
+            lambda tokens, q, kv, tables: (jnp.asarray(hidden),), head)
+        return np.asarray(step(*sampling.call_arrays(
+            np.zeros((12,), np.int32), lanes, np.zeros((4, 2)),
+            np.zeros((4,))))[0])
+
     for row, lane in ((0, 0), (1, 1), (3, 1), (5, 1), (6, 3), (8, 3)):
-        bad = logits.copy()
+        bad = hidden.copy()
         bad[row, 7] = np.nan if row % 2 else np.inf
-        picked, finite = np.asarray(sampling.step_tail(
-            jnp.asarray(bad), jnp.asarray(lanes), jnp.zeros((4,))))
+        picked, finite = tail(bad)
         assert finite.tolist() == [int(i != lane) for i in range(4)], row
         ok = [i for i in (0, 1, 3) if i != lane]
         assert picked[ok].tolist() == lanes[ok, 2].tolist()
     # rows past the packed tokens (guard slots) convict nobody
-    bad = logits.copy()
+    bad = hidden.copy()
     bad[9:] = np.nan
-    assert np.asarray(sampling.step_tail(
-        jnp.asarray(bad), jnp.asarray(lanes), jnp.zeros((4,))))[1].all()
+    assert tail(bad)[1].all()
+    # a head that makes a lane's sampled row not finite convicts that lane;
+    # the empty lane 2, whose row is lane 1's, reads finite all the same
+    picked, finite = tail(hidden, lambda state, rows, lane: jnp.where(
+        ((lane == 1) | (lane == 2))[:, None], jnp.inf, rows))
+    assert finite.tolist() == [1, 0, 1, 1]
+    assert picked[[0, 3]].tolist() == [0, 8]
 
 
 POISON = VOCAB - 1
 
 
-def poisoned_engine():
-    """An MLP engine whose program turns the logits row of every POISON
-    token to NaN: planted where the step computes, before its tail."""
-    import jax
+def halves(kind, eng):
+    """An engine's `(stack, head, donated arguments)`: what its `_ragged`
+    and its `_logits` were jitted from."""
+    if kind == "mlp":
+        return (functools.partial(_mlp_ragged_stack, block_size=BLOCK),
+                _mlp_head, (1,))
+    if kind == "llama":
+        cfg = lr._StaticCfg(eng.config)
+        return (functools.partial(lr._ragged_stack, cfg=cfg),
+                functools.partial(lr._head, cfg=cfg), (1,))
+    return (functools.partial(dr._ragged_stack, cfg=eng.config, narrow=True),
+            functools.partial(dr._head, cfg=eng.config), (1, 2))
 
-    eng = MLPLMEngine(vocab_size=VOCAB, hidden=16, max_batch_size=LANES,
-                      num_blocks=48, block_size=BLOCK,
-                      max_blocks_per_seq=MAXB)
-    body = functools.partial(_mlp_ragged, block_size=BLOCK)
 
-    def planted(params, pools, tokens, q_lens, kv_lens, tables):
-        logits, pools = body(params, pools, tokens, q_lens, kv_lens, tables)
-        return jnp.where((tokens == POISON)[:, None], jnp.nan, logits), pools
+def poisoned_engine(kind="mlp", models=None):
+    """An engine whose programs turn the final hidden row of every POISON
+    token of a chunk to NaN: planted where the stack computes, before the
+    tail. (Of a chunk: a decode lane may sample POISON itself.)"""
+    eng = make_engine(kind, models)
+    stack, head, donated = halves(kind, eng)
 
-    eng._ragged = jax.jit(sampling.with_tail(planted), donate_argnums=(1,))
+    def planted(*args):
+        tokens, q_lens, kv_lens = args[-4:-1]
+        hidden, *state = stack(*args)
+        lane, _pos = ragged_metadata(q_lens, kv_lens, tokens.shape[0])
+        bad = (tokens == POISON) & (q_lens[lane] > 1)
+        return jnp.where(bad[:, None], jnp.nan, hidden), *state
+
+    eng._ragged = jax.jit(sampling.with_tail(planted, head),
+                          donate_argnums=donated)
+    eng._logits = jax.jit(sampling.all_rows(planted, head),
+                          donate_argnums=donated)
     return eng
 
 
-def test_a_nan_in_an_early_row_of_a_chunk_fails_that_lane_only():
+@pytest.mark.parametrize("kind", KINDS)
+def test_a_nan_in_an_early_row_of_a_chunk_fails_that_lane_only(kind, models):
     rng = np.random.default_rng(2)
     prompts = [rng.integers(1, POISON, n).tolist() for n in (6, 7, 5, 6)]
-    clean = [h.tokens for h in _serve_prompts(poisoned_engine(), prompts)]
+    clean = [h.tokens
+             for h in _serve_prompts(poisoned_engine(kind, models), prompts)]
     prompts[2][1] = POISON                 # the chunk's second row of five
-    handles = _serve_prompts(poisoned_engine(), prompts)
+    handles = _serve_prompts(poisoned_engine(kind, models), prompts)
     assert handles[2].status is RequestStatus.FAILED
     assert handles[2].finish_reason == "nan_logits"
     assert handles[2].tokens == []
@@ -301,6 +364,8 @@ def test_a_nan_in_an_early_row_of_a_chunk_fails_that_lane_only():
         assert handles[i].status is RequestStatus.FINISHED
         assert handles[i].tokens == clean[i]
     assert monitor.get("serving.isolated_faults.decode") == 1
+    # the round's own flag convicted the lane: no probe replayed it
+    assert monitor.get("serving.step.all_rows_calls") == 0
 
 
 def _serve_prompts(eng, prompts, new_tokens=4):
@@ -349,7 +414,7 @@ def test_the_decode_flag_and_the_sample_fault_fire_at_the_one_round():
 def test_a_token_altered_inside_the_tail_is_served(kind, models, monkeypatch):
     """The served token is the one the step's tail produces: shift it there
     and the served stream leaves `generate`'s, which samples on the host
-    from the same program's logits."""
+    from the all-rows program's logits."""
     prompt = np.random.default_rng(4).integers(1, VOCAB, 9)
 
     def served():
@@ -371,3 +436,56 @@ def test_a_token_altered_inside_the_tail_is_served(kind, models, monkeypatch):
     monkeypatch.setattr(sampling, "step_tail", off_by_one)
     got = served()
     assert got != want and got[0] == (want[0] + 1) % VOCAB
+
+
+# ---- the two programs, lowered and counted ----------------------------------
+@pytest.mark.parametrize("kind", KINDS)
+def test_the_lowered_round_returns_no_row_by_vocabulary_array(kind, models):
+    """Ahead of any run: the round's outputs are `sampled [2, B]` and the
+    engine's state; `[T, V]` float32 logits leave the all-rows program
+    alone."""
+    eng = make_engine(kind, models)
+    T = LANES + CHUNK
+    lead = (eng.params, eng.pools) if kind != "deepseek_v3" else (
+        eng.params, eng.pool, eng.counters)
+    q = np.array([1, CHUNK, 0, 1], np.int32)
+    tables = np.zeros((LANES, MAXB), np.int32)
+    arrays = step_args(np.zeros((T,), np.int32), q, q + 3, tables)
+    outs = jax.tree.leaves(eng._ragged.lower(*lead, *arrays).out_info)
+    assert (outs[0].shape, outs[0].dtype) == ((2, LANES), jnp.int32)
+    assert all(o.shape != (T, VOCAB) for o in outs)
+    outs = jax.tree.leaves(eng._logits.lower(
+        *lead, *arrays[:1], q, q + 3, tables).out_info)
+    assert (outs[0].shape, outs[0].dtype) == ((T, VOCAB), jnp.float32)
+
+
+def test_the_audit_still_finds_the_sampler():
+    from paddle_tpu.analysis import hlo_audit
+
+    fn, args = hlo_audit.EXECUTABLES["sampler"]()
+    assert fn.lower(*args).out_info.shape == (4, 1)
+
+
+@pytest.mark.parametrize("kind", KINDS)
+def test_a_probe_counts_as_no_retrace_of_the_round(kind, models):
+    """`ragged_step` after served rounds is the all-rows program's first
+    trace and not the round's: `serving.ragged_retraces` stays, and a run
+    that never probes reads 0 for both of the all-rows counters."""
+    eng = make_engine(kind, models)
+    fe = ServingFrontend(eng, prefill_chunk_tokens=CHUNK)
+    fe.submit([3, 1, 4, 1, 5, 9, 2, 6], max_new_tokens=4)
+    fe.run_until_idle()
+    names = ("serving.ragged_retraces", "serving.logits_retraces",
+             "serving.step.all_rows_calls")
+    assert [monitor.get(n) for n in names] == [1, 0, 0]
+    assert monitor.get("serving.decode_retraces") == 1
+    zeros = np.zeros((LANES,), np.int32)
+    for _ in range(3):
+        eng.ragged_step(np.zeros((LANES + CHUNK,), np.int32), zeros, zeros,
+                        np.zeros((LANES, MAXB), np.int32))
+    assert [monitor.get(n) for n in names] == [1, 1, 3]
+    assert monitor.get("serving.decode_retraces") == 1
+    # and the round is still the executable it was
+    fe.submit([2, 7, 1, 8], max_new_tokens=3)
+    fe.run_until_idle()
+    assert [monitor.get(n) for n in names] == [1, 1, 3]

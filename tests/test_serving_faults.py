@@ -326,7 +326,7 @@ class TestFaultIsolation:
             def __getattr__(self, name):
                 return getattr(inner, name)
 
-            def sampled_step(self, tokens, lanes, tables, temperature):
+            def _refuse_the_victim(self, tables):
                 if self.victim is not None:
                     try:
                         vrow = inner.manager.block_table_array(
@@ -337,8 +337,16 @@ class TestFaultIsolation:
                             int(r[0]) == int(vrow[0])
                             for r in np.asarray(tables)):
                         raise RuntimeError("victim lane poisons the step")
+
+            def sampled_step(self, tokens, lanes, tables, temperature):
+                self._refuse_the_victim(tables)
                 return inner.sampled_step(tokens, lanes, tables,
                                           temperature)
+
+            def ragged_step(self, tokens, q_lens, kv_lens, tables):
+                # the probe's way in: the all-rows program
+                self._refuse_the_victim(tables)
+                return inner.ragged_step(tokens, q_lens, kv_lens, tables)
 
         eng = VictimEngine()
         fe = ServingFrontend(eng)

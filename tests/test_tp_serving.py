@@ -332,9 +332,8 @@ class TestShardedSurfaces:
         assert s["tp"] == 2 and s["overlap"] and s["tiles"] == 3
         assert s["mesh"]["dim_names"] == ["dp", "tp"]
         fn, lead = sh.cost_card_args("ragged")
-        sampled, logits, _pools = fn(*lead, *step_args(*_ragged_batch(0)))
+        sampled, _pools = fn(*lead, *step_args(*_ragged_batch(0)))
         assert np.asarray(sampled).shape == (2, 4)
-        assert np.asarray(logits).shape[-1] == 64
         with pytest.raises(KeyError):
             sh.cost_card_args("prefill")
 

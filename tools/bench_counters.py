@@ -27,7 +27,7 @@ import runpy
 import sys
 
 _DEFAULT = ("serving.step.", "serving.ragged_retraces",
-            "serving.preemptions", "serving.step_faults",
+            "serving.logits_retraces", "serving.preemptions", "serving.step_faults",
             "serving.engine_restarts")
 
 
