@@ -1,0 +1,457 @@
+"""Serving engine for the GLM-MoE-DSA architecture (`models/glm_moe_dsa.py`):
+an `EngineCore` with TWO paged pools on ONE block table, and a selection that
+travels from a layer with an indexer to the layers that share it.
+
+- The pools are one donated tuple `(latent, index)`: `latent [L, NB, BS, row]`
+  holds one latent row a token a layer (`deepseek_v3_runner`'s pool: `row` is
+  the latent width rounded up to whole 128-lane tiles), `index [F, NB, BS,
+  index_head_dim]` one indexer key a token and `full` layer. A block id names
+  the same `BS` tokens in both, so the cache manager knows one group: a block
+  is taken, freed, shared and copied in both pools at once.
+- A `full` layer scores each live row against its lane's live pages of the
+  index pool (`ops/pallas/dsa.dsa_index_scores`) and keeps the `index_topk`
+  best positions (`lax.top_k`: the scope `llama.dsa_topk` says what it
+  costs; a tile of rows that all lie in the first quarter of the table's
+  span is sorted over that quarter, `NEAR_SHARE`); the selection `(idx [T, K], n [T])` is carried, inside the one
+  compiled step, to the `shared` layers after it.
+- EVERY row attends over its selected rows, gathered: `sparse_rows` reads `K`
+  latent rows through the block table (`row_ids`, worked out once a
+  selection, not once a layer), `mla_sparse_attention` takes their softmax
+  whole. So attention's bytes and FLOPs follow `K` and not the
+  context, for a decode lane and for a row of a prefill chunk alike; a chunk
+  could instead walk its pages under a mask (fewer bytes, more FLOPs: about
+  even at the benchmark's contexts, PERF.md section 7), but one path serves a
+  decode lane, a chunk, a verify window and a resumed lane with one kernel.
+- The rows are walked a tile of at most `lanes` at a time (`_live_tiles`), and
+  only the tiles that hold a live row: a round of decode lanes alone scores,
+  selects and gathers for its lanes, not for the chunk's guard rows.
+- Expert load and selection load are counted inside the step, on the
+  device, in donated counters; `expert_load()` / `selection_load()` read them.
+- `attention_witness` is a THIRD program over the same stack, compiled when
+  first called and never by a served round: one decode row a lane, and per
+  layer what that row's attention was given (the selection) and what the
+  attention sub-block made of it. An operator or a check replays a cached
+  position through it; the served step carries nothing for it.
+
+The radix prefix cache works over this engine (`copy_kv_block` copies a block
+in both pools: an indexer key is a function of its token's prefix as a latent
+row is) and so does speculative decoding (`verify_step` is a case of the
+stack: every row of a window selects for itself). The engine transforms
+(`quantize_engine`, `shard_engine`, `attach_adapters`) refuse this engine by
+its name; KV migration is refused here, by family.
+"""
+from __future__ import annotations
+
+import functools
+import time
+from typing import Dict
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from ..framework import monitor
+from ..models import deepseek_v3 as dsv3
+from ..models import glm_moe_dsa as glm
+from ..observability import compile_trace
+from ..ops import sampling
+from ..ops.pallas import dsa
+from ..ops.pallas.paged_attention import ragged_metadata
+from . import kv_migrate, live_prefix
+from .cache import BlockCacheManager
+from .generate import generate
+
+__all__ = ["GlmMoeDsaInferenceEngine"]
+
+FAMILY = "glm_moe_dsa"
+POOLS = ("latent", "index")
+# the selection's sort takes its time from the positions it is given, not
+# from the live ones: rows in the first 1 / NEAR_SHARE of the table's span
+# sort over that part (most of a prefill, whose rows pass every depth)
+NEAR_SHARE = 4
+
+
+def _tile_rows(t: int, lanes: int) -> int:
+    """Rows a tile of the context-owning loops: the largest divisor of the
+    packed buffer's `t` slots that is at most the lane count."""
+    return max(d for d in range(1, min(t, lanes) + 1) if t % d == 0)
+
+
+def _live_tiles(n_live, rows: int, t: int, fn, outs):
+    """`fn(r0) -> tuple of [rows, ...]` over the tiles `[r0, r0 + rows)` of
+    a packed buffer of `t` slots that hold a live row (the first `n_live`
+    slots are the live ones), written into `outs` (zeros `[t, ...]`): a
+    loop whose trip count the device takes from the step's own `q_lens`."""
+    def body(i, outs):
+        r0 = i * jnp.int32(rows)
+        return tuple(jax.lax.dynamic_update_slice_in_dim(o, g, r0, 0)
+                     for o, g in zip(outs, fn(r0)))
+
+    return jax.lax.fori_loop(0, (n_live + rows - 1) // rows, body, outs)
+
+
+def _ragged_stack(params, pools, counters, tokens, q_lens, kv_lens, tables,
+                  *, cfg: glm.GlmMoeDsaConfig, narrow: bool = False,
+                  witness: list = None):
+    """Packed tokens `[T]` + per-lane `(q_len, kv_len)` through the decoder:
+    `(hidden [T, H] before the final norm, pools, counters)`, the `stack` of
+    `ops/sampling.with_tail`. `narrow`: a step whose live rows number at
+    most its lanes runs the layers' row-wise segments over that prefix of
+    the packed buffer (`live_prefix.rowwise`; the choice is made on the
+    device, from `q_lens`). `witness`: a list that takes, a layer, `(idx
+    [B, K], n [B], out [B, H] float32)` at each lane's last packed row: the
+    selection the layer's attention was given there and the attention
+    sub-block's output (`_witness_fn`); a served step is traced without."""
+    t = tokens.shape[0]
+    latent, index = pools
+    nb, bs, row = latent.shape[1:]
+    rank, topk = cfg.kv_lora_rank, cfg.index_topk
+    kv_lens = kv_lens.astype(jnp.int32)
+    tables = tables.astype(jnp.int32)
+    tok_lane, tok_pos = ragged_metadata(q_lens, kv_lens, t)
+    live = tok_pos >= 0
+    lanes = q_lens.shape[0]
+    n_live = jnp.sum(q_lens.astype(jnp.int32))
+    rowwise = live_prefix.rowwise(n_live, lanes if narrow else None, t)
+    tile = _tile_rows(t, lanes)
+    span = tables.shape[1] * bs
+    k_sel = min(topk, span)
+    near = span // NEAR_SHARE
+    pos = jnp.maximum(tok_pos, 0)
+    # a guard slot's rows go to a block past the pools: the scatter drops it
+    blk = jnp.where(live, tables[tok_lane, pos // bs], jnp.int32(nb))
+    off = pos % bs
+    with jax.named_scope("llama.rope"):
+        cos = jnp.take(params["rope_cos"], pos, axis=0)
+        sin = jnp.take(params["rope_sin"], pos, axis=0)
+    with jax.named_scope("llama.embed"):
+        x = jnp.take(params["model.embed_tokens.weight"], tokens, axis=0)
+
+    def cut(a, r0):
+        return jax.lax.dynamic_slice_in_dim(a, r0, tile, 0)
+
+    def index_layer(n):
+        def pick(q_i, k_i, w):
+            nonlocal index
+            with jax.named_scope("llama.dsa_index_write"):
+                index = index.at[n, blk, off].set(k_i.astype(index.dtype),
+                                                  mode="drop")
+            with jax.named_scope("llama.dsa_index_scores"):
+                kernel = dsa.dsa_index_scores if dsa.index_supported(
+                    q_i.shape, index.shape, index.dtype, tables.shape[1]) \
+                    else dsa.dsa_index_scores_ref
+                scores = kernel(q_i, w, index, n, tables, kv_lens, tok_lane,
+                                tok_pos)
+            with jax.named_scope("llama.dsa_topk"):
+                def select_tile(r0):
+                    at = cut(tok_pos, r0)
+
+                    def over(positions):
+                        return lambda: glm.select(
+                            dsa.score_rows(scores, r0, tile, positions), at,
+                            k_sel)
+
+                    # a tile whose rows all lie in the table's first
+                    # quarter is sorted over that quarter alone: what lies
+                    # past a row's own position is no candidate
+                    if near < k_sel:
+                        return over(span)()
+                    return jax.lax.cond(jnp.max(at) < near, over(near),
+                                        over(span))
+
+                return _live_tiles(
+                    n_live, tile, t, select_tile,
+                    (jnp.zeros((t, k_sel), jnp.int32),
+                     jnp.zeros((t,), jnp.int32)))
+        return pick
+
+    ids_of = {}     # a selection's `(idx, row ids)`, by `id(idx)`
+
+    def attend_layer(i):
+        def attend(q_abs, rows, selection):
+            nonlocal latent
+            idx, n = selection
+            # where the selected rows lie is the same in every layer: the
+            # first layer handed a selection works it out beside its own
+            # gather, the layers that share it read it
+            known = ids_of.get(id(idx))
+            with jax.named_scope("llama.kv_write"):
+                rows = jnp.pad(rows.astype(latent.dtype),
+                               ((0, 0), (0, row - rows.shape[-1])))
+                latent = latent.at[i, blk, off].set(rows, mode="drop")
+            with jax.named_scope("llama.attn_sparse"):
+                kernel = dsa.mla_sparse_attention if dsa.sparse_supported(
+                    (tile,) + q_abs.shape[1:], (tile, k_sel, row),
+                    latent.dtype, rank) else dsa.mla_sparse_attention_ref
+
+                def rows_of(r0):
+                    ids = cut(known[1], r0) if known else dsa.row_ids(
+                        tables, cut(tok_lane, r0), cut(idx, r0), bs)
+                    out = kernel(cut(q_abs, r0),
+                                 dsa.sparse_rows(latent, i, ids), cut(n, r0),
+                                 rank, cfg.qk_head_dim ** -0.5)
+                    return (out,) if known else (out, ids)
+
+                outs = (jnp.zeros(q_abs.shape[:2] + (rank,), q_abs.dtype),)
+                if not known:
+                    outs += (jnp.zeros(idx.shape, jnp.int32),)
+                o_lat, *ids = _live_tiles(n_live, tile, t, rows_of, outs)
+                if ids:
+                    ids_of[id(idx)] = (idx, ids[0])
+            if witness is not None:
+                at = jnp.maximum(jnp.cumsum(q_lens.astype(jnp.int32)) - 1, 0)
+                witness.append((idx[at], n[at], dsv3.mla_output(
+                    o_lat[at], dsv3.layer_params(params, i), cfg,
+                    jnp.float32)))
+            return o_lat
+        return attend
+
+    sizes, carried = [], None
+    picked = candidates = jnp.zeros((), jnp.int32)
+    full = {layer: n for n, layer in enumerate(cfg.full_layers)}
+    for i, kind in enumerate(cfg.indexer_types):
+        x, n, carried = glm.decoder_layer(
+            x, dsv3.layer_params(params, i), cfg, kind, cos, sin,
+            index_layer(full.get(i)), attend_layer(i), carried, live, rowwise)
+        sizes.append(jnp.zeros((cfg.n_routed_experts,), jnp.int32)
+                     if n is None else n)
+        if kind == glm.FULL:
+            picked = picked + jnp.sum(carried[1])
+            candidates = candidates + jnp.sum(tok_pos + 1)
+    sizes = jnp.stack(sizes)                                     # [L, E]
+    first, count = cfg.held
+    counters = {
+        "tokens": counters["tokens"] + sizes,
+        "touched": counters["touched"] + jnp.sum(
+            sizes[:, first:first + count] > 0, axis=1, dtype=jnp.int32),
+        "steps": counters["steps"] + 1,
+        "narrow_steps": counters["narrow_steps"] + (
+            (n_live <= lanes).astype(jnp.int32) if narrow else 0),
+        # float32: a step's candidates pass 2^31 in two hundred steps
+        "dsa_selected": counters["dsa_selected"] + picked.astype(jnp.float32),
+        "dsa_candidates": counters["dsa_candidates"]
+        + candidates.astype(jnp.float32),
+    }
+    return x, (latent, index), counters
+
+
+def _head(state, x, lane, *, cfg):
+    """The `head` of `ops/sampling.with_tail`: the final norm and the output
+    matmul over the rows it is given; `state[0]` is the params."""
+    return glm.head(x, state[0], cfg)
+
+
+def _verify_fn(params, pools, counters, tokens, ctx_lens, tables, *, cfg):
+    """Speculative verify as a case of the ragged step: every lane a fixed
+    window of S tokens, every row its own selection; logits fold back to
+    `[B, S, V]`."""
+    monitor.inc("serving.verify_retraces")        # trace-time only
+    b, s = tokens.shape
+    x, pools, counters = _ragged_stack(
+        params, pools, counters, tokens.reshape(b * s),
+        jnp.full((b,), s, jnp.int32), ctx_lens, tables, cfg=cfg)
+    return glm.head(x, params, cfg).reshape(b, s, -1), pools, counters
+
+
+def _witness_fn(params, pools, counters, tokens, kv_lens, tables, *, cfg):
+    """One decode row a lane (`kv_lens` 0: an idle lane) through the stack
+    with its witness: `({"idx" [L, B, K], "n" [L, B], "out" [L, B, H]},
+    pools, counters)`."""
+    seen = []
+    live = kv_lens > 0
+    # the packed buffer holds the live lanes' rows first, in lane order
+    packed = tokens[jnp.argsort(~live, stable=True)]
+    _, pools, counters = _ragged_stack(
+        params, pools, counters, packed, live.astype(jnp.int32), kv_lens,
+        tables, cfg=cfg, witness=seen)
+    idx, n, out = (jnp.stack(a) for a in zip(*seen))
+    return {"idx": idx, "n": n, "out": out}, pools, counters
+
+
+class GlmMoeDsaInferenceEngine:
+    """`EngineCore` over `GlmMoeDsaForCausalLM` with a paged latent cache and
+    a paged index cache on one block table. Serves in the dtype the model's
+    weights have."""
+
+    def __init__(self, model: glm.GlmMoeDsaForCausalLM,
+                 max_batch_size: int = 8, num_blocks: int = 256,
+                 block_size: int = 16, max_blocks_per_seq: int = 16):
+        began = time.time()     # `engine.build_s`: this line to the last
+        cfg = model.config
+        self.config = cfg
+        self.block_size = block_size
+        self.max_batch_size = max_batch_size
+        self.manager = BlockCacheManager(num_blocks, block_size,
+                                         max_blocks_per_seq, name=POOLS[0])
+        cos, sin = dsv3.rope_tables(cfg, max_blocks_per_seq * block_size)
+        # the model's own arrays, by reference, beside the rope tables
+        self.params: Dict[str, jax.Array] = dict(
+            model.weight_tree(), rope_cos=cos, rope_sin=sin)
+        cdtype = self.params["model.embed_tokens.weight"].dtype
+        L, e = cfg.num_hidden_layers, cfg.n_routed_experts
+        self.row_width = -(-cfg.latent_dim // 128) * 128
+        self.pools = (
+            jnp.zeros((L, num_blocks, block_size, self.row_width), cdtype),
+            jnp.zeros((len(cfg.full_layers), num_blocks, block_size,
+                       cfg.index_head_dim), cdtype))
+        self.counters = {"tokens": jnp.zeros((L, e), jnp.int32),
+                         "touched": jnp.zeros((L,), jnp.int32),
+                         "steps": jnp.zeros((), jnp.int32),
+                         "narrow_steps": jnp.zeros((), jnp.int32),
+                         "dsa_selected": jnp.zeros((), jnp.float32),
+                         "dsa_candidates": jnp.zeros((), jnp.float32)}
+        itemsize = jnp.dtype(cdtype).itemsize
+        self._pool_bytes_per_token = {
+            POOLS[0]: L * self.row_width * itemsize,
+            POOLS[1]: len(cfg.full_layers) * cfg.index_head_dim * itemsize}
+        self.manager.set_kv_geometry(
+            block_size * sum(self._pool_bytes_per_token.values()), 16)
+
+        stack = functools.partial(_ragged_stack, cfg=cfg, narrow=True)
+        head = functools.partial(_head, cfg=cfg)
+        verify = functools.partial(_verify_fn, cfg=cfg)
+        verify.__name__ = _verify_fn.__name__      # the XLA module's name
+        # the screen, the row gather, the head over the sampled rows and
+        # the sampler end the round's one program (`ops/sampling.with_tail`);
+        # `_logits` is the same stack with the head over every row,
+        # compiled when `ragged_step` first calls it
+        self._ragged = jax.jit(sampling.with_tail(stack, head),
+                               donate_argnums=(1, 2))
+        self._logits = jax.jit(sampling.all_rows(stack, head),
+                               donate_argnums=(1, 2))
+        self.last_sampled = None    # the last step's `sampled`, on device
+        self._verify = jax.jit(verify, donate_argnums=(1, 2))
+        self._witness = jax.jit(functools.partial(_witness_fn, cfg=cfg),
+                                donate_argnums=(1, 2))
+        # COW copy (prefix caching): one block of BOTH pools, every layer,
+        # donated; src/dst trace as scalars, so COWs never recompile
+        self._copy_block = jax.jit(
+            lambda pools, s, d: tuple(p.at[:, d].set(p[:, s]) for p in pools),
+            donate_argnums=(0,))
+        compile_trace.stamp("engine.build", began)
+
+    # ---- the EngineCore dispatch surface ----
+    def sampled_step(self, tokens: np.ndarray, lanes: np.ndarray,
+                     block_tables: np.ndarray, temperature: np.ndarray):
+        """ONE fixed-shape step over a packed ragged batch, sampled (see
+        `EngineCore.sampled_step`): `sampled [2, B] int32`, on the device."""
+        self.last_sampled = self._run(
+            self._ragged, *sampling.call_arrays(
+                tokens, lanes, block_tables, temperature, self.last_sampled))
+        return self.last_sampled
+
+    def _run(self, fn, *arrays):
+        """One of the step programs over this engine's state, which it
+        replaces; what the program returns ahead of it."""
+        out, self.pools, self.counters = fn(self.params, self.pools,
+                                            self.counters, *arrays)
+        return out
+
+    ragged_step = sampling.ragged_step
+
+    def verify_step(self, tokens: np.ndarray, context_lens: np.ndarray,
+                    block_tables: np.ndarray):
+        """Multi-token verify (see `EngineCore.verify_step`): `[B, S, V]`."""
+        return self._run(
+            self._verify, np.asarray(tokens, np.int32),
+            np.asarray(context_lens, np.int32),
+            np.asarray(block_tables, np.int32))
+
+    generate = generate
+
+    # ---- hooks the scheduler and the cache manager look for ----
+    def copy_kv_block(self, src: int, dst: int) -> None:
+        """Copy one physical block, all layers, in both pools (the manager's
+        COW hook when prefix caching is on)."""
+        self.pools = self._copy_block(self.pools, np.int32(src),
+                                      np.int32(dst))
+
+    def kv_bytes_per_token(self, pool: str = None) -> float:
+        """HBM bytes one cached token costs: in `pool` (`"latent"`: one row
+        a layer as stored; `"index"`: one key a `full` layer), or in both."""
+        if pool is None:
+            return float(sum(self._pool_bytes_per_token.values()))
+        return float(self._pool_bytes_per_token[pool])
+
+    def quant_info(self) -> dict:
+        """What `serving.quant.*` and `serving.kv_bytes_per_token[.<pool>]`
+        publish."""
+        return {"wbits": 16, "kv_bits": 16,
+                "kv_bytes_per_token": self.kv_bytes_per_token(),
+                "kv_bytes_per_token_by_group": dict(
+                    self._pool_bytes_per_token)}
+
+    def cost_card_args(self, phase: str):
+        fn = {"decode": self._ragged, "ragged": self._ragged,
+              "verify": self._verify, "witness": self._witness}[phase]
+        return fn, (self.params, self.pools, self.counters)
+
+    def extract_kv_blocks(self, seq_id: int):
+        raise kv_migrate.KVMigrationError(
+            f"{FAMILY}: a block of two pools (latent rows and indexer keys) "
+            "has no migration payload yet")
+
+    def inject_kv_blocks(self, seq_id: int, payload) -> None:
+        raise kv_migrate.KVMigrationError(
+            f"{FAMILY}: a block of two pools (latent rows and indexer keys) "
+            "has no migration payload yet")
+
+    # ---- the device-side counters ----
+    def expert_load(self) -> dict:
+        """The counters the step keeps on the device, fetched now: `tokens
+        [L, E]` routed to each of the ROUTER's experts since the engine was
+        built (held and absent alike), `touched [L]` HELD experts with at
+        least one token summed over steps, `steps`, `narrow_steps`.
+        Publishes what `cohere2_moe_runner.expert_load` does."""
+        c = jax.device_get(self.counters)
+        tokens = np.asarray(c["tokens"], np.int64)
+        first, count = self.config.held
+        mine = tokens[:, first:first + count]
+        monitor.set_value("serving.moe.expert_tokens", int(mine.sum()))
+        if tokens.sum():
+            monitor.set_gauge("serving.moe.held_assignment_share",
+                              round(float(mine.sum() / tokens.sum()), 4))
+        if mine.sum():
+            moe = mine[self.config.first_k_dense_replace:]
+            monitor.set_gauge("serving.moe.load_max_over_mean",
+                              round(float(moe.max() / moe.mean()), 3))
+        steps, narrow = int(c["steps"]), int(c["narrow_steps"])
+        if steps:
+            monitor.set_gauge("serving.step.live_prefix_share",
+                              round(narrow / steps, 4))
+        return {"tokens": tokens, "touched": np.asarray(c["touched"], np.int64),
+                "steps": steps, "narrow_steps": narrow,
+                "held": (first, count)}
+
+    def attention_witness(self, tokens: np.ndarray, context_lens: np.ndarray,
+                          block_tables: np.ndarray) -> dict:
+        """What each layer's attention does at ONE decode row a lane: lane b
+        feeds `tokens[b]` at position `context_lens[b] - 1` of the sequence
+        `block_tables[b]` names (0: an idle lane), exactly as a served decode
+        round would. Per layer `idx [L, B, K]` of which the first `n [L, B]`
+        count, the positions the row's attention was given, and `out [L, B,
+        H]` float32, the attention sub-block's output there (before the
+        residual). A position already cached is REPLAYED: its rows are
+        written again with what they held (a row is a function of its
+        prefix), so a check can ask after a window what its decode rounds
+        read. The program is compiled at the first call; the engine's
+        counters count its rows like any step's."""
+        out = self._run(self._witness, np.asarray(tokens, np.int32),
+                        np.asarray(context_lens, np.int32),
+                        np.asarray(block_tables, np.int32))
+        return {k: np.asarray(v) for k, v in jax.device_get(out).items()}
+
+    def selection_load(self) -> dict:
+        """Over the live rows of every `full` layer since the engine was
+        built: `selected`, the positions their selections hold (`|S_t|`
+        summed), and `candidates`, the positions they were picked from (`t +
+        1` summed). Publishes the gauge `serving.dsa.selected_share`
+        (`selected / candidates`: 1 while no context passes `index_topk`)."""
+        c = jax.device_get({k: self.counters[k]
+                            for k in ("dsa_selected", "dsa_candidates")})
+        selected, candidates = (float(c["dsa_selected"]),
+                                float(c["dsa_candidates"]))
+        if candidates:
+            monitor.set_gauge("serving.dsa.selected_share",
+                              round(selected / candidates, 4))
+        return {"selected": selected, "candidates": candidates}

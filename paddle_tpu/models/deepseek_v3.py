@@ -10,7 +10,8 @@ thin holder of the pytree whose `forward` runs the same `decoder_layer` with
 a dense causal `attend` over the rows in flight. Nothing here is imported by
 `paddle_tpu` or by the Llama serving path.
 
-Equations, per token row x (published DeepSeek-V3 ones; `q_lora_rank` null):
+Equations, per token row x (published DeepSeek-V3 ones; `q_lora_rank` null:
+`models/glm_moe_dsa.py` makes its q-LoRA's queries and hands them in):
 
 - MLA: `q = x W_q -> [heads, nope + rope]`; `a = x W_kva -> [rank + rope]`,
   `c = RMSNorm(a[:rank])`, `k_rope = a[rank:]` shared by every head; RoPE on
@@ -159,18 +160,25 @@ def param_shapes(cfg: DeepseekV3Config) -> Dict[str, Tuple[tuple, str]]:
     return out
 
 
-def init_params(cfg: DeepseekV3Config, seed: int = 0, dtype=jnp.float32,
-                std: float = 0.02) -> Dict[str, jax.Array]:
-    """A pytree drawn on the device: matrices N(0, std^2), gains 1."""
+def draw_params(shapes: Dict[str, Tuple[tuple, str]], seed: int, dtype,
+                std: float) -> Dict[str, jax.Array]:
+    """A pytree of `shapes` (name -> (shape, kind)) drawn on the device:
+    matrices N(0, std^2), gains 1."""
     key = jax.random.key(seed)
     out = {}
-    for n, (name, (shape, kind)) in enumerate(sorted(param_shapes(cfg).items())):
+    for n, (name, (shape, kind)) in enumerate(sorted(shapes.items())):
         if kind == "norm":
             out[name] = jnp.ones(shape, dtype)
         else:
             out[name] = (jax.random.normal(jax.random.fold_in(key, n), shape,
                                            jnp.float32) * std).astype(dtype)
     return out
+
+
+def init_params(cfg: DeepseekV3Config, seed: int = 0, dtype=jnp.float32,
+                std: float = 0.02) -> Dict[str, jax.Array]:
+    """`draw_params` over this configuration's `param_shapes`."""
+    return draw_params(param_shapes(cfg), seed, dtype, std)
 
 
 def layer_params(params: Dict[str, jax.Array], i: int) -> Dict[str, jax.Array]:
@@ -225,7 +233,7 @@ def _w_kvb(p, cfg: DeepseekV3Config):
         cfg.qk_nope_head_dim + cfg.v_head_dim)
 
 
-def mla_query(x, p, cfg: DeepseekV3Config, cos, sin):
+def mla_query(x, p, cfg: DeepseekV3Config, cos, sin, q=None):
     """The attention sub-block before its context, on normed rows `x [T,
     H]`: `(q_abs [T, heads, rank + rope], rows [T, rank + rope])`, the
     absorbed queries and this step's cache rows `[c | rotated k_rope]`.
@@ -233,13 +241,17 @@ def mla_query(x, p, cfg: DeepseekV3Config, cos, sin):
     which owns the context: it stores `rows` and answers each query with
     `softmax(q . rows^T * scale) . rows[:, :rank]` over its token's causal
     context; `mla_output` takes `o_lat` on. Everything in the sub-block but
-    `attend` maps a row to a row."""
+    `attend` maps a row to a row. `q [T, heads * (nope + rope)]`: the
+    queries where the caller has projected them (an architecture with a
+    q-LoRA makes them from its `c_q`); else `x W_q`."""
     t = x.shape[0]
     nh, nope, rd = cfg.num_attention_heads, cfg.qk_nope_head_dim, \
         cfg.qk_rope_head_dim
     rank = cfg.kv_lora_rank
     with _scope("llama.mla_q"):
-        q = _mm(x, p["self_attn.q_proj.weight"]).reshape(t, nh, nope + rd)
+        if q is None:
+            q = _mm(x, p["self_attn.q_proj.weight"])
+        q = q.reshape(t, nh, nope + rd)
         q_nope, q_rope = q[..., :nope], q[..., nope:]
     with _scope("llama.mla_kv_a"):
         a = _mm(x, p["self_attn.kv_a_proj_with_mqa.weight"])

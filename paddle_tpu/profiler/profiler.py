@@ -558,6 +558,15 @@ class Profiler:
                 f"{g('serving.step.forced_settles')} forced settles; "
                 f"{g('serving.step.all_rows_calls')} all-rows calls "
                 f"(logits retraces {g('serving.logits_retraces')})")
+        if g("serving.dsa.selected_share"):
+            # an engine with a learned indexer: what its selections hold of
+            # what they were picked from (docs/OBSERVABILITY.md)
+            lines.append(
+                f"  selection: {g('serving.dsa.selected_share')} of the "
+                f"causal positions selected, "
+                f"{g('serving.kv_bytes_per_token.latent')} + "
+                f"{g('serving.kv_bytes_per_token.index')} bytes a token "
+                f"(latent + index)")
         if g("serving.state.bytes_per_seq"):
             # an engine over a state group: a sequence holds one slot of
             # recurrent state, whatever its length (docs/SERVING.md "A

@@ -66,10 +66,11 @@ def test_every_kernel_call_site_names_its_kernel(site):
 
 def test_kernel_names_are_distinct_and_cover_the_main_path():
     names = [n.value for _f, _l, n in SITES]
-    assert len(names) == len(set(names)) == 20
+    assert len(names) == len(set(names)) == 22
     assert {"paged_attention_ragged", "kv_write_ragged",
             "paged_attention_mla", "power_retention_update",
-            "power_retention_chunk",
+            "power_retention_chunk", "dsa_index_scores",
+            "mla_sparse_attention",
             "moe_grouped_matmul", "flash_fwd",
             "flash_dq", "flash_dkv",
             "rms_norm", "fused_rope", "quant_matmul_int8",

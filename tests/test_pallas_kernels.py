@@ -905,6 +905,94 @@ def test_brumby_step_compiles_for_v5e_at_the_cell_s_size(v5e_chip):
     assert held < 14.5e9, held          # of the chip's 16.9 GB
 
 
+@pytest.mark.parametrize("program", ["round", "witness"])
+def test_glm_moe_dsa_step_compiles_for_v5e_at_the_cell_s_size(v5e_chip,
+                                                              program):
+    """The GLM-5.2 engine's whole ragged step at the benchmark cell's size (5
+    layers at published widths, 16 of 256 experts held, 1/8 of the
+    vocabulary, 11,000 blocks in both pools on one table 896 wide, 32 lanes +
+    a 512-token chunk), compiled by the installed libtpu for a v5e from
+    shapes alone: an index-score kernel a `full` layer and a sparse-attention
+    kernel a layer and no dense latent-attention kernel; both donated pools
+    aliased to their outputs and no second copy of either among the
+    temporaries (the row loops close over the pools: a `while` that copied
+    its invariants would show here); weights + pools + temporaries fit the
+    chip. `witness`: the program a check replays decode rows through (a
+    row a lane), over the same state."""
+    import functools
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.inference import glm_moe_dsa_runner as gr
+    from paddle_tpu.models import glm_moe_dsa as glm
+    from paddle_tpu.ops import sampling
+    from paddle_tpu.ops.pallas import _support
+
+    cfg = glm.GlmMoeDsaConfig(
+        vocab_size=19360, hidden_size=6144, intermediate_size=12288,
+        moe_intermediate_size=2048, num_hidden_layers=5,
+        num_attention_heads=64, kv_lora_rank=512, qk_nope_head_dim=192,
+        qk_rope_head_dim=64, v_head_dim=256, n_routed_experts=256,
+        n_shared_experts=1, num_experts_per_tok=8, first_k_dense_replace=1,
+        rope_theta=8e6, held_experts=(0, 16),
+        indexer_types=(glm.FULL,) + (glm.SHARED,) * 3 + (glm.FULL,))
+    lanes, tokens, width, blocks = 32, 32 + 512, 896, 11000
+
+    def arr(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=v5e_chip)
+
+    params = {k: arr(s) for k, (s, _) in glm.param_shapes(cfg).items()}
+    weights = sum(int(np.prod(p.shape)) for p in params.values()) * 2
+    assert 7.76e9 < weights < 7.77e9            # the configuration's count
+    params["rope_cos"] = params["rope_sin"] = arr((width * 64, 32), jnp.float32)
+    pools = (arr((5, blocks, 64, 640)), arr((2, blocks, 64, 128)))
+    counters = {"tokens": arr((5, 256), jnp.int32),
+                "touched": arr((5,), jnp.int32), "steps": arr((), jnp.int32),
+                "narrow_steps": arr((), jnp.int32),
+                "dsa_selected": arr((), jnp.float32),
+                "dsa_candidates": arr((), jnp.float32)}
+    if program == "witness":
+        step = functools.partial(gr._witness_fn, cfg=cfg)
+        ints = [arr((lanes,), jnp.int32), arr((lanes,), jnp.int32),
+                arr((lanes, width), jnp.int32)]
+    else:
+        step = sampling.with_tail(
+            functools.partial(gr._ragged_stack, cfg=cfg, narrow=True),
+            functools.partial(gr._head, cfg=cfg))
+        ints = [arr((tokens,), jnp.int32),
+                arr((lanes, len(sampling.LANE_COLS)), jnp.int32),
+                arr((lanes, width), jnp.int32), arr((lanes,), jnp.float32),
+                arr((2, lanes), jnp.int32)]
+    cache_was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        with pytest.MonkeyPatch.context() as mp:
+            mp.setattr(_support, "backend", lambda: "tpu")
+            lowered = jax.jit(step, donate_argnums=(1, 2)).trace(
+                params, pools, counters, *ints).lower(
+                    lowering_platforms=("tpu",))
+            compiled = lowered.compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache_was)
+    kernels = re.findall(r'kernel_name = "(\w+)"', lowered.as_text())
+    assert sorted(set(kernels)) == ["dsa_index_scores", "mla_sparse_attention",
+                                    "moe_grouped_matmul"]
+    assert kernels.count("dsa_index_scores") == 2        # one a full layer
+    assert kernels.count("mla_sparse_attention") == 5    # one a layer
+    mem = compiled.memory_analysis()
+    pool_bytes = sum(int(np.prod(p.shape)) * 2 for p in pools)
+    assert pool_bytes == 11000 * 64 * 6912
+    assert mem.alias_size_in_bytes >= pool_bytes
+    # the index scores [544, 57344] float32 twice over (the kernel's tiles
+    # and their rows), a tile's gathered rows, the activations
+    assert mem.temp_size_in_bytes < 512 << 20, mem.temp_size_in_bytes
+    held = mem.argument_size_in_bytes + mem.output_size_in_bytes \
+        - mem.alias_size_in_bytes + mem.temp_size_in_bytes
+    assert held < 13.5e9, held          # of the chip's 16.9 GB
+
+
 def test_deepseek_v3_step_keeps_its_live_prefix_switches_on_the_v5e(v5e_chip):
     """ISSUE 39: the Kanana-shaped step (published attention and expert
     widths; depth, experts, vocabulary and pool cut), 32 lanes + a 512-token
