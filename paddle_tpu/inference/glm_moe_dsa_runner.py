@@ -10,9 +10,13 @@ travels from a layer with an indexer to the layers that share it.
   is taken, freed, shared and copied in both pools at once.
 - A `full` layer scores each live row against its lane's live pages of the
   index pool (`ops/pallas/dsa.dsa_index_scores`) and keeps the `index_topk`
-  best positions (`lax.top_k`: the scope `llama.dsa_topk` says what it
-  costs; a tile of rows that all lie in the first quarter of the table's
-  span is sorted over that quarter, `NEAR_SHARE`); the selection `(idx [T, K], n [T])` is carried, inside the one
+  best positions (`ops/pallas/dsa.dsa_select`: a threshold found by
+  bisection over the scores' bits and a placement by rank, no sort; the
+  scope `llama.dsa_topk` says what it costs; a tile of rows that all lie in
+  the first quarter of the table's span selects over that quarter,
+  `NEAR_SHARE`); the selection `(idx [T, K], n [T])`, `idx` in POSITION
+  order over its first `n` (`models/glm_moe_dsa.select`'s set, not its
+  order: nothing downstream reads an order), is carried, inside the one
   compiled step, to the `shared` layers after it.
 - EVERY row attends over its selected rows, gathered: `sparse_rows` reads `K`
   latent rows through the block table (`row_ids`, worked out once a
@@ -65,9 +69,9 @@ __all__ = ["GlmMoeDsaInferenceEngine"]
 
 FAMILY = "glm_moe_dsa"
 POOLS = ("latent", "index")
-# the selection's sort takes its time from the positions it is given, not
-# from the live ones: rows in the first 1 / NEAR_SHARE of the table's span
-# sort over that part (most of a prefill, whose rows pass every depth)
+# the selection takes its time from the positions it is given, not from the
+# live ones: rows in the first 1 / NEAR_SHARE of the table's span select
+# over that part (most of a prefill, whose rows pass every depth)
 NEAR_SHARE = 4
 
 
@@ -147,12 +151,12 @@ def _ragged_stack(params, pools, counters, tokens, q_lens, kv_lens, tables,
                     at = cut(tok_pos, r0)
 
                     def over(positions):
-                        return lambda: glm.select(
-                            dsa.score_rows(scores, r0, tile, positions), at,
+                        return lambda: dsa.dsa_select(
+                            dsa.score_tile(scores, r0, tile, positions), at,
                             k_sel)
 
                     # a tile whose rows all lie in the table's first
-                    # quarter is sorted over that quarter alone: what lies
+                    # quarter selects over that quarter alone: what lies
                     # past a row's own position is no candidate
                     if near < k_sel:
                         return over(span)()

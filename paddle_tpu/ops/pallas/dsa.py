@@ -1,7 +1,7 @@
-"""Pallas TPU kernels of learned sparse attention (DSA) over paged caches:
+"""Learned sparse attention (DSA) over paged caches: two Pallas TPU kernels,
 the indexer's scores of a packed ragged batch against a lane's live pages of
-the INDEX pool, and absorbed-MLA attention of a row over the latent rows its
-selection names.
+the INDEX pool and absorbed-MLA attention of a row over the latent rows its
+selection names, and between them the selection of each row's best positions.
 
 `dsa_index_scores` is built as `paged_attention_mla` is (grid = (lanes,),
 everything ragged scalar-prefetched, q, the pool and the output in HBM, live
@@ -12,7 +12,17 @@ they are made. The output is `[T, S / 128, 128]` float32 so that a token's
 group of scores is whole `(8, 128)` tiles however few tokens a lane holds (a
 one-row slice of a `[T, S]` array is a piece of a tile, which a DMA cannot
 address). What lies past a token's causal context is whatever was there: the
-caller masks it (`models/glm_moe_dsa.select` does).
+caller masks it (`select_keys` does).
+
+`dsa_select` picks a tile of rows' `index_topk` best positions out of those
+scores WITHOUT a sort (the v5e compiler lowers `lax.top_k` to a full sort of
+the row), in plain `jnp`: the k-th largest score is found exactly, by
+bisection over the bits of the float32 scores mapped to integers of the same
+order (32 passes of compare-and-count), and the chosen positions are placed
+by their rank (a product with a triangle of ones inside a group of 128, a
+prefix across groups, a product with a one-hot to bring a group's counts to
+a slot). The set is `models/glm_moe_dsa.select`'s to the last tie; the order
+is by position.
 
 `mla_sparse_attention` attends rows that were GATHERED: `gathered [R, K, DK]`
 holds row r's selected latent rows (`sparse_rows` over `row_ids`: a gather of
@@ -164,7 +174,7 @@ def dsa_index_scores(q_i, w, pool, layer, block_tables, kv_lens, tok_lane,
     Returns `[T + 8, S / 128, 128]` float32, `S >= W * BS` (whole page
     groups): row t's `I[t, s]` at `[t, s // 128, s % 128]` for every
     position `s` of its lane up to its own; what lies past that, and the 8
-    spare rows, is NOT defined. `score_rows` reads rows of it.
+    spare rows, is NOT defined. `score_tile` cuts rows of it.
     """
     tokens, heads, d = q_i.shape
     block_size = pool.shape[2]
@@ -205,12 +215,20 @@ def dsa_index_scores(q_i, w, pool, layer, block_tables, kv_lens, tok_lane,
       q_lens, q_starts, block_tables.astype(jnp.int32), q, wrep, pool)
 
 
-def score_rows(scores, r0, rows: int, positions: int):
+def score_tile(scores, r0, rows: int, positions: int):
     """Rows `[r0, r0 + rows)` of what `dsa_index_scores` (or its ref)
-    returned, as `[rows, positions]`: the tiles of a few rows re-laid, not
-    of the whole buffer (125 MB at the benchmark's size)."""
+    returned, over their first `positions` columns, as `[rows, G, 128]`
+    (`G` whole groups of 128; the kernel's own layout, cut and not re-laid):
+    what `dsa_select` takes. Columns past `positions` in the last group are
+    whatever was there (the ref's are `-inf`)."""
     cut = jax.lax.dynamic_slice_in_dim(scores, r0, rows, 0)
-    return cut.reshape(rows, -1)[:, :positions]
+    groups = -(-positions // 128)
+    if cut.ndim == 3:
+        return cut[:, :groups]
+    cut = cut[:, :positions]
+    cut = jnp.pad(cut, ((0, 0), (0, groups * 128 - cut.shape[1])),
+                  constant_values=-jnp.inf)
+    return cut.reshape(rows, groups, 128)
 
 
 # the ref gathers each token's whole window: bound what is live at once
@@ -220,7 +238,7 @@ _REF_TOKEN_TILE = 64
 def dsa_index_scores_ref(q_i, w, pool, layer, block_tables, kv_lens, tok_lane,
                          tok_pos):
     """XLA reference of `dsa_index_scores` (and the path off the TPU): per
-    packed token a gather of its lane's window, `[T, W * BS]` (`score_rows`
+    packed token a gather of its lane's window, `[T, W * BS]` (`score_tile`
     reads it too). Scores past a token's own position are computed too (the
     kernel leaves them undefined)."""
     del kv_lens, tok_pos
@@ -266,6 +284,96 @@ def index_supported(q_shape, pool_shape, pool_dtype, table_width) -> bool:
     if _support.on_tpu() and (d % 128 or heads % 8):
         return False
     return True
+
+
+# --- the selection: a threshold and a rank, not a sort ------------------------------
+
+_KEY_MIN = -2 ** 31
+
+
+def select_keys(tile, pos):
+    """Step 1: the scores `tile [R, G, 128]` float32 of rows at positions
+    `pos [R]` as int32 keys whose order is the selection's: a column past
+    its row's position is `-inf`, and a float's bits `b` become `b ^ ((b >>
+    31) & 0x7fffffff)`, which orders as `lax.top_k` orders floats (their
+    total order: `-0.0` under `+0.0`, a denormal by its bits)."""
+    rows, groups, _ = tile.shape
+    col = jax.lax.broadcasted_iota(jnp.int32, (rows, groups, 128), 1) * 128 \
+        + jax.lax.broadcasted_iota(jnp.int32, (rows, groups, 128), 2)
+    b = jax.lax.bitcast_convert_type(
+        jnp.where(col <= pos.astype(jnp.int32)[:, None, None],
+                  tile.astype(jnp.float32), -jnp.inf), jnp.int32)
+    return b ^ ((b >> 31) & jnp.int32(0x7fffffff))
+
+
+def select_threshold(keys, k: int):
+    """Step 2: each row's k-th largest key, exactly, by bisection over its
+    32 bits from the top (`t + 2^bit` wraps from the least int32 to 0 at
+    the sign bit): a pass counts `key >= candidate` a row. Plain XLA: the
+    v5e compiler keeps a tile's 7.3 MB of keys in VMEM over the 32 passes
+    (0.077 ms at the table's span beside 0.082 for a Pallas kernel that held
+    eight rows in VMEM: my chip run, PR 48), so there is no kernel."""
+    def bit(i, t):
+        cand = t + (jnp.int32(1) << (31 - i).astype(jnp.int32))
+        count = jnp.sum(keys >= cand[:, None, None], axis=(1, 2),
+                        dtype=jnp.int32)
+        return jnp.where(count >= k, cand, t)
+
+    return jax.lax.fori_loop(
+        0, 32, bit, jnp.full((keys.shape[0],), _KEY_MIN, jnp.int32))
+
+
+def select_place(keys, t, k: int):
+    """Steps 3 and 4: the positions of the `k` largest keys of each row of
+    `keys [R, G, 128]`, given the row's k-th largest `t [R]`, in position
+    order, `[R, k]` int32. In are the keys over `t` and, of those equal to
+    it, the first `k - count(key > t)` by position (`lax.top_k`'s ties).
+
+    A chosen position's rank is how many chosen ones lie before it: inside
+    a group of 128 a product with a triangle of ones (counts to 128 are
+    exact in bfloat16), across groups a prefix over `G` totals. Slot `j`
+    lies in the group `g_j` whose prefix first passes `j`; that group's
+    counts reach the slot as `one_hot(g_j) @ counts`, and the offset in it
+    is how many of them are at most the slot's rank in the group. No
+    scatter, no gather, no sort."""
+    rows, groups, _ = keys.shape
+    i32 = jnp.int32
+    lane = jax.lax.broadcasted_iota(i32, (128, 128), 0)
+    upto = (lane <= lane.T).astype(jnp.bfloat16)        # [l', l]: l' <= l
+    t = t[:, None, None]
+    over_eq = jnp.stack([keys > t, keys == t]).astype(jnp.bfloat16)
+    over, eq = jnp.einsum("xrgl,lm->xrgm", over_eq, upto,
+                          preferred_element_type=jnp.float32).astype(i32)
+    need = (k - jnp.sum(over[:, :, -1], axis=1, dtype=i32))[:, None]
+    eq_before = jnp.cumsum(eq[:, :, -1], axis=1, dtype=i32) - eq[:, :, -1]
+    tied = jnp.minimum(eq_before[:, :, None] + eq, need[:, :, None]) \
+        - jnp.minimum(eq_before, need)[:, :, None]
+    counts = over + tied                  # chosen up to each lane of a group
+    ends = jnp.cumsum(counts[:, :, -1], axis=1, dtype=i32)      # [R, G]
+    slot = jax.lax.broadcasted_iota(i32, (rows, k, groups), 1)
+    passed = ends[:, None, :] <= slot
+    g = jnp.sum(passed, axis=2, dtype=i32)                      # [R, k]
+    before = jnp.max(jnp.where(passed, ends[:, None, :], 0), axis=2)
+    mine = jnp.einsum(
+        "rjg,rgl->rjl", jax.nn.one_hot(g, groups, dtype=jnp.bfloat16),
+        counts.astype(jnp.bfloat16), preferred_element_type=jnp.float32)
+    rank = (slot[:, :, 0] - before).astype(jnp.float32)[:, :, None]
+    return g * 128 + jnp.sum(mine <= rank, axis=2, dtype=i32)
+
+
+def dsa_select(tile, pos, k: int):
+    """The selection of rows whose scores are `tile [R, G, 128]` float32
+    (`score_tile`) and whose own positions are `pos [R]` (negative: a guard
+    row): `models/glm_moe_dsa.select`'s set, `(idx [R, k] int32, n [R]
+    int32)`, the `n = min(k, pos + 1)` causal positions of largest score
+    first in `idx`, ties to the lower position, IN POSITION ORDER; what
+    follows them in `idx` is not a selection (the lowest positions past the
+    row's own). Found by a threshold (`select_threshold`) and placed by
+    rank (`select_place`): plain `jnp`, on the TPU and off it."""
+    k = min(k, tile.shape[1] * 128)
+    keys = select_keys(tile, pos)
+    idx = select_place(keys, select_threshold(keys, k), k)
+    return idx, jnp.clip(pos + 1, 0, k).astype(jnp.int32)
 
 
 # --- attention over gathered rows ----------------------------------------------------

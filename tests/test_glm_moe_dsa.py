@@ -235,28 +235,137 @@ def test_rows_in_the_near_part_select_over_it_alone(near):
         assert whole[r, :n[r]].tolist() == part[r, :n[r]].tolist()
 
 
-def test_the_step_sorts_near_tiles_over_the_near_part(rng):
-    """The compiled step holds both sorts (the table's span and its first
-    `1 / NEAR_SHARE`), and a table too narrow for a part that holds
-    `index_topk` positions holds one."""
+def _select_cases():
+    """name -> (scores [R, S], pos [R], k): what `dsa.dsa_select` must pick
+    as `glm.select` does. The widths: one group of 128, the benchmark
+    table's near quarter (14,336) and its span (57,344), and ragged ones."""
+    rng = np.random.default_rng(48)
+
+    def rows(s, r=5):
+        return np.r_[rng.integers(0, s, r - 2), s - 1, -1]
+
+    cases = {}
+    for s, k in ((128, 16), (96, 16), (1408, 290), (14336, 2048),
+                 (57344, 2048)):
+        r = 5 if s < 10000 else 3
+        x = rng.normal(size=(r, s))
+        cases[f"random scores, {s} wide"] = (x, rows(s, r), k)
+        cases[f"scores rounded to one decimal, {s} wide"] = (
+            np.round(x, 1), rows(s, r), k)
+    cases["a row of one value"] = (np.full((4, 300), 0.25), rows(300, 4), 40)
+    cases["fewer causal positions than k"] = (
+        rng.normal(size=(4, 640)), np.asarray([0, 7, 38, 39]), 40)
+    cases["k wider than the row"] = (rng.normal(size=(3, 96)), rows(96, 3),
+                                     200)
+    cases["guard rows alone"] = (rng.normal(size=(2, 256)),
+                                 np.asarray([-1, -1]), 16)
+    x = rng.normal(size=(5, 700))
+    x[:, ::3] = -np.inf
+    x[:, 1::7], x[:, 2::7] = -0.0, 0.0
+    x[:, 5::11], x[:, 6::11] = 1e-40, -1e-41
+    cases["-inf, zeros of both signs and denormals"] = (x, rows(700), 290)
+    cases["scores near -1e30"] = (-1e30 * (1 + rng.random((5, 700))),
+                                  rows(700), 64)
+    # the k-th and (k + 1)-th scores equal, the tie's members on both sides
+    # of a group's edge: 8 over the tie, 8 of the tie's 12 to take
+    x = np.zeros((2, 512))
+    x[:, [3, 130, 200, 255, 256, 300, 400, 500]] = 2.0
+    x[:, 250:256], x[:, 256:262] = 1.0, 1.0
+    x[1] = np.roll(x[1], 128)
+    cases["the k-th and the next equal across a group's edge"] = (
+        x, np.asarray([511, 511]), 16)
+    return cases
+
+
+SELECT_CASES = _select_cases()
+
+
+@pytest.mark.parametrize("case", sorted(SELECT_CASES))
+def test_the_selection_by_threshold_is_the_top_k_s_set(case):
+    """`dsa.dsa_select` (a threshold by bisection over the scores' bits,
+    a placement by rank) against `glm.select` (`lax.top_k`): `n` equal and
+    the first `n` positions the same set row by row, `lax.top_k`'s ties
+    among them, in strictly ascending position order."""
+    scores, pos, k = SELECT_CASES[case]
+    rows, s = scores.shape
+    scores = jnp.asarray(scores, jnp.float32)
+    pos = jnp.asarray(pos, jnp.int32)
+    want, n = glm.select(scores, pos, k)
+    got, m = dsa.dsa_select(dsa.score_tile(scores, 0, rows, s), pos, k)
+    assert got.dtype == jnp.int32
+    assert got.shape == (rows, min(k, -(-s // 128) * 128))
+    assert m.tolist() == n.tolist()
+    for r in range(rows):
+        mine = np.asarray(got[r, :n[r]])
+        assert mine.tolist() == sorted(want[r, :n[r]].tolist()), r
+        assert (np.diff(mine) > 0).all(), "position order"
+
+
+@pytest.mark.parametrize("rows,groups,k", [(8, 8, 100), (5, 3, 16),
+                                           (16, 12, 290), (3, 1, 128)])
+def test_the_threshold_is_the_k_th_largest_key(rows, groups, k, rng):
+    """`dsa.select_threshold` over `select_keys`: at least `k` keys reach
+    it and fewer than `k` pass it, with ties by the hundred, a row at the
+    tile's last position and a guard row; and the keys order as the
+    masked scores do."""
+    # `+ 0.0`: no -0.0, which the keys put under +0.0 and a float sort does not
+    tile = jnp.asarray(np.round(rng.normal(size=(rows, groups, 128)), 1)
+                       + 0.0, jnp.float32)
+    pos = jnp.asarray(np.r_[rng.integers(0, groups * 128, rows - 2),
+                            groups * 128 - 1, -1], jnp.int32)
+    keys = np.asarray(dsa.select_keys(tile, pos), np.int64).reshape(rows, -1)
+    t = np.asarray(dsa.select_threshold(dsa.select_keys(tile, pos), k))
+    assert ((keys >= t[:, None]).sum(1) >= k).all()
+    assert ((keys > t[:, None]).sum(1) < k).all()
+    masked = np.where(np.arange(groups * 128)[None, :] <= np.asarray(pos)[:, None],
+                      np.asarray(tile).reshape(rows, -1), -np.inf)
+    for r in range(rows):
+        order = np.argsort(masked[r], kind="stable")
+        assert (np.diff(keys[r][order]) >= 0).all()
+
+
+def _under(jaxpr, scope, path=""):
+    """Every equation of `jaxpr`, nested ones too, whose scope path (an
+    inner jaxpr's name stack starts anew: the outer equation's is put
+    before it) holds `scope`."""
+    for eqn in jaxpr.eqns:
+        here = f"{path}/{eqn.source_info.name_stack}"
+        if scope in here:
+            yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _under(sub, scope, here)
+
+
+def test_the_step_selects_near_tiles_over_the_near_part(rng):
+    """The traced step holds the selection at both widths (the table's span
+    and its first `1 / NEAR_SHARE`), a table too narrow for a part that
+    holds `index_topk` positions holds one, and no equation under
+    `llama.dsa_topk` is a sort, a top-k, a gather or a scatter."""
     from paddle_tpu.inference import glm_moe_dsa_runner as gr
 
     model = glm.GlmMoeDsaForCausalLM(CFG, weights=make_params())
 
-    def sort_widths(blocks_per_seq):
+    def select_widths(blocks_per_seq):
         eng = GlmMoeDsaInferenceEngine(model, max_batch_size=2, num_blocks=9,
                                        block_size=16,
                                        max_blocks_per_seq=blocks_per_seq)
         fn, lead = eng.cost_card_args("ragged")
-        text = fn.lower(*lead, *sampling.step_args(
+        jaxpr = jax.make_jaxpr(fn)(*lead, *sampling.step_args(
             np.zeros((18,), np.int32), [1, 1], [5, 9],
-            np.zeros((2, blocks_per_seq), np.int32))).as_text()
-        return set(re.findall(rf"chlo\.top_k\(%\S+, k = {TOPK}\) : "
-                              r"tensor<\d+x(\d+)xf32>", text))
+            np.zeros((2, blocks_per_seq), np.int32))).jaxpr
+        scoped = list(_under(jaxpr, "llama.dsa_topk"))
+        names = {e.primitive.name for e in scoped}
+        assert "while" in names and "dot_general" in names      # it is there
+        assert not names & {"sort", "top_k", "approx_top_k", "gather",
+                            "scatter", "scatter-add"}, names
+        # a tile's keys: the bitcast of its masked scores `[rows, G, 128]`
+        return {e.invars[0].aval.shape[1] for e in scoped
+                if e.primitive.name == "bitcast_convert_type"}
 
     assert gr.NEAR_SHARE == 4
-    assert sort_widths(6) == {"96", "24"}       # 24 >= index_topk 16
-    assert sort_widths(2) == {"32"}             # 8 < 16: one sort
+    assert select_widths(24) == {3, 1}          # 384 and its quarter, 96
+    assert select_widths(6) == {1}              # 96 and 24: a group each
+    assert select_widths(2) == {1}              # 8 < index_topk 16: one
 
 
 # ---- the kernels -----------------------------------------------------------------
@@ -287,12 +396,16 @@ def test_index_kernel_against_its_ref(case, rng, interpret):
     q = jnp.asarray(rng.normal(size=(tokens, H, D)), jnp.float32)
     w = jnp.asarray(rng.normal(size=(tokens, H)), jnp.float32)
     assert dsa.index_supported(q.shape, pool.shape, pool.dtype, W)
-    got = dsa.score_rows(
+    got = dsa.score_tile(
         dsa.dsa_index_scores(q, w, pool, 1, tables, kv_lens, lane, pos), 0,
-        tokens, W * BS)
+        tokens, W * BS).reshape(tokens, W * BS)
     want = dsa.dsa_index_scores_ref(q, w, pool, 1, tables, kv_lens, lane, pos)
-    np.testing.assert_array_equal(dsa.score_rows(want, 3, 8, W * BS),
-                                  want[3:11])
+    np.testing.assert_array_equal(
+        dsa.score_tile(want, 3, 8, W * BS).reshape(8, W * BS), want[3:11])
+    # a cut that ends inside a group of 128: the ref's is padded with -inf
+    part = np.asarray(dsa.score_tile(want, 3, 8, 100)).reshape(8, 128)
+    np.testing.assert_array_equal(part[:, :100], want[3:11, :100])
+    assert np.isneginf(part[:, 100:]).all()
     causal = np.arange(W * BS)[None, :] <= np.asarray(pos)[:, None]
     assert causal.sum() == sum(
         q * k - q * (q - 1) // 2 for q, k in zip(*KERNEL_CASES[case]))

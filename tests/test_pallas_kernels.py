@@ -912,8 +912,9 @@ def test_glm_moe_dsa_step_compiles_for_v5e_at_the_cell_s_size(v5e_chip,
     layers at published widths, 16 of 256 experts held, 1/8 of the
     vocabulary, 11,000 blocks in both pools on one table 896 wide, 32 lanes +
     a 512-token chunk), compiled by the installed libtpu for a v5e from
-    shapes alone: an index-score kernel a `full` layer and a sparse-attention
-    kernel a layer and no dense latent-attention kernel; both donated pools
+    shapes alone: an index-score kernel and a selection without a sort a
+    `full` layer and a sparse-attention kernel a layer and no dense
+    latent-attention kernel; both donated pools
     aliased to their outputs and no second copy of either among the
     temporaries (the row loops close over the pools: a `while` that copied
     its invariants would show here); weights + pools + temporaries fit the
@@ -980,6 +981,12 @@ def test_glm_moe_dsa_step_compiles_for_v5e_at_the_cell_s_size(v5e_chip,
     assert sorted(set(kernels)) == ["dsa_index_scores", "mla_sparse_attention",
                                     "moe_grouped_matmul"]
     assert kernels.count("dsa_index_scores") == 2        # one a full layer
+    # the selection is there, and the v5e compiler was handed no sort and no
+    # top-k to lower under its scope
+    text = compiled.as_text()
+    assert "llama.dsa_topk" in text
+    assert not re.findall(
+        r"\n[^\n]*\b(?:sort|topk|top_k)\b[^\n]*llama\.dsa_topk", text)
     assert kernels.count("mla_sparse_attention") == 5    # one a layer
     mem = compiled.memory_analysis()
     pool_bytes = sum(int(np.prod(p.shape)) * 2 for p in pools)
