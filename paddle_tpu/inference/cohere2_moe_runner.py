@@ -91,6 +91,14 @@ def _ragged_stack(params, pools, counters, tokens, q_lens, kv_lens, tables,
     for g, (_kind, _name, layers) in enumerate(groups):
         where.update({layer: (g, n) for n, layer in enumerate(layers)})
 
+    # the kernel takes the packed buffer as the first segment leaves it
+    # (`attend.pack`: `pk.ragged_prepare`, placed by the segment's
+    # `rowwise`) and hands its own buffer to the second (`attend.unpack`)
+    nh, kvh, d = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim
+    packed = all(pk.ragged_supported(
+        (t, nh, d), x.dtype, pools[2 * g].shape, pools[2 * g].dtype, width)
+        for g in range(len(groups)))
+
     def attend_layer(i, kind):
         g, n = where[i]
         table = tables[:, g * width:(g + 1) * width]
@@ -104,11 +112,17 @@ def _ragged_stack(params, pools, counters, tokens, q_lens, kv_lens, tables,
                     tok_pos, layer=n)
             with jax.named_scope(scope):
                 kc, vc = pools[2 * g], pools[2 * g + 1]
-                kernel = pk.paged_attention_ragged if pk.ragged_supported(
-                    q.shape, q.dtype, kc.shape, kc.dtype, width) \
-                    else pk.paged_attention_ragged_ref
-                return kernel(q, kc, vc, table, kv_lens, tok_lane, tok_pos,
-                              layer=n, window=window)
+                if packed:
+                    return pk.paged_attention_ragged_packed(
+                        q, kc, vc, table, kv_lens, tok_lane, tok_pos, layer=n,
+                        window=window, mxu_bf16=x.dtype == jnp.bfloat16)
+                return pk.paged_attention_ragged_ref(
+                    q, kc, vc, table, kv_lens, tok_lane, tok_pos, layer=n,
+                    window=window)
+        if packed:
+            attend.pack = functools.partial(pk.ragged_prepare, kv_heads=kvh)
+            attend.unpack = functools.partial(pk.ragged_finish, heads=nh,
+                                              dtype=x.dtype)
         return attend
 
     sizes = []

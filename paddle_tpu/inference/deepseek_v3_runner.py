@@ -78,6 +78,12 @@ def _ragged_stack(params, pool, counters, tokens, q_lens, kv_lens, tables,
     with jax.named_scope("llama.embed"):
         x = jnp.take(params["model.embed_tokens.weight"], tokens, axis=0)
 
+    # the kernel takes the packed buffer as the query segment leaves it
+    # (`attend.pack`: `pm.mla_prepare`, placed by the segment's `rowwise`)
+    # and hands its own buffer to the next one
+    packed = pm.mla_supported((t, cfg.num_attention_heads, cfg.latent_dim),
+                              pool.shape, pool.dtype, tables.shape[1], rank)
+
     def attend_layer(i):
         def attend(q_abs, rows):
             nonlocal pool
@@ -86,12 +92,13 @@ def _ragged_stack(params, pool, counters, tokens, q_lens, kv_lens, tables,
                                ((0, 0), (0, row - rows.shape[-1])))
                 pool = pool.at[i, blk, off].set(rows, mode="drop")
             with jax.named_scope("llama.attn"):
-                kernel = pm.paged_attention_mla if pm.mla_supported(
-                    q_abs.shape, pool.shape, pool.dtype, tables.shape[1],
-                    rank) else pm.paged_attention_mla_ref
+                kernel = pm.paged_attention_mla_packed if packed \
+                    else pm.paged_attention_mla_ref
                 return kernel(q_abs, pool, jnp.int32(i), tables, kv_lens,
                               tok_lane, tok_pos, rank,
                               cfg.qk_head_dim ** -0.5)
+        if packed:
+            attend.pack = lambda q_abs: pm.mla_prepare(q_abs, pool)
         return attend
 
     sizes = []

@@ -59,7 +59,7 @@ from ..models import deepseek_v3 as dsv3
 from ..models import glm_moe_dsa as glm
 from ..observability import compile_trace
 from ..ops import sampling
-from ..ops.pallas import dsa
+from ..ops.pallas import _support, dsa
 from ..ops.pallas.paged_attention import ragged_metadata
 from . import kv_migrate, live_prefix
 from .cache import BlockCacheManager
@@ -84,8 +84,10 @@ def _tile_rows(t: int, lanes: int) -> int:
 def _live_tiles(n_live, rows: int, t: int, fn, outs):
     """`fn(r0) -> tuple of [rows, ...]` over the tiles `[r0, r0 + rows)` of
     a packed buffer of `t` slots that hold a live row (the first `n_live`
-    slots are the live ones), written into `outs` (zeros `[t, ...]`): a
-    loop whose trip count the device takes from the step's own `q_lens`."""
+    slots are the live ones), written into `outs` (`[t, ...]`: blank where
+    only live rows are read of them, zeros where a reduction reads them
+    whole): a loop whose trip count the device takes from the step's own
+    `q_lens`."""
     def body(i, outs):
         r0 = i * jnp.int32(rows)
         return tuple(jax.lax.dynamic_update_slice_in_dim(o, g, r0, 0)
@@ -134,6 +136,13 @@ def _ragged_stack(params, pools, counters, tokens, q_lens, kv_lens, tables,
     def cut(a, r0):
         return jax.lax.dynamic_slice_in_dim(a, r0, tile, 0)
 
+    # the index-score kernel takes the packed buffers as the query segment
+    # leaves them (`pick.pack`: `dsa.index_prepare`, placed by the
+    # segment's `rowwise`)
+    packed = dsa.index_supported(
+        (t, cfg.index_n_heads, cfg.index_head_dim), index.shape, index.dtype,
+        tables.shape[1])
+
     def index_layer(n):
         def pick(q_i, k_i, w):
             nonlocal index
@@ -141,8 +150,7 @@ def _ragged_stack(params, pools, counters, tokens, q_lens, kv_lens, tables,
                 index = index.at[n, blk, off].set(k_i.astype(index.dtype),
                                                   mode="drop")
             with jax.named_scope("llama.dsa_index_scores"):
-                kernel = dsa.dsa_index_scores if dsa.index_supported(
-                    q_i.shape, index.shape, index.dtype, tables.shape[1]) \
+                kernel = dsa.dsa_index_scores_packed if packed \
                     else dsa.dsa_index_scores_ref
                 scores = kernel(q_i, w, index, n, tables, kv_lens, tok_lane,
                                 tok_pos)
@@ -163,10 +171,14 @@ def _ragged_stack(params, pools, counters, tokens, q_lens, kv_lens, tables,
                     return jax.lax.cond(jnp.max(at) < near, over(near),
                                         over(span))
 
+                # `n` is summed whole (`dsa_selected`): zeros past the
+                # live tiles; of `idx` a live tile's rows alone are read
                 return _live_tiles(
                     n_live, tile, t, select_tile,
-                    (jnp.zeros((t, k_sel), jnp.int32),
+                    (_support.blank((t, k_sel), jnp.int32),
                      jnp.zeros((t,), jnp.int32)))
+        if packed:
+            pick.pack = lambda q_i, w: dsa.index_prepare(q_i, w, index)
         return pick
 
     ids_of = {}     # a selection's `(idx, row ids)`, by `id(idx)`
@@ -196,9 +208,10 @@ def _ragged_stack(params, pools, counters, tokens, q_lens, kv_lens, tables,
                                  rank, cfg.qk_head_dim ** -0.5)
                     return (out,) if known else (out, ids)
 
-                outs = (jnp.zeros(q_abs.shape[:2] + (rank,), q_abs.dtype),)
+                outs = (_support.blank(q_abs.shape[:2] + (rank,),
+                                       q_abs.dtype),)
                 if not known:
-                    outs += (jnp.zeros(idx.shape, jnp.int32),)
+                    outs += (_support.blank(idx.shape, jnp.int32),)
                 o_lat, *ids = _live_tiles(n_live, tile, t, rows_of, outs)
                 if ids:
                     ids_of[id(idx)] = (idx, ids[0])

@@ -9,19 +9,26 @@ layer already cost what is live whatever `T` is: `attend` (the pool's write
 and the paged-attention kernel follow live pages) and an expert layer's
 grouped matmuls (they follow the experts touched). Everything else maps a row
 to a row and costs its rows. So the engines whose row-wise regions are
-compute-bound at `T` rows (`deepseek_v3_runner`, `cohere2_moe_runner`) wrap
-those regions, and not the kernels between them, in `rowwise`: ONE
-executable, every kernel in it once, the width chosen on the device from the
-step's own `q_lens`.
+compute-bound at `T` rows (`deepseek_v3_runner`, `cohere2_moe_runner`,
+`glm_moe_dsa_runner`) wrap those regions, and not the kernels between them,
+in `rowwise`: ONE executable, every kernel in it once, the width chosen on
+the device from the step's own `q_lens`.
+
+A packed buffer that travels between a segment and a kernel is made blank,
+once a use, at the shape the kernel takes, and a round writes into it the
+rows it computed (`ops/pallas/_support.place`). A guard row of a packed
+buffer (a slot at or past `sum(q_lens)`, and the spare rows a kernel's DMA
+may run over) holds whatever; every reader of a packed buffer reads live
+rows only (docs/SERVING.md lists them).
 """
 from __future__ import annotations
 
 from typing import Callable, Optional
 
 import jax
-import jax.numpy as jnp
 
 from ..models.deepseek_v3 import whole
+from ..ops.pallas import _support
 
 __all__ = ["rowwise"]
 
@@ -32,32 +39,41 @@ def rowwise(n_live, narrow: Optional[int], t: int) -> Callable:
     them first in the buffer, and the static `narrow` (the lane count).
 
     `fn(*rows) -> (row_outs, others)`: every leaf of `rows` and `row_outs`
-    has a whole number of rows a token slot as its leading dimension (`t`,
-    or `t * k` for an expert layer's assignments) and slot i of an output
-    depends on slot i of the inputs alone; `others` (an expert layer's
-    `tokens_per_expert [E]`, or None) have no row dimension and count live
-    rows only. `fn'` is `lax.cond(n_live <= narrow, on_prefix, fn)`:
-    `on_prefix` runs `fn` on the first `narrow` slots of every row argument
-    and zero-pads `row_outs` back to `t` slots, so a guard row reads exact
-    zeros where `fn` leaves whatever a guard row computes; nothing reads
-    either. Where `t <= narrow` (or `narrow` is None) `fn'` is `fn`: no
-    `cond`."""
+    has a whole number of rows a token slot as its leading dimension (`w`,
+    or `w * k` for an expert layer's assignments, where `fn` is given `w`
+    slots) and slot i of an output depends on slot i of the inputs alone;
+    `others` (an expert layer's `tokens_per_expert [E]`, or None) have no
+    row dimension and count live rows only. A leaf of `row_outs` bound for
+    a kernel is a `Packed` (`ops/pallas/_support.py`): its rows, prepared,
+    and the spare rows the kernel's buffer has.
+
+    `fn'` takes the packed buffers (`t` slots and whatever spare rows a
+    kernel left on them) and returns packed buffers. It is `lax.cond(n_live
+    <= narrow, on_prefix, on_all)`: `on_prefix` runs `fn` on the first
+    `narrow` slots of every row argument and PLACES what it made at the top
+    of a blank buffer (`_support.place`), so of a round of decode lanes
+    only the lanes' rows are computed, written or read. A guard row (a slot
+    at or past `n_live`, and a spare row) holds WHATEVER: zeros off the
+    TPU, what the allocation held on it, what `fn` makes of such a row
+    where it was given one. Every reader of a packed buffer reads live rows
+    only. The prefix is cut before the `cond` and handed in beside the
+    whole buffers: cut inside, the TPU compiler re-laid a whole buffer out
+    before slicing it. `on_all` is `models/deepseek_v3.whole(t)(fn)`: `fn`
+    over every slot. Where `t <= narrow` (or `narrow` is None) `fn'` is
+    `on_all`: no `cond`."""
     if narrow is None or t <= narrow:
-        return whole
+        return whole(t)
+
+    def cut(a):
+        return a[:a.shape[0] // t * narrow]
 
     def wrap(fn):
-        def on_prefix(*rows):
-            # cut first and hold the cut: fused into its consumer, the
-            # TPU compiler re-laid the whole buffer out before slicing it
-            head = jax.lax.optimization_barrier(jax.tree.map(
-                lambda a: a[:a.shape[0] // t * narrow], rows))
+        def on_prefix(head, rows):
             outs, others = fn(*head)
-            return jax.tree.map(
-                lambda a: jnp.pad(a, ((0, a.shape[0] // narrow
-                                       * (t - narrow)),)
-                                  + ((0, 0),) * (a.ndim - 1)),
-                outs), others
+            return _support.place(outs, narrow, t), others
 
-        return lambda *rows: jax.lax.cond(n_live <= narrow, on_prefix, fn,
-                                          *rows)
+        on_all = whole(t)(fn)
+        return lambda *rows: jax.lax.cond(
+            n_live <= narrow, on_prefix, lambda head, rows: on_all(*rows),
+            jax.tree.map(cut, rows), rows)
     return wrap

@@ -57,8 +57,8 @@ import numpy as np
 
 from .. import nn
 from ..nn.parameter import Parameter
-from .deepseek_v3 import (_mm, layer_params, moe_combine, moe_dispatch,
-                          moe_experts, whole)
+from .deepseek_v3 import (_mm, expert_rows, layer_params, moe_combine,
+                          moe_dispatch, moe_experts, whole)
 
 __all__ = ["Cohere2MoeConfig", "Cohere2MoeForCausalLM", "param_shapes",
            "init_params", "decoder_layer", "model_forward", "rope_tables"]
@@ -273,36 +273,48 @@ def route(h, p, cfg: Cohere2MoeConfig):
 
 
 def decoder_layer(x, p, cfg: Cohere2MoeConfig, kind: str, cos, sin, attend,
-                  live, rowwise: Callable = whole):
+                  live, rowwise: Callable = None):
     """One decoder layer of `kind` on rows `x [T, H]`: `(x, tokens_per_expert
     [num_experts])`.
 
     A layer is *segment -> `attend` and the experts -> segment*, and a
     segment maps a row to a row: `rowwise(segment)` may run it over fewer
     rows than `T` (the serving step's live prefix,
-    `inference/live_prefix.py`). `attend`, which owns the context, and the
-    experts' grouped matmuls, whose cost follows the experts touched, always
-    take the whole packed buffer; the block is parallel, so neither waits
-    for the other."""
+    `inference/live_prefix.py`; None: `whole(T)`). `attend`, which owns the
+    context, and the experts' grouped matmuls, whose cost follows the
+    experts touched, always take the whole packed buffer; the block is
+    parallel, so neither waits for the other. An `attend` that is a kernel
+    which takes and leaves the buffer as it is carries `attend.pack`, its
+    row-wise `prepare`, applied by the first segment to the rows it made,
+    and `attend.unpack`, what the second applies to its rows of the
+    kernel's own buffer (`deepseek_v3.decoder_layer`); the experts' rows go
+    the same way."""
+    t = x.shape[0]
+    m = t * cfg.num_experts_per_tok
+    rowwise = rowwise or whole(t)
+    pack, unpack = (getattr(attend, a, None) for a in ("pack", "unpack"))
     with _scope("llama.layer"):
         def before(x, cos, sin, live):
             with _scope("llama.rms_norm"):
                 h = layer_norm(x, p["input_layernorm.weight"],
                                cfg.layer_norm_eps)
-            heads = qkv(h, p, cfg, kind, cos, sin)
-            sorted_rows, counts = moe_dispatch(h, p, cfg, live, cfg.held,
+            q, k, v = qkv(h, p, cfg, kind, cos, sin)
+            (xs, *rest), counts = moe_dispatch(h, p, cfg, live, cfg.held,
                                                route)
-            return (h, heads) + sorted_rows, counts
+            xs = expert_rows(xs, p["mlp.experts.gate_proj.weight"], m)
+            return (h, (pack(q) if pack else q, k, v), xs, *rest), counts
 
         def after(x, h, a, y, order, keep, weights):
             ffn = moe_combine(h, y, order, keep, weights, p,
                               cfg.num_shared_experts)
+            a = unpack(a) if unpack else a
             return x + o_proj(a, p, cfg, x.dtype) + ffn, None
 
         (h, (q, k, v), xs, order, keep, weights), (mine, sizes) = rowwise(
             before)(x, cos, sin, live)
-        x, _ = rowwise(after)(x, h, attend(q, k, v), moe_experts(xs, mine, p),
-                              order, keep, weights)
+        x, _ = rowwise(after)(x, h, attend(q, k, v),
+                              moe_experts(xs, mine, p, rowwise, m), order,
+                              keep, weights)
         return x, sizes
 
 

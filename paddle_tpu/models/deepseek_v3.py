@@ -43,7 +43,7 @@ import numpy as np
 
 from .. import nn
 from ..nn.parameter import Parameter
-from ..ops.pallas import grouped_matmul
+from ..ops.pallas import _support, grouped_matmul
 
 __all__ = ["DeepseekV3Config", "DeepseekV3ForCausalLM", "param_shapes",
            "init_params", "decoder_layer", "model_forward", "rope_tables"]
@@ -334,14 +334,29 @@ def dispatch(x, experts, live, cfg, held: Optional[Tuple[int, int]] = None):
     return (xs, order, jnp.broadcast_to(keep, (t, k))), (mine, sizes)
 
 
-def expert_ffn(xs, mine, p):
+def expert_rows(a, w, m: int):
+    """Rows `a` (all or the first of `m` sorted assignments) on their way
+    to a grouped matmul over `w [E, in, out]`: the kernel's `prepare`
+    (its dtype, and the spare rows that make `m` whole row tiles: a
+    `Packed`, which a `rowwise` places) where `expert_ffn` will take the
+    kernel, else `a`."""
+    if grouped_matmul.supported(w.shape, w.dtype):
+        return grouped_matmul.prepare(a, w, m)
+    return a
+
+
+def expert_ffn(xs, mine, p, rowwise: Callable = None, m: int = None):
     """The experts' SwiGLUs on rows sorted by expert: three grouped matmuls
-    (the kernel `moe_grouped_matmul`, `ops/pallas/grouped_matmul.py`; off
-    the TPU `jax.lax.ragged_dot`). `xs [M, H]`, `mine [count]` rows an
-    expert, their sum at most M; an expert with no row is not computed and
-    its matrices are not read, rows past the sum cost nothing and hold
-    whatever. Returns `[M, H]` float32. Its cost follows the experts
-    touched, not M."""
+    (the kernel `moe_grouped_matmul`, `ops/pallas/grouped_matmul.py`, which
+    takes a buffer of whole row tiles in the weights' dtype, `expert_rows`
+    placed, as it is; off the TPU `jax.lax.ragged_dot`). `xs [M, H]` or
+    that buffer, `mine [count]` rows an expert, their sum at most M; an
+    expert with no row is not computed and its matrices are not read, rows
+    past the sum cost nothing and hold whatever. The product between the
+    matmuls maps a row to a row: a segment of its own, which `rowwise` may
+    run over fewer rows (`m`: the assignments of the whole packed buffer,
+    where `xs` has spare rows past them). Returns `[M, H]` float32, or the
+    buffer's rows. Its cost follows the experts touched, not M."""
     with _scope("llama.moe_experts"):
         def rd(a, w):
             if grouped_matmul.supported(w.shape, w.dtype):
@@ -349,10 +364,16 @@ def expert_ffn(xs, mine, p):
             return jax.lax.ragged_dot(a, w.astype(a.dtype), mine,
                                       preferred_element_type=jnp.float32)
 
+        down = p["mlp.experts.down_proj.weight"]
+
+        def gated(g, u):
+            act = (jax.nn.silu(g) * u).astype(xs.dtype)
+            return expert_rows(act, down, m or act.shape[0]), None
+
         g = rd(xs, p["mlp.experts.gate_proj.weight"])
         u = rd(xs, p["mlp.experts.up_proj.weight"])
-        return rd((jax.nn.silu(g) * u).astype(xs.dtype),
-                  p["mlp.experts.down_proj.weight"])    # [M, H] float32
+        act, _ = (rowwise or whole(g.shape[0]))(gated)(g, u)
+        return rd(act, down)                            # [M, H] float32
 
 
 def combine(y, order, keep, weights, dtype):
@@ -381,10 +402,10 @@ def moe_dispatch(x, p, cfg, live, held=None, router: Callable = None):
     return sorted_rows + (weights,), counts
 
 
-def moe_experts(xs, mine, p):
+def moe_experts(xs, mine, p, rowwise: Callable = None, m: int = None):
     """The experts themselves (`expert_ffn`) under the layer's scope."""
     with _scope("llama.moe"):
-        return expert_ffn(xs, mine, p)
+        return expert_ffn(xs, mine, p, rowwise, m)
 
 
 def moe_combine(x, y, order, keep, weights, p, mean_of: int = 1):
@@ -403,30 +424,49 @@ def moe_combine(x, y, order, keep, weights, p, mean_of: int = 1):
             return out + shared
 
 
-def whole(fn: Callable) -> Callable:
-    """`rowwise` where every row is live (no cache, a verify window): `fn`
-    itself."""
-    return fn
+def whole(t: int) -> Callable:
+    """`rowwise` where every row is live (no cache, a verify window):
+    `wrap(fn)` runs `fn` over all `t` token slots of its row arguments
+    (less the spare rows a kernel left after them) and places a `Packed`
+    row output in its kernel's buffer (`_support.place`)."""
+    def wrap(fn):
+        def on_all(*rows):
+            outs, others = fn(*jax.tree.map(
+                lambda a: a[:a.shape[0] // t * t], rows))
+            return _support.place(outs, t, t), others
+        return on_all
+    return wrap
 
 
 def decoder_layer(x, p, cfg: DeepseekV3Config, cos, sin, attend, live,
-                  rowwise: Callable = whole):
+                  rowwise: Callable = None):
     """One decoder layer on rows `x [T, H]` (`p`: the layer's weights by
     their names under `model.layers.<i>.`). Returns `(x, tokens_per_expert
     [E] | None)`; None for a dense layer.
 
     A layer is *segment -> `attend` -> segment* (an expert layer's second
-    segment again *-> experts -> segment*), and a segment maps a row to a
-    row: `rowwise(segment)` may run it over fewer rows than `T` (the serving
-    step's live prefix, `inference/live_prefix.py`). `attend`, which owns
-    the context, and the experts' grouped matmuls, whose cost follows the
-    experts touched, always take the whole packed buffer."""
+    segment again *-> experts -> segment*, the experts' own matmuls around
+    one more), and a segment maps a row to a row: `rowwise(segment)` may
+    run it over fewer rows than `T` (the serving step's live prefix,
+    `inference/live_prefix.py`; None: `whole(T)`). `attend`, which owns the
+    context, and the experts' grouped matmuls, whose cost follows the
+    experts touched, always take the whole packed buffer: an `attend` that
+    is a kernel which takes the buffer as it is carries `attend.pack`, the
+    kernel's row-wise `prepare`, which the query segment applies to the
+    rows it made (`attend` then returns the kernel's own buffer, and the
+    next segment reads its rows of it); the experts' rows go the same way
+    (`expert_rows`)."""
+    t = x.shape[0]
+    m = t * cfg.num_experts_per_tok
+    rowwise = rowwise or whole(t)
+    pack = getattr(attend, "pack", None)
     with _scope("llama.layer"):
         def query(x, cos, sin):
             with _scope("llama.rms_norm"):
                 h = rms_norm(x, p["input_layernorm.weight"],
                              cfg.rms_norm_eps)
-            return mla_query(h, p, cfg, cos, sin), None
+            q_abs, rows = mla_query(h, p, cfg, cos, sin)
+            return (pack(q_abs) if pack else q_abs, rows), None
 
         def attended(x, o_lat):
             x = x + mla_output(o_lat, p, cfg, x.dtype)
@@ -443,8 +483,9 @@ def decoder_layer(x, p, cfg: DeepseekV3Config, cos, sin, attend, live,
 
         def routed(x, o_lat, live):
             x, h = attended(x, o_lat)
-            sorted_rows, counts = moe_dispatch(h, p, cfg, live)
-            return (x, h) + sorted_rows, counts
+            (xs, *rest), counts = moe_dispatch(h, p, cfg, live)
+            xs = expert_rows(xs, p["mlp.experts.gate_proj.weight"], m)
+            return (x, h, xs, *rest), counts
 
         def combined(x, h, y, order, keep, weights):
             return x + moe_combine(h, y, order, keep, weights, p), None
@@ -455,8 +496,8 @@ def decoder_layer(x, p, cfg: DeepseekV3Config, cos, sin, attend, live,
             return rowwise(dense)(x, o_lat)
         (x, h, xs, order, keep, weights), (mine, sizes) = rowwise(routed)(
             x, o_lat, live)
-        x, _ = rowwise(combined)(x, h, moe_experts(xs, mine, p), order, keep,
-                                 weights)
+        x, _ = rowwise(combined)(x, h, moe_experts(xs, mine, p, rowwise, m),
+                                 order, keep, weights)
         return x, sizes
 
 

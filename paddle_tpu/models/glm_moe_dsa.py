@@ -235,7 +235,7 @@ def select(scores, pos, k: int):
 # --- the layer ----------------------------------------------------------------------
 
 def decoder_layer(x, p, cfg: GlmMoeDsaConfig, kind: str, cos, sin, index,
-                  attend, carried, live, rowwise: Callable = dsv3.whole):
+                  attend, carried, live, rowwise: Callable = None):
     """One decoder layer on rows `x [T, H]` (`p`: the layer's weights by
     their names under `model.layers.<i>.`). Returns `(x, tokens_per_expert
     [E] | None, selection)`.
@@ -248,8 +248,15 @@ def decoder_layer(x, p, cfg: GlmMoeDsaConfig, kind: str, cos, sin, index,
     before it, to `attend` and on. What a selection is made of is theirs.
     The rest is `deepseek_v3.decoder_layer`: row-wise segments that
     `rowwise` may run over fewer rows, and the held experts' grouped
-    matmuls."""
+    matmuls. An `index` that scores with a kernel which takes the packed
+    buffers as they are carries `index.pack(q_i, w) -> (q_i, w)`, that
+    kernel's row-wise `prepare`, applied by the query segment to the rows
+    it made."""
     eps = cfg.rms_norm_eps
+    t = x.shape[0]
+    m = t * cfg.num_experts_per_tok
+    rowwise = rowwise or dsv3.whole(t)
+    pack = getattr(index, "pack", None)
     with _scope("llama.layer"):
         def query(x, cos, sin):
             with _scope("llama.rms_norm"):
@@ -263,8 +270,10 @@ def decoder_layer(x, p, cfg: GlmMoeDsaConfig, kind: str, cos, sin, index,
             if kind != FULL:
                 return (q_abs, rows), None
             with _scope("llama.dsa_index"):
-                return (q_abs, rows) + index_inputs(h, c_q, p, cfg, cos,
-                                                    sin), None
+                q_i, k_i, w = index_inputs(h, c_q, p, cfg, cos, sin)
+                if pack:
+                    q_i, w = pack(q_i, w)
+            return (q_abs, rows, q_i, k_i, w), None
 
         def attended(x, o_lat):
             x = x + dsv3.mla_output(o_lat, p, cfg, x.dtype)
@@ -281,8 +290,9 @@ def decoder_layer(x, p, cfg: GlmMoeDsaConfig, kind: str, cos, sin, index,
 
         def routed(x, o_lat, live):
             x, h = attended(x, o_lat)
-            sorted_rows, counts = dsv3.moe_dispatch(h, p, cfg, live, cfg.held)
-            return (x, h) + sorted_rows, counts
+            (xs, *rest), counts = dsv3.moe_dispatch(h, p, cfg, live, cfg.held)
+            xs = dsv3.expert_rows(xs, p["mlp.experts.gate_proj.weight"], m)
+            return (x, h, xs, *rest), counts
 
         def combined(x, h, y, order, keep, weights):
             return x + dsv3.moe_combine(h, y, order, keep, weights, p), None
@@ -297,8 +307,9 @@ def decoder_layer(x, p, cfg: GlmMoeDsaConfig, kind: str, cos, sin, index,
             return x, None, carried
         (x, h, xs, order, keep, weights), (mine, sizes) = rowwise(routed)(
             x, o_lat, live)
-        x, _ = rowwise(combined)(x, h, dsv3.moe_experts(xs, mine, p), order,
-                                 keep, weights)
+        x, _ = rowwise(combined)(
+            x, h, dsv3.moe_experts(xs, mine, p, rowwise, m), order, keep,
+            weights)
         return x, sizes, carried
 
 

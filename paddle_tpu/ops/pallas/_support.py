@@ -91,6 +91,53 @@ def pallas_call(*args, name: str, **kwargs):
     return wrapped
 
 
+def blank(shape, dtype):
+    """A buffer nobody has written: whatever the allocation held on the TPU
+    (`jax.lax.empty`, an `AllocateBuffer` there), zeros elsewhere. The ONE
+    maker of every packed buffer's guard rows and of every output a kernel
+    writes only in part: a test that fills it with NaN shows who reads a
+    row nobody computed."""
+    import jax
+
+    return jax.lax.empty(tuple(shape), dtype)
+
+
+class Packed:
+    """Rows on their way to a kernel that takes the packed buffer whole:
+    `rows [n * slots, ...]`, the kernel's own dtype and columns already
+    (its `prepare`), and the `spare` rows its buffer holds past the last
+    slot's (what a DMA may run over, the rest of a row tile). `place` makes
+    the buffer."""
+
+    def __init__(self, rows, spare: int):
+        self.rows, self.spare = rows, spare
+
+
+def place(outs, slots: int, of: int):
+    """The packed buffers of `of` token slots that hold `outs`, a pytree of
+    the rows of the first `slots` slots (arrays, or `Packed` with spare
+    rows): each at the top of a `blank`, so the rows after them hold
+    whatever. A leaf that already fills its buffer is returned as it is."""
+    import jax
+    from jax.experimental.layout import Layout, with_layout_constraint
+
+    def one(out):
+        rows, spare = (out.rows, out.spare) if isinstance(out, Packed) \
+            else (out, 0)
+        total = rows.shape[0] // slots * of + spare
+        if total == rows.shape[0]:
+            return rows
+        # the rows in the buffer's own (row-major) layout BEFORE they are
+        # placed: left to itself the TPU compiler gives the buffer the
+        # layout of what made the rows and re-lays the whole of it after
+        rows = with_layout_constraint(
+            rows, Layout(major_to_minor=tuple(range(rows.ndim))))
+        return jax.lax.dynamic_update_slice_in_dim(
+            blank((total,) + rows.shape[1:], rows.dtype), rows, 0, 0)
+
+    return jax.tree.map(one, outs, is_leaf=lambda o: isinstance(o, Packed))
+
+
 def float_dtype_ok(dtype) -> bool:
     """The float dtypes every gate here admits. float16 is out: Mosaic
     for the v5e refuses f16 vectors in each of these kernels ("Invalid

@@ -161,32 +161,36 @@ def _index_kernel(layer_ref, kv_lens_ref, q_lens_ref, q_starts_ref,
         lane(_INDEX_Q_CHUNK)
 
 
-def dsa_index_scores(q_i, w, pool, layer, block_tables, kv_lens, tok_lane,
-                     tok_pos):
-    """The indexer's scores of a packed ragged batch over the index pool.
+def index_prepare(q_i, w, pool) -> tuple:
+    """Row-wise, before the rows are placed: the indexer's queries `q_i [n,
+    heads, D]` in the pool's dtype and its head weights `w [n, heads]` as
+    float32 `[n, heads, 128]` (a weight a lane tile, as the kernel reads
+    them), each with the spare chunk a lane's last chunk's DMA may run
+    over: two `Packed`."""
+    n, heads = w.shape
+    return (_support.Packed(q_i.astype(pool.dtype), _INDEX_Q_CHUNK),
+            _support.Packed(jnp.broadcast_to(
+                w.astype(jnp.float32)[:, :, None], (n, heads, 128)),
+                _INDEX_Q_CHUNK))
 
-    Args:
-      q_i: `[T, heads, D]` packed indexer queries (lane-major, as
-        `ragged_metadata` packs them); w: `[T, heads]` float32 head weights.
-      pool: `[L, NB, BS, D]` index keys, read as stored; `layer`: which `L`.
-      block_tables `[B, W]`, kv_lens `[B]` (this dispatch's tokens
-        included), tok_lane / tok_pos `[T]`: as `paged_attention_ragged`.
-    Returns `[T + 8, S / 128, 128]` float32, `S >= W * BS` (whole page
-    groups): row t's `I[t, s]` at `[t, s // 128, s % 128]` for every
-    position `s` of its lane up to its own; what lies past that, and the 8
-    spare rows, is NOT defined. `score_tile` cuts rows of it.
-    """
-    tokens, heads, d = q_i.shape
+
+def dsa_index_scores_packed(q, wrep, pool, layer, block_tables, kv_lens,
+                            tok_lane, tok_pos):
+    """`dsa_index_scores` on the buffers as the kernel takes them: q `[T +
+    8, heads, D]` and wrep `[T + 8, heads, 128]` (`index_prepare`, placed;
+    `T` is `tok_lane`'s). Nothing is padded or filled."""
+    tokens = tok_lane.shape[0]
+    _, heads, d = q.shape
     block_size = pool.shape[2]
     lanes, width = block_tables.shape
     pages = _index_pages(block_size, width)
     cols = pages * block_size
     groups = -(-width // pages)
     qc = _INDEX_Q_CHUNK
-    q = jnp.pad(q_i.astype(pool.dtype), ((0, qc), (0, 0), (0, 0)))
-    wrep = jnp.broadcast_to(
-        jnp.pad(w.astype(jnp.float32), ((0, qc), (0, 0)))[:, :, None],
-        (tokens + qc, heads, 128))
+    if q.shape[0] != tokens + qc or wrep.shape != (tokens + qc, heads, 128) \
+            or q.dtype != pool.dtype:
+        raise ValueError(f"dsa_index_scores_packed: q {q.dtype}{q.shape}, "
+                         f"w {wrep.shape} are not a placed `index_prepare`")
     q_lens, q_starts = lane_spans(tok_lane, tok_pos, lanes)
     hbm = pl.BlockSpec(memory_space=pl.ANY)
     out_shape = (tokens + qc, groups * cols // 128, 128)
@@ -213,6 +217,28 @@ def dsa_index_scores(q_i, w, pool, layer, block_tables, kv_lens, tok_lane,
         interpret=_support.interpret_mode(),
     )(jnp.asarray(layer, jnp.int32).reshape(1), kv_lens.astype(jnp.int32),
       q_lens, q_starts, block_tables.astype(jnp.int32), q, wrep, pool)
+
+
+def dsa_index_scores(q_i, w, pool, layer, block_tables, kv_lens, tok_lane,
+                     tok_pos):
+    """The indexer's scores of a packed ragged batch over the index pool.
+
+    Args:
+      q_i: `[T, heads, D]` packed indexer queries (lane-major, as
+        `ragged_metadata` packs them); w: `[T, heads]` float32 head weights.
+      pool: `[L, NB, BS, D]` index keys, read as stored; `layer`: which `L`.
+      block_tables `[B, W]`, kv_lens `[B]` (this dispatch's tokens
+        included), tok_lane / tok_pos `[T]`: as `paged_attention_ragged`.
+    Returns `[T + 8, S / 128, 128]` float32, `S >= W * BS` (whole page
+    groups): row t's `I[t, s]` at `[t, s // 128, s % 128]` for every
+    position `s` of its lane up to its own; what lies past that, and the 8
+    spare rows, is NOT defined. `score_tile` cuts rows of it.
+    `index_prepare`, placed, through `dsa_index_scores_packed`.
+    """
+    tokens = q_i.shape[0]
+    return dsa_index_scores_packed(
+        *_support.place(index_prepare(q_i, w, pool), tokens, tokens), pool,
+        layer, block_tables, kv_lens, tok_lane, tok_pos)
 
 
 def score_tile(scores, r0, rows: int, positions: int):

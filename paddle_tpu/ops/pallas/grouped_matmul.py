@@ -57,21 +57,39 @@ def _kernel(offsets_ref, groups_ref, tiles_ref, lhs_ref, rhs_ref, out_ref, *,
     out_ref[...] = jnp.where(mine, acc.astype(out_ref.dtype), kept)
 
 
-def grouped_matmul(lhs, rhs, group_sizes, out_dtype=jnp.float32):
-    """lhs `[M, K]` (rows sorted by group, the groups' rows first), rhs
-    `[G, K, N]`, group_sizes `[G]` int32 with a sum of at most M. Returns
-    `[M, N]`: row r of group g is `lhs[r] @ rhs[g]`; rows of a visited tile
-    that belong to no group are zeros, rows of a tile no group reaches are
-    never written."""
-    m, k = lhs.shape
+def _row_tile(m):
+    return min(_ROW_TILE, -(-m // 8) * 8)
+
+
+def buffer_rows(m: int) -> int:
+    """Rows of the buffer the kernel takes for `m` rows: whole row tiles."""
+    return -(-m // _row_tile(m)) * _row_tile(m)
+
+
+def prepare(lhs, rhs, m: int) -> _support.Packed:
+    """Row-wise, before the rows are placed: `lhs`, all or the first of `m`
+    sorted rows, in `rhs`'s dtype, with the spare rows that make `m` whole
+    row tiles."""
+    return _support.Packed(lhs.astype(rhs.dtype), buffer_rows(m) - m)
+
+
+def grouped_matmul_packed(lhs, rhs, group_sizes, out_dtype=jnp.float32):
+    """`grouped_matmul` on the buffer as the kernel takes it and leaves it:
+    lhs `[buffer_rows(M), K]` in rhs's dtype (`prepare`, placed), the
+    groups' rows first. Returns `[buffer_rows(M), N]`: row r of group g is
+    `lhs[r] @ rhs[g]`; rows of a visited tile that belong to no group are
+    zeros, rows of a tile no group reaches are never written and hold
+    whatever. Nothing is padded, filled or cut."""
+    mp, k = lhs.shape
     groups, _, n = rhs.shape
-    tm = min(_ROW_TILE, -(-m // 8) * 8)
+    tm = _row_tile(mp)
     tn = _col_tile(k, n, rhs.dtype.itemsize)
     if tn is None:
         raise ValueError(f"grouped_matmul: no block of rhs [{k}, {n}] fits; "
                          "ask supported first")
-    mp = -(-m // tm) * tm
-    lhs = jnp.pad(lhs.astype(rhs.dtype), ((0, mp - m), (0, 0)))
+    if mp % tm or lhs.dtype != rhs.dtype:
+        raise ValueError(f"grouped_matmul_packed: lhs {lhs.dtype}[{mp}, {k}] "
+                         "is not a placed `prepare`")
     with _support.x64_off():
         (offsets, group_ids, tile_ids), pairs = make_group_metadata(
             group_sizes=group_sizes.astype(jnp.int32), m=mp, tm=tm,
@@ -88,7 +106,7 @@ def grouped_matmul(lhs, rhs, group_sizes, out_dtype=jnp.float32):
         out_specs=pl.BlockSpec((tm, tn),
                                lambda j, w, off, gid, tid: (tid[w], j)),
     )
-    out = _support.pallas_call(
+    return _support.pallas_call(
         functools.partial(_kernel, tm=tm),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((mp, n), out_dtype),
@@ -97,7 +115,19 @@ def grouped_matmul(lhs, rhs, group_sizes, out_dtype=jnp.float32):
         name="moe_grouped_matmul",
         interpret=_support.interpret_mode(),
     )(offsets, group_ids, tile_ids, lhs, rhs)
-    return out[:m]
+
+
+def grouped_matmul(lhs, rhs, group_sizes, out_dtype=jnp.float32):
+    """lhs `[M, K]` (rows sorted by group, the groups' rows first), rhs
+    `[G, K, N]`, group_sizes `[G]` int32 with a sum of at most M. Returns
+    `[M, N]`: row r of group g is `lhs[r] @ rhs[g]`; rows of a visited tile
+    that belong to no group are zeros, rows of a tile no group reaches are
+    never written. `prepare`, placed, through `grouped_matmul_packed`, and
+    the first M rows of what it left."""
+    m = lhs.shape[0]
+    return grouped_matmul_packed(
+        _support.place(prepare(lhs, rhs, m), m, m), rhs, group_sizes,
+        out_dtype)[:m]
 
 
 def supported(rhs_shape, dtype) -> bool:

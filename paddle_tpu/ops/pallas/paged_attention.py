@@ -152,9 +152,9 @@ def _ragged_kernel(layer_ref, window_ref, kv_lens_ref, q_lens_ref,
 
     Output rows of a tile's last chunk past the lane's tokens are written
     as zeros; the next lane, processed after it, overwrites the ones it
-    owns, and the output buffer starts zeroed (aliased input), so guard
-    rows come back exact zeros. (A decode lane's chunk is its one token:
-    it writes no row but its own.)
+    owns. A row no lane owns is never written: the output is whatever its
+    allocation held there. (A decode lane's chunk is its one token: it
+    writes no row but its own.)
 
     Quantized KV (`inference/kv_quant.py` layout): K/V arrive as int8 and
     `rest` leads with the lane's per-slot f32 scale rows in logical order
@@ -165,7 +165,7 @@ def _ragged_kernel(layer_ref, window_ref, kv_lens_ref, q_lens_ref,
     if quantized:
         ks_ref, vs_ref = rest[:2]
         rest = rest[2:]
-    _, o_hbm, qbuf, kbuf, vbuf, acc_ref, m_ref, l_ref, sem = rest
+    o_hbm, qbuf, kbuf, vbuf, acc_ref, m_ref, l_ref, sem = rest
     kv_h, d = kbuf.shape[2], kbuf.shape[4]
     cols = pages * block_size             # kv positions of one page group
     i32 = jnp.int32
@@ -335,7 +335,7 @@ def _ragged_call(q, k_cache, v_cache, layer, window, block_tables, kv_lens,
     [L, NB, KV_H, BS, D], `layer` int32 [1], which of them to read, and
     `window` int32 [1], the layer's sliding window (0: none)
     (int8 when the per-lane f32 scale windows [B, KV_H, groups, pages*BS]
-    ride along). Returns f32, q's shape."""
+    ride along). Returns f32, q's shape, written where a lane owns rows."""
     tokens, kv_h, g_pad, d = q.shape
     block_size = k_cache.shape[3]
     lanes = block_tables.shape[0]
@@ -352,9 +352,6 @@ def _ragged_call(q, k_cache, v_cache, layer, window, block_tables, kv_lens,
             (b, 0, 0, 0))
         operands += [k_scale, v_scale]
         in_specs += [scale_rows, scale_rows]
-    # the output buffer starts as zeros: guard rows are never written
-    operands.append(jnp.zeros(q.shape, jnp.float32))
-    in_specs.append(hbm)
     page_buf = pltpu.VMEM((2, pages, kv_h, block_size, d), k_cache.dtype)
     lm = pltpu.VMEM((kv_h, rows, 128), jnp.float32)
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -377,7 +374,6 @@ def _ragged_call(q, k_cache, v_cache, layer, window, block_tables, kv_lens,
                           mxu_dtype=mxu_dtype),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(q.shape, jnp.float32),
-        input_output_aliases={6 + len(operands) - 1: 0},
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         name="paged_attention_ragged",
@@ -426,6 +422,81 @@ def _window_scalar(window):
     return jnp.asarray(0 if window is None else window, jnp.int32)
 
 
+def ragged_prepare(q, kv_heads: int) -> _support.Packed:
+    """Row-wise, before the rows are placed: `q [n, H, D]` as the kernel
+    takes it, f32 `[n, KV_H, Gp, D]` (a token's head-group band is then
+    whole (8, 128) tiles, so a chunk of tokens folds into MXU rows without
+    a relayout; `Gp`: the group's rows padded to whole sublane tiles), and
+    the spare chunk the last live chunk's DMA may run over."""
+    n, h, d = q.shape
+    g = h // kv_heads
+    qg = q.reshape(n, kv_heads, g, d).astype(jnp.float32)
+    return _support.Packed(
+        jnp.pad(qg, ((0, 0), (0, 0), (0, _group_pad(g) - g), (0, 0))),
+        _RAGGED_Q_CHUNK)
+
+
+def ragged_finish(out, heads: int, dtype):
+    """Row-wise, on rows cut from what `paged_attention_ragged_packed`
+    left: f32 `[n, KV_H, Gp, D]` -> `[n, H, D]` in `dtype`."""
+    n, kv_h, _, d = out.shape
+    return out[:, :, :heads // kv_h, :].reshape(n, heads, d).astype(dtype)
+
+
+def paged_attention_ragged_packed(qg, k_cache, v_cache, block_tables, kv_lens,
+                                  tok_lane, tok_pos, sm_scale=None,
+                                  k_scale=None, v_scale=None, layer=None,
+                                  window=None, mxu_bf16: bool = False):
+    """`paged_attention_ragged` on the buffer as the kernel takes it and
+    leaves it: qg f32 `[T + chunk, KV_H, Gp, D]` (`ragged_prepare`, placed;
+    `T` is `tok_lane`'s); `mxu_bf16`: the queries were bfloat16, so beside
+    a bf16 or int8 pool the MXU takes bf16 operands. Returns f32, qg's
+    shape: a live row's answer (`ragged_finish` takes its rows on); a guard
+    row and the spare chunk hold whatever. Nothing is padded, filled or
+    cut."""
+    layer, k_cache, v_cache, k_scale, v_scale = _layered(
+        layer, k_cache, v_cache, k_scale, v_scale)
+    tokens = tok_lane.shape[0]
+    _, kv_h, g_pad, d = qg.shape
+    block_size = k_cache.shape[3]
+    lanes, width = block_tables.shape
+    if sm_scale is None:
+        sm_scale = 1.0 / float(np.sqrt(d))
+    tiles = _ragged_tiles(tokens, kv_h, g_pad, d, block_size, width,
+                          k_cache.dtype.itemsize)
+    if tiles is None:
+        raise ValueError(
+            f"paged_attention_ragged: no tile of {kv_h} kv heads x {g_pad} "
+            f"rows x {d} fits VMEM; ask ragged_supported first")
+    if qg.shape[0] != tokens + _RAGGED_Q_CHUNK or qg.dtype != jnp.float32:
+        raise ValueError(f"paged_attention_ragged_packed: q {qg.dtype}"
+                         f"{qg.shape} is not a placed `ragged_prepare`")
+    pages, _ = tiles
+    q_lens, q_starts = lane_spans(tok_lane, tok_pos, lanes)
+    block_tables = block_tables.astype(jnp.int32)
+    if k_scale is not None:
+        # the lane's scale rows in logical order, [B, KV_H, groups,
+        # pages*BS]: one (1, pages*BS) row per page group lies along the
+        # score tile's lanes, which no in-kernel gather of (KV_H, BS)
+        # pieces could give without a relayout. A table-wide gather, but
+        # of planes 1/D the pool's size (and of this layer's alone).
+        groups = -(-width // pages)
+        padded = jnp.pad(block_tables, ((0, 0), (0, groups * pages - width)))
+
+        def by_lane(scale):
+            return jnp.swapaxes(scale[layer, padded], 1, 2) \
+                .reshape(lanes, kv_h, groups, pages * block_size)
+
+        k_scale, v_scale = by_lane(k_scale), by_lane(v_scale)
+    exact_bf16 = mxu_bf16 and k_cache.dtype in (jnp.bfloat16, jnp.int8)
+    return _ragged_call(qg, k_cache, v_cache, layer.reshape(1),
+                        _window_scalar(window).reshape(1), block_tables,
+                        kv_lens.astype(jnp.int32), q_lens, q_starts,
+                        float(sm_scale), tiles,
+                        jnp.bfloat16 if exact_bf16 else jnp.float32,
+                        k_scale, v_scale)
+
+
 def paged_attention_ragged(q, k_cache, v_cache, block_tables, kv_lens,
                            tok_lane, tok_pos, sm_scale=None,
                            k_scale=None, v_scale=None, layer=None,
@@ -470,55 +541,18 @@ def paged_attention_ragged(q, k_cache, v_cache, block_tables, kv_lens,
          `i - window < j <= i`, and table entries of pages wholly behind
          the window of the lane's first query are never read (the cache
          manager has released them). None or 0: every key up to i.
-    Returns [T, H, D]; guard rows are exact zeros.
+    Returns [T, H, D]; guard rows are exact zeros. `ragged_prepare`,
+    placed, through `paged_attention_ragged_packed`, and `ragged_finish`
+    of what it left on the live rows.
     """
-    layer, k_cache, v_cache, k_scale, v_scale = _layered(
-        layer, k_cache, v_cache, k_scale, v_scale)
-    tokens, h, d = q.shape
-    kv_h, block_size = k_cache.shape[2:4]
-    lanes, width = block_tables.shape
-    g = h // kv_h
-    if sm_scale is None:
-        sm_scale = 1.0 / float(np.sqrt(d))
-    g_pad = _group_pad(g)
-    tiles = _ragged_tiles(tokens, kv_h, g_pad, d, block_size, width,
-                          k_cache.dtype.itemsize)
-    if tiles is None:
-        raise ValueError(
-            f"paged_attention_ragged: no tile of {kv_h} kv heads x {g_pad} "
-            f"rows x {d} fits VMEM; ask ragged_supported first")
-    pages, _ = tiles
-    # q crosses into the kernel as f32 [T + chunk, KV_H, Gp, D]: a token's
-    # head-group band is then whole (8, 128) tiles, so a chunk of tokens
-    # folds into MXU rows without a relayout; the spare chunk is what the
-    # last live chunk's DMA may run over
-    qg = q.reshape(tokens, kv_h, g, d).astype(jnp.float32)
-    qg = jnp.pad(qg, ((0, _RAGGED_Q_CHUNK), (0, 0), (0, g_pad - g), (0, 0)))
-    q_lens, q_starts = lane_spans(tok_lane, tok_pos, lanes)
-    block_tables = block_tables.astype(jnp.int32)
-    if k_scale is not None:
-        # the lane's scale rows in logical order, [B, KV_H, groups,
-        # pages*BS]: one (1, pages*BS) row per page group lies along the
-        # score tile's lanes, which no in-kernel gather of (KV_H, BS)
-        # pieces could give without a relayout. A table-wide gather, but
-        # of planes 1/D the pool's size (and of this layer's alone).
-        groups = -(-width // pages)
-        padded = jnp.pad(block_tables, ((0, 0), (0, groups * pages - width)))
-
-        def by_lane(scale):
-            return jnp.swapaxes(scale[layer, padded], 1, 2) \
-                .reshape(lanes, kv_h, groups, pages * block_size)
-
-        k_scale, v_scale = by_lane(k_scale), by_lane(v_scale)
-    exact_bf16 = q.dtype == jnp.bfloat16 and k_cache.dtype in (
-        jnp.bfloat16, jnp.int8)
-    out = _ragged_call(qg, k_cache, v_cache, layer.reshape(1),
-                       _window_scalar(window).reshape(1), block_tables,
-                       kv_lens.astype(jnp.int32), q_lens, q_starts,
-                       float(sm_scale), tiles,
-                       jnp.bfloat16 if exact_bf16 else jnp.float32,
-                       k_scale, v_scale)
-    return out[:tokens, :, :g, :].reshape(tokens, h, d).astype(q.dtype)
+    tokens, h, _ = q.shape
+    kv_h = k_cache.shape[-3]
+    out = paged_attention_ragged_packed(
+        _support.place(ragged_prepare(q, kv_h), tokens, tokens), k_cache,
+        v_cache, block_tables, kv_lens, tok_lane, tok_pos, sm_scale, k_scale,
+        v_scale, layer, window, q.dtype == jnp.bfloat16)
+    return jnp.where((tok_pos >= 0)[:, None, None],
+                     ragged_finish(out[:tokens], h, q.dtype), 0)
 
 
 # above this many packed tokens the ref tiles its per-token window

@@ -11,7 +11,7 @@ buffer whose minor dimension is not whole lane tiles, and XLA lays a
 576-wide bf16 pool out with ANOTHER dimension minor (which the kernel could
 only read after a relayout of the whole pool). So the pool's owner pads a
 row with zero columns to the next 128 (576 -> 640); q is padded to match
-here, and zero columns add nothing to a score.
+(`mla_prepare`), and zero columns add nothing to a score.
 
 Built as `paged_attention_ragged` is (`paged_attention.py`, whose packing,
 metadata and online-softmax step it shares): grid = (lanes,), everything
@@ -65,7 +65,7 @@ def _mla_tiles(tokens, heads, dk, dv, block_size, width, itemsize):
 
 
 def _mla_kernel(layer_ref, kv_lens_ref, q_lens_ref, q_starts_ref, tables_ref,
-                q_hbm, pool_hbm, _, o_hbm, qbuf, kbuf, acc_ref, obuf, m_ref,
+                q_hbm, pool_hbm, o_hbm, qbuf, kbuf, acc_ref, obuf, m_ref,
                 l_ref, sem, *, sm_scale, block_size, pages, q_tile, v_dim):
     """See the module docstring. Lane b owns the packed query tokens
     [q_start, q_start + q_len), the first at absolute position kv_len -
@@ -76,8 +76,8 @@ def _mla_kernel(layer_ref, kv_lens_ref, q_lens_ref, q_starts_ref, tables_ref,
     v_dim]` update on the SAME page buffer, online softmax in f32, the
     probabilities fed to the MXU in the pool's own dtype. Rows of a tile's
     last chunk past the lane's tokens are written as zeros and overwritten
-    by the next lane; the output starts zeroed (aliased), so guard rows come
-    back exact zeros."""
+    by the next lane; a row no lane owns is never written (the output is
+    whatever its allocation held there)."""
     heads = qbuf.shape[1]
     cols = pages * block_size
     i32 = jnp.int32
@@ -208,17 +208,16 @@ def _mla_kernel(layer_ref, kv_lens_ref, q_lens_ref, q_starts_ref, tables_ref,
 def _mla_call(q, pool, layer, block_tables, kv_lens, q_lens, q_starts,
               sm_scale, tiles, v_dim):
     """q `[T + chunk, H, DK]` in the MXU's dtype; the pool as stored. Returns
-    `[T + chunk, H, v_dim]` in q's dtype."""
+    `[T + chunk, H, v_dim]` in q's dtype, written where a lane owns rows."""
     _, heads, dk = q.shape
     block_size = pool.shape[2]
     lanes = block_tables.shape[0]
     pages, q_tile = tiles
-    out_shape = q.shape[:2] + (v_dim,)
     hbm = pl.BlockSpec(memory_space=pl.ANY)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=5,
         grid=(lanes,),
-        in_specs=[hbm, hbm, hbm],
+        in_specs=[hbm, hbm],
         out_specs=hbm,
         scratch_shapes=[
             pltpu.VMEM((q_tile, heads, dk), q.dtype),               # q tile
@@ -235,14 +234,50 @@ def _mla_call(q, pool, layer, block_tables, kv_lens, q_lens, q_starts,
                           block_size=block_size, pages=pages, q_tile=q_tile,
                           v_dim=v_dim),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(out_shape, q.dtype),
-        input_output_aliases={7: 0},       # the zeroed output buffer
+        out_shape=jax.ShapeDtypeStruct(q.shape[:2] + (v_dim,), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         name="paged_attention_mla",
         interpret=_support.interpret_mode(),
-    )(layer, kv_lens, q_lens, q_starts, block_tables, q, pool,
-      jnp.zeros(out_shape, q.dtype))
+    )(layer, kv_lens, q_lens, q_starts, block_tables, q, pool)
+
+
+def mla_prepare(q_abs, pool) -> _support.Packed:
+    """Row-wise, before the rows are placed: `q_abs [n, H, <= DK]` in the
+    pool's dtype (the MXU takes it: bf16 products are exact in the f32
+    accumulator) with zero columns up to the pool's `DK`, and the spare
+    chunk a lane's last chunk's DMA may run over."""
+    dk = pool.shape[-1]
+    return _support.Packed(
+        jnp.pad(q_abs.astype(pool.dtype),
+                ((0, 0), (0, 0), (0, dk - q_abs.shape[-1]))), _MLA_Q_CHUNK)
+
+
+def paged_attention_mla_packed(q, pool, layer, block_tables, kv_lens,
+                               tok_lane, tok_pos, v_dim, sm_scale):
+    """`paged_attention_mla` on the buffer as the kernel takes it and leaves
+    it: q `[T + chunk, H, DK]` (`mla_prepare`, placed; `T` is `tok_lane`'s).
+    Returns `[T + chunk, H, v_dim]` in the pool's dtype: a live row's
+    answer; a guard row and the spare chunk hold whatever. Nothing is
+    padded, filled or cut."""
+    tokens = tok_lane.shape[0]
+    heads = q.shape[1]
+    block_size, dk = pool.shape[2:]
+    lanes, width = block_tables.shape
+    tiles = _mla_tiles(tokens, heads, dk, v_dim, block_size, width,
+                       pool.dtype.itemsize)
+    if tiles is None:
+        raise ValueError(
+            f"paged_attention_mla: no tile of {heads} heads x {dk} fits "
+            "VMEM; ask mla_supported first")
+    if q.shape != (tokens + _MLA_Q_CHUNK, heads, dk) or q.dtype != pool.dtype:
+        raise ValueError(f"paged_attention_mla_packed: q {q.dtype}{q.shape} "
+                         "is not a placed `mla_prepare`")
+    q_lens, q_starts = lane_spans(tok_lane, tok_pos, lanes)
+    return _mla_call(q, pool, jnp.asarray(layer, jnp.int32).reshape(1),
+                     block_tables.astype(jnp.int32),
+                     kv_lens.astype(jnp.int32), q_lens, q_starts,
+                     float(sm_scale), tiles, v_dim)
 
 
 def paged_attention_mla(q_abs, pool, layer, block_tables, kv_lens, tok_lane,
@@ -258,27 +293,15 @@ def paged_attention_mla(q_abs, pool, layer, block_tables, kv_lens, tok_lane,
         included), tok_lane / tok_pos `[T]`: as `paged_attention_ragged`.
       v_dim: the leading columns of a row that are its value.
     Returns `[T, H, v_dim]` in q's dtype; guard rows are exact zeros.
+    `mla_prepare`, placed, through `paged_attention_mla_packed`, and of
+    what it left the live rows.
     """
-    tokens, heads, _ = q_abs.shape
-    block_size, dk = pool.shape[2:]
-    lanes, width = block_tables.shape
-    tiles = _mla_tiles(tokens, heads, dk, v_dim, block_size, width,
-                       pool.dtype.itemsize)
-    if tiles is None:
-        raise ValueError(
-            f"paged_attention_mla: no tile of {heads} heads x {dk} fits "
-            "VMEM; ask mla_supported first")
-    # the MXU takes the pool's own dtype (bf16 products are exact in the
-    # f32 accumulator); the spare chunk is what a lane's last chunk's DMA
-    # may run over
-    q = jnp.pad(q_abs.astype(pool.dtype),
-                ((0, _MLA_Q_CHUNK), (0, 0), (0, dk - q_abs.shape[-1])))
-    q_lens, q_starts = lane_spans(tok_lane, tok_pos, lanes)
-    out = _mla_call(q, pool, jnp.asarray(layer, jnp.int32).reshape(1),
-                    block_tables.astype(jnp.int32),
-                    kv_lens.astype(jnp.int32), q_lens, q_starts,
-                    float(sm_scale), tiles, v_dim)
-    return out[:tokens].astype(q_abs.dtype)
+    tokens = q_abs.shape[0]
+    out = paged_attention_mla_packed(
+        _support.place(mla_prepare(q_abs, pool), tokens, tokens), pool,
+        layer, block_tables, kv_lens, tok_lane, tok_pos, v_dim, sm_scale)
+    return jnp.where((tok_pos >= 0)[:, None, None], out[:tokens],
+                     0).astype(q_abs.dtype)
 
 
 # the ref gathers each token's whole window: bound what is live at once

@@ -212,6 +212,39 @@ def test_kernel_against_its_ref(case, rng, interpret):
     assert not np.asarray(got[live:]).any(), "guard rows are exact zeros"
 
 
+@pytest.mark.parametrize("case", sorted(KERNEL_CASES))
+def test_the_buffer_taking_call_answers_the_live_rows(case, rng, interpret,
+                                                      monkeypatch):
+    """ISSUE 49: `mla_prepare`, placed in a blank (here full of NaN),
+    through `paged_attention_mla_packed`: the kernel's own buffer, equal to
+    the public function on the live rows; a guard row and the spare chunk
+    hold whatever (the interpreter's unwritten rows read NaN). The public
+    function, made of the same two, still gives exact zeros there."""
+    from test_live_prefix import poison
+
+    from paddle_tpu.ops.pallas import _support
+
+    q_lens, kv_lens = KERNEL_CASES[case]
+    q, pool, tables, kv_lens, lane, pos = _kernel_inputs(rng, q_lens, kv_lens)
+    want = pm.paged_attention_mla(q, pool, 1, tables, kv_lens, lane, pos, DV,
+                                  0.3)
+    monkeypatch.setattr(_support, "blank", poison)
+    t = q.shape[0]
+    buf = _support.place(pm.mla_prepare(q, pool), t, t)
+    assert buf.shape == (t + 4, H, DK) and np.isnan(buf[t:]).all()
+    got = pm.paged_attention_mla_packed(buf, pool, 1, tables, kv_lens, lane,
+                                        pos, DV, 0.3)
+    live = int(np.sum(q_lens))
+    assert got.shape == (t + 4, H, DV)
+    np.testing.assert_array_equal(got[:live], want[:live])
+    again = pm.paged_attention_mla(q, pool, 1, tables, kv_lens, lane, pos, DV,
+                                   0.3)
+    np.testing.assert_array_equal(again, want)
+    with pytest.raises(ValueError, match="placed"):
+        pm.paged_attention_mla_packed(buf[:t], pool, 1, tables, kv_lens, lane,
+                                      pos, DV, 0.3)
+
+
 def test_kernel_lowers_for_tpu_at_kanana_width(monkeypatch):
     """Pallas' TPU block-shape checks at the cell's shape (no libtpu)."""
     from paddle_tpu.ops.pallas import _support
@@ -247,6 +280,39 @@ def test_grouped_matmul_against_ragged_dot(case, rng, interpret):
                               precision=jax.lax.Precision.HIGHEST)
     live = int(sizes.sum())
     np.testing.assert_allclose(got[:live], want[:live], atol=1e-5, rtol=0)
+
+
+@pytest.mark.parametrize("case", sorted(GROUPED_CASES))
+def test_grouped_matmul_takes_the_buffer_as_it_is(case, rng, interpret,
+                                                  monkeypatch):
+    """ISSUE 49: `prepare`, the first rows of it placed in a blank (here
+    full of NaN: what a decode round leaves past its lanes' assignments),
+    through `grouped_matmul_packed`: whole row tiles in, whole row tiles
+    out, the groups' rows the public function's; and a buffer that already
+    is whole tiles passes through the public function untouched."""
+    from test_live_prefix import poison
+
+    from paddle_tpu.ops.pallas import _support
+
+    m, k, n, sizes = GROUPED_CASES[case]
+    x = jnp.asarray(rng.normal(size=(m, k)), jnp.float32)
+    w = jnp.asarray(rng.normal(size=(len(sizes), k, n)), jnp.float32)
+    live = int(np.sum(sizes))
+    sizes = jnp.asarray(sizes, jnp.int32)
+    want = gm.grouped_matmul(x, w, sizes)
+    monkeypatch.setattr(_support, "blank", poison)
+    # the rows the experts were given, and nothing after them
+    head = -(-live // 8) * 8
+    buf = _support.place(gm.prepare(x[:head], w, m), head, m)
+    assert buf.shape == (gm.buffer_rows(m), k) and buf.shape[0] % 8 == 0
+    assert np.isnan(buf[head:]).all()
+    got = gm.grouped_matmul_packed(buf, w, sizes)
+    assert got.shape == (gm.buffer_rows(m), n)
+    np.testing.assert_array_equal(got[:live], want[:live])
+    np.testing.assert_array_equal(gm.grouped_matmul(buf, w, sizes), got)
+    if gm.buffer_rows(m) != m:
+        with pytest.raises(ValueError, match="placed"):
+            gm.grouped_matmul_packed(x, w, sizes)
 
 
 def test_grouped_matmul_lowers_for_tpu_at_kanana_width(monkeypatch):

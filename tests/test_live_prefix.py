@@ -11,7 +11,13 @@ chosen on the device from `sum(q_lens)`. On the CPU at toy widths, with
 - `narrow_steps`, `steps` and the gauge `serving.step.live_prefix_share` read
   what the rounds' `q_lens` say;
 - the helper alone: row outputs are the whole function's on the live prefix
-  and exact zeros after it, the others are equal;
+  and WHATEVER after it (the blank's own fill, here made NaN), the others are
+  equal;
+- nothing reads a blank row: the backlog served with every blank buffer full
+  of NaN (`ops/pallas/_support.blank`, the one maker of them), by the XLA
+  composites and by the kernels that take and leave the packed buffers as they
+  are (the interpreter's unwritten output rows are NaN too), gives the same
+  tokens and moves no fault, retrace or restart counter;
 - the fault probe's one-lane replay and `verify_step` give the rows they gave.
 """
 import types
@@ -21,12 +27,13 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from paddle_tpu.framework import monitor
+from paddle_tpu.framework import flags, monitor
 from paddle_tpu.inference import live_prefix
 from paddle_tpu.inference.cohere2_moe_runner import Cohere2MoeInferenceEngine
 from paddle_tpu.inference.deepseek_v3_runner import DeepseekV3InferenceEngine
 from paddle_tpu.models import cohere2_moe as c2
 from paddle_tpu.models import deepseek_v3 as dsv3
+from paddle_tpu.ops.pallas import _support
 from paddle_tpu.serving import RequestStatus, ServingFrontend
 
 LANES, CHUNK, BS, WIDTH, WINDOW = 4, 16, 8, 16, 24
@@ -97,6 +104,17 @@ def serve(arch, num_blocks):
     return eng, prompts, handles
 
 
+def poison(shape, dtype):
+    """`_support.blank` for a test: a buffer nobody has written reads NaN
+    (the least integer, True), so whoever reads a row of it shows."""
+    dtype = jnp.dtype(dtype)
+    if jnp.issubdtype(dtype, jnp.floating):
+        return jnp.full(shape, jnp.nan, dtype)
+    if dtype == jnp.bool_:
+        return jnp.ones(shape, dtype)
+    return jnp.full(shape, jnp.iinfo(dtype).min, dtype)
+
+
 @pytest.fixture(scope="module")
 def backlog(arch):
     """The backlog served twice by the engine as it is (roomy, and in a pool
@@ -110,7 +128,7 @@ def backlog(arch):
     tight_eng, _, tight = serve(arch, 10)
     with pytest.MonkeyPatch.context() as mp:
         mp.setattr(live_prefix, "rowwise", lambda n_live, narrow, t:
-                   dsv3.whole)
+                   dsv3.whole(t))
         _, _, unswitched = serve(arch, LANES * WIDTH + 1)
     return types.SimpleNamespace(
         eng=eng, prompts=prompts, roomy=roomy, tight=tight,
@@ -163,10 +181,43 @@ def test_counters_read_what_the_rounds_q_lens_say(backlog):
     assert backlog.gauge == round(load["narrow_steps"] / load["steps"], 4)
 
 
+FAULTS = ("serving.step_faults", "serving.isolated_faults",
+          "serving.engine_restarts", "serving.state.restarts")
+
+
+@pytest.mark.parametrize("kernels", [False, True],
+                         ids=["composites", "kernels"])
+def test_nothing_reads_a_blank_row(arch, backlog, kernels):
+    """ISSUE 49: a guard row of a packed buffer holds whatever. With every
+    blank full of NaN the backlog (chunked, decode-only and short rounds)
+    gives the tokens it gave, through the XLA composites and through the
+    kernels' buffer-taking calls (the interpreter: a kernel's unwritten
+    output rows are NaN there too); one executable, no fault, no restart."""
+    before = {k: monitor.get(k) or 0 for k in FAULTS + (
+        "serving.ragged_retraces",)}
+    flags.set_flags({"pallas_interpret": kernels})
+    try:
+        with pytest.MonkeyPatch.context() as mp:
+            mp.setattr(_support, "blank", poison)
+            eng, _, served = serve(arch, LANES * WIDTH + 1)
+    finally:
+        flags.set_flags({"pallas_interpret": False})
+    assert [h.tokens for h in served] == [h.tokens for h in backlog.roomy]
+    assert all(h._req.num_preemptions == 0 for h in served)
+    moved = {k: (monitor.get(k) or 0) - v for k, v in before.items()}
+    assert moved == dict.fromkeys(FAULTS, 0) | {"serving.ragged_retraces": 1}
+    load = eng.expert_load()
+    assert load["steps"] == len(eng.q_lens) == backlog.load["steps"]
+    assert load["tokens"].tolist() == backlog.load["tokens"].tolist()
+
+
+@pytest.mark.parametrize("blank", ["zeros", "poisoned"])
 @pytest.mark.parametrize("n_live", [LANES, LANES + 1])
-def test_helper_alone(arch, n_live):
+def test_helper_alone(arch, n_live, blank, monkeypatch):
     """The expert layer's feed-forward as the wrapped function: `out` is a
     row output, `tokens_per_expert` is not."""
+    if blank == "poisoned":
+        monkeypatch.setattr(_support, "blank", poison)
     rng = np.random.default_rng(n_live)
     h = jnp.asarray(rng.standard_normal((T, arch.cfg.hidden_size)),
                     jnp.float32)
@@ -198,8 +249,10 @@ def test_helper_alone(arch, n_live):
                                atol=1e-6)
     np.testing.assert_array_equal(xs[:n_live * k], want_xs[:n_live * k])
     if n_live <= LANES:
-        assert not np.asarray(got[LANES:]).any(), "exact zeros, not small"
-        assert not np.asarray(xs[LANES * k:]).any()
+        # past the prefix nobody computed a row: the blank's own fill
+        fill = np.isnan if blank == "poisoned" else np.logical_not
+        assert fill(np.asarray(got[LANES:])).all()
+        assert fill(np.asarray(xs[LANES * k:])).all()
     else:
         # the whole function: a guard row still gets its shared experts
         np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-6)
@@ -212,7 +265,6 @@ def test_helper_alone(arch, n_live):
                                                       t)(fn)(h, live))(
                                                           h, live))
     assert switches(LANES) and not switches(T) and not switches(None)
-    assert live_prefix.rowwise(jnp.int32(1), None, T)(fn) is fn
 
 
 def test_probe_replay_and_verify_give_the_rows_they_gave(arch):
@@ -258,5 +310,5 @@ def test_probe_replay_and_verify_give_the_rows_they_gave(arch):
     assert (load["steps"], load["narrow_steps"]) == (7, 4)  # verify: no switch
     with pytest.MonkeyPatch.context() as mp:
         mp.setattr(live_prefix, "rowwise", lambda n_live, narrow, t:
-                   dsv3.whole)
+                   dsv3.whole(t))
         np.testing.assert_allclose(got, rows_of(build(arch)), rtol=1e-5, atol=1e-6)

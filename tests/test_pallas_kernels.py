@@ -649,6 +649,149 @@ def v5e_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
+def _compile_for_v5e(step, args, donate):
+    """`(lowered, compiled)` of `step` over abstract `args` for the described
+    chip their shardings name, the kernels taken as on a TPU. (A TPU
+    executable cannot be read back from the persistent cache here.)"""
+    import jax
+
+    from paddle_tpu.ops.pallas import _support
+
+    cache_was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        with pytest.MonkeyPatch.context() as mp:
+            mp.setattr(_support, "backend", lambda: "tpu")
+            lowered = jax.jit(step, donate_argnums=donate).trace(*args).lower(
+                lowering_platforms=("tpu",))
+            return lowered, lowered.compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache_was)
+
+
+_HLO_SIZES = {"bf16": 2, "f32": 4, "s32": 4, "u32": 4, "pred": 1, "s8": 1,
+              "u8": 1, "f16": 2, "s64": 8, "u64": 8, "s16": 2, "u16": 2}
+_HLO_FREE = ("parameter", "get-tuple-element", "tuple", "bitcast",
+             "conditional", "while", "constant", "optimization-barrier")
+
+
+def _hlo_bytes(shape):
+    """Bytes of every array in an HLO shape string (a tuple's summed)."""
+    import re
+
+    return sum(_HLO_SIZES[dt] * int(np.prod([int(d) for d in dims.split(",")
+                                             if d] or [1]))
+               for dt, dims in re.findall(r"\b(\w+)\[([\d,]*)\]", shape)
+               if dt in _HLO_SIZES)
+
+
+def _hlo_computations(text):
+    """name -> [(root?, name, shape, opcode, operand names, the line)] of a
+    compiled module's text, and the entry computation's name."""
+    import re
+
+    instr = re.compile(r"^\s*(ROOT )?%([\w.-]+) = (.*?) ([\w-]+)\((.*)$")
+    comps, name, entry = {}, None, None
+    for line in text.splitlines():
+        head = re.match(r"(ENTRY )?%([\w.-]+) \(", line)
+        if head and line.rstrip().endswith("{"):
+            name = head.group(2)
+            comps[name] = []
+            entry = name if head.group(1) else entry
+            continue
+        m = instr.match(line)
+        if m and name is not None:
+            root, iname, shape, op, rest = m.groups()
+            depth, end = 1, len(rest)
+            for i, ch in enumerate(rest):
+                depth += (ch == "(") - (ch == ")")
+                if depth == 0:
+                    end = i
+                    break
+            comps[name].append((bool(root), iname, shape, op,
+                                re.findall(r"%([\w.-]+)", rest[:end]), line))
+    return comps, entry
+
+
+def moved_bytes(text, floor=2 << 20):
+    """What a decode round of a compiled served step (`jit(step)(params,
+    pools, ...)`, its HLO `text`) moves that is neither a kernel, a weight
+    nor a pool: over ENTRY and the narrow branch of every live-prefix
+    switch, each instruction whose output is `floor` bytes or more, as
+    `(computation, name, opcode, shape, bytes)`. Left out: a kernel
+    (`tpu_custom_call`) and a blank's allocation; what is made of the
+    step's weights alone (argument 0: a weight's own re-layout, its
+    prefetch); an in-place update of a donated pool (argument 1: its
+    scatter); and of an in-place update of a blank buffer (a
+    `dynamic-update-slice`, alone or as a fusion's root) all but the rows
+    it writes."""
+    import re
+
+    comps, entry = _hlo_computations(text)
+
+    def update_bytes(comp):
+        """What a fused computation whose root is a dynamic-update-slice
+        (or a tuple with some) writes."""
+        shapes = {n: s for _r, n, s, _o, _a, _l in comps[comp]}
+        ops = {n: (o, a) for _r, n, _s, o, a, _l in comps[comp]}
+        root = next(i for i in comps[comp] if i[0])
+        return sum(_hlo_bytes(shapes[ops[n][1][1]]
+                              if ops[n][0] == "dynamic-update-slice"
+                              else shapes[n])
+                   for n in (root[4] if root[3] == "tuple" else [root[1]]))
+
+    found = []
+
+    def walk(comp, given):
+        """`given`: what the computation's tuple parameter's elements are
+        (None in ENTRY, whose parameters say it by their names)."""
+        kind, shapes = {}, {}       # name -> "weight" | "pool" | None
+        for _root, name, shape, op, args, line in comps[comp]:
+            shapes[name] = shape
+            if op == "parameter":
+                arg = re.match(r"args_(\d)_", name)
+                kind[name] = {"0": "weight", "1": "pool"}.get(
+                    arg.group(1) if arg else None, given)
+                continue
+            kinds = [kind.get(a) for a in args]
+            if op == "get-tuple-element":
+                kind[name] = kinds[0][int(re.search(
+                    r"index=(\d+)", line).group(1))] if isinstance(
+                        kinds[0], list) else kinds[0]
+                continue
+            if op == "tuple":
+                kind[name] = kinds
+                continue
+            if args and all(k == "weight" for k in kinds):
+                kind[name] = "weight"
+            elif any(k == "pool" and _hlo_bytes(shape) >= _hlo_bytes(shapes[a])
+                     for k, a in zip(kinds, args)):
+                kind[name] = "pool"         # updated in place
+            else:
+                kind[name] = None
+            if op == "conditional" and "sampler/cond" not in line \
+                    and "llama.dsa_topk" not in line:
+                narrow = re.search(r"branch_computations=\{([^}]*)\}",
+                                   line).group(1).split(",")[1].strip(" %")
+                walk(narrow, kind.get(args[2]))
+            if op in _HLO_FREE or op.endswith("-start") or kind[name] \
+                    or "tpu_custom_call" in line or "AllocateBuffer" in line:
+                continue
+            size = _hlo_bytes(shape)
+            calls = re.search(r"calls=%([\w.-]+)", line)
+            if op == "dynamic-update-slice":
+                size = _hlo_bytes(shapes[args[1]])
+            elif op == "fusion" and calls and any(
+                    i[3] == "dynamic-update-slice"
+                    for i in comps[calls.group(1)]):
+                size = update_bytes(calls.group(1))
+            if size >= floor:
+                found.append((comp, name, op, shape, size))
+
+    walk(entry, None)
+    return found
+
+
 @pytest.fixture(scope="module", params=[16, 8])
 def v5e_ragged_step(request, v5e_chip):
     """The Llama engine's ragged step at the benchmark's Mistral-7B size
@@ -728,6 +871,8 @@ def test_llama_ragged_step_compiles_for_v5e_without_a_pool_copy(
     pool (they were 4.03 GB of copies when the pool rode the layer scan as
     xs/ys), the pools aliased to their outputs, the kernel in the program,
     and the layer loop still rolled."""
+    import re
+
     import jax
     import jax.numpy as jnp
 
@@ -749,6 +894,11 @@ def test_llama_ragged_step_compiles_for_v5e_without_a_pool_copy(
     assert outs[0].dtype == jnp.int32 and len(outs) == 1 + len(pools)
     assert f"f32[{step.tokens},{step.vocab}]" not in text
     assert f"f32[{step.lanes},{step.vocab}]" in text
+    # ISSUE 49: the kernel's output is the allocation it is handed, written
+    # where a lane owns rows: nothing fills it with zeros first
+    out = f"f32[{step.tokens + 8},8,8,128]"
+    assert re.search(rf"= {re.escape(out)}\S* custom-call\(", text)
+    assert not re.search(rf"= {re.escape(out)}\S* broadcast\(", text)
 
 
 def test_llama_ragged_step_holds_one_attention_kernel(v5e_ragged_step):
@@ -770,17 +920,14 @@ def test_llama_ragged_step_holds_one_attention_kernel(v5e_ragged_step):
     assert sorted(re.sub(r"[.\d]+$", "", c) for c in calls) == want
 
 
-def test_cohere2_moe_step_compiles_for_v5e_at_the_cell_s_size(v5e_chip):
-    """The Command A+ engine's whole ragged step at the benchmark cell's
-    size (4 layers at published widths, 16 of 128 experts held, a full pool
-    of 9,600 blocks and a window pool of 2,337, 32 lanes + a 512-token
-    chunk), compiled by the installed libtpu for a v5e from shapes alone: a
-    16-row GQA band in the ragged kernel, two pools with two tables, the
-    window as a prefetched scalar. Both pools are aliased to their outputs,
-    the step's temporaries stay far under a pool, and weights + pools +
-    logits fit the chip."""
+@pytest.fixture(scope="module")
+def v5e_cmdaplus_step(v5e_chip):
+    """The Command A+ engine's whole served step at the benchmark cell's size
+    (4 layers at published widths, 16 of 128 experts held, a full pool of
+    9,600 blocks and a window pool of 2,337, 32 lanes + a 512-token chunk),
+    compiled by the installed libtpu for a v5e from shapes alone."""
     import functools
-    import re
+    import types
 
     import jax
     import jax.numpy as jnp
@@ -788,7 +935,6 @@ def test_cohere2_moe_step_compiles_for_v5e_at_the_cell_s_size(v5e_chip):
     from paddle_tpu.inference import cohere2_moe_runner as cr
     from paddle_tpu.models import cohere2_moe as c2
     from paddle_tpu.ops import sampling
-    from paddle_tpu.ops.pallas import _support
 
     cfg = c2.Cohere2MoeConfig(vocab_size=32768, num_hidden_layers=4,
                               layer_types=(c2.SLIDING,) * 3 + (c2.FULL,),
@@ -811,18 +957,23 @@ def test_cohere2_moe_step_compiles_for_v5e_at_the_cell_s_size(v5e_chip):
             arr((lanes, len(sampling.LANE_COLS)), jnp.int32),
             arr((lanes, 2 * width), jnp.int32), arr((lanes,), jnp.float32),
             arr((2, lanes), jnp.int32)]
-    cache_was = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    try:
-        with pytest.MonkeyPatch.context() as mp:
-            mp.setattr(_support, "backend", lambda: "tpu")
-            lowered = jax.jit(step, donate_argnums=(1, 2)).trace(
-                params, pools, counters, *ints).lower(
-                    lowering_platforms=("tpu",))
-            compiled = lowered.compile()
-    finally:
-        jax.config.update("jax_enable_compilation_cache", cache_was)
-    kernels = re.findall(r'kernel_name = "(\w+)"', lowered.as_text())
+    lowered, compiled = _compile_for_v5e(
+        step, (params, pools, counters, *ints), (1, 2))
+    return types.SimpleNamespace(lowered=lowered.as_text(), compiled=compiled,
+                                 pools=pools, tokens=tokens)
+
+
+def test_cohere2_moe_step_compiles_for_v5e_at_the_cell_s_size(
+        v5e_cmdaplus_step):
+    """The Command A+ engine's whole ragged step at the benchmark cell's
+    size (`v5e_cmdaplus_step`): a 16-row GQA band in the ragged kernel, two
+    pools with two tables, the window as a prefetched scalar. Both pools are
+    aliased to their outputs, the step's temporaries stay far under a pool,
+    and weights + pools + logits fit the chip."""
+    import re
+
+    pools, compiled = v5e_cmdaplus_step.pools, v5e_cmdaplus_step.compiled
+    kernels = re.findall(r'kernel_name = "(\w+)"', v5e_cmdaplus_step.lowered)
     assert sorted(set(kernels)) == ["kv_write_ragged", "moe_grouped_matmul",
                                     "paged_attention_ragged"]
     assert kernels.count("paged_attention_ragged") == 4      # one a layer
@@ -905,23 +1056,14 @@ def test_brumby_step_compiles_for_v5e_at_the_cell_s_size(v5e_chip):
     assert held < 14.5e9, held          # of the chip's 16.9 GB
 
 
-@pytest.mark.parametrize("program", ["round", "witness"])
-def test_glm_moe_dsa_step_compiles_for_v5e_at_the_cell_s_size(v5e_chip,
-                                                              program):
-    """The GLM-5.2 engine's whole ragged step at the benchmark cell's size (5
-    layers at published widths, 16 of 256 experts held, 1/8 of the
-    vocabulary, 11,000 blocks in both pools on one table 896 wide, 32 lanes +
-    a 512-token chunk), compiled by the installed libtpu for a v5e from
-    shapes alone: an index-score kernel and a selection without a sort a
-    `full` layer and a sparse-attention kernel a layer and no dense
-    latent-attention kernel; both donated pools
-    aliased to their outputs and no second copy of either among the
-    temporaries (the row loops close over the pools: a `while` that copied
-    its invariants would show here); weights + pools + temporaries fit the
-    chip. `witness`: the program a check replays decode rows through (a
-    row a lane), over the same state."""
+def _glm_step(v5e_chip, program):
+    """The GLM-5.2 engine's served step (`round`) or its witness program at
+    the benchmark cell's size (5 layers at published widths, 16 of 256
+    experts held, 1/8 of the vocabulary, 11,000 blocks in both pools on one
+    table 896 wide, 32 lanes + a 512-token chunk), compiled by the installed
+    libtpu for a v5e from shapes alone."""
     import functools
-    import re
+    import types
 
     import jax
     import jax.numpy as jnp
@@ -929,7 +1071,6 @@ def test_glm_moe_dsa_step_compiles_for_v5e_at_the_cell_s_size(v5e_chip,
     from paddle_tpu.inference import glm_moe_dsa_runner as gr
     from paddle_tpu.models import glm_moe_dsa as glm
     from paddle_tpu.ops import sampling
-    from paddle_tpu.ops.pallas import _support
 
     cfg = glm.GlmMoeDsaConfig(
         vocab_size=19360, hidden_size=6144, intermediate_size=12288,
@@ -966,18 +1107,36 @@ def test_glm_moe_dsa_step_compiles_for_v5e_at_the_cell_s_size(v5e_chip,
                 arr((lanes, len(sampling.LANE_COLS)), jnp.int32),
                 arr((lanes, width), jnp.int32), arr((lanes,), jnp.float32),
                 arr((2, lanes), jnp.int32)]
-    cache_was = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    try:
-        with pytest.MonkeyPatch.context() as mp:
-            mp.setattr(_support, "backend", lambda: "tpu")
-            lowered = jax.jit(step, donate_argnums=(1, 2)).trace(
-                params, pools, counters, *ints).lower(
-                    lowering_platforms=("tpu",))
-            compiled = lowered.compile()
-    finally:
-        jax.config.update("jax_enable_compilation_cache", cache_was)
-    kernels = re.findall(r'kernel_name = "(\w+)"', lowered.as_text())
+    lowered, compiled = _compile_for_v5e(
+        step, (params, pools, counters, *ints), (1, 2))
+    return types.SimpleNamespace(lowered=lowered.as_text(), compiled=compiled,
+                                 pools=pools, tokens=tokens)
+
+
+@pytest.fixture(scope="module")
+def v5e_glm52_step(v5e_chip):
+    return _glm_step(v5e_chip, "round")
+
+
+@pytest.mark.parametrize("program", ["round", "witness"])
+def test_glm_moe_dsa_step_compiles_for_v5e_at_the_cell_s_size(v5e_chip,
+                                                              program,
+                                                              request):
+    """The GLM-5.2 engine's whole ragged step at the benchmark cell's size
+    (`_glm_step`): an index-score kernel and a selection without a sort a
+    `full` layer and a sparse-attention kernel a layer and no dense
+    latent-attention kernel; both donated pools
+    aliased to their outputs and no second copy of either among the
+    temporaries (the row loops close over the pools: a `while` that copied
+    its invariants would show here); weights + pools + temporaries fit the
+    chip. `witness`: the program a check replays decode rows through (a
+    row a lane), over the same state."""
+    import re
+
+    step = request.getfixturevalue("v5e_glm52_step") if program == "round" \
+        else _glm_step(v5e_chip, program)
+    pools, compiled = step.pools, step.compiled
+    kernels = re.findall(r'kernel_name = "(\w+)"', step.lowered)
     assert sorted(set(kernels)) == ["dsa_index_scores", "mla_sparse_attention",
                                     "moe_grouped_matmul"]
     assert kernels.count("dsa_index_scores") == 2        # one a full layer
@@ -1000,20 +1159,15 @@ def test_glm_moe_dsa_step_compiles_for_v5e_at_the_cell_s_size(v5e_chip,
     assert held < 13.5e9, held          # of the chip's 16.9 GB
 
 
-def test_deepseek_v3_step_keeps_its_live_prefix_switches_on_the_v5e(v5e_chip):
-    """ISSUE 39: the Kanana-shaped step (published attention and expert
-    widths; depth, experts, vocabulary and pool cut), 32 lanes + a 512-token
-    chunk, compiled by the installed libtpu for a v5e from shapes alone. The
-    row-wise segments' switches are still control flow in the compiled
-    program (two `conditional`s a dense layer, three an expert layer: XLA
-    has not turned them into selects that compute both widths); each layer's
-    ONE `paged_attention_mla`, the pool's write and the experts' grouped
-    matmuls, which cost what is live whatever the buffer's width, lie
-    outside them and are in the program once; the pool is aliased to its
-    output, and the temporaries are those of the program with no switch plus
-    at most the padded row outputs."""
+@pytest.fixture(scope="module")
+def v5e_kanana_step(v5e_chip):
+    """The Kanana-shaped served step (published attention and expert
+    widths; depth, experts, vocabulary and pool cut: a dense and an expert
+    layer), 32 lanes + a 512-token chunk, compiled by the installed libtpu
+    for a v5e from shapes alone; `unswitched`: the same step with no switch
+    in it (`deepseek_v3.whole`)."""
     import functools
-    import re
+    import types
 
     import jax
     import jax.numpy as jnp
@@ -1022,7 +1176,6 @@ def test_deepseek_v3_step_keeps_its_live_prefix_switches_on_the_v5e(v5e_chip):
     from paddle_tpu.inference import live_prefix
     from paddle_tpu.models import deepseek_v3 as dsv3
     from paddle_tpu.ops import sampling
-    from paddle_tpu.ops.pallas import _support
 
     cfg = dsv3.DeepseekV3Config(
         vocab_size=8192, hidden_size=2048, intermediate_size=6144,
@@ -1051,36 +1204,40 @@ def test_deepseek_v3_step_keeps_its_live_prefix_switches_on_the_v5e(v5e_chip):
         step = sampling.with_tail(
             functools.partial(dr._ragged_stack, cfg=cfg, narrow=True),
             functools.partial(dr._head, cfg=cfg))
-        return jax.jit(step, donate_argnums=(1, 2)).trace(
-            params, pool, counters, *ints).lower(
-                lowering_platforms=("tpu",)).compile()
+        return _compile_for_v5e(step, (params, pool, counters, *ints),
+                                (1, 2))[1]
 
-    cache_was = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    try:
-        with pytest.MonkeyPatch.context() as mp:
-            mp.setattr(_support, "backend", lambda: "tpu")
-            compiled = compile_step()
-            mp.setattr(live_prefix, "rowwise",
-                       lambda n_live, narrow, t: dsv3.whole)
-            unswitched = compile_step()
-    finally:
-        jax.config.update("jax_enable_compilation_cache", cache_was)
+    compiled = compile_step()
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(live_prefix, "rowwise",
+                   lambda n_live, narrow, t: dsv3.whole(t))
+        unswitched = compile_step()
+    return types.SimpleNamespace(compiled=compiled, unswitched=unswitched,
+                                 pool=pool, layers=layers, tokens=tokens)
 
-    text = compiled.as_text()
-    bodies, name = {}, None              # computation -> its instructions
-    for line in text.splitlines():
-        head = re.match(r"(?:ENTRY )?%([\w.-]+) \(", line)
-        if head and line.rstrip().endswith("{"):
-            name = head.group(1)
-            bodies[name] = []
-        elif name is not None:
-            bodies[name].append(line)
+
+def test_deepseek_v3_step_keeps_its_live_prefix_switches_on_the_v5e(
+        v5e_kanana_step):
+    """ISSUE 39: the Kanana-shaped step (`v5e_kanana_step`). The row-wise
+    segments' switches are still control flow in the compiled program (two
+    `conditional`s a dense layer, four an expert layer, the product between
+    its experts' matmuls among them: XLA has not turned them into selects
+    that compute both widths); each layer's ONE `paged_attention_mla`, the
+    pool's write and the experts' grouped matmuls, which cost what is live
+    whatever the buffer's width, lie outside them and are in the program
+    once; the pool is aliased to its output, and the temporaries are those
+    of the program with no switch plus at most the row outputs' buffers."""
+    import re
+
+    layers, tokens = v5e_kanana_step.layers, v5e_kanana_step.tokens
+    compiled, unswitched = v5e_kanana_step.compiled, \
+        v5e_kanana_step.unswitched
+    bodies, _ = _hlo_computations(compiled.as_text())
     # (the compiler moves a neighbour's ops into a branch and then drops
     # the switch's own scope path: the sampler's greedy switch is the other)
-    switches = [ln for body in bodies.values() for ln in body
-                if " conditional(" in ln and "sampler/cond" not in ln]
-    assert len(switches) == 2 + 3 * (layers - 1), len(switches)
+    switches = [i[5] for body in bodies.values() for i in body
+                if i[3] == "conditional" and "sampler/cond" not in i[5]]
+    assert len(switches) == 2 + 4 * (layers - 1), len(switches)
     inside = set()
     for ln in switches:
         inside.update(n.strip().lstrip("%") for n in re.search(
@@ -1088,10 +1245,8 @@ def test_deepseek_v3_step_keeps_its_live_prefix_switches_on_the_v5e(v5e_chip):
     assert len(inside) == 2 * len(switches)
 
     def kernels(names):
-        return sorted(re.sub(r"[.\d]+$", "", k) for n in names
-                      for k in re.findall(
-                          r'%([\w.-]+) = [^\n]*custom_call_target='
-                          r'"tpu_custom_call"', "\n".join(bodies[n])))
+        return sorted(re.sub(r"[.\d]+$", "", i[1]) for n in names
+                      for i in bodies[n] if "tpu_custom_call" in i[5])
 
     assert kernels(inside) == []
     assert kernels(set(bodies) - inside) == \
@@ -1099,13 +1254,56 @@ def test_deepseek_v3_step_keeps_its_live_prefix_switches_on_the_v5e(v5e_chip):
         + ["paged_attention_mla"] * layers
     assert "llama.layer/cond" not in unswitched.as_text()
     mem, plain = compiled.memory_analysis(), unswitched.memory_analysis()
-    pool_bytes = int(np.prod(pool.shape)) * 2
+    pool_bytes = int(np.prod(v5e_kanana_step.pool.shape)) * 2
     assert mem.alias_size_in_bytes >= pool_bytes
     # the switch's outputs are buffers where the one width fused them away:
-    # the padded q_abs, the cache rows and a hidden state a layer at most
+    # the packed q_abs, the experts' rows and their product, the cache rows
+    # and a hidden state a layer at most
     q_abs = tokens * 32 * 640 * 2
-    assert mem.temp_size_in_bytes <= plain.temp_size_in_bytes + 2 * q_abs, (
+    assert mem.temp_size_in_bytes <= plain.temp_size_in_bytes + 3 * q_abs, (
         mem.temp_size_in_bytes, plain.temp_size_in_bytes)
+
+
+# engine -> (its compiled step, the bytes `moved_bytes` read of the PARENT's
+# step: 172c563, compiled here for the described v5e, PR 49)
+_MOVED_BEFORE = {"kanana": ("v5e_kanana_step", 269.8e6),
+                 "glm52": ("v5e_glm52_step", 1173.1e6),
+                 "cmdaplus": ("v5e_cmdaplus_step", 1168.2e6)}
+
+
+@pytest.mark.parametrize("engine", sorted(_MOVED_BEFORE))
+def test_a_decode_round_moves_its_live_rows_and_nothing_else(engine, request):
+    """ISSUE 49: a packed buffer that travels between a layer's row-wise
+    segments and its kernels is made blank, once, at the kernel's own shape,
+    and a decode round writes its lanes' rows into it. Over ENTRY and every
+    switch's narrow branch of the step compiled for the v5e, the outputs of
+    2 MiB or more that are not a kernel, made of weights alone or a pool's
+    scatter (`moved_bytes`) sum to under a fifth of what the parent's step
+    wrote there: Kanana (a dense and an expert layer) 269.8 MB, of which the
+    expert layer's 22.4 `pad -> bf16[548,32,640]`, 18.0 `broadcast ->
+    bf16[548,32,512]`, 22.4 its copy into place, 17.8 `slice ->
+    bf16[544,32,512]`, 17.8 `copy bf16[544,32,512]{2,0,1}`, 13.6 `pad ->
+    bf16[3328,2048]`, 26.7 `slice -> f32[3264,2048]`, 5.0 + 5.1 the product
+    and its pad; GLM-5.2 (5 layers) 1,173.1 MB, a layer's 40.1 `pad ->
+    bf16[544,64,576]`, 53.5 `pad -> bf16[4352,6144]`, 35.7 `copy
+    bf16[544,64,512]{2,0,1}`, 35.7 `broadcast -> bf16[544,64,512]`, 17.8
+    `multiply_convert_fusion -> bf16[4352,2048]`; Command A+ (4 layers)
+    1,168.2 MB, a layer's 17.8 + 36.2 pads of `q`, 36.2 `broadcast ->
+    f32[552,8,16,128]`, 17.8 `slice`, 35.7 `pad -> bf16[4352,4096]`, 35.7
+    `multiply_convert_fusion`. The change reads 17.8, 206.6 and 175.6 MB:
+    the rows the lanes computed, and the hidden state's and the embedding's
+    544 rows. And no `pad`, `broadcast`, `slice` or `copy` there makes `T`
+    rows or more."""
+    import re
+
+    fixture, before = _MOVED_BEFORE[engine]
+    step = request.getfixturevalue(fixture)
+    found = moved_bytes(step.compiled.as_text())
+    total = sum(size for *_, size in found)
+    assert found and total < before / 5, (total, found)
+    whole = [f for f in found if f[2] in ("pad", "broadcast", "slice", "copy")
+             and int(re.search(r"\[(\d+)", f[3]).group(1)) >= step.tokens]
+    assert not whole, whole
 
 
 def test_gate_closes_for_gspmd_partitioned_operands():

@@ -104,6 +104,42 @@ class TestRaggedKernelParity:
         assert float(np.abs(np.asarray(out)[guard]).max()) == 0.0
         assert float(np.abs(np.asarray(ref)[guard]).max()) == 0.0
 
+    @pytest.mark.parametrize("kvh,h", [(2, 4), (4, 4)])
+    def test_the_buffer_taking_call_answers_the_live_rows(self, kvh, h,
+                                                          monkeypatch):
+        """ISSUE 49: `ragged_prepare`, placed in a blank (here full of NaN),
+        through `paged_attention_ragged_packed`: the kernel's own buffer,
+        whose live rows `ragged_finish` turns into the public function's;
+        a guard row and the spare chunk hold whatever (the interpreter's
+        unwritten rows read NaN). The public function, made of the same
+        three, still gives exact zeros there."""
+        from paddle_tpu.ops.pallas import _support
+        from test_live_prefix import poison
+
+        rng = np.random.default_rng(3)
+        q, kc, vc, tables, kv_lens, lane, pos = self._mixed(rng, kvh, h)
+        want = pa.paged_attention_ragged(q, kc, vc, tables, kv_lens, lane,
+                                         pos)
+        monkeypatch.setattr(_support, "blank", poison)
+        t, g_pad = q.shape[0], -(-(h // kvh) // 8) * 8
+        buf = _support.place(pa.ragged_prepare(q, kvh), t, t)
+        assert buf.shape == (t + 8, kvh, g_pad, 32) \
+            and buf.dtype == jnp.float32
+        assert np.isnan(np.asarray(buf[t:])).all()
+        out = pa.paged_attention_ragged_packed(buf, kc, vc, tables, kv_lens,
+                                               lane, pos)
+        assert out.shape == buf.shape
+        live = np.asarray(pos) >= 0
+        np.testing.assert_array_equal(
+            np.asarray(pa.ragged_finish(out[:t], h, q.dtype))[live],
+            np.asarray(want)[live])
+        again = pa.paged_attention_ragged(q, kc, vc, tables, kv_lens, lane,
+                                          pos)
+        np.testing.assert_array_equal(np.asarray(again), np.asarray(want))
+        with pytest.raises(ValueError, match="placed"):
+            pa.paged_attention_ragged_packed(buf[:t], kc, vc, tables,
+                                             kv_lens, lane, pos)
+
     def test_decode_composition_matches_decode_reference(self):
         """A pure decode batch through the ragged kernel is the
         single-query XLA reference `paged_attention_ref` to float
