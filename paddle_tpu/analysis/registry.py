@@ -41,34 +41,27 @@ HOT_PATHS = {
     ("serving/scheduler.py", "Scheduler._decode_spec"),
     ("serving/scheduler.py", "Scheduler._commit_token"),
     ("serving/frontend.py", "ServingFrontend.step"),
-    ("serving/engine.py", "MLPLMEngine.sampled_step"),
-    ("serving/engine.py", "MLPLMEngine.verify_step"),
-    ("inference/llama_runner.py", "LlamaInferenceEngine.sampled_step"),
-    ("inference/llama_runner.py", "LlamaInferenceEngine.verify_step"),
+    # the engine shell: every engine class's dispatch (MLP, Llama,
+    # DeepSeek-V3, Cohere2-MoE, Brumby, GLM-MoE-DSA, TP-sharded, LoRA) is
+    # these, written once
+    ("inference/step_engine.py", "StepEngine.sampled_step"),
+    ("inference/step_engine.py", "StepEngine.verify_step"),
+    ("inference/step_engine.py", "StepEngine._run"),
     ("ops/sampling.py", "sample_tokens"),
-    ("ops/sampling.py", "ragged_step"),
     ("ops/sampling.py", "pack_lanes"),
-    ("inference/deepseek_v3_runner.py",
-     "DeepseekV3InferenceEngine.sampled_step"),
     ("inference/cache.py", "BlockCacheManager.append_tokens"),
     # the COW block-copy hooks run mid-decode under prefix sharing, and
     # PR 14's quantized pools extend them to move int8 blocks + scale
     # planes in one donated executable — still one dispatch, no per-call
     # host conversions allowed
     ("inference/kv_migrate.py", "PagedPools.copy_kv_block"),
-    # the TP-sharded dispatch surfaces (ISSUE 16): every token of every
-    # multichip serving run crosses these — the shard_map program is one
-    # dispatch; stray host work here multiplies by tp chips' worth of
-    # traffic
-    ("serving/tp.py", "ShardedEngine.sampled_step"),
-    ("serving/tp.py", "ShardedEngine.verify_step"),
+    # the TP-sharded dispatch (ISSUE 16): every token of every multichip
+    # serving run crosses it — the shard_map program is one dispatch;
+    # stray host work here multiplies by tp chips' worth of traffic
     ("serving/tp.py", "ShardedEngine._dispatch"),
-    # the multi-LoRA dispatch surfaces (ISSUE 18): every token of every
-    # multi-adapter serving run crosses these; the per-lane adapter-slot
-    # install runs before EVERY ragged/verify round — stray per-call
-    # imports or host conversions here tax every tenant at once
-    ("serving/lora.py", "LoRAEngine.sampled_step"),
-    ("serving/lora.py", "LoRAEngine.verify_step"),
+    # the multi-LoRA per-lane adapter-slot install (ISSUE 18) runs before
+    # EVERY ragged/verify round — stray per-call imports or host
+    # conversions here tax every tenant at once
     ("serving/lora.py", "LoRAEngine.set_lane_adapters"),
     ("serving/scheduler.py", "Scheduler._install_lane_adapters"),
     # the elastic supervisor's per-step heartbeat: one membership-store

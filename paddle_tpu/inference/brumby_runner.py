@@ -21,11 +21,9 @@ unrolled as the two MoE engines', and nothing is paged.
   so the step's own screen flags the lane (`ops/sampling.step_tail`) and the
   scheduler fails the request. A state cannot be trimmed, so nothing here
   replays a token.
-- `sampled_step` is a round's one compiled step, ending in the NaN screen,
-  the head over the sampled rows and the sampler (`ops/sampling.with_tail`);
-  `ragged_step` is the same stack with the head over every row (a program
-  of its own, `ops/sampling.all_rows`) and `generate` a host loop over it.
-  `verify_step` raises: a verify window's
+- The `EngineCore` surface and the programs are the shell's
+  (`inference/step_engine.StepEngine`); this file holds the stack, the
+  head and the state's layout. `verify_step` raises: a verify window's
   rollback needs a snapshot of the state.
 
 The engine transforms (`quantize_engine`, `shard_engine`, `attach_adapters`)
@@ -41,17 +39,13 @@ from typing import Dict
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from ..framework import monitor
 from ..models import brumby as bm
-from ..observability import compile_trace
-from ..ops import sampling
 from ..ops.pallas import power_retention as pr
 from ..ops.pallas.paged_attention import ragged_metadata
-from . import kv_migrate
+from . import step_engine
 from .cache import BlockCacheManager
-from .generate import generate
 
 __all__ = ["BrumbyInferenceEngine"]
 
@@ -98,10 +92,7 @@ def _ragged_stack(params, state, tokens, q_lens, kv_lens, tables, *, cfg):
     sound = live & (fresh | (start == length[slot]))
     with jax.named_scope("llama.rope"):
         pos = jnp.maximum(tok_pos, 0)
-        cos = jnp.take(params["rope_cos"], pos, axis=0)
-        sin = jnp.take(params["rope_sin"], pos, axis=0)
-    with jax.named_scope("llama.embed"):
-        x = jnp.take(params["model.embed_tokens.weight"], tokens, axis=0)
+    cos, sin, x = step_engine.token_rows(params, tokens, pos)
     held = [S, z]
     rows = jnp.maximum(jnp.cumsum(q_lens) - 1, 0)      # a lane's last row
     for i in range(cfg.num_hidden_layers):
@@ -124,13 +115,20 @@ def _head(state, x, lane, *, cfg):
     return bm.head(x, state[0], cfg)
 
 
-class BrumbyInferenceEngine:
+class BrumbyInferenceEngine(step_engine.StepEngine):
     """`EngineCore` over `BrumbyForCausalLM` with one state slot a sequence.
     Serves in the dtype the model's weights have; the state is float32.
 
     `slots`: sequences that can be resident at once (one more is allocated
     for a scheduler's guard); by default one a lane. `context_tokens`: the
     longest a sequence may grow, the rotary table's length."""
+
+    FAMILY = FAMILY
+    DONATED = ("state",)
+    NO_VERIFY = ("verify_step over a state group is not implemented: "
+                 "rejecting a draft would have to roll the state back, and a "
+                 "recurrent state has no snapshot yet")
+    NO_MIGRATION = "a state slot has no migration payload yet"
 
     def __init__(self, model: bm.BrumbyForCausalLM, max_batch_size: int = 8,
                  slots: int = None, context_tokens: int = None):
@@ -159,45 +157,8 @@ class BrumbyInferenceEngine:
                       jnp.zeros((slots + 1,), jnp.int32),
                       jnp.zeros((), jnp.int32))
         self.manager.set_kv_geometry(self.state_bytes_per_seq(), 32)
-        stack = functools.partial(_ragged_stack, cfg=cfg)
-        head = functools.partial(_head, cfg=cfg)
-        # the screen, the row gather, the head over the sampled rows and
-        # the sampler end the round's one program (`ops/sampling.with_tail`);
-        # `_logits` is the same stack with the head over every row,
-        # compiled when `ragged_step` first calls it
-        self._ragged = jax.jit(sampling.with_tail(stack, head),
-                               donate_argnums=(1,))
-        self._logits = jax.jit(sampling.all_rows(stack, head),
-                               donate_argnums=(1,))
-        self.last_sampled = None    # the last step's `sampled`, on device
-        compile_trace.stamp("engine.build", began)
-
-    # ---- the EngineCore dispatch surface ----
-    def sampled_step(self, tokens: np.ndarray, lanes: np.ndarray,
-                     block_tables: np.ndarray, temperature: np.ndarray):
-        """ONE fixed-shape step over a packed ragged batch, sampled (see
-        `EngineCore.sampled_step`): `sampled [2, B] int32`, on the device.
-        `block_tables` `[B, 1]`: each lane's state slot."""
-        self.last_sampled = self._run(
-            self._ragged, *sampling.call_arrays(
-                tokens, lanes, block_tables, temperature, self.last_sampled))
-        return self.last_sampled
-
-    def _run(self, fn, *arrays):
-        """One of the step programs over this engine's state, which it
-        replaces; what the program returns ahead of it."""
-        out, self.state = fn(self.params, self.state, *arrays)
-        return out
-
-    ragged_step = sampling.ragged_step
-
-    def verify_step(self, tokens, context_lens, block_tables):
-        raise NotImplementedError(
-            f"{FAMILY}: verify_step over a state group is not implemented: "
-            "rejecting a draft would have to roll the state back, and a "
-            "recurrent state has no snapshot yet")
-
-    generate = generate
+        self._build_programs(functools.partial(_ragged_stack, cfg=cfg),
+                             functools.partial(_head, cfg=cfg), began=began)
 
     # ---- hooks the scheduler and the cache manager look for ----
     def kv_bytes_per_token(self) -> float:
@@ -213,21 +174,8 @@ class BrumbyInferenceEngine:
     def quant_info(self) -> dict:
         """What `serving.quant.*`, `serving.kv_bytes_per_token` and
         `serving.state.bytes_per_seq` publish."""
-        return {"wbits": 16, "kv_bits": 16,
-                "kv_bytes_per_token": self.kv_bytes_per_token(),
-                "state_bytes_per_seq": self.state_bytes_per_seq()}
-
-    def cost_card_args(self, phase: str):
-        return {"decode": self._ragged, "ragged": self._ragged}[phase], \
-            (self.params, self.state)
-
-    def extract_kv_blocks(self, seq_id: int):
-        raise kv_migrate.KVMigrationError(
-            f"{FAMILY}: a state slot has no migration payload yet")
-
-    def inject_kv_blocks(self, seq_id: int, payload) -> None:
-        raise kv_migrate.KVMigrationError(
-            f"{FAMILY}: a state slot has no migration payload yet")
+        return dict(super().quant_info(),
+                    state_bytes_per_seq=self.state_bytes_per_seq())
 
     # ---- the state's counter ----
     def state_resets(self) -> int:

@@ -18,11 +18,9 @@ context, so it has TWO paged pools and two block tables a sequence.
   `llama.attn_window` / `llama.attn_full` tell its calls apart in a trace.
 - The weights are the model's own pytree, by reference; the expert layer
   holds `config.held_experts` of the router's experts (`[held, in, out]`).
-- `sampled_step` is a round's one compiled step, ending in the NaN screen,
-  the head over the sampled rows and the sampler (`ops/sampling.with_tail`);
-  `ragged_step` is the same stack with the head over every row (a program
-  of its own, `ops/sampling.all_rows`), `verify_step` a case of the stack
-  and `generate` a host loop over `ragged_step`.
+- The `EngineCore` surface and the three programs are the shell's
+  (`inference/step_engine.StepEngine`); this file holds the stack, the
+  head and the two pools' layout.
 - Expert load is counted inside the step, on the device, in donated
   counters; `expert_load()` reads them.
 
@@ -39,16 +37,11 @@ from typing import Dict
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
-from ..framework import monitor
 from ..models import cohere2_moe as c2
-from ..observability import compile_trace
-from ..ops import sampling
 from ..ops.pallas import paged_attention as pk
-from . import kv_migrate, live_prefix
+from . import live_prefix, step_engine
 from .cache import BlockCacheManager
-from .generate import generate
 
 __all__ = ["Cohere2MoeInferenceEngine"]
 
@@ -76,16 +69,10 @@ def _ragged_stack(params, pools, counters, tokens, q_lens, kv_lens, tables,
     groups = _groups(cfg)
     width = tables.shape[1] // len(groups)
     tok_lane, tok_pos = pk.ragged_metadata(q_lens, kv_lens, t)
-    live = tok_pos >= 0
-    lanes = q_lens.shape[0]
-    n_live = jnp.sum(q_lens.astype(jnp.int32))
-    rowwise = live_prefix.rowwise(n_live, lanes if narrow else None, t)
+    step = live_prefix.prologue(q_lens, tok_pos, narrow)
     with jax.named_scope("llama.rope"):
         pos = jnp.maximum(tok_pos, 0)
-        cos = jnp.take(params["rope_cos"], pos, axis=0)
-        sin = jnp.take(params["rope_sin"], pos, axis=0)
-    with jax.named_scope("llama.embed"):
-        x = jnp.take(params["model.embed_tokens.weight"], tokens, axis=0)
+    cos, sin, x = step_engine.token_rows(params, tokens, pos)
     pools = list(pools)
     where = {}        # layer -> (its group, its index in the group's pool)
     for g, (_kind, _name, layers) in enumerate(groups):
@@ -128,19 +115,11 @@ def _ragged_stack(params, pools, counters, tokens, q_lens, kv_lens, tables,
     sizes = []
     for i, kind in enumerate(cfg.layer_types):
         x, n = c2.decoder_layer(x, c2.layer_params(params, i), cfg, kind, cos,
-                                sin, attend_layer(i, kind), live, rowwise)
+                                sin, attend_layer(i, kind), step.live,
+                                step.rowwise)
         sizes.append(n)
-    sizes = jnp.stack(sizes)                                     # [L, E]
-    first, count = cfg.held
-    counters = {
-        "tokens": counters["tokens"] + sizes,
-        "touched": counters["touched"] + jnp.sum(
-            sizes[:, first:first + count] > 0, axis=1, dtype=jnp.int32),
-        "steps": counters["steps"] + 1,
-        "narrow_steps": counters["narrow_steps"] + (
-            (n_live <= lanes).astype(jnp.int32) if narrow else 0),
-    }
-    return x, tuple(pools), counters
+    return x, tuple(pools), live_prefix.moe_counters(counters, sizes, step,
+                                                     cfg.held)
 
 
 def _head(state, x, lane, *, cfg):
@@ -149,18 +128,7 @@ def _head(state, x, lane, *, cfg):
     return c2.head(x, state[0], cfg)
 
 
-def _verify_fn(params, pools, counters, tokens, ctx_lens, tables, *, cfg):
-    """Speculative verify as a case of the ragged step: every lane a fixed
-    window of S tokens; logits fold back to `[B, S, V]`."""
-    monitor.inc("serving.verify_retraces")        # trace-time only
-    b, s = tokens.shape
-    x, pools, counters = _ragged_stack(
-        params, pools, counters, tokens.reshape(b * s),
-        jnp.full((b,), s, jnp.int32), ctx_lens, tables, cfg=cfg)
-    return c2.head(x, params, cfg).reshape(b, s, -1), pools, counters
-
-
-class Cohere2MoeInferenceEngine:
+class Cohere2MoeInferenceEngine(step_engine.StepEngine):
     """`EngineCore` over `Cohere2MoeForCausalLM` with a pool a layer kind.
     Serves in the dtype the model's weights have.
 
@@ -169,6 +137,11 @@ class Cohere2MoeInferenceEngine:
     holds at most `(window + tokens a step - 2) // block_size + 2` of its
     blocks at once; by default every lane's whole table fits, plus the
     guard block)."""
+
+    FAMILY = FAMILY
+    DONATED = ("pools", "counters")
+    NO_MIGRATION = ("two pools of different geometry (a window's and a "
+                    "context's) have no migration payload yet")
 
     def __init__(self, model: c2.Cohere2MoeForCausalLM,
                  max_batch_size: int = 8, num_blocks: int = 256,
@@ -215,52 +188,10 @@ class Cohere2MoeInferenceEngine:
                          "steps": jnp.zeros((), jnp.int32),
                          "narrow_steps": jnp.zeros((), jnp.int32)}
 
-        stack = functools.partial(_ragged_stack, cfg=cfg, narrow=True)
-        head = functools.partial(_head, cfg=cfg)
-        verify = functools.partial(_verify_fn, cfg=cfg)
-        verify.__name__ = _verify_fn.__name__      # the XLA module's name
-        # the screen, the row gather, the head over the sampled rows and
-        # the sampler end the round's one program (`ops/sampling.with_tail`);
-        # `_logits` is the same stack with the head over every row,
-        # compiled when `ragged_step` first calls it
-        self._ragged = jax.jit(sampling.with_tail(stack, head),
-                               donate_argnums=(1, 2))
-        self._logits = jax.jit(sampling.all_rows(stack, head),
-                               donate_argnums=(1, 2))
-        self.last_sampled = None    # the last step's `sampled`, on device
-        self._verify = jax.jit(verify, donate_argnums=(1, 2))
-        compile_trace.stamp("engine.build", began)
-
-    # ---- the EngineCore dispatch surface ----
-    def sampled_step(self, tokens: np.ndarray, lanes: np.ndarray,
-                     block_tables: np.ndarray, temperature: np.ndarray):
-        """ONE fixed-shape step over a packed ragged batch, sampled (see
-        `EngineCore.sampled_step`): `sampled [2, B] int32`, on the device.
-        `block_tables` `[B, n_groups * W]`: every group's table of a lane,
-        side by side."""
-        self.last_sampled = self._run(
-            self._ragged, *sampling.call_arrays(
-                tokens, lanes, block_tables, temperature, self.last_sampled))
-        return self.last_sampled
-
-    def _run(self, fn, *arrays):
-        """One of the step programs over this engine's state, which it
-        replaces; what the program returns ahead of it."""
-        out, self.pools, self.counters = fn(self.params, self.pools,
-                                            self.counters, *arrays)
-        return out
-
-    ragged_step = sampling.ragged_step
-
-    def verify_step(self, tokens: np.ndarray, context_lens: np.ndarray,
-                    block_tables: np.ndarray):
-        """Multi-token verify (see `EngineCore.verify_step`): `[B, S, V]`."""
-        return self._run(
-            self._verify, np.asarray(tokens, np.int32),
-            np.asarray(context_lens, np.int32),
-            np.asarray(block_tables, np.int32))
-
-    generate = generate
+        self._build_programs(
+            functools.partial(_ragged_stack, cfg=cfg, narrow=True),
+            functools.partial(_head, cfg=cfg),
+            window=functools.partial(_ragged_stack, cfg=cfg), began=began)
 
     # ---- hooks the scheduler and the cache manager look for ----
     def kv_bytes_per_token(self, group: str = None) -> float:
@@ -275,53 +206,12 @@ class Cohere2MoeInferenceEngine:
     def quant_info(self) -> dict:
         """What `serving.quant.*` and `serving.kv_bytes_per_token[.<group>]`
         publish."""
-        return {"wbits": 16, "kv_bits": 16,
-                "kv_bytes_per_token": self.kv_bytes_per_token(),
-                "kv_bytes_per_token_by_group": dict(
-                    self._group_bytes_per_token)}
-
-    def cost_card_args(self, phase: str):
-        fn = {"decode": self._ragged, "ragged": self._ragged,
-              "verify": self._verify}[phase]
-        return fn, (self.params, self.pools, self.counters)
-
-    def extract_kv_blocks(self, seq_id: int):
-        raise kv_migrate.KVMigrationError(
-            f"{FAMILY}: two pools of different geometry (a window's and a "
-            "context's) have no migration payload yet")
-
-    def inject_kv_blocks(self, seq_id: int, payload) -> None:
-        raise kv_migrate.KVMigrationError(
-            f"{FAMILY}: two pools of different geometry (a window's and a "
-            "context's) have no migration payload yet")
+        return dict(super().quant_info(), kv_bytes_per_token_by_group=dict(
+            self._group_bytes_per_token))
 
     # ---- expert load ----
     def expert_load(self) -> dict:
-        """The counters the step keeps on the device, fetched now: `tokens
-        [L, E]` routed to each of the ROUTER's experts since the engine was
-        built (held and absent alike), `touched [L]` HELD experts with at
-        least one token summed over steps, `steps`, `narrow_steps` (those
-        whose row-wise work ran over the live prefix). Publishes
-        `serving.moe.expert_tokens` (assignments that fell on a held
-        expert), `serving.moe.held_assignment_share` (their share of all
-        assignments) and the gauges `serving.moe.load_max_over_mean`
-        (busiest held expert of a layer against the mean one) and
-        `serving.step.live_prefix_share` (`narrow_steps / steps`)."""
-        c = jax.device_get(self.counters)
-        tokens = np.asarray(c["tokens"], np.int64)
-        first, count = self.config.held
-        mine = tokens[:, first:first + count]
-        monitor.set_value("serving.moe.expert_tokens", int(mine.sum()))
-        if tokens.sum():
-            monitor.set_gauge("serving.moe.held_assignment_share",
-                              round(float(mine.sum() / tokens.sum()), 4))
-        if mine.sum():
-            monitor.set_gauge("serving.moe.load_max_over_mean",
-                              round(float(mine.max() / mine.mean()), 3))
-        steps, narrow = int(c["steps"]), int(c["narrow_steps"])
-        if steps:
-            monitor.set_gauge("serving.step.live_prefix_share",
-                              round(narrow / steps, 4))
-        return {"tokens": tokens, "touched": np.asarray(c["touched"], np.int64),
-                "steps": steps, "narrow_steps": narrow,
-                "held": (first, count)}
+        """The step's device-side counters, fetched now
+        (`step_engine.expert_load`, over the held experts: `tokens` counts
+        the ROUTER's experts, held and absent alike)."""
+        return step_engine.expert_load(self.counters, self.config.held)

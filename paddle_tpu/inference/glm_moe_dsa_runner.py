@@ -37,7 +37,10 @@ travels from a layer with an indexer to the layers that share it.
   attention sub-block made of it. An operator or a check replays a cached
   position through it; the served step carries nothing for it.
 
-The radix prefix cache works over this engine (`copy_kv_block` copies a block
+The `EngineCore` surface and the three served programs are the shell's
+(`inference/step_engine.StepEngine`); this file holds the stack, the head,
+the two pools' layout and the witness. The radix prefix cache works over
+this engine (`copy_kv_block` copies a block
 in both pools: an indexer key is a function of its token's prefix as a latent
 row is) and so does speculative decoding (`verify_step` is a case of the
 stack: every row of a window selects for itself). The engine transforms
@@ -57,13 +60,10 @@ import numpy as np
 from ..framework import monitor
 from ..models import deepseek_v3 as dsv3
 from ..models import glm_moe_dsa as glm
-from ..observability import compile_trace
-from ..ops import sampling
 from ..ops.pallas import _support, dsa
 from ..ops.pallas.paged_attention import ragged_metadata
-from . import kv_migrate, live_prefix
+from . import live_prefix, step_engine
 from .cache import BlockCacheManager
-from .generate import generate
 
 __all__ = ["GlmMoeDsaInferenceEngine"]
 
@@ -115,11 +115,9 @@ def _ragged_stack(params, pools, counters, tokens, q_lens, kv_lens, tables,
     kv_lens = kv_lens.astype(jnp.int32)
     tables = tables.astype(jnp.int32)
     tok_lane, tok_pos = ragged_metadata(q_lens, kv_lens, t)
-    live = tok_pos >= 0
-    lanes = q_lens.shape[0]
-    n_live = jnp.sum(q_lens.astype(jnp.int32))
-    rowwise = live_prefix.rowwise(n_live, lanes if narrow else None, t)
-    tile = _tile_rows(t, lanes)
+    step = live_prefix.prologue(q_lens, tok_pos, narrow)
+    live, n_live = step.live, step.n_live
+    tile = _tile_rows(t, q_lens.shape[0])
     span = tables.shape[1] * bs
     k_sel = min(topk, span)
     near = span // NEAR_SHARE
@@ -127,11 +125,7 @@ def _ragged_stack(params, pools, counters, tokens, q_lens, kv_lens, tables,
     # a guard slot's rows go to a block past the pools: the scatter drops it
     blk = jnp.where(live, tables[tok_lane, pos // bs], jnp.int32(nb))
     off = pos % bs
-    with jax.named_scope("llama.rope"):
-        cos = jnp.take(params["rope_cos"], pos, axis=0)
-        sin = jnp.take(params["rope_sin"], pos, axis=0)
-    with jax.named_scope("llama.embed"):
-        x = jnp.take(params["model.embed_tokens.weight"], tokens, axis=0)
+    cos, sin, x = step_engine.token_rows(params, tokens, pos)
 
     def cut(a, r0):
         return jax.lax.dynamic_slice_in_dim(a, r0, tile, 0)
@@ -229,26 +223,19 @@ def _ragged_stack(params, pools, counters, tokens, q_lens, kv_lens, tables,
     for i, kind in enumerate(cfg.indexer_types):
         x, n, carried = glm.decoder_layer(
             x, dsv3.layer_params(params, i), cfg, kind, cos, sin,
-            index_layer(full.get(i)), attend_layer(i), carried, live, rowwise)
+            index_layer(full.get(i)), attend_layer(i), carried, live,
+            step.rowwise)
         sizes.append(jnp.zeros((cfg.n_routed_experts,), jnp.int32)
                      if n is None else n)
         if kind == glm.FULL:
             picked = picked + jnp.sum(carried[1])
             candidates = candidates + jnp.sum(tok_pos + 1)
-    sizes = jnp.stack(sizes)                                     # [L, E]
-    first, count = cfg.held
-    counters = {
-        "tokens": counters["tokens"] + sizes,
-        "touched": counters["touched"] + jnp.sum(
-            sizes[:, first:first + count] > 0, axis=1, dtype=jnp.int32),
-        "steps": counters["steps"] + 1,
-        "narrow_steps": counters["narrow_steps"] + (
-            (n_live <= lanes).astype(jnp.int32) if narrow else 0),
+    counters = dict(
+        live_prefix.moe_counters(counters, sizes, step, cfg.held),
         # float32: a step's candidates pass 2^31 in two hundred steps
-        "dsa_selected": counters["dsa_selected"] + picked.astype(jnp.float32),
-        "dsa_candidates": counters["dsa_candidates"]
-        + candidates.astype(jnp.float32),
-    }
+        dsa_selected=counters["dsa_selected"] + picked.astype(jnp.float32),
+        dsa_candidates=counters["dsa_candidates"]
+        + candidates.astype(jnp.float32))
     return x, (latent, index), counters
 
 
@@ -256,18 +243,6 @@ def _head(state, x, lane, *, cfg):
     """The `head` of `ops/sampling.with_tail`: the final norm and the output
     matmul over the rows it is given; `state[0]` is the params."""
     return glm.head(x, state[0], cfg)
-
-
-def _verify_fn(params, pools, counters, tokens, ctx_lens, tables, *, cfg):
-    """Speculative verify as a case of the ragged step: every lane a fixed
-    window of S tokens, every row its own selection; logits fold back to
-    `[B, S, V]`."""
-    monitor.inc("serving.verify_retraces")        # trace-time only
-    b, s = tokens.shape
-    x, pools, counters = _ragged_stack(
-        params, pools, counters, tokens.reshape(b * s),
-        jnp.full((b,), s, jnp.int32), ctx_lens, tables, cfg=cfg)
-    return glm.head(x, params, cfg).reshape(b, s, -1), pools, counters
 
 
 def _witness_fn(params, pools, counters, tokens, kv_lens, tables, *, cfg):
@@ -285,10 +260,17 @@ def _witness_fn(params, pools, counters, tokens, kv_lens, tables, *, cfg):
     return {"idx": idx, "n": n, "out": out}, pools, counters
 
 
-class GlmMoeDsaInferenceEngine:
+class GlmMoeDsaInferenceEngine(step_engine.BlockCopy,
+                               step_engine.StepEngine):
     """`EngineCore` over `GlmMoeDsaForCausalLM` with a paged latent cache and
     a paged index cache on one block table. Serves in the dtype the model's
     weights have."""
+
+    FAMILY = FAMILY
+    DONATED = ("pools", "counters")
+    NO_MIGRATION = ("a block of two pools (latent rows and indexer keys) "
+                    "has no migration payload yet")
+    PHASES = dict(step_engine.StepEngine.PHASES, witness="_witness")
 
     def __init__(self, model: glm.GlmMoeDsaForCausalLM,
                  max_batch_size: int = 8, num_blocks: int = 256,
@@ -324,65 +306,16 @@ class GlmMoeDsaInferenceEngine:
         self.manager.set_kv_geometry(
             block_size * sum(self._pool_bytes_per_token.values()), 16)
 
-        stack = functools.partial(_ragged_stack, cfg=cfg, narrow=True)
-        head = functools.partial(_head, cfg=cfg)
-        verify = functools.partial(_verify_fn, cfg=cfg)
-        verify.__name__ = _verify_fn.__name__      # the XLA module's name
-        # the screen, the row gather, the head over the sampled rows and
-        # the sampler end the round's one program (`ops/sampling.with_tail`);
-        # `_logits` is the same stack with the head over every row,
-        # compiled when `ragged_step` first calls it
-        self._ragged = jax.jit(sampling.with_tail(stack, head),
-                               donate_argnums=(1, 2))
-        self._logits = jax.jit(sampling.all_rows(stack, head),
-                               donate_argnums=(1, 2))
-        self.last_sampled = None    # the last step's `sampled`, on device
-        self._verify = jax.jit(verify, donate_argnums=(1, 2))
         self._witness = jax.jit(functools.partial(_witness_fn, cfg=cfg),
                                 donate_argnums=(1, 2))
-        # COW copy (prefix caching): one block of BOTH pools, every layer,
-        # donated; src/dst trace as scalars, so COWs never recompile
-        self._copy_block = jax.jit(
-            lambda pools, s, d: tuple(p.at[:, d].set(p[:, s]) for p in pools),
-            donate_argnums=(0,))
-        compile_trace.stamp("engine.build", began)
-
-    # ---- the EngineCore dispatch surface ----
-    def sampled_step(self, tokens: np.ndarray, lanes: np.ndarray,
-                     block_tables: np.ndarray, temperature: np.ndarray):
-        """ONE fixed-shape step over a packed ragged batch, sampled (see
-        `EngineCore.sampled_step`): `sampled [2, B] int32`, on the device."""
-        self.last_sampled = self._run(
-            self._ragged, *sampling.call_arrays(
-                tokens, lanes, block_tables, temperature, self.last_sampled))
-        return self.last_sampled
-
-    def _run(self, fn, *arrays):
-        """One of the step programs over this engine's state, which it
-        replaces; what the program returns ahead of it."""
-        out, self.pools, self.counters = fn(self.params, self.pools,
-                                            self.counters, *arrays)
-        return out
-
-    ragged_step = sampling.ragged_step
-
-    def verify_step(self, tokens: np.ndarray, context_lens: np.ndarray,
-                    block_tables: np.ndarray):
-        """Multi-token verify (see `EngineCore.verify_step`): `[B, S, V]`."""
-        return self._run(
-            self._verify, np.asarray(tokens, np.int32),
-            np.asarray(context_lens, np.int32),
-            np.asarray(block_tables, np.int32))
-
-    generate = generate
+        # COW copy (prefix caching): one block of BOTH pools, every layer
+        self._build_block_ops(1)
+        self._build_programs(
+            functools.partial(_ragged_stack, cfg=cfg, narrow=True),
+            functools.partial(_head, cfg=cfg),
+            window=functools.partial(_ragged_stack, cfg=cfg), began=began)
 
     # ---- hooks the scheduler and the cache manager look for ----
-    def copy_kv_block(self, src: int, dst: int) -> None:
-        """Copy one physical block, all layers, in both pools (the manager's
-        COW hook when prefix caching is on)."""
-        self.pools = self._copy_block(self.pools, np.int32(src),
-                                      np.int32(dst))
-
     def kv_bytes_per_token(self, pool: str = None) -> float:
         """HBM bytes one cached token costs: in `pool` (`"latent"`: one row
         a layer as stored; `"index"`: one key a `full` layer), or in both."""
@@ -393,52 +326,18 @@ class GlmMoeDsaInferenceEngine:
     def quant_info(self) -> dict:
         """What `serving.quant.*` and `serving.kv_bytes_per_token[.<pool>]`
         publish."""
-        return {"wbits": 16, "kv_bits": 16,
-                "kv_bytes_per_token": self.kv_bytes_per_token(),
-                "kv_bytes_per_token_by_group": dict(
-                    self._pool_bytes_per_token)}
-
-    def cost_card_args(self, phase: str):
-        fn = {"decode": self._ragged, "ragged": self._ragged,
-              "verify": self._verify, "witness": self._witness}[phase]
-        return fn, (self.params, self.pools, self.counters)
-
-    def extract_kv_blocks(self, seq_id: int):
-        raise kv_migrate.KVMigrationError(
-            f"{FAMILY}: a block of two pools (latent rows and indexer keys) "
-            "has no migration payload yet")
-
-    def inject_kv_blocks(self, seq_id: int, payload) -> None:
-        raise kv_migrate.KVMigrationError(
-            f"{FAMILY}: a block of two pools (latent rows and indexer keys) "
-            "has no migration payload yet")
+        return dict(super().quant_info(), kv_bytes_per_token_by_group=dict(
+            self._pool_bytes_per_token))
 
     # ---- the device-side counters ----
     def expert_load(self) -> dict:
-        """The counters the step keeps on the device, fetched now: `tokens
-        [L, E]` routed to each of the ROUTER's experts since the engine was
-        built (held and absent alike), `touched [L]` HELD experts with at
-        least one token summed over steps, `steps`, `narrow_steps`.
-        Publishes what `cohere2_moe_runner.expert_load` does."""
-        c = jax.device_get(self.counters)
-        tokens = np.asarray(c["tokens"], np.int64)
-        first, count = self.config.held
-        mine = tokens[:, first:first + count]
-        monitor.set_value("serving.moe.expert_tokens", int(mine.sum()))
-        if tokens.sum():
-            monitor.set_gauge("serving.moe.held_assignment_share",
-                              round(float(mine.sum() / tokens.sum()), 4))
-        if mine.sum():
-            moe = mine[self.config.first_k_dense_replace:]
-            monitor.set_gauge("serving.moe.load_max_over_mean",
-                              round(float(moe.max() / moe.mean()), 3))
-        steps, narrow = int(c["steps"]), int(c["narrow_steps"])
-        if steps:
-            monitor.set_gauge("serving.step.live_prefix_share",
-                              round(narrow / steps, 4))
-        return {"tokens": tokens, "touched": np.asarray(c["touched"], np.int64),
-                "steps": steps, "narrow_steps": narrow,
-                "held": (first, count)}
+        """The step's device-side counters, fetched now
+        (`step_engine.expert_load`, over the held experts of the expert
+        layers: `tokens` counts the ROUTER's experts, held and absent
+        alike)."""
+        return step_engine.expert_load(
+            self.counters, self.config.held,
+            self.config.first_k_dense_replace)
 
     def attention_witness(self, tokens: np.ndarray, context_lens: np.ndarray,
                           block_tables: np.ndarray) -> dict:

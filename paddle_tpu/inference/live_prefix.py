@@ -12,7 +12,9 @@ to a row and costs its rows. So the engines whose row-wise regions are
 compute-bound at `T` rows (`deepseek_v3_runner`, `cohere2_moe_runner`,
 `glm_moe_dsa_runner`) wrap those regions, and not the kernels between them,
 in `rowwise`: ONE executable, every kernel in it once, the width chosen on
-the device from the step's own `q_lens`.
+the device from the step's own `q_lens`. What those three stacks work out
+of a step's live rows before their first layer (`prologue`) and count
+after their last (`moe_counters`) is here too, once.
 
 A packed buffer that travels between a segment and a kernel is made blank,
 once a use, at the shape the kernel takes, and a round writes into it the
@@ -23,14 +25,15 @@ rows only (docs/SERVING.md lists them).
 """
 from __future__ import annotations
 
-from typing import Callable, Optional
+from typing import Callable, NamedTuple, Optional
 
 import jax
+import jax.numpy as jnp
 
 from ..models.deepseek_v3 import whole
 from ..ops.pallas import _support
 
-__all__ = ["rowwise"]
+__all__ = ["rowwise", "Step", "prologue", "moe_counters"]
 
 
 def rowwise(n_live, narrow: Optional[int], t: int) -> Callable:
@@ -77,3 +80,47 @@ def rowwise(n_live, narrow: Optional[int], t: int) -> Callable:
             n_live <= narrow, on_prefix, lambda head, rows: on_all(*rows),
             jax.tree.map(cut, rows), rows)
     return wrap
+
+
+class Step(NamedTuple):
+    """What a stack knows of a step's live rows before its first layer
+    (`prologue`)."""
+    live: jax.Array         # [T] bool: no guard slot
+    n_live: jax.Array       # [] int32, `sum(q_lens)`
+    narrow: Optional[int]   # the lane count where the switch is asked for
+    rowwise: Callable       # `rowwise(n_live, narrow, t)`
+
+
+def prologue(q_lens, tok_pos, narrow: bool) -> Step:
+    """The live rows of a step whose `ragged_metadata` gave every packed
+    slot the position `tok_pos` [T] (-1: a guard slot), as the three expert
+    engines' stacks take them in: which slots are live and how many, and the
+    live-prefix switch of the layers' row-wise segments (`narrow`: the
+    engine asks for one, at its lane count)."""
+    live = tok_pos >= 0
+    lanes = q_lens.shape[0] if narrow else None
+    n_live = jnp.sum(q_lens.astype(jnp.int32))
+    return Step(live, n_live, lanes,
+                rowwise(n_live, lanes, tok_pos.shape[0]))
+
+
+def moe_counters(counters, sizes, step: Step, held=None) -> dict:
+    """The expert engines' donated counters after a step: `sizes`, a layer's
+    `tokens_per_expert [E]` each, stacked `[L, E]` and added to `tokens`;
+    `touched [L]` the experts of the `held` range `(first, count)` (None:
+    all) that got a token; `steps`; `narrow_steps`, the steps whose live
+    rows fit the switch's prefix. `inference/step_engine.expert_load` reads
+    them."""
+    sizes = jnp.stack(sizes)                                     # [L, E]
+    # (the sum before the cut: the order the steps' lowered text has)
+    tokens = counters["tokens"] + sizes
+    mine = sizes if held is None else sizes[:, held[0]:held[0] + held[1]]
+    return {
+        "tokens": tokens,
+        "touched": counters["touched"] + jnp.sum(mine > 0, axis=1,
+                                                 dtype=jnp.int32),
+        "steps": counters["steps"] + 1,
+        "narrow_steps": counters["narrow_steps"] + (
+            (step.n_live <= step.narrow).astype(jnp.int32)
+            if step.narrow else 0),
+    }

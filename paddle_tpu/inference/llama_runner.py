@@ -21,15 +21,12 @@ TPU-first choices:
   of, stacked back into, reshaped or copied by the loop
   (tests/test_inference.py pins the compiled step's temporaries under one
   layer's pool).
-- ONE compiled step a round: `sampled_step`'s program ends in the NaN
-  screen, the gather of each lane's last hidden row, the head over those
-  `B` rows and the sampler (`ops/sampling.with_tail`), so a serving round
-  is one program and one fetch and makes no `[T, V]` array. `ragged_step`
-  is the same stack with the head over every row (`ops/sampling.all_rows`,
-  a program of its own that no round runs), `verify_step` the stack's
-  `q_len == S` case, `generate` a host loop over `ragged_step`. What a
-  pool is made of is known where it is allocated and where its migration
-  header is written, nowhere else.
+- ONE compiled step a round: the `EngineCore` surface and the three
+  programs are the shell's (`inference/step_engine.StepEngine`) over this
+  file's `_ragged_stack` and `_head`, so a serving round is one program
+  and one fetch and makes no `[T, V]` array. What a pool is made of is
+  known where it is allocated and where its migration header is written,
+  nowhere else.
 - static shapes everywhere: batch and max_blocks fixed at engine build.
 """
 from __future__ import annotations
@@ -37,14 +34,11 @@ from __future__ import annotations
 import functools
 import time
 
-import numpy as np
-
 from ..models.llama import LlamaForCausalLM
-from ..observability import compile_trace
-from ..ops import sampling
 from . import kv_migrate
 from .cache import BlockCacheManager
-from .generate import GenerationConfig, generate
+from .generate import GenerationConfig
+from .step_engine import StepEngine
 
 __all__ = ["LlamaInferenceEngine", "GenerationConfig"]
 
@@ -151,14 +145,10 @@ def _rope_half(x, cos, sin):
     return jnp.concatenate([x1 * c - x2 * s, x2 * c + x1 * s], axis=-1)
 
 
-class LlamaInferenceEngine(kv_migrate.PagedPools):
-    """Batch inference over LlamaForCausalLM with a paged KV cache.
-
-    `sampled_step` is a round's one jitted program (`ragged_step` the
-    same stack's logits over every row, `verify_step` a case of it);
-    `generate` runs the host-side loop over `ragged_step` (sampling +
-    block-table bookkeeping, `inference/generate.py`).
-    """
+class LlamaInferenceEngine(kv_migrate.PagedPools, StepEngine):
+    """Batch inference over LlamaForCausalLM with a paged KV cache: the
+    shell's surface (`StepEngine`) over `_ragged_stack` and `_head`, COW
+    copy and KV migration over the pool tuple (`kv_migrate.PagedPools`)."""
 
     def __init__(self, model: LlamaForCausalLM, max_batch_size: int = 8,
                  num_blocks: int = 256, block_size: int = 16,
@@ -175,7 +165,6 @@ class LlamaInferenceEngine(kv_migrate.PagedPools):
         ragged scatter, dequantize inside the attention kernel — bf16 KV
         never round-trips HBM, so the same HBM budget holds ~2x the
         blocks."""
-        import jax
         import jax.numpy as jnp
 
         began = time.time()     # `engine.build_s`: this line to the last
@@ -218,24 +207,6 @@ class LlamaInferenceEngine(kv_migrate.PagedPools):
         self.manager.set_kv_geometry(
             kv_quant.kv_bytes_per_block(**self._kv_geom), self.kv_bits)
 
-        stack, head, verify = (
-            functools.partial(fn, cfg=_StaticCfg(cfg))
-            for fn in (_ragged_stack, _head, _verify_fn))
-        # a bare partial has no name and the XLA module would be
-        # `jit__unknown`; with the function's it is `jit__verify_fn` (the
-        # tail's wrappers bring theirs: `jit__ragged_fn`), which is how a
-        # profile's "XLA Modules" line tells the steps
-        verify.__name__ = _verify_fn.__name__
-        # the serving step ends in the screen, the row gather, the head
-        # over the sampled rows and the sampler (`ops/sampling.with_tail`):
-        # one program a round; `_logits` is the same stack with the head
-        # over every row, compiled when `ragged_step` first calls it
-        self._ragged = jax.jit(sampling.with_tail(stack, head),
-                               donate_argnums=(1,))
-        self._logits = jax.jit(sampling.all_rows(stack, head),
-                               donate_argnums=(1,))
-        self.last_sampled = None    # the last step's `sampled`, on device
-        self._verify = jax.jit(verify, donate_argnums=(1,))
         # COW copy and KV migration over the block axis (axis 1, all
         # layers at once): `kv_migrate.PagedPools`
         self._build_block_ops(1)
@@ -247,18 +218,9 @@ class LlamaInferenceEngine(kv_migrate.PagedPools):
             "kv_heads": kvh, "head_dim": d,
             "dtype": str(self.pools[0].dtype),
         }
-        compile_trace.stamp("engine.build", began)
-
-    def cost_card_args(self, phase: str):
-        """Observability hook (`observability.costs.ensure_engine_card`):
-        the jitted executable behind `phase` plus the leading arguments
-        the scheduler never sees (stacked params, the pool tuple).
-        Lowered — never executed — for `cost_analysis()`:
-        compiler-reported FLOPs per dispatch. The serving scheduler's
-        "decode" phase is the ragged step (its only decode program)."""
-        fn = {"decode": self._ragged, "ragged": self._ragged,
-              "verify": self._verify}[phase]
-        return fn, (self.params, self.pools)
+        self._build_programs(*(
+            functools.partial(fn, cfg=_StaticCfg(cfg))
+            for fn in (_ragged_stack, _head)), began=began)
 
     def kv_bytes_per_token(self) -> float:
         """HBM bytes one cached token costs across K+V and all layers
@@ -276,59 +238,6 @@ class LlamaInferenceEngine(kv_migrate.PagedPools):
         wb = {"int8": 8, "int4": 4, "fp8": 8}.get(self.weight_only, 16)
         return {"wbits": wb, "kv_bits": self.kv_bits,
                 "kv_bytes_per_token": self.kv_bytes_per_token()}
-
-    # ---- public API (the serving EngineCore surface) ----
-    def sampled_step(self, tokens: np.ndarray, lanes: np.ndarray,
-                     block_tables: np.ndarray, temperature: np.ndarray):
-        """ONE fixed-shape step over a packed ragged batch, sampled — the
-        serving scheduler's only decode-path program (chunked prefill +
-        decode lanes fused; see docs/SERVING.md "Ragged batching").
-
-        tokens [T] int32: packed lane-major query tokens; lane i owns
-        slots [sum(q_lens[:i]), sum(q_lens[:i]) + q_lens[i]), its token j
-        landing at position `kv_lens[i] - q_lens[i] + j` (kv_lens counts
-        the cache INCLUDING this step's tokens; q_lens[i] == 0 marks an
-        empty lane). `lanes` [B, 6] int32 carries q_lens, kv_lens and
-        each lane's last packed row, top_k, seed and draw index
-        (`ops/sampling.LANE_COLS`); `temperature` [B] float32. Returns
-        `sampled` [2, B] int32, left on the device: each lane's token and
-        whether its band is all finite (`ops/sampling.step_tail`). The
-        head runs over the `B` sampled rows alone; rows at guard slots
-        past sum(q_lens) are meaningless and ignored (their KV writes are
-        dropped, their attention output is forced to zero). Shape-stable
-        in everything but T, which the scheduler fixes at `max_batch_size
-        + prefill_chunk_tokens` — one compiled executable regardless of
-        batch composition or prompt length."""
-        self.last_sampled = self._run(
-            self._ragged, *sampling.call_arrays(
-                tokens, lanes, block_tables, temperature, self.last_sampled))
-        return self.last_sampled
-
-    def _run(self, fn, *arrays):
-        """One of the step programs over this engine's state, which it
-        replaces; what the program returns ahead of it."""
-        out, self.pools = fn(self.params, self.pools, *arrays)
-        return out
-
-    ragged_step = sampling.ragged_step
-
-    def verify_step(self, tokens: np.ndarray, context_lens: np.ndarray,
-                    block_tables: np.ndarray):
-        """Batched multi-token verify pass (speculative decoding).
-
-        tokens [B, S] int32 — per row, the pending last committed token
-        followed by S-1 draft tokens; `context_lens` [B] counts the cache
-        INCLUDING all S of them, so token i is written at position
-        `context_lens - S + i` and attends causally up to itself (same
-        fixed shape every step: zero recompiles once traced). Returns
-        logits [B, S, V]: row i is the distribution for the token AFTER
-        tokens[:, i] — rows 0..S-2 verify the drafts, row S-1 samples the
-        bonus token when every draft is accepted."""
-        return self._run(self._verify, np.asarray(tokens, np.int32),
-                         np.asarray(context_lens, np.int32),
-                         np.asarray(block_tables, np.int32))
-
-    generate = generate
 
 
 class _StaticCfg:
@@ -520,19 +429,3 @@ def _ragged_stack(params, pools, tokens, q_lens, kv_lens, tables, *, cfg):
         params, pools, x, positions, tables, kv_lens.astype(jnp.int32),
         cfg, ragged_meta=(tok_lane, tok_pos))
     return x[0], pools                                       # [T, H]
-
-
-def _verify_fn(params, pools, tokens, ctx_lens, tables, *, cfg):
-    """Speculative verify as a special case of the ragged step: every
-    lane contributes a fixed q_len == S window, so the packed buffer is
-    just tokens.reshape(B*S) and the logits fold back to [B, S, V]."""
-    import jax.numpy as jnp
-
-    from ..framework import monitor
-
-    monitor.inc("serving.verify_retraces")  # trace-time only
-    b, s = tokens.shape
-    q_lens = jnp.full((b,), s, jnp.int32)
-    x, pools = _ragged_stack(params, pools, tokens.reshape(b * s), q_lens,
-                             ctx_lens.astype(jnp.int32), tables, cfg=cfg)
-    return _head((params,), x, None, cfg=cfg).reshape(b, s, -1), pools

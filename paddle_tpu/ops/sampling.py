@@ -25,9 +25,11 @@ the gather of each lane's last row, the output head over those `B` rows and
 the sampler above are the END of the step's one program (`step_tail`), and
 what crosses to the host is one `[2, B]` int32 array: no `[T, V]` array is
 ever made. `sample_tokens` stays for the speculative verify round, whose
-`[B, S, V]` logits it samples whole. A caller that wants every packed row's
-logits (`generate`, proposers, a fault probe) takes the engine's SECOND
-program, `all_rows` over the same stack and head (`ragged_step`).
+`[B, S, V]` logits (the engine's THIRD program, `verify_windows`) it samples
+whole. A caller that wants every packed row's logits (`generate`,
+proposers, a fault probe) takes the engine's SECOND program, `all_rows`
+over the same stack and head (`ragged_step`). The three wrappers are all an
+engine compiles of its model (`inference/step_engine.StepEngine`).
 
 A round's decode tokens need not cross to the host between two rounds:
 `with_tail` reads a token `-(b + 1)` as "what lane `b` sampled in this
@@ -45,8 +47,8 @@ import numpy as np
 from ..framework import monitor
 
 __all__ = ["sample_tokens", "step_tail", "with_tail", "all_rows",
-           "pack_lanes", "call_arrays", "step_args", "ragged_step",
-           "fed_token", "LANE_COLS"]
+           "verify_windows", "pack_lanes", "call_arrays", "step_args",
+           "ragged_step", "fed_token", "LANE_COLS"]
 
 # the per-lane int32 block of a sampled step, one column each: with
 # `tokens`, `tables` and `temperature` it is everything a round sends
@@ -255,6 +257,27 @@ def all_rows(stack, head):
         return (head(tuple(state), hidden, lane), *out)
 
     return _logits_fn
+
+
+def verify_windows(stack, head):
+    """The same `stack` and `head` (see `with_tail`) as the speculative
+    verify program: `(*state, tokens [B, S], ctx_lens [B], tables) ->
+    (logits [B, S, V] float32, *state)`. A case of the ragged step: every
+    lane a window of `S` tokens, so the packed buffer is `tokens.reshape(B *
+    S)` and every `q_len` is `S`; the head runs over all rows and the logits
+    fold back a lane. An executable of its own (`jit__verify_fn`)."""
+    def _verify_fn(*args):
+        import jax.numpy as jnp
+
+        monitor.inc("serving.verify_retraces")  # trace-time only
+        *state, tokens, ctx_lens, tables = args
+        b, s = tokens.shape
+        hidden, *out = stack(*state, tokens.reshape(b * s),
+                             jnp.full((b,), s, jnp.int32), ctx_lens, tables)
+        lane = jnp.repeat(jnp.arange(b, dtype=jnp.int32), s)
+        return (head(tuple(state), hidden, lane).reshape(b, s, -1), *out)
+
+    return _verify_fn
 
 
 def call_arrays(tokens, lanes, block_tables, temperature, fed=None):

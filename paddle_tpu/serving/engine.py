@@ -26,6 +26,9 @@ exposes ONE compiled program a round:
   `generate(input_ids)` a host loop over `ragged_step`
   (`inference/generate.py`).
 
+Every engine class is state + a stack + a head under ONE shell,
+`inference.step_engine.StepEngine`, which writes this surface once.
+
 Beside it: `copy_kv_block(src, dst)` (the manager's COW hook) and
 `extract_kv_blocks(seq_id)` / `inject_kv_blocks(seq_id, payload)` (KV
 migration), each one donated executable over the whole pool tuple
@@ -63,8 +66,7 @@ import numpy as np
 
 from ..inference import kv_migrate
 from ..inference.cache import BlockCacheManager
-from ..inference.generate import generate
-from ..ops import sampling
+from ..inference.step_engine import StepEngine
 
 __all__ = ["EngineCore", "MLPLMEngine"]
 
@@ -185,23 +187,6 @@ def _mlp_ragged_stack(params, pools, tokens, q_lens, kv_lens, tables, *,
     return jnp.concatenate([x_loc, mean], axis=-1), pools    # [T, 2D]
 
 
-def _mlp_verify(params, pools, tokens, ctx_lens, tables, *, block_size,
-                tp=None):
-    """Speculative verify as a special case of the ragged step: every
-    lane is a fixed q_len == S window of the packed buffer."""
-    import jax.numpy as jnp
-
-    from ..framework import monitor
-
-    monitor.inc("serving.verify_retraces")  # trace-time only
-    b, s = tokens.shape
-    q_lens = jnp.full((b,), s, jnp.int32)
-    h, pools = _mlp_ragged_stack(
-        params, pools, tokens.reshape(b * s), q_lens,
-        ctx_lens.astype(jnp.int32), tables, block_size=block_size, tp=tp)
-    return _mlp_head((params,), h, None, tp=tp).reshape(b, s, -1), pools
-
-
 def _mlp_mm(h, w):
     """h [..., K] @ head weight: dense [K, N] array, weight-only-
     quantized {"q": [N, K], "s": [N]} / int4 {"q4": [N, K//2], "s"}
@@ -252,7 +237,7 @@ def _mlp_head(state, h, lane, *, tp=None):
     return logits.astype(jnp.float32)
 
 
-class MLPLMEngine(kv_migrate.PagedPools):
+class MLPLMEngine(kv_migrate.PagedPools, StepEngine):
     """Bag-of-embeddings MLP LM over the paged cache (EngineCore #2).
 
     The "KV" cache is [num_blocks, block_size, D] token embeddings; a token
@@ -265,7 +250,6 @@ class MLPLMEngine(kv_migrate.PagedPools):
                  max_batch_size: int = 8, num_blocks: int = 64,
                  block_size: int = 8, max_blocks_per_seq: int = 8,
                  seed: int = 0, kv_bits: int = 16):
-        import jax
         import jax.numpy as jnp
 
         self._init_kwargs = dict(
@@ -306,19 +290,9 @@ class MLPLMEngine(kv_migrate.PagedPools):
         self._slab_names = ("cache", "scale")[:len(self.pools)]
         self._kv_bytes_per_token = bpb / block_size
         self.manager.set_kv_geometry(bpb, self.kv_bits)
-        # the step ends in the screen, the row gather, the head over the
-        # sampled rows and the sampler (`ops/sampling.with_tail`): one
-        # program a round; `_logits` is the same stack with the head over
-        # every row, compiled when `ragged_step` first calls it
-        stack = functools.partial(_mlp_ragged_stack, block_size=block_size)
-        self._ragged = jax.jit(sampling.with_tail(stack, _mlp_head),
-                               donate_argnums=(1,))
-        self._logits = jax.jit(sampling.all_rows(stack, _mlp_head),
-                               donate_argnums=(1,))
-        self.last_sampled = None    # the last step's `sampled`, on device
-        self._verify = jax.jit(
-            functools.partial(_mlp_verify, block_size=block_size),
-            donate_argnums=(1,))
+        self._build_programs(
+            functools.partial(_mlp_ragged_stack, block_size=block_size),
+            _mlp_head)
         # COW copy and KV migration over the block axis (axis 0):
         # `kv_migrate.PagedPools`
         self._build_block_ops(0)
@@ -351,49 +325,3 @@ class MLPLMEngine(kv_migrate.PagedPools):
         an empty cache/pool — the watchdog `engine_factory` for this
         engine class (`engine_factory=broken_engine.respawn`)."""
         return MLPLMEngine(**self._init_kwargs)
-
-    def cost_card_args(self, phase: str):
-        """Observability hook (`observability.costs.ensure_engine_card`):
-        the jitted executable behind `phase` plus the leading arguments
-        the scheduler never sees (params, the pool tuple). The scheduler
-        appends its own call arrays and lowers the pair for
-        `cost_analysis()`/`memory_analysis()` — compiler-reported FLOPs
-        per dispatch, cached alongside the executable. Optional on
-        EngineCore: engines without it simply have no CostCard. The
-        serving "decode" phase maps to the ragged step (the scheduler's
-        only decode program)."""
-        fn = {"decode": self._ragged, "ragged": self._ragged,
-              "verify": self._verify}[phase]
-        return fn, (self.params, self.pools)
-
-    def verify_step(self, tokens: np.ndarray, context_lens: np.ndarray,
-                    block_tables: np.ndarray) -> np.ndarray:
-        """Multi-token verify pass; see `EngineCore.verify_step`. Token i
-        of row b lands at position context_lens[b] - S + i and conditions
-        on (its own embedding, masked mean through its position). Rides
-        the ragged step (q_len == S per lane)."""
-        # args go to the jit as exact-dtype numpy: the C++ dispatch path
-        # transfers them far cheaper than per-arg host-side jnp.asarray
-        # device_put calls — this discipline (shared with
-        # ops/sampling.py) is worth ~1 ms/arg on the decode hot loop
-        return self._run(self._verify, np.asarray(tokens, np.int32),
-                         np.asarray(context_lens, np.int32),
-                         np.asarray(block_tables, np.int32))
-
-    def sampled_step(self, tokens: np.ndarray, lanes: np.ndarray,
-                     block_tables: np.ndarray, temperature: np.ndarray):
-        """Packed ragged step, sampled; see `EngineCore.sampled_step`."""
-        self.last_sampled = self._run(
-            self._ragged, *sampling.call_arrays(
-                tokens, lanes, block_tables, temperature, self.last_sampled))
-        return self.last_sampled
-
-    def _run(self, fn, *arrays):
-        """One of the step programs over this engine's state, which it
-        replaces; what the program returns ahead of it."""
-        out, self.pools = fn(self.params, self.pools, *arrays)
-        return out
-
-    ragged_step = sampling.ragged_step
-
-    generate = generate

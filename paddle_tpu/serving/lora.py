@@ -60,8 +60,7 @@ import numpy as np
 from ..framework import monitor
 from ..inference import kv_migrate
 from ..inference.cache import BlockCacheManager
-from ..inference.generate import generate
-from ..ops import sampling
+from ..inference.step_engine import StepEngine
 
 __all__ = [
     "attach_adapters", "LoRAEngine", "AdapterPool", "lora_mm",
@@ -147,14 +146,15 @@ def _lane_ids(q_lens, kv_lens, num_tokens, lane_slots):
 
 # ---- wrapper jit bodies -------------------------------------------------
 # Each computes per-token ids, swaps the target weights, and calls the
-# BASE engine's function `base` (its stack, its head or its verify step,
-# the Llama engine's or the MLP engine's, its static arguments already
-# bound), so the tail's and the verify step's retrace counters bump at OUR
-# trace time and the zero-recompile suite's assertions carry over
-# unchanged. The `serving.lora.switch_retraces` bump is trace-time too:
-# adapter ids are data, so any post-warmup bump means an adapter switch
-# recompiled. `nlayers` is the leading axis of a stacked engine's weights
-# (they ride `lax.scan` xs, and so must the ids), None for a flat one.
+# BASE engine's function `base` (its stack or its head, the Llama engine's
+# or the MLP engine's, its static arguments already bound) under the
+# shell's three wrappers (`ops/sampling`), so the tail's and the verify
+# step's retrace counters bump at OUR trace time and the zero-recompile
+# suite's assertions carry over unchanged. The
+# `serving.lora.switch_retraces` bump is trace-time too: adapter ids are
+# data, so any post-warmup bump means an adapter switch recompiled.
+# `nlayers` is the leading axis of a stacked engine's weights (they ride
+# `lax.scan` xs, and so must the ids), None for a flat one.
 
 def _lora_stack(params, adapters, pools, lane_slots, tokens, q_lens,
                 kv_lens, tables, *, base, nlayers):
@@ -175,22 +175,6 @@ def _lora_head(state, x, lane, *, base):
     adapted; the Llama engine's head reads none of the swapped weights)."""
     params, adapters, _pools, lane_slots = state
     return base((_swap_lora(params, adapters, lane_slots[lane]),), x, lane)
-
-
-def _lora_verify(params, adapters, pools, lane_slots, tokens, ctx_lens,
-                 tables, *, base, nlayers):
-    import jax.numpy as jnp
-
-    monitor.inc("serving.lora.switch_retraces")  # trace-time only
-    b, s = tokens.shape
-    # the verify pass packs q_len == S per lane before riding the
-    # ragged stack — mirror that exact metadata here
-    q_lens = jnp.full((b,), s, jnp.int32)
-    ids = _lane_ids(q_lens, ctx_lens.astype(jnp.int32), b * s, lane_slots)
-    if nlayers is not None:
-        ids = jnp.broadcast_to(ids[None, :], (nlayers, b * s))
-    return base(_swap_lora(params, adapters, ids), pools, tokens,
-                ctx_lens, tables)
 
 
 # ---- the paged adapter pool --------------------------------------------
@@ -457,14 +441,18 @@ class AdapterPool:
 
 # ---- the engine wrapper -------------------------------------------------
 
-class LoRAEngine(kv_migrate.PagedPools):
+class LoRAEngine(kv_migrate.PagedPools, StepEngine):
     """`EngineCore` over a base engine plus a paged adapter pool: the
-    scheduler's dispatch surfaces (`ragged_step`, `verify_step`) re-jitted
+    shell's dispatch surfaces (`inference.step_engine.StepEngine`) re-jitted
     with the per-lane LoRA epilogue, fresh paged bookkeeping (own
     `BlockCacheManager` + zeroed KV pools — the base engine's donated
     executables stay valid; `copy_kv_block` and KV migration are the
     base's pure block executables over THIS engine's pools), and the
     observability hooks (`cost_card_args`, `quant_info`, `lora_info`)."""
+
+    # the adapters and the lane slots lead a step's arguments beside the
+    # params and the pools (`cost_card_args` hands them on)
+    LEADING = ("params", "_adapters", "pools", "_lane_slots")
 
     def __init__(self, base, pool_slots: int = 8,
                  rank_buckets: Tuple[int, ...] = DEFAULT_RANK_BUCKETS):
@@ -552,30 +540,20 @@ class LoRAEngine(kv_migrate.PagedPools):
         if self._kind == "llama":
             from ..inference import llama_runner as lr
 
-            stack, head, verify = (
+            stack, head = (
                 functools.partial(fn, cfg=lr._StaticCfg(base.config))
-                for fn in (lr._ragged_stack, lr._head, lr._verify_fn))
+                for fn in (lr._ragged_stack, lr._head))
         else:
-            from .engine import _mlp_head, _mlp_ragged_stack, _mlp_verify
+            from .engine import _mlp_head, _mlp_ragged_stack
 
-            stack, verify = (
-                functools.partial(fn, block_size=base.block_size)
-                for fn in (_mlp_ragged_stack, _mlp_verify))
+            stack = functools.partial(_mlp_ragged_stack,
+                                      block_size=base.block_size)
             head = _mlp_head
         self.pools = jax.tree.map(jnp.zeros_like, base.pools)
-        stack, verify = (
-            functools.partial(fn, base=b, nlayers=self._nlayers)
-            for fn, b in ((_lora_stack, stack), (_lora_verify, verify)))
-        head = functools.partial(_lora_head, base=head)
-        # the round ends in the base engines' tail: one program a round
-        # here too (`ops/sampling.with_tail`), and `_logits` the same stack
-        # with the head over every row (`ops/sampling.all_rows`)
-        self._ragged = jax.jit(sampling.with_tail(stack, head),
-                               donate_argnums=(2,))
-        self._logits = jax.jit(sampling.all_rows(stack, head),
-                               donate_argnums=(2,))
-        self.last_sampled = None    # the last step's `sampled`, on device
-        self._verify = jax.jit(verify, donate_argnums=(2,))
+        self._build_programs(
+            functools.partial(_lora_stack, base=stack,
+                              nlayers=self._nlayers),
+            functools.partial(_lora_head, base=head))
         # the base's block executables are pure: over THIS engine's
         # pools they cost no extra trace
         self._copy_block, self._kv_gather, self._kv_scatter = (
@@ -626,29 +604,6 @@ class LoRAEngine(kv_migrate.PagedPools):
         (`ServingMetrics.on_lora` -> `serving.lora.*` gauges)."""
         return self.adapter_pool.stats()
 
-    # -- EngineCore dispatch surfaces --
-    def sampled_step(self, tokens, lanes, block_tables, temperature):
-        self.last_sampled = self._run(
-            self._ragged, *sampling.call_arrays(
-                tokens, lanes, block_tables, temperature, self.last_sampled))
-        return self.last_sampled
-
-    def _run(self, fn, *arrays):
-        """One of the step programs over this engine's state, which it
-        replaces; what the program returns ahead of it."""
-        out, self.pools = fn(self.params, self._adapters, self.pools,
-                             self._lane_slots, *arrays)
-        return out
-
-    ragged_step = sampling.ragged_step
-
-    def verify_step(self, tokens, context_lens, block_tables):
-        return self._run(self._verify, np.asarray(tokens, np.int32),
-                         np.asarray(context_lens, np.int32),
-                         np.asarray(block_tables, np.int32))
-
-    generate = generate
-
     # -- observability / lifecycle --
     def quant_info(self) -> Dict[str, object]:
         info = getattr(self.base, "quant_info", None)
@@ -658,14 +613,6 @@ class LoRAEngine(kv_migrate.PagedPools):
 
     def kv_bytes_per_token(self) -> float:
         return self.base.kv_bytes_per_token()
-
-    def cost_card_args(self, phase: str):
-        """Cost-card hook: the LoRA executables take (params, adapters,
-        pools, lane_slots) ahead of the scheduler's call arrays."""
-        fn = {"decode": self._ragged, "ragged": self._ragged,
-              "verify": self._verify}[phase]
-        return fn, (self.params, self._adapters, self.pools,
-                    self._lane_slots)
 
     def respawn(self) -> "LoRAEngine":
         """Watchdog `engine_factory` hook: rebuild the base through ITS

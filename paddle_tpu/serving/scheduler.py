@@ -301,7 +301,6 @@ class Scheduler:
                  admission: Optional[AdmissionConfig] = None,
                  watchdog: Optional[WatchdogConfig] = None,
                  engine_factory: Optional[Callable[[], EngineCore]] = None,
-                 nan_checks: bool = True,
                  prefill_chunk_tokens: int = 32,
                  prefix_cache: bool = False,
                  slo: Optional[SLOConfig] = None,
@@ -341,7 +340,6 @@ class Scheduler:
         # the chunk being appended and the one still in flight before it
         self._window_step = 2 * self.prefill_chunk_tokens
         self.engine_factory = engine_factory
-        self.nan_checks = nan_checks
         self._overload = OverloadController(admission) if admission else None
         if watchdog is None and engine_factory is not None:
             # a factory without a config opts into the default watchdog —
@@ -1891,7 +1889,7 @@ class Scheduler:
         live = [ln for ln in rnd.lanes.values() if ln.holds(self)]
         if len(live) < len(rnd.lanes):
             self.metrics.on_wasted_lanes(len(rnd.lanes) - len(live))
-        if live and (rnd.flagged or self.nan_checks):
+        if live:
             finite = finite.astype(bool)
             if rnd.flagged:          # injection path: poison one lane
                 finite[live[0].slot] = False
@@ -2097,21 +2095,20 @@ class Scheduler:
             self._step_fault("verify", e, lane_pairs, probe=probe,
                              rollback=rollback)
             return 0
-        if flagged or self.nan_checks:
-            if flagged:              # injection path: poison one lane
-                arr = np.array(logits)
-                arr[lanes[0][0]] = np.nan
-                logits = arr
-                finite = np.isfinite(arr).all(axis=(-2, -1))
-            else:                    # hot path: [B, S] bool fetch only
-                finite = self._finite_rows(logits).all(axis=-1)
-            for i, req in lane_pairs:
-                if not finite[i]:
-                    self._isolated(req, "nan_logits", "verify", slot=i)
-                    lane_reqs[i] = None
-            lanes = [ln for ln in lanes if self.slots[ln[0]] is ln[1]]
-            if not lanes:
-                return 0
+        if flagged:                  # injection path: poison one lane
+            arr = np.array(logits)
+            arr[lanes[0][0]] = np.nan
+            logits = arr
+            finite = np.isfinite(arr).all(axis=(-2, -1))
+        else:                        # hot path: [B, S] bool fetch only
+            finite = self._finite_rows(logits).all(axis=-1)
+        for i, req in lane_pairs:
+            if not finite[i]:
+                self._isolated(req, "nan_logits", "verify", slot=i)
+                lane_reqs[i] = None
+        lanes = [ln for ln in lanes if self.slots[ln[0]] is ln[1]]
+        if not lanes:
+            return 0
         t_tok = self._clock()
         try:
             _faults.check("serve.sample")

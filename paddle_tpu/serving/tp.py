@@ -61,9 +61,8 @@ from ..distributed.process_mesh import ProcessMesh
 from ..distributed.tp_overlap import TPInfo
 from ..inference import kv_migrate
 from ..inference.cache import BlockCacheManager
-from ..inference.generate import generate
+from ..inference.step_engine import StepEngine
 from ..observability import comms
-from ..ops import sampling
 
 __all__ = ["ShardingConfigError", "shard_engine", "ShardedEngine"]
 
@@ -266,9 +265,9 @@ def shard_engine(engine, mesh: Optional[ProcessMesh] = None, *,
                          overlap_tiles=int(overlap_tiles))
 
 
-class ShardedEngine(kv_migrate.PagedPools):
-    """TP-sharded `EngineCore`: the serving scheduler's dispatch surfaces
-    (`ragged_step`, `verify_step`) over shard_map'd executables,
+class ShardedEngine(kv_migrate.PagedPools, StepEngine):
+    """TP-sharded `EngineCore`: the shell's dispatch surfaces
+    (`inference.step_engine.StepEngine`) over shard_map'd executables,
     `copy_kv_block` and KV migration over the sharded pool tuple
     (`kv_migrate.PagedPools`: block ids are logical, the sharded
     head/feature axis is untouched, so every chip moves its own slice
@@ -315,7 +314,7 @@ class ShardedEngine(kv_migrate.PagedPools):
         if kind == "llama":
             from ..inference import kv_quant
             from ..inference.llama_runner import (_head, _ragged_stack,
-                                                  _StaticCfg, _verify_fn)
+                                                  _StaticCfg)
 
             cfg = base.config
             nh, kvh, d = (cfg.num_attention_heads, cfg.num_key_value_heads,
@@ -346,10 +345,8 @@ class ShardedEngine(kv_migrate.PagedPools):
             lcfg.num_kv_heads //= tp
             lcfg.tp = self.tpinfo
             lspec = R if (overlap or not vocab_sharded) else P(None, "tp")
-            vspec = R if (overlap or not vocab_sharded) \
-                else P(None, None, "tp")
-            stack, head, verify = (functools.partial(fn, cfg=lcfg) for fn in
-                                   (_ragged_stack, _head, _verify_fn))
+            stack, head = (functools.partial(fn, cfg=lcfg)
+                           for fn in (_ragged_stack, _head))
             # the row-parallel gemms' psums leave every shard the whole
             # hidden row
             hspec = R
@@ -363,7 +360,7 @@ class ShardedEngine(kv_migrate.PagedPools):
                       "kv_heads": base._kv_geom["kv_heads"],
                       "head_dim": geom["head_dim"]}
         else:
-            from .engine import _mlp_head, _mlp_ragged_stack, _mlp_verify
+            from .engine import _mlp_head, _mlp_ragged_stack
 
             d = int(base.params["embed"].shape[1])
             p = dict(base.params)
@@ -378,11 +375,9 @@ class ShardedEngine(kv_migrate.PagedPools):
             # shard holds every slot's scale
             poolspec = (P(None, None, "tp"), R)[:len(base.pools)]
             lspec = R if overlap else P(None, "tp")
-            vspec = R if overlap else P(None, None, "tp")
-            stack, verify = (
-                functools.partial(fn, block_size=base.block_size,
-                                  tp=self.tpinfo)
-                for fn in (_mlp_ragged_stack, _mlp_verify))
+            stack = functools.partial(_mlp_ragged_stack,
+                                      block_size=base.block_size,
+                                      tp=self.tpinfo)
             head = functools.partial(_mlp_head, tp=self.tpinfo)
             # each shard's [own embedding, window mean] feature slices,
             # side by side in the order of `w1`'s permuted rows
@@ -399,7 +394,8 @@ class ShardedEngine(kv_migrate.PagedPools):
         # (`ops/sampling.with_tail`), so the head's gemms and its
         # collective run over `B` rows, and the screen and the sampler
         # follow over whatever layout the logits leave the head in: vocab
-        # shards in sequential mode, replicated rows under overlap
+        # shards in sequential mode, replicated rows under overlap; the
+        # verify program is the same two over a window a lane
         stack = jax.shard_map(
             stack, mesh=jmesh, in_specs=(pspec, poolspec, R, R, R, R),
             out_specs=(hspec, poolspec), check_vma=False)
@@ -410,19 +406,11 @@ class ShardedEngine(kv_migrate.PagedPools):
         def sharded_head(state, x, lane):
             return rows_head(state[0], x)
 
-        self._ragged = jax.jit(sampling.with_tail(stack, sharded_head),
-                               donate_argnums=(1,))
-        self._logits = jax.jit(sampling.all_rows(stack, sharded_head),
-                               donate_argnums=(1,))
+        self._build_programs(stack, sharded_head)
         # the last step's `sampled`, replicated as the step leaves it: a
         # host array first would key a second executable
         self.last_sampled = put(
             np.zeros((2, self.max_batch_size), np.int32), R)
-        self._verify = jax.jit(jax.shard_map(
-            verify, mesh=jmesh,
-            in_specs=(pspec, poolspec, R, R, R),
-            out_specs=(vspec, poolspec),
-            check_vma=False), donate_argnums=(1,))
         self._step_label = f"serving.ragged_step_tp{tp}"
         # COW copy and KV migration index the LOGICAL block axis, which is
         # unsharded in both layouts: shardings propagate through the
@@ -457,36 +445,6 @@ class ShardedEngine(kv_migrate.PagedPools):
     def kv_bytes_per_token(self) -> float:
         return self._kv_bytes_per_token
 
-    def cost_card_args(self, phase: str):
-        """The SPMD executable + sharded leading args: lowering this
-        pair reports PER-CHIP FLOPs (XLA cost analysis is per-device for
-        SPMD programs) — the %peak math stops counting the replicated
-        illusion. Phases without a TP executable raise KeyError (the
-        caller tombstones)."""
-        fn = {"decode": self._ragged, "ragged": self._ragged,
-              "verify": self._verify}[phase]
-        return fn, (self.params, self.pools)
-
-    # ---- the EngineCore dispatch surface ----
-    def sampled_step(self, tokens: np.ndarray, lanes: np.ndarray,
-                     block_tables: np.ndarray, temperature: np.ndarray):
-        """Packed ragged step, sampled (see `EngineCore.sampled_step`),
-        TP-sharded: `sampled [2, B]`, replicated, on the device."""
-        self.last_sampled = self._run(
-            self._ragged, *sampling.call_arrays(
-                tokens, lanes, block_tables, temperature, self.last_sampled))
-        return self.last_sampled
-
-    ragged_step = sampling.ragged_step
-
-    def verify_step(self, tokens: np.ndarray, context_lens: np.ndarray,
-                    block_tables: np.ndarray) -> np.ndarray:
-        """Speculative verify (see `EngineCore.verify_step`), TP-sharded
-        — rides the same sharded ragged stack, so spec == plain under TP."""
-        return self._run(self._verify, *(
-            np.asarray(a, np.int32)
-            for a in (tokens, context_lens, block_tables)))
-
     def _run(self, fn, *args):
         """One of the step programs over this engine's pools, which it
         replaces; what the program returns ahead of them. With
@@ -505,7 +463,7 @@ class ShardedEngine(kv_migrate.PagedPools):
         ran in-program, over the head's `B` rows); logits (the verify
         step's, the all-rows program's) leave sequential mode through
         the host."""
-        out, self.pools = fn(self.params, self.pools, *args)
+        out = StepEngine._run(self, fn, *args)
         if self.overlap or fn is self._ragged:
             if obs_on:
                 self._jax.block_until_ready(out)
@@ -520,5 +478,3 @@ class ShardedEngine(kv_migrate.PagedPools):
             comms.record("all_gather", self.tp, out.nbytes, t0,
                          time.perf_counter() - t0)
         return out
-
-    generate = generate
